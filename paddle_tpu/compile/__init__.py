@@ -21,13 +21,13 @@ wide compilation reuse):
 Wired into the three compile paths: ``jit.to_static`` dispatch, SOT
 segment flushes, and loaded inference artifacts (``jit.load`` /
 ``inference.Predictor``). Enable with ``FLAGS_compile_cache=1`` (cache
-directory: ``FLAGS_compile_cache_dir`` or
-``$PADDLE_TPU_COMPILE_CACHE_DIR``).
+directory: ``FLAGS_compile_cache_dir``, ``$PADDLE_TPU_COMPILE_CACHE_DIR``
+or ``<cache_root>/paddle_tpu/pcc`` — see :func:`.cache.cache_root`).
 """
 from __future__ import annotations
 
-from .cache import (CompileCache, cache_dir, enabled, get_cache,
-                    record_time_saved)
+from .cache import (CompileCache, cache_dir, cache_root, enable_jax_cache,
+                    enabled, get_cache, record_time_saved)
 from .fingerprint import (aval_sig, blob_digest, code_fingerprint,
                           env_fingerprint, key_of)
 from .warmup import (manifest_path, read_manifest, record_artifact,
@@ -35,8 +35,9 @@ from .warmup import (manifest_path, read_manifest, record_artifact,
 from . import aot
 
 __all__ = [
-    "CompileCache", "get_cache", "enabled", "cache_dir",
-    "record_time_saved", "key_of", "env_fingerprint", "aval_sig",
+    "CompileCache", "get_cache", "enabled", "cache_dir", "cache_root",
+    "enable_jax_cache", "record_time_saved", "key_of", "env_fingerprint",
+    "aval_sig",
     "blob_digest", "code_fingerprint", "warm", "record_to_static",
     "record_artifact",
     "manifest_path", "read_manifest", "aot",

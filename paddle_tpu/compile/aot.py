@@ -50,8 +50,14 @@ def serialize_compiled(compiled) -> Optional[Tuple[str, bytes]]:
     try:
         from jax.experimental import serialize_executable as se
         payload, in_tree, out_tree = se.serialize(compiled)
-        return TIER_EXEC, pickle.dumps((payload, in_tree, out_tree),
-                                       protocol=4)
+        # the executable is loaded back over exactly the devices it was
+        # compiled for (deserialize_and_load defaults to EVERY local
+        # device, which mis-shards a 1-device program on a multi-device
+        # host)
+        device_ids = [d.id for d in
+                      compiled.runtime_executable().local_devices()]
+        return TIER_EXEC, pickle.dumps(
+            (payload, in_tree, out_tree, device_ids), protocol=4)
     except Exception:
         return None
 
@@ -72,10 +78,14 @@ def load_runner(tier: str, payload: bytes) -> Optional[Callable]:
     """
     if tier == TIER_EXEC:
         try:
+            import jax
             from jax.experimental import serialize_executable as se
             with _trace.span("pcc_deserialize:exec", "compile"):
-                blob, in_tree, out_tree = pickle.loads(payload)
-                return se.deserialize_and_load(blob, in_tree, out_tree)
+                blob, in_tree, out_tree, device_ids = pickle.loads(payload)
+                by_id = {d.id: d for d in jax.devices()}
+                return se.deserialize_and_load(
+                    blob, in_tree, out_tree,
+                    execution_devices=[by_id[i] for i in device_ids])
         except Exception:
             _m_deser_fail.inc(tier=TIER_EXEC)
             return None

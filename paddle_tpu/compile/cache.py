@@ -47,7 +47,7 @@ from ..observability import metrics as _metrics
 from ..observability import trace as _trace
 
 __all__ = ["CompileCache", "get_cache", "enabled", "cache_dir",
-           "record_time_saved"]
+           "cache_root", "enable_jax_cache", "record_time_saved"]
 
 _MAGIC = b"PTPCC001"
 _HEADER = struct.Struct("<IIQI")   # meta_len, meta_crc, payload_len, payload_crc
@@ -89,6 +89,37 @@ def enabled() -> bool:
     return bool(flags.get_flag("compile_cache"))
 
 
+_JAX_CACHE_ENV = "JAX_COMPILATION_CACHE_DIR"
+
+
+def cache_root() -> str:
+    """The one directory every on-disk cache of this checkout lives
+    under: ``$JAX_COMPILATION_CACHE_DIR`` where the machine sets it,
+    else ``<checkout>/.jax_cache``. The path is FIXED (never a temp
+    dir, pid or timestamp): it is part of JAX's cache key, so a
+    directory that moves never hits, and a machine that sets the
+    variable is the only place a later run finds this run's entries."""
+    env = os.environ.get(_JAX_CACHE_ENV)
+    if env:
+        return env
+    checkout = os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    return os.path.join(checkout, ".jax_cache")
+
+
+def enable_jax_cache() -> str:
+    """Turn on JAX's persistent compilation cache at :func:`cache_root`
+    and return that directory. Entry points that compile on the chip
+    (``chip_smoke.py``, ``bench.py``, the ``tools/`` chip scripts) call
+    this BEFORE their first compile. With the variable set JAX reads it
+    itself and nothing is set here."""
+    root = cache_root()
+    if not os.environ.get(_JAX_CACHE_ENV):
+        import jax
+        jax.config.update("jax_compilation_cache_dir", root)
+    return root
+
+
 def cache_dir() -> str:
     d = flags.get_flag("compile_cache_dir")
     if d:
@@ -96,8 +127,7 @@ def cache_dir() -> str:
     env = os.environ.get("PADDLE_TPU_COMPILE_CACHE_DIR")
     if env:
         return os.path.expanduser(env)
-    return os.path.expanduser(os.path.join("~", ".cache", "paddle_tpu",
-                                           "pcc"))
+    return os.path.join(cache_root(), "paddle_tpu", "pcc")
 
 
 def record_time_saved(seconds: float) -> None:

@@ -116,8 +116,8 @@ define_flag("use_autotune", True,
             "(reference FLAGS_use_autotune).")
 define_flag("autotune_attn_impl", False,
             "Also autotune the attention ALGORITHM (XLA dense vs Pallas "
-            "flash) per shape class. Opt-in: a probe taken on a degraded "
-            "transport can flip a model to the slow path wholesale; tile "
+            "flash) per shape class. Opt-in: one noisy probe can "
+            "flip a model to the slow path wholesale; tile "
             "tuning has bounded downside, algorithm selection does not.")
 define_flag("eager_jit_cache", True, "Run steady-state eager ops through cached compiled lowerings.")
 define_flag("log_level", 0, "VLOG-style verbosity for framework logging.")
@@ -129,7 +129,7 @@ define_flag("compile_cache", False,
             "Enable the persistent on-disk compilation cache.")
 define_flag("compile_cache_dir", "",
             "Cache directory; empty = $PADDLE_TPU_COMPILE_CACHE_DIR or "
-            "~/.cache/paddle_tpu/pcc.")
+            "<cache_root>/paddle_tpu/pcc (compile.cache.cache_root).")
 define_flag("compile_cache_size_mb", 512,
             "LRU size budget for the persistent compilation cache (MB).")
 define_flag("compile_cache_manifest", "",

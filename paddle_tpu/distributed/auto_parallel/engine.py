@@ -272,6 +272,23 @@ class Engine:
         return jax.device_put(jnp.asarray(arr),
                               NamedSharding(self._mesh, spec))
 
+    def _replicate_over_mesh(self, tree):
+        """Default data-parallel placement of params + optimizer state:
+        leaves still on one device are committed REPLICATED over the
+        mesh before the first step. Left where model init put them, the
+        first step compiles for single-device inputs and the second for
+        the replicated arrays the first returned — two compiles of the
+        same step. Leaves already laid out over the mesh (shard_tensor,
+        fleet layers) keep their placement."""
+        replicated = NamedSharding(self._mesh, P())
+
+        def place(a):
+            if isinstance(a, jax.Array) and len(a.sharding.device_set) == 1:
+                return jax.device_put(a, replicated)
+            return a
+
+        return jax.tree_util.tree_map(place, tree)
+
     def dataloader(self, dataset, batch_size=32, shuffle=False,
                    mode="train"):
         from ...io import DataLoader
@@ -303,6 +320,8 @@ class Engine:
         loader = self.dataloader(train_data, batch_size, shuffle=True)
         pa = [p._data for p in self._params]
         opt_state = self._init_opt_state(pa)
+        if self._mesh.size > 1 and not self._spmd_auto:
+            pa, opt_state = self._replicate_over_mesh((pa, opt_state))
         sched = getattr(self._opt, "_learning_rate", None)
         sched = sched if isinstance(sched, LRScheduler) else None
         use_prefetch = (bool(_flags.get_flag("prefetch"))

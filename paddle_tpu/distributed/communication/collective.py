@@ -29,7 +29,7 @@ from ..shard_map_compat import shard_map as _shard_map_compat
 def shard_map(f, mesh, in_specs, out_specs):
     """shard_map with the static replication checker off — collective
     outputs (all_gather/broadcast) are replicated in ways the checker can't
-    infer. Version portability lives in distributed.shard_map_compat."""
+    infer."""
     return _shard_map_compat(f, mesh=mesh, in_specs=in_specs,
                              out_specs=out_specs, check=False)
 

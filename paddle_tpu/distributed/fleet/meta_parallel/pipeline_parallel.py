@@ -58,8 +58,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from ....core.tensor import Tensor
 from ....nn.layer.layers import Layer
 from ... import mesh as mesh_mod
-from ...shard_map_compat import (replicate_for_manual as _replicate,
-                                 shard_map as _shard_map)
+from ...shard_map_compat import shard_map as _shard_map
 from .pipeline_schedules import (spmd_pipeline_hetero,
                                  spmd_pipeline_interleaved, spmd_pipeline_zb)
 from .pp_layers import PipelineLayer, SegmentLayers
@@ -194,13 +193,11 @@ def spmd_pipeline(block_fn: Callable, stacked: Sequence, xs, *, mesh,
         return jax.lax.psum(
             jnp.where(idx == S - 1, out, jnp.zeros_like(out)), "pp")
 
-    staged = [_replicate(a, mesh) for a in staged]
     return _shard_map(
         body, mesh=mesh,
         in_specs=([P("pp")] * len(staged), P()),
         out_specs=P(),
-        axis_names=frozenset({"pp"}), check=False)(staged,
-                                                   _replicate(xs, mesh))
+        axis_names=frozenset({"pp"}), check=False)(staged, xs)
 
 
 class PipelineParallel(Layer):

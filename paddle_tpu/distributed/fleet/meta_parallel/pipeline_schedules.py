@@ -39,8 +39,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from ...shard_map_compat import (replicate_for_manual as _replicate,
-                                 shard_map as _shard_map)
+from ...shard_map_compat import shard_map as _shard_map
 
 
 def schedule_block_ticks(schedule: str, m: int, S: int, K: int) -> int:
@@ -170,13 +169,11 @@ def spmd_pipeline_interleaved(block_fn: Callable, stacked: Sequence, xs, *,
             jnp.where(idx == S - 1, out, jnp.zeros_like(out)), "pp")
         return out, jax.lax.psum(n_active, "pp")
 
-    chunked = [_replicate(a, mesh) for a in chunked]
     out, n_active = _shard_map(
         body, mesh=mesh,
         in_specs=([P("pp")] * len(chunked), P()),
         out_specs=(P(), P()),
-        axis_names=frozenset({"pp"}), check=False)(chunked,
-                                                   _replicate(xs, mesh))
+        axis_names=frozenset({"pp"}), check=False)(chunked, xs)
     if return_stats:
         return out, {"active_block_ticks": n_active,
                      "total_block_slots": T * S}
@@ -309,13 +306,11 @@ def spmd_pipeline_zb(block_fn: Callable, stacked: Sequence, xs, *,
         out_local = pipe(local_outer, xs)
         return jax.lax.psum(out_local, "pp")
 
-    staged = [_replicate(a, mesh) for a in staged]
     out = _shard_map(
         lambda st, xs: body(st, xs), mesh=mesh,
         in_specs=([P("pp")] * len(staged), P()),
         out_specs=P(),
-        axis_names=frozenset({"pp"}), check=False)(staged,
-                                                   _replicate(xs, mesh))
+        axis_names=frozenset({"pp"}), check=False)(staged, xs)
     return out
 
 
@@ -463,7 +458,6 @@ def spmd_pipeline_hetero(stage_fns: List[Callable],
         body, mesh=mesh,
         in_specs=(P("pp"), P()),
         out_specs=P(),
-        axis_names=frozenset({"pp"}), check=False)(
-            _replicate(packed, mesh), _replicate(xs, mesh))
+        axis_names=frozenset({"pp"}), check=False)(packed, xs)
     out = out_flat[:, :out_size].reshape((m,) + tuple(out_aval.shape))
     return out.astype(out_aval.dtype)

@@ -46,23 +46,7 @@ def _mesh(group: Optional[Group]):
 
 def _constraint(arr, mesh, spec: P):
     """Differentiable reshard: with_sharding_constraint works both eagerly
-    and under trace on jax>=0.9.
-
-    Inside a legacy FULL-manual shard_map region (the pipeline runtime's
-    old-jax fallback — see distributed.shard_map_compat), every mesh
-    axis is manual and a constraint over one fails at LOWERING time.
-    The region's in_specs already claimed these values replicated at the
-    boundary (the buffers were gathered), so the reshard hint is a no-op
-    there — detect the bound axes at trace time and skip emitting the
-    op, keeping the composed hybrid (pp×mp) path alive."""
-    for entry in spec:
-        for ax in ((entry,) if isinstance(entry, str)
-                   else (entry or ())):
-            try:
-                jax.core.axis_frame(ax)  # raises if the axis is unbound
-            except Exception:
-                continue
-            return arr  # axis is manual in the enclosing region
+    and under trace."""
     return jax.lax.with_sharding_constraint(arr, NamedSharding(mesh, spec))
 
 

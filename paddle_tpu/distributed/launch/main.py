@@ -4,14 +4,21 @@ Capability parity with the reference launcher (reference:
 python/paddle/distributed/launch/main.py:21 — `python -m
 paddle.distributed.launch --nnodes ... train.py`, builds per-rank envs,
 spawns/monitors workers, restarts under elastic policy
-fleet/elastic/manager.py:124). TPU-native: one process per HOST (single
-controller drives all local chips), so --nproc_per_node defaults to 1; the
-env contract sets both the reference names (PADDLE_TRAINER_ID …) and the
-jax.distributed coordinates the framework's parallel.init reads.
+fleet/elastic/manager.py:124). TPU-native: ONE process drives all local
+chips of a host (single controller), so --nproc_per_node defaults to 1.
+Workers get no chip of their own from this launcher — a chip belongs to
+one process at a time, and every worker that reaches the TPU claims every
+local chip — so ``--nproc_per_node > 1`` is refused on a TPU host unless
+the workers are pinned off it (``JAX_PLATFORMS=cpu``, as the CPU test
+drills do). The launcher itself never imports jax: a parent that has
+touched JAX holds the chip its workers need. The env contract sets both
+the reference names (PADDLE_TRAINER_ID …) and the jax.distributed
+coordinates the framework's parallel.init reads.
 """
 from __future__ import annotations
 
 import argparse
+import glob
 import os
 import signal
 import subprocess
@@ -44,6 +51,17 @@ def _parse(argv=None):
     p.add_argument("training_script")
     p.add_argument("training_script_args", nargs=argparse.REMAINDER)
     return p.parse_args(argv)
+
+
+def _workers_would_claim_tpu() -> bool:
+    """True when worker processes started with this environment would
+    initialize the TPU backend: the host exposes TPU device nodes and
+    ``JAX_PLATFORMS`` does not pin the workers to another platform.
+    Decided without importing jax (see the module docstring)."""
+    platforms = os.environ.get("JAX_PLATFORMS", "")
+    if platforms and "tpu" not in platforms.lower().split(","):
+        return False
+    return bool(glob.glob("/dev/accel*") or glob.glob("/dev/vfio/[0-9]*"))
 
 
 def _worker_env(args, local_rank: int, epoch: int = 0,
@@ -140,6 +158,13 @@ def launch(argv=None) -> int:
     from .elastic import parse_nnodes
 
     args = _parse(argv)
+    if args.nproc_per_node > 1 and _workers_would_claim_tpu():
+        raise SystemExit(
+            f"--nproc_per_node {args.nproc_per_node} on a TPU host: every "
+            f"worker would claim every local chip, and a chip belongs to "
+            f"one process at a time. One process drives all local chips "
+            f"(--nproc_per_node 1, shard over jax.devices() with a mesh); "
+            f"for a CPU-only multi-process run set JAX_PLATFORMS=cpu.")
     nnodes_min, nnodes_max = parse_nnodes(args.nnodes)
     args.ps_token = os.environ.get("PADDLE_PS_TOKEN", "")
     if not args.ps_token and nnodes_max == 1:

@@ -63,20 +63,6 @@ class ParallelEnv:
 _initialized = False
 
 
-def _jax_distributed_initialized() -> bool:
-    """``jax.distributed.is_initialized`` across jax versions — older
-    lineages never exported it; the coordination client on the global
-    distributed state is the same probe (and touches no backend)."""
-    probe = getattr(jax.distributed, "is_initialized", None)
-    if probe is not None:
-        return bool(probe())
-    try:
-        from jax._src import distributed as _dist
-        return _dist.global_state.client is not None
-    except Exception:
-        return False
-
-
 def init_parallel_env(mesh_shape=None):
     """Bring up the distributed runtime (reference parallel.py:945).
 
@@ -104,16 +90,8 @@ def init_parallel_env(mesh_shape=None):
             coord = master if ":" in master else f"{master}:{port}"
     # must not probe jax.process_count() here: touching the backend before
     # jax.distributed.initialize permanently forecloses multi-process init
-    # (the coordination-client probe reads no backend state)
-    if coord and nnodes > 1 and not _jax_distributed_initialized():
-        try:
-            # CPU cross-process collectives need an explicit transport
-            # on this jax lineage (newer ones default it); must be set
-            # before the backend client exists
-            jax.config.update("jax_cpu_collectives_implementation",
-                              "gloo")
-        except Exception:
-            pass
+    # (jax.distributed.is_initialized reads no backend state)
+    if coord and nnodes > 1 and not jax.distributed.is_initialized():
         jax.distributed.initialize(
             coordinator_address=coord,
             num_processes=nnodes,

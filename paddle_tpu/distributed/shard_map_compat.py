@@ -1,84 +1,29 @@
-"""``shard_map`` across jax versions — one call site contract.
+"""``shard_map`` — one call-site contract for the distributed runtimes.
 
-jax moved ``shard_map`` from ``jax.experimental.shard_map`` to the
-top-level ``jax.shard_map`` and renamed its knobs along the way
-(``check_rep`` → ``check_vma``; explicit manual axes went from the
-``auto=`` complement to ``axis_names=``). The fleet pipeline/sep
-runtimes were written against the new surface and broke on toolchains
-that only ship the experimental entry point. This module owns the
-version dance so every caller — collectives, pipeline schedules, ring
-attention — speaks ONE signature:
+Every caller — collectives, pipeline schedules, ring attention — speaks
+ONE signature over ``jax.shard_map``:
 
     shard_map(f, mesh, in_specs, out_specs, axis_names=None, check=False)
 
 ``axis_names`` is the set of mesh axes the body maps manually (None =
-all of them); ``check`` is the static replication/VMA checker.
+all of them); ``check`` is the static varying-manual-axes checker
+(``check_vma``), off by default because the schedules' bodies mix
+replicated and device-varying carries.
 """
 from __future__ import annotations
 
 import jax
 
-try:  # modern jax: top-level export
-    _shard_map_raw = jax.shard_map
-    _MODERN = True
-except AttributeError:  # older jax: experimental module only
-    from jax.experimental.shard_map import shard_map as _shard_map_raw
-    _MODERN = False
 
-try:  # modern jax: varying-manual-axes marker for the VMA checker
-    pvary = jax.lax.pvary
-except AttributeError:
-    def pvary(x, axis_name):
-        """No-op on jax lineages without the VMA type system — there is
-        no device-varying annotation to apply."""
-        return x
-
-
-def replicate_for_manual(x, mesh):
-    """Pin a value entering a manual (shard_map) region to REPLICATED.
-
-    Legacy-lineage workaround: when a shard_map input is *produced
-    in-trace* by a concatenate/stack/pad of several values (stacked
-    stage weights, padded ring buffers), the old SPMD partitioner on a
-    multi-axis mesh mis-slices the region's input — silently wrong
-    numbers (reproduced: stack of jit args → in_specs P("pp") on a
-    dp×pp mesh). Forcing the buffer replicated at the boundary makes
-    shard_map itself do the slicing, which partitions correctly. On
-    modern jax this is an identity — the partitioner handles it.
-    """
-    if _MODERN:
-        return x
-    from jax.sharding import NamedSharding, PartitionSpec
-    return jax.lax.with_sharding_constraint(
-        x, NamedSharding(mesh, PartitionSpec()))
+def pvary(x, axis_name):
+    """Mark ``x`` device-varying over ``axis_name`` for the VMA checker
+    (a loop carry initialized from a constant must be varying before a
+    ppermute result is written into it)."""
+    return jax.lax.pcast(x, axis_name, to="varying")
 
 
 def shard_map(f, mesh, in_specs, out_specs, axis_names=None, check=False):
-    """Version-portable ``shard_map``.
-
-    ``axis_names``: mesh axes mapped manually inside ``f`` (None = every
-    axis of ``mesh``). ``check``: enable the static replication checker
-    (``check_vma`` on modern jax, ``check_rep`` before the rename).
-    """
-    if _MODERN:
-        kw = {"check_vma": check}
-        if axis_names is not None:
-            kw["axis_names"] = frozenset(axis_names)
-        try:
-            return _shard_map_raw(f, mesh=mesh, in_specs=in_specs,
-                                  out_specs=out_specs, **kw)
-        except TypeError:
-            # transitional releases: check_vma not yet renamed
-            kw.pop("check_vma")
-            return _shard_map_raw(f, mesh=mesh, in_specs=in_specs,
-                                  out_specs=out_specs, check_rep=check,
-                                  **kw)
-    # Experimental API. Partial-manual (auto=complement) is broken on
-    # this lineage — lax.axis_index inside an auto region lowers to a
-    # PartitionId instruction the SPMD partitioner rejects — so the body
-    # runs FULL manual over every mesh axis instead. Axes absent from
-    # in_specs/out_specs are thereby claimed replicated: inputs actually
-    # sharded over an unnamed axis get all-gathered at the region edge
-    # (correct, redundant) rather than passing through GSPMD-managed.
-    return _shard_map_raw(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_rep=check)
+    # jax.shard_map's own "all axes" is the empty set
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check,
+                         axis_names=frozenset(axis_names or ()))

@@ -397,11 +397,16 @@ class LlamaForCausalLM(nn.Layer):
         b, prompt_len = ids.shape
         total = prompt_len + max_new_tokens
         hd = cfg.hidden_size // cfg.num_heads
-        cache = [
-            {"k": jnp.zeros((b, total, cfg.num_kv_heads, hd), jnp.float32),
-             "v": jnp.zeros((b, total, cfg.num_kv_heads, hd), jnp.float32)}
-            for _ in range(cfg.num_layers)]
         params = list(self.parameters())
+        # the cache holds K/V in the model's compute dtype (bf16 serving
+        # weights project bf16 K/V; PagedEngine sizes its pages the same)
+        kv_dtype = next((p._data.dtype for p in params
+                         if jnp.issubdtype(p._data.dtype, jnp.floating)),
+                        jnp.float32)
+        cache = [
+            {"k": jnp.zeros((b, total, cfg.num_kv_heads, hd), kv_dtype),
+             "v": jnp.zeros((b, total, cfg.num_kv_heads, hd), kv_dtype)}
+            for _ in range(cfg.num_layers)]
 
         def with_params(fn):
             def wrapped(pa, *args):

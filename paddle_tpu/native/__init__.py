@@ -1,10 +1,11 @@
 """Native (C++) runtime bindings.
 
 Builds ``src/ptruntime.cc`` into a shared library on first import (g++,
-cached beside the source) and binds it with ctypes — the image has no
-pybind11, and the C ABI keeps the boundary trivial. Falls back cleanly
-(``AVAILABLE = False``) when no compiler is present so pure-Python paths
-keep working.
+cached beside the source; the ``.so`` is build output, git-ignored and
+rebuilt from the committed source when absent) and binds it with ctypes —
+the image has no pybind11, and the C ABI keeps the boundary trivial. When
+the build or load fails the pure-Python paths keep working
+(``AVAILABLE = False``), and the reason is printed once on stderr.
 """
 from __future__ import annotations
 
@@ -12,6 +13,7 @@ import ctypes
 import hashlib
 import os
 import subprocess
+import sys
 import threading
 
 import numpy as np
@@ -46,7 +48,13 @@ def _load():
             return _lib
         try:
             lib = ctypes.CDLL(_build())
-        except Exception:
+        except (OSError, subprocess.CalledProcessError) as e:
+            # _load runs once, at import: this is the one report
+            detail = getattr(e, "stderr", b"") or b""
+            print(f"paddle_tpu.native: no native runtime, using the "
+                  f"pure-Python paths ({type(e).__name__}: {e}) "
+                  f"{detail.decode(errors='replace')[-500:]}",
+                  file=sys.stderr)
             return None
         lib.pt_collate.argtypes = [
             ctypes.POINTER(ctypes.c_void_p), ctypes.c_int64,

@@ -70,31 +70,44 @@ PEAK_HBM_BW = {
 }
 
 
-def _chip_lookup(table, device_obj, tpu_default, cpu_default) -> float:
-    try:
-        import jax
+#: Nominal figures for the CPU platform ONLY. Dev hosts and the tier-1
+#: tests need finite planner / roofline arithmetic; these are not the
+#: peaks of any device and no number derived from them is a device metric.
+CPU_NOMINAL_FLOPS = 1e12
+CPU_NOMINAL_MEM_BW = 100e9
+CPU_NOMINAL_MEM_BYTES = 16e9
 
-        d = device_obj or jax.devices()[0]
-    except Exception:
-        return cpu_default
-    kind = getattr(d, "device_kind", "")
+
+def _chip_lookup(table, device_obj, cpu_nominal: float) -> float:
+    """``table`` entry for the device's ``device_kind``. A kind the
+    table does not know is an error, not a default: a roofline or MFU
+    computed against another chip's peak is a wrong number under the
+    right name."""
+    import jax
+
+    d = device_obj if device_obj is not None else jax.devices()[0]
+    if d.platform == "cpu":
+        return cpu_nominal
+    kind = d.device_kind
     for name, v in table.items():
         if kind.lower().startswith(name.lower()):
             return v
-    return (tpu_default if getattr(d, "platform", "") == "tpu"
-            else cpu_default)
+    raise KeyError(
+        f"no published peak for device_kind {kind!r} (platform "
+        f"{d.platform!r}); add it to the tables in "
+        f"paddle_tpu/observability/perf/__init__.py with its source")
 
 
 def chip_peak_flops(device_obj=None) -> float:
-    """Peak dense bf16 FLOPs/s of the chip (CPU fallback 1 TF/s so the
-    MFU math stays finite on dev hosts)."""
-    return _chip_lookup(PEAK_FLOPS, device_obj, 275e12, 1e12)
+    """Peak dense bf16 FLOPs/s of the chip (``CPU_NOMINAL_FLOPS`` on the
+    CPU platform; unknown accelerator kinds raise)."""
+    return _chip_lookup(PEAK_FLOPS, device_obj, CPU_NOMINAL_FLOPS)
 
 
 def chip_peak_bw(device_obj=None) -> float:
-    """Peak HBM bytes/s of the chip (CPU fallback ~100 GB/s DDR so the
-    roofline math stays finite on dev hosts)."""
-    return _chip_lookup(PEAK_HBM_BW, device_obj, 1228e9, 100e9)
+    """Peak HBM bytes/s of the chip (``CPU_NOMINAL_MEM_BW`` on the CPU
+    platform; unknown accelerator kinds raise)."""
+    return _chip_lookup(PEAK_HBM_BW, device_obj, CPU_NOMINAL_MEM_BW)
 
 
 #: HBM capacity (bytes) per chip — public spec sheets; the placement
@@ -115,6 +128,6 @@ HBM_CAPACITY = {
 
 
 def chip_hbm_bytes(device_obj=None) -> float:
-    """HBM capacity in bytes of one chip (CPU fallback 16 GB host RAM
-    budget so planner capacity checks stay meaningful on dev hosts)."""
-    return _chip_lookup(HBM_CAPACITY, device_obj, 32e9, 16e9)
+    """HBM capacity in bytes of one chip (``CPU_NOMINAL_MEM_BYTES`` on
+    the CPU platform; unknown accelerator kinds raise)."""
+    return _chip_lookup(HBM_CAPACITY, device_obj, CPU_NOMINAL_MEM_BYTES)

@@ -70,10 +70,9 @@ def _tuned_blocks(kind, bh, s_q, s_k, d, dtype, causal, scale):
     Falls back to the hand-tuned v5e constants when autotuning is off or
     the backend is not a real TPU (reference
     phi/kernels/autotune/switch_autotune.cc gate). Benchmarks run on
-    zeros at the BUCKETED sequence lengths (tile ranking is data- and
+    noise at the BUCKETED sequence lengths (tile ranking is data- and
     batch-mostly-independent; batch*heads is capped at 8 to keep the
-    probe cheap) — safe to call at trace time, since the probe inputs
-    are concrete.
+    probe cheap).
     """
     from . import autotune as at
 
@@ -90,9 +89,7 @@ def _tuned_blocks(kind, bh, s_q, s_k, d, dtype, causal, scale):
         return tuple(cached)
 
     bh_b = min(bh, 8)
-    # probe on noise, not zeros (constant-folding could skip real work),
-    # with several DISTINCT inputs cycled across timed iterations
-    # (replay-caching backends fake repeat-identical executions)
+    # probe on noise, not zeros (constant-folding could skip real work)
     nvar = 3
     qs, ks, vs = [], [], []
     for i in range(nvar):
@@ -102,10 +99,9 @@ def _tuned_blocks(kind, bh, s_q, s_k, d, dtype, causal, scale):
             jax.random.fold_in(kp, 1), (bh_b, sk_b, d)).astype(dtype))
         vs.append(jax.random.normal(
             jax.random.fold_in(kp, 2), (bh_b, sk_b, d)).astype(dtype))
-    # amortize per-call dispatch/transport under the kernel: chain K
-    # applications data-dependently inside ONE program (the kernel's
-    # q-shaped output feeds the next iteration), sized so device time
-    # dominates even a ~100 ms remote-dispatch floor
+    # amortize per-call dispatch under the kernel: chain K applications
+    # data-dependently inside ONE program (the kernel's q-shaped output
+    # feeds the next iteration), sized so device time dominates
     kernel_flops = 4.0 * bh_b * sq_b * sk_b * d * (0.5 if causal else 1.0)
     reps = at.probe_reps(kernel_flops)
     jitted = {}
@@ -447,13 +443,26 @@ def _flash_attention(q, k, v, causal, scale, block_q, block_k):
     return out
 
 
+def _resolve_blocks(kind, block_q, block_k, q, k, causal, scale):
+    """Caller-pinned tiles win; unset ones come from the autotuner. The
+    vjp rules run while the model's step is being TRACED, so the probe
+    is built and run in an eval context: it must execute on the chip,
+    not be staged into the caller's program (where its timings would be
+    trace time)."""
+    if block_q is not None and block_k is not None:
+        return block_q, block_k
+    b, s, h, d = q.shape
+    with jax.core.eval_context():
+        tq, tk = _tuned_blocks(kind, b * h, s, k.shape[1], d, q.dtype,
+                               causal, scale)
+    return (tq if block_q is None else block_q,
+            tk if block_k is None else block_k)
+
+
 def _flash_fwd_rule(q, k, v, causal, scale, block_q, block_k):
     b, s, h, d = q.shape
-    if block_q is None or block_k is None:
-        tq, tk = _tuned_blocks("fwd", b * h, s, k.shape[1], d, q.dtype,
-                               causal, scale)
-        block_q = tq if block_q is None else block_q
-        block_k = tk if block_k is None else block_k
+    block_q, block_k = _resolve_blocks("fwd", block_q, block_k, q, k,
+                                       causal, scale)
     out, lse = _flash_fwd_bhsd(
         _bshd_to_bhsd(q), _bshd_to_bhsd(k), _bshd_to_bhsd(v),
         causal=causal, scale=scale, block_q=block_q, block_k=block_k)
@@ -464,12 +473,8 @@ def _flash_fwd_rule(q, k, v, causal, scale, block_q, block_k):
 def _flash_bwd_rule(causal, scale, block_q, block_k, res, g):
     q, k, v, out, lse = res
     b, s, h, d = q.shape
-    s_k = k.shape[1]
-    if block_q is None or block_k is None:
-        tq, tk = _tuned_blocks("bwd", b * h, s, s_k, d, q.dtype, causal,
-                               scale)
-        block_q = tq if block_q is None else block_q
-        block_k = tk if block_k is None else block_k
+    block_q, block_k = _resolve_blocks("bwd", block_q, block_k, q, k,
+                                       causal, scale)
     dq, dk, dv = _flash_bwd_bhsd(
         _bshd_to_bhsd(q), _bshd_to_bhsd(k), _bshd_to_bhsd(v),
         _bshd_to_bhsd(out), lse, _bshd_to_bhsd(g),

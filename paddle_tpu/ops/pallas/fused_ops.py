@@ -26,10 +26,15 @@ Kernels:
 All kernels run under the Pallas interpreter (``INTERPRET = True``) so
 CPU tests execute the real kernel bodies. Shape gates (`pallas_ok_*`)
 keep the kernels on aligned shapes — anything else takes the composite.
+Kernel bodies stay inside what Mosaic lowers on a TPU: 2-D tiles, integer
+iota, no ``erf``/``erfc`` primitive, lane rotation by ``pltpu.roll``
+rather than sub-128-lane slices; ``chip_smoke.py`` compiles every kernel
+and tile candidate on the chip.
 """
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -50,8 +55,24 @@ DEFAULT_NORM_ROWS = 512
 DEFAULT_BLOCK_M = 256
 DEFAULT_BLOCK_N = 256
 
-#: VMEM budget the matmul tiles must fit (x panel + w panel + acc, f32)
+#: VMEM budget a kernel's resident blocks must fit — under Mosaic's
+#: 16 MiB scoped-VMEM default with room for its own temporaries
 _VMEM_BUDGET_BYTES = 12 * 1024 * 1024
+
+
+def _fit_rows(block_rows: int, rows: int, d: int, itemsize: int,
+              n_blocks: int, n_temps: int) -> int:
+    """Row tile for the row-wise kernels: ``block_rows`` clamped to the
+    array and to what VMEM holds. Each of the ``n_blocks`` (rows, d)
+    operand/result blocks is double-buffered by the pipeline, and the
+    body keeps ``n_temps`` block-sized f32 temporaries live (Mosaic
+    spills them to scoped VMEM). Measured on v5e: four bf16 (1024, 1024)
+    blocks alone are 16 MiB; the exact-GELU body held 24 MiB of
+    temporaries over a (256, 4096) tile."""
+    cap = _VMEM_BUDGET_BYTES // (d * (2 * n_blocks * itemsize + 4 * n_temps))
+    while block_rows > max(cap, 8):
+        block_rows //= 2        # halve: the tile keeps dividing the rows
+    return max(8, min(block_rows, max(rows, 8)))
 
 
 def _act_apply(y, act: str):
@@ -71,6 +92,25 @@ def _act_apply(y, act: str):
     if act in ("", "none", None):
         return y
     raise ValueError(f"unknown fused activation {act!r}")
+
+
+def _erf(x):
+    """erf for kernel bodies — Mosaic lowers neither ``erf`` nor ``erfc``.
+    Abramowitz & Stegun 7.1.26, |error| <= 1.5e-7 (below f32 rounding of
+    the activations it feeds)."""
+    a = jnp.abs(x)
+    t = 1.0 / (1.0 + 0.3275911 * a)
+    poly = t * (0.254829592 + t * (-0.284496736 + t * (
+        1.421413741 + t * (-1.453152027 + t * 1.061405429))))
+    return jnp.sign(x) * (1.0 - poly * jnp.exp(-a * a))
+
+
+def _act_kernel(y, act: str):
+    """:func:`_act_apply` for kernel bodies: same vocabulary, with exact
+    GELU spelled through :func:`_erf`."""
+    if act == "gelu":
+        return 0.5 * y * (1.0 + _erf(y * 0.7071067811865476))
+    return _act_apply(y, act)
 
 
 def _normalize_rows(x32, w32, b32, kind: str, eps: float):
@@ -117,8 +157,8 @@ def fused_residual_norm(x2d, res2d, weight, bias, *, kind="layer_norm",
                         eps=1e-5, block_rows=None):
     """One pass: ``s = x + res; y = norm(s) * w + b`` → ``(y, s)``."""
     r, d = x2d.shape
-    block_rows = int(block_rows or DEFAULT_NORM_ROWS)
-    block_rows = max(8, min(block_rows, max(r, 8)))
+    block_rows = _fit_rows(int(block_rows or DEFAULT_NORM_ROWS), r, d,
+                           x2d.dtype.itemsize, n_blocks=4, n_temps=1)
     xp = _pad_rows(x2d, block_rows)
     sp = _pad_rows(res2d, block_rows)
     rp = xp.shape[0]
@@ -151,14 +191,14 @@ def fused_residual_norm(x2d, res2d, weight, bias, *, kind="layer_norm",
 # --------------------------------------------------------------------------
 def _bias_act_kernel(x_ref, b_ref, y_ref, *, act):
     y = x_ref[...].astype(jnp.float32) + b_ref[...].astype(jnp.float32)
-    y_ref[...] = _act_apply(y, act).astype(y_ref.dtype)
+    y_ref[...] = _act_kernel(y, act).astype(y_ref.dtype)
 
 
 def fused_bias_act(x2d, bias, *, act="gelu", block_rows=None):
     """``act(x + b)`` over (R, D) with b (D,), one VPU pass."""
     r, d = x2d.shape
-    block_rows = int(block_rows or DEFAULT_NORM_ROWS)
-    block_rows = max(8, min(block_rows, max(r, 8)))
+    block_rows = _fit_rows(int(block_rows or DEFAULT_NORM_ROWS), r, d,
+                           x2d.dtype.itemsize, n_blocks=2, n_temps=8)
     xp = _pad_rows(x2d, block_rows)
     rp = xp.shape[0]
     return pl.pallas_call(
@@ -189,7 +229,7 @@ def _matmul_kernel(x_ref, w_ref, b_ref, nw_ref, nb_ref, o_ref, *,
         x32.astype(x_ref.dtype), w_ref[...], (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)       # (bm, bn)
     acc = acc + b_ref[...].astype(jnp.float32)
-    o_ref[...] = _act_apply(acc, act).astype(o_ref.dtype)
+    o_ref[...] = _act_kernel(acc, act).astype(o_ref.dtype)
 
 
 def pallas_ok_matmul(m: int, k: int, n: int, block_m: int,
@@ -249,22 +289,28 @@ def _matmul_rope_kernel(x_ref, w_ref, b_ref, o_ref, *, seq, head_dim,
         x, w_ref[...], (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)        # (bm, bn)
     acc = acc + b_ref[...].astype(jnp.float32)
-    # rows are the flattened (batch, seq) axis: position = row % seq
+    # Everything stays a 2-D (bm, bn) tile: a tile holds whole heads, so
+    # column c sits at channel c % head_dim of its head and pairs with
+    # the channel half a head away — fetched by rotating the lanes, not
+    # by slicing 32/64-lane halves (which Mosaic does not tile).
     half = head_dim // 2
+    # rows are the flattened (batch, seq) axis: position = row % seq
     rows = i * block_m + jax.lax.broadcasted_iota(
         jnp.int32, (block_m, 1), 0)
-    pos = (rows % seq).astype(jnp.float32) + float(pos_offset)
-    freqs = 1.0 / (theta ** (jax.lax.broadcasted_iota(
-        jnp.float32, (1, half), 1) / half))
-    angle = pos * freqs                            # (bm, half)
-    cos = jnp.cos(angle)[:, None, :]               # (bm, 1, half)
-    sin = jnp.sin(angle)[:, None, :]
-    heads_per_tile = block_n // head_dim
-    a = acc.reshape(block_m, heads_per_tile, head_dim)
-    x1, x2 = a[..., :half], a[..., half:]
-    roped = jnp.concatenate(
-        [x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
-    o_ref[...] = roped.reshape(block_m, block_n).astype(o_ref.dtype)
+    pos = (rows % seq + pos_offset).astype(jnp.float32)
+    chan = jax.lax.broadcasted_iota(jnp.int32, (1, block_n), 1) % head_dim
+    freqs = jnp.exp((chan % half).astype(jnp.float32)
+                    * (-math.log(theta) / half))   # theta ** -(j / half)
+    angle = pos * freqs                            # (bm, bn)
+    # x1 lanes (first half of a head) rotate with -x2, x2 lanes with +x1;
+    # the (1, bn) lane mask is applied as f32 arithmetic so it broadcasts
+    # over the rows like any other operand
+    first = (chan < half).astype(jnp.float32)
+    ahead = pltpu.roll(acc, block_n - half, 1)     # acc[c + half]
+    behind = pltpu.roll(acc, half, 1)              # acc[c - half]
+    partner = behind - first * (ahead + behind)    # -x2 | +x1
+    o_ref[...] = (acc * jnp.cos(angle)
+                  + partner * jnp.sin(angle)).astype(o_ref.dtype)
 
 
 def pallas_ok_matmul_rope(m: int, k: int, n: int, head_dim: int,

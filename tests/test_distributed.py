@@ -91,10 +91,9 @@ class TestCollectives:
         import jax
         import jax.numpy as jnp
         from jax.sharding import NamedSharding, PartitionSpec as P
-        from jax.experimental.shard_map import shard_map
         mesh = dist.get_mesh()
         x = jax.device_put(jnp.arange(8.0), NamedSharding(mesh, P("dp")))
-        f = jax.jit(shard_map(
+        f = jax.jit(jax.shard_map(
             lambda a: dist.shift_along_axis(a, "dp", 1, mesh),
             mesh=mesh, in_specs=(P("dp"),), out_specs=P("dp")))
         out = np.asarray(f(x))

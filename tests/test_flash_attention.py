@@ -224,3 +224,36 @@ def test_grad_under_jit():
         q, k, v, causal=True, block_q=32, block_k=32) ** 2)))
     g = f(q)
     assert np.all(np.isfinite(np.asarray(g)))
+
+
+def test_pallas_flash_is_partitioned_by_hand_under_a_mesh(monkeypatch):
+    """Mosaic kernels cannot be auto-partitioned (on a TPU the lowering
+    raises "wrap the call in a shard_map"): under a multi-device mesh the
+    functional's Pallas path is a full-manual shard_map over batch x
+    heads, leaving a dim that does not divide replicated. Here batch 8
+    shards over dp=4 and 3 heads stay replicated over mp=2; values and
+    gradients must match the XLA reference."""
+    import importlib
+
+    from jax.sharding import PartitionSpec as P
+
+    from paddle_tpu.distributed import mesh as mesh_mod
+    F = importlib.import_module("paddle_tpu.nn.functional.flash_attention")
+
+    monkeypatch.setattr(mesh_mod, "_global_mesh",
+                        mesh_mod.build_mesh({"dp": 4, "mp": 2}))
+    q, k, v = _rand_qkv(b=8, s=128, h=3)
+    g = _rand_qkv(b=8, s=128, h=3, seed=1)[0]
+
+    def vg(attn):
+        return jax.jit(jax.value_and_grad(
+            lambda q, k, v: jnp.sum(attn(q, k, v) * g), argnums=(0, 1, 2)))
+
+    (ref, ref_grads) = vg(lambda q, k, v: F._sdpa_xla(
+        q, k, v, causal=True))(q, k, v)
+    (got, got_grads) = vg(lambda q, k, v: F._flash_pallas(
+        q, k, v, True))(q, k, v)
+    np.testing.assert_allclose(got, ref, rtol=1e-5)
+    for a, b in zip(got_grads, ref_grads):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=2e-5)
+    assert got_grads[0].sharding.spec == P("dp")

@@ -215,29 +215,70 @@ class TestAutotune:
         at2_cache = at.AutotuneCache(str(tmp_path / "a.json"))
         assert tuple(at2_cache.get("k")) == (1024, 1024)
 
-    def test_autotune_skips_failing_candidates(self, tmp_path, monkeypatch):
+    def test_autotune_reports_failing_candidates(self, tmp_path,
+                                                 monkeypatch):
+        """A failed candidate is never dropped in silence: survivors are
+        ranked, the failure is recorded with its message; a failed
+        DEFAULT (or no survivor) raises instead of being cached."""
         import jax.numpy as jnp
         from paddle_tpu.ops.pallas import autotune as at
-        monkeypatch.setattr(at, "_cache",
-                            at.AutotuneCache(str(tmp_path / "b.json")))
+        cache = at.AutotuneCache(str(tmp_path / "b.json"))
+        monkeypatch.setattr(at, "_cache", cache)
 
         def run(c, i):
             if c == "bad":
                 raise RuntimeError("no compile")
             return jnp.zeros(())
 
-        assert at.autotune("k2", ["bad", "good"], run, default="d") == "good"
-        # all candidates fail -> default cached, failure not re-paid
-        ran = []
+        assert at.autotune("k2", ["bad", "good"], run, default="good") \
+            == "good"
+        assert "no compile" in cache.failures["k2"]["bad"]
+        # the caller's own default is broken on this chip: raise, and
+        # cache nothing (the next call must see the failure again)
+        with pytest.raises(RuntimeError, match="default 'bad' failed"):
+            at.autotune("k3", ["bad", "good"], run, default="bad")
+        assert cache.get("k3") is None
+        # every candidate AND the default fail
+        with pytest.raises(RuntimeError, match="no candidate survived"):
+            at.autotune("k4", ["bad"], run, default="bad")
+        assert cache.get("k4") is None
 
-        def run_all_bad(c, i):
-            ran.append(c)
-            raise RuntimeError("never compiles")
+    def test_autotune_probe_executes_under_an_outer_trace(self, tmp_path,
+                                                          monkeypatch):
+        """The flash vjp rules and fused lowerings reach autotune() while
+        the model's step is being TRACED. The probe must still execute
+        on the device (concrete arrays, real timings), and the Pallas
+        kernel inside it must still trace (program_id has no eval rule,
+        so blanket compile-time eval breaks it)."""
+        import jax
+        import jax.numpy as jnp
+        from jax.experimental import pallas as pl
+        from paddle_tpu.ops.pallas import autotune as at
+        monkeypatch.setattr(at, "_cache",
+                            at.AutotuneCache(str(tmp_path / "c.json")))
 
-        assert at.autotune("k3", ["bad"], run_all_bad, default="d") == "d"
-        n = len(ran)
-        assert at.autotune("k3", ["bad"], run_all_bad, default="x") == "d"
-        assert len(ran) == n
+        def kernel(x_ref, o_ref):
+            o_ref[...] = x_ref[...] + pl.program_id(0).astype(jnp.float32)
+
+        probe = jax.jit(lambda x: pl.pallas_call(
+            kernel, out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
+            grid=(2,), in_specs=[pl.BlockSpec((8, 128), lambda i: (i, 0))],
+            out_specs=pl.BlockSpec((8, 128), lambda i: (i, 0)),
+            interpret=True)(x))
+        seen = []
+
+        def run(c, i):
+            out = probe(jnp.ones((16, 128)))
+            seen.append(isinstance(out, jax.core.Tracer))
+            return out
+
+        def step(x):
+            assert at.autotune("k5", ["a"], run, default="a",
+                               warmup=1, iters=1) == "a"
+            return x + 1
+
+        jax.jit(step)(jnp.zeros(3))
+        assert seen and not any(seen)
 
     def test_flash_defaults_untouched_off_tpu(self):
         """On CPU (tests), should_autotune is False and the flash path

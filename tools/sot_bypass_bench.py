@@ -25,8 +25,10 @@ def main():
     import jax
 
     import paddle_tpu as paddle
+    from paddle_tpu.compile.cache import enable_jax_cache
     from paddle_tpu.models import GPTConfig, GPTForCausalLM
 
+    enable_jax_cache()      # before the first compile
     paddle.seed(0)
     cfg = GPTConfig(vocab_size=50304, hidden_size=768, num_layers=12,
                     num_heads=12, max_seq_len=1024,

@@ -38,7 +38,8 @@ METADATA_CALLS = {"issubdtype", "isdtype", "result_type", "can_cast",
                   "promote_types", "iinfo", "finfo", "dtype",
                   "default_backend", "device_count", "local_device_count",
                   "devices", "local_devices", "process_index",
-                  "process_count", "jit", "eval_shape",
+                  "process_count", "is_initialized", "get_abstract_mesh",
+                  "jit", "eval_shape",
                   "ShapeDtypeStruct", "tree_structure"}
 
 FIXITS = {
