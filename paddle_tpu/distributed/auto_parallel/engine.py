@@ -13,6 +13,7 @@ device_put/with_sharding_constraint.
 """
 from __future__ import annotations
 
+import contextlib
 from typing import Callable, List, Optional
 
 import jax
@@ -21,6 +22,7 @@ import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ...core.tensor import Tensor
+from ...observability import trace as _trace
 from .. import mesh as mesh_mod
 
 
@@ -125,7 +127,7 @@ class Engine:
 
         self._init_opt_state = init_opt_state
 
-        def step(param_arrays, opt_state, lr, x, y):
+        def engine_train_step(param_arrays, opt_state, lr, x, y):
             def f(pa):
                 originals = [p._data for p in params]
                 for p, a in zip(params, pa):
@@ -151,9 +153,10 @@ class Engine:
                     g if rt is None else
                     g + rt[1] * (jnp.sign(w) if rt[0] else w).astype(g.dtype)
                     for g, rt, w in zip(grads, reg_terms, param_arrays)]
-            new_p, new_m, new_st = opt._tree_step(
-                lr, t, param_arrays, grads, masters, states, lr_mults,
-                wd_flags)
+            with jax.named_scope("optimizer"):
+                new_p, new_m, new_st = opt._tree_step(
+                    lr, t, param_arrays, grads, masters, states, lr_mults,
+                    wd_flags)
             return loss, new_p, (t, new_m, new_st)
 
         # donation (opt-in via Engine(donate=True) / FLAGS_donate_buffers):
@@ -167,10 +170,13 @@ class Engine:
         self._donate = (bool(_flags.get_flag("donate_buffers"))
                         if self._donate_arg is None
                         else bool(self._donate_arg))
+        # the functions' names are the programs' names on the trace's
+        # ``XLA Modules`` line (jit_engine_train_step, jit_engine_eval_step)
         self._train_step = jax.jit(
-            step, donate_argnums=(0, 1) if self._donate else ())
+            engine_train_step,
+            donate_argnums=(0, 1) if self._donate else ())
 
-        def eval_step(param_arrays, x, y):
+        def engine_eval_step(param_arrays, x, y):
             originals = [p._data for p in params]
             for p, a in zip(params, param_arrays):
                 p._data = a
@@ -181,7 +187,7 @@ class Engine:
                 for p, o in zip(params, originals):
                     p._data = o
 
-        self._eval_step = jax.jit(eval_step)
+        self._eval_step = jax.jit(engine_eval_step)
         return self
 
     def _traced_loss(self, model, loss_fn, params, x, y):
@@ -297,6 +303,15 @@ class Engine:
     # ------------------------------------------------------------ running
     def fit(self, train_data, epochs=1, batch_size=32, steps_per_epoch=None,
             log_freq=10, verbose=0):
+        with contextlib.ExitStack() as setup_span:
+            setup_span.enter_context(_trace.boundary("fit.setup"))
+            return self._fit(setup_span, train_data, epochs, batch_size,
+                             steps_per_epoch, log_freq, verbose)
+
+    def _fit(self, setup_span, train_data, epochs, batch_size,
+             steps_per_epoch, log_freq, verbose):
+        """``fit`` under its ``fit.setup`` span, which ``_fit`` closes at the
+        first wait for a batch (``ExitStack.close`` is idempotent)."""
         if self._placement == "auto" and self.placement_plan is None:
             # plan on the first batch's shapes BEFORE the step compiles
             peek = next(iter(self.dataloader(train_data, batch_size)),
@@ -354,6 +369,7 @@ class Engine:
                 ((p.name, a) for p, a in zip(self._params, pa)),
                 "Engine.fit(donate=True)")
         census_left = 2     # attributed HBM census on the first steps
+        n_steps = 0         # fit.step's step_num, over the whole call
         try:
             for epoch in range(epochs):
                 # loss stays a device scalar: no per-step host sync —
@@ -364,73 +380,91 @@ class Engine:
                 it = iter(loader)
                 batches = (DevicePrefetcher(it, place_fn=place)
                            if use_prefetch else (place(b) for b in it))
+                setup_span.close()
                 try:
-                    for step_i, (x, y) in enumerate(batches):
-                        if steps_per_epoch and step_i >= steps_per_epoch:
-                            break
-                        # fleet beacon: per-step wall time + windowed
-                        # cross-rank skew gather — the straggler
-                        # detector's feed. Resolved per step (like the
-                        # fleet trainers) so reset_beacon() takes effect
-                        # mid-fit.
-                        led.step_begin()
-                        bcn = _fleet.beacon()
-                        bcn.step_begin()
-                        # lr is a traced INPUT: schedulers tick without
-                        # retracing (constant LR: placed once, pre-loop)
-                        lr = (lr_const if lr_const is not None
-                              else jnp.asarray(self._opt.get_lr(),
-                                               jnp.float32))
-                        prev = (pa, opt_state) if self._donate else None
-                        n_sigs = cache_size() if cache_size else None
-                        loss, pa, opt_state = self._train_step(
-                            pa, opt_state, lr, x, y)
-                        if n_sigs is not None and cache_size() > n_sigs:
-                            # jit-cache miss: the (synchronous) trace +
-                            # XLA compile wall heads this step's window
-                            led.bill_since_step_begin("compile")
-                            snt.note_compile(
-                                "initial" if n_sigs == 0 else "retrace")
-                        if prev is not None:
-                            _donation.mark_donated(
-                                jax.tree_util.tree_leaves(prev),
-                                "the Engine's donated train step")
-                        if sched is not None:
-                            sched.step()
-                        loss_sum = loss if loss_sum is None \
-                            else loss_sum + loss
-                        loss_n += 1
-                        if census_left:
-                            # mid-flight census: with donation the just-
-                            # donated buffers count 0, so the recorded
-                            # high-water shows the drop
-                            _perf_mem.update_high_water(
-                                "engine_step_donated" if self._donate
-                                else "engine_step")
-                            census_left -= 1
-                        bcn.step_end()
-                        snt.observe_step(led.step_end())
-                        if verbose and step_i % log_freq == 0:
-                            print(f"[engine] epoch {epoch} step {step_i} "
-                                  f"loss {float(loss):.4f}")  # tpulint: disable=TPU103 — the log-interval materialization IS the documented host boundary (async-loss contract)
+                    step_i = 0
+                    while True:
+                        # an epoch's last fit.step holds only the fetch
+                        # that finds the loader exhausted
+                        with _trace.boundary("fit.step", step_num=n_steps):
+                            with _trace.boundary("fit.next_batch"):
+                                batch = next(batches, None)
+                            if batch is None or (steps_per_epoch and
+                                                 step_i >= steps_per_epoch):
+                                break
+                            x, y = batch
+                            # fleet beacon: per-step wall time + windowed
+                            # cross-rank skew gather — the straggler
+                            # detector's feed. Resolved per step (like the
+                            # fleet trainers) so reset_beacon() takes
+                            # effect mid-fit.
+                            led.step_begin()
+                            bcn = _fleet.beacon()
+                            bcn.step_begin()
+                            # lr is a traced INPUT: schedulers tick without
+                            # retracing (constant LR: placed once, pre-loop)
+                            lr = (lr_const if lr_const is not None
+                                  else jnp.asarray(self._opt.get_lr(),
+                                                   jnp.float32))
+                            prev = (pa, opt_state) if self._donate else None
+                            n_sigs = cache_size() if cache_size else None
+                            with _trace.boundary("fit.dispatch"):
+                                loss, pa, opt_state = self._train_step(
+                                    pa, opt_state, lr, x, y)
+                            with _trace.boundary("fit.post_step"):
+                                if n_sigs is not None \
+                                        and cache_size() > n_sigs:
+                                    # jit-cache miss: the (synchronous)
+                                    # trace + XLA compile wall heads this
+                                    # step's window
+                                    led.bill_since_step_begin("compile")
+                                    snt.note_compile(
+                                        "initial" if n_sigs == 0
+                                        else "retrace")
+                                if prev is not None:
+                                    _donation.mark_donated(
+                                        jax.tree_util.tree_leaves(prev),
+                                        "the Engine's donated train step")
+                                if sched is not None:
+                                    sched.step()
+                                loss_sum = loss if loss_sum is None \
+                                    else loss_sum + loss
+                                loss_n += 1
+                                if census_left:
+                                    # mid-flight census: with donation the
+                                    # just-donated buffers count 0, so the
+                                    # recorded high-water shows the drop
+                                    _perf_mem.update_high_water(
+                                        "engine_step_donated"
+                                        if self._donate else "engine_step")
+                                    census_left -= 1
+                                bcn.step_end()
+                                snt.observe_step(led.step_end())
+                                if verbose and step_i % log_freq == 0:
+                                    print(f"[engine] epoch {epoch} step "
+                                          f"{step_i} loss {float(loss):.4f}")  # tpulint: disable=TPU103 — the log-interval materialization IS the documented host boundary (async-loss contract)
+                        step_i += 1
+                        n_steps += 1
                 finally:
                     if isinstance(batches, DevicePrefetcher):
                         batches.close()
                 if loss_n:
                     # ONE host sync per epoch for the history mean
-                    self.history.append(
-                        float(loss_sum) / loss_n)  # tpulint: disable=TPU103 — end-of-epoch history materialization (documented contract), not a per-step sync
+                    with _trace.boundary("fit.epoch_sync"):
+                        self.history.append(
+                            float(loss_sum) / loss_n)  # tpulint: disable=TPU103 — end-of-epoch history materialization (documented contract), not a per-step sync
         finally:
             # write the trained arrays AND accumulator states back into
             # the eager optimizer, so a later opt.step()/state_dict()
             # continues from where the Engine left off. Runs on abort
             # too: under donation the Parameters' pre-fit payloads are
             # dead — the latest live arrays must land back.
-            t, _masters, states = opt_state
-            self._opt._step_count = int(t)  # tpulint: disable=TPU103 — one end-of-fit writeback into the eager optimizer (documented contract), not a per-step sync
-            for p, a, st in zip(self._params, pa, states):
-                p._data = a
-                self._opt._accumulators[id(p)] = st
+            with _trace.boundary("fit.writeback"):
+                t, _masters, states = opt_state
+                self._opt._step_count = int(t)  # tpulint: disable=TPU103 — one end-of-fit writeback into the eager optimizer (documented contract), not a per-step sync
+                for p, a, st in zip(self._params, pa, states):
+                    p._data = a
+                    self._opt._accumulators[id(p)] = st
         return self.history
 
     def evaluate(self, eval_data, batch_size=32, verbose=0):

@@ -54,6 +54,7 @@ terminal status). The multi-replica front door over R engines is
 """
 from __future__ import annotations
 
+import contextlib
 import time
 import weakref
 from dataclasses import dataclass, field
@@ -65,6 +66,7 @@ import numpy as np
 
 from ..core.tensor import Tensor
 from ..observability import reqtrace as _reqtrace
+from ..observability import trace as _trace
 from .resilience import (Overloaded, ReplicaLifecycle, ReplicaState,
                          RequestOutcome, RequestStatus, ResilienceConfig,
                          TERMINAL_STATUSES)
@@ -140,24 +142,28 @@ class _LlamaArch:
         nh = cfg.num_heads
         hd = cfg.hidden_size // nh
         nkv = self.num_kv_heads
-        x = model.model.embed_tokens(Tensor(tokens))
+        with jax.named_scope("embed"):
+            x = model.model.embed_tokens(Tensor(tokens))
         for li, blk in enumerate(model.model.layers):
-            ln = blk.input_layernorm(x)
-            q = ops.reshape(blk.self_attn.q_proj(ln), [B, T, nh, hd])
-            k = ops.reshape(blk.self_attn.k_proj(ln), [B, T, nkv, hd])
-            v = ops.reshape(blk.self_attn.v_proj(ln), [B, T, nkv, hd])
-            q = rotary_embedding(q, cfg.rope_theta, pos_offset=start)
-            k = rotary_embedding(k, cfg.rope_theta, pos_offset=start)
-            out = attend(li, q, k, v)
-            x = x + blk.self_attn.o_proj(
-                ops.reshape(out, [B, T, nh * hd]))
-            x = x + blk.mlp(blk.post_attention_layernorm(x))
+            with jax.named_scope("attn"):
+                ln = blk.input_layernorm(x)
+                q = ops.reshape(blk.self_attn.q_proj(ln), [B, T, nh, hd])
+                k = ops.reshape(blk.self_attn.k_proj(ln), [B, T, nkv, hd])
+                v = ops.reshape(blk.self_attn.v_proj(ln), [B, T, nkv, hd])
+                q = rotary_embedding(q, cfg.rope_theta, pos_offset=start)
+                k = rotary_embedding(k, cfg.rope_theta, pos_offset=start)
+                out = attend(li, q, k, v)
+                x = x + blk.self_attn.o_proj(
+                    ops.reshape(out, [B, T, nh * hd]))
+            with jax.named_scope("mlp"):
+                x = x + blk.mlp(blk.post_attention_layernorm(x))
         x = model.model.norm(x)
         last = Tensor(x._data[:, -logits_t:, :])
-        if model.lm_head is None:
-            return ops.matmul(last, model.model.embed_tokens.weight,
-                              transpose_y=True)
-        return model.lm_head(last)
+        with jax.named_scope("lm_head"):
+            if model.lm_head is None:
+                return ops.matmul(last, model.model.embed_tokens.weight,
+                                  transpose_y=True)
+            return model.lm_head(last)
 
 
 class _GPTArch:
@@ -181,21 +187,26 @@ class _GPTArch:
         # learned positional embeddings at per-slot positions
         pos_idx = (start[:, None]
                    + jnp.arange(T, dtype=start.dtype)[None, :])
-        pos_emb = jnp.take(m.wpe.weight._data, pos_idx, axis=0)
-        x = m.wte(Tensor(tokens)) + Tensor(pos_emb)
+        with jax.named_scope("embed"):
+            pos_emb = jnp.take(m.wpe.weight._data, pos_idx, axis=0)
+            x = m.wte(Tensor(tokens)) + Tensor(pos_emb)
         for li, blk in enumerate(m.blocks):
-            ln = blk.ln1(x)
-            qkv = blk.attn.qkv_proj(ln)
-            q, k, v = ops.split(qkv, 3, axis=-1)
-            q = ops.reshape(q, [B, T, nh, hd])
-            k = ops.reshape(k, [B, T, nh, hd])
-            v = ops.reshape(v, [B, T, nh, hd])
-            out = attend(li, q, k, v)
-            x = x + blk.attn.out_proj(ops.reshape(out, [B, T, nh * hd]))
-            x = x + blk.mlp(blk.ln2(x))
+            with jax.named_scope("attn"):
+                ln = blk.ln1(x)
+                qkv = blk.attn.qkv_proj(ln)
+                q, k, v = ops.split(qkv, 3, axis=-1)
+                q = ops.reshape(q, [B, T, nh, hd])
+                k = ops.reshape(k, [B, T, nh, hd])
+                v = ops.reshape(v, [B, T, nh, hd])
+                out = attend(li, q, k, v)
+                x = x + blk.attn.out_proj(
+                    ops.reshape(out, [B, T, nh * hd]))
+            with jax.named_scope("mlp"):
+                x = x + blk.mlp(blk.ln2(x))
         x = m.ln_f(x)
         last = Tensor(x._data[:, -logits_t:, :])
-        return ops.matmul(last, m.wte.weight, transpose_y=True)
+        with jax.named_scope("lm_head"):
+            return ops.matmul(last, m.wte.weight, transpose_y=True)
 
 
 class _DenseArch:
@@ -557,29 +568,34 @@ class PagedEngine:
         import functools
         cache = _PAGED_JIT_CACHE.setdefault(model, {})
         arch_key = type(self.arch).__name__
-        if self._dense:
-            dfn = cache.get((arch_key, "dense"))
-            if dfn is None:
-                dfn = cache[(arch_key, "dense")] = jax.jit(
-                    functools.partial(_dense_forward, self.arch,
-                                      tuple(self._params)))
-            self._dense_fn = dfn
-            self._fn = self._vfn = None
-        else:
-            fn = cache.get((arch_key, "chunk"))
+
+        def program(kind, forward, **jit_kw):
+            """The shared jit wrapper of one program kind. ``kind`` is the
+            program's name on a trace's ``XLA Modules`` line
+            (``jit_<kind>``): the chunk forward is wrapped twice, as
+            ``paged_prefill_chunk`` and ``paged_decode_step``, so the two
+            phases are told apart there (their shapes differ already, so
+            nothing compiles twice)."""
+            fn = cache.get((arch_key, kind))
             if fn is None:
-                fn = cache[(arch_key, "chunk")] = jax.jit(
-                    functools.partial(_paged_forward, self.arch,
-                                      tuple(self._params)),
-                    donate_argnums=(1, 2), static_argnames=("sampling",))
-            self._fn = fn
-            vfn = cache.get((arch_key, "verify"))
-            if vfn is None:
-                vfn = cache[(arch_key, "verify")] = jax.jit(
-                    functools.partial(_paged_verify, self.arch,
-                                      tuple(self._params)),
-                    donate_argnums=(1, 2), static_argnames=("sampling",))
-            self._vfn = vfn
+                bound = functools.partial(forward, self.arch,
+                                          tuple(self._params))
+                bound.__name__ = kind
+                fn = cache[(arch_key, kind)] = jax.jit(bound, **jit_kw)
+            return fn
+
+        if self._dense:
+            self._dense_fn = program("dense_forward", _dense_forward)
+            self._fns = self._vfn = None
+        else:
+            paged = dict(donate_argnums=(1, 2),
+                         static_argnames=("sampling",))
+            self._fns = {
+                "prefill": program("paged_prefill_chunk", _paged_forward,
+                                   **paged),
+                "decode": program("paged_decode_step", _paged_forward,
+                                  **paged)}
+            self._vfn = program("paged_verify", _paged_verify, **paged)
         self._base_key = jax.random.key(seed)
         self._done: List[Request] = []
         self._rid = 0
@@ -739,38 +755,53 @@ class PagedEngine:
                 jnp.asarray(rids_np, jnp.int32),
                 jnp.asarray(ngens_np, jnp.int32), self._base_key)
 
+    def _call_program(self, phase, fn, host_args, extra=(), **span_args):
+        """One program call under its boundary spans: ``serving.<phase>``
+        over ``.build`` (eval mode on, the host arrays to the device),
+        ``.launch`` (the compiled call; the caches are rebound to its
+        outputs) and ``.wait`` (the blocking read of what it hands the host,
+        the training flag restored). Returns the program's host-bound
+        outputs as numpy arrays."""
+        tokens_np, seq_lens_np, _tables_np, temps_np, *_ = host_args
+        with contextlib.ExitStack() as restore, _trace.boundary(
+                f"serving.{phase}",
+                args=dict(span_args, phase=phase,
+                          batch=int(len(seq_lens_np)))):
+            with _trace.boundary(f"serving.{phase}.build"):
+                # serving always runs eval-mode (dropout off); restore the
+                # caller's training flag afterwards — the engine must not
+                # mutate a model a training loop is still using. Either
+                # switch walks every sublayer, so both sit inside a leaf
+                # span: the walk is host work the chip waits for
+                if getattr(self.model, "training", False):
+                    self.model.eval()
+                    restore.callback(self.model.train)
+                t0 = time.perf_counter()
+                args = self._chunk_args(*host_args) + tuple(
+                    jnp.asarray(a, jnp.int32) for a in extra)
+            with _trace.boundary(f"serving.{phase}.launch"):
+                *outs, self.kc, self.vc = fn(
+                    *args,
+                    sampling=bool(np.any(np.asarray(temps_np) > 0)))
+            with _trace.boundary(f"serving.{phase}.wait"):
+                # np.asarray blocks until the program finishes, so the
+                # serving.<phase> bracket bounds the chunk's device
+                # execution from above — the per-tick prefill-vs-decode
+                # attribution loadgen/bench report
+                outs = [np.asarray(o) for o in outs]  # tpulint: disable=TPU104 — host boundary by design: sampled token ids feed python-side scheduling
+                restore.close()
+                seconds = time.perf_counter() - t0
+        self.scheduler.note_phase(
+            phase, int(len(seq_lens_np)) * int(tokens_np.shape[1]), seconds)
+        return outs
+
     def _run_chunk(self, tokens_np, seq_lens_np, tables_np,
                    temps_np, top_ps_np, rids_np, ngens_np,
                    phase: str = "decode"):
-        from ..observability import trace as _otrace
-
-        # serving always runs eval-mode (dropout off); restore the
-        # caller's training flag afterwards — the engine must not mutate
-        # a model a training loop is still using
-        was_training = getattr(self.model, "training", False)
-        if was_training:
-            self.model.eval()
-        t0 = time.perf_counter()
-        try:
-            nxt, self.kc, self.vc = self._fn(
-                *self._chunk_args(tokens_np, seq_lens_np, tables_np,
-                                  temps_np, top_ps_np, rids_np, ngens_np),
-                sampling=bool(np.any(np.asarray(temps_np) > 0)))
-            # np.asarray blocks until the program finishes, so this span
-            # covers the chunk's actual device execution — the per-tick
-            # prefill-vs-decode attribution loadgen/bench report
-            out = np.asarray(nxt)  # tpulint: disable=TPU104 — host boundary by design: sampled token ids feed python-side scheduling
-        finally:
-            if was_training:
-                self.model.train()
-        t1 = time.perf_counter()
-        self.scheduler.note_phase(
-            phase, int(len(seq_lens_np)) * int(tokens_np.shape[1]),
-            t1 - t0)
-        if _otrace._active["on"]:
-            _otrace.add_complete(f"serving.{phase}", "device", t0, t1,
-                                 {"phase": phase,
-                                  "batch": int(len(seq_lens_np))})
+        (out,) = self._call_program(
+            phase, self._fns[phase],
+            (tokens_np, seq_lens_np, tables_np, temps_np, top_ps_np,
+             rids_np, ngens_np))
         return out
 
     def _run_verify(self, tokens_np, seq_lens_np, tables_np, temps_np,
@@ -778,31 +809,10 @@ class PagedEngine:
         """Speculative verify program: decode-phase compute (the spans
         and token counters attribute it to decode — it IS the decode
         step, just yielding up to k+1 tokens)."""
-        from ..observability import trace as _otrace
-
-        was_training = getattr(self.model, "training", False)
-        if was_training:
-            self.model.eval()
-        t0 = time.perf_counter()
-        try:
-            emit, n_emit, self.kc, self.vc = self._vfn(
-                *self._chunk_args(tokens_np, seq_lens_np, tables_np,
-                                  temps_np, top_ps_np, rids_np, ngens_np),
-                jnp.asarray(max_accept_np, jnp.int32),
-                sampling=bool(np.any(np.asarray(temps_np) > 0)))
-            out = np.asarray(emit)  # tpulint: disable=TPU104 — host boundary by design: verified token ids feed python-side scheduling
-            n_out = np.asarray(n_emit)  # tpulint: disable=TPU104 — same verify-result host boundary
-        finally:
-            if was_training:
-                self.model.train()
-        t1 = time.perf_counter()
-        self.scheduler.note_phase(
-            "decode", int(len(seq_lens_np)) * int(tokens_np.shape[1]),
-            t1 - t0)
-        if _otrace._active["on"]:
-            _otrace.add_complete("serving.decode", "device", t0, t1,
-                                 {"phase": "decode", "speculative": True,
-                                  "batch": int(len(seq_lens_np))})
+        out, n_out = self._call_program(
+            "decode", self._vfn,
+            (tokens_np, seq_lens_np, tables_np, temps_np, top_ps_np,
+             rids_np, ngens_np), extra=(max_accept_np,), speculative=True)
         return out, n_out
 
     # -------------------------------------------------------- scheduling
@@ -886,68 +896,83 @@ class PagedEngine:
         bs = self.block_size
         quota = self.scheduler.chunk_quota(bs)
         while self._prefilling:
-            slots = sorted(self._prefilling)
-            if quota is not None:
-                slots = slots[:quota]
-                if not slots:
-                    self.scheduler.note_deferred(sum(
-                        st["n_chunks"] - st["next"]
-                        for st in self._prefilling.values()))
-                    # the WHY of a slow TTFT: this tick's budget pushed
-                    # these requests' remaining chunks to a later tick
-                    for slot, st in self._prefilling.items():
-                        req = self.slots[slot]
-                        if req is not None:
-                            self._rt_event(
-                                req.rid, "prefill_deferred",
-                                tick=self._ticks,
-                                chunks_left=st["n_chunks"] - st["next"])
-                    return
-            tokens = np.zeros((self.max_batch, bs), np.int32)
-            seq = np.zeros((self.max_batch,), np.int32)   # 0 = inactive
-            temps = np.zeros((self.max_batch,), np.float32)
-            top_ps = np.ones((self.max_batch,), np.float32)
-            rids = np.zeros((self.max_batch,), np.int32)
-            ngens = np.zeros((self.max_batch,), np.int32)
-            finalists = []
-            for slot in slots:
-                st = self._prefilling[slot]
-                req = self.slots[slot]
-                j = st["next"]
-                tokens[slot] = st["prefix"][j * bs:(j + 1) * bs]
-                seq[slot] = (j + 1) * bs - st["pad"]
-                temps[slot] = req.temperature
-                top_ps[slot] = req.top_p
-                rids[slot] = req.rid
-                ngens[slot] = len(req.generated)
-                st["next"] = j + 1
-                if st["next"] == st["n_chunks"]:
-                    finalists.append(slot)
+            with _trace.boundary("serving.plan"):
+                plan = self._plan_prefill_chunk(quota)
+            if plan is None:
+                return
+            slots, finalists, tokens, seq, temps, top_ps, rids, ngens = plan
             nxt = self._run_chunk(tokens, seq, self.tables, temps, top_ps,
                                   rids, ngens, phase="prefill")
+            self._tick_work["prompt_tokens"] += len(slots) * bs
             if quota is not None:
                 quota -= len(slots)
-            now = self._clock()
-            for slot in slots:
-                # finalists' state entries are still live here — the
-                # chunk just computed is the one BEFORE the cursor
-                st = self._prefilling[slot]
-                req = self.slots[slot]
-                self._rt_event(req.rid, "prefill_chunk", t=now,
-                               chunk=st["next"] - 1,
-                               n_chunks=st["n_chunks"], tokens=bs,
-                               tick=self._ticks)
-            for slot in finalists:
-                del self._prefilling[slot]
-                req = self.slots[slot]
-                # cached positions == the prefilled prefix; the sampled
-                # token lands in the cache on its decode step
-                self.seq_lens[slot] = len(req.prompt) + len(req.generated)
-                tok = int(nxt[slot])
-                req.generated.append(tok)
-                self.last_token[slot] = tok
-                self._record_token(req, now)
-                self._maybe_finish(slot)
+            with _trace.boundary("serving.emit"):
+                now = self._clock()
+                for slot in slots:
+                    # finalists' state entries are still live here — the
+                    # chunk just computed is the one BEFORE the cursor
+                    st = self._prefilling[slot]
+                    req = self.slots[slot]
+                    self._rt_event(req.rid, "prefill_chunk", t=now,
+                                   chunk=st["next"] - 1,
+                                   n_chunks=st["n_chunks"], tokens=bs,
+                                   tick=self._ticks)
+                for slot in finalists:
+                    del self._prefilling[slot]
+                    req = self.slots[slot]
+                    # cached positions == the prefilled prefix; the
+                    # sampled token lands in the cache on its decode step
+                    self.seq_lens[slot] = (len(req.prompt)
+                                           + len(req.generated))
+                    tok = int(nxt[slot])
+                    req.generated.append(tok)
+                    self.last_token[slot] = tok
+                    self._record_token(req, now)
+                    self._maybe_finish(slot)
+
+    def _plan_prefill_chunk(self, quota):
+        """The next chunk call's host rows ``(slots, finalists, tokens, seq,
+        temps, top_ps, rids, ngens)`` for up to ``quota`` prefilling slots;
+        None (and the deferral noted) when the tick's budget is spent."""
+        bs = self.block_size
+        slots = sorted(self._prefilling)
+        if quota is not None:
+            slots = slots[:quota]
+            if not slots:
+                self.scheduler.note_deferred(sum(
+                    st["n_chunks"] - st["next"]
+                    for st in self._prefilling.values()))
+                # the WHY of a slow TTFT: this tick's budget pushed
+                # these requests' remaining chunks to a later tick
+                for slot, st in self._prefilling.items():
+                    req = self.slots[slot]
+                    if req is not None:
+                        self._rt_event(
+                            req.rid, "prefill_deferred",
+                            tick=self._ticks,
+                            chunks_left=st["n_chunks"] - st["next"])
+                return None
+        tokens = np.zeros((self.max_batch, bs), np.int32)
+        seq = np.zeros((self.max_batch,), np.int32)   # 0 = inactive
+        temps = np.zeros((self.max_batch,), np.float32)
+        top_ps = np.ones((self.max_batch,), np.float32)
+        rids = np.zeros((self.max_batch,), np.int32)
+        ngens = np.zeros((self.max_batch,), np.int32)
+        finalists = []
+        for slot in slots:
+            st = self._prefilling[slot]
+            req = self.slots[slot]
+            j = st["next"]
+            tokens[slot] = st["prefix"][j * bs:(j + 1) * bs]
+            seq[slot] = (j + 1) * bs - st["pad"]
+            temps[slot] = req.temperature
+            top_ps[slot] = req.top_p
+            rids[slot] = req.rid
+            ngens[slot] = len(req.generated)
+            st["next"] = j + 1
+            if st["next"] == st["n_chunks"]:
+                finalists.append(slot)
+        return slots, finalists, tokens, seq, temps, top_ps, rids, ngens
 
     def _evict(self, slot: int,
                reason: str = "kv-block pressure (livelock preemption)"):
@@ -1120,16 +1145,16 @@ class PagedEngine:
         faults: an internal tick failure marks the in-flight requests
         FAILED, reclaims their KV blocks, and flips the replica
         DEGRADED — the engine keeps serving."""
-        from ..observability import trace
-
         wd = self._watchdog
         if wd is not None:
             wd.begin_work()
         self._ticks += 1
         t0 = time.perf_counter()
-        span_args = {"tick": self._ticks}
+        span_args = {"tick": self._ticks, "active": self.num_active,
+                     "queued": len(self.queue)}
+        self._tick_work = {"prompt_tokens": 0, "decode_slots": 0}
         try:
-            with trace.span("serving.tick", "serving", args=span_args):
+            with _trace.boundary("serving.tick", args=span_args):
                 try:
                     self._tick()
                     if self.lifecycle.state == ReplicaState.STARTING:
@@ -1137,8 +1162,10 @@ class PagedEngine:
                 except Exception as e:
                     self._on_tick_failure(e)
                 finally:
-                    # this tick's phase split rides its span (read at
-                    # span EXIT — end_tick resets the accumulator later)
+                    # this tick's work and phase split ride its span
+                    # (read at span EXIT — end_tick resets the
+                    # accumulator later)
+                    span_args.update(self._tick_work)
                     span_args.update(
                         self.scheduler.tick_phase_seconds())
         finally:
@@ -1163,19 +1190,21 @@ class PagedEngine:
             raise _inject.InjectedFault(
                 "serving.crash_at_tick",
                 f"injected crash at tick {self._ticks}")
-        self._expire_deadlines()
         if self._dense:
             # dense path: the tick itself admits (it consumes up to
             # max_batch from the queue head), so shed only what the
             # forward could not absorb
+            self._expire_deadlines()
             self._dense_tick()
             self._shed_overload()
             return
-        # admit BEFORE shedding: a burst hitting an idle replica flows
-        # into free decode slots first; only what capacity could not
-        # absorb this tick counts against the high-water mark
-        self._admit()
-        self._shed_overload()
+        with _trace.boundary("serving.admit"):
+            self._expire_deadlines()
+            # admit BEFORE shedding: a burst hitting an idle replica flows
+            # into free decode slots first; only what capacity could not
+            # absorb this tick counts against the high-water mark
+            self._admit()
+            self._shed_overload()
         # phase split: bounded prefill, then decode — decode runs EVERY
         # tick there is decodable work, however much prefill is pending
         self._prefill_step()
@@ -1247,6 +1276,32 @@ class PagedEngine:
                    for i in active)
 
     def _decode_plain(self, active: List[int]):
+        with _trace.boundary("serving.plan"):
+            plan = self._plan_decode(active)
+        if plan is None:
+            return
+        tokens, seq, temps, top_ps, rids, ngens, skipped = plan
+        nxt = self._run_chunk(tokens, seq, self.tables, temps, top_ps,
+                              rids, ngens, phase="decode")
+        self._tick_work["decode_slots"] += len(active) - len(skipped)
+        with _trace.boundary("serving.emit"):
+            now = self._clock()
+            for i in active:
+                if seq[i] == 0:
+                    continue
+                req = self.slots[i]
+                req.generated.append(int(nxt[i]))
+                self.seq_lens[i] = int(seq[i])   # cached positions now
+                self.last_token[i] = int(nxt[i])
+                self._rt_event(req.rid, "decode_tick", t=now,
+                               tick=self._ticks, new_tokens=1)
+                self._record_token(req, now)
+                self._maybe_finish(i)
+
+    def _plan_decode(self, active: List[int]):
+        """The decode call's host rows ``(tokens, seq, temps, top_ps, rids,
+        ngens, skipped)``, every lane's blocks ensured; None when every
+        active slot is memory-stalled and one was evicted instead."""
         seq = self.seq_lens.copy()
         for i in self._prefilling:
             seq[i] = 0               # masked lane: no write, no attend
@@ -1271,7 +1326,7 @@ class PagedEngine:
             # deadline-aware) and retry next tick with its blocks free.
             victim = max(skipped, key=self._eviction_key)
             self._evict(victim)
-            return
+            return None
         tokens = self.last_token[:, None].astype(np.int32)
         temps = np.zeros((self.max_batch,), np.float32)
         top_ps = np.ones((self.max_batch,), np.float32)
@@ -1282,20 +1337,7 @@ class PagedEngine:
             top_ps[i] = self.slots[i].top_p
             rids[i] = self.slots[i].rid
             ngens[i] = len(self.slots[i].generated)
-        nxt = self._run_chunk(tokens, seq, self.tables, temps, top_ps,
-                              rids, ngens, phase="decode")
-        now = self._clock()
-        for i in active:
-            if seq[i] == 0:
-                continue
-            req = self.slots[i]
-            req.generated.append(int(nxt[i]))
-            self.seq_lens[i] = int(seq[i])   # cached positions now
-            self.last_token[i] = int(nxt[i])
-            self._rt_event(req.rid, "decode_tick", t=now,
-                           tick=self._ticks, new_tokens=1)
-            self._record_token(req, now)
-            self._maybe_finish(i)
+        return tokens, seq, temps, top_ps, rids, ngens, skipped
 
     def _decode_speculative(self, active: List[int]):
         """Decode via the fused verify program: per active slot, feed
@@ -1304,50 +1346,49 @@ class PagedEngine:
         k+1 tokens per slot per tick, greedy output identical to plain
         decode by construction (acceptance only keeps drafts the target
         model would have emitted itself)."""
-        from ..serving import speculative as _spec_mod
-
-        k = self._spec_k
-        T = k + 1
-        seq = self.seq_lens.copy()
-        for i in range(self.max_batch):
-            if i not in active:
-                seq[i] = 0           # idle / mid-prefill: masked lane
-        tokens = np.zeros((self.max_batch, T), np.int32)
-        temps = np.zeros((self.max_batch,), np.float32)
-        top_ps = np.ones((self.max_batch,), np.float32)
-        rids = np.zeros((self.max_batch,), np.int32)
-        ngens = np.zeros((self.max_batch,), np.int32)
-        max_accept = np.zeros((self.max_batch,), np.int32)
-        skipped = []
-        max_pos = getattr(self.arch, "max_positions", None)
-        for i in active:
-            req = self.slots[i]
-            # draft positions extend to seq_len-1+k: allocate for the
-            # whole verify up front (stale tail entries are masked by
-            # the rolled-back seq_len and overwritten as the sequence
-            # legitimately reaches them)
-            if not self._ensure_blocks(i, req.seq_len + k):
-                seq[i] = 0
-                skipped.append(i)
-                continue
-            draft: List[int] = []
-            if req.temperature == 0:
-                draft = list(self._spec.propose(
-                    req.prompt + req.generated))[:k]
-            ma = len(draft)
-            if max_pos is not None:
-                # drafts whose positions would clip-gather past the
-                # learned-position table can never be verified honestly
-                ma = max(0, min(ma, max_pos - req.seq_len))
-            row = [int(self.last_token[i])] + draft
-            row += [row[-1]] * (T - len(row))     # pad: always rejected
-            tokens[i] = row
-            seq[i] = req.seq_len + k
-            temps[i] = req.temperature
-            top_ps[i] = req.top_p
-            rids[i] = req.rid
-            ngens[i] = len(req.generated)
-            max_accept[i] = ma
+        with _trace.boundary("serving.plan"):
+            k = self._spec_k
+            T = k + 1
+            seq = self.seq_lens.copy()
+            for i in range(self.max_batch):
+                if i not in active:
+                    seq[i] = 0           # idle / mid-prefill: masked lane
+            tokens = np.zeros((self.max_batch, T), np.int32)
+            temps = np.zeros((self.max_batch,), np.float32)
+            top_ps = np.ones((self.max_batch,), np.float32)
+            rids = np.zeros((self.max_batch,), np.int32)
+            ngens = np.zeros((self.max_batch,), np.int32)
+            max_accept = np.zeros((self.max_batch,), np.int32)
+            skipped = []
+            max_pos = getattr(self.arch, "max_positions", None)
+            for i in active:
+                req = self.slots[i]
+                # draft positions extend to seq_len-1+k: allocate for the
+                # whole verify up front (stale tail entries are masked by
+                # the rolled-back seq_len and overwritten as the sequence
+                # legitimately reaches them)
+                if not self._ensure_blocks(i, req.seq_len + k):
+                    seq[i] = 0
+                    skipped.append(i)
+                    continue
+                draft: List[int] = []
+                if req.temperature == 0:
+                    draft = list(self._spec.propose(
+                        req.prompt + req.generated))[:k]
+                ma = len(draft)
+                if max_pos is not None:
+                    # drafts whose positions would clip-gather past the
+                    # learned-position table can never be verified honestly
+                    ma = max(0, min(ma, max_pos - req.seq_len))
+                row = [int(self.last_token[i])] + draft
+                row += [row[-1]] * (T - len(row))     # pad: always rejected
+                tokens[i] = row
+                seq[i] = req.seq_len + k
+                temps[i] = req.temperature
+                top_ps[i] = req.top_p
+                rids[i] = req.rid
+                ngens[i] = len(req.generated)
+                max_accept[i] = ma
         if skipped and len(skipped) == len(active):
             victim = max(skipped, key=self._eviction_key)
             self._evict(victim)
@@ -1361,6 +1402,14 @@ class PagedEngine:
             return
         emit, n_emit = self._run_verify(tokens, seq, self.tables, temps,
                                         top_ps, rids, ngens, max_accept)
+        self._tick_work["decode_slots"] += len(active) - len(skipped)
+        with _trace.boundary("serving.emit"):
+            self._emit_verified(active, seq, emit, n_emit, max_accept)
+
+    def _emit_verified(self, active, seq, emit, n_emit, max_accept):
+        """Per-slot bookkeeping of one verify program's accepted tokens."""
+        from ..serving import speculative as _spec_mod
+
         now = self._clock()
         proposed = accepted = 0
         for i in active:

@@ -99,7 +99,7 @@ def _producer_loop(it, q, stop, place_fn):
     try:
         while not stop.is_set():
             try:
-                with _trace.span("io.prefetch", "io"):
+                with _trace.boundary("io.prefetch"):
                     batch = next(it)
                     placed = place_fn(batch)
             except StopIteration:

@@ -13,6 +13,8 @@ from __future__ import annotations
 import math
 from dataclasses import dataclass
 
+import jax
+
 from .. import nn
 from ..nn import functional as F
 from ..nn.initializer import Normal
@@ -161,14 +163,16 @@ class GPTBlock(nn.Layer):
         self.dropout = cfg.dropout
 
     def forward(self, x):
-        y = self.attn(self.ln1(x))
-        if self.dropout > 0:
-            y = F.dropout(y, p=self.dropout, training=self.training)
-        x = x + y
-        y = self.mlp(self.ln2(x))
-        if self.dropout > 0:
-            y = F.dropout(y, p=self.dropout, training=self.training)
-        return x + y
+        with jax.named_scope("attn"):
+            y = self.attn(self.ln1(x))
+            if self.dropout > 0:
+                y = F.dropout(y, p=self.dropout, training=self.training)
+            x = x + y
+        with jax.named_scope("mlp"):
+            y = self.mlp(self.ln2(x))
+            if self.dropout > 0:
+                y = F.dropout(y, p=self.dropout, training=self.training)
+            return x + y
 
 
 class GPTModel(nn.Layer):
@@ -189,8 +193,9 @@ class GPTModel(nn.Layer):
 
     def forward(self, input_ids):
         b, s = input_ids.shape
-        pos = ops.arange(0, s, dtype="int64")
-        x = self.wte(input_ids) + self.wpe(pos)
+        with jax.named_scope("embed"):
+            pos = ops.arange(0, s, dtype="int64")
+            x = self.wte(input_ids) + self.wpe(pos)
         if self.cfg.recompute:
             from ._remat import remat_block
             for blk in self.blocks:
@@ -212,18 +217,21 @@ class GPTForCausalLM(nn.Layer):
     def forward(self, input_ids, labels=None):
         h = self.gpt(input_ids)
         if labels is not None and self.cfg.fused_loss:
-            loss = F.fused_linear_cross_entropy(
-                ops.reshape(h[:, :-1, :], [-1, self.cfg.hidden_size]),
-                self.gpt.wte.weight,
-                ops.reshape(labels[:, 1:], [-1]), transpose_y=True)
+            with jax.named_scope("loss"):       # head and loss in one op
+                loss = F.fused_linear_cross_entropy(
+                    ops.reshape(h[:, :-1, :], [-1, self.cfg.hidden_size]),
+                    self.gpt.wte.weight,
+                    ops.reshape(labels[:, 1:], [-1]), transpose_y=True)
             return None, loss
-        logits = ops.matmul(h, self.gpt.wte.weight, transpose_y=True)
+        with jax.named_scope("lm_head"):
+            logits = ops.matmul(h, self.gpt.wte.weight, transpose_y=True)
         if labels is None:
             return logits
-        v = logits.shape[-1]
-        loss = F.cross_entropy(
-            ops.reshape(logits[:, :-1, :], [-1, v]),
-            ops.reshape(labels[:, 1:], [-1]))
+        with jax.named_scope("loss"):
+            v = logits.shape[-1]
+            loss = F.cross_entropy(
+                ops.reshape(logits[:, :-1, :], [-1, v]),
+                ops.reshape(labels[:, 1:], [-1]))
         return logits, loss
 
     def num_params(self) -> int:

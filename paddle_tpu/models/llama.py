@@ -14,6 +14,7 @@ from __future__ import annotations
 import math
 from dataclasses import dataclass
 
+import jax
 import jax.numpy as jnp
 
 from .. import nn, ops
@@ -268,8 +269,10 @@ class LlamaBlock(nn.Layer):
             x = x + att
             return x + self.mlp(self.post_attention_layernorm(x)), \
                 new_cache
-        x = x + self.self_attn(self.input_layernorm(x))
-        return x + self.mlp(self.post_attention_layernorm(x))
+        with jax.named_scope("attn"):
+            x = x + self.self_attn(self.input_layernorm(x))
+        with jax.named_scope("mlp"):
+            return x + self.mlp(self.post_attention_layernorm(x))
 
 
 class LlamaModel(nn.Layer):
@@ -288,7 +291,8 @@ class LlamaModel(nn.Layer):
         self.norm = nn.RMSNorm(cfg.hidden_size, epsilon=cfg.rms_eps)
 
     def forward(self, input_ids):
-        x = self.embed_tokens(input_ids)
+        with jax.named_scope("embed"):
+            x = self.embed_tokens(input_ids)
         if self.cfg.recompute:
             from ._remat import remat_block
             for blk in self.layers:
@@ -316,27 +320,30 @@ class LlamaForCausalLM(nn.Layer):
     def forward(self, input_ids, labels=None):
         h = self.model(input_ids)
         if labels is not None and self.cfg.fused_loss:
-            hh = ops.reshape(h[:, :-1, :], [-1, self.cfg.hidden_size])
-            lab = ops.reshape(labels[:, 1:], [-1])
-            if self.lm_head is None:
-                loss = F.fused_linear_cross_entropy(
-                    hh, self.model.embed_tokens.weight, lab,
-                    transpose_y=True)
-            else:
-                loss = F.fused_linear_cross_entropy(
-                    hh, self.lm_head.weight, lab)
+            with jax.named_scope("loss"):       # head and loss in one op
+                hh = ops.reshape(h[:, :-1, :], [-1, self.cfg.hidden_size])
+                lab = ops.reshape(labels[:, 1:], [-1])
+                if self.lm_head is None:
+                    loss = F.fused_linear_cross_entropy(
+                        hh, self.model.embed_tokens.weight, lab,
+                        transpose_y=True)
+                else:
+                    loss = F.fused_linear_cross_entropy(
+                        hh, self.lm_head.weight, lab)
             return None, loss
-        if self.lm_head is None:
-            logits = ops.matmul(h, self.model.embed_tokens.weight,
-                                transpose_y=True)
-        else:
-            logits = self.lm_head(h)
+        with jax.named_scope("lm_head"):
+            if self.lm_head is None:
+                logits = ops.matmul(h, self.model.embed_tokens.weight,
+                                    transpose_y=True)
+            else:
+                logits = self.lm_head(h)
         if labels is None:
             return logits
-        v = logits.shape[-1]
-        loss = F.cross_entropy(
-            ops.reshape(logits[:, :-1, :], [-1, v]),
-            ops.reshape(labels[:, 1:], [-1]))
+        with jax.named_scope("loss"):
+            v = logits.shape[-1]
+            loss = F.cross_entropy(
+                ops.reshape(logits[:, :-1, :], [-1, v]),
+                ops.reshape(labels[:, 1:], [-1]))
         return logits, loss
 
     def num_params(self) -> int:

@@ -81,7 +81,13 @@ def block_multihead_attention(q, key_cache, value_cache, block_tables,
         ks_t, vs_t = _t(k_scale), _t(v_scale)
         tensors += [ks_t, vs_t]
 
-    def f(qa, kca, vca, bta, sla, *rest):
+    def f(*arrays):
+        # the composite's scope in a trace: cache write, per-sequence page
+        # gather, scores, softmax, values
+        with jax.named_scope("paged_attention"):
+            return composite(*arrays)
+
+    def composite(qa, kca, vca, bta, sla, *rest):
         from ...ops.pallas.serving import (kv_dequantize_int8,
                                            kv_quantize_int8)
 

@@ -7,6 +7,12 @@ in-memory buffer while a profiler session is recording; the profiler's
 single chrome trace (the role of the reference's HostTraceLevel event
 collector in fluid/platform/profiler/host_tracer.cc). When no session is
 active every instrumentation site costs one dict lookup.
+
+The spans at the layer boundaries of the two hot paths (``BOUNDARY_SPANS``)
+have a second sink: ``boundary`` also enters a
+``jax.profiler.TraceAnnotation`` of the same name, so whoever records a
+``jax.profiler`` trace finds them on the xplane's host plane, on the clock
+of the device's ``XLA Ops`` line. Per-op sites stay buffer-only.
 """
 from __future__ import annotations
 
@@ -14,8 +20,10 @@ import threading
 import time
 from typing import Any, Dict, List, Optional, Tuple
 
+from jax import profiler as _profiler
+
 __all__ = ["active", "activate", "deactivate", "add_complete", "span",
-           "drain", "clear", "MAX_EVENTS"]
+           "boundary", "BOUNDARY_SPANS", "drain", "clear", "MAX_EVENTS"]
 
 #: buffer cap — a runaway loop must degrade to dropped spans, not OOM
 MAX_EVENTS = 200_000
@@ -93,6 +101,111 @@ class span:
         if self._t0 is not None:
             add_complete(self.name, self.cat, self._t0,
                          time.perf_counter(), self.args)
+        return False
+
+
+#: THE list of boundary spans: name -> (buffer category, parent, what it
+#: brackets). A parent's time outside its children is its own host work; a
+#: span no other names as parent is a leaf. ``fit.*`` run on the thread that
+#: called ``Engine.fit``, ``io.prefetch`` on the prefetcher's producer
+#: thread, the rest on the thread that ticks the router. README
+#: "Observability" and PERF.md section 3 say which metric reads which.
+BOUNDARY_SPANS: Dict[str, Tuple[str, Optional[str], str]] = {
+    "fit.setup": ("fit", None,
+                  "entry of Engine.fit to the first wait for a batch: "
+                  "optimizer state, replication over the mesh, the loader"),
+    "fit.step": ("fit", None,
+                 "one iteration of the training loop (a StepTraceAnnotation "
+                 "with step_num)"),
+    "fit.next_batch": ("fit", "fit.step",
+                       "the wait on the prefetcher (or the synchronous "
+                       "fetch and placement)"),
+    "fit.dispatch": ("fit", "fit.step",
+                     "the call of the compiled train step"),
+    "fit.post_step": ("fit", "fit.step",
+                      "LR scheduler, running loss sum, memory census, fleet "
+                      "beacon, goodput ledger, sentinel"),
+    "fit.epoch_sync": ("fit", None,
+                       "the epoch's one host read of the loss sum"),
+    "fit.writeback": ("fit", None,
+                      "trained arrays and optimizer state back into the "
+                      "eager objects (fit's finally)"),
+    "io.prefetch": ("io", None,
+                    "producer thread: fetch the next batch and place it on "
+                    "the device"),
+    "router.step": ("serving", None, "one tier tick"),
+    "router.deliver": ("serving", "router.step",
+                       "token deltas to the open streams, outcomes settled"),
+    "serving.tick": ("serving", "router.step",
+                     "one PagedEngine.step (args: tick, active, queued, "
+                     "prompt_tokens, decode_slots, the scheduler's phase "
+                     "seconds)"),
+    "serving.admit": ("serving", "serving.tick",
+                      "expire deadlines, admit from the queue, shed overload"),
+    "serving.plan": ("serving", "serving.tick",
+                     "before a program call: pick its lanes, ensure their KV "
+                     "blocks, fill the call's host rows"),
+    # the two brackets keep the category tools/loadgen.py, perf.attribute
+    # and tools/request_trace.py key on; they are HOST clock over launch +
+    # blocking read, an upper bound of the program's device time
+    "serving.prefill": ("device", "serving.tick",
+                        "one prefill-chunk program call"),
+    "serving.prefill.build": ("serving", "serving.prefill",
+                              "eval mode on (a walk over every sublayer of "
+                              "a model in training mode), the call's host "
+                              "arrays to the device"),
+    "serving.prefill.launch": ("serving", "serving.prefill",
+                               "the call of the compiled program"),
+    "serving.prefill.wait": ("serving", "serving.prefill",
+                             "the blocking read of the sampled tokens, the "
+                             "training flag restored"),
+    "serving.decode": ("device", "serving.tick",
+                       "one decode (or speculative verify) program call"),
+    "serving.decode.build": ("serving", "serving.decode",
+                             "eval mode on (a walk over every sublayer of "
+                             "a model in training mode), the call's host "
+                             "arrays to the device"),
+    "serving.decode.launch": ("serving", "serving.decode",
+                              "the call of the compiled program"),
+    "serving.decode.wait": ("serving", "serving.decode",
+                            "the blocking read of the sampled tokens, the "
+                            "training flag restored"),
+    "serving.emit": ("serving", "serving.tick",
+                     "per-slot token bookkeeping after a program returns"),
+}
+
+
+class boundary(span):
+    """A span of ``BOUNDARY_SPANS`` (any other name is a KeyError): the
+    buffer like ``span``, and a ``jax.profiler.TraceAnnotation`` of the same
+    name (a ``StepTraceAnnotation`` when ``step_num`` is given) that carries
+    ``args`` as the event's stats. The annotation exists only while a
+    ``jax.profiler`` recording runs, the buffer entry only while ``active()``;
+    ``args`` is read at exit, so a site may fill it while the span is open."""
+
+    __slots__ = ("_step", "_ann")
+
+    def __init__(self, name: str, args: Optional[dict] = None,
+                 step_num: Optional[int] = None):
+        self._step = step_num is not None
+        if self._step:
+            args = dict(args or (), step_num=step_num)
+        span.__init__(self, name, BOUNDARY_SPANS[name][0], args)
+        self._ann = None
+
+    def __enter__(self):
+        if _profiler.TraceAnnotation.is_enabled():
+            self._ann = (_profiler.StepTraceAnnotation if self._step
+                         else _profiler.TraceAnnotation)(self.name)
+            self._ann.__enter__()
+        return span.__enter__(self)
+
+    def __exit__(self, *exc):
+        span.__exit__(self, *exc)
+        if self._ann is not None:
+            if self.args:
+                self._ann.set_metadata(**self.args)
+            self._ann.__exit__(*exc)
         return False
 
 

@@ -53,10 +53,6 @@ _m_at_cache = _metrics.counter(
 _m_at_probe_time = _metrics.histogram(
     "paddle_tpu_autotune_measure_seconds",
     "Wall time of one full candidate-grid measurement (all probes).")
-_m_at_failed = _metrics.counter(
-    "paddle_tpu_autotune_failed_candidates_total",
-    "Autotune candidates that failed to compile or run (each is logged "
-    "with the compiler's message; a failed default raises).")
 _m_at_winner = _metrics.gauge(
     "paddle_tpu_autotune_winner_seconds",
     "Median per-call latency of the winning candidate, per cache key.",
@@ -272,7 +268,6 @@ def autotune(key: str,
                 # the compiler's refusal IS the datum: recorded and
                 # reported, never dropped
                 failed[str(cand)] = msg = f"{type(e).__name__}: {e}"
-                _m_at_failed.inc()
                 log.warning("autotune %s: candidate %s failed: %s",
                             key, cand, msg[:2000])
                 continue

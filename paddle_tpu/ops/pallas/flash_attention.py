@@ -360,6 +360,7 @@ def _flash_fwd_bhsd(q, k, v, *, causal, scale, block_q, block_k):
             pltpu.VMEM((block_q, dp), jnp.float32),
         ],
         interpret=INTERPRET,
+        name="flash_fwd",
     )(q, k, v)
     return out[:, :s_q, :d], lse
 
@@ -407,6 +408,7 @@ def _flash_bwd_bhsd(q, k, v, out, lse, do, *, causal, scale, block_q,
         out_specs=q_spec,
         scratch_shapes=[pltpu.VMEM((block_q, dp), jnp.float32)],
         interpret=INTERPRET,
+        name="flash_dq",
     )(q, k, v, do, lse, delta)
 
     # dk/dv: kv outer, q inner
@@ -423,6 +425,7 @@ def _flash_bwd_bhsd(q, k, v, out, lse, do, *, causal, scale, block_q,
         scratch_shapes=[pltpu.VMEM((block_k, dp), jnp.float32),
                         pltpu.VMEM((block_k, dp), jnp.float32)],
         interpret=INTERPRET,
+        name="flash_dkv",
     )(q, k, v, do, lse, delta)
     return (dq[:, :s_q, :d], dk[:, :s_k, :d], dv[:, :s_k, :d])
 

@@ -180,6 +180,7 @@ def fused_residual_norm(x2d, res2d, weight, bias, *, kind="layer_norm",
         out_specs=[pl.BlockSpec((block_rows, d), lambda i: (i, 0)),
                    pl.BlockSpec((block_rows, d), lambda i: (i, 0))],
         interpret=INTERPRET,
+        name="fused_residual_norm",
     )(xp, sp, w2, b2)
     return y[:r], s[:r]
 
@@ -211,6 +212,7 @@ def fused_bias_act(x2d, bias, *, act="gelu", block_rows=None):
         ],
         out_specs=pl.BlockSpec((block_rows, d), lambda i: (i, 0)),
         interpret=INTERPRET,
+        name="fused_bias_act",
     )(xp, bias.reshape(1, d))[:r]
 
 
@@ -274,6 +276,7 @@ def fused_matmul(x2d, w, bias=None, norm_weight=None, norm_bias=None, *,
         ],
         out_specs=pl.BlockSpec((block_m, block_n), lambda i, j: (i, j)),
         interpret=INTERPRET,
+        name="fused_matmul",
     )(xp, w, b2, nw2, nb2)
     return out[:m]
 
@@ -354,5 +357,6 @@ def fused_matmul_rope(x2d, w, bias=None, *, seq, head_dim,
         ],
         out_specs=pl.BlockSpec((block_m, block_n), lambda i, j: (i, j)),
         interpret=INTERPRET,
+        name="fused_matmul_rope",
     )(xp, w, b2)
     return out[:m]

@@ -38,6 +38,7 @@ from ..inference.resilience import (Overloaded, RequestOutcome,
                                     RequestStatus, TERMINAL_STATUSES)
 from ..observability import metrics as _metrics
 from ..observability import reqtrace as _reqtrace
+from ..observability import trace as _trace
 from .stream import TokenStream
 
 __all__ = ["RouterConfig", "Router"]
@@ -261,11 +262,13 @@ class Router:
         """One tier tick: tick every replica with work, then settle
         outcomes. Returns {router_rid: full_token_list} for requests
         that FINISHED this tick."""
-        for rep in self.replicas:
-            if rep.has_work() and rep.lifecycle.live():
-                rep.step()
-        self._pump_streams()
-        return self._settle()
+        with _trace.boundary("router.step"):
+            for rep in self.replicas:
+                if rep.has_work() and rep.lifecycle.live():
+                    rep.step()
+            with _trace.boundary("router.deliver"):
+                self._pump_streams()
+                return self._settle()
 
     def run_to_completion(self, max_ticks: int = 10_000) -> Dict[int, List[int]]:
         out: Dict[int, List[int]] = {}

@@ -1,0 +1,300 @@
+"""Boundary spans (observability/trace.py BOUNDARY_SPANS): both sinks, the
+annotation budget of a training step and a serving tick, and the names the
+device side carries: programs, kernels, scopes."""
+from __future__ import annotations
+
+import contextlib
+import glob
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+import paddle_tpu as paddle
+from paddle_tpu import nn
+from paddle_tpu.distributed.auto_parallel import Engine
+from paddle_tpu.inference import PagedEngine
+from paddle_tpu.models import (GPTConfig, GPTForCausalLM, LlamaConfig,
+                               LlamaForCausalLM)
+from paddle_tpu.observability import trace
+from paddle_tpu.serving import Router
+
+
+class FakeAnnotation:
+    """Stands in for jax.profiler.TraceAnnotation while 'a recording runs':
+    counts what is entered, keeps the metadata it was given."""
+
+    entered: list = []
+
+    def __init__(self, name, **kwargs):
+        self.name, self.meta = name, dict(kwargs)
+
+    @staticmethod
+    def is_enabled():
+        return True
+
+    def set_metadata(self, **kwargs):
+        self.meta.update(kwargs)
+
+    def __enter__(self):
+        FakeAnnotation.entered.append(self)
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+
+@pytest.fixture
+def fake_profiler(monkeypatch):
+    class Fake:
+        TraceAnnotation = FakeAnnotation
+        StepTraceAnnotation = FakeAnnotation
+    FakeAnnotation.entered = []
+    monkeypatch.setattr(trace, "_profiler", Fake)
+    return FakeAnnotation.entered
+
+
+@pytest.fixture
+def buffer():
+    trace.clear()
+    trace.activate()
+    yield
+    trace.deactivate()
+    trace.clear()
+
+
+def tiny_gpt_engine():
+    class LMLoss(nn.Layer):
+        def __init__(self, lm):
+            super().__init__()
+            self.lm = lm
+
+        def forward(self, ids):
+            return self.lm(ids, labels=ids)[1]
+
+    paddle.seed(0)
+    lm = GPTForCausalLM(GPTConfig(vocab_size=61, hidden_size=32,
+                                  num_layers=1, num_heads=2, max_seq_len=16,
+                                  use_flash_attention=False))
+    net = LMLoss(lm)
+    opt = paddle.optimizer.AdamW(learning_rate=1e-3,
+                                 parameters=net.parameters())
+    return Engine(net, loss=lambda loss, _y: loss, optimizer=opt)
+
+
+class Rows:
+    def __init__(self, n, seq=16, vocab=61):
+        self.x = np.random.default_rng(0).integers(0, vocab, (n, seq))
+
+    def __len__(self):
+        return len(self.x)
+
+    def __getitem__(self, i):
+        return self.x[i], self.x[i]
+
+
+def tiny_replica():
+    paddle.seed(0)
+    model = LlamaForCausalLM(LlamaConfig(
+        vocab_size=67, hidden_size=32, intermediate_size=64, num_layers=1,
+        num_heads=2, num_kv_heads=1, max_seq_len=64,
+        use_flash_attention=False))
+    return PagedEngine(model, max_batch=2, block_size=8, num_blocks=16,
+                       max_blocks_per_seq=8)
+
+
+# ------------------------------------------------------------- two sinks
+def test_boundary_span_enters_both_sinks(fake_profiler, buffer):
+    args = {"batch": 8}
+    with trace.boundary("serving.tick", args=args):
+        args["decode_slots"] = 3        # filled while the span is open
+    (name, cat, t0, t1, _tid, got), = trace.drain()
+    assert (name, cat) == ("serving.tick", "serving") and t1 >= t0
+    assert got == {"batch": 8, "decode_slots": 3}
+    (ann,) = fake_profiler
+    assert ann.name == "serving.tick"
+    assert ann.meta == {"batch": 8, "decode_slots": 3}
+
+
+def test_buffer_stays_empty_when_inactive(fake_profiler):
+    trace.clear()
+    assert not trace.active()
+    with trace.boundary("fit.step", step_num=7):
+        pass
+    assert trace.drain() == []
+    (ann,) = fake_profiler                # the recording still sees it
+    assert ann.name == "fit.step" and ann.meta == {"step_num": 7}
+
+
+def test_no_annotation_object_without_a_recording(monkeypatch):
+    made = []
+
+    class Off(FakeAnnotation):
+        @staticmethod
+        def is_enabled():
+            return False
+
+        def __init__(self, *a, **k):
+            made.append(a)
+
+    class Fake:
+        TraceAnnotation = StepTraceAnnotation = Off
+    monkeypatch.setattr(trace, "_profiler", Fake)
+    with trace.boundary("fit.dispatch"):
+        pass
+    assert made == []
+
+
+def test_only_listed_names_are_boundary_spans():
+    with pytest.raises(KeyError):
+        trace.boundary("matmul")
+    for name, (cat, parent, what) in trace.BOUNDARY_SPANS.items():
+        assert parent is None or parent in trace.BOUNDARY_SPANS, name
+        assert cat and what
+
+
+def test_a_real_recording_holds_the_span_on_the_host_plane(tmp_path):
+    from jax.profiler import ProfileData
+
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        with trace.boundary("router.step"):
+            with trace.boundary("serving.tick", args={"tick": 3}):
+                pass
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(str(tmp_path / "plugins/profile/*/*.xplane.pb"))
+    events = {ev.name: ev for plane in ProfileData.from_file(path).planes
+              if plane.name == "/host:CPU"
+              for line in plane.lines for ev in line.events}
+    tick, step = events["serving.tick"], events["router.step"]
+    assert dict(tick.stats) == {"tick": 3}
+    assert step.start_ns <= tick.start_ns
+    assert (tick.start_ns + tick.duration_ns
+            <= step.start_ns + step.duration_ns)
+
+
+# ----------------------------------------------------- annotation budget
+def test_fit_step_enters_at_most_six_annotations(fake_profiler):
+    engine = tiny_gpt_engine()
+    engine.fit(Rows(24), epochs=1, batch_size=8)
+    names = [a.name for a in fake_profiler]
+    assert names[0] == "fit.setup" and names[-1] == "fit.writeback"
+    assert names.count("fit.dispatch") == 3
+    # three steps and the fetch that finds the loader exhausted
+    assert [a.meta["step_num"] for a in fake_profiler
+            if a.name == "fit.step"] == [0, 1, 2, 3]
+    per_step = [n for n in names
+                if n in ("fit.step", "fit.next_batch", "fit.dispatch",
+                         "fit.post_step", "io.prefetch")]
+    assert len(per_step) <= 6 * 3
+    assert set(names) == {"fit.setup", "fit.step", "fit.next_batch",
+                          "fit.dispatch", "fit.post_step", "io.prefetch",
+                          "fit.epoch_sync", "fit.writeback"}
+
+
+def test_tick_enters_four_annotations_and_six_a_program(fake_profiler):
+    router = Router([tiny_replica()]).warmup()
+    del fake_profiler[:]
+    rid = router.add_request(list(range(1, 12)), max_new_tokens=3)
+    ticks = []
+    while router.has_work():
+        before = len(fake_profiler)
+        router.step()
+        ticks.append([a.name for a in fake_profiler[before:]])
+    assert router.outcomes[rid].status == "FINISHED"
+    assert len(ticks) >= 2
+    for names in ticks:
+        programs = sum(n in ("serving.prefill", "serving.decode")
+                       for n in names)
+        assert len(names) == 4 + 6 * programs
+    # a decode-only tick: ten, under the twelve the budget allows
+    assert sorted(ticks[-1]) == sorted([
+        "router.step", "serving.tick", "serving.admit", "serving.plan",
+        "serving.decode",
+        "serving.decode.build", "serving.decode.launch",
+        "serving.decode.wait", "serving.emit", "router.deliver"])
+    # the first tick prefills (two 8-token chunks) and decodes
+    assert ticks[0].count("serving.prefill") == 2
+    tick = next(a for a in fake_profiler if a.name == "serving.tick")
+    assert tick.meta["prompt_tokens"] == 16
+    assert tick.meta["decode_slots"] == 1 and tick.meta["queued"] == 1
+
+
+# ------------------------------------------------ names on the device side
+def _scoped(text, scope):
+    """``scope`` as one component of an op's name-stack path, bare or
+    inside jvp(...) / transpose(...)."""
+    return re.search(r'[/(]%s[/)"]' % re.escape(scope), text) is not None
+
+
+def _lower_train_step(engine, batch=8, seq=16):
+    engine.prepare()
+    pa = [p._data for p in engine._params]
+    ids = jnp.zeros((batch, seq), jnp.int32)
+    return engine._train_step.lower(pa, engine._init_opt_state(pa),
+                                    jnp.float32(1e-3), ids, ids)
+
+
+def _lower_serving(replica, phase):
+    t = replica.block_size if phase == "prefill" else 1
+    b = replica.max_batch
+    host = (np.zeros((b, t), np.int32), np.full((b,), t, np.int32),
+            replica.tables, np.zeros((b,), np.float32),
+            np.ones((b,), np.float32), np.zeros((b,), np.int32),
+            np.zeros((b,), np.int32))
+    return replica._fns[phase].lower(*replica._chunk_args(*host),
+                                     sampling=False)
+
+
+def test_train_step_carries_its_name_and_scopes():
+    text = _lower_train_step(tiny_gpt_engine()).as_text(debug_info=True)
+    assert "module @jit_engine_train_step" in text
+    for scope in ("embed", "attn", "mlp", "lm_head", "loss", "optimizer"):
+        assert _scoped(text, scope), scope
+
+
+@pytest.mark.parametrize("phase,module", [
+    ("prefill", "jit_paged_prefill_chunk"),
+    ("decode", "jit_paged_decode_step")])
+def test_serving_programs_carry_their_names_and_scopes(phase, module):
+    text = _lower_serving(tiny_replica(), phase).as_text(debug_info=True)
+    assert f"module @{module}" in text
+    for scope in ("embed", "attn", "mlp", "lm_head", "paged_attention"):
+        assert _scoped(text, scope), scope
+
+
+def test_flash_kernels_carry_their_names_in_the_lowered_custom_calls():
+    from paddle_tpu.ops.pallas import flash_attention as fa
+
+    def loss(q, k, v):
+        return fa._flash_attention(q, k, v, True, 0.125, 128, 128).sum()
+
+    q = jnp.zeros((1, 256, 2, 64), jnp.bfloat16)
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).trace(q, q, q).lower(
+        lowering_platforms=("tpu",)).as_text()
+    calls = [ln for ln in text.splitlines() if "@tpu_custom_call" in ln]
+    assert len(calls) == 3
+    for name in ("flash_fwd", "flash_dq", "flash_dkv"):
+        assert sum(f'kernel_name = "{name}"' in c for c in calls) == 1
+
+
+@pytest.mark.parametrize("program", ["train_step", "prefill_chunk"])
+def test_scopes_leave_the_compiled_program_unchanged(program, monkeypatch):
+    def flops():
+        if program == "train_step":
+            lowered = _lower_train_step(tiny_gpt_engine())
+        else:
+            lowered = _lower_serving(tiny_replica(), "prefill")
+        cost = lowered.compile().cost_analysis()
+        return cost["flops"], cost["bytes accessed"]
+
+    with_scopes = flops()
+    monkeypatch.setattr(jax, "named_scope",
+                        lambda _name: contextlib.nullcontext())
+    assert flops() == with_scopes
+    assert with_scopes[0] > 0
