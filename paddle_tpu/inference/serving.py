@@ -8,12 +8,17 @@ request is ONE jitted SPMD-friendly program — paged K/V caches live as
 donated device arrays, a host-side BlockManager owns the physical-block
 free list, and admission/eviction is plain Python between ticks:
 
-* prefill runs per request in block_size chunks (two compiled shapes:
-  a full chunk and each remainder), appending K/V pages via
-  ``nn.functional.block_multihead_attention``; under a phase-split
-  scheduler (``paddle_tpu.serving.Scheduler``) the chunks are budgeted
-  per tick and interleaved with decode, so a long prompt stops stalling
-  every in-flight stream's inter-token latency;
+* prefill runs one request a program, oldest admission first, in chunks
+  of W tokens: ONE compiled shape, (1, W), that carries only the
+  prefilling slot's rows and block table and appends its K/V pages via
+  ``nn.functional.block_multihead_attention``. W is a multiple of
+  ``block_size`` the engine derives (``prefill_width``): the tick's
+  whole prefill budget under a phase-split scheduler
+  (``paddle_tpu.serving.Scheduler``), so the budgeted chunk interleaves
+  with decode and a long prompt stops stalling every in-flight stream's
+  inter-token latency; ``_PREFILL_WIDTH`` without one. A prefix is
+  left-padded to a multiple of W, so its last chunk ends on the
+  prompt's last token;
 * decode runs ALL active slots in one (B, 1) step; idle slots point at a
   reserved trash block so the compiled program never branches on
   occupancy. With ``speculate=`` the decode step becomes a speculative
@@ -184,9 +189,12 @@ class _GPTArch:
         B, T = tokens.shape
         nh = cfg.num_heads
         hd = cfg.hidden_size // nh
-        # learned positional embeddings at per-slot positions
-        pos_idx = (start[:, None]
-                   + jnp.arange(T, dtype=start.dtype)[None, :])
+        # learned positional embeddings at per-slot positions; a prefill
+        # chunk's left padding sits at negative positions (up to
+        # prefill_width - 1 of them), whose rows are discarded: row 0
+        # stands in, so no gather reaches outside the table
+        pos_idx = jnp.maximum(
+            start[:, None] + jnp.arange(T, dtype=start.dtype)[None, :], 0)
         with jax.named_scope("embed"):
             pos_emb = jnp.take(m.wpe.weight._data, pos_idx, axis=0)
             x = m.wte(Tensor(tokens)) + Tensor(pos_emb)
@@ -313,6 +321,14 @@ def _tuned_decode_block_size(cfg, nkv, max_batch, max_blocks_per_seq,
 #: engines of one model (entries die with the model; see
 #: PagedEngine.__init__)
 _PAGED_JIT_CACHE: "weakref.WeakKeyDictionary" = weakref.WeakKeyDictionary()
+
+#: tokens a prefill program carries when no scheduler budget says how many
+#: a tick may advance: a chunk this wide still costs little more than
+#: streaming the weights once, and of 128 / 256 / 512 it served the most
+#: tokens a second on a TPU v5e (PERF.md §6 "PR 25"). An engine never
+#: runs more rows than its decode batch has lanes x ``block_size``, nor
+#: more than one sequence can hold.
+_PREFILL_WIDTH = 256
 
 
 def _request_keys(base_key, rids, ngens):
@@ -509,8 +525,15 @@ class PagedEngine:
             scheduler = Scheduler(scheduler)
         self.scheduler = scheduler
         #: slot -> in-progress chunked-prefill state (padded prefix,
-        #: chunk cursor); a slot decodes only once it leaves this map
+        #: chunk cursor), in admission order; a slot decodes only once
+        #: it leaves this map
         self._prefilling: Dict[int, dict] = {}
+        #: W, the one width of this engine's prefill program, in whole
+        #: blocks: the tick's whole budget where the scheduler has one
+        widest = block_size * min(max(1, _PREFILL_WIDTH // block_size),
+                                  max_batch, max_blocks_per_seq)
+        self.prefill_width = min(
+            scheduler.token_quota(block_size) or widest, widest)
 
         # ---- speculative decoding (paddle_tpu.serving.NgramProposer) --
         if speculate == "ngram":
@@ -870,16 +893,16 @@ class PagedEngine:
                            kv_blocks=len(self.slot_blocks[slot]))
             # stage the chunked prefill; compute happens in
             # _prefill_step under the scheduler's per-tick budget. The
-            # prefix is LEFT-padded to a multiple of block_size — padded
-            # positions sit at negative sequence positions, which the
-            # paged-attention kernel drops from the cache write and
+            # prefix is LEFT-padded to a multiple of the chunk width —
+            # padded positions sit at negative sequence positions, which
+            # the paged-attention kernel drops from the cache write and
             # fully masks, so only two compiled shapes exist in steady
-            # state: (max_batch, block_size) and the (max_batch, 1-or-
-            # k+1) decode/verify.
-            bs = self.block_size
+            # state: (1, prefill_width) and the (max_batch, 1-or-k+1)
+            # decode/verify.
+            width = self.prefill_width
             prefix = np.asarray(req.prompt + req.generated, np.int32)
-            n_chunks = -(-len(prefix) // bs)
-            pad = n_chunks * bs - len(prefix)
+            n_chunks = -(-len(prefix) // width)
+            pad = n_chunks * width - len(prefix)
             self._prefilling[slot] = {
                 "prefix": np.concatenate(
                     [np.zeros(pad, np.int32), prefix]),
@@ -887,92 +910,78 @@ class PagedEngine:
 
     def _prefill_step(self):
         """Advance pending chunked prefills under the scheduler's
-        per-tick budget: each chunk program carries the NEXT chunk of up
-        to ``quota`` prefilling slots (slots at different chunk indices
-        share one program — per-slot seq_lens position the writes). The
-        final chunk of a slot yields its first sampled token; chunks
-        past the budget defer to later ticks so the decode step below
-        never waits out a long prompt."""
-        bs = self.block_size
-        quota = self.scheduler.chunk_quota(bs)
+        per-tick budget: each chunk program carries the next
+        ``prefill_width`` tokens of ONE prefilling slot, the one admitted
+        first, and only that slot's rows and block table. The final chunk
+        of a slot yields its first sampled token; chunks past the budget
+        defer to later ticks so the decode step below never waits out a
+        long prompt."""
+        width = self.prefill_width
+        quota = self.scheduler.token_quota(self.block_size)
+        # whole programs the tick's tokens pay for (a budget wider than
+        # the engine's widest chunk buys several)
+        programs = (float("inf") if quota is None
+                    else max(1, quota // width))
         while self._prefilling:
             with _trace.boundary("serving.plan"):
-                plan = self._plan_prefill_chunk(quota)
+                plan = self._plan_prefill_chunk(programs)
             if plan is None:
                 return
-            slots, finalists, tokens, seq, temps, top_ps, rids, ngens = plan
-            nxt = self._run_chunk(tokens, seq, self.tables, temps, top_ps,
-                                  rids, ngens, phase="prefill")
-            self._tick_work["prompt_tokens"] += len(slots) * bs
-            if quota is not None:
-                quota -= len(slots)
+            slot, chunk, final, rows = plan
+            st = self._prefilling[slot]
+            req = self.slots[slot]
+            (tok,) = self._run_chunk(*rows, phase="prefill")
+            self.scheduler.note_prompt_tokens(
+                width - (st["pad"] if chunk == 0 else 0))
+            self._tick_work["prompt_tokens"] += width
+            programs -= 1
             with _trace.boundary("serving.emit"):
                 now = self._clock()
-                for slot in slots:
-                    # finalists' state entries are still live here — the
-                    # chunk just computed is the one BEFORE the cursor
-                    st = self._prefilling[slot]
-                    req = self.slots[slot]
-                    self._rt_event(req.rid, "prefill_chunk", t=now,
-                                   chunk=st["next"] - 1,
-                                   n_chunks=st["n_chunks"], tokens=bs,
-                                   tick=self._ticks)
-                for slot in finalists:
+                self._rt_event(req.rid, "prefill_chunk", t=now,
+                               chunk=chunk, n_chunks=st["n_chunks"],
+                               tokens=width, tick=self._ticks)
+                if final:
                     del self._prefilling[slot]
-                    req = self.slots[slot]
                     # cached positions == the prefilled prefix; the
                     # sampled token lands in the cache on its decode step
                     self.seq_lens[slot] = (len(req.prompt)
                                            + len(req.generated))
-                    tok = int(nxt[slot])
+                    tok = int(tok)
                     req.generated.append(tok)
                     self.last_token[slot] = tok
                     self._record_token(req, now)
                     self._maybe_finish(slot)
 
-    def _plan_prefill_chunk(self, quota):
-        """The next chunk call's host rows ``(slots, finalists, tokens, seq,
-        temps, top_ps, rids, ngens)`` for up to ``quota`` prefilling slots;
-        None (and the deferral noted) when the tick's budget is spent."""
-        bs = self.block_size
-        slots = sorted(self._prefilling)
-        if quota is not None:
-            slots = slots[:quota]
-            if not slots:
-                self.scheduler.note_deferred(sum(
-                    st["n_chunks"] - st["next"]
-                    for st in self._prefilling.values()))
-                # the WHY of a slow TTFT: this tick's budget pushed
-                # these requests' remaining chunks to a later tick
-                for slot, st in self._prefilling.items():
-                    req = self.slots[slot]
-                    if req is not None:
-                        self._rt_event(
-                            req.rid, "prefill_deferred",
-                            tick=self._ticks,
-                            chunks_left=st["n_chunks"] - st["next"])
-                return None
-        tokens = np.zeros((self.max_batch, bs), np.int32)
-        seq = np.zeros((self.max_batch,), np.int32)   # 0 = inactive
-        temps = np.zeros((self.max_batch,), np.float32)
-        top_ps = np.ones((self.max_batch,), np.float32)
-        rids = np.zeros((self.max_batch,), np.int32)
-        ngens = np.zeros((self.max_batch,), np.int32)
-        finalists = []
-        for slot in slots:
-            st = self._prefilling[slot]
-            req = self.slots[slot]
-            j = st["next"]
-            tokens[slot] = st["prefix"][j * bs:(j + 1) * bs]
-            seq[slot] = (j + 1) * bs - st["pad"]
-            temps[slot] = req.temperature
-            top_ps[slot] = req.top_p
-            rids[slot] = req.rid
-            ngens[slot] = len(req.generated)
-            st["next"] = j + 1
-            if st["next"] == st["n_chunks"]:
-                finalists.append(slot)
-        return slots, finalists, tokens, seq, temps, top_ps, rids, ngens
+    def _plan_prefill_chunk(self, programs):
+        """The next chunk call: ``(slot, chunk index, is the slot's last
+        chunk, host rows (tokens, seq, tables, temps, top_ps, rids,
+        ngens))`` for the slot admitted first; None (and the deferral
+        noted) when the tick's ``programs`` are spent."""
+        width = self.prefill_width
+        if programs <= 0:
+            self.scheduler.note_deferred(sum(
+                st["n_chunks"] - st["next"]
+                for st in self._prefilling.values()))
+            # the WHY of a slow TTFT: this tick's budget pushed
+            # these requests' remaining chunks to a later tick
+            for slot, st in self._prefilling.items():
+                self._rt_event(
+                    self.slots[slot].rid, "prefill_deferred",
+                    tick=self._ticks,
+                    chunks_left=st["n_chunks"] - st["next"])
+            return None
+        slot, st = next(iter(self._prefilling.items()))
+        req = self.slots[slot]
+        j = st["next"]
+        st["next"] = j + 1
+        rows = (st["prefix"][None, j * width:(j + 1) * width],
+                np.asarray([(j + 1) * width - st["pad"]], np.int32),
+                self.tables[slot:slot + 1],
+                np.asarray([req.temperature], np.float32),
+                np.asarray([req.top_p], np.float32),
+                np.asarray([req.rid], np.int32),
+                np.asarray([len(req.generated)], np.int32))
+        return slot, j, st["next"] == st["n_chunks"], rows
 
     def _evict(self, slot: int,
                reason: str = "kv-block pressure (livelock preemption)"):
@@ -1574,8 +1583,8 @@ class PagedEngine:
 
     def warmup(self, prompt_len: Optional[int] = None,
                max_new_tokens: int = 2) -> "PagedEngine":
-        """Compile the steady-state programs (full prefill chunk + the
-        batched decode step) before real traffic:
+        """Compile the steady-state programs (the one (1, prefill_width)
+        prefill chunk + the batched decode step) before real traffic:
         STARTING→WARMING→READY. Idempotent on a READY replica.
 
         Traffic that arrived before READY (admission is open from
@@ -1690,6 +1699,7 @@ class PagedEngine:
              "ticks": self._ticks,
              "tick_failures": self.tick_failures,
              "phase_share": self.scheduler.phase_share(),
+             "prefill_fill": self.scheduler.prefill_fill(),
              # the probe path doubles as the burn-rate decay poll: an
              # idle replica's windows age out here, so the gauges fall
              # back to 0 after an incident instead of pinning high

@@ -5,13 +5,16 @@ that prefills every admitted prompt to completion inside the admission
 tick stalls the decode batch for the whole prompt length — one 2k-token
 prompt freezes every in-flight stream's inter-token latency. The fix the
 production stacks converged on (Sarathi chunked prefill, DistServe
-prefill/decode disaggregation): prompts advance in fixed ``block_size``
-chunks under a per-tick token budget, and the batched decode step runs
-EVERY tick regardless of pending prefill — decode has priority, prefill
-gets the leftover budget.
+prefill/decode disaggregation): prompts advance under a per-tick token
+budget, and the batched decode step runs EVERY tick regardless of
+pending prefill — decode has priority, prefill gets the leftover budget.
+The engine spends the tick's budget as ONE chunk program as wide as the
+budget (a whole number of ``block_size`` blocks, capped at the engine's
+own widest chunk), on ONE slot: of two slots mid-prefill the one admitted
+first gets all of the tick's tokens, the other waits for it to finish.
 
 :class:`Scheduler` owns that budget arithmetic plus the phase
-accounting; the engine asks it ``chunk_quota()`` each tick and reports
+accounting; the engine asks it ``token_quota()`` each tick and reports
 every chunk/decode program it runs. ``prefill_token_budget=None`` keeps
 the round-3 behavior (drain all pending chunks in the admission tick) —
 single-replica batch jobs that only care about completion throughput
@@ -21,7 +24,10 @@ inter-token latency through prompt bursts.
 Metrics (stable rows, see README "Serving tier"):
 ``paddle_tpu_serving_prefill_tokens_total`` /
 ``paddle_tpu_serving_decode_tokens_total`` count scheduled tokens per
-phase; ``paddle_tpu_serving_tick_phase_share{phase=}`` is the sliding
+phase, ``paddle_tpu_serving_prefill_prompt_tokens_total`` the real
+prompt tokens among the prefill rows (their ratio is
+:meth:`Scheduler.prefill_fill`: how much of a chunk program was work);
+``paddle_tpu_serving_tick_phase_share{phase=}`` is the sliding
 share of device time each phase took over recent ticks — the signal a
 capacity planner reads to split a fleet into prefill- and decode-heavy
 replica pools (the DistServe topology) without re-instrumenting.
@@ -40,6 +46,11 @@ M_PREFILL_TOKENS = _metrics.counter(
     "paddle_tpu_serving_prefill_tokens_total",
     "Prompt tokens scheduled through chunked prefill (includes chunk "
     "padding — the tokens the chip actually processed).")
+M_PREFILL_PROMPT_TOKENS = _metrics.counter(
+    "paddle_tpu_serving_prefill_prompt_tokens_total",
+    "Real prompt tokens advanced through chunked prefill (chunk padding "
+    "left out); over paddle_tpu_serving_prefill_tokens_total it is the "
+    "share of the prefill programs' rows that were work.")
 M_DECODE_TOKENS = _metrics.counter(
     "paddle_tpu_serving_decode_tokens_total",
     "Tokens scheduled through the batched decode step (speculative "
@@ -60,14 +71,16 @@ class SchedulerConfig:
     """Knobs for the phase-split tick scheduler.
 
     ``prefill_token_budget``
-        Upper bound on prompt tokens advanced per tick across the batch
-        (each scheduled chunk-slot costs ``block_size`` tokens). ``None``
-        disables the split: admitted prompts prefill to completion in
-        their admission tick (the round-3 behavior).
+        Upper bound on prompt tokens advanced per tick (whole
+        ``block_size`` blocks; a chunk's left padding counts, it is
+        rows the chip computes). ``None`` disables the split: admitted
+        prompts prefill to completion in their admission tick (the
+        round-3 behavior).
     ``min_prefill_chunks``
         Progress guarantee: even when the budget is smaller than one
-        chunk, at least this many chunk-slots run per tick while prefill
-        work is pending — a budget can interleave, never livelock.
+        block, at least this many blocks of prompt run per tick while
+        prefill work is pending — a budget can interleave, never
+        livelock.
     ``share_window_ticks``
         Ticks in the sliding window behind the phase-share gauge.
     """
@@ -100,24 +113,40 @@ class Scheduler:
         #: lifetime token totals per phase (mirrors the counters, local
         #: so health()/bench can read them without the metrics registry)
         self.prefill_tokens = 0
+        self.prefill_prompt_tokens = 0
         self.decode_tokens = 0
         self.deferred_chunks = 0
         self._window = []          # (prefill_s, decode_s) per tick
         self._tick_s = {"prefill": 0.0, "decode": 0.0}
 
     # ------------------------------------------------------------ budget
-    def chunk_quota(self, block_size: int) -> Optional[int]:
-        """Chunk-slots (``block_size`` tokens each) this tick may spend
-        on prefill; ``None`` = unbounded (no phase split configured)."""
+    def token_quota(self, block_size: int) -> Optional[int]:
+        """Prompt tokens (whole ``block_size`` blocks) this tick may
+        spend on prefill; ``None`` = unbounded (no phase split
+        configured)."""
         budget = self.config.prefill_token_budget
         if budget is None:
             return None
-        return max(self.config.min_prefill_chunks, budget // block_size)
+        return block_size * max(self.config.min_prefill_chunks,
+                                budget // block_size)
 
     def note_deferred(self, chunks: int):
         if chunks > 0:
             self.deferred_chunks += chunks
             M_PREFILL_DEFERRED.inc(chunks)
+
+    def note_prompt_tokens(self, tokens: int):
+        """``tokens`` real prompt tokens rode the prefill program just
+        noted (its other rows were left padding)."""
+        self.prefill_prompt_tokens += tokens
+        M_PREFILL_PROMPT_TOKENS.inc(tokens)
+
+    def prefill_fill(self) -> Optional[float]:
+        """Real prompt tokens over the rows the prefill programs
+        computed, lifetime; None before the first chunk."""
+        if not self.prefill_tokens:
+            return None
+        return self.prefill_prompt_tokens / self.prefill_tokens
 
     def tick_phase_seconds(self) -> dict:
         """The CURRENT tick's accumulated per-phase device seconds

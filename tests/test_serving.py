@@ -9,8 +9,10 @@ import numpy as np
 import pytest
 
 import paddle_tpu as paddle
-from paddle_tpu.inference import BlockManager, LlamaPagedEngine
-from paddle_tpu.models import LlamaConfig, LlamaForCausalLM
+from paddle_tpu.inference import BlockManager, LlamaPagedEngine, PagedEngine
+from paddle_tpu.models import (GPTConfig, GPTForCausalLM, LlamaConfig,
+                               LlamaForCausalLM)
+from paddle_tpu.serving import SchedulerConfig
 
 
 _MODEL_CACHE = {}
@@ -30,11 +32,33 @@ def _tiny_model():
     return _MODEL_CACHE["m"]
 
 
+def _tiny_gpt():
+    if "gpt" not in _MODEL_CACHE:
+        paddle.seed(11)
+        cfg = GPTConfig(vocab_size=83, hidden_size=64, num_layers=2,
+                        num_heads=4, max_seq_len=64, dropout=0.0,
+                        use_flash_attention=False)
+        _MODEL_CACHE["gpt"] = GPTForCausalLM(cfg)
+        _MODEL_CACHE["gpt"].eval()
+    return _MODEL_CACHE["gpt"]
+
+
 def _ref_greedy(model, prompt, n_new):
     ids = paddle.to_tensor(np.asarray([prompt], np.int64))
     out = model.generate(ids, max_new_tokens=n_new, temperature=0.0,
                          use_cache=False)
     return [int(t) for t in np.asarray(out.numpy())[0][len(prompt):]]
+
+
+def _ref_greedy_any(model, prompt, n_new):
+    """Full-recompute greedy through the model's own forward (either
+    architecture)."""
+    ids, out = list(prompt), []
+    for _ in range(n_new):
+        logits = model(paddle.to_tensor(np.asarray([ids], np.int64)))
+        out.append(int(np.argmax(np.asarray(logits.numpy())[0, -1])))
+        ids.append(out[-1])
+    return out
 
 
 class TestBlockManager:
@@ -180,29 +204,150 @@ class TestSampling:
 
 
 class TestGPTPagedEngine:
-    def test_gpt_matches_full_recompute_greedy(self):
-        from paddle_tpu.inference import PagedEngine
-        from paddle_tpu.models import GPTConfig, GPTForCausalLM
-        paddle.seed(11)
-        cfg = GPTConfig(vocab_size=83, hidden_size=64, num_layers=2,
-                        num_heads=4, max_seq_len=64, dropout=0.0,
-                        use_flash_attention=False)
-        model = GPTForCausalLM(cfg)
-        model.eval()
+    # W = 8 here: the prompts leave 7, 1, 7 and 4 rows of left padding at
+    # negative positions of the learned-position table
+    @pytest.mark.parametrize("n_prompt", [1, 7, 9, 20])
+    def test_gpt_matches_full_recompute_greedy(self, n_prompt):
+        model = _tiny_gpt()
         rng = np.random.RandomState(5)
-        prompt = [int(t) for t in rng.randint(1, 83, size=7)]
-
-        # reference: full-recompute greedy loop through the model itself
-        ids = list(prompt)
-        ref = []
-        for _ in range(6):
-            logits = model(paddle.to_tensor(np.asarray([ids], np.int64)))
-            nxt = int(np.argmax(np.asarray(logits.numpy())[0, -1]))
-            ref.append(nxt)
-            ids.append(nxt)
+        prompt = [int(t) for t in rng.randint(1, 83, size=n_prompt)]
 
         eng = PagedEngine(model, max_batch=2, block_size=4,
                           num_blocks=32, max_blocks_per_seq=8)
+        assert eng.prefill_width == 8
         rid = eng.add_request(prompt, max_new_tokens=6)
         out = eng.run_to_completion()
-        assert out[rid] == ref
+        assert out[rid] == _ref_greedy_any(model, prompt, 6)
+
+
+# --------------------------------------------- the prefill program's shape
+#: kind -> (the scheduler's budget, the W it gives)
+_WIDTHS = {"block": (4, 4), "budget": (8, 8), "wide": (None, 12)}
+
+
+def _width_engine(model, kind, **kw):
+    """One engine of each way W comes about: the budget of one block, a
+    budget of two, and the engine's own widest chunk (three blocks: a
+    decode batch of 3 lanes x block 4)."""
+    budget, width = _WIDTHS[kind]
+    kw.setdefault("num_blocks", 64)
+    kw.setdefault("max_blocks_per_seq", 16)
+    eng = PagedEngine(
+        model, max_batch=3, block_size=4,
+        scheduler=(SchedulerConfig(prefill_token_budget=budget)
+                   if budget else None), **kw)
+    assert eng.prefill_width == width
+    return eng
+
+
+def _patterned(vocab, n, seed):
+    """A prompt of a tiled 5-token pattern, so the n-gram proposer has
+    something to propose."""
+    pat = np.random.RandomState(seed).randint(1, vocab, size=5)
+    return [int(t) for t in np.resize(pat, n)]
+
+
+class TestPrefillShape:
+    # prompt lengths 1, W-1, W, W+1 of the widest engine's W = 12, and one
+    # of several chunks at every width
+    @pytest.mark.parametrize("n_prompt", [1, 11, 12, 13, 29])
+    @pytest.mark.parametrize("speculate", [None, "ngram"])
+    @pytest.mark.parametrize("kv_dtype", [None, "int8"])
+    @pytest.mark.parametrize("arch", ["llama", "gpt"])
+    def test_greedy_tokens_equal_at_every_width(self, arch, kv_dtype,
+                                                speculate, n_prompt):
+        model = _tiny_model() if arch == "llama" else _tiny_gpt()
+        prompt = _patterned(model.cfg.vocab_size, n_prompt, seed=n_prompt)
+        got = {}
+        for kind in _WIDTHS:
+            eng = _width_engine(model, kind, kv_dtype=kv_dtype,
+                                speculate=speculate)
+            rid = eng.add_request(prompt, max_new_tokens=6)
+            got[kind] = eng.run_to_completion()[rid]
+            assert eng.tick_failures == 0
+        assert got["wide"] == got["block"]
+        assert got["budget"] == got["block"]
+        if kv_dtype is None:
+            # the float engine is also the model's own greedy decode
+            assert got["block"] == _ref_greedy_any(model, prompt, 6)
+
+    @pytest.mark.parametrize("kind", list(_WIDTHS))
+    def test_preemption_reprefills_same_tokens(self, kind):
+        """A preempted request re-prefills prompt + generated through the
+        same (1, W) program and ends on the model's greedy tokens."""
+        model = _tiny_model()
+        rng = np.random.RandomState(4)
+        prompts = [[int(t) for t in rng.randint(1, 97, size=4)]
+                   for _ in range(2)]
+        eng = _width_engine(model, kind, num_blocks=5, max_blocks_per_seq=4)
+        evicted = []
+        evict = eng._evict
+        eng._evict = lambda slot: (evicted.append(slot), evict(slot))[-1]
+        rids = [eng.add_request(p, max_new_tokens=6) for p in prompts]
+        out = eng.run_to_completion(max_ticks=200)
+        assert evicted
+        for rid, p in zip(rids, prompts):
+            assert out[rid] == _ref_greedy(model, p, 6)
+        assert eng.bm.available == 4
+
+    @pytest.mark.parametrize("kind", list(_WIDTHS))
+    @pytest.mark.parametrize("arch", ["llama", "gpt"])
+    def test_one_prefill_shape_compiled_by_warmup(self, arch, kind):
+        """The chunk call is (1, W) with the slot's own block table, and
+        after warmup() a prompt of any length compiles nothing."""
+        # a model of its own: the programs of a shared one are shared too
+        paddle.seed(3)
+        if arch == "llama":
+            model = LlamaForCausalLM(LlamaConfig(
+                vocab_size=61, hidden_size=32, intermediate_size=64,
+                num_layers=1, num_heads=2, max_seq_len=64,
+                use_flash_attention=False))
+        else:
+            model = GPTForCausalLM(GPTConfig(
+                vocab_size=61, hidden_size=32, num_layers=1, num_heads=2,
+                max_seq_len=64, dropout=0.0, use_flash_attention=False))
+        eng = _width_engine(model, kind)
+        width = eng.prefill_width
+        prefill, shapes = eng._fns["prefill"], []
+
+        def recording(*args, **kw):
+            shapes.append(tuple(a.shape for a in args[3:10]))
+            return prefill(*args, **kw)
+
+        eng._fns["prefill"] = recording
+        eng.warmup()
+        compiled = lambda: (prefill._cache_size(),
+                            eng._fns["decode"]._cache_size())
+        assert compiled() == (1, 1)
+        for n in (1, width - 1, width, width + 1, 3 * width + 2):
+            eng.add_request(list(range(1, n + 1)), max_new_tokens=3)
+        eng.run_to_completion()
+        assert compiled() == (1, 1)
+        # tokens, seq, tables, temps, top_ps, rids, ngens: one lane each
+        assert set(shapes) == {((1, width), (1,), (1, 16), (1,), (1,),
+                                (1,), (1,))}
+
+    @pytest.mark.parametrize("n_prompt,kind,rows", [
+        (10, "wide", 12), (13, "wide", 24), (10, "block", 12),
+        (3, "budget", 8), (16, "budget", 16)])
+    def test_prefill_fill_is_real_over_computed(self, n_prompt, kind, rows):
+        from paddle_tpu.observability import REGISTRY
+        paddle.set_flags({"FLAGS_enable_metrics": True})
+        try:
+            REGISTRY.reset()
+            eng = _width_engine(_tiny_model(), kind)
+            assert eng.health()["prefill_fill"] is None
+            eng.add_request(list(range(1, n_prompt + 1)), max_new_tokens=2)
+            eng.run_to_completion()
+            sched = eng.scheduler
+            assert sched.prefill_prompt_tokens == n_prompt
+            assert sched.prefill_tokens == rows
+            assert eng.health()["prefill_fill"] == n_prompt / rows
+            assert REGISTRY.get(
+                "paddle_tpu_serving_prefill_prompt_tokens_total"
+            ).total() == n_prompt
+            assert REGISTRY.get(
+                "paddle_tpu_serving_prefill_tokens_total").total() == rows
+        finally:
+            paddle.set_flags({"FLAGS_enable_metrics": False})
+            REGISTRY.reset()

@@ -405,6 +405,56 @@ class TestPhaseSplitScheduler:
         assert split.scheduler.phase_share()["prefill"] is not None
         split.drain()
 
+    # the tick's tokens: one chunk as wide as the budget (4, 8: W = 4, 8),
+    # a budget under one block still moves a block, and a budget wider
+    # than the engine's widest chunk (2 lanes x 4 = 8) buys whole chunks
+    @pytest.mark.parametrize("budget,width,per_tick", [
+        (4, 4, 4), (8, 8, 8), (2, 4, 4), (20, 8, 16)])
+    def test_no_tick_over_budget_and_decode_every_tick(
+            self, model, budget, width, per_tick):
+        split = make_engine(
+            model, max_batch=2,
+            scheduler=SchedulerConfig(prefill_token_budget=budget))
+        assert split.prefill_width == width
+        fast = split.add_request(prompt(55, n=4), max_new_tokens=40)
+        split.step()
+        split.add_request(prompt(56, n=38), max_new_tokens=4)
+        ticks, spent = 0, []
+        n0 = len(split.slots[0].generated)
+        while ticks == 0 or 1 in split._prefilling:
+            split.step()
+            ticks += 1
+            spent.append(split._tick_work["prompt_tokens"])
+            assert split._tick_work["decode_slots"] >= 1
+        assert max(spent) == per_tick <= max(budget, 4)
+        assert sum(spent) == -(-38 // width) * width
+        assert split.slots[0].rid == fast
+        assert len(split.slots[0].generated) == n0 + ticks
+        split.drain()
+
+    def test_older_admission_gets_the_ticks_tokens(self, model):
+        """Two slots mid-prefill under a budget: the one admitted first
+        gets every token of the tick until it is done; the other's chunks
+        are counted as deferred."""
+        split = make_engine(
+            model, max_batch=2,
+            scheduler=SchedulerConfig(prefill_token_budget=4))
+        old = split.add_request(prompt(57, n=12), max_new_tokens=2)
+        new = split.add_request(prompt(58, n=12), max_new_tokens=2)
+        split.step()
+        assert list(split._prefilling) == [0, 1]
+        assert [split.slots[i].rid for i in (0, 1)] == [old, new]
+        split.step()
+        assert split._prefilling[0]["next"] == 2
+        assert split._prefilling[1]["next"] == 0
+        # left after tick 1: 2 + 3 chunks; after tick 2: 1 + 3
+        assert split.scheduler.deferred_chunks == 9
+        out = split.step()                  # the older one's last chunk
+        assert list(split._prefilling) == [1]
+        out.update(split.run_to_completion())
+        assert out[old] == ref_greedy(model, prompt(57, n=12), 2)
+        assert out[new] == ref_greedy(model, prompt(58, n=12), 2)
+
     def test_token_accounting(self, model):
         eng = make_engine(
             model, scheduler=SchedulerConfig(prefill_token_budget=8))

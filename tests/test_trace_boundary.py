@@ -218,8 +218,9 @@ def test_tick_enters_four_annotations_and_six_a_program(fake_profiler):
         "serving.decode",
         "serving.decode.build", "serving.decode.launch",
         "serving.decode.wait", "serving.emit", "router.deliver"])
-    # the first tick prefills (two 8-token chunks) and decodes
-    assert ticks[0].count("serving.prefill") == 2
+    # the first tick prefills (one chunk of 16 rows, 11 of them the
+    # prompt) and decodes
+    assert ticks[0].count("serving.prefill") == 1
     tick = next(a for a in fake_profiler if a.name == "serving.tick")
     assert tick.meta["prompt_tokens"] == 16
     assert tick.meta["decode_slots"] == 1 and tick.meta["queued"] == 1
@@ -241,10 +242,11 @@ def _lower_train_step(engine, batch=8, seq=16):
 
 
 def _lower_serving(replica, phase):
-    t = replica.block_size if phase == "prefill" else 1
-    b = replica.max_batch
+    # the prefill program carries one slot, the decode step every lane
+    b, t = ((1, replica.prefill_width) if phase == "prefill"
+            else (replica.max_batch, 1))
     host = (np.zeros((b, t), np.int32), np.full((b,), t, np.int32),
-            replica.tables, np.zeros((b,), np.float32),
+            replica.tables[:b], np.zeros((b,), np.float32),
             np.ones((b,), np.float32), np.zeros((b,), np.int32),
             np.zeros((b,), np.int32))
     return replica._fns[phase].lower(*replica._chunk_args(*host),
