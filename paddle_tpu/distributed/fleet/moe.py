@@ -14,6 +14,11 @@ combine one-hot einsums over global arrays —
   (tokens sharded on batch × experts sharded on E) makes XLA insert the
   all-to-all on ICI — no hand-written NCCL alltoall, and the routing is
   differentiable end-to-end by construction.
+
+This is the layer of the reference API (softmax gate, capacity factor,
+dropped tokens, per-expert sublayers). The dropless serving-side layer that
+holds a share of the experts as stacked weights is ``nn.LatentMoE``
+(``nn/layer/latent_moe.py``).
 """
 from __future__ import annotations
 
@@ -33,12 +38,35 @@ from .. import mesh as mesh_mod
 
 _m_expert_tokens = _metrics.counter(
     "paddle_tpu_moe_expert_tokens_total",
-    "Tokens routed (within capacity) per expert by eager MoE dispatch.",
+    "Tokens routed per expert: by eager MoE dispatch (within capacity), "
+    "and by a serving engine's compiled programs (read from their device "
+    "counters when health() or the metrics dump asks).",
     labelnames=("expert",))
 _m_load_imbalance = _metrics.gauge(
     "paddle_tpu_moe_load_imbalance",
-    "max/mean tokens-per-expert of the latest eager MoE dispatch "
+    "max/mean tokens-per-expert of the latest eager MoE dispatch, or of "
+    "a serving engine's worst expert layer since it started "
     "(1.0 = perfectly balanced).")
+_m_routed_pairs = _metrics.counter(
+    "paddle_tpu_moe_routed_pairs_total",
+    "(token, expert) pairs a serving engine's routers selected "
+    "(kind=selected) and those that landed on experts held here "
+    "(kind=held).", labelnames=("kind",))
+
+
+def stamp_expert_load(tokens_per_expert, first_expert: int, pairs_held,
+                      pairs_selected, imbalance):
+    """Export host-side expert-load counts read from a serving engine's
+    device counters (``PagedEngine.expert_load``)."""
+    if not _metrics.enabled():
+        return
+    for e, c in enumerate(tokens_per_expert):
+        if c > 0:
+            _m_expert_tokens.inc(float(c), expert=first_expert + e)
+    _m_routed_pairs.inc(float(pairs_held), kind="held")
+    _m_routed_pairs.inc(float(pairs_selected), kind="selected")
+    if imbalance is not None:
+        _m_load_imbalance.set(imbalance)
 
 
 def _stamp_expert_load(dispatch_mask: Tensor):

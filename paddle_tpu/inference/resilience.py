@@ -294,6 +294,11 @@ M_KV_BYTES_PER_TOKEN = _metrics.gauge(
     "Resident KV bytes one cached token costs across all layers "
     "(int8 page pools roughly halve this vs bf16 — the resident-batch "
     "multiplier).")
+M_STATE_BYTES = _metrics.gauge(
+    "paddle_tpu_serving_state_bytes",
+    "Resident bytes of the per-slot recurrent state (convolution windows, "
+    "SSM states) reserved for max_batch slots; 0 for a pure-attention "
+    "model.")
 M_REQUESTS = _metrics.counter(
     "paddle_tpu_serving_requests",
     "Requests reaching a terminal status, by outcome.",

@@ -137,7 +137,7 @@ class _LlamaArch:
         self.cfg = model.cfg
         self.num_kv_heads = model.cfg.num_kv_heads or model.cfg.num_heads
 
-    def forward_chunk(self, tokens, start, attend, logits_t: int = 1):
+    def forward_chunk(self, tokens, start, cache, logits_t: int = 1):
         from paddle_tpu import ops
         from ..models.llama import rotary_embedding
 
@@ -157,7 +157,7 @@ class _LlamaArch:
                 v = ops.reshape(blk.self_attn.v_proj(ln), [B, T, nkv, hd])
                 q = rotary_embedding(q, cfg.rope_theta, pos_offset=start)
                 k = rotary_embedding(k, cfg.rope_theta, pos_offset=start)
-                out = attend(li, q, k, v)
+                out = cache.attend(li, q, k, v)
                 x = x + blk.self_attn.o_proj(
                     ops.reshape(out, [B, T, nh * hd]))
             with jax.named_scope("mlp"):
@@ -181,7 +181,7 @@ class _GPTArch:
         self.num_kv_heads = model.cfg.num_heads
         self.max_positions = model.cfg.max_seq_len
 
-    def forward_chunk(self, tokens, start, attend, logits_t: int = 1):
+    def forward_chunk(self, tokens, start, cache, logits_t: int = 1):
         from paddle_tpu import ops
 
         m = self.model.gpt
@@ -206,7 +206,7 @@ class _GPTArch:
                 q = ops.reshape(q, [B, T, nh, hd])
                 k = ops.reshape(k, [B, T, nh, hd])
                 v = ops.reshape(v, [B, T, nh, hd])
-                out = attend(li, q, k, v)
+                out = cache.attend(li, q, k, v)
                 x = x + blk.attn.out_proj(
                     ops.reshape(out, [B, T, nh * hd]))
             with jax.named_scope("mlp"):
@@ -234,6 +234,11 @@ class _DenseArch:
 def _pick_arch(model):
     from ..models.gpt import GPTForCausalLM
     from ..models.llama import LlamaForCausalLM
+    if hasattr(model, "paged_adapter"):
+        # the protocol: a model brings its own adapter (``cfg``,
+        # ``num_kv_heads``, ``head_dim``, ``cache_layout(dtype)`` and
+        # ``forward_chunk(tokens, start, cache, logits_t)``)
+        return model.paged_adapter()
     if isinstance(model, LlamaForCausalLM):
         return _LlamaArch(model)
     if isinstance(model, GPTForCausalLM):
@@ -242,7 +247,8 @@ def _pick_arch(model):
         return _DenseArch(model)
     raise TypeError(
         f"PagedEngine supports LlamaForCausalLM / GPTForCausalLM (or "
-        f"subclasses) and dense-scoring models exposing serve_dense(); "
+        f"subclasses), models that bring a paged_adapter(), and "
+        f"dense-scoring models exposing serve_dense(); "
         f"got {type(model).__name__}")
 
 
@@ -330,6 +336,11 @@ _PAGED_JIT_CACHE: "weakref.WeakKeyDictionary" = weakref.WeakKeyDictionary()
 #: more than one sequence can hold.
 _PREFILL_WIDTH = 256
 
+#: seconds a ``health()`` probe may report the expert load it read last
+#: before it reads the device counters again (a probe polled every tick
+#: must not put a device read into every tick)
+_EXPERT_LOAD_POLL_S = 1.0
+
 
 def _request_keys(base_key, rids, ngens):
     """Per-slot sampling keys folded from (engine seed, request id, token
@@ -374,35 +385,124 @@ def _bind_params(params, param_arrays):
     return originals
 
 
-def _make_attend(kcs, vcs, tb_t, sl_t):
-    """Paged-attention closure over one chunk's cache state. Cache
-    entries are arrays (float pages) or (payload, scales) tuples (int8
-    pages) — the structure picks the kernel path at trace time."""
-    import paddle_tpu.nn.functional as F
+class _PagedCache:
+    """The one cache handle a model's ``forward_chunk`` sees for a chunk
+    of (B, T) tokens. The model's adapter declares per layer which kind of
+    state it keeps (``cache_layout``); the handle serves each kind:
 
-    def attend(li, q, k, v):
+    * ``attend(li, q, k, v)`` — paged K/V (attention layers): append the
+      chunk's K/V pages and attend over the slot's block table. Cache
+      entries are arrays (float pages) or (payload, scales) tuples (int8
+      pages) — the structure picks the kernel path at trace time.
+    * ``recur(li, fn)`` — per-SLOT state that does not grow with the
+      sequence (a convolution window, an SSM state): ``fn(state) -> (out,
+      new state)`` runs on the lanes' states. A lane whose chunk starts a
+      sequence (``start <= 0``) starts from zeros; a lane with no real row
+      (the ``seq = 0`` sentinel of mid-prefill and memory-stalled lanes)
+      gets its state back bit for bit. ``valid`` (B, T) marks the real
+      rows (left padding of a first chunk sits at negative positions).
+    * ``accumulate(li, delta)`` — a device-side counter carried with the
+      caches (expert load), read by the host only on request.
+
+    ``states`` is a flat list over the layers that keep slot state or a
+    counter; ``lanes`` (B,) maps the chunk's rows to slots (None: row i is
+    slot i, the decode batch)."""
+
+    def __init__(self, index, kcs, vcs, states, tables, seq_lens, start,
+                 lanes, width):
+        # layer -> (kind, position); None: K/V in every layer
+        self.index = index if index is not None else {
+            li: ("paged_kv", li) for li in range(len(kcs))}
+        self.kcs, self.vcs, self.states = kcs, vcs, list(states)
+        self.tables, self.seq_lens = Tensor(tables), Tensor(seq_lens)
+        self.start, self.lanes = start, lanes
+        self.valid = (start[:, None]
+                      + jnp.arange(width, dtype=start.dtype)[None, :]) >= 0
+
+    def attend(self, li, q, k, v):
+        import paddle_tpu.nn.functional as F
+
+        kcs, vcs = self.kcs, self.vcs
+        li = self.index[li][1]
         if isinstance(kcs[li], tuple):
             (kp, ksc), (vp, vsc) = kcs[li], vcs[li]
             out, nkp, nvp, nks, nvs = F.block_multihead_attention(
-                q, Tensor(kp), Tensor(vp), tb_t, sl_t,
+                q, Tensor(kp), Tensor(vp), self.tables, self.seq_lens,
                 new_k=k, new_v=v, causal=True,
                 k_scale=Tensor(ksc), v_scale=Tensor(vsc))
             kcs[li] = (nkp._data, nks._data)
             vcs[li] = (nvp._data, nvs._data)
         else:
             out, nkc, nvc = F.block_multihead_attention(
-                q, Tensor(kcs[li]), Tensor(vcs[li]), tb_t, sl_t,
-                new_k=k, new_v=v, causal=True)
+                q, Tensor(kcs[li]), Tensor(vcs[li]), self.tables,
+                self.seq_lens, new_k=k, new_v=v, causal=True)
             kcs[li] = nkc._data
             vcs[li] = nvc._data
         return out
 
-    return attend
+    def recur(self, li, fn):
+        at = self.index[li][1]
+        whole = self.states[at]            # {name: (max_batch, ...)}
+        lanes = self.lanes
+        if lanes is None:
+            mine = whole
+        else:
+            mine = {k: jnp.concatenate(
+                [jax.lax.dynamic_slice_in_dim(v, lanes[b], 1)
+                 for b in range(lanes.shape[0])]) for k, v in whole.items()}
+
+        def per_lane(flag, v):
+            return flag.reshape((-1,) + (1,) * (v.ndim - 1))
+
+        fresh = self.start <= 0
+        out, new = fn({k: jnp.where(per_lane(fresh, v), jnp.zeros_like(v), v)
+                       for k, v in mine.items()})
+        idle = ~jnp.any(self.valid, axis=1)
+        new = {k: jnp.where(per_lane(idle, v), mine[k], v.astype(mine[k].dtype))
+               for k, v in new.items()}
+        if lanes is None:
+            self.states[at] = new
+        else:
+            for k, v in new.items():
+                for b in range(lanes.shape[0]):
+                    whole[k] = jax.lax.dynamic_update_slice_in_dim(
+                        whole[k], v[b:b + 1], lanes[b], 0)
+            self.states[at] = whole
+        return out
+
+    def accumulate(self, li, delta):
+        at = self.index[li][1]
+        self.states[at] = self.states[at] + delta.astype(
+            self.states[at].dtype)
+
+
+def _max_over_mean(tokens):
+    """Per row of host counts the largest over the mean (None for a row
+    of zeros): 1.0 is a perfectly even load."""
+    return [float(row.max() / row.mean()) if row.any() else None  # tpulint: disable=TPU103 — host numpy totals
+            for row in tokens]
+
+
+def _cache_index(layout):
+    """layer -> (kind, position among the layers of its kind's list):
+    paged K/V layers index ``kcs`` / ``vcs``, slot-state and accumulator
+    layers share the flat ``states`` list."""
+    index, pages, states = {}, 0, 0
+    for li, entry in enumerate(layout):
+        if entry is None:
+            continue
+        if entry[0] == "paged_kv":
+            index[li] = ("paged_kv", pages)
+            pages += 1
+        else:
+            index[li] = (entry[0], states)
+            states += 1
+    return index
 
 
 def _paged_forward(arch, params, param_arrays, kcs, vcs, tokens, seq_lens,
-                   tables, temps, top_ps, rids, ngens, base_key,
-                   sampling: bool = False):
+                   tables, temps, top_ps, rids, ngens, base_key, states=(),
+                   lanes=None, sampling: bool = False, index=None):
     """One chunk for a (B, T) token batch; returns (next-token ids, new
     caches). Traced under jit. A module-level function (arch + params
     pre-bound via functools.partial) so the shared jit cache holds only
@@ -413,19 +513,20 @@ def _paged_forward(arch, params, param_arrays, kcs, vcs, tokens, seq_lens,
     try:
         B, T = tokens.shape
         start = seq_lens - T
-        attend = _make_attend(kcs, vcs, Tensor(tables), Tensor(seq_lens))
-        logits = arch.forward_chunk(tokens, start, attend)
+        cache = _PagedCache(index, kcs, vcs, states, tables, seq_lens,
+                            start, lanes, T)
+        logits = arch.forward_chunk(tokens, start, cache)
         nxt = _sample_tokens(logits._data[:, -1, :], temps, top_ps,
                              base_key, rids, ngens, sampling)
-        return nxt.astype(jnp.int32), kcs, vcs
+        return nxt.astype(jnp.int32), kcs, vcs, cache.states
     finally:
         for p, o in zip(params, originals):
             p._data = o
 
 
 def _paged_verify(arch, params, param_arrays, kcs, vcs, tokens, seq_lens,
-                  tables, temps, top_ps, rids, ngens, base_key,
-                  max_accept, sampling: bool = False):
+                  tables, temps, top_ps, rids, ngens, base_key, states,
+                  max_accept, sampling: bool = False, index=None):
     """Speculative verify: one (B, k+1) forward over [last_token, k
     draft tokens] per slot, greedy accept-prefix in-graph — draft
     append, target forward, and acceptance are ONE compiled program with
@@ -440,8 +541,9 @@ def _paged_verify(arch, params, param_arrays, kcs, vcs, tokens, seq_lens,
     try:
         B, T = tokens.shape
         start = seq_lens - T
-        attend = _make_attend(kcs, vcs, Tensor(tables), Tensor(seq_lens))
-        logits = arch.forward_chunk(tokens, start, attend, logits_t=T)
+        cache = _PagedCache(index, kcs, vcs, states, tables, seq_lens,
+                            start, None, T)
+        logits = arch.forward_chunk(tokens, start, cache, logits_t=T)
         lg = logits._data                      # (B, T, V)
         greedy = jnp.argmax(lg, axis=-1).astype(jnp.int32)
         first = _sample_tokens(lg[:, 0, :], temps, top_ps,
@@ -451,7 +553,8 @@ def _paged_verify(arch, params, param_arrays, kcs, vcs, tokens, seq_lens,
              greedy[:, 1:]], axis=1)
         n_emit, _accepted = spec_accept_prefix(
             tokens[:, 1:], greedy, max_accept)
-        return emit.astype(jnp.int32), n_emit.astype(jnp.int32), kcs, vcs
+        return (emit.astype(jnp.int32), n_emit.astype(jnp.int32), kcs, vcs,
+                cache.states)
     finally:
         for p, o in zip(params, originals):
             p._data = o
@@ -514,7 +617,8 @@ class PagedEngine:
             self.head_dim = 0
             nkv = 0
         else:
-            self.head_dim = cfg.hidden_size // cfg.num_heads
+            self.head_dim = getattr(self.arch, "head_dim", None) or (
+                cfg.hidden_size // cfg.num_heads)
             nkv = self.arch.num_kv_heads
         self.num_kv_heads = nkv
 
@@ -555,21 +659,40 @@ class PagedEngine:
         self._kv_int8 = (kv_dtype == "int8"
                          or (kv_dtype is not None
                              and jnp.dtype(kv_dtype) == jnp.int8))
+        compute_dtype = next(
+            (p._data.dtype for p in model.parameters()
+             if jnp.issubdtype(p._data.dtype, jnp.floating)), jnp.float32)
         if self._kv_int8:
             kv_dtype = jnp.int8
         elif kv_dtype is None:
-            kv_dtype = next(
-                (p._data.dtype for p in model.parameters()
-                 if jnp.issubdtype(p._data.dtype, jnp.floating)),
-                jnp.float32)
+            kv_dtype = compute_dtype
         self.kv_dtype = jnp.dtype(kv_dtype)
         self._kv_shape = (num_blocks, block_size, nkv, self.head_dim)
         self._kv_scale_shape = (num_blocks, block_size, nkv)
+        # ---- the cache states, one declaration a layer: ``paged_kv``
+        # (a K and a V page pool, attention layers), ``slot_state``
+        # (arrays [max_batch, ...] that do not grow with the sequence: a
+        # recurrent layer's window and state) or ``accumulator`` (a
+        # device-side counter). A model's own adapter declares them; Llama
+        # and GPT keep K/V in every layer. The dense path keeps none.
         if self._dense:
-            self.kc, self.vc = [], []     # no KV state on the dense path
+            self._layout = []
+        elif hasattr(self.arch, "cache_layout"):
+            self._layout = list(self.arch.cache_layout(compute_dtype))
         else:
-            self.kc = [self._fresh_cache() for _ in range(cfg.num_layers)]
-            self.vc = [self._fresh_cache() for _ in range(cfg.num_layers)]
+            self._layout = [("paged_kv",)] * cfg.num_layers
+        self._cache_index = _cache_index(self._layout)
+        self._has_slot_state = any(
+            e is not None and e[0] == "slot_state" for e in self._layout)
+        if self._has_slot_state and speculate is not None:
+            raise TypeError(
+                "speculate= needs a state rollback this engine does not "
+                "have: a verify step feeds k draft tokens through the "
+                "recurrent layers, and a rejected draft would have to take "
+                "its update of the per-slot state (conv window, SSM state) "
+                "back. Serve a model with slot_state layers without "
+                "speculate=.")
+        self.kc, self.vc, self.state = self._fresh_caches()
 
         self.tables = np.zeros((max_batch, max_blocks_per_seq), np.int32)
         self.seq_lens = np.ones((max_batch,), np.int32)  # idle: len 1
@@ -602,7 +725,9 @@ class PagedEngine:
             fn = cache.get((arch_key, kind))
             if fn is None:
                 bound = functools.partial(forward, self.arch,
-                                          tuple(self._params))
+                                          tuple(self._params),
+                                          **({} if self._dense else
+                                             {"index": self._cache_index}))
                 bound.__name__ = kind
                 fn = cache[(arch_key, kind)] = jax.jit(bound, **jit_kw)
             return fn
@@ -611,7 +736,9 @@ class PagedEngine:
             self._dense_fn = program("dense_forward", _dense_forward)
             self._fns = self._vfn = None
         else:
-            paged = dict(donate_argnums=(1, 2),
+            # the K/V pools and the slot states are donated: every program
+            # updates them in place
+            paged = dict(donate_argnums=(1, 2, 11),
                          static_argnames=("sampling",))
             self._fns = {
                 "prefill": program("paged_prefill_chunk", _paged_forward,
@@ -650,6 +777,10 @@ class PagedEngine:
         _perf_memory.register_object("kv_cache", self,
                                      lambda e: (e.kc, e.vc))
         _res.M_KV_BYTES_PER_TOKEN.set(self.kv_bytes_per_token)
+        _res.M_STATE_BYTES.set(self.state_bytes_per_slot * max_batch)
+        #: host-side totals of the expert-load accumulators, one row a
+        #: layer that has one (see ``expert_load``)
+        self._expert_load, self._expert_load_t = None, 0.0
         # fleet telemetry: this replica's health() rides every
         # fleet.snapshot(), so a multi-replica router polls one endpoint
         # per rank (weakly held — a dropped engine unregisters itself)
@@ -664,6 +795,27 @@ class PagedEngine:
                     jnp.zeros(self._kv_scale_shape, jnp.float32))
         return jnp.zeros(self._kv_shape, self.kv_dtype)
 
+    def _fresh_caches(self):
+        """``(kc, vc, state)`` zeroed: a K and a V pool per ``paged_kv``
+        layer, and per ``slot_state`` / ``accumulator`` layer its arrays
+        (in the order of ``_cache_index``)."""
+        kc, vc, state = [], [], []
+        for entry in self._layout:
+            if entry is None:
+                continue
+            if entry[0] == "paged_kv":
+                kc.append(self._fresh_cache())
+                vc.append(self._fresh_cache())
+            elif entry[0] == "slot_state":
+                state.append({
+                    name: jnp.zeros((self.max_batch,) + tuple(shape), dtype)
+                    for name, (shape, dtype) in entry[1].items()})
+            elif entry[0] == "accumulator":
+                state.append(jnp.zeros(tuple(entry[1]), entry[2]))
+            else:
+                raise ValueError(f"unknown cache state kind {entry[0]!r}")
+        return kc, vc, state
+
     @property
     def kv_bytes_per_token(self) -> int:
         """Resident KV bytes one cached token costs across all layers
@@ -673,7 +825,68 @@ class PagedEngine:
         per = self.num_kv_heads * self.head_dim * self.kv_dtype.itemsize
         if self._kv_int8:
             per += self.num_kv_heads * 4          # sidecar fp32 scale
-        return 2 * self.cfg.num_layers * per      # K and V
+        return 2 * len(self.kc) * per     # K and V, attention layers only
+
+    @property
+    def state_bytes_per_slot(self) -> int:
+        """Resident bytes one slot costs in the layers that keep state
+        per slot, not per token (0 for a pure-attention model): it does
+        not grow with the sequence and is reserved for ``max_batch``
+        slots whether they are in use or not."""
+        return sum(int(np.prod(shape)) * jnp.dtype(dtype).itemsize
+                   for entry in self._layout
+                   if entry is not None and entry[0] == "slot_state"
+                   for shape, dtype in entry[1].values())
+
+    def expert_load(self, max_age_s: float = 0.0) -> Optional[dict]:
+        """Expert load since the engine started, from the counters the
+        compiled programs keep on the device (``accumulator`` layers): per
+        expert layer the tokens each held expert received, the (token,
+        expert) pairs that landed on held experts and the pairs selected.
+        THE host read of those counters: nothing reads them during a tick.
+        Each read moves the device counts into host totals (so the int32
+        counters never run over) and exports the difference as
+        ``paddle_tpu_moe_*``. A caller that polls (``health()``) passes
+        ``max_age_s`` and gets the last reading while it is younger than
+        that. None for a model with no such layer."""
+        at = [(li, pos) for li, (kind, pos) in self._cache_index.items()
+              if kind == "accumulator"]
+        if not at:
+            return None
+        now = time.monotonic()
+        if (self._expert_load is None
+                or now - self._expert_load_t >= max_age_s):
+            self._expert_load_t = now
+            self._read_expert_counters([pos for _li, pos in at])
+        total = self._expert_load
+        tokens = total[:, :-2]
+        # host numpy: the totals left the device in _read_expert_counters
+        return {"layers": [li for li, _pos in at],
+                "tokens": tokens.tolist(),  # tpulint: disable=TPU102 — host numpy totals
+                "pairs_held": total[:, -2].tolist(),  # tpulint: disable=TPU102 — host numpy totals
+                "pairs_selected": total[:, -1].tolist(),  # tpulint: disable=TPU102 — host numpy totals
+                "max_over_mean": _max_over_mean(tokens)}
+
+    def _read_expert_counters(self, positions):
+        """Move the device counters into ``_expert_load`` (host int64
+        totals), zero them, export the difference."""
+        from ..distributed.fleet import moe as _moe
+
+        # one transfer, no compiled op: a probe must not compile
+        fresh = np.stack(jax.device_get(  # tpulint: disable=TPU104 — telemetry-by-design: the counters' one host read, on request only
+            [self.state[pos] for pos in positions])).astype(np.int64)
+        for pos in positions:
+            self.state[pos] = jax.device_put(
+                np.zeros(self.state[pos].shape, self.state[pos].dtype))
+        self._expert_load = fresh if self._expert_load is None \
+            else self._expert_load + fresh
+        worst = [v for v in _max_over_mean(self._expert_load[:, :-2])
+                 if v is not None]
+        _moe.stamp_expert_load(
+            fresh[:, :-2].sum(axis=0),
+            getattr(self.cfg, "experts_held", (0, 0))[0],
+            fresh[:, -2].sum(), fresh[:, -1].sum(),
+            max(worst, default=None))
 
     # ------------------------------------------------- request tracing
     @property
@@ -776,7 +989,8 @@ class PagedEngine:
                 jnp.asarray(temps_np, jnp.float32),
                 jnp.asarray(top_ps_np, jnp.float32),
                 jnp.asarray(rids_np, jnp.int32),
-                jnp.asarray(ngens_np, jnp.int32), self._base_key)
+                jnp.asarray(ngens_np, jnp.int32), self._base_key,
+                self.state)
 
     def _call_program(self, phase, fn, host_args, extra=(), **span_args):
         """One program call under its boundary spans: ``serving.<phase>``
@@ -803,7 +1017,7 @@ class PagedEngine:
                 args = self._chunk_args(*host_args) + tuple(
                     jnp.asarray(a, jnp.int32) for a in extra)
             with _trace.boundary(f"serving.{phase}.launch"):
-                *outs, self.kc, self.vc = fn(
+                *outs, self.kc, self.vc, self.state = fn(
                     *args,
                     sampling=bool(np.any(np.asarray(temps_np) > 0)))
             with _trace.boundary(f"serving.{phase}.wait"):
@@ -820,11 +1034,17 @@ class PagedEngine:
 
     def _run_chunk(self, tokens_np, seq_lens_np, tables_np,
                    temps_np, top_ps_np, rids_np, ngens_np,
-                   phase: str = "decode"):
+                   phase: str = "decode", lanes=None):
+        """``lanes``: the slots the chunk's rows belong to, for the layers
+        that keep state per slot (a prefill chunk carries one slot's rows;
+        the decode batch's row i is slot i and passes none). An engine
+        with no such layer never sends them."""
+        extra = (lanes,) if lanes is not None and self._has_slot_state \
+            else ()
         (out,) = self._call_program(
             phase, self._fns[phase],
             (tokens_np, seq_lens_np, tables_np, temps_np, top_ps_np,
-             rids_np, ngens_np))
+             rids_np, ngens_np), extra=extra)
         return out
 
     def _run_verify(self, tokens_np, seq_lens_np, tables_np, temps_np,
@@ -930,7 +1150,8 @@ class PagedEngine:
             slot, chunk, final, rows = plan
             st = self._prefilling[slot]
             req = self.slots[slot]
-            (tok,) = self._run_chunk(*rows, phase="prefill")
+            (tok,) = self._run_chunk(*rows, phase="prefill",
+                                     lanes=np.asarray([slot], np.int32))
             self.scheduler.note_prompt_tokens(
                 width - (st["pad"] if chunk == 0 else 0))
             self._tick_work["prompt_tokens"] += width
@@ -1469,14 +1690,13 @@ class PagedEngine:
             except Exception:
                 self.slots[slot] = None   # never mask the containment
             self._finish_request(req, RequestStatus.FAILED, detail=detail)
-        # the decode call DONATES kc/vc: a crash inside the executable
+        # the decode call DONATES kc/vc/state: a crash inside the executable
         # may have invalidated those buffers with the new ones never
         # assigned. Reallocate fresh pages — every slot was discarded
         # above, so later admissions re-prefill from their prompts; a
         # stale-buffer engine would otherwise fail every future tick
         # while still admitting.
-        self.kc = [self._fresh_cache() for _ in range(self.cfg.num_layers)]
-        self.vc = [self._fresh_cache() for _ in range(self.cfg.num_layers)]
+        self.kc, self.vc, self.state = self._fresh_caches()
         self.lifecycle.degrade(detail)
 
     def _drain_done(self) -> Dict[int, List[int]]:
@@ -1696,6 +1916,7 @@ class PagedEngine:
              "kv_blocks_total": self._total_usable,
              "kv_dtype": str(self.kv_dtype),
              "kv_bytes_per_token": self.kv_bytes_per_token,
+             "state_bytes_per_slot": self.state_bytes_per_slot,
              "ticks": self._ticks,
              "tick_failures": self.tick_failures,
              "phase_share": self.scheduler.phase_share(),
@@ -1708,6 +1929,9 @@ class PagedEngine:
             h["spec_acceptance_rate"] = (
                 self.spec_accepted / self.spec_proposed
                 if self.spec_proposed else None)
+        load = self.expert_load(max_age_s=_EXPERT_LOAD_POLL_S)
+        if load is not None:
+            h["expert_load"] = load
         return h
 
 

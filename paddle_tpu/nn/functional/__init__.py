@@ -13,7 +13,9 @@ from .vision import *      # noqa: F401,F403
 from .paged_attention import *  # noqa: F401,F403
 from .fused import *       # noqa: F401,F403
 from .tail import *        # noqa: F401,F403
+from .ssm import *         # noqa: F401,F403
+from .experts import *     # noqa: F401,F403
 from ...ops.search import class_center_sample, gather_tree  # noqa: F401
 
-from . import (activation, common, conv, flash_attention, fused, loss,
-               norm, paged_attention, pooling, tail, vision)
+from . import (activation, common, conv, experts, flash_attention, fused,
+               loss, norm, paged_attention, pooling, ssm, tail, vision)

@@ -19,3 +19,4 @@ from .transformer import (MultiHeadAttention, Transformer, TransformerDecoder,
 from .rnn import (RNN, BiRNN, GRU, GRUCell, LSTM, LSTMCell, RNNCellBase,
                   SimpleRNN, SimpleRNNCell)
 from .tail import *        # noqa: F401,F403
+from .latent_moe import LatentMoE

@@ -110,6 +110,13 @@ def _install_exit_dump():
         except Exception:
             pass
         try:
+            # live serving replicas move their device-side counters
+            # (expert load) into the registry when their health is read
+            from . import fleet as _fleet
+            _fleet.replica_health()
+        except Exception:
+            pass
+        try:
             with open(_dump_path(path), "w") as f:
                 json.dump(REGISTRY.snapshot(), f, indent=1, sort_keys=True)
         except OSError:
