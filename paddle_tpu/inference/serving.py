@@ -21,7 +21,10 @@ free list, and admission/eviction is plain Python between ticks:
   prompt's last token;
 * decode runs ALL active slots in one (B, 1) step; idle slots point at a
   reserved trash block so the compiled program never branches on
-  occupancy. With ``speculate=`` the decode step becomes a speculative
+  occupancy. Its attention is the ``paged_decode_attn`` Pallas kernel
+  where the step's shapes allow (``health()["decode_attention"]``): each
+  lane reads the pages it holds, not its whole block table. With
+  ``speculate=`` the decode step becomes a speculative
   verify: draft tokens appended to the feed, one (B, k+1) forward, and
   the accept-prefix rule in-graph — still ONE compiled program, now
   yielding up to k+1 tokens per request per tick;
@@ -70,6 +73,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..core.tensor import Tensor
+from ..nn.functional.paged_attention import log_paths as _attention_paths
 from ..observability import reqtrace as _reqtrace
 from ..observability import trace as _trace
 from .resilience import (Overloaded, ReplicaLifecycle, ReplicaState,
@@ -257,9 +261,12 @@ def _tuned_decode_block_size(cfg, nkv, max_batch, max_blocks_per_seq,
     """Measured KV page size for the decode tick on this chip.
 
     Probes one paged-attention decode step (T=1, full batch) per
-    candidate on zero caches sized to the engine's real geometry; the
-    winner persists in the autotune cache (ops/pallas/autotune.py), so
-    one process per chip ever pays the probe. Off-TPU: 16.
+    candidate on zero caches sized to the engine's real geometry — through
+    ``block_multihead_attention`` itself, so each candidate is timed on
+    the path an engine built with it would run (the decode kernel where a
+    page fills whole sublane tiles, else the composite); the winner
+    persists in the autotune cache (ops/pallas/autotune.py), so one
+    process per chip ever pays the probe. Off-TPU: 16.
     """
     from ..ops.pallas import autotune as at
 
@@ -714,6 +721,11 @@ class PagedEngine:
         import functools
         cache = _PAGED_JIT_CACHE.setdefault(model, {})
         arch_key = type(self.arch).__name__
+        # what the attention layers of each program were lowered to: kept
+        # beside the compiled programs, because an engine that shares them
+        # traces none of its own
+        self._attention_lowered = cache.setdefault((arch_key, "attention"),
+                                                   {})
 
         def program(kind, forward, **jit_kw):
             """The shared jit wrapper of one program kind. ``kind`` is the
@@ -837,6 +849,23 @@ class PagedEngine:
                    for entry in self._layout
                    if entry is not None and entry[0] == "slot_state"
                    for shape, dtype in entry[1].values())
+
+    def _program_key(self, phase, tokens_shape):
+        return (phase, tuple(tokens_shape), self._kv_shape,
+                self.kv_dtype.name)
+
+    @property
+    def decode_attention(self) -> Optional[str]:
+        """``"kernel"`` or ``"composite"``: what the attention layers of the
+        decode program were lowered to when it was traced (``nn.functional.
+        paged_attention.log_paths`` around that call; one query a lane,
+        ``speculate_k + 1`` under ``speculate=``). None until then, and for
+        the dense path, which attends to no cache."""
+        if self._dense:
+            return None
+        t = 1 if self._spec is None else self._spec_k + 1
+        return self._attention_lowered.get(
+            self._program_key("decode", (self.max_batch, t)))
 
     def expert_load(self, max_age_s: float = 0.0) -> Optional[dict]:
         """Expert load since the engine started, from the counters the
@@ -1016,10 +1045,14 @@ class PagedEngine:
                 t0 = time.perf_counter()
                 args = self._chunk_args(*host_args) + tuple(
                     jnp.asarray(a, jnp.int32) for a in extra)
-            with _trace.boundary(f"serving.{phase}.launch"):
+            with _trace.boundary(f"serving.{phase}.launch"), \
+                    _attention_paths() as lowered:
                 *outs, self.kc, self.vc, self.state = fn(
                     *args,
                     sampling=bool(np.any(np.asarray(temps_np) > 0)))
+            if lowered:     # this call traced the program
+                self._attention_lowered[self._program_key(
+                    phase, tokens_np.shape)] = "+".join(sorted(set(lowered)))
             with _trace.boundary(f"serving.{phase}.wait"):
                 # np.asarray blocks until the program finishes, so the
                 # serving.<phase> bracket bounds the chunk's device
@@ -1917,6 +1950,7 @@ class PagedEngine:
              "kv_dtype": str(self.kv_dtype),
              "kv_bytes_per_token": self.kv_bytes_per_token,
              "state_bytes_per_slot": self.state_bytes_per_slot,
+             "decode_attention": self.decode_attention,
              "ticks": self._ticks,
              "tick_failures": self.tick_failures,
              "phase_share": self.scheduler.phase_share(),

@@ -29,8 +29,10 @@ import math
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
+
+from . import import_pallas
+
+pl, pltpu = import_pallas()
 
 NEG_INF = -1e30
 

@@ -38,8 +38,10 @@ import math
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
+
+from . import import_pallas
+
+pl, pltpu = import_pallas()
 
 #: run kernels through the Pallas interpreter (CPU testing of kernel code)
 INTERPRET = False

@@ -4,12 +4,13 @@ Siblings to :mod:`.fused_ops`, but these are the serving tier's hot
 inner loops (reference: the block_multi_head_attention serving family in
 phi/kernels/fusion/ plus PaddleNLP's speculative-decoding verify step).
 Both are expressed as pure jnp/lax composites so they fuse into the ONE
-jitted engine tick — the paged gather/scatter shapes here are exactly
-the ones XLA already lays out well on TPU (vectorized int8<->fp convert
-on the VPU, the scale multiply folded into the attention einsum's
-prologue), so no hand-written Mosaic kernel is warranted yet; when the
-fused ``block_multi_head_attention`` Pallas kernel lands (ROADMAP
-roofline item) these helpers define its quantized-page ABI.
+jitted engine tick — the int8<->fp convert is vectorized on the VPU and the
+scale multiply folds into the composite attention's einsum prologue. The
+hand-written Mosaic kernel of this tier is the decode step's attention,
+:mod:`.paged_attention` (``paged_decode_attn``: float pages, one new token
+a lane); int8 pages, chunked prefill and the speculative verify step still
+attend through the composite in ``nn.functional.paged_attention``, and
+these helpers define the quantized-page ABI a kernel for them would read.
 
 * ``kv_quantize_int8`` / ``kv_dequantize_int8`` — symmetric per-token,
   per-KV-head abs-max int8 over the head dim (the ``nn/quant``

@@ -15,6 +15,10 @@ from paddle_tpu.distributed import (Partial, ProcessMesh, Replicate, Shard,
 
 @pytest.fixture(autouse=True)
 def _mesh():
+    # the default group remembers the mesh it was first asked for under: one
+    # left by an earlier file on this worker (another mesh shape) would make
+    # every dp-sharded payload below look replicated
+    dist.destroy_process_group()
     dist.set_mesh(dist.build_mesh({"dp": 8}))
     yield
 

@@ -227,3 +227,289 @@ class TestPagedAttention:
                                        atol=2e-5)
         finally:
             mesh_mod.set_mesh(prev)  # restore exactly, including None
+
+
+# --------------------------------------------------------------------------
+# The decode kernel (ops/pallas/paged_attention.py) through the Pallas
+# interpreter: the same public function, T = 1 with new_k / new_v, shapes the
+# kernel was written for (D = 128, block 16).
+# --------------------------------------------------------------------------
+from paddle_tpu.ops.pallas import paged_attention as PK  # noqa: E402
+
+# (H, KVH): Mistral's grouping (group 4) and the hybrid's (group 16)
+GROUPINGS = [pytest.param((32, 8), id="g4-kvh8"),
+             pytest.param((32, 2), id="g16-kvh2")]
+
+
+@pytest.fixture
+def kernel_on(monkeypatch):
+    monkeypatch.setattr(PK, "INTERPRET", True)
+
+
+def _decode(q, kc, vc, tables, lens, nk, nv, dtype="float32", **kw):
+    """One write-path decode call; numpy float32 back."""
+    t = lambda a: paddle.to_tensor(np.asarray(a)).astype(dtype)  # noqa: E731
+    out, kc2, vc2 = F.block_multihead_attention(
+        t(q), t(kc), t(vc), paddle.to_tensor(tables),
+        paddle.to_tensor(np.asarray(lens, np.int32)),
+        new_k=t(nk), new_v=t(nv), **kw)
+    return tuple(np.asarray(x.astype("float32").numpy())
+                 for x in (out, kc2, vc2))
+
+
+def _runs_kernel(fn, *args) -> bool:
+    """What ``fn``'s one attention call is lowered to for these arguments."""
+    import jax
+    from paddle_tpu.nn.functional.paged_attention import log_paths
+    with log_paths() as seen:
+        jax.jit(fn).lower(*args)
+    assert len(seen) == 1, seen
+    return seen[0] == "kernel"
+
+
+def _decode_case(rng, lens, H, KVH, mb, D=128, bs=16):
+    """Histories of ``len - 1`` tokens in shuffled pages plus the step's new
+    token; returns the call's inputs and each lane's full K / V."""
+    hist = [max(l - 1, 0) for l in lens]
+    kc, vc, tables, ks, vs = _build_cache(rng, hist, bs, H, KVH, D, mb)
+    B = len(lens)
+    q = rng.randn(B, 1, H, D).astype(np.float32)
+    nk = rng.randn(B, 1, KVH, D).astype(np.float32)
+    nv = rng.randn(B, 1, KVH, D).astype(np.float32)
+    full = [(np.concatenate([ks[b], nk[b]]), np.concatenate([vs[b], nv[b]]))
+            for b in range(B)]
+    return q, kc, vc, tables, nk, nv, full
+
+
+class TestDecodeKernel:
+    @pytest.mark.parametrize("dtype,atol", [("float32", 2e-5),
+                                            ("bfloat16", 2e-2)])
+    @pytest.mark.parametrize("heads", GROUPINGS)
+    def test_matches_composite_and_dense(self, monkeypatch, heads, dtype,
+                                         atol):
+        """Lanes of length 1, exactly one block, one block + 1, the whole
+        table, 0 (the sentinel of mid-prefill and stalled lanes) and a
+        length that ends mid-chunk."""
+        H, KVH = heads
+        mb, bs = 12, 16
+        lens = [1, bs, bs + 1, mb * bs, 0, 150]
+        q, kc, vc, tables, nk, nv, full = _decode_case(
+            np.random.RandomState(10), lens, H, KVH, mb)
+        if dtype == "bfloat16":     # the values the pools really hold
+            import jax.numpy as jnp
+            r = lambda a: np.asarray(jnp.asarray(a, jnp.bfloat16)  # noqa: E731
+                                     .astype(jnp.float32))
+            q, kc, vc, nk, nv = map(r, (q, kc, vc, nk, nv))
+            full = [(r(k), r(v)) for k, v in full]
+        want = _decode(q, kc, vc, tables, lens, nk, nv, dtype)
+        monkeypatch.setattr(PK, "INTERPRET", True)
+        got = _decode(q, kc, vc, tables, lens, nk, nv, dtype)
+        # pools bit for bit what the composite leaves, the sentinel lane's
+        # blocks untouched
+        np.testing.assert_array_equal(got[1], want[1])
+        np.testing.assert_array_equal(got[2], want[2])
+        np.testing.assert_array_equal(got[1][tables[4]], kc[tables[4]])
+        np.testing.assert_allclose(got[0], want[0], atol=atol)
+        np.testing.assert_array_equal(got[0][4], 0.0)
+        for b, L in enumerate(lens):
+            if L:
+                ref = _dense_attn(q[b], full[b][0], full[b][1], L - 1)
+                np.testing.assert_allclose(got[0][b], ref, atol=atol)
+
+    @pytest.mark.parametrize("heads", GROUPINGS)
+    def test_entries_past_the_length_are_never_read(self, kernel_on, heads):
+        """Table entries past a lane's pages point at another lane's live
+        blocks and at a block of NaN; the sentinel lane's whole table does:
+        neither may reach an output (0 x NaN is NaN)."""
+        H, KVH = heads
+        mb, bs = 6, 16
+        lens = [20, 70, 0]
+        q, kc, vc, tables, nk, nv, full = _decode_case(
+            np.random.RandomState(11), lens, H, KVH, mb)
+        poison = max(set(range(kc.shape[0])) - set(tables.ravel().tolist()))
+        kc[poison] = vc[poison] = np.nan
+        tables[0, 2:] = [tables[1, 0], tables[1, 1], poison, poison]
+        tables[1, 5] = poison
+        tables[2, :] = poison
+        out, _, _ = _decode(q, kc, vc, tables, lens, nk, nv)
+        assert np.isfinite(out).all()
+        np.testing.assert_array_equal(out[2], 0.0)
+        for b in (0, 1):
+            ref = _dense_attn(q[b], full[b][0], full[b][1], lens[b] - 1)
+            np.testing.assert_allclose(out[b], ref, atol=2e-5)
+
+    @pytest.mark.parametrize("heads", GROUPINGS)
+    def test_new_token_is_visible_to_its_own_query(self, kernel_on, heads):
+        """A lane of length 1 attends to nothing but the token this call
+        writes: each query head gets its kv head's new V back."""
+        H, KVH = heads
+        q, kc, vc, tables, nk, nv, _ = _decode_case(
+            np.random.RandomState(12), [1, 1], H, KVH, 2)
+        out, _, _ = _decode(q, kc, vc, tables, [1, 1], nk, nv)
+        want = np.repeat(nv[:, 0], H // KVH, axis=1).reshape(2, 1, H, 128)
+        np.testing.assert_allclose(out, want, atol=1e-6)
+
+    @pytest.mark.parametrize("heads", GROUPINGS)
+    def test_jitted_decode_loop_matches_full_context(self, kernel_on, heads):
+        """``test_jitted_decode_loop_matches_full_context`` with the kernel
+        on: one jitted step, pools donated, a token a call across two page
+        boundaries."""
+        import jax
+        rng = np.random.RandomState(13)
+        H, KVH = heads
+        D, bs, mb, S = 128, 16, 3, 35
+        ks = rng.randn(S, KVH, D).astype(np.float32)
+        vs = rng.randn(S, KVH, D).astype(np.float32)
+        qs = rng.randn(S, H, D).astype(np.float32)
+        tables = np.arange(1, mb + 1, dtype=np.int32)[None]
+
+        def step(q, kc, vc, n, nk, nv):
+            out, kc, vc = F.block_multihead_attention(
+                paddle.Tensor(q), paddle.Tensor(kc), paddle.Tensor(vc),
+                paddle.to_tensor(tables), paddle.Tensor(n),
+                new_k=paddle.Tensor(nk), new_v=paddle.Tensor(nv))
+            return out._data, kc._data, vc._data
+
+        kc = np.zeros((mb + 1, bs, KVH, D), np.float32)
+        assert _runs_kernel(step, qs[None, :1], kc, kc, np.asarray([1]),
+                            ks[None, :1], vs[None, :1])
+        jitted = jax.jit(step, donate_argnums=(1, 2))
+        kc_d, vc_d = jax.numpy.asarray(kc), jax.numpy.asarray(kc)
+        outs = []
+        for t in range(S):
+            out, kc_d, vc_d = jitted(qs[None, t:t + 1], kc_d, vc_d,
+                                     np.asarray([t + 1], np.int32),
+                                     ks[None, t:t + 1], vs[None, t:t + 1])
+            outs.append(np.asarray(out)[0, 0])
+        np.testing.assert_allclose(np.stack(outs), _dense_attn(qs, ks, vs, 0),
+                                   atol=2e-5)
+
+    @pytest.mark.parametrize("case,kernel", [
+        ("decode", True), ("decode-bf16", True),
+        ("verify-t2", False), ("int8-pages", False), ("read-only", False),
+        ("head-dim-64", False), ("block-4", False),
+        ("pools-of-another-dtype", False), ("no-tpu-no-interpreter", False),
+        ("pallas-kernels-off", False), ("pools-over-two-devices", False),
+        ("manual-over-two-devices", True)])
+    def test_dispatch_rule(self, monkeypatch, case, kernel):
+        """What the call can see decides: its shapes, dtypes and backend,
+        and, where it is lowered, whether the compiler would have to
+        partition it (it cannot partition a Mosaic kernel)."""
+        import jax
+        import jax.numpy as jnp
+        from paddle_tpu.core import flags
+
+        if case != "no-tpu-no-interpreter":
+            monkeypatch.setattr(PK, "INTERPRET", True)
+        B, T, H, KVH, D, bs, mb = 2, 1, 32, 8, 128, 16, 2
+        dt = pool_dt = jnp.float32
+        kw = {}
+        if case == "decode-bf16":
+            dt = pool_dt = jnp.bfloat16
+        elif case == "verify-t2":
+            T = 2
+        elif case == "head-dim-64":
+            D = 64
+        elif case == "block-4":
+            bs, KVH = 4, 1              # a page of 4 rows: not a whole tile
+        elif case == "pools-of-another-dtype":
+            pool_dt = jnp.bfloat16
+        elif case == "pallas-kernels-off":
+            flags.set_flags({"use_pallas_kernels": False})
+        nb = B * mb + 1
+        q = jnp.ones((B, T, H, D), dt)
+        kc = jnp.zeros((nb, bs, KVH, D), pool_dt)
+        new = jnp.ones((B, T, KVH, D), pool_dt)
+        tables = jnp.arange(1, nb, dtype=jnp.int32).reshape(B, mb)
+        lens = jnp.asarray([5, 9], jnp.int32)
+        if case == "int8-pages":
+            kc = kc.astype(jnp.int8)
+            scales = jnp.ones((nb, bs, KVH), jnp.float32)
+            kw = dict(k_scale=paddle.Tensor(scales),
+                      v_scale=paddle.Tensor(scales))
+        if case.endswith("over-two-devices"):
+            from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+            mesh = Mesh(np.asarray(jax.devices()[:2]), ("mp",))
+            heads = P(None, None, "mp")
+            # a sharding on an argument is all the caller's jit shows; the
+            # tracers inside carry none
+            kc = jax.device_put(kc, NamedSharding(mesh, heads))
+
+        def call(q, kc, new):
+            news = {} if case == "read-only" else dict(
+                new_k=paddle.Tensor(new), new_v=paddle.Tensor(new))
+            return F.block_multihead_attention(
+                paddle.Tensor(q), paddle.Tensor(kc), paddle.Tensor(kc),
+                paddle.Tensor(tables), paddle.Tensor(lens), **news,
+                **kw)[0]._data
+
+        if case == "manual-over-two-devices":
+            # the way to run the kernel over a mesh: a region that is manual
+            # over every axis, each device on the heads it holds
+            from paddle_tpu.distributed.shard_map_compat import shard_map
+            call = shard_map(call, mesh, (heads, heads, heads), heads)
+        try:
+            assert _runs_kernel(call, q, kc, new) is kernel
+        finally:
+            if case == "pallas-kernels-off":
+                flags.set_flags({"use_pallas_kernels": True})
+
+    def test_differentiated_call_gets_the_composites_gradient(
+            self, monkeypatch):
+        """The kernel carries the composite's gradient: a caller that
+        backpropagates through a write-path decode call gets what it got."""
+        rng = np.random.RandomState(14)
+        lens = [33, 7]
+        q, kc, vc, tables, nk, nv, _ = _decode_case(rng, lens, 32, 8, 3)
+        w = rng.randn(*q.shape).astype(np.float32)
+
+        def grads():
+            qt, nkt, nvt = (paddle.to_tensor(a, stop_gradient=False)
+                            for a in (q, nk, nv))
+            out, _, _ = F.block_multihead_attention(
+                qt, paddle.to_tensor(kc), paddle.to_tensor(vc),
+                paddle.to_tensor(tables),
+                paddle.to_tensor(np.asarray(lens, np.int32)),
+                new_k=nkt, new_v=nvt)
+            (out * paddle.to_tensor(w)).sum().backward()
+            return [np.asarray(t.grad.numpy()) for t in (qt, nkt, nvt)]
+
+        want = grads()
+        monkeypatch.setattr(PK, "INTERPRET", True)
+        got = grads()
+        for g, ref in zip(got, want):
+            assert np.abs(ref).max() > 0
+            np.testing.assert_allclose(g, ref, atol=1e-5)
+
+
+def test_kernel_modules_import_pallas_without_the_gpu_interpreter():
+    """``ops.pallas.import_pallas`` in a fresh process (the test process may
+    hold Pallas already): every kernel module brings Pallas in without
+    Mosaic GPU, no blocked entry stays behind, a kernel runs, and a process
+    that holds Pallas already gets it as it is."""
+    import os
+    import subprocess
+    import sys
+    code = (
+        "import sys\n"
+        "import jax.numpy as jnp\n"
+        "from paddle_tpu.ops.pallas import (flash_attention, fused_ops,\n"
+        "                                   import_pallas)\n"
+        "from paddle_tpu.ops.pallas import paged_attention as pk\n"
+        "assert 'jax.experimental.mosaic.gpu' not in sys.modules\n"
+        "assert 'jax._src.pallas.mosaic_gpu.interpret' not in sys.modules\n"
+        "assert import_pallas() == (pk.pl, pk.pltpu)\n"
+        "assert flash_attention.pl is fused_ops.pl is pk.pl\n"
+        "pk.INTERPRET = True\n"
+        "q = jnp.ones((1, 1, 8, 128)); kc = jnp.ones((3, 8, 1, 128))\n"
+        "out = pk.paged_decode_attention(\n"
+        "    q, kc, kc, jnp.asarray([[1, 2]], jnp.int32),\n"
+        "    jnp.asarray([9], jnp.int32))\n"
+        "assert float(abs(out - 1).max()) < 1e-6\n"
+        "import jax._src.pallas.mosaic_gpu.interpret.interpret_pallas_call\n")
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    done = subprocess.run(
+        [sys.executable, "-c", code], cwd=root, capture_output=True,
+        text=True, timeout=120,
+        env=dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=root))
+    assert done.returncode == 0, done.stderr[-2000:]

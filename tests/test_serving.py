@@ -351,3 +351,87 @@ class TestPrefillShape:
         finally:
             paddle.set_flags({"FLAGS_enable_metrics": False})
             REGISTRY.reset()
+
+
+class TestDecodeKernelParity:
+    """The decode program with the Pallas decode kernel (through the
+    interpreter) serves what it serves with the composite."""
+
+    @staticmethod
+    def _model():
+        # a model of its own a run: the compiled programs of a shared one
+        # are shared too, and the two runs must trace their own. Heads of
+        # 128 and pages of 16 tokens x 2 KV heads: shapes the kernel takes
+        paddle.seed(5)
+        cfg = LlamaConfig(vocab_size=97, hidden_size=1024,
+                          intermediate_size=128, num_layers=2, num_heads=8,
+                          num_kv_heads=2, max_seq_len=128,
+                          use_flash_attention=False)
+        return LlamaForCausalLM(cfg)
+
+    def _serve(self, prompts):
+        """Three lanes, a 16-token prefill budget (a long prompt is
+        mid-prefill over several ticks while the others decode) and a pool
+        too small for all of them (one is preempted and re-prefilled)."""
+        eng = PagedEngine(
+            self._model(), max_batch=3, block_size=16, num_blocks=8,
+            max_blocks_per_seq=6,
+            scheduler=SchedulerConfig(prefill_token_budget=16))
+        evicted, sentinel_ticks = [], []
+        evict, run = eng._evict, eng._run_chunk
+        eng._evict = lambda slot: (evicted.append(slot), evict(slot))[-1]
+
+        def spy(tokens, seq_lens, *a, phase="decode", **kw):
+            if phase == "decode" and eng._prefilling:
+                sentinel_ticks.append((np.asarray(seq_lens) <= 0).sum())
+            return run(tokens, seq_lens, *a, phase=phase, **kw)
+
+        eng._run_chunk = spy
+        rids = [eng.add_request(p, max_new_tokens=24) for p in prompts]
+        out = eng.run_to_completion(max_ticks=400)
+        assert eng.tick_failures == 0
+        assert evicted, "no lane was preempted"
+        assert any(sentinel_ticks), "no decode step ran beside a prefill"
+        return [out[r] for r in rids], eng.health()["decode_attention"]
+
+    def test_same_greedy_tokens_with_kernel_and_composite(self, monkeypatch):
+        from paddle_tpu.ops.pallas import paged_attention as PK
+
+        rng = np.random.RandomState(6)
+        prompts = [[int(t) for t in rng.randint(1, 97, size=n)]
+                   for n in (14, 40, 30)]
+        want, path = self._serve(prompts)
+        assert path == "composite"
+        monkeypatch.setattr(PK, "INTERPRET", True)
+        got, path = self._serve(prompts)
+        assert path == "kernel"
+        assert got == want
+
+    @pytest.mark.parametrize("kw,path", [
+        (dict(), "kernel"),
+        (dict(kv_dtype="int8"), "composite"),
+        (dict(speculate="ngram"), "composite"),
+        (dict(block_size=2), "composite"),
+        # the tiny shared model's heads are 16 wide: never the kernel
+        (dict(model=_tiny_model), "composite")])
+    def test_health_says_what_the_decode_program_was_lowered_to(
+            self, monkeypatch, kw, path):
+        from paddle_tpu.ops.pallas import paged_attention as PK
+
+        monkeypatch.setattr(PK, "INTERPRET", True)
+        geometry = dict(max_batch=2, block_size=16, num_blocks=8,
+                        max_blocks_per_seq=4)
+        fresh = "model" not in kw
+        model = kw.pop("model", self._model)()
+        eng = PagedEngine(model, **{**geometry, **kw})
+        if fresh:   # no program yet (the shared model may bring its own)
+            assert eng.health()["decode_attention"] is None
+        eng.add_request([3, 1, 4], max_new_tokens=3)
+        eng.run_to_completion(max_ticks=20)
+        assert eng.health()["decode_attention"] == path
+        # the gauge is the compiled program's, not the rule's of the moment;
+        # a second engine over the model shares program and gauge
+        monkeypatch.setattr(PK, "INTERPRET", False)
+        assert eng.health()["decode_attention"] == path
+        twin = PagedEngine(model, **{**geometry, **kw})
+        assert twin.health()["decode_attention"] == path

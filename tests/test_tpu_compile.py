@@ -1,0 +1,118 @@
+"""Kernels of the serving path compiled for a TPU v5e that is described, not
+attached: what Mosaic and XLA refuse at the cells' real widths costs no chip
+time here. Nothing runs, so these say nothing about results or speed.
+
+The topology is described inside a fixture (never at import: every xdist
+worker imports every test file, and only one process may hold libtpu).
+"""
+import os
+import re
+
+import pytest
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,
+                          SingleDeviceSharding)
+
+import paddle_tpu as paddle
+import paddle_tpu.nn.functional as F
+
+
+@pytest.fixture(scope="module")
+def four_chips():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — any failure to describe: skip
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return topo.devices
+
+
+@pytest.fixture
+def one_chip(four_chips):
+    return SingleDeviceSharding(four_chips[0])
+
+
+@pytest.fixture
+def tpu_backend(monkeypatch):
+    """The backend here is the CPU; the dispatch rule's other inputs are the
+    call's own."""
+    from paddle_tpu.nn.functional import paged_attention as fpa
+    monkeypatch.setattr(fpa.jax, "default_backend", lambda: "tpu")
+
+
+def _decode_step(q, kc, vc, tables, lens, nk, nv):
+    out, kc, vc = F.block_multihead_attention(
+        paddle.Tensor(q), paddle.Tensor(kc), paddle.Tensor(vc),
+        paddle.Tensor(tables), paddle.Tensor(lens),
+        new_k=paddle.Tensor(nk), new_v=paddle.Tensor(nv))
+    return out._data, kc._data, vc._data
+
+
+# (lanes, H, KVH, blocks, table width): the two serving configurations
+@pytest.mark.parametrize("geometry", [
+    pytest.param((32, 32, 8, 4096, 128), id="mistral-7b"),
+    pytest.param((64, 32, 2, 16384, 256), id="nemotron-3-super")])
+def test_paged_decode_step_compiles_with_the_kernel_and_no_pool_copy(
+        one_chip, tpu_backend, geometry):
+    """The decode step of ``block_multihead_attention`` at a cell's shapes:
+    the scatter of the new token, then ``paged_decode_attn`` reading the
+    donated pools as they lie (the reshape to page rows is a bitcast)."""
+    B, H, KVH, nb, mb = geometry
+    D, bs, dt = 128, 16, jnp.bfloat16
+
+    def s(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    text = jax.jit(_decode_step, donate_argnums=(1, 2)).lower(
+        s((B, 1, H, D), dt), s((nb, bs, KVH, D), dt), s((nb, bs, KVH, D), dt),
+        s((B, mb), jnp.int32), s((B,), jnp.int32),
+        s((B, 1, KVH, D), dt), s((B, 1, KVH, D), dt)).compile().as_text()
+    entry = text[text.index("ENTRY"):]
+    assert entry.count('custom_call_target="tpu_custom_call"') == 1
+    assert "%paged_decode_attn" in entry
+    # what makes or moves an array of the pools' size: the two parameters,
+    # the two in-place scatters, the two bitcasts to page rows; no copy
+    made = re.findall(rf"= bf16\[{nb},\S+ ([\w-]+)\(", entry)
+    assert sorted(made) == ["bitcast", "bitcast", "fusion", "fusion",
+                            "parameter", "parameter"], made
+
+
+@pytest.mark.parametrize("partitioned_by", ["the-compiler", "shard-map"])
+def test_paged_decode_step_compiles_over_a_mesh(four_chips, tpu_backend,
+                                                partitioned_by):
+    """Mistral's decode step over dp 2 x mp 2, heads over ``mp`` (tensor
+    parallel serving; the ``dp`` pairs hold copies). Nothing but the
+    arguments' shardings says so (no global mesh, no context): the caller's
+    jit leaves the partitioning to the compiler, which cannot partition a
+    Mosaic kernel, so the composite is lowered, as before the kernel was
+    there. Under a ``shard_map`` that is manual over both axes each device
+    runs the kernel on the heads it holds."""
+    from paddle_tpu.distributed.shard_map_compat import shard_map
+    from paddle_tpu.nn.functional.paged_attention import log_paths
+
+    mesh = Mesh(np.asarray(four_chips).reshape(2, 2), ("dp", "mp"))
+    B, H, KVH, nb, mb, D, bs, dt = 32, 32, 8, 4096, 128, 128, 16, jnp.bfloat16
+    heads = P(None, None, "mp", None)
+    specs = (heads, heads, heads, P(), P(), heads, heads)
+    shapes = ((B, 1, H, D), (nb, bs, KVH, D), (nb, bs, KVH, D), (B, mb),
+              (B,), (B, 1, KVH, D), (B, 1, KVH, D))
+    dtypes = (dt, dt, dt, jnp.int32, jnp.int32, dt, dt)
+    step = _decode_step
+    if partitioned_by == "shard-map":
+        step = shard_map(_decode_step, mesh, specs, (heads, heads, heads))
+    with log_paths() as lowered:
+        text = jax.jit(step, donate_argnums=(1, 2)).lower(*(
+            jax.ShapeDtypeStruct(shape, dtype,
+                                 sharding=NamedSharding(mesh, spec))
+            for shape, dtype, spec in zip(shapes, dtypes, specs))
+        ).compile().as_text()
+    kernels = text.count('custom_call_target="tpu_custom_call"')
+    if partitioned_by == "shard-map":
+        assert (lowered, kernels) == (["kernel"], 1)
+    else:
+        assert (lowered, kernels) == (["composite"], 0)
