@@ -48,7 +48,7 @@ FUSED_SHAPE = dict(rows=2048, hidden=1024, ffn=4096, head_dim=64, seq=1024)
 #: train: GPT-2 345M at full width and depth; batch 4 x 1024 tokens with
 #: block recompute is what fits 16 GB next to f32 params + AdamW moments
 TRAIN = dict(batch=4, steps=6, recompute=True)
-#: serve: bench.py:_bench_serving's decoder (vocab 32000, h 1024, ffn 2816,
+#: serve: a Llama-shaped decoder (vocab 32000, h 1024, ffn 2816,
 #: L 16, 16 heads, bf16 weights), 8 requests of 32-192 prompt tokens
 SERVE = dict(n_requests=8, prompt_range=(32, 192), new_tokens=32,
              max_batch=8, block_size=32)

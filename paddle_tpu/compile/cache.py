@@ -110,8 +110,8 @@ def cache_root() -> str:
 def enable_jax_cache() -> str:
     """Turn on JAX's persistent compilation cache at :func:`cache_root`
     and return that directory. Entry points that compile on the chip
-    (``chip_smoke.py``, ``bench.py``, the ``tools/`` chip scripts) call
-    this BEFORE their first compile. With the variable set JAX reads it
+    (``chip_smoke.py``, ``benchmark/run.py``, the ``tools/`` chip scripts)
+    call this BEFORE their first compile. With the variable set JAX reads it
     itself and nothing is set here."""
     root = cache_root()
     if not os.environ.get(_JAX_CACHE_ENV):

@@ -15,7 +15,7 @@ under any schedule table from :mod:`.schedules`:
 * steps execute host-serially in dataflow order (the same dependency
   relation :func:`.schedules.simulate` models), optionally timed per
   step so the measured bubble fraction can be compared against the
-  analytical one (the ``pipeline_bubble`` bench rung);
+  analytical one;
 * with a ``(data, pp)`` mesh, each stage is pinned to its submesh
   (``distributed.spmd.stage_submeshes``) and boundary values hop
   between adjacent submeshes via ``jax.device_put`` with the

@@ -22,8 +22,7 @@ event simulation under the dataflow dependencies (F(s,µ) after
 F(s-1,µ); B(s,µ) after B(s+1,µ) and F(s,µ); W after its B; per-stage
 serialization in table order) and reports the makespan + per-stage
 busy time — with unit costs that IS the analytical bubble fraction,
-and with measured per-step durations it is the measured one (the
-``pipeline_bubble`` bench rung compares the two).
+and with measured per-step durations it is the measured one.
 """
 from __future__ import annotations
 

@@ -713,11 +713,11 @@ class PagedEngine:
         # per (B, T) shape and cache pytree structure. Engines over the
         # SAME model share them — the forward fns read only the model's
         # Parameter objects (identical across engines) and take
-        # caches/tables/tokens as arguments, so a second replica (or the
-        # single-stream baseline in bench.py) reuses compiled programs
-        # instead of re-tracing identical ones. The cache lives in a
-        # weak side table, NOT on the model: jitted callables hold locks
-        # and must not ride through deepcopy/pickle of the model.
+        # caches/tables/tokens as arguments, so a second replica reuses
+        # compiled programs instead of re-tracing identical ones. The
+        # cache lives in a weak side table, NOT on the model: jitted
+        # callables hold locks and must not ride through deepcopy/pickle
+        # of the model.
         import functools
         cache = _PAGED_JIT_CACHE.setdefault(model, {})
         arch_key = type(self.arch).__name__
@@ -1057,7 +1057,7 @@ class PagedEngine:
                 # np.asarray blocks until the program finishes, so the
                 # serving.<phase> bracket bounds the chunk's device
                 # execution from above — the per-tick prefill-vs-decode
-                # attribution loadgen/bench report
+                # attribution tools/loadgen.py reports
                 outs = [np.asarray(o) for o in outs]  # tpulint: disable=TPU104 — host boundary by design: sampled token ids feed python-side scheduling
                 restore.close()
                 seconds = time.perf_counter() - t0

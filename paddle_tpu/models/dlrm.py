@@ -5,10 +5,9 @@ sparse fields + dot interaction) from its host parameter-server tier;
 here the sparse fields share ONE mesh-sharded table
 (:class:`~paddle_tpu.distributed.embedding.ShardedEmbedding`, vocab
 row-sharded over ``(fsdp, tp)``) so the capacity lives on chip. The
-model is the ``embedding`` bench rung's workload and doubles as the
-dense-path serving fixture: :meth:`DLRM.serve_dense` scores a flat id
-batch in one forward, which ``PagedEngine`` runs behind the Router
-without any KV cache.
+model doubles as the dense-path serving fixture:
+:meth:`DLRM.serve_dense` scores a flat id batch in one forward, which
+``PagedEngine`` runs behind the Router without any KV cache.
 
 Architecture (Naumov et al., arXiv:1906.00091):
 
@@ -67,7 +66,8 @@ class DLRM(nn.Layer):
 
     Pass ``mesh`` (or call :meth:`shard_` later) to row-shard the table
     over ``cfg.embedding_axes``; without a mesh the table is replicated
-    — that is the loss-parity baseline the bench rung compares against.
+    — that is the loss-parity baseline the sharded table is tested
+    against.
     """
 
     def __init__(self, cfg: DLRMConfig, mesh=None):

@@ -391,7 +391,7 @@ class FleetBeacon:
     # The hot path is deliberately flat: on a non-probe step,
     # step_begin/step_end execute a handful of bytecodes each — in a real
     # training loop these run cache-cold, so every avoided function call
-    # is measurable (the bench rung's <2% bar is on exactly this path).
+    # is measurable.
     def _probe_next(self) -> bool:
         return self._n == self._wm1
 

@@ -17,9 +17,7 @@ memory-profiling half; XLA lineage: ``Compiled.cost_analysis()`` /
   per-phase high-water tracking (``paddle_tpu_hbm_*`` metrics).
 
 Reporting rides in ``tools/perf_report.py`` (roofline table + attribution
-breakdown) and ``tools/perf_gate.py`` (bench-vs-frozen-baseline CI gate);
-``bench.py`` records MFU + attribution columns on every ladder run. See
-PERF.md for the methodology.
+breakdown).
 """
 from __future__ import annotations
 
@@ -40,7 +38,7 @@ __all__ = ["costmodel", "device", "memory", "OpCost", "cost_of",
            "chip_peak_bw", "chip_hbm_bytes"]
 
 #: peak dense bf16 FLOPs/s per chip (public spec sheets) — the roofline's
-#: compute ceiling; bench.py's MFU math delegates here
+#: compute ceiling
 PEAK_FLOPS = {
     "TPU v2": 45e12,
     "TPU v3": 123e12,

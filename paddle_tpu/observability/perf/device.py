@@ -104,9 +104,9 @@ def record_compiled(site: str, label: str, compiled) -> Optional[dict]:
 
 def memory_breakdown(compiled) -> Optional[dict]:
     """Alias-aware memory accounting of one compiled program — the ONE
-    place the peak formula lives (``record_compiled`` and the bench
-    batch sweep both read it). Donated inputs alias outputs, so XLA
-    reuses the argument HBM: ``peak = arg + out + temp − alias``.
+    place the peak formula lives (``record_compiled`` reads it).
+    Donated inputs alias outputs, so XLA reuses the argument HBM:
+    ``peak = arg + out + temp − alias``.
     None when the backend exposes no analysis."""
     try:
         mem = compiled.memory_analysis()

@@ -158,7 +158,7 @@ class TestStaticPeakVsCensus:
         rep = liveness.peak_report(prog, fetch_ids=fetch)
         assert rep["n_ops"] == len(prog.global_block().ops)
         assert 0 <= rep["peak_index"] < rep["n_ops"]
-        assert rep["peak_bytes"] >= rep["entry_bytes"]
+        assert rep["peak_bytes"] >= rep["entry_bytes"] > 0
         assert len(rep["top_values"]) == 5
 
 

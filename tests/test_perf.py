@@ -5,10 +5,8 @@ their cross-check against XLA's own cost_analysis on compiled programs,
 the attribution-sums-to-step-time property on a real train loop, the
 attributed HBM census, compiled-program capture at to_static/SOT compile
 time, the per-op metric accumulation in dispatch, the perf_report
-renderer, the perf_gate freeze/gate workflow (CI teeth), and the
-process-unique metrics-dump suffix.
+renderer, and the process-unique metrics-dump suffix.
 """
-import json
 import os
 import subprocess
 import sys
@@ -28,7 +26,7 @@ from paddle_tpu.observability.perf import costmodel, device, memory
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
 
-from tools import perf_gate, perf_report  # noqa: E402
+from tools import perf_report  # noqa: E402
 
 
 @pytest.fixture(autouse=True)
@@ -480,175 +478,6 @@ class TestPerfReport:
         r = perf_report.build_report_from_snapshot(snap)
         ops = {row["op"] for row in r["ops"]}
         assert "matmul" in ops
-
-
-# =========================================================================
-# perf_gate — the CI teeth (tier-1 smoke per ISSUE: schema/structure on
-# CPU, no timing assertions)
-# =========================================================================
-class TestPerfGate:
-    LINES = "\n".join([
-        json.dumps({"metric": "gpt2", "value": 100.0, "unit": "tokens/s",
-                    "vs_baseline": 1.0, "extra": {"mfu": 0.5}}),
-        json.dumps({"metric": "disp", "value": 10.0, "unit": "us/op",
-                    "vs_baseline": 1.0}),
-    ])
-
-    def test_parse_json_lines_and_wrapper(self):
-        direct = perf_gate.parse_bench_output(self.LINES)
-        assert set(direct) == {"gpt2", "disp"}
-        wrapped = perf_gate.parse_bench_output(
-            json.dumps({"n": 1, "tail": "noise\n" + self.LINES}))
-        assert set(wrapped) == {"gpt2", "disp"}
-        aslist = perf_gate.parse_bench_output(
-            json.dumps(list(direct.values())))
-        assert set(aslist) == {"gpt2", "disp"}
-
-    def test_schema_validation(self):
-        ok = perf_gate.parse_bench_output(self.LINES)
-        assert perf_gate.validate_schema(ok) == []
-        bad = {"x": {"metric": "x", "unit": "error",
-                     "vs_baseline": 0.0, "value": 0.0}}
-        assert perf_gate.validate_schema(bad)
-        assert perf_gate.validate_schema({}) == [
-            "no bench rungs found in input"]
-
-    def test_freeze_then_pass(self):
-        cand = perf_gate.parse_bench_output(self.LINES)
-        base = perf_gate.freeze(cand, min_ratio=0.9)
-        assert set(base["rungs"]) == {"gpt2", "disp"}
-        r = perf_gate.gate(cand, base)
-        assert r["pass"] and all(c["status"] == "pass"
-                                 for c in r["checks"])
-
-    def test_gate_fails_on_slowed_rung(self):
-        cand = perf_gate.parse_bench_output(self.LINES)
-        base = perf_gate.freeze(cand, min_ratio=0.9)
-        slow = {k: dict(v) for k, v in cand.items()}
-        slow["gpt2"]["value"] = 80.0          # −20% > 10% tolerance
-        r = perf_gate.gate(slow, base)
-        assert not r["pass"]
-        assert [c["metric"] for c in r["checks"]
-                if c["status"] == "fail"] == ["gpt2"]
-
-    def test_lower_is_better_direction(self):
-        cand = perf_gate.parse_bench_output(self.LINES)
-        base = perf_gate.freeze(cand, min_ratio=0.9)
-        worse = {k: dict(v) for k, v in cand.items()}
-        worse["disp"]["value"] = 20.0         # dispatch 2x SLOWER
-        r = perf_gate.gate(worse, base)
-        assert not r["pass"]
-        better = {k: dict(v) for k, v in cand.items()}
-        better["disp"]["value"] = 5.0         # 2x faster passes
-        assert perf_gate.gate(better, base)["pass"]
-
-    def test_gate_fails_on_missing_and_errored_rung(self):
-        cand = perf_gate.parse_bench_output(self.LINES)
-        base = perf_gate.freeze(cand)
-        partial = {"gpt2": cand["gpt2"]}
-        assert not perf_gate.gate(partial, base)["pass"]
-        assert perf_gate.gate(partial, base,
-                              allow_missing=True)["pass"]
-        errored = {k: dict(v) for k, v in cand.items()}
-        errored["disp"]["unit"] = "error"
-        assert not perf_gate.gate(errored, base)["pass"]
-
-    def test_freeze_skips_errored_rungs(self):
-        cand = perf_gate.parse_bench_output(self.LINES)
-        cand["broken"] = {"metric": "broken", "value": 0.0,
-                          "unit": "error", "vs_baseline": 0.0}
-        base = perf_gate.freeze(cand)
-        assert "broken" not in base["rungs"]
-
-    def test_frozen_repo_baseline_is_valid(self):
-        """tools/perf_baseline.json (checked in) parses and gates the
-        run it was frozen from. Rungs added to the baseline AFTER the
-        r05 freeze (fleet_observability round 14, fusion round 15,
-        planner_vs_manual round 16, async_overlap + async_batch_sweep
-        round 17, serving_router round 18, serving_reqtrace round 19,
-        pipeline_bubble round 21) are absent from the archived run —
-        they may be missing, but nothing may fail."""
-        with open(os.path.join(REPO, "tools", "perf_baseline.json")) as f:
-            base = json.load(f)
-        assert base["format"] == "paddle_tpu.perf_baseline/1"
-        assert base["rungs"]
-        assert "fleet_observability_overhead_ratio" in base["rungs"]
-        assert "fusion_fused_vs_unfused_step_ratio" in base["rungs"]
-        # the fusion bar is the acceptance criterion itself: >= 1.10x
-        fusion = base["rungs"]["fusion_fused_vs_unfused_step_ratio"]
-        assert fusion["value"] * fusion["min_ratio"] >= 1.10
-        # the planner bar likewise: planner placement >= best manual
-        pv = base["rungs"]["planner_vs_manual_step_ratio"]
-        assert pv["value"] * pv["min_ratio"] >= 1.0
-        with open(os.path.join(REPO, "BENCH_r05.json")) as f:
-            cand = perf_gate.parse_bench_output(f.read())
-        res = perf_gate.gate(cand, base, allow_missing=True)
-        assert res["pass"]
-        # the async bars: overlap >= the frozen no-regression floor,
-        # batch sweep within the ladder tolerance of parity
-        ao = base["rungs"]["async_overlap_step_ratio"]
-        assert ao["value"] * ao["min_ratio"] >= 0.85
-        assert "async_batch_sweep_tokens_ratio" in base["rungs"]
-        missing = {c["metric"] for c in res["checks"]
-                   if c["status"] == "missing"}
-        assert "serving_reqtrace_overhead_ratio" in base["rungs"]
-        # the verifier bar encodes the <2% budget: value * min_ratio
-        vo = base["rungs"]["verifier_overhead_ratio"]
-        assert vo["value"] * vo["min_ratio"] >= 0.98
-        # the static-analyzer bar encodes the same <2% compile budget
-        sa = base["rungs"]["static_analysis_overhead_ratio"]
-        assert sa["value"] * sa["min_ratio"] >= 0.98
-        # the pipeline bar is the boolean acceptance gate itself
-        pb = base["rungs"]["pipeline_bubble_measured_vs_analytical"]
-        assert pb["value"] * pb["min_ratio"] >= 1.0
-        # the goodput-ledger bar encodes the <2% step budget (round 23)
-        go = base["rungs"]["goodput_overhead_ratio"]
-        assert go["value"] * go["min_ratio"] >= 0.95
-        # the fault-recovery bar: armed abort plane < 2% of disarmed
-        # step time (round 24); MTTR rides ungated in extra
-        fr = base["rungs"]["fault_recovery_overhead_ratio"]
-        assert fr["value"] * fr["min_ratio"] >= 0.95
-        # the giant-embedding bar: sharded DLRM step >= the frozen
-        # no-regression floor vs the replicated baseline (round 25;
-        # parity + pod capacity proof + dedup win gate the score)
-        eb = base["rungs"]["embedding_sharded_vs_replicated_step_ratio"]
-        assert eb["value"] * eb["min_ratio"] >= 0.8
-        assert missing <= {"fleet_observability_overhead_ratio",
-                           "embedding_sharded_vs_replicated_step_ratio",
-                           "fault_recovery_overhead_ratio",
-                           "fusion_fused_vs_unfused_step_ratio",
-                           "planner_vs_manual_step_ratio",
-                           "async_overlap_step_ratio",
-                           "async_batch_sweep_tokens_ratio",
-                           "serving_router_goodput_scaling",
-                           "verifier_overhead_ratio",
-                           "static_analysis_overhead_ratio",
-                           "serving_reqtrace_overhead_ratio",
-                           "pipeline_bubble_measured_vs_analytical",
-                           "goodput_overhead_ratio"}
-
-    def test_cli_schema_only(self, tmp_path):
-        p = tmp_path / "cand.json"
-        p.write_text(self.LINES)
-        rc = perf_gate.main(["--schema-only", str(p)])
-        assert rc == 0
-
-    def test_cli_freeze_and_gate(self, tmp_path, capsys):
-        cand = tmp_path / "cand.json"
-        cand.write_text(self.LINES)
-        basep = tmp_path / "base.json"
-        assert perf_gate.main(["--freeze", str(cand),
-                               "--baseline", str(basep)]) == 0
-        assert perf_gate.main([str(cand),
-                               "--baseline", str(basep)]) == 0
-        slow = tmp_path / "slow.json"
-        rec = json.loads(self.LINES.splitlines()[0])
-        rec["value"] = 1.0
-        slow.write_text("\n".join([json.dumps(rec),
-                                   self.LINES.splitlines()[1]]))
-        capsys.readouterr()
-        assert perf_gate.main([str(slow),
-                               "--baseline", str(basep)]) == 1
 
 
 # =========================================================================

@@ -195,14 +195,22 @@ class TestSchedules:
 # runtime: exact parity
 # ==========================================================================
 class TestRuntimeParity:
+    @pytest.mark.parametrize("timed", [False, True],
+                             ids=["untimed", "timed"])
     @pytest.mark.parametrize("schedule", SCHEDULES)
-    def test_bitwise_vs_unpipelined(self, schedule):
+    def test_bitwise_vs_unpipelined(self, schedule, timed):
         prog, loss = _mlp_program()
         part = partition_program(prog, 2, fetch_ids=[id(loss)])
         pp = PipelinedProgram(part, schedule=schedule,
                               loss_id=id(loss))
         feed = _feed(prog, m=4)
-        l_pp, g_pp, stats = pp.train_step(feed, 4)
+        l_pp, g_pp, stats = pp.train_step(feed, 4, collect_timing=timed)
+        if timed:
+            # every step of the table was timed, and the replay of those
+            # durations gives a bubble fraction (how close it lies to the
+            # analytical one is a property of the host, not asserted)
+            assert len(stats["timings"]) == stats["steps"]
+            assert 0.0 <= stats["measured_bubble"] < 1.0
         l_ref, g_ref = pp.run_unpipelined(feed, 4)
         # bitwise: pipelining reorders execution, not arithmetic
         assert np.asarray(l_pp).tobytes() == np.asarray(l_ref).tobytes()

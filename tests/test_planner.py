@@ -401,6 +401,65 @@ class TestRoundTrip:
             p = dict(model.named_parameters())[name]
             assert tuple(p._spmd_spec) == tuple(spec)
 
+    def test_planned_gpt_step_matches_unsharded(self):
+        """The planner's own placement, applied to the GPT and traced
+        through the propagation scope: the unsharded model's loss
+        (rtol 1e-3) and no replicate-fallback op in the traced step.
+        Capacity is cut so that the winner has to shard parameters."""
+        mesh = _mesh(data=2, tp=4)
+        ids = np.random.RandomState(0).randint(
+            0, GPT_CFG["vocab_size"], (4, 32)).astype(np.int64)
+
+        def build():
+            paddle.seed(3)
+            return GPTForCausalLM(GPTConfig(**GPT_CFG))
+
+        def loss_of(model, scope=None, in_spec=None):
+            params = [p for p in model.parameters()
+                      if not p.stop_gradient]
+
+            def f(arrays, ids_a):
+                originals = [p._data for p in params]
+                for p, a in zip(params, arrays):
+                    p._data = a
+                try:
+                    x = Tensor(ids_a)
+                    if scope is not None:
+                        for p in params:
+                            spec = spmd.param_spec_of(p)
+                            if spec is not None:
+                                scope.seed(p, spec)
+                        scope.seed(x, in_spec)
+                    _, loss = model(x, labels=x)
+                    return loss._data
+                finally:
+                    for p, o in zip(params, originals):
+                        p._data = o
+
+            return float(jax.jit(f)([p._data for p in params], ids))
+
+        ref_loss = loss_of(build())
+
+        model = build()
+
+        def plan_loss(x):
+            _, loss = model(x, labels=x)
+            return loss
+
+        probe = planner.plan(plan_loss, mesh, example_inputs=(ids,),
+                             model=model)
+        dp = next(s for s in probe.ranked if s.candidate.name == "dp")
+        res = planner.plan(plan_loss, mesh, example_inputs=(ids,),
+                           model=model,
+                           capacity_bytes=dp.score.hbm_bytes * 0.7)
+        assert res.apply(model)         # the winner shards something
+        scope = spmd.trace_scope(mesh)
+        with scope:
+            loss = loss_of(model, scope, res.in_specs)
+        assert scope.stats["annotated"] > 0      # the scope saw the step
+        assert not scope.stats["fallback"], scope.stats
+        np.testing.assert_allclose(loss, ref_loss, rtol=1e-3)
+
     def test_in_specs_shape(self, gpt_plan):
         _, _, res = gpt_plan
         spec = res.in_specs

@@ -351,7 +351,7 @@ class TestDLRMOnMesh:
 
     def test_pod_proof_is_device_independent(self):
         """The same proof runs against a duck-typed pod mesh (axis
-        sizes only) — what the bench rung does on a 1-device host."""
+        sizes only), so a 1-device host can run it."""
         from paddle_tpu import static
         from paddle_tpu.distributed.spmd.propagate import \
             propagate_program

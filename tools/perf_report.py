@@ -23,9 +23,8 @@ Modes::
 
 The demo loop runs a tiny two-layer-attention model trained eagerly with
 ``FLAGS_benchmark=1`` (per-op device sync) so the dispatch latency
-histogram approximates per-op execution time; on real ladder models the
-same columns ride in ``bench.py`` extras and the metrics snapshot of any
-instrumented run.
+histogram approximates per-op execution time; on a real model the same
+columns ride in the metrics snapshot of any instrumented run.
 """
 from __future__ import annotations
 
@@ -84,8 +83,8 @@ def build_report(op_time: Dict[str, dict], op_cost: Dict[str, dict],
                  compiled: Optional[list] = None,
                  device_info: Optional[dict] = None,
                  cost_window_steps: Optional[int] = None) -> dict:
-    """Assemble the report dict from its measured pieces (the demo run,
-    bench extras, and tests all come through here)."""
+    """Assemble the report dict from its measured pieces (the demo run
+    and the tests both come through here)."""
     from paddle_tpu.observability import perf
 
     if device_info is None:

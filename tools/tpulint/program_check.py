@@ -3,7 +3,7 @@ programs and run the static verifier over each recorded op-list IR.
 
 ``python -m tools.tpulint --programs`` (and the tier-1 gate in
 ``tests/test_program_verifier.py``) drives :func:`run`: every program
-the bench ladder and the test suite already trace — a GPT block with
+the test suite already traces — a GPT block with
 loss, a tiny llama forward, an SGD train step, in-graph control flow,
 the fusion pass's rewritten plan, and a sharded program over a mesh —
 must verify CLEAN. A finding here is new framework debt: fix the
