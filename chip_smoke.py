@@ -342,6 +342,8 @@ def phase_train(cfg=None, batch=TRAIN["batch"], steps=TRAIN["steps"]) -> dict:
     n_compiles = engine._train_step._cache_size()
     if n_compiles != 1:
         raise RuntimeError(f"train step compiled {n_compiles} times")
+    if any(p._data.is_deleted() for p in engine._params):
+        raise RuntimeError("fit left Parameters on donated (dead) buffers")
     failed = dict(at.get_cache().failures)
     if failed:
         raise RuntimeError(f"autotune candidates failed: {failed}")

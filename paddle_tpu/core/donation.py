@@ -122,6 +122,6 @@ def ensure_distinct(pairs: Iterable, site: str):
             raise DonatedBufferError(
                 f"{site}: {label!r} and {prev!r} share one device "
                 f"buffer, which cannot be donated twice. Materialize "
-                f"distinct copies (e.g. paddle.assign) before enabling "
-                f"donation, or turn donation off for this call.")
+                f"distinct copies (e.g. paddle.assign) before the "
+                f"donating call.")
         seen[id(a)] = label

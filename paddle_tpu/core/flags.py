@@ -162,9 +162,9 @@ define_flag("perf_op_cost", False,
             "Accumulate the analytical cost model's per-op FLOPs/bytes "
             "into paddle_tpu_perf_op_* metrics at eager dispatch "
             "(requires FLAGS_enable_metrics).")
-# Async runtime (io/prefetch.py + donated train steps + decomposed
-# sharded-optimizer gathers) — registered here so set_flags works before
-# the io/compile packages first import.
+# Async runtime (io/prefetch.py + decomposed sharded-optimizer gathers) —
+# registered here so set_flags works before the io/compile packages
+# first import.
 define_flag("prefetch", True,
             "Double-buffered device prefetch in Engine.fit / "
             "hapi.Model.fit: the next batch's host fetch + device_put "
@@ -173,12 +173,6 @@ define_flag("prefetch", True,
 define_flag("prefetch_depth", 2,
             "Batches the DevicePrefetcher keeps in flight ahead of the "
             "consumer (>=1; 2 = classic double buffering).")
-define_flag("donate_buffers", False,
-            "Donate parameter/optimizer-state buffers in traced train "
-            "steps (to_static(donate=True) / Engine donation default): "
-            "XLA reuses the input HBM for the updated state, cutting the "
-            "step's high-water roughly by the donated bytes. Default OFF "
-            "— the undonated path is bit-exact seed behavior.")
 define_flag("sharding_gather_group_mb", 16,
             "Byte budget (MB) of one decomposed all-gather group in the "
             "ZeRO stage-2/3 parameter re-gather: params are gathered in "

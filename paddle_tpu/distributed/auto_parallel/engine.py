@@ -29,8 +29,7 @@ from .. import mesh as mesh_mod
 class Engine:
     def __init__(self, model, loss=None, optimizer=None, metrics=None,
                  strategy=None, mesh=None, in_specs=None,
-                 param_specs=None, placement=None, donate=None,
-                 prefetch=None):
+                 param_specs=None, placement=None, prefetch=None):
         self._model = model
         self._loss = loss
         self._optimizer = optimizer
@@ -65,13 +64,9 @@ class Engine:
         self.fusion_stats = None
         self._params = [p for p in model.parameters()
                         if not p.stop_gradient]
-        # Async runtime knobs (None = resolve from FLAGS at use time):
-        # donate hands the param/optimizer-state buffers to the compiled
-        # step (HBM high-water drop — see core.donation for the safety
-        # contract); prefetch double-buffers the input pipeline
-        # (io.DevicePrefetcher) so the next batch transfers during the
-        # current step.
-        self._donate_arg = donate
+        # prefetch (None = FLAGS_prefetch at use time) double-buffers the
+        # input pipeline (io.DevicePrefetcher) so the next batch transfers
+        # during the current step.
         self._prefetch_arg = prefetch
         self._train_step = None
         self._eval_step = None
@@ -159,22 +154,20 @@ class Engine:
                     wd_flags)
             return loss, new_p, (t, new_m, new_st)
 
-        # donation (opt-in via Engine(donate=True) / FLAGS_donate_buffers):
-        # params + optimizer state are donated so XLA reuses their HBM
-        # for the updated values — the step's high-water drops by
-        # roughly the donated bytes (perf.memory records it). fit()
-        # writes the latest live arrays back into the Parameters in a
-        # finally block, so a mid-epoch abort leaves the model usable;
-        # stale pre-step references raise core.donation's clear error.
-        from ...core import flags as _flags
-        self._donate = (bool(_flags.get_flag("donate_buffers"))
-                        if self._donate_arg is None
-                        else bool(self._donate_arg))
-        # the functions' names are the programs' names on the trace's
+        # The step owns the state it is given: params + optimizer state
+        # are donated, so XLA writes the updated values into their HBM.
+        # A launch then allocates no new state buffers (it need not wait
+        # for the running step to free its inputs, so it overlaps it) and
+        # the high-water holds the state once, not twice. fit() re-binds
+        # the returned arrays every step and writes the latest live ones
+        # back into the Parameters in a finally block, so a mid-epoch
+        # abort leaves the model usable; an array taken from a Parameter
+        # BEFORE fit is dead after the first step, and reading it raises
+        # core.donation's error naming this site.
+        # The functions' names are the programs' names on the trace's
         # ``XLA Modules`` line (jit_engine_train_step, jit_engine_eval_step)
-        self._train_step = jax.jit(
-            engine_train_step,
-            donate_argnums=(0, 1) if self._donate else ())
+        self._train_step = jax.jit(engine_train_step,
+                                   donate_argnums=(0, 1))
 
         def engine_eval_step(param_arrays, x, y):
             originals = [p._data for p in params]
@@ -334,6 +327,15 @@ class Engine:
 
         loader = self.dataloader(train_data, batch_size, shuffle=True)
         pa = [p._data for p in self._params]
+        _donation.ensure_live(pa, "Engine.fit entry")
+        _donation.ensure_distinct(
+            ((p.name, a) for p, a in zip(self._params, pa)), "Engine.fit")
+        # the first step donates the arrays the Parameters hold now (on a
+        # mesh their replicated copies, which may share the first device's
+        # buffer): the only donated buffers a caller can still hold a
+        # reference to — every later step is given arrays that never left
+        # this loop
+        _donation.mark_donated(pa, "the Engine's donated train step")
         opt_state = self._init_opt_state(pa)
         if self._mesh.size > 1 and not self._spmd_auto:
             pa, opt_state = self._replicate_over_mesh((pa, opt_state))
@@ -363,11 +365,6 @@ class Engine:
                                   else ys, which=1)
             return x, y
 
-        if self._donate:
-            _donation.ensure_live(pa, "Engine.fit(donate=True) entry")
-            _donation.ensure_distinct(
-                ((p.name, a) for p, a in zip(self._params, pa)),
-                "Engine.fit(donate=True)")
         census_left = 2     # attributed HBM census on the first steps
         n_steps = 0         # fit.step's step_num, over the whole call
         try:
@@ -406,7 +403,6 @@ class Engine:
                             lr = (lr_const if lr_const is not None
                                   else jnp.asarray(self._opt.get_lr(),
                                                    jnp.float32))
-                            prev = (pa, opt_state) if self._donate else None
                             n_sigs = cache_size() if cache_size else None
                             with _trace.boundary("fit.dispatch"):
                                 loss, pa, opt_state = self._train_step(
@@ -421,22 +417,17 @@ class Engine:
                                     snt.note_compile(
                                         "initial" if n_sigs == 0
                                         else "retrace")
-                                if prev is not None:
-                                    _donation.mark_donated(
-                                        jax.tree_util.tree_leaves(prev),
-                                        "the Engine's donated train step")
                                 if sched is not None:
                                     sched.step()
                                 loss_sum = loss if loss_sum is None \
                                     else loss_sum + loss
                                 loss_n += 1
                                 if census_left:
-                                    # mid-flight census: with donation the
-                                    # just-donated buffers count 0, so the
-                                    # recorded high-water shows the drop
+                                    # mid-flight census: the just-donated
+                                    # buffers count 0, so the recorded
+                                    # high-water holds the state once
                                     _perf_mem.update_high_water(
-                                        "engine_step_donated"
-                                        if self._donate else "engine_step")
+                                        "engine_step_donated")
                                     census_left -= 1
                                 bcn.step_end()
                                 snt.observe_step(led.step_end())
@@ -457,8 +448,8 @@ class Engine:
             # write the trained arrays AND accumulator states back into
             # the eager optimizer, so a later opt.step()/state_dict()
             # continues from where the Engine left off. Runs on abort
-            # too: under donation the Parameters' pre-fit payloads are
-            # dead — the latest live arrays must land back.
+            # too: the Parameters' pre-fit payloads were donated by the
+            # first step — the latest live arrays must land back.
             with _trace.boundary("fit.writeback"):
                 t, _masters, states = opt_state
                 self._opt._step_count = int(t)  # tpulint: disable=TPU103 — one end-of-fit writeback into the eager optimizer (documented contract), not a per-step sync

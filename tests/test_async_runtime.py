@@ -4,7 +4,8 @@ decomposed ZeRO gathers, async loss fetch.
 Covers the tentpole contracts — DevicePrefetcher ordering/teardown
 (including worker-process reaping through a wrapped multiprocess
 DataLoader iterator), to_static/Engine donation safety (framework error
-on stale reads, pcc separation, FLAGS-off bit-exactness), stage-2/3
+on stale reads, pcc separation, the Engine's always-donated step against
+an undonated jit of the same function), stage-2/3
 decomposed gathers + the stage-3 lookahead schedule, the hapi non-finite
 degradation path under the async pipeline, and the fleet_trace
 transfer/compute span-overlap report.
@@ -320,63 +321,168 @@ class _XY(Dataset):
         return self.n
 
 
+def _undonated(engine):
+    """The same step function jitted WITHOUT donation: what the Engine
+    compiled before its step owned its state. The yardstick of the
+    donated step's histories."""
+    engine.prepare()
+    engine._train_step = jax.jit(engine._train_step.__wrapped__)
+    return engine
+
+
+def _mse(o, t):
+    return paddle.ops.mean((o - t) ** 2)
+
+
 class TestEngineAsync:
-    def _run(self, epochs=1, **kw):
+    def _build(self, undonated=False, **kw):
         from paddle_tpu.distributed.auto_parallel.engine import Engine
         from paddle_tpu.optimizer import Adam
 
         paddle.seed(5)
-        np.random.seed(5)
+        np.random.seed(5)                   # the loader's shuffle
         m = nn.Sequential(nn.Linear(8, 16), nn.ReLU(), nn.Linear(16, 2))
         opt = Adam(learning_rate=1e-3, parameters=m.parameters())
-        e = Engine(m, loss=lambda o, t: paddle.ops.mean((o - t) ** 2),
-                   optimizer=opt, **kw)
-        hist = e.fit(_XY(), epochs=epochs, batch_size=8)
-        return hist, m
+        e = Engine(m, loss=_mse, optimizer=opt, **kw)
+        return (_undonated(e) if undonated else e), m, opt
 
-    def test_parity_across_async_knobs(self):
-        base, _ = self._run(donate=False, prefetch=False)
-        for kw in ({"donate": True, "prefetch": False},
-                   {"donate": False, "prefetch": True},
-                   {"donate": True, "prefetch": True}):
-            hist, m = self._run(**kw)
-            assert hist == pytest.approx(base, rel=1e-5), kw
-            assert all(not p._data.is_deleted()
-                       for p in m.parameters()), kw
+    def _run(self, epochs=1, **kw):
+        e, m, opt = self._build(**kw)
+        hist = e.fit(_XY(), epochs=epochs, batch_size=8)
+        return hist, m, opt
+
+    @staticmethod
+    def _state(m, opt):
+        """Every parameter and accumulator leaf, read through its owner."""
+        params = list(m.parameters())
+        leaves = [p._data for p in params]
+        for p in params:
+            leaves += jax.tree_util.tree_leaves(opt._accumulators[id(p)])
+        assert all(not a.is_deleted() for a in leaves)
+        return [np.asarray(a) for a in leaves]
+
+    @pytest.mark.parametrize("prefetch", [False, True])
+    def test_parity_with_the_undonated_step(self, prefetch):
+        base, m0, opt0 = self._run(undonated=True, prefetch=False)
+        want = self._state(m0, opt0)
+        hist, m, opt = self._run(prefetch=prefetch)
+        assert hist == pytest.approx(base, rel=1e-5)
+        got = self._state(m, opt)
+        assert len(got) == len(want) == 4 * 3   # 4 params, m and v each
+        for g, w in zip(got, want):
+            np.testing.assert_allclose(g, w, rtol=1e-5, atol=1e-7)
+        assert opt._step_count == opt0._step_count == 6
+
+    def test_default_engine_donates_the_prefit_buffers(self):
+        """No ``donate=`` argument, no flag: one step and the arrays the
+        Parameters held before ``fit`` are gone; the Parameters are not."""
+        from paddle_tpu.core import flags as flags_mod
+
+        with pytest.raises(KeyError):
+            flags_mod.get_flag("donate_buffers")
+        with pytest.raises(TypeError):
+            self._build(donate=False)
+        e, m, opt = self._build()
+        e.fit(_XY(8), epochs=1, batch_size=8)      # places + one step
+        before = [p._data for p in m.parameters()]
+        e.fit(_XY(8), epochs=1, batch_size=8)      # ONE step
+        assert all(a.is_deleted() for a in before)
+        with pytest.raises(DonatedBufferError, match="Engine"):
+            Tensor(before[0]).numpy()
+        self._state(m, opt)                        # live, readable
+        for p in m.parameters():
+            p.numpy()
 
     def test_history_finite_and_per_epoch(self):
-        hist, _ = self._run(epochs=2, donate=True, prefetch=True)
+        hist, _, _ = self._run(epochs=2, prefetch=True)
         assert len(hist) == 2
         assert all(np.isfinite(h) for h in hist)
 
     def test_abort_mid_fit_writes_back_live_params(self):
-        from paddle_tpu.distributed.auto_parallel.engine import Engine
-        from paddle_tpu.optimizer import Adam
-
         class Exploding(_XY):
-            def __getitem__(self, i):
-                if i >= 24:
+            fetches = 0
+
+            def __getitem__(self, i):       # the sampler shuffles: count
+                self.fetches += 1
+                if self.fetches > 24:       # three whole batches, then
                     raise RuntimeError("loader died mid-epoch")
                 return super().__getitem__(i)
 
-        paddle.seed(5)
-        m = nn.Sequential(nn.Linear(8, 16), nn.ReLU(), nn.Linear(16, 2))
-        opt = Adam(learning_rate=1e-3, parameters=m.parameters())
-        e = Engine(m, loss=lambda o, t: paddle.ops.mean((o - t) ** 2),
-                   optimizer=opt, donate=True)
+        e, m, opt = self._build()
+        start = [np.asarray(p._data) for p in m.parameters()]
         with pytest.raises(RuntimeError, match="loader died"):
             e.fit(Exploding(), epochs=1, batch_size=8)
-        # donation invalidated the pre-fit payloads; the finally-block
-        # writeback must leave every Parameter on a LIVE buffer
+        # the first step donated the pre-fit payloads; the finally-block
+        # writeback must leave every Parameter and accumulator on the
+        # LATEST live buffer (steps ran before the loader died)
+        got = self._state(m, opt)
+        assert opt._step_count == 3
+        assert any(not np.array_equal(g, s0)
+                   for g, s0 in zip(got, start))
         for p in m.parameters():
-            assert not p._data.is_deleted()
             p.numpy()                      # readable, no DonatedBufferError
+
+    def test_two_fits_in_a_row_on_one_engine(self):
+        """The benchmark's ``check_calls`` pattern: fit, clear the
+        accumulators written back, fit again on the same Engine."""
+        def both_calls(undonated):
+            eng, model, o = self._build(undonated)
+            eng.fit(_XY(16), epochs=1, batch_size=8)
+            o._accumulators.clear()
+            eng.fit(_XY(16), epochs=2, batch_size=8)
+            return eng, model, o
+
+        u, m_u, opt_u = both_calls(True)
+        e, m, opt = both_calls(False)
+        assert len(e.history) == 3
+        assert e.history == pytest.approx(u.history, rel=1e-5)
+        assert e._train_step._cache_size() == 1     # one step program
+        for g, w in zip(self._state(m, opt), self._state(m_u, opt_u)):
+            np.testing.assert_allclose(g, w, rtol=1e-5, atol=1e-7)
+
+    def test_replicated_state_is_what_is_donated_on_a_mesh(self):
+        """dp over 4 devices: the step is given (and consumes) the copies
+        replicated over the mesh, and the Parameters end up on them."""
+        from paddle_tpu.distributed import mesh as mesh_mod
+
+        old = mesh_mod._global_mesh
+        mesh_mod.set_mesh(mesh_mod.build_mesh(devices=jax.devices()[:4]))
+        try:
+            e, m, opt = self._build()
+            init = [p._data for p in m.parameters()]
+            assert all(len(a.sharding.device_set) == 1 for a in init)
+            e.prepare()
+            step, given = e._train_step, []
+
+            def spy(pa, opt_state, lr, x, y):
+                given.append(jax.tree_util.tree_leaves((pa, opt_state)))
+                return step(pa, opt_state, lr, x, y)
+
+            e._train_step = spy
+            e.fit(_XY(16), epochs=1, batch_size=8)
+            assert len(given) == 2
+            for leaves in given:
+                assert all(a.is_deleted() for a in leaves)
+            # model init's arrays may share the first device's buffer
+            # with the copies: dead or alive, never XLA's own error
+            for a in init:
+                try:
+                    Tensor(a).numpy()
+                except DonatedBufferError as err:
+                    assert "Engine" in str(err)
+            for p in m.parameters():
+                assert len(p._data.sharding.device_set) == 4
+                assert p._data.sharding.is_fully_replicated
+            self._state(m, opt)
+            assert step._cache_size() == 1
+        finally:
+            mesh_mod._global_mesh = old
 
     def test_engine_census_recorded(self):
         from paddle_tpu.observability.perf import memory as mem
 
         mem.reset_high_water()
-        self._run(donate=True, prefetch=True)
+        self._run(prefetch=True)
         assert mem.high_water("engine_step_donated")["total"] > 0
 
 
