@@ -299,6 +299,11 @@ M_STATE_BYTES = _metrics.gauge(
     "Resident bytes of the per-slot recurrent state (convolution windows, "
     "SSM states) reserved for max_batch slots; 0 for a pure-attention "
     "model.")
+M_WINDOW_BYTES = _metrics.gauge(
+    "paddle_tpu_serving_window_bytes",
+    "Resident bytes of the sliding-window layers' K/V rows (window plus one "
+    "prefill chunk a slot) reserved for max_batch slots; 0 for a model "
+    "with no window layer.")
 M_REQUESTS = _metrics.counter(
     "paddle_tpu_serving_requests",
     "Requests reaching a terminal status, by outcome.",

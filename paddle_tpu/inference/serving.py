@@ -32,6 +32,12 @@ free list, and admission/eviction is plain Python between ticks:
   batch shares one program, not one position): RoPE offsets for Llama,
   learned-position gathers for GPT (architecture adapters `_LlamaArch` /
   `_GPTArch`);
+* what a layer keeps follows from the model's ``cache_layout``: pages
+  (full-attention layers: the only layers with page pools, so only they
+  make a block cost bytes), a fixed ring of K/V rows a slot (sliding-window
+  layers: ``window + prefill_width`` rows whatever the context), a
+  recurrent state a slot, a device-side counter, or several of these;
+  Llama and GPT keep pages in every layer;
 * K/V pages are stored in the model's compute dtype, or as an int8 page
   pool with sidecar per-(position, head) scales (``kv_dtype="int8"`` —
   the ``nn/quant`` weight-only pattern applied to KV), halving resident
@@ -88,7 +94,10 @@ __all__ = ["BlockManager", "Request", "PagedEngine", "LlamaPagedEngine",
 
 class BlockManager:
     """Physical-block free list (block 0 is the reserved trash block idle
-    slots write into)."""
+    slots write into). One table serves the model: a block id indexes the
+    page pool of every ``paged_kv`` layer, so a request's demand in blocks
+    does not depend on how many layers page (layers that keep a window or a
+    recurrent state hold no pages); what a block costs in bytes does."""
 
     def __init__(self, num_blocks: int):
         if num_blocks < 2:
@@ -240,7 +249,8 @@ def _pick_arch(model):
     from ..models.llama import LlamaForCausalLM
     if hasattr(model, "paged_adapter"):
         # the protocol: a model brings its own adapter (``cfg``,
-        # ``num_kv_heads``, ``head_dim``, ``cache_layout(dtype)`` and
+        # ``num_kv_heads``, ``head_dim``, ``cache_layout(dtype)``: one entry
+        # a layer, a state or a tuple of states, and
         # ``forward_chunk(tokens, start, cache, logits_t)``)
         return model.paged_adapter()
     if isinstance(model, LlamaForCausalLM):
@@ -251,9 +261,13 @@ def _pick_arch(model):
         return _DenseArch(model)
     raise TypeError(
         f"PagedEngine supports LlamaForCausalLM / GPTForCausalLM (or "
-        f"subclasses), models that bring a paged_adapter(), and "
-        f"dense-scoring models exposing serve_dense(); "
-        f"got {type(model).__name__}")
+        f"subclasses), models that bring a paged_adapter() (cfg, "
+        f"num_kv_heads, head_dim, forward_chunk(tokens, start, cache, "
+        f"logits_t) and cache_layout(dtype): per layer None, one state "
+        f"('paged_kv',) / ('window_kv', window) / ('slot_state', "
+        f"{{name: (shape, dtype)}}) / ('accumulator', shape, dtype), or a "
+        f"tuple of such states), and dense-scoring models exposing "
+        f"serve_dense(); got {type(model).__name__}")
 
 
 def _tuned_decode_block_size(cfg, nkv, max_batch, max_blocks_per_seq,
@@ -401,6 +415,13 @@ class _PagedCache:
       chunk's K/V pages and attend over the slot's block table. Cache
       entries are arrays (float pages) or (payload, scales) tuples (int8
       pages) — the structure picks the kernel path at trace time.
+    * ``attend(li, q, k, v, window=w)`` — window K/V (sliding-window
+      attention layers): per SLOT a fixed ring of rows that does not grow
+      with the sequence; the chunk's K/V are written round-robin by
+      position and a query sees the ``w`` positions up to its own
+      (``nn.functional.window_ring_attention``). It follows ``recur``'s
+      rules: rows a lane's own sequence did not write are never seen, left
+      padding and the ``seq = 0`` sentinel lanes write nothing.
     * ``recur(li, fn)`` — per-SLOT state that does not grow with the
       sequence (a convolution window, an SSM state): ``fn(state) -> (out,
       new state)`` runs on the lanes' states. A lane whose chunk starts a
@@ -411,26 +432,35 @@ class _PagedCache:
     * ``accumulate(li, delta)`` — a device-side counter carried with the
       caches (expert load), read by the host only on request.
 
-    ``states`` is a flat list over the layers that keep slot state or a
-    counter; ``lanes`` (B,) maps the chunk's rows to slots (None: row i is
+    A layer may keep more than one state (attention state and an expert
+    counter): ``index`` maps ``(layer, kind)`` to the state's position.
+    ``states`` is a flat list over the window rows, slot states and
+    counters; ``lanes`` (B,) maps the chunk's rows to slots (None: row i is
     slot i, the decode batch)."""
 
     def __init__(self, index, kcs, vcs, states, tables, seq_lens, start,
                  lanes, width):
-        # layer -> (kind, position); None: K/V in every layer
-        self.index = index if index is not None else {
-            li: ("paged_kv", li) for li in range(len(kcs))}
+        # (layer, kind) -> position; None: paged K/V in every layer. The
+        # form of one state a layer, layer -> (kind, position), is taken too
+        if index is None:
+            index = {(li, "paged_kv"): li for li in range(len(kcs))}
+        self.index = {
+            (key if isinstance(key, tuple) else (key, at[0])):
+            (at if isinstance(key, tuple) else at[1])
+            for key, at in index.items()}
         self.kcs, self.vcs, self.states = kcs, vcs, list(states)
         self.tables, self.seq_lens = Tensor(tables), Tensor(seq_lens)
         self.start, self.lanes = start, lanes
         self.valid = (start[:, None]
                       + jnp.arange(width, dtype=start.dtype)[None, :]) >= 0
 
-    def attend(self, li, q, k, v):
+    def attend(self, li, q, k, v, window=None):
         import paddle_tpu.nn.functional as F
 
+        if window is not None:
+            return self._attend_window(li, q, k, v, window)
         kcs, vcs = self.kcs, self.vcs
-        li = self.index[li][1]
+        li = self.index[li, "paged_kv"]
         if isinstance(kcs[li], tuple):
             (kp, ksc), (vp, vsc) = kcs[li], vcs[li]
             out, nkp, nvp, nks, nvs = F.block_multihead_attention(
@@ -447,16 +477,43 @@ class _PagedCache:
             vcs[li] = nvc._data
         return out
 
-    def recur(self, li, fn):
-        at = self.index[li][1]
-        whole = self.states[at]            # {name: (max_batch, ...)}
+    def _lane_rows(self, whole):
+        """The chunk's lanes of a per-slot state ``{name: (max_batch,
+        ...)}``."""
         lanes = self.lanes
         if lanes is None:
-            mine = whole
-        else:
-            mine = {k: jnp.concatenate(
-                [jax.lax.dynamic_slice_in_dim(v, lanes[b], 1)
-                 for b in range(lanes.shape[0])]) for k, v in whole.items()}
+            return whole
+        return {k: jnp.concatenate(
+            [jax.lax.dynamic_slice_in_dim(v, lanes[b], 1)
+             for b in range(lanes.shape[0])]) for k, v in whole.items()}
+
+    def _put_lane_rows(self, at, whole, new):
+        lanes = self.lanes
+        if lanes is None:
+            self.states[at] = new
+            return
+        for k, v in new.items():
+            for b in range(lanes.shape[0]):
+                whole[k] = jax.lax.dynamic_update_slice_in_dim(
+                    whole[k], v[b:b + 1], lanes[b], 0)
+        self.states[at] = whole
+
+    def _attend_window(self, li, q, k, v, window):
+        import paddle_tpu.nn.functional as F
+
+        at = self.index[li, "window_kv"]
+        whole = dict(self.states[at])      # {"k", "v": (max_batch, R, ..)}
+        mine = self._lane_rows(whole)
+        out, nk, nv = F.window_ring_attention(
+            q, Tensor(mine["k"]), Tensor(mine["v"]), self.seq_lens, k, v,
+            window=window)
+        self._put_lane_rows(at, whole, {"k": nk._data, "v": nv._data})
+        return out
+
+    def recur(self, li, fn):
+        at = self.index[li, "slot_state"]
+        whole = dict(self.states[at])      # {name: (max_batch, ...)}
+        mine = self._lane_rows(whole)
 
         def per_lane(flag, v):
             return flag.reshape((-1,) + (1,) * (v.ndim - 1))
@@ -467,18 +524,11 @@ class _PagedCache:
         idle = ~jnp.any(self.valid, axis=1)
         new = {k: jnp.where(per_lane(idle, v), mine[k], v.astype(mine[k].dtype))
                for k, v in new.items()}
-        if lanes is None:
-            self.states[at] = new
-        else:
-            for k, v in new.items():
-                for b in range(lanes.shape[0]):
-                    whole[k] = jax.lax.dynamic_update_slice_in_dim(
-                        whole[k], v[b:b + 1], lanes[b], 0)
-            self.states[at] = whole
+        self._put_lane_rows(at, whole, new)
         return out
 
     def accumulate(self, li, delta):
-        at = self.index[li][1]
+        at = self.index[li, "accumulator"]
         self.states[at] = self.states[at] + delta.astype(
             self.states[at].dtype)
 
@@ -490,19 +540,37 @@ def _max_over_mean(tokens):
             for row in tokens]
 
 
-def _cache_index(layout):
-    """layer -> (kind, position among the layers of its kind's list):
-    paged K/V layers index ``kcs`` / ``vcs``, slot-state and accumulator
-    layers share the flat ``states`` list."""
-    index, pages, states = {}, 0, 0
+_STATE_KINDS = ("paged_kv", "window_kv", "slot_state", "accumulator")
+
+
+def _layer_states(layout):
+    """``(layer, state)`` for every state of a ``cache_layout``, in order:
+    a layer's entry is None, one state (a tuple that starts with its
+    kind) or a tuple of states."""
     for li, entry in enumerate(layout):
         if entry is None:
             continue
-        if entry[0] == "paged_kv":
-            index[li] = ("paged_kv", pages)
+        for state in ((entry,) if isinstance(entry[0], str) else entry):
+            if state[0] not in _STATE_KINDS:
+                raise ValueError(f"layer {li}: unknown cache state kind "
+                                 f"{state[0]!r}; known: {_STATE_KINDS}")
+            yield li, state
+
+
+def _cache_index(layout):
+    """``(layer, kind)`` -> position among the states of its list: paged
+    K/V states index ``kcs`` / ``vcs``; window rows, slot states and
+    accumulators share the flat ``states`` list. A layer keeps at most one
+    state of a kind."""
+    index, pages, states = {}, 0, 0
+    for li, state in _layer_states(layout):
+        if (li, state[0]) in index:
+            raise ValueError(f"layer {li} declares two {state[0]!r} states")
+        if state[0] == "paged_kv":
+            index[li, "paged_kv"] = pages
             pages += 1
         else:
-            index[li] = (entry[0], states)
+            index[li, state[0]] = states
             states += 1
     return index
 
@@ -583,6 +651,14 @@ def _dense_forward(arch, params, param_arrays, ids):
 class PagedEngine:
     """Continuous-batching engine for causal LMs (paged KV caches).
 
+    What a layer keeps follows from the model's ``cache_layout``: pages of
+    K/V that grow with the sequence (``paged_kv``: full-attention layers;
+    only these have page pools, and only they make a block cost bytes), a
+    fixed ring of K/V rows a slot (``window_kv``: sliding-window layers), a
+    recurrent state a slot (``slot_state``), a device-side counter
+    (``accumulator``), or several of these. Llama and GPT keep pages in
+    every layer.
+
     Dense-scoring models (anything exposing ``serve_dense`` /
     ``serve_dense_width``, e.g. :class:`~paddle_tpu.models.DLRM`) run
     on the same engine through the dense path: no KV pool, one forward
@@ -601,6 +677,13 @@ class PagedEngine:
 
         self.model = model
         self.arch = _pick_arch(model)
+        # the adapter rides in the compiled programs that engines of one
+        # model share (``_PAGED_JIT_CACHE``, entries keyed weakly by the
+        # model): it reaches the model through a weak proxy, or an entry's
+        # value would hold its own key, and a model that every engine and
+        # caller has let go of would keep its parameters on the device
+        if getattr(self.arch, "model", None) is model:
+            self.arch.model = weakref.proxy(model)
         self._dense = isinstance(self.arch, _DenseArch)
         self.cfg = model.cfg
         self.max_batch = max_batch
@@ -669,6 +752,7 @@ class PagedEngine:
         compute_dtype = next(
             (p._data.dtype for p in model.parameters()
              if jnp.issubdtype(p._data.dtype, jnp.floating)), jnp.float32)
+        self._compute_dtype = compute_dtype
         if self._kv_int8:
             kv_dtype = jnp.int8
         elif kv_dtype is None:
@@ -676,12 +760,14 @@ class PagedEngine:
         self.kv_dtype = jnp.dtype(kv_dtype)
         self._kv_shape = (num_blocks, block_size, nkv, self.head_dim)
         self._kv_scale_shape = (num_blocks, block_size, nkv)
-        # ---- the cache states, one declaration a layer: ``paged_kv``
-        # (a K and a V page pool, attention layers), ``slot_state``
-        # (arrays [max_batch, ...] that do not grow with the sequence: a
-        # recurrent layer's window and state) or ``accumulator`` (a
-        # device-side counter). A model's own adapter declares them; Llama
-        # and GPT keep K/V in every layer. The dense path keeps none.
+        # ---- the cache states a layer declares: ``paged_kv`` (a K and a
+        # V page pool, full-attention layers), ``window_kv`` (K/V rows
+        # [max_batch, window + prefill_width, KVH, D] written round-robin:
+        # sliding-window layers), ``slot_state`` (arrays [max_batch, ...]
+        # that do not grow with the sequence: a recurrent layer's window
+        # and state), ``accumulator`` (a device-side counter), or a tuple
+        # of these. A model's own adapter declares them; Llama and GPT
+        # keep pages in every layer. The dense path keeps none.
         if self._dense:
             self._layout = []
         elif hasattr(self.arch, "cache_layout"):
@@ -689,9 +775,10 @@ class PagedEngine:
         else:
             self._layout = [("paged_kv",)] * cfg.num_layers
         self._cache_index = _cache_index(self._layout)
-        self._has_slot_state = any(
-            e is not None and e[0] == "slot_state" for e in self._layout)
-        if self._has_slot_state and speculate is not None:
+        kinds = {state[0] for _li, state in _layer_states(self._layout)}
+        #: whether a prefill chunk must say which slot its rows belong to
+        self._has_slot_state = bool(kinds & {"slot_state", "window_kv"})
+        if "slot_state" in kinds and speculate is not None:
             raise TypeError(
                 "speculate= needs a state rollback this engine does not "
                 "have: a verify step feeds k draft tokens through the "
@@ -699,6 +786,13 @@ class PagedEngine:
                 "its update of the per-slot state (conv window, SSM state) "
                 "back. Serve a model with slot_state layers without "
                 "speculate=.")
+        if "window_kv" in kinds and speculate is not None:
+            raise TypeError(
+                "speculate= needs a state rollback this engine does not "
+                "have: a verify step writes k draft tokens' K/V into the "
+                "window layers' rows over the oldest positions they hold, "
+                "and a rejected draft would have to take its rows back. "
+                "Serve a model with window_kv layers without speculate=.")
         self.kc, self.vc, self.state = self._fresh_caches()
 
         self.tables = np.zeros((max_batch, max_blocks_per_seq), np.int32)
@@ -790,6 +884,7 @@ class PagedEngine:
                                      lambda e: (e.kc, e.vc))
         _res.M_KV_BYTES_PER_TOKEN.set(self.kv_bytes_per_token)
         _res.M_STATE_BYTES.set(self.state_bytes_per_slot * max_batch)
+        _res.M_WINDOW_BYTES.set(self.window_bytes_per_slot * max_batch)
         #: host-side totals of the expert-load accumulators, one row a
         #: layer that has one (see ``expert_load``)
         self._expert_load, self._expert_load_t = None, 0.0
@@ -807,37 +902,46 @@ class PagedEngine:
                     jnp.zeros(self._kv_scale_shape, jnp.float32))
         return jnp.zeros(self._kv_shape, self.kv_dtype)
 
+    def _window_rows(self, window: int) -> int:
+        """Rows a slot holds in a window layer: the window plus one prefill
+        chunk (a chunk's first query still sees the window before it after
+        the chunk's last row is written), in whole sublane tiles."""
+        return -(-(window + self.prefill_width) // 8) * 8
+
     def _fresh_caches(self):
         """``(kc, vc, state)`` zeroed: a K and a V pool per ``paged_kv``
-        layer, and per ``slot_state`` / ``accumulator`` layer its arrays
-        (in the order of ``_cache_index``)."""
+        state, and per ``window_kv`` / ``slot_state`` / ``accumulator``
+        state its arrays (in the order of ``_cache_index``)."""
         kc, vc, state = [], [], []
-        for entry in self._layout:
-            if entry is None:
-                continue
+        for _li, entry in _layer_states(self._layout):
             if entry[0] == "paged_kv":
                 kc.append(self._fresh_cache())
                 vc.append(self._fresh_cache())
+            elif entry[0] == "window_kv":
+                shape = (self.max_batch, self._window_rows(entry[1]),
+                         self.num_kv_heads, self.head_dim)
+                state.append({"k": jnp.zeros(shape, self._compute_dtype),
+                              "v": jnp.zeros(shape, self._compute_dtype)})
             elif entry[0] == "slot_state":
                 state.append({
                     name: jnp.zeros((self.max_batch,) + tuple(shape), dtype)
                     for name, (shape, dtype) in entry[1].items()})
-            elif entry[0] == "accumulator":
-                state.append(jnp.zeros(tuple(entry[1]), entry[2]))
             else:
-                raise ValueError(f"unknown cache state kind {entry[0]!r}")
+                state.append(jnp.zeros(tuple(entry[1]), entry[2]))
         return kc, vc, state
 
     @property
     def kv_bytes_per_token(self) -> int:
-        """Resident KV bytes one cached token costs across all layers
-        (the resident-batch ceiling is HBM / (this * mean seq len))."""
+        """Resident KV bytes one cached token costs across the layers that
+        page (``paged_kv``; window and recurrent layers cost a slot, not a
+        token); the resident-batch ceiling is HBM / (this * mean seq
+        len)."""
         if self._dense:
             return 0                     # dense path keeps no KV state
         per = self.num_kv_heads * self.head_dim * self.kv_dtype.itemsize
         if self._kv_int8:
             per += self.num_kv_heads * 4          # sidecar fp32 scale
-        return 2 * len(self.kc) * per     # K and V, attention layers only
+        return 2 * len(self.kc) * per     # K and V, paged layers only
 
     @property
     def state_bytes_per_slot(self) -> int:
@@ -846,9 +950,20 @@ class PagedEngine:
         not grow with the sequence and is reserved for ``max_batch``
         slots whether they are in use or not."""
         return sum(int(np.prod(shape)) * jnp.dtype(dtype).itemsize
-                   for entry in self._layout
-                   if entry is not None and entry[0] == "slot_state"
+                   for _li, entry in _layer_states(self._layout)
+                   if entry[0] == "slot_state"
                    for shape, dtype in entry[1].values())
+
+    @property
+    def window_bytes_per_slot(self) -> int:
+        """Resident bytes one slot costs in the sliding-window layers: K
+        and V rows for the window plus one prefill chunk, whatever the
+        context (0 for a model with no such layer)."""
+        row = (2 * self.num_kv_heads * self.head_dim
+               * jnp.dtype(self._compute_dtype).itemsize)
+        return sum(self._window_rows(entry[1]) * row
+                   for _li, entry in _layer_states(self._layout)
+                   if entry[0] == "window_kv")
 
     def _program_key(self, phase, tokens_shape):
         return (phase, tuple(tokens_shape), self._kv_shape,
@@ -878,7 +993,7 @@ class PagedEngine:
         ``paddle_tpu_moe_*``. A caller that polls (``health()``) passes
         ``max_age_s`` and gets the last reading while it is younger than
         that. None for a model with no such layer."""
-        at = [(li, pos) for li, (kind, pos) in self._cache_index.items()
+        at = [(li, pos) for (li, kind), pos in self._cache_index.items()
               if kind == "accumulator"]
         if not at:
             return None
@@ -1950,6 +2065,7 @@ class PagedEngine:
              "kv_dtype": str(self.kv_dtype),
              "kv_bytes_per_token": self.kv_bytes_per_token,
              "state_bytes_per_slot": self.state_bytes_per_slot,
+             "window_bytes_per_slot": self.window_bytes_per_slot,
              "decode_attention": self.decode_attention,
              "ticks": self._ticks,
              "tick_failures": self.tick_failures,

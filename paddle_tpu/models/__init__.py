@@ -1,7 +1,8 @@
 """Model zoo (reference: python/paddle/vision/models + the GPT fixtures the
 reference uses for auto-parallel tests, test/auto_parallel/get_gpt_model.py).
 These are the BASELINE.md ladder configs: LeNet, ResNet, BERT, GPT, LLaMA,
-and the Nemotron-H hybrid (Mamba-2 + attention + latent experts).
+the Nemotron-H hybrid (Mamba-2 + attention + latent experts) and the
+K-EXAONE decoder (window + full attention, SwiGLU experts).
 """
 from .lenet import LeNet
 from .gpt import GPTConfig, GPTModel, GPTForCausalLM, gpt2_small, gpt2_medium
@@ -12,6 +13,8 @@ from .llama import (LlamaConfig, LlamaForCausalLM, LlamaModel,
 from .dlrm import DLRM, DLRMConfig, dlrm_tiny
 from .nemotron_h import (NemotronHConfig, NemotronHForCausalLM,
                          NemotronHModel, nemotron_h_tiny)
+from .exaone_moe import (ExaoneMoeConfig, ExaoneMoeForCausalLM,
+                         ExaoneMoeModel, exaone_moe_tiny)
 
 __all__ = [
     "LeNet", "GPTConfig", "GPTModel", "GPTForCausalLM",
@@ -23,4 +26,6 @@ __all__ = [
     "gpt2_small", "gpt2_medium",
     "NemotronHConfig", "NemotronHModel", "NemotronHForCausalLM",
     "nemotron_h_tiny",
+    "ExaoneMoeConfig", "ExaoneMoeModel", "ExaoneMoeForCausalLM",
+    "exaone_moe_tiny",
 ]

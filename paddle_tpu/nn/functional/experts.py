@@ -19,7 +19,8 @@ import jax.numpy as jnp
 from ...core import dispatch
 from ...core.tensor import Tensor, as_tensor
 
-__all__ = ["sigmoid_topk_route", "held_experts_relu2"]
+__all__ = ["sigmoid_topk_route", "held_experts_relu2",
+           "held_experts_swiglu"]
 
 
 def _t(x):
@@ -68,15 +69,24 @@ def load_arrays(idx, lo, held, valid=None):
                             jnp.sum(live, dtype=jnp.int32)[None]])
 
 
-def experts_arrays(x, combine, w1, w2):
-    """``sum_e combine[:, e] * relu(x W1_e)^2 W2_e`` over the held
-    experts: the product over ALL of them with each token's unchosen
-    experts weighted 0. ``x`` (n, latent), ``combine`` (n, E_held),
-    ``w1`` (E_held, latent, width), ``w2`` (E_held, width, latent)."""
+def experts_arrays(x, combine, mats):
+    """``sum_e combine[:, e] * E_e(x)`` over the held experts: the product
+    over ALL of them with each token's unchosen experts weighted 0. The
+    expert's form follows from the matrices it is made of: two, ``(w1,
+    w2)``, give ``relu(x W1_e)^2 W2_e``; three, ``(gate, up, down)``, the
+    SwiGLU ``(silu(x G_e) * x U_e) D_e``. ``x`` (n, in), ``combine``
+    (n, E_held), first matrices (E_held, in, width), last (E_held, width,
+    out)."""
     f32 = jnp.float32
-    h = jnp.einsum("nl,elf->enf", x, w1, preferred_element_type=f32)
-    h = jnp.square(jax.nn.relu(h)) * combine.T[:, :, None]
-    return jnp.einsum("enf,efl->nl", h.astype(x.dtype), w2,
+    *first, last = mats
+    h = jnp.einsum("nl,elf->enf", x, first[0], preferred_element_type=f32)
+    if len(first) == 1:
+        h = jnp.square(jax.nn.relu(h))
+    else:
+        h = jax.nn.silu(h) * jnp.einsum("nl,elf->enf", x, first[1],
+                                        preferred_element_type=f32)
+    h = h * combine.T[:, :, None]
+    return jnp.einsum("enf,efl->nl", h.astype(x.dtype), last,
                       preferred_element_type=f32).astype(x.dtype)
 
 
@@ -96,6 +106,26 @@ def sigmoid_topk_route(u, gate, bias, k, scale=1.0, normalize=True,
                "normalize": bool(normalize)})
 
 
+def _held_experts(op, x, idx, weights, mats, lo, valid):
+    """The held experts' part of a routed sum, dropless, for an expert
+    made of ``mats`` (``experts_arrays`` has the forms)."""
+    mats = [_t(m) for m in mats]
+    held = mats[0].shape[0]
+    inputs = [_t(x), _t(idx), _t(weights), *mats]
+    if valid is not None:
+        inputs.append(_t(valid))
+
+    def f(xa, ia, wa, *rest, **_attrs):
+        ms, va = rest[:len(mats)], rest[len(mats):]
+        combine = combine_arrays(ia, wa, lo, held, va[0] if va else None)
+        return experts_arrays(xa, combine, ms)
+
+    return dispatch.call(
+        op, f, inputs, attrs={"lo": int(lo)},
+        differentiable_mask=[True, False, True] + [True] * len(mats)
+        + [False] * (valid is not None))
+
+
 def held_experts_relu2(x, idx, weights, w1, w2, lo=0, valid=None,
                        name=None):
     """The held experts' part of a routed sum, dropless: for every token
@@ -104,16 +134,14 @@ def held_experts_relu2(x, idx, weights, w1, w2, lo=0, valid=None,
     ``idx`` / ``weights`` (n, k), ``w1`` (E_held, latent, width), ``w2``
     (E_held, width, latent); ``valid`` (n,) bool drops padding rows.
     Returns (n, latent)."""
-    held = _t(w1).shape[0]
-    inputs = [_t(x), _t(idx), _t(weights), _t(w1), _t(w2)]
-    if valid is not None:
-        inputs.append(_t(valid))
+    return _held_experts("held_experts_relu2", x, idx, weights, (w1, w2),
+                         lo, valid)
 
-    def f(xa, ia, wa, w1a, w2a, *va, **_attrs):
-        combine = combine_arrays(ia, wa, lo, held, va[0] if va else None)
-        return experts_arrays(xa, combine, w1a, w2a)
 
-    return dispatch.call(
-        "held_experts_relu2", f, inputs, attrs={"lo": int(lo)},
-        differentiable_mask=[True, False, True, True, True]
-        + [False] * (valid is not None))
+def held_experts_swiglu(x, idx, weights, w_gate, w_up, w_down, lo=0,
+                        valid=None, name=None):
+    """As ``held_experts_relu2`` for SwiGLU experts: ``sum_j weights[j] *
+    D_e (silu(G_e x) * U_e x)``. ``w_gate`` / ``w_up`` (E_held, hidden,
+    width), ``w_down`` (E_held, width, hidden). Returns (n, hidden)."""
+    return _held_experts("held_experts_swiglu", x, idx, weights,
+                         (w_gate, w_up, w_down), lo, valid)

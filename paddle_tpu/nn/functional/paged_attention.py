@@ -26,6 +26,20 @@ backend (``attention_path``), never from a flag a caller sets:
   sequence it gathers the blocks of its table into a contiguous
   (S_max, KVH, D) view and runs masked attention in float32 — gathers + one
   MXU einsum, all static shapes, fully jittable.
+* **the blockwise composite** — the composite's write path (``new_k``
+  given, float pages) where a table spans more than ``BLOCKWISE_FROM``
+  tokens, so that the scores over a whole table, (T, H, S_max) in float32,
+  would be hundreds of megabytes a call (1.2 GB for a 256-token chunk of 64
+  heads over 18 432 positions): the same masked attention taken over groups
+  of pages with an online softmax, up to the sequence's own length and no
+  further. Nothing holds scores wider than a group; a chunk early in a
+  long context costs what its context asks, not what the table could hold.
+
+Beside the paged layers, **the window path** (``window_ring_attention``):
+sliding-window layers keep no pages. A sequence holds a fixed ring of K/V
+rows, the window plus one chunk, written round-robin by position; a query
+at position i sees key j iff ``0 <= i - j < window``. Plain XLA over the
+ring's rows, which do not grow with the sequence.
 
 Two things a trace cannot see are settled where they can be seen. *How many
 devices the program is compiled for* is known when it is lowered: GSPMD
@@ -38,8 +52,8 @@ differentiated* is known to JAX: the kernel path's derivative rule is the
 composite's (``jax.custom_jvp``), so ``jax.vjp`` / ``jax.grad`` through a
 call run the composite, value and gradient.
 
-Both sit under the named scope ``paged_attention``. ``log_paths`` collects
-which one each call inside it was lowered to.
+All sit under the named scope ``paged_attention``. ``log_paths`` collects
+which of kernel and composite each paged call inside it was lowered to.
 
 int8 page pool (the serving tier's ``kv_dtype="int8"`` knob): pass int8
 caches plus sidecar per-(position, head) scale arrays ``k_scale`` /
@@ -65,7 +79,17 @@ from ...core import dispatch, flags
 from ...core.tensor import Tensor, as_tensor
 from ...ops.pallas.serving import kv_dequantize_int8, kv_quantize_int8
 
-__all__ = ["block_multihead_attention"]
+__all__ = ["block_multihead_attention", "window_ring_attention"]
+
+#: tokens a block table must span before the composite's write path is taken
+#: blockwise (the module docstring has why); the serving configurations up
+#: to a context of 4096 keep the program they had
+BLOCKWISE_FROM = 4096
+
+#: tokens of K/V one step of the blockwise composite attends over: wide
+#: enough that a step's products hide the loop's overhead, and the float32
+#: scores of a 256-token chunk of 64 heads stay at 64 MiB
+BLOCKWISE_GROUP_TOKENS = 1024
 
 
 def _t(x):
@@ -147,6 +171,94 @@ def _gather_attend(qa, kca, vca, bta, sla, ksa, vsa, causal, sc):
         return o.reshape(T, H, D).astype(qb.dtype)
 
     return jax.vmap(per_seq)(bta, sla, qa)
+
+
+def _weighted_values(eq, p, v):
+    """``einsum(eq, p, v)`` with float32 probabilities ``p`` and float32
+    accumulation. Values narrower than float32 meet the probabilities as
+    two halves of the values' dtype (``p = hi + lo``, 16 bits of mantissa
+    for bfloat16), as the decode kernel does; float32 values are multiplied
+    at full precision."""
+    if v.dtype == jnp.float32:
+        return jnp.einsum(eq, p, v, precision=jax.lax.Precision.HIGHEST)
+    hi = p.astype(v.dtype)
+    lo = (p - hi.astype(jnp.float32)).astype(v.dtype)
+    return (jnp.einsum(eq, hi, v, preferred_element_type=jnp.float32)
+            + jnp.einsum(eq, lo, v, preferred_element_type=jnp.float32))
+
+
+def _scores(eq, q, k):
+    """``einsum(eq, q, k)`` in float32: a product of two bfloat16 numbers is
+    exact there; float32 operands are multiplied at full precision."""
+    exact = jax.lax.Precision.HIGHEST if q.dtype == jnp.float32 else None
+    return jnp.einsum(eq, q, k, preferred_element_type=jnp.float32,
+                      precision=exact)
+
+
+def _blockwise_rows(qa, kca, vca, bta, sla, causal, sc):
+    B, T, H, D = qa.shape
+    _nb, bs, KVH, _ = kca.shape
+    group = H // KVH
+    pages = max(1, BLOCKWISE_GROUP_TOKENS // bs)
+    span = pages * bs                       # tokens a step attends over
+    steps = -(-bta.shape[1] // pages)
+    bta = jnp.pad(bta, ((0, 0), (0, steps * pages - bta.shape[1])))
+
+    def per_seq(blocks, length, qb):
+        qg = qb.reshape(T, KVH, group, D)
+        qpos = length - T + jnp.arange(T)
+
+        def step(g, carry):
+            m, l, acc = carry
+            ids = jax.lax.dynamic_slice_in_dim(blocks, g * pages, pages)
+            k = kca[ids].reshape(span, KVH, D)
+            v = vca[ids].reshape(span, KVH, D)
+            s = _scores("tkgd,skd->tkgs", qg, k) * sc
+            jpos = g * span + jnp.arange(span)
+            seen = jpos[None, :] < length
+            if causal:
+                seen = jpos[None, :] <= qpos[:, None]
+            seen = jnp.broadcast_to(seen, (T, span))[:, None, None, :]
+            s = jnp.where(seen, s, -1e30)
+            m_new = jnp.maximum(m, jnp.max(s, axis=-1))
+            alpha = jnp.exp(m - m_new)
+            # a row with nothing seen yet has m_new = -1e30: exp(0) is not 0
+            p = jnp.where(seen, jnp.exp(s - m_new[..., None]), 0.0)
+            l = alpha * l + jnp.sum(p, axis=-1)
+            acc = alpha[..., None] * acc + _weighted_values(
+                "tkgs,skd->tkgd", p, v)
+            return m_new, l, acc
+
+        init = (jnp.full((T, KVH, group), -1e30, jnp.float32),
+                jnp.zeros((T, KVH, group), jnp.float32),
+                jnp.zeros((T, KVH, group, D), jnp.float32))
+        _m, l, acc = jax.lax.fori_loop(
+            0, jnp.clip(-(-length // span), 0, steps), step, init)
+        # a padded row (nothing seen) yields 0, not NaN
+        o = jnp.where(l[..., None] > 0,
+                      acc / jnp.maximum(l, 1e-30)[..., None], 0.0)
+        return o.reshape(T, H, D).astype(qb.dtype)
+
+    return jax.vmap(per_seq)(bta, sla, qa)
+
+
+@functools.partial(jax.custom_jvp, nondiff_argnums=(5, 6))
+def _blockwise_attend(qa, kca, vca, bta, sla, causal, sc):
+    """The composite over page groups with an online softmax: per sequence
+    a loop over ``ceil(length / BLOCKWISE_GROUP_TOKENS)`` groups of its
+    table, each gathered, scored and folded into a running (max, sum,
+    weighted values). Differentiated, it is the composite (a loop whose
+    trip count is data has no reverse rule)."""
+    return _blockwise_rows(qa, kca, vca, bta, sla, causal, sc)
+
+
+@_blockwise_attend.defjvp
+def _blockwise_attend_jvp(causal, sc, primals, tangents):
+    *_, bta, sla = primals
+    return jax.jvp(
+        lambda q, k, v: _gather_attend(q, k, v, bta, sla, None, None,
+                                       causal, sc),
+        primals[:3], tangents[:3])
 
 
 def _composite_decode_attend(qa, kca, vca, bta, sla, sc):
@@ -250,6 +362,9 @@ def _write_and_attend(qa, kca, vca, bta, sla, new, scales, *, causal, scale,
     sc = scale if scale is not None else 1.0 / (D ** 0.5)
     if use_kernel:
         out = _decode_attend(qa, kca, vca, bta_i, sla_i, sc)
+    elif (new is not None and not quantized
+          and bta.shape[1] * bs > BLOCKWISE_FROM):
+        out = _blockwise_attend(qa, kca, vca, bta_i, sla_i, causal, sc)
     else:
         out = _gather_attend(qa, kca, vca, bta_i, sla_i, ksa, vsa, causal,
                              sc)
@@ -324,3 +439,78 @@ def block_multihead_attention(q, key_cache, value_cache, block_tables,
             + [True, True] * has_new + [False, False] * quantized)
     return dispatch.call("block_multihead_attention", f, tensors,
                          differentiable_mask=mask)
+
+
+@functools.partial(jax.jit, static_argnames=("window", "scale"))
+def _ring_write_and_attend(qa, kra, vra, sla, nk, nv, *, window, scale):
+    """One ``window_ring_attention`` call on arrays: write the chunk's K/V
+    into the rows their positions map to, then attend over the ring under
+    the window mask."""
+    B, T, H, D = qa.shape
+    _b, R, KVH, _ = kra.shape
+    if H % KVH:
+        raise ValueError(f"H={H} not a multiple of KVH={KVH}")
+    if R < window + T - 1:
+        raise ValueError(
+            f"a ring of {R} rows cannot hold a window of {window} beside a "
+            f"chunk of {T}: the chunk's last row would overwrite a row its "
+            f"first query still sees")
+    sla_i = sla.astype(jnp.int32)
+    pos = sla_i[:, None] - T + jnp.arange(T)[None, :]         # (B, T)
+    # position p lives in row p % R; a negative position (left padding,
+    # the seq = 0 sentinel) writes nothing: row R is out of range
+    row = jnp.where(pos >= 0, pos % R, R)
+    lane = jnp.arange(B)[:, None]
+    kra = kra.at[lane, row].set(nk.astype(kra.dtype), mode="drop")
+    vra = vra.at[lane, row].set(nv.astype(vra.dtype), mode="drop")
+    # the position each row holds once the chunk is written: the newest
+    # p <= last with p % R == r; negative: nothing of this sequence yet
+    last = sla_i[:, None] - 1
+    held = last - (last - jnp.arange(R)[None, :]) % R          # (B, R)
+    back = pos[:, :, None] - held[:, None, :]                  # (B, T, R)
+    seen = ((held[:, None, :] >= 0) & (pos[:, :, None] >= 0)
+            & (back >= 0) & (back < window))[:, :, None, None, :]
+    sc = scale if scale is not None else 1.0 / (D ** 0.5)
+    qg = qa.reshape(B, T, KVH, H // KVH, D)
+    s = _scores("btkgd,brkd->btkgr", qg, kra) * sc
+    s = jnp.where(seen, s, -1e30)
+    p = jnp.where(seen, jax.nn.softmax(s, axis=-1), 0.0)
+    o = _weighted_values("btkgr,brkd->btkgd", p, vra)
+    return o.reshape(B, T, H, D).astype(qa.dtype), kra, vra
+
+
+def window_ring_attention(q, key_rows, value_rows, seq_lens, new_k, new_v,
+                          window, scale=None, name=None):
+    """Sliding-window attention over a per-sequence ring of K/V rows.
+
+    Args:
+      q: (B, T, H, D) queries for the T newest positions of each sequence.
+      key_rows / value_rows: (B, R, KVH, D), ``R >= window + T - 1``: the
+         rows sequence b holds; position p lives in row ``p % R``.
+      seq_lens: (B,) int32 sequence lengths INCLUDING the new T tokens. A
+         row of the chunk at a negative position (left padding; every row
+         of a ``seq_len <= 0`` lane) writes nothing and yields zeros.
+      new_k / new_v: (B, T, KVH, D), written before attending.
+      window: query i sees key j iff ``0 <= i - j < window``.
+
+    A row holds something of this sequence only if the sequence wrote it:
+    the position a row is read as follows from ``seq_lens`` alone, so what
+    an earlier occupant of the rows left behind is never seen and the rows
+    need no clearing between sequences.
+
+    Returns (out (B, T, H, D), key_rows, value_rows); the rows update
+    functionally (donate them in a jitted serving step)."""
+    tensors = [_t(q), _t(key_rows), _t(value_rows), _t(seq_lens),
+               _t(new_k), _t(new_v)]
+
+    def f(qa, kra, vra, sla, nk, nv):
+        # under the paged layers' scope as well: the share of a serving
+        # program that is attention over cached K/V reads one scope
+        with jax.named_scope("paged_attention"):
+            return _ring_write_and_attend(
+                qa, kra, vra, sla, nk, nv, window=int(window),
+                scale=None if scale is None else float(scale))
+
+    return dispatch.call("window_ring_attention", f, tensors,
+                         differentiable_mask=[True, True, True, False,
+                                              True, True])

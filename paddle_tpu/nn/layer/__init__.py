@@ -20,3 +20,4 @@ from .rnn import (RNN, BiRNN, GRU, GRUCell, LSTM, LSTMCell, RNNCellBase,
                   SimpleRNN, SimpleRNNCell)
 from .tail import *        # noqa: F401,F403
 from .latent_moe import LatentMoE
+from .swiglu_moe import SwiGLUMoE

@@ -1,0 +1,93 @@
+"""Mixture of SwiGLU experts with a shared expert, told which experts it
+holds.
+
+The DeepSeek-V3 form that K-EXAONE's sparse layers take: a float32 sigmoid
+router over ALL ``num_experts`` (``functional.sigmoid_topk_route``), routed
+experts at full width, each ``down(silu(gate x) * up x)``, and one shared
+expert of the same form beside them that every token passes through. Like
+``LatentMoE`` the layer holds experts ``[lo, hi)`` as stacked parameters and
+computes only their part of the routed sum, which is what expert parallelism
+asks of a chip; the exchange that would bring the other chips' parts is not
+here.
+"""
+from __future__ import annotations
+
+import jax
+
+from ...core.tensor import Tensor
+from .. import functional as F
+from ..functional import experts as _experts
+from ..initializer import Constant, Normal
+from ..parameter import ParamAttr
+from .latent_moe import _linear
+from .layers import Layer
+
+__all__ = ["SwiGLUMoE"]
+
+
+class SwiGLUMoE(Layer):
+    """``sum_k w_k D_e (silu(G_e u) * U_e u) + D_s (silu(G_s u) * U_s u)``.
+
+    ``experts_held = (lo, hi)``: the routed experts whose weights live
+    here, ``w_gate`` / ``w_up`` ``[hi - lo, hidden, width]`` and ``w_down``
+    ``[hi - lo, width, hidden]``. The router stays ``num_experts`` wide and
+    picks ``top_k``. ``forward(u, valid=None)`` returns the layer's output;
+    ``forward(..., with_load=True)`` also the int32 load vector of
+    ``functional.experts.load_arrays`` (tokens per held expert, pairs
+    landed here, pairs selected)."""
+
+    def __init__(self, hidden_size: int, expert_width: int,
+                 shared_width: int, num_experts: int, top_k: int,
+                 experts_held=None, routed_scale: float = 1.0,
+                 norm_topk: bool = True, init_std: float = 0.02):
+        super().__init__()
+        lo, hi = experts_held or (0, num_experts)
+        if not 0 <= lo < hi <= num_experts:
+            raise ValueError(f"experts_held {experts_held!r} outside "
+                             f"[0, {num_experts}]")
+        self.num_experts, self.top_k = num_experts, top_k
+        self.experts_held = (lo, hi)
+        self.routed_scale, self.norm_topk = routed_scale, norm_topk
+        normal = ParamAttr(initializer=Normal(0.0, init_std))
+        self.gate_weight = self.create_parameter(
+            [hidden_size, num_experts], attr=normal)
+        self.e_score_correction_bias = self.create_parameter(
+            [num_experts], dtype="float32",
+            default_initializer=Constant(0.0))
+        self.w_gate = self.create_parameter(
+            [hi - lo, hidden_size, expert_width], attr=normal)
+        self.w_up = self.create_parameter(
+            [hi - lo, hidden_size, expert_width], attr=normal)
+        self.w_down = self.create_parameter(
+            [hi - lo, expert_width, hidden_size], attr=normal)
+        self.shared_gate = _linear(hidden_size, shared_width, init_std)
+        self.shared_up = _linear(hidden_size, shared_width, init_std)
+        self.shared_down = _linear(shared_width, hidden_size, init_std)
+
+    def shared(self, flat):
+        """The shared expert: what every chip computes alike."""
+        return self.shared_down(F.swiglu(self.shared_gate(flat),
+                                         self.shared_up(flat)))
+
+    def forward(self, u, valid=None, with_load: bool = False):
+        lo, hi = self.experts_held
+        shape = u.shape
+        flat = u.reshape([-1, shape[-1]])
+        rows = None if valid is None else valid.reshape([-1])
+        with jax.named_scope("moe.router"):
+            idx, w = F.sigmoid_topk_route(
+                flat, self.gate_weight, self.e_score_correction_bias,
+                self.top_k, scale=self.routed_scale,
+                normalize=self.norm_topk)
+        with jax.named_scope("moe.experts"):
+            routed = F.held_experts_swiglu(
+                flat, idx, w, self.w_gate, self.w_up, self.w_down, lo=lo,
+                valid=rows)
+        with jax.named_scope("moe.shared"):
+            shared = self.shared(flat)
+        out = (routed + shared).reshape(shape)
+        if not with_load:
+            return out
+        load = _experts.load_arrays(
+            idx._data, lo, hi - lo, None if rows is None else rows._data)
+        return out, Tensor(load)

@@ -513,3 +513,236 @@ def test_kernel_modules_import_pallas_without_the_gpu_interpreter():
         text=True, timeout=120,
         env=dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=root))
     assert done.returncode == 0, done.stderr[-2000:]
+
+
+# ------------------------------------------------- a table 18 432 tokens wide
+def test_kernel_walks_a_table_as_wide_as_the_longest_context(kernel_on):
+    """The K-EXAONE cell's block table, 1152 entries of 16 tokens a lane (a
+    scalar-prefetch operand 72 times the Mistral cells'): a lane that fills
+    it, one that ends mid-page deep inside it, the sentinel and a lane of
+    one token, against dense attention."""
+    H, KVH, mb, bs = 16, 2, 1152, 16
+    lens = [mb * bs, 4097, 0, 1]
+    q, kc, vc, tables, nk, nv, full = _decode_case(
+        np.random.RandomState(12), lens, H, KVH, mb)
+    import jax.numpy as jnp
+    args = [jnp.asarray(a) for a in (q, kc, vc, tables,
+                                     np.asarray(lens, np.int32), nk, nv)]
+    assert _runs_kernel(
+        lambda *a: F.block_multihead_attention(
+            *map(paddle.Tensor, a[:5]), new_k=paddle.Tensor(a[5]),
+            new_v=paddle.Tensor(a[6]))[0]._data, *args)
+    got = _decode(q, kc, vc, tables, lens, nk, nv)
+    np.testing.assert_array_equal(got[0][2], 0.0)
+    for b, L in enumerate(lens):
+        if L:
+            ref = _dense_attn(q[b], full[b][0], full[b][1], L - 1)
+            np.testing.assert_allclose(got[0][b], ref, atol=2e-5)
+
+
+# ------------------------------------------------------ blockwise composite
+class TestBlockwiseComposite:
+    """The composite taken over page groups with an online softmax against
+    the composite over the whole table (``_gather_attend``), on shapes both
+    take. Tolerance 2e-5 on outputs of order 1: float32 sums in another
+    order."""
+
+    @pytest.mark.parametrize("T", [1, 5, 16])
+    @pytest.mark.parametrize("causal", [True, False])
+    def test_is_the_composite(self, monkeypatch, T, causal):
+        import jax.numpy as jnp
+        from paddle_tpu.nn.functional import paged_attention as fpa
+        # groups of 2 pages over a table of 7: the last group is padded
+        monkeypatch.setattr(fpa, "BLOCKWISE_GROUP_TOKENS", 16)
+        rng = np.random.RandomState(20)
+        H, KVH, D, bs, mb = 4, 2, 16, 8, 7
+        # one token, a length inside the first group, group edges, the
+        # whole table, fewer tokens than the chunk (padded rows), none
+        lens = [1, 9, 16, 17, mb * bs, max(T - 2, 0), 0]
+        kc, vc, tables, _ks, _vs = _build_cache(rng, [mb * bs] * len(lens),
+                                                bs, H, KVH, D, mb)
+        q = rng.randn(len(lens), T, H, D).astype(np.float32)
+        args = (jnp.asarray(q), jnp.asarray(kc), jnp.asarray(vc),
+                jnp.asarray(tables), jnp.asarray(lens, jnp.int32))
+        want = fpa._gather_attend(*args, None, None, causal, 0.25)
+        got = fpa._blockwise_attend(*args, causal, 0.25)
+        np.testing.assert_allclose(got, want, atol=2e-5)
+        assert np.isfinite(np.asarray(got)).all()
+        if T > 2 and causal:    # a row before the first token: zeros
+            np.testing.assert_array_equal(np.asarray(got)[5, :2], 0.0)
+
+    def test_a_wide_table_takes_it_and_a_narrow_one_does_not(
+            self, monkeypatch):
+        """The write path of a table that spans more than
+        ``BLOCKWISE_FROM`` tokens never builds scores over the table; a
+        narrower one, int8 pages and read-only attention keep the
+        composite. Both against dense attention."""
+        import jax
+        import jax.numpy as jnp
+        from paddle_tpu.nn.functional import paged_attention as fpa
+        calls = []
+        inner = fpa._blockwise_rows
+        monkeypatch.setattr(
+            fpa, "_blockwise_rows",
+            lambda *a: (calls.append(a[3].shape), inner(*a))[1])
+        rng = np.random.RandomState(21)
+        H, KVH, D, bs, T = 4, 2, 16, 8, 6
+        for mb, wide in ((fpa.BLOCKWISE_FROM // bs + 1, True), (6, False)):
+            lens = [T, 20 + T, mb * bs if not wide else 300]
+            hist = [n - T for n in lens]
+            kc, vc, tables, ks, vs = _build_cache(rng, hist, bs, H, KVH, D,
+                                                  mb)
+            q = rng.randn(3, T, H, D).astype(np.float32)
+            nk = rng.randn(3, T, KVH, D).astype(np.float32)
+            nv = rng.randn(3, T, KVH, D).astype(np.float32)
+            out, _kc, _vc = F.block_multihead_attention(
+                *map(paddle.to_tensor, (q, kc, vc, tables)),
+                paddle.to_tensor(np.asarray(lens, np.int32)),
+                new_k=paddle.to_tensor(nk), new_v=paddle.to_tensor(nv))
+            assert bool(calls) == wide
+            for b, n in enumerate(lens):
+                ref = _dense_attn(q[b], np.concatenate([ks[b], nk[b]]),
+                                  np.concatenate([vs[b], nv[b]]), n - T)
+                np.testing.assert_allclose(out.numpy()[b], ref, atol=2e-5)
+            if wide:        # read-only attention stays differentiable
+                calls.clear()
+                qt = paddle.to_tensor(q)
+                qt.stop_gradient = False
+                out, _kc, _vc = F.block_multihead_attention(
+                    qt, *map(paddle.to_tensor, (kc, vc, tables)),
+                    paddle.to_tensor(np.asarray(hist, np.int32)))
+                out.sum().backward()
+                assert not calls and np.isfinite(qt.grad.numpy()).all()
+        # differentiated, the blockwise path is the composite's rule
+        args = (jnp.asarray(q), jnp.asarray(kc), jnp.asarray(vc),
+                jnp.asarray(tables), jnp.asarray(lens, jnp.int32))
+        g = jax.grad(lambda qa: fpa._blockwise_attend(
+            qa, *args[1:], True, 0.25).sum())(args[0])
+        w = jax.grad(lambda qa: fpa._gather_attend(
+            qa, *args[1:], None, None, True, 0.25).sum())(args[0])
+        np.testing.assert_allclose(g, w, atol=1e-5)
+
+
+# ------------------------------------------------------------- window rows
+def _window_dense(q, k, v, first, window):
+    """q (T,H,D) at positions first.., k/v (S,KVH,D) at positions 0..:
+    key j is seen from query i iff 0 <= i - j < window."""
+    T, H, D = q.shape
+    S, KVH, _ = k.shape
+    qg = q.reshape(T, KVH, H // KVH, D).astype(np.float64)
+    s = np.einsum("tkgd,skd->tkgs", qg, k.astype(np.float64)) / np.sqrt(D)
+    back = (first + np.arange(T))[:, None] - np.arange(S)[None, :]
+    seen = ((back >= 0) & (back < window))[:, None, None, :]
+    s = np.where(seen, s, -np.inf)
+    p = np.exp(s - s.max(-1, keepdims=True))
+    p = p / p.sum(-1, keepdims=True)
+    return np.einsum("tkgs,skd->tkgd", p, v.astype(np.float64)).reshape(
+        T, H, D)
+
+
+class TestWindowRing:
+    """``window_ring_attention``: rows written round-robin by position,
+    attended under ``0 <= i - j < window``, against dense attention over
+    the whole history under the same mask."""
+
+    H, KVH, D, WINDOW = 4, 2, 16, 128
+
+    def _feed(self, rng, total, chunk, rows, lens_of=None):
+        """Feed ``total`` tokens in chunks of ``chunk`` through a ring of
+        ``rows`` rows; returns the outputs by position and the history."""
+        H, KVH, D = self.H, self.KVH, self.D
+        k = rng.randn(total, KVH, D).astype(np.float32)
+        v = rng.randn(total, KVH, D).astype(np.float32)
+        q = rng.randn(total, H, D).astype(np.float32)
+        kr = paddle.to_tensor(np.full((1, rows, KVH, D), 7.0, np.float32))
+        vr = paddle.to_tensor(np.full((1, rows, KVH, D), 7.0, np.float32))
+        outs = []
+        for lo in range(0, total, chunk):
+            hi = lo + chunk
+            out, kr, vr = F.window_ring_attention(
+                paddle.to_tensor(q[None, lo:hi]), kr, vr,
+                paddle.to_tensor(np.asarray([hi], np.int32)),
+                paddle.to_tensor(k[None, lo:hi]),
+                paddle.to_tensor(v[None, lo:hi]), window=self.WINDOW)
+            outs.append(out.numpy()[0])
+        return np.concatenate(outs), q, k, v
+
+    @pytest.mark.parametrize("chunk,rows", [(1, 128), (64, 191), (64, 192),
+                                            (100, 384)])
+    def test_matches_dense_under_the_window_mask(self, chunk, rows):
+        """Decode steps over a ring of exactly a window, chunks over the
+        smallest ring that holds them (window + chunk - 1) and larger
+        ones; about 400 tokens, so the rows wrap. The ring starts full of
+        7s: a row the sequence did not write is never seen."""
+        total = chunk * (400 // chunk)
+        got, q, k, v = self._feed(np.random.RandomState(30), total, chunk,
+                                  rows)
+        want = _window_dense(q, k, v, 0, self.WINDOW)
+        np.testing.assert_allclose(got, want, atol=2e-5)
+
+    def test_the_window_ends_between_127_and_128_back(self):
+        """Query i sees key i - 127 and not key i - 128: changing V at
+        either shows, or does not, in query i's output."""
+        rng = np.random.RandomState(31)
+        H, KVH, D = self.H, self.KVH, self.D
+        i, rows = 300, 192
+        k = rng.randn(i + 1, KVH, D).astype(np.float32)
+        v = rng.randn(i + 1, KVH, D).astype(np.float32)
+        q = rng.randn(1, 1, H, D).astype(np.float32)
+
+        def last_output(values):
+            kr = paddle.to_tensor(np.zeros((1, rows, KVH, D), np.float32))
+            vr = paddle.to_tensor(np.zeros((1, rows, KVH, D), np.float32))
+            for lo in range(0, i, 60):         # the history, in chunks
+                hi = lo + 60
+                _o, kr, vr = F.window_ring_attention(
+                    paddle.to_tensor(np.zeros((1, 60, H, D), np.float32)),
+                    kr, vr, paddle.to_tensor(np.asarray([hi], np.int32)),
+                    paddle.to_tensor(k[None, lo:hi]),
+                    paddle.to_tensor(values[None, lo:hi]),
+                    window=self.WINDOW)
+            out, _k, _v = F.window_ring_attention(
+                paddle.to_tensor(q), kr, vr,
+                paddle.to_tensor(np.asarray([i + 1], np.int32)),
+                paddle.to_tensor(k[None, i:]),
+                paddle.to_tensor(values[None, i:]), window=self.WINDOW)
+            return out.numpy()
+
+        base = last_output(v)
+        for back, shows in ((127, True), (128, False), (0, True)):
+            moved = v.copy()
+            moved[i - back] += 50.0
+            assert (np.abs(last_output(moved) - base).max() > 1e-3) == shows
+
+    def test_padding_and_sentinel_lanes_write_nothing(self):
+        """A first chunk left-padded to its width writes only its real
+        rows and yields zeros for the padding; a lane under the ``seq = 0``
+        sentinel gets its rows back bit for bit and yields zeros."""
+        rng = np.random.RandomState(32)
+        H, KVH, D, T, rows = self.H, self.KVH, self.D, 16, 160
+        before = rng.randn(2, rows, KVH, D).astype(np.float32)
+        q = rng.randn(2, T, H, D).astype(np.float32)
+        nk = rng.randn(2, T, KVH, D).astype(np.float32)
+        nv = rng.randn(2, T, KVH, D).astype(np.float32)
+        out, kr, vr = F.window_ring_attention(
+            *map(paddle.to_tensor, (q, before, before)),
+            paddle.to_tensor(np.asarray([5, 0], np.int32)),   # 11 padded
+            paddle.to_tensor(nk), paddle.to_tensor(nv), window=self.WINDOW)
+        kr, vr, out = kr.numpy(), vr.numpy(), out.numpy()
+        np.testing.assert_array_equal(kr[1], before[1])
+        np.testing.assert_array_equal(vr[1], before[1])
+        np.testing.assert_array_equal(kr[0, 5:], before[0, 5:])
+        np.testing.assert_array_equal(kr[0, :5], nk[0, 11:])
+        np.testing.assert_array_equal(out[1], 0.0)
+        np.testing.assert_array_equal(out[0, :11], 0.0)
+        want = _window_dense(q[0, 11:], nk[0, 11:], nv[0, 11:], 0,
+                             self.WINDOW)
+        np.testing.assert_allclose(out[0, 11:], want, atol=2e-5)
+
+    def test_a_ring_too_small_for_the_chunk_is_refused(self):
+        z = lambda *s: paddle.to_tensor(np.zeros(s, np.float32))  # noqa: E731
+        with pytest.raises(ValueError, match="cannot hold a window"):
+            F.window_ring_attention(
+                z(1, 64, 4, 16), z(1, 190, 2, 16), z(1, 190, 2, 16),
+                paddle.to_tensor(np.asarray([64], np.int32)),
+                z(1, 64, 2, 16), z(1, 64, 2, 16), window=128)

@@ -435,3 +435,76 @@ class TestDecodeKernelParity:
         assert eng.health()["decode_attention"] == path
         twin = PagedEngine(model, **{**geometry, **kw})
         assert twin.health()["decode_attention"] == path
+
+
+class TestCacheLayout:
+    """What a layer keeps follows from the model's ``cache_layout``; a model
+    without one keeps pages in every layer."""
+
+    def test_a_dense_decoder_keeps_pages_and_nothing_else(self):
+        from paddle_tpu.inference import resilience, serving
+        eng = PagedEngine(_tiny_model(), max_batch=2, block_size=8,
+                          num_blocks=16, max_blocks_per_seq=4)
+        assert eng._cache_index == {(0, "paged_kv"): 0, (1, "paged_kv"): 1}
+        h = eng.health()
+        assert h["window_bytes_per_slot"] == h["state_bytes_per_slot"] == 0
+        assert resilience.M_WINDOW_BYTES.value() == 0
+        # both layers page: K and V of 4 heads of 16 in float32, twice
+        assert h["kv_bytes_per_token"] == 2 * 2 * 4 * 16 * 4
+        assert eng.state == [] and len(eng.kc) == 2
+        assert list(serving._layer_states(
+            [None, ("paged_kv",), (("window_kv", 8), ("accumulator", (3,),
+                                                      "int32"))])) == [
+            (1, ("paged_kv",)), (2, ("window_kv", 8)),
+            (2, ("accumulator", (3,), "int32"))]
+
+    def test_a_prefill_chunk_touches_its_own_slots_rows_only(self):
+        """The cache handle with ``lanes``: a chunk of slot 2 writes slot
+        2's window rows; slots 0, 1 and 3 keep theirs bit for bit."""
+        import jax.numpy as jnp
+        from paddle_tpu.inference import serving
+        rng = np.random.RandomState(0)
+        rows = jnp.asarray(rng.randn(4, 24, 2, 16).astype(np.float32))
+        T = 16
+        q = paddle.to_tensor(rng.randn(1, T, 4, 16).astype(np.float32))
+        k = paddle.to_tensor(rng.randn(1, T, 2, 16).astype(np.float32))
+        start = jnp.asarray([16], jnp.int32)        # the second chunk
+        cache = serving._PagedCache(
+            {(0, "window_kv"): 0}, [], [], [{"k": rows, "v": rows}],
+            jnp.zeros((1, 4), jnp.int32), start + T, start,
+            jnp.asarray([2], jnp.int32), T)
+        out = cache.attend(0, q, k, k, window=8)
+        assert out.shape == [1, T, 4, 16]
+        got = np.asarray(cache.states[0]["k"])
+        for slot in (0, 1, 3):
+            np.testing.assert_array_equal(got[slot], np.asarray(rows)[slot])
+        # positions 16..31 live in rows 16..23 and 0..7 of a ring of 24
+        np.testing.assert_array_equal(got[2, 16:], k.numpy()[0, :8])
+        np.testing.assert_array_equal(got[2, :8], k.numpy()[0, 8:])
+        np.testing.assert_array_equal(got[2, 8:16], np.asarray(rows)[2, 8:16])
+
+
+def test_compiled_programs_do_not_keep_a_model_alive():
+    """Engines of one model share compiled programs through a table keyed
+    weakly by the model; the programs reach the model through a proxy, so
+    once the engine and the caller let go, the model and its parameters'
+    arrays go too (a benchmark run frees the program before it builds the
+    float32 reference)."""
+    import gc
+    import weakref
+    paddle.seed(3)
+    model = LlamaForCausalLM(LlamaConfig(
+        vocab_size=61, hidden_size=32, intermediate_size=64, num_layers=1,
+        num_heads=2, max_seq_len=32, use_flash_attention=False))
+    eng = PagedEngine(model, max_batch=2, block_size=8, num_blocks=8,
+                      max_blocks_per_seq=2)
+    rid = eng.add_request([1, 2, 3], max_new_tokens=2)
+    assert len(eng.run_to_completion()[rid]) == 2
+    twin = PagedEngine(model, max_batch=2, block_size=8, num_blocks=8,
+                       max_blocks_per_seq=2)
+    assert twin._fns["decode"] is eng._fns["decode"]    # still shared
+    held = [weakref.ref(model), weakref.ref(eng),
+            weakref.ref(next(iter(model.parameters()))._data)]
+    del model, eng, twin
+    gc.collect()
+    assert [ref() for ref in held] == [None, None, None]

@@ -53,10 +53,12 @@ def _decode_step(q, kc, vc, tables, lens, nk, nv):
     return out._data, kc._data, vc._data
 
 
-# (lanes, H, KVH, blocks, table width): the two serving configurations
+# (lanes, H, KVH, blocks, table width): the serving configurations; the
+# last one's block table, 64 x 1152 int32, is 288 KiB of scalar prefetch
 @pytest.mark.parametrize("geometry", [
     pytest.param((32, 32, 8, 4096, 128), id="mistral-7b"),
-    pytest.param((64, 32, 2, 16384, 256), id="nemotron-3-super")])
+    pytest.param((64, 32, 2, 16384, 256), id="nemotron-3-super"),
+    pytest.param((64, 64, 8, 49152, 1152), id="k-exaone")])
 def test_paged_decode_step_compiles_with_the_kernel_and_no_pool_copy(
         one_chip, tpu_backend, geometry):
     """The decode step of ``block_multihead_attention`` at a cell's shapes:
