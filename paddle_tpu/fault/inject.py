@@ -41,6 +41,14 @@ guard). The registered points:
                                     ``tick`` — exercises the fail-in-flight
                                     + degrade + keep-serving path; params:
                                     ``tick``
+``serving.program_failure``         the blocking read of a launched serving
+                                    program's tokens raises, as the read of
+                                    a program that failed on the device
+                                    does: one launch after the tick that
+                                    launched it — exercises the same
+                                    fail-in-flight + degrade + keep-serving
+                                    path from the read; params: optional
+                                    ``tick`` (the launching tick)
 ``fleet.slow_step``                 the fleet beacon sleeps ``seconds``
                                     inside each observed training step —
                                     the deterministic slow-rank drill for
@@ -102,6 +110,7 @@ POINTS = frozenset({
     "serving.tick_stall",
     "serving.admission_oom",
     "serving.crash_at_tick",
+    "serving.program_failure",
     "fleet.slow_step",
     "collective.desync",
     "rank.crash_at_step",

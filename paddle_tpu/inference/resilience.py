@@ -315,6 +315,18 @@ M_TICK_FAILURES = _metrics.counter(
     "paddle_tpu_serving_tick_failures",
     "Engine ticks that raised internally; the tick loop absorbed the "
     "error, failed the in-flight requests and degraded the replica.")
+M_LAUNCHES = _metrics.counter(
+    "paddle_tpu_serving_launches_total",
+    "Serving programs launched, by whether an earlier program was still "
+    "unread at the launch (overlapped=true: the host prepared this one "
+    "while the chip ran that one). health()[\"overlap_share\"] is the "
+    "true share.", labelnames=("overlapped",))
+M_READS = _metrics.counter(
+    "paddle_tpu_serving_reads_total",
+    "Reads of a launched program's tokens, by whether the program had "
+    "already finished when the host came for them (host_late=true: the "
+    "host, not the chip, set the pace). health()[\"host_late_share\"] is "
+    "the true share.", labelnames=("host_late",))
 M_REPLICA_STATE = _metrics.gauge(
     "paddle_tpu_serving_replica_state",
     "Replica lifecycle state ordinal: 0=STARTING 1=WARMING 2=READY "

@@ -43,6 +43,17 @@ free list, and admission/eviction is plain Python between ticks:
   the ``nn/quant`` weight-only pattern applied to KV), halving resident
   KV vs bf16 and roughly doubling the resident batch a chip can hold.
 
+One program stays in flight: a tick plans, builds and launches its
+program(s) first and only then reads the tokens of the program launched
+before, so the host's work for a tick (read, emit, deliver, admit, plan,
+build) runs while the chip executes that tick's program. The token a
+lane feeds stays on the device (the decode step's output is the next
+step's input), the host counts the tokens it has launched but not read,
+and ``step()`` returns with its last program still running: its tokens
+are delivered by the next ``step()``. ``speculate=`` engines (the next
+rows depend on the accepted count) and the dense scorer read their
+program in the tick that launched it.
+
 Sampling is per-request deterministic: every sampled token draws from a
 key folded from (engine seed, request id, token position), so a request
 preempted and re-prefilled resumes the SAME sampled continuation — a
@@ -395,6 +406,31 @@ def _sample_tokens(logits, temps, top_ps, base_key, rids, ngens,
             pr[None], pp[None, 0], kk)[0, 0])(
         probs, top_ps[:, None], keys)
     return jnp.where(temps > 0, sampled, greedy)
+
+
+@jax.jit
+def _feed_tokens(unread, on_host, from_host):
+    """The decode step's (B, 1) feed while the program that sampled some of
+    its tokens is still unread: ``unread`` is that program's output on the
+    device (a decode step's (B,) tokens, or the (1,) first token of a
+    slot's final prefill chunk), ``on_host`` the tokens the host has read,
+    ``from_host`` (B,) which lanes take the host's."""
+    return jnp.where(from_host, on_host, unread)[:, None]
+
+
+@dataclass
+class _Launched:
+    """A launched program whose outputs the host has not read yet."""
+    phase: str                 # "prefill" / "decode": its span and gauge
+    kind: str                  # "prefill" / "decode" / "verify": its emit
+    outs: list                 # the device arrays it hands the host
+    t0: float                  # host clock at its build
+    positions: int             # rows x width, for ``scheduler.note_phase``
+    tick: int
+    #: ``(slot, tenancy)`` of each lane it sampled a token for; a lane
+    #: released since (finished, cancelled, evicted, expired) drops it
+    lanes: list = field(default_factory=list)
+    meta: dict = field(default_factory=dict)
 
 
 def _bind_params(params, param_arrays):
@@ -800,6 +836,21 @@ class PagedEngine:
         self.last_token = np.zeros((max_batch,), np.int32)
         self.slots: List[Optional[Request]] = [None] * max_batch
         self.slot_blocks: List[List[int]] = [[] for _ in range(max_batch)]
+        #: tokens launched for a slot's tenant and not read yet (0 or 1
+        #: between ticks): sequence lengths, block demand, the sampling
+        #: key's counter and the ``max_new_tokens`` test count them
+        self._inflight = np.zeros((max_batch,), np.int32)
+        #: a slot's tenancy, bumped at every release: the read of a
+        #: program tells the tenant it was launched for from a successor
+        self._tenancy = np.zeros((max_batch,), np.int64)
+        #: the one launched program whose outputs the host has not read
+        self._unread: Optional[_Launched] = None
+        #: whether a tick returns with its last program unread (a verify
+        #: step's accepted count decides the next launch's rows, and the
+        #: dense scorer keeps no state between ticks: both read at once)
+        self._overlap = not self._dense and self._spec is None
+        self._launches = self._launches_overlapped = 0
+        self._reads = self._reads_late = 0
         self.queue: List[Request] = []
         self.rejected: Dict[int, str] = {}
         self._params = [p for p in model.parameters()]
@@ -1122,28 +1173,41 @@ class PagedEngine:
         return sum(1 for s in self.slots if s is not None)
 
     def has_work(self) -> bool:
-        return bool(self.queue) or self.num_active > 0
+        """Whether a ``step()`` has something to do: a queued or running
+        request, or a launched program whose tokens are still unread."""
+        return (bool(self.queue) or self.num_active > 0
+                or self._unread is not None)
 
     # ----------------------------------------------------------- compute
     def _chunk_args(self, tokens_np, seq_lens_np, tables_np, temps_np,
                     top_ps_np, rids_np, ngens_np):
-        return ([p._data for p in self._params], self.kc, self.vc,
-                jnp.asarray(tokens_np), jnp.asarray(seq_lens_np),
-                jnp.asarray(tables_np),
-                jnp.asarray(temps_np, jnp.float32),
-                jnp.asarray(top_ps_np, jnp.float32),
-                jnp.asarray(rids_np, jnp.int32),
-                jnp.asarray(ngens_np, jnp.int32), self._base_key,
-                self.state)
+        """The program's arguments. Every host array is handed over as a
+        snapshot: the launch returns while the transfer (on the CPU
+        backend, where the device array may alias the numpy buffer, the
+        program itself) can still be reading it, and the next tick's
+        admission mutates ``tables`` in place."""
+        def snap(a, dtype):
+            return a if isinstance(a, jax.Array) else jnp.asarray(
+                np.array(a, dtype))
 
-    def _call_program(self, phase, fn, host_args, extra=(), **span_args):
-        """One program call under its boundary spans: ``serving.<phase>``
-        over ``.build`` (eval mode on, the host arrays to the device),
-        ``.launch`` (the compiled call; the caches are rebound to its
-        outputs) and ``.wait`` (the blocking read of what it hands the host,
-        the training flag restored). Returns the program's host-bound
-        outputs as numpy arrays."""
-        tokens_np, seq_lens_np, _tables_np, temps_np, *_ = host_args
+        return ([p._data for p in self._params], self.kc, self.vc,
+                snap(tokens_np, np.int32), snap(seq_lens_np, np.int32),
+                snap(tables_np, np.int32), snap(temps_np, np.float32),
+                snap(top_ps_np, np.float32), snap(rids_np, np.int32),
+                snap(ngens_np, np.int32), self._base_key, self.state)
+
+    def _call_program(self, rec: _Launched, fn, host_args, extra=(),
+                      **span_args):
+        """Launch one program under its boundary spans and read the one
+        launched before it: ``serving.<phase>`` over ``.build`` (eval mode
+        on, the host arrays to the device), ``.launch`` (the compiled call;
+        the caches are rebound to its outputs, the training flag restored)
+        and ``.wait`` (the blocking read of the oldest unread program's
+        outputs: the program launched before this one, or this one on an
+        engine that does not overlap), then ``serving.emit`` for what was
+        read. ``rec`` stays behind as the unread program."""
+        phase = rec.phase
+        tokens, seq_lens_np, _tables_np, temps_np, *_ = host_args
         with contextlib.ExitStack() as restore, _trace.boundary(
                 f"serving.{phase}",
                 args=dict(span_args, phase=phase,
@@ -1153,58 +1217,132 @@ class PagedEngine:
                 # caller's training flag afterwards — the engine must not
                 # mutate a model a training loop is still using. Either
                 # switch walks every sublayer, so both sit inside a leaf
-                # span: the walk is host work the chip waits for
+                # span: host work that runs beside the program before
                 if getattr(self.model, "training", False):
                     self.model.eval()
                     restore.callback(self.model.train)
-                t0 = time.perf_counter()
+                rec.t0 = time.perf_counter()
                 args = self._chunk_args(*host_args) + tuple(
-                    jnp.asarray(a, jnp.int32) for a in extra)
+                    jnp.asarray(np.array(a, np.int32)) for a in extra)
             with _trace.boundary(f"serving.{phase}.launch"), \
                     _attention_paths() as lowered:
-                *outs, self.kc, self.vc, self.state = fn(
+                *rec.outs, self.kc, self.vc, self.state = fn(
                     *args,
                     sampling=bool(np.any(np.asarray(temps_np) > 0)))
+                restore.close()
             if lowered:     # this call traced the program
                 self._attention_lowered[self._program_key(
-                    phase, tokens_np.shape)] = "+".join(sorted(set(lowered)))
-            with _trace.boundary(f"serving.{phase}.wait"):
-                # np.asarray blocks until the program finishes, so the
-                # serving.<phase> bracket bounds the chunk's device
-                # execution from above — the per-tick prefill-vs-decode
-                # attribution tools/loadgen.py reports
-                outs = [np.asarray(o) for o in outs]  # tpulint: disable=TPU104 — host boundary by design: sampled token ids feed python-side scheduling
-                restore.close()
-                seconds = time.perf_counter() - t0
-        self.scheduler.note_phase(
-            phase, int(len(seq_lens_np)) * int(tokens_np.shape[1]), seconds)
+                    phase, tokens.shape)] = "+".join(sorted(set(lowered)))
+            due, self._unread = self._unread, rec
+            self._launches += 1
+            _res.M_LAUNCHES.inc(overlapped=str(due is not None).lower())
+            if due is not None:
+                self._launches_overlapped += 1
+            elif not self._overlap:
+                due, self._unread = rec, None
+            if due is None:
+                return
+            outs = self._read(due, phase)
+        self._emit(due, outs)
+
+    def _read(self, rec: _Launched, phase: str):
+        """The blocking read of a launched program's host-bound outputs
+        (numpy arrays), under the ``.wait`` span of the launch it follows
+        (``phase``). A failure of the program surfaces here."""
+        from ..fault import inject as _inject
+
+        with _trace.boundary(f"serving.{phase}.wait"):
+            _inject.check("serving.program_failure", tick=rec.tick)
+            late = all(o.is_ready() for o in rec.outs)
+            # np.asarray blocks until the program finishes: launch to read
+            # bounds its device execution from above — the per-tick
+            # prefill-vs-decode attribution tools/loadgen.py reports
+            outs = [np.asarray(o) for o in rec.outs]  # tpulint: disable=TPU104 — host boundary by design: sampled token ids feed python-side scheduling
+        self._reads += 1
+        self._reads_late += late
+        _res.M_READS.inc(host_late=str(late).lower())
+        self.scheduler.note_phase(rec.phase, rec.positions,
+                                  time.perf_counter() - rec.t0)
         return outs
 
-    def _run_chunk(self, tokens_np, seq_lens_np, tables_np,
-                   temps_np, top_ps_np, rids_np, ngens_np,
-                   phase: str = "decode", lanes=None):
+    def _settle(self):
+        """Read the unread program, if there is one, with nothing new to
+        launch (inside a tick: a failure goes the tick's way)."""
+        rec, self._unread = self._unread, None
+        if rec is not None:
+            self._emit(rec, self._read(rec, rec.phase))
+
+    def _flush(self):
+        """``_settle`` for the callers outside a tick (``cancel``,
+        ``recover``, ``warmup``): a failure is contained as a tick's is."""
+        try:
+            self._settle()
+        except Exception as e:
+            self._on_tick_failure(e)
+
+    def _holds(self, slot: int, tenancy: int) -> Optional[Request]:
+        """The request a program was launched for, if the lane still holds
+        it: cancel, eviction, deadline expiry or a finish learnt late (an
+        ``eos_id`` hit) may have released the lane since the launch."""
+        return self.slots[slot] if self._tenancy[slot] == tenancy else None
+
+    def _run_chunk(self, rec: _Launched, tokens, seq_lens_np, tables_np,
+                   temps_np, top_ps_np, rids_np, ngens_np, lanes=None):
         """``lanes``: the slots the chunk's rows belong to, for the layers
         that keep state per slot (a prefill chunk carries one slot's rows;
         the decode batch's row i is slot i and passes none). An engine
         with no such layer never sends them."""
         extra = (lanes,) if lanes is not None and self._has_slot_state \
             else ()
-        (out,) = self._call_program(
-            phase, self._fns[phase],
-            (tokens_np, seq_lens_np, tables_np, temps_np, top_ps_np,
+        self._call_program(
+            rec, self._fns[rec.phase],
+            (tokens, seq_lens_np, tables_np, temps_np, top_ps_np,
              rids_np, ngens_np), extra=extra)
-        return out
 
-    def _run_verify(self, tokens_np, seq_lens_np, tables_np, temps_np,
-                    top_ps_np, rids_np, ngens_np, max_accept_np):
+    def _run_verify(self, rec: _Launched, tokens_np, seq_lens_np, tables_np,
+                    temps_np, top_ps_np, rids_np, ngens_np, max_accept_np):
         """Speculative verify program: decode-phase compute (the spans
         and token counters attribute it to decode — it IS the decode
         step, just yielding up to k+1 tokens)."""
-        out, n_out = self._call_program(
-            "decode", self._vfn,
+        self._call_program(
+            rec, self._vfn,
             (tokens_np, seq_lens_np, tables_np, temps_np, top_ps_np,
              rids_np, ngens_np), extra=(max_accept_np,), speculative=True)
-        return out, n_out
+
+    def _record(self, phase: str, shape, kind: Optional[str] = None,
+                **kw) -> _Launched:
+        return _Launched(phase=phase, kind=kind or phase, outs=[], t0=0.0,
+                         positions=int(shape[0]) * int(shape[1]),
+                         tick=self._ticks, **kw)
+
+    def _emit(self, rec: _Launched, outs):
+        """Per-slot bookkeeping of the tokens a program sampled, at its
+        read."""
+        with _trace.boundary("serving.emit"):
+            now = self._clock()
+            if rec.kind == "verify":
+                self._emit_verified(rec.meta["active"], rec.meta["seq"],
+                                    *outs, rec.meta["max_accept"])
+                return
+            if rec.kind == "prefill":
+                self._rt_event(rec.meta["rid"], "prefill_chunk", t=now,
+                               tick=rec.tick, **rec.meta["chunk"])
+            (toks,) = outs
+            for slot, tenancy in rec.lanes:
+                req = self._holds(slot, tenancy)
+                if req is None:
+                    continue
+                # a prefill chunk carries its slot's one row, the decode
+                # step row i for slot i
+                tok = int(toks[0 if rec.kind == "prefill" else slot])
+                req.generated.append(tok)
+                self.last_token[slot] = tok
+                self._inflight[slot] -= 1
+                if rec.kind == "decode":
+                    self._rt_event(req.rid, "decode_tick", t=now,
+                                   tick=rec.tick, new_tokens=1)
+                self._record_token(req, now)
+                self._maybe_finish(slot)
 
     # -------------------------------------------------------- scheduling
     def _blocks_needed(self, length: int) -> int:
@@ -1281,9 +1419,10 @@ class PagedEngine:
         per-tick budget: each chunk program carries the next
         ``prefill_width`` tokens of ONE prefilling slot, the one admitted
         first, and only that slot's rows and block table. The final chunk
-        of a slot yields its first sampled token; chunks past the budget
-        defer to later ticks so the decode step below never waits out a
-        long prompt."""
+        of a slot yields its first sampled token (read with the next
+        launch; the slot joins this tick's decode step with the token
+        still on the device); chunks past the budget defer to later ticks
+        so the decode step below never waits out a long prompt."""
         width = self.prefill_width
         quota = self.scheduler.token_quota(self.block_size)
         # whole programs the tick's tokens pay for (a budget wider than
@@ -1298,28 +1437,26 @@ class PagedEngine:
             slot, chunk, final, rows = plan
             st = self._prefilling[slot]
             req = self.slots[slot]
-            (tok,) = self._run_chunk(*rows, phase="prefill",
-                                     lanes=np.asarray([slot], np.int32))
+            rec = self._record(
+                "prefill", rows[0].shape,
+                meta={"rid": req.rid,
+                      "chunk": {"chunk": chunk, "n_chunks": st["n_chunks"],
+                                "tokens": width}})
+            # what the launch settles is booked before it: by count the
+            # host knows it without reading the program
             self.scheduler.note_prompt_tokens(
                 width - (st["pad"] if chunk == 0 else 0))
             self._tick_work["prompt_tokens"] += width
             programs -= 1
-            with _trace.boundary("serving.emit"):
-                now = self._clock()
-                self._rt_event(req.rid, "prefill_chunk", t=now,
-                               chunk=chunk, n_chunks=st["n_chunks"],
-                               tokens=width, tick=self._ticks)
-                if final:
-                    del self._prefilling[slot]
-                    # cached positions == the prefilled prefix; the
-                    # sampled token lands in the cache on its decode step
-                    self.seq_lens[slot] = (len(req.prompt)
-                                           + len(req.generated))
-                    tok = int(tok)
-                    req.generated.append(tok)
-                    self.last_token[slot] = tok
-                    self._record_token(req, now)
-                    self._maybe_finish(slot)
+            if final:
+                del self._prefilling[slot]
+                # cached positions == the prefilled prefix; the sampled
+                # token (in flight until this program is read) lands in
+                # the cache on its decode step
+                self.seq_lens[slot] = len(req.prompt) + len(req.generated)
+                self._inflight[slot] += 1
+                rec.lanes.append((slot, int(self._tenancy[slot])))
+            self._run_chunk(rec, *rows, lanes=np.asarray([slot], np.int32))
 
     def _plan_prefill_chunk(self, programs):
         """The next chunk call: ``(slot, chunk index, is the slot's last
@@ -1377,6 +1514,9 @@ class PagedEngine:
         self.tables[slot, :] = 0
         self.seq_lens[slot] = 1
         self.last_token[slot] = 0
+        # a token still in flight for the tenant is dropped at its read
+        self._inflight[slot] = 0
+        self._tenancy[slot] += 1
 
     def _finish_request(self, req: Request, status: str,
                         detail: str = ""):
@@ -1519,6 +1659,15 @@ class PagedEngine:
         step for every fully-prefilled slot. Returns
         {rid: generated_tokens} for requests that finished this tick.
 
+        One program stays in flight: every launch is followed by the read
+        of the program launched before it, and the tick returns with its
+        last program still running. The tokens of a program are therefore
+        delivered (``req.generated``, streams, outcomes) by the next
+        ``step()``, and a finished request is returned one ``step()``
+        after its last program; a ``step()`` with nothing to launch reads
+        what is unread. A ``speculate=`` engine and the dense scorer read
+        each program in the tick that launched it.
+
         Never raises from scheduling, memory pressure, or injected
         faults: an internal tick failure marks the in-flight requests
         FAILED, reclaims their KV blocks, and flips the replica
@@ -1585,8 +1734,13 @@ class PagedEngine:
             self._shed_overload()
         # phase split: bounded prefill, then decode — decode runs EVERY
         # tick there is decodable work, however much prefill is pending
+        launched = self._launches
         self._prefill_step()
         self._decode_active()
+        if self._launches == launched:
+            # nothing to launch (the last tokens are in flight, or memory
+            # stalls every lane): read what is unread
+            self._settle()
 
     def _dense_tick(self):
         """Score up to ``max_batch`` queued requests in ONE
@@ -1654,41 +1808,67 @@ class PagedEngine:
                    for i in active)
 
     def _decode_plain(self, active: List[int]):
-        with _trace.boundary("serving.plan"):
-            plan = self._plan_decode(active)
-        if plan is None:
-            return
-        tokens, seq, temps, top_ps, rids, ngens, skipped = plan
-        nxt = self._run_chunk(tokens, seq, self.tables, temps, top_ps,
-                              rids, ngens, phase="decode")
-        self._tick_work["decode_slots"] += len(active) - len(skipped)
-        with _trace.boundary("serving.emit"):
-            now = self._clock()
-            for i in active:
-                if seq[i] == 0:
-                    continue
-                req = self.slots[i]
-                req.generated.append(int(nxt[i]))
-                self.seq_lens[i] = int(seq[i])   # cached positions now
-                self.last_token[i] = int(nxt[i])
-                self._rt_event(req.rid, "decode_tick", t=now,
-                               tick=self._ticks, new_tokens=1)
-                self._record_token(req, now)
-                self._maybe_finish(i)
+        while True:
+            with _trace.boundary("serving.plan"):
+                plan = self._plan_decode(active)
+            if plan is None:
+                return
+            tokens, seq, temps, top_ps, rids, ngens, fed, skipped = plan
+            if not skipped or len(skipped) < len(fed):
+                break
+            # every lane that would be fed is memory-stalled
+            if self._unread is None:
+                # nobody can finish to free blocks, so this would
+                # livelock. Preempt the slot with the most deadline slack
+                # (vLLM recompute-preemption, deadline-aware) and retry
+                # next tick with its blocks free.
+                self._evict(max(skipped, key=self._eviction_key))
+                return
+            # the unread program may finish lanes and free their blocks:
+            # read it, then plan on what the host knows now
+            self._settle()
+            active = self._decode_lanes()
+        rec = self._record(
+            "decode", tokens.shape,
+            lanes=[(i, int(self._tenancy[i])) for i in fed
+                   if i not in skipped])
+        for i, _tenancy in rec.lanes:
+            self._inflight[i] += 1
+            self.seq_lens[i] = int(seq[i])   # cached positions, launched
+        self._tick_work["decode_slots"] += len(rec.lanes)
+        self._run_chunk(rec, tokens, seq, self.tables, temps, top_ps, rids,
+                        ngens)
 
     def _plan_decode(self, active: List[int]):
-        """The decode call's host rows ``(tokens, seq, temps, top_ps, rids,
-        ngens, skipped)``, every lane's blocks ensured; None when every
-        active slot is memory-stalled and one was evicted instead."""
+        """The decode call's rows ``(tokens, seq, temps, top_ps, rids,
+        ngens, fed, skipped)``, counted on the tokens read plus the tokens
+        in flight: ``fed`` are the active lanes that still have a token to
+        sample (a lane whose last token by count is in flight is not fed
+        again), ``skipped`` those of them that found no KV block. None
+        when no lane is fed."""
         seq = self.seq_lens.copy()
         for i in self._prefilling:
             seq[i] = 0               # masked lane: no write, no attend
-        skipped = []
+        temps = np.zeros((self.max_batch,), np.float32)
+        top_ps = np.ones((self.max_batch,), np.float32)
+        rids = np.zeros((self.max_batch,), np.int32)
+        ngens = np.zeros((self.max_batch,), np.int32)
+        fed, skipped = [], []
         for i in active:
+            req = self.slots[i]
+            pending = int(self._inflight[i])
+            if len(req.generated) + pending >= req.max_new_tokens:
+                seq[i] = 0           # its last token is in flight
+                continue
+            fed.append(i)
+            temps[i] = req.temperature
+            top_ps[i] = req.top_p
+            rids[i] = req.rid
+            ngens[i] = len(req.generated) + pending
             # the cache holds seq_len-1 positions; the token being fed
             # (the newest sample) lands at position seq_len-1, so the
             # total INCLUDING it is exactly req.seq_len
-            seq[i] = self.slots[i].seq_len
+            seq[i] = req.seq_len + pending
             if not self._ensure_blocks(i, int(seq[i])):
                 # OOM: skip this slot's tick. Sentinel 0 — with seq=1
                 # the op would write the token's K/V into position 0
@@ -1697,25 +1877,24 @@ class PagedEngine:
                 # which the kernel drops and fully masks.
                 seq[i] = 0
                 skipped.append(i)
-        if skipped and len(skipped) == len(active):
-            # every active slot is memory-stalled: nobody can finish
-            # to free blocks, so this would livelock. Preempt the slot
-            # with the most deadline slack (vLLM recompute-preemption,
-            # deadline-aware) and retry next tick with its blocks free.
-            victim = max(skipped, key=self._eviction_key)
-            self._evict(victim)
+        if not fed:
             return None
-        tokens = self.last_token[:, None].astype(np.int32)
-        temps = np.zeros((self.max_batch,), np.float32)
-        top_ps = np.ones((self.max_batch,), np.float32)
-        rids = np.zeros((self.max_batch,), np.int32)
-        ngens = np.zeros((self.max_batch,), np.int32)
-        for i in active:
-            temps[i] = self.slots[i].temperature
-            top_ps[i] = self.slots[i].top_p
-            rids[i] = self.slots[i].rid
-            ngens[i] = len(self.slots[i].generated)
-        return tokens, seq, temps, top_ps, rids, ngens, skipped
+        return (self._decode_feed(), seq, temps, top_ps, rids, ngens, fed,
+                skipped)
+
+    def _decode_feed(self):
+        """The decode step's (B, 1) tokens: what the host has read, and
+        for the lanes whose newest token the unread program sampled that
+        program's output, left on the device."""
+        on_host = self.last_token.astype(np.int32)
+        rec = self._unread
+        lanes = [slot for slot, tenancy in (rec.lanes if rec else ())
+                 if self._holds(slot, tenancy) is not None]
+        if not lanes:
+            return on_host[:, None]
+        from_host = np.ones((self.max_batch,), bool)
+        from_host[lanes] = False
+        return _feed_tokens(rec.outs[0], on_host, from_host)
 
     def _decode_speculative(self, active: List[int]):
         """Decode via the fused verify program: per active slot, feed
@@ -1778,11 +1957,14 @@ class PagedEngine:
             # work — a dry proposer costs one ordinary decode step
             self._decode_plain(active)
             return
-        emit, n_emit = self._run_verify(tokens, seq, self.tables, temps,
-                                        top_ps, rids, ngens, max_accept)
         self._tick_work["decode_slots"] += len(active) - len(skipped)
-        with _trace.boundary("serving.emit"):
-            self._emit_verified(active, seq, emit, n_emit, max_accept)
+        # read in this tick: the accepted counts decide the next rows
+        self._run_verify(
+            self._record("decode", tokens.shape, kind="verify",
+                         meta={"active": active, "seq": seq,
+                               "max_accept": max_accept}),
+            tokens, seq, self.tables, temps, top_ps, rids, ngens,
+            max_accept)
 
     def _emit_verified(self, active, seq, emit, n_emit, max_accept):
         """Per-slot bookkeeping of one verify program's accepted tokens."""
@@ -1845,6 +2027,10 @@ class PagedEngine:
         # stale-buffer engine would otherwise fail every future tick
         # while still admitting.
         self.kc, self.vc, self.state = self._fresh_caches()
+        # an unread program ran on the suspect caches for requests that
+        # are FAILED now: nobody reads it
+        self._unread = None
+        self._inflight[:] = 0
         self.lifecycle.degrade(detail)
 
     def _drain_done(self) -> Dict[int, List[int]]:
@@ -1862,7 +2048,7 @@ class PagedEngine:
         / CANCELLED / FAILED are absent here — read ``self.outcomes``
         (or ``drain_outcomes()``) for their terminal records; never-
         fitting submissions also appear in ``self.rejected``."""
-        out: Dict[int, List[int]] = {}
+        out = self._drain_done()    # what finished while warmup() ticked
         ticks = 0
         while self.has_work():
             out.update(self.step())
@@ -1897,7 +2083,10 @@ class PagedEngine:
 
     def cancel(self, rid: int, reason: str = "cancelled by caller") -> bool:
         """Cancel a queued or in-flight request; its KV blocks return to
-        the free list immediately. False if ``rid`` is not live."""
+        the free list immediately, without waiting for the chip: a token
+        of its that is still in flight is dropped when its program is
+        read (the outcome and the stream hold the tokens read so far).
+        False if ``rid`` is not live."""
         for i, req in enumerate(self.queue):
             if req.rid == rid:
                 self.queue.pop(i)
@@ -1909,6 +2098,10 @@ class PagedEngine:
                 self._release_slot(slot)
                 self._finish_request(req, RequestStatus.CANCELLED,
                                      detail=reason)
+                if not (self.queue or self.num_active):
+                    # it was the last request: the unread program has no
+                    # reader to come
+                    self._flush()
                 return True
         return False
 
@@ -1950,10 +2143,12 @@ class PagedEngine:
             trace_hook=lambda ev, **meta: self._rt_event(rid, ev, **meta))
 
     def warmup(self, prompt_len: Optional[int] = None,
-               max_new_tokens: int = 2) -> "PagedEngine":
+               max_new_tokens: int = 3) -> "PagedEngine":
         """Compile the steady-state programs (the one (1, prefill_width)
-        prefill chunk + the batched decode step) before real traffic:
-        STARTING→WARMING→READY. Idempotent on a READY replica.
+        prefill chunk + the batched decode step, and the hand-over of the
+        fed token on the device after a chunk and after a step: hence
+        three tokens) before real traffic: STARTING→WARMING→READY.
+        Idempotent on a READY replica. Nothing is left unread.
 
         Traffic that arrived before READY (admission is open from
         STARTING — those requests wait for exactly these compiles) is
@@ -1978,6 +2173,9 @@ class PagedEngine:
             res = self.step()
             res.pop(rid, None)          # warmup is not traffic
             self._spillover.update(res)
+        self._flush()       # client traffic's program, if one is unread
+        self._spillover = self._drain_done()
+        self._spillover.pop(rid, None)
         oc = self.outcomes.pop(rid, None)
         if oc is None or oc.status != RequestStatus.FINISHED:
             # stay in WARMING (still admits): READY would advertise a
@@ -1993,8 +2191,8 @@ class PagedEngine:
         """Graceful shutdown: stop admission, finish in-flight decodes,
         then STOP. Queued requests that never got a slot are CANCELLED
         (their clients retry on another replica); running requests
-        decode to completion. Returns {rid: tokens} finished during the
-        drain."""
+        decode to completion and the last program is read: nothing is
+        left unread. Returns {rid: tokens} finished during the drain."""
         if self.lifecycle.state == ReplicaState.STOPPED:
             return {}
         self.lifecycle.to(ReplicaState.DRAINING, "drain()")
@@ -2022,6 +2220,7 @@ class PagedEngine:
                         self._finish_request(
                             req, RequestStatus.FAILED,
                             detail="drain did not converge")
+                self._unread = None
                 break
         self.lifecycle.to(ReplicaState.STOPPED, "drained")
         _res.M_QUEUE_DEPTH.set(0)
@@ -2030,7 +2229,10 @@ class PagedEngine:
 
     def recover(self, reason: str = "operator recover"):
         """DEGRADED → READY once the operator (or an orchestrator health
-        check) has decided the stall/crash cause is gone."""
+        check) has decided the stall/crash cause is gone. A program still
+        unread is read first (a failure of it degrades the replica like a
+        tick's would, before the transition)."""
+        self._flush()
         self.lifecycle.to(ReplicaState.READY, reason)
 
     def attach_watchdog(self, watchdog) -> "PagedEngine":
@@ -2069,6 +2271,13 @@ class PagedEngine:
              "decode_attention": self.decode_attention,
              "ticks": self._ticks,
              "tick_failures": self.tick_failures,
+             # launches made while an earlier program was unread (the
+             # host worked beside the chip), and reads that found their
+             # program finished (the host, not the chip, set the pace)
+             "overlap_share": (self._launches_overlapped / self._launches
+                               if self._launches else None),
+             "host_late_share": (self._reads_late / self._reads
+                                 if self._reads else None),
              "phase_share": self.scheduler.phase_share(),
              "prefill_fill": self.scheduler.prefill_fill(),
              # the probe path doubles as the burn-rate decay poll: an

@@ -146,32 +146,39 @@ BOUNDARY_SPANS: Dict[str, Tuple[str, Optional[str], str]] = {
                      "before a program call: pick its lanes, ensure their KV "
                      "blocks, fill the call's host rows"),
     # the two brackets keep the category tools/loadgen.py, perf.attribute
-    # and tools/request_trace.py key on; they are HOST clock over launch +
-    # blocking read, an upper bound of the program's device time
+    # and tools/request_trace.py key on; they are HOST clock over a launch
+    # and the blocking read of the program launched BEFORE it (one program
+    # stays in flight): back to back they cover the device's time, each is
+    # one program late
     "serving.prefill": ("device", "serving.tick",
-                        "one prefill-chunk program call"),
+                        "one prefill-chunk program launch"),
     "serving.prefill.build": ("serving", "serving.prefill",
                               "eval mode on (a walk over every sublayer of "
                               "a model in training mode), the call's host "
                               "arrays to the device"),
     "serving.prefill.launch": ("serving", "serving.prefill",
-                               "the call of the compiled program"),
+                               "the call of the compiled program, the "
+                               "training flag restored"),
     "serving.prefill.wait": ("serving", "serving.prefill",
-                             "the blocking read of the sampled tokens, the "
-                             "training flag restored"),
+                             "the blocking read of the oldest unread "
+                             "program's tokens: the one launched before "
+                             "this launch"),
     "serving.decode": ("device", "serving.tick",
-                       "one decode (or speculative verify) program call"),
+                       "one decode (or speculative verify) program launch"),
     "serving.decode.build": ("serving", "serving.decode",
                              "eval mode on (a walk over every sublayer of "
                              "a model in training mode), the call's host "
                              "arrays to the device"),
     "serving.decode.launch": ("serving", "serving.decode",
-                              "the call of the compiled program"),
+                              "the call of the compiled program, the "
+                              "training flag restored"),
     "serving.decode.wait": ("serving", "serving.decode",
-                            "the blocking read of the sampled tokens, the "
-                            "training flag restored"),
+                            "the blocking read of the oldest unread "
+                            "program's tokens: the one launched before "
+                            "this launch (a verify step's own; with "
+                            "nothing to launch, directly under the tick)"),
     "serving.emit": ("serving", "serving.tick",
-                     "per-slot token bookkeeping after a program returns"),
+                     "per-slot token bookkeeping of the program just read"),
 }
 
 

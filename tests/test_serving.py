@@ -381,10 +381,10 @@ class TestDecodeKernelParity:
         evict, run = eng._evict, eng._run_chunk
         eng._evict = lambda slot: (evicted.append(slot), evict(slot))[-1]
 
-        def spy(tokens, seq_lens, *a, phase="decode", **kw):
-            if phase == "decode" and eng._prefilling:
+        def spy(rec, tokens, seq_lens, *a, **kw):
+            if rec.phase == "decode" and eng._prefilling:
                 sentinel_ticks.append((np.asarray(seq_lens) <= 0).sum())
-            return run(tokens, seq_lens, *a, phase=phase, **kw)
+            return run(rec, tokens, seq_lens, *a, **kw)
 
         eng._run_chunk = spy
         rids = [eng.add_request(p, max_new_tokens=24) for p in prompts]

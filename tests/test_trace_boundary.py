@@ -198,6 +198,11 @@ def test_fit_step_enters_at_most_six_annotations(fake_profiler):
 
 
 def test_tick_enters_four_annotations_and_six_a_program(fake_profiler):
+    """A launch enters plan, its bracket, build and launch, and behind it
+    the wait for the program launched before and that one's emit: six, as
+    when a program was read in the tick that launched it. The first launch
+    of all has nothing to wait for (four), and the step that finds nothing
+    to launch only reads (a wait and an emit)."""
     router = Router([tiny_replica()]).warmup()
     del fake_profiler[:]
     rid = router.add_request(list(range(1, 12)), max_new_tokens=3)
@@ -207,16 +212,36 @@ def test_tick_enters_four_annotations_and_six_a_program(fake_profiler):
         router.step()
         ticks.append([a.name for a in fake_profiler[before:]])
     assert router.outcomes[rid].status == "FINISHED"
-    assert len(ticks) >= 2
+    # chunk + step, step, the read of the last step
+    assert len(ticks) == 3
+    launches = 0
     for names in ticks:
         programs = sum(n in ("serving.prefill", "serving.decode")
                        for n in names)
-        assert len(names) == 4 + 6 * programs
+        waits = sum(n.endswith(".wait") for n in names)
+        assert names.count("serving.emit") == waits
+        assert waits == programs - (launches == 0) or (
+            programs == 0 and waits == 1)
+        plans = names.count("serving.plan")
+        assert plans == programs or (programs == 0 and plans == 1)
+        assert len(names) == 4 + plans + 3 * programs + 2 * waits
+        launches += programs
     # a decode-only tick: ten, under the twelve the budget allows
-    assert sorted(ticks[-1]) == sorted([
+    assert sorted(ticks[1]) == sorted([
         "router.step", "serving.tick", "serving.admit", "serving.plan",
         "serving.decode",
         "serving.decode.build", "serving.decode.launch",
+        "serving.decode.wait", "serving.emit", "router.deliver"])
+    # the wait sits under the bracket of the launch it follows, the emit
+    # under the tick
+    at = ticks[1].index
+    assert at("serving.decode") < at("serving.decode.build") \
+        < at("serving.decode.launch") < at("serving.decode.wait") \
+        < at("serving.emit")
+    # the last tick plans, finds no lane to feed and launches nothing: it
+    # reads the unread step
+    assert sorted(ticks[2]) == sorted([
+        "router.step", "serving.tick", "serving.admit", "serving.plan",
         "serving.decode.wait", "serving.emit", "router.deliver"])
     # the first tick prefills (one chunk of 16 rows, 11 of them the
     # prompt) and decodes

@@ -63,7 +63,9 @@ def run_load(engine, *, offered_rps: float, n_requests: int,
 
     With ``attribution`` (default) the run collects the engine's
     per-tick device spans (``serving.prefill`` / ``serving.decode``, each
-    bracketed by the blocking result read) and reports device-time
+    a launch and the blocking read of the program launched before it: one
+    program stays in flight, so a span is one program late and back to back
+    they cover the device's time) and reports device-time
     attribution: prefill vs decode compute seconds and shares, plus
     device time per tick — the SLO view of *where* the chip's time went,
     not just wall-clock TTFT/ITL. Skipped when a profiler recording
