@@ -304,6 +304,11 @@ M_WINDOW_BYTES = _metrics.gauge(
     "Resident bytes of the sliding-window layers' K/V rows (window plus one "
     "prefill chunk a slot) reserved for max_batch slots; 0 for a model "
     "with no window layer.")
+M_LATENT_BYTES = _metrics.gauge(
+    "paddle_tpu_serving_latent_bytes",
+    "Resident bytes of the latent-attention layers' page pools (one row "
+    "[c | k_r] a token a layer, in whole lane tiles, num_blocks pages); 0 "
+    "for a model with no latent layer.")
 M_REQUESTS = _metrics.counter(
     "paddle_tpu_serving_requests",
     "Requests reaching a terminal status, by outcome.",

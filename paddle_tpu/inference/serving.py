@@ -32,12 +32,15 @@ free list, and admission/eviction is plain Python between ticks:
   batch shares one program, not one position): RoPE offsets for Llama,
   learned-position gathers for GPT (architecture adapters `_LlamaArch` /
   `_GPTArch`);
-* what a layer keeps follows from the model's ``cache_layout``: pages
-  (full-attention layers: the only layers with page pools, so only they
-  make a block cost bytes), a fixed ring of K/V rows a slot (sliding-window
-  layers: ``window + prefill_width`` rows whatever the context), a
-  recurrent state a slot, a device-side counter, or several of these;
-  Llama and GPT keep pages in every layer;
+* what a layer keeps follows from the model's ``cache_layout``, one of
+  five state kinds or several of them: K/V pages (``paged_kv``:
+  full-attention layers), latent pages (``latent_kv``: latent-attention
+  layers, ONE pool of rows ``[c | k_r]`` a layer under the same block
+  table; these two kinds alone have page pools, so only they make a block
+  cost bytes), a fixed ring of K/V rows a slot (``window_kv``:
+  sliding-window layers, ``window + prefill_width`` rows whatever the
+  context), a recurrent state a slot (``slot_state``), a device-side
+  counter (``accumulator``); Llama and GPT keep K/V pages in every layer;
 * K/V pages are stored in the model's compute dtype, or as an int8 page
   pool with sidecar per-(position, head) scales (``kv_dtype="int8"`` —
   the ``nn/quant`` weight-only pattern applied to KV), halving resident
@@ -275,8 +278,9 @@ def _pick_arch(model):
         f"subclasses), models that bring a paged_adapter() (cfg, "
         f"num_kv_heads, head_dim, forward_chunk(tokens, start, cache, "
         f"logits_t) and cache_layout(dtype): per layer None, one state "
-        f"('paged_kv',) / ('window_kv', window) / ('slot_state', "
-        f"{{name: (shape, dtype)}}) / ('accumulator', shape, dtype), or a "
+        f"('paged_kv',) / ('latent_kv', row_width) / ('window_kv', window) "
+        f"/ ('slot_state', {{name: (shape, dtype)}}) / ('accumulator', "
+        f"shape, dtype), or a "
         f"tuple of such states), and dense-scoring models exposing "
         f"serve_dense(); got {type(model).__name__}")
 
@@ -451,6 +455,11 @@ class _PagedCache:
       chunk's K/V pages and attend over the slot's block table. Cache
       entries are arrays (float pages) or (payload, scales) tuples (int8
       pages) — the structure picks the kernel path at trace time.
+    * ``attend_latent(li, q, rows, value_dim, scale)`` — latent pages
+      (latent-attention layers): append the chunk's rows ``[c | k_r]`` to
+      the layer's ONE pool and attend over the slot's block table in the
+      absorbed form (``nn.functional.latent_paged_attention``): a row is
+      its token's key and, in its first ``value_dim`` columns, its value.
     * ``attend(li, q, k, v, window=w)`` — window K/V (sliding-window
       attention layers): per SLOT a fixed ring of rows that does not grow
       with the sequence; the chunk's K/V are written round-robin by
@@ -511,6 +520,24 @@ class _PagedCache:
                 self.seq_lens, new_k=k, new_v=v, causal=True)
             kcs[li] = nkc._data
             vcs[li] = nvc._data
+        return out
+
+    def attend_latent(self, li, q, rows, value_dim, scale):
+        import paddle_tpu.nn.functional as F
+
+        at = self.index[li, "latent_kv"]
+        pool = self.states[at]          # (num_blocks, block_size, D)
+        # the pool's rows are whole lane tiles: zeros past [c | k_r], in
+        # the queries too
+        pad = pool.shape[-1] - rows.shape[-1]
+        q, rows = q._data, rows._data
+        if pad:
+            q = jnp.pad(q, ((0, 0),) * 3 + ((0, pad),))
+            rows = jnp.pad(rows, ((0, 0),) * 2 + ((0, pad),))
+        out, new = F.latent_paged_attention(
+            Tensor(q), Tensor(pool), self.tables, self.seq_lens,
+            Tensor(rows), value_dim, scale)
+        self.states[at] = new._data
         return out
 
     def _lane_rows(self, whole):
@@ -576,7 +603,11 @@ def _max_over_mean(tokens):
             for row in tokens]
 
 
-_STATE_KINDS = ("paged_kv", "window_kv", "slot_state", "accumulator")
+_STATE_KINDS = ("paged_kv", "latent_kv", "window_kv", "slot_state",
+                "accumulator")
+
+#: lanes of a vector register: a latent row is stored in whole tiles of them
+_LANE_TILE = 128
 
 
 def _layer_states(layout):
@@ -595,8 +626,8 @@ def _layer_states(layout):
 
 def _cache_index(layout):
     """``(layer, kind)`` -> position among the states of its list: paged
-    K/V states index ``kcs`` / ``vcs``; window rows, slot states and
-    accumulators share the flat ``states`` list. A layer keeps at most one
+    K/V states index ``kcs`` / ``vcs``; latent pools, window rows, slot
+    states and accumulators share the flat ``states`` list. A layer keeps at most one
     state of a kind."""
     index, pages, states = {}, 0, 0
     for li, state in _layer_states(layout):
@@ -688,12 +719,13 @@ class PagedEngine:
     """Continuous-batching engine for causal LMs (paged KV caches).
 
     What a layer keeps follows from the model's ``cache_layout``: pages of
-    K/V that grow with the sequence (``paged_kv``: full-attention layers;
-    only these have page pools, and only they make a block cost bytes), a
-    fixed ring of K/V rows a slot (``window_kv``: sliding-window layers), a
-    recurrent state a slot (``slot_state``), a device-side counter
-    (``accumulator``), or several of these. Llama and GPT keep pages in
-    every layer.
+    K/V that grow with the sequence (``paged_kv``: full-attention layers),
+    pages of latent rows ``[c | k_r]`` (``latent_kv``: latent-attention
+    layers, one pool a layer; these two kinds have page pools, and only
+    they make a block cost bytes), a fixed ring of K/V rows a slot
+    (``window_kv``: sliding-window layers), a recurrent state a slot
+    (``slot_state``), a device-side counter (``accumulator``), or several
+    of these. Llama and GPT keep K/V pages in every layer.
 
     Dense-scoring models (anything exposing ``serve_dense`` /
     ``serve_dense_width``, e.g. :class:`~paddle_tpu.models.DLRM`) run
@@ -797,7 +829,9 @@ class PagedEngine:
         self._kv_shape = (num_blocks, block_size, nkv, self.head_dim)
         self._kv_scale_shape = (num_blocks, block_size, nkv)
         # ---- the cache states a layer declares: ``paged_kv`` (a K and a
-        # V page pool, full-attention layers), ``window_kv`` (K/V rows
+        # V page pool, full-attention layers), ``latent_kv`` (ONE page pool
+        # [num_blocks, block_size, row width in whole lane tiles] of rows
+        # [c | k_r]: latent-attention layers), ``window_kv`` (K/V rows
         # [max_batch, window + prefill_width, KVH, D] written round-robin:
         # sliding-window layers), ``slot_state`` (arrays [max_batch, ...]
         # that do not grow with the sequence: a recurrent layer's window
@@ -822,6 +856,13 @@ class PagedEngine:
                 "its update of the per-slot state (conv window, SSM state) "
                 "back. Serve a model with slot_state layers without "
                 "speculate=.")
+        if "latent_kv" in kinds and self._kv_int8:
+            raise TypeError(
+                "kv_dtype='int8' quantizes K and V pages per (position, "
+                "head); a latent_kv layer keeps one row [c | k_r] a token "
+                "that is key and value at once, and no int8 form of it is "
+                "built. Serve a model with latent_kv layers without "
+                "kv_dtype='int8'.")
         if "window_kv" in kinds and speculate is not None:
             raise TypeError(
                 "speculate= needs a state rollback this engine does not "
@@ -931,11 +972,12 @@ class PagedEngine:
         # HBM attribution: KV pages report under the "kv_cache" tag (the
         # getter re-reads kc/vc, which donation replaces every tick)
         from ..observability.perf import memory as _perf_memory
-        _perf_memory.register_object("kv_cache", self,
-                                     lambda e: (e.kc, e.vc))
+        _perf_memory.register_object(
+            "kv_cache", self, lambda e: (e.kc, e.vc, e._latent_pools()))
         _res.M_KV_BYTES_PER_TOKEN.set(self.kv_bytes_per_token)
         _res.M_STATE_BYTES.set(self.state_bytes_per_slot * max_batch)
         _res.M_WINDOW_BYTES.set(self.window_bytes_per_slot * max_batch)
+        _res.M_LATENT_BYTES.set(self.latent_bytes)
         #: host-side totals of the expert-load accumulators, one row a
         #: layer that has one (see ``expert_load``)
         self._expert_load, self._expert_load_t = None, 0.0
@@ -959,15 +1001,27 @@ class PagedEngine:
         the chunk's last row is written), in whole sublane tiles."""
         return -(-(window + self.prefill_width) // 8) * 8
 
+    @staticmethod
+    def _latent_row(width: int) -> int:
+        """Columns a latent pool's row takes: ``width`` in whole lane tiles
+        (576 -> 640; a bfloat16 array whose minor dimension is 576 is laid
+        out in 640 lanes on the device anyway), zeros past ``width``."""
+        return -(-width // _LANE_TILE) * _LANE_TILE
+
     def _fresh_caches(self):
         """``(kc, vc, state)`` zeroed: a K and a V pool per ``paged_kv``
-        state, and per ``window_kv`` / ``slot_state`` / ``accumulator``
-        state its arrays (in the order of ``_cache_index``)."""
+        state, and per ``latent_kv`` / ``window_kv`` / ``slot_state`` /
+        ``accumulator`` state its arrays (in the order of
+        ``_cache_index``)."""
         kc, vc, state = [], [], []
         for _li, entry in _layer_states(self._layout):
             if entry[0] == "paged_kv":
                 kc.append(self._fresh_cache())
                 vc.append(self._fresh_cache())
+            elif entry[0] == "latent_kv":
+                state.append(jnp.zeros(
+                    self._kv_shape[:2] + (self._latent_row(entry[1]),),
+                    self._compute_dtype))
             elif entry[0] == "window_kv":
                 shape = (self.max_batch, self._window_rows(entry[1]),
                          self.num_kv_heads, self.head_dim)
@@ -984,15 +1038,35 @@ class PagedEngine:
     @property
     def kv_bytes_per_token(self) -> int:
         """Resident KV bytes one cached token costs across the layers that
-        page (``paged_kv``; window and recurrent layers cost a slot, not a
-        token); the resident-batch ceiling is HBM / (this * mean seq
-        len)."""
+        page (``paged_kv`` and ``latent_kv``; window and recurrent layers
+        cost a slot, not a token); the resident-batch ceiling is HBM /
+        (this * mean seq len)."""
         if self._dense:
             return 0                     # dense path keeps no KV state
         per = self.num_kv_heads * self.head_dim * self.kv_dtype.itemsize
         if self._kv_int8:
             per += self.num_kv_heads * 4          # sidecar fp32 scale
-        return 2 * len(self.kc) * per     # K and V, paged layers only
+        # K and V of the paged layers; one row of the latent layers
+        return 2 * len(self.kc) * per + self._latent_bytes_per_token
+
+    @property
+    def _latent_bytes_per_token(self) -> int:
+        return sum(self._latent_row(entry[1])
+                   for _li, entry in _layer_states(self._layout)
+                   if entry[0] == "latent_kv") * jnp.dtype(
+                       self._compute_dtype).itemsize
+
+    def _latent_pools(self):
+        return [self.state[pos] for (_li, kind), pos
+                in self._cache_index.items() if kind == "latent_kv"]
+
+    @property
+    def latent_bytes(self) -> int:
+        """Resident bytes of the latent-attention layers' page pools: a row
+        a token a layer for ``num_blocks`` pages (0 for a model with no
+        such layer)."""
+        return (self._latent_bytes_per_token * self._kv_shape[0]
+                * self._kv_shape[1])
 
     @property
     def state_bytes_per_slot(self) -> int:
@@ -2268,6 +2342,7 @@ class PagedEngine:
              "kv_bytes_per_token": self.kv_bytes_per_token,
              "state_bytes_per_slot": self.state_bytes_per_slot,
              "window_bytes_per_slot": self.window_bytes_per_slot,
+             "latent_bytes": self.latent_bytes,
              "decode_attention": self.decode_attention,
              "ticks": self._ticks,
              "tick_failures": self.tick_failures,

@@ -1,8 +1,9 @@
 """Model zoo (reference: python/paddle/vision/models + the GPT fixtures the
 reference uses for auto-parallel tests, test/auto_parallel/get_gpt_model.py).
 These are the BASELINE.md ladder configs: LeNet, ResNet, BERT, GPT, LLaMA,
-the Nemotron-H hybrid (Mamba-2 + attention + latent experts) and the
-K-EXAONE decoder (window + full attention, SwiGLU experts).
+the Nemotron-H hybrid (Mamba-2 + attention + latent experts), the
+K-EXAONE decoder (window + full attention, SwiGLU experts) and the
+DeepSeek-V3 decoder (latent attention, SwiGLU experts).
 """
 from .lenet import LeNet
 from .gpt import GPTConfig, GPTModel, GPTForCausalLM, gpt2_small, gpt2_medium
@@ -15,6 +16,8 @@ from .nemotron_h import (NemotronHConfig, NemotronHForCausalLM,
                          NemotronHModel, nemotron_h_tiny)
 from .exaone_moe import (ExaoneMoeConfig, ExaoneMoeForCausalLM,
                          ExaoneMoeModel, exaone_moe_tiny)
+from .deepseek_v3 import (DeepseekV3Config, DeepseekV3ForCausalLM,
+                          DeepseekV3Model, deepseek_v3_tiny)
 
 __all__ = [
     "LeNet", "GPTConfig", "GPTModel", "GPTForCausalLM",
@@ -28,4 +31,6 @@ __all__ = [
     "nemotron_h_tiny",
     "ExaoneMoeConfig", "ExaoneMoeModel", "ExaoneMoeForCausalLM",
     "exaone_moe_tiny",
+    "DeepseekV3Config", "DeepseekV3Model", "DeepseekV3ForCausalLM",
+    "deepseek_v3_tiny",
 ]

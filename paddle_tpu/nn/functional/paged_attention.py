@@ -35,6 +35,21 @@ backend (``attention_path``), never from a flag a caller sets:
   further. Nothing holds scores wider than a group; a chunk early in a
   long context costs what its context asks, not what the table could hold.
 
+**Latent pages** (``latent_paged_attention``): a latent-attention (MLA)
+layer caches ONE row a token, ``[c | k_r]``, in one pool ``(num_blocks,
+block_size, D)`` under the same block table. The absorbed form reads a row
+as the key of its token (all D columns, under every query head) and as its
+value (the first ``value_dim`` columns): multi-query attention with one K/V
+head whose values are a slice of its keys. The same three implementations
+serve it with the pool in both roles: the kernel for T = 1 (a latent row
+leaves HBM once a step), the blockwise composite for a write over a long
+table (a chunk of queries against the lane's own pages, a group at a time)
+and the gathered composite otherwise. The probabilities meet the values as
+ONE bfloat16 number there (relative rounding 2^-9 a probability, averaged
+over the keys of a row: far below what rounding ``q_lat`` and ``o_lat`` to
+bfloat16 already costs the absorbed form), where the K/V composite splits
+them in two: the PV product over a 32 k prefix is most of a chunk's time.
+
 Beside the paged layers, **the window path** (``window_ring_attention``):
 sliding-window layers keep no pages. A sequence holds a fixed ring of K/V
 rows, the window plus one chunk, written round-robin by position; a query
@@ -79,7 +94,8 @@ from ...core import dispatch, flags
 from ...core.tensor import Tensor, as_tensor
 from ...ops.pallas.serving import kv_dequantize_int8, kv_quantize_int8
 
-__all__ = ["block_multihead_attention", "window_ring_attention"]
+__all__ = ["block_multihead_attention", "latent_paged_attention",
+           "window_ring_attention"]
 
 #: tokens a block table must span before the composite's write path is taken
 #: blockwise (the module docstring has why); the serving configurations up
@@ -97,17 +113,19 @@ def _t(x):
 
 
 def attention_path(q_shape, q_dtype, cache_shape, cache_dtype,
-                   has_new=True, quantized=False) -> str:
+                   has_new=True, quantized=False, value_dim=None) -> str:
     """``"kernel"`` or ``"composite"``: which implementation a call of
     these shapes and dtypes is traced to on this backend (the module
-    docstring has the rule)."""
+    docstring has the rule). ``value_dim``: the cache is one pool of latent
+    pages."""
     if not has_new or quantized or not flags.get_flag("use_pallas_kernels"):
         return "composite"
     # Pallas is imported where a call first needs it, not with the package
     from ...ops.pallas import paged_attention as kernel
     if not (kernel.INTERPRET or jax.default_backend() == "tpu"):
         return "composite"
-    ok = kernel.supports(q_shape, q_dtype, cache_shape, cache_dtype)
+    ok = kernel.supports(q_shape, q_dtype, cache_shape, cache_dtype,
+                         value_dim)
     return "kernel" if ok else "composite"
 
 
@@ -135,10 +153,17 @@ def _log_path(path):
         seen.append(path)
 
 
-def _gather_attend(qa, kca, vca, bta, sla, ksa, vsa, causal, sc):
+def _gather_attend(qa, kca, vca, bta, sla, ksa, vsa, causal, sc, dv=None):
     """The composite: per sequence, gather the blocks of its table into a
-    contiguous view and attend in float32 under a length mask."""
+    contiguous view and attend in float32 under a length mask. ``vca`` None:
+    ``kca`` is the one pool of latent pages, (num_blocks, block_size, D),
+    one K/V head whose rows are keys and, in their first ``dv`` columns,
+    values."""
     B, T, H, D = qa.shape
+    if vca is None:
+        kca = kca[:, :, None, :]
+    else:
+        dv = D
     _nb, bs, KVH, _ = kca.shape
     s_max = bta.shape[1] * bs
     group = H // KVH
@@ -152,7 +177,8 @@ def _gather_attend(qa, kca, vca, bta, sla, ksa, vsa, causal, sc):
             v = v.reshape(s_max, KVH, D)
         else:
             k = kca[blocks].reshape(s_max, KVH, D)
-            v = vca[blocks].reshape(s_max, KVH, D)
+            v = (vca[blocks].reshape(s_max, KVH, D) if vca is not None
+                 else k[..., :dv])
         qg = qb.reshape(T, KVH, group, D)
         s = jnp.einsum("tkgd,skd->tkgs", qg.astype(jnp.float32),
                        k.astype(jnp.float32)) * sc
@@ -168,20 +194,22 @@ def _gather_attend(qa, kca, vca, bta, sla, ksa, vsa, causal, sc):
         o = jnp.einsum("tkgs,skd->tkgd", p, v.astype(jnp.float32))
         any_valid = mask.any(axis=-1, keepdims=True)
         o = jnp.where(any_valid, o, 0.0)
-        return o.reshape(T, H, D).astype(qb.dtype)
+        return o.reshape(T, H, dv).astype(qb.dtype)
 
     return jax.vmap(per_seq)(bta, sla, qa)
 
 
-def _weighted_values(eq, p, v):
+def _weighted_values(eq, p, v, split=True):
     """``einsum(eq, p, v)`` with float32 probabilities ``p`` and float32
     accumulation. Values narrower than float32 meet the probabilities as
     two halves of the values' dtype (``p = hi + lo``, 16 bits of mantissa
-    for bfloat16), as the decode kernel does; float32 values are multiplied
-    at full precision."""
+    for bfloat16), as the decode kernel does, or with ``split`` off as one
+    number of it; float32 values are multiplied at full precision."""
     if v.dtype == jnp.float32:
         return jnp.einsum(eq, p, v, precision=jax.lax.Precision.HIGHEST)
     hi = p.astype(v.dtype)
+    if not split:
+        return jnp.einsum(eq, hi, v, preferred_element_type=jnp.float32)
     lo = (p - hi.astype(jnp.float32)).astype(v.dtype)
     return (jnp.einsum(eq, hi, v, preferred_element_type=jnp.float32)
             + jnp.einsum(eq, lo, v, preferred_element_type=jnp.float32))
@@ -195,8 +223,13 @@ def _scores(eq, q, k):
                       precision=exact)
 
 
-def _blockwise_rows(qa, kca, vca, bta, sla, causal, sc):
+def _blockwise_rows(qa, kca, vca, bta, sla, causal, sc, dv=None):
     B, T, H, D = qa.shape
+    shared = vca is None        # latent pages: a row is key and value
+    if shared:
+        kca = kca[:, :, None, :]
+    else:
+        dv = D
     _nb, bs, KVH, _ = kca.shape
     group = H // KVH
     pages = max(1, BLOCKWISE_GROUP_TOKENS // bs)
@@ -212,7 +245,7 @@ def _blockwise_rows(qa, kca, vca, bta, sla, causal, sc):
             m, l, acc = carry
             ids = jax.lax.dynamic_slice_in_dim(blocks, g * pages, pages)
             k = kca[ids].reshape(span, KVH, D)
-            v = vca[ids].reshape(span, KVH, D)
+            v = k[..., :dv] if shared else vca[ids].reshape(span, KVH, D)
             s = _scores("tkgd,skd->tkgs", qg, k) * sc
             jpos = g * span + jnp.arange(span)
             seen = jpos[None, :] < length
@@ -226,53 +259,64 @@ def _blockwise_rows(qa, kca, vca, bta, sla, causal, sc):
             p = jnp.where(seen, jnp.exp(s - m_new[..., None]), 0.0)
             l = alpha * l + jnp.sum(p, axis=-1)
             acc = alpha[..., None] * acc + _weighted_values(
-                "tkgs,skd->tkgd", p, v)
+                "tkgs,skd->tkgd", p, v, split=not shared)
             return m_new, l, acc
 
         init = (jnp.full((T, KVH, group), -1e30, jnp.float32),
                 jnp.zeros((T, KVH, group), jnp.float32),
-                jnp.zeros((T, KVH, group, D), jnp.float32))
+                jnp.zeros((T, KVH, group, dv), jnp.float32))
         _m, l, acc = jax.lax.fori_loop(
             0, jnp.clip(-(-length // span), 0, steps), step, init)
         # a padded row (nothing seen) yields 0, not NaN
         o = jnp.where(l[..., None] > 0,
                       acc / jnp.maximum(l, 1e-30)[..., None], 0.0)
-        return o.reshape(T, H, D).astype(qb.dtype)
+        return o.reshape(T, H, dv).astype(qb.dtype)
 
     return jax.vmap(per_seq)(bta, sla, qa)
 
 
-@functools.partial(jax.custom_jvp, nondiff_argnums=(5, 6))
-def _blockwise_attend(qa, kca, vca, bta, sla, causal, sc):
+@functools.partial(jax.custom_jvp, nondiff_argnums=(5, 6, 7))
+def _blockwise_attend(qa, kca, vca, bta, sla, causal, sc, dv=None):
     """The composite over page groups with an online softmax: per sequence
     a loop over ``ceil(length / BLOCKWISE_GROUP_TOKENS)`` groups of its
     table, each gathered, scored and folded into a running (max, sum,
-    weighted values). Differentiated, it is the composite (a loop whose
-    trip count is data has no reverse rule)."""
-    return _blockwise_rows(qa, kca, vca, bta, sla, causal, sc)
+    weighted values). ``vca`` None: ``kca`` is the one pool of latent pages
+    (``dv`` columns of a row are its value). Differentiated, it is the
+    composite (a loop whose trip count is data has no reverse rule)."""
+    return _blockwise_rows(qa, kca, vca, bta, sla, causal, sc, dv)
+
+
+def _jvp_as_composite(primals, tangents, causal, sc, dv):
+    """The derivative rule of both write paths: the gathered composite's,
+    in the queries and the pool(s) (``vca`` None: one pool)."""
+    qa, kca, vca, bta, sla = primals
+    n = 2 if vca is None else 3
+    return jax.jvp(
+        lambda q, k, v=None: _gather_attend(q, k, v, bta, sla, None, None,
+                                            causal, sc, dv),
+        primals[:n], tangents[:n])
 
 
 @_blockwise_attend.defjvp
-def _blockwise_attend_jvp(causal, sc, primals, tangents):
-    *_, bta, sla = primals
-    return jax.jvp(
-        lambda q, k, v: _gather_attend(q, k, v, bta, sla, None, None,
-                                       causal, sc),
-        primals[:3], tangents[:3])
+def _blockwise_attend_jvp(causal, sc, dv, primals, tangents):
+    return _jvp_as_composite(primals, tangents, causal, sc, dv)
 
 
-def _composite_decode_attend(qa, kca, vca, bta, sla, sc):
+def _composite_decode_attend(qa, *pools_table_lens, sc, dv=None):
     # T = 1: the query's own slot is the last one its length admits, so the
     # length mask is the causal mask
-    return _gather_attend(qa, kca, vca, bta, sla, None, None, True, sc)
+    *pools, bta, sla = pools_table_lens
+    kca, vca = pools if len(pools) == 2 else (pools[0], None)
+    return _gather_attend(qa, kca, vca, bta, sla, None, None, True, sc, dv)
 
 
 # The kernel path is a primitive of its own so that the choice the trace
 # cannot make is made by its lowering rule, which sees the devices.
 _decode_attend_p = Primitive("paged_decode_attend")
 _decode_attend_p.def_abstract_eval(
-    lambda q, *_pools_table_lens, scale: jax.core.ShapedArray(q.shape,
-                                                              q.dtype))
+    lambda q, *_pools_table_lens, scale, value_dim: jax.core.ShapedArray(
+        q.shape if value_dim is None else q.shape[:-1] + (value_dim,),
+        q.dtype))
 
 
 def _compiler_partitions(axis_context) -> bool:
@@ -286,36 +330,54 @@ def _compiler_partitions(axis_context) -> bool:
     return bool(axis_context.manual_axes) and manual != set(mesh.axis_names)
 
 
-def _lower_decode_attend(ctx, qa, kca, vca, bta, sla, *, scale):
+def _lower_decode_attend(ctx, qa, *rest, scale, value_dim):
     if _compiler_partitions(ctx.module_context.axis_context):
         _log_path("composite")
-        attend = functools.partial(_composite_decode_attend, sc=scale)
+        attend = functools.partial(_composite_decode_attend, sc=scale,
+                                   dv=value_dim)
     else:
         _log_path("kernel")
         # Pallas is imported where a call first needs it, not with the package
         from ...ops.pallas.paged_attention import paged_decode_attention
-        attend = functools.partial(paged_decode_attention, scale=scale)
-    return mlir.lower_fun(attend, multiple_results=False)(
-        ctx, qa, kca, vca, bta, sla)
+
+        def attend(q, *pools_table_lens):
+            *pools, bta, sla = pools_table_lens
+            if value_dim is None:
+                return paged_decode_attention(q, *pools, bta, sla,
+                                              scale=scale)
+            return paged_decode_attention(q, pools[0], None, bta, sla,
+                                          scale=scale, value_dim=value_dim)
+    return mlir.lower_fun(attend, multiple_results=False)(ctx, qa, *rest)
 
 
 mlir.register_lowering(_decode_attend_p, _lower_decode_attend)
 
 
-@functools.partial(jax.custom_jvp, nondiff_argnums=(5,))
-def _decode_attend(qa, kca, vca, bta, sla, sc):
-    """The T = 1 write-path attention of float pages: the kernel where the
-    program is compiled whole, the composite where it is partitioned or
+@functools.partial(jax.custom_jvp, nondiff_argnums=(5, 6))
+def _decode_attend(qa, kca, vca, bta, sla, sc, dv=None):
+    """The T = 1 write-path attention of float pages (``vca`` None: ``kca``
+    is the one pool of latent pages): the kernel where the program is
+    compiled whole, the composite where it is partitioned or
     differentiated."""
-    return _decode_attend_p.bind(qa, kca, vca, bta, sla, scale=sc)
+    pools = (kca,) if vca is None else (kca, vca)
+    return _decode_attend_p.bind(qa, *pools, bta, sla, scale=sc,
+                                 value_dim=dv)
 
 
 @_decode_attend.defjvp
-def _decode_attend_jvp(sc, primals, tangents):
-    *_, bta, sla = primals
-    return jax.jvp(
-        lambda q, k, v: _composite_decode_attend(q, k, v, bta, sla, sc),
-        primals[:3], tangents[:3])
+def _decode_attend_jvp(sc, dv, primals, tangents):
+    return _jvp_as_composite(primals, tangents, True, sc, dv)
+
+
+def _new_rows_slots(bta_i, sla_i, T, nb, bs):
+    """``(block, offset)`` (B, T) of the T newest positions of each
+    sequence: flat slot of new token t of seq b is pos = len - T + t. Rows
+    with seq_len < T (padded batch rows) would yield negative positions
+    that WRAP into live blocks: they get block ``nb``, which a
+    ``mode="drop"`` write leaves out."""
+    pos = sla_i[:, None] - T + jnp.arange(T)[None, :]         # (B, T)
+    blk = jnp.take_along_axis(bta_i, jnp.maximum(pos, 0) // bs, axis=1)
+    return jnp.where(pos >= 0, blk, nb), jnp.maximum(pos, 0) % bs
 
 
 # jitted so that the N attention layers of a serving program trace and lower
@@ -339,15 +401,7 @@ def _write_and_attend(qa, kca, vca, bta, sla, new, scales, *, causal, scale,
 
     if new is not None:
         nk, nv = new
-        # flat slot of new token t of seq b: pos = len - T + t. Rows
-        # with seq_len < T (padded batch rows) would yield negative
-        # positions that WRAP into live blocks — drop those writes.
-        pos = sla_i[:, None] - T + jnp.arange(T)[None, :]     # (B, T)
-        ok = pos >= 0
-        blk = jnp.take_along_axis(bta_i, jnp.maximum(pos, 0) // bs,
-                                  axis=1)                     # (B, T)
-        blk = jnp.where(ok, blk, nb)  # out-of-range -> mode="drop"
-        off = jnp.maximum(pos, 0) % bs
+        blk, off = _new_rows_slots(bta_i, sla_i, T, nb, bs)
         if quantized:
             qk, sk = kv_quantize_int8(nk)
             qv, sv = kv_quantize_int8(nv)
@@ -439,6 +493,69 @@ def block_multihead_attention(q, key_cache, value_cache, block_tables,
             + [True, True] * has_new + [False, False] * quantized)
     return dispatch.call("block_multihead_attention", f, tensors,
                          differentiable_mask=mask)
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("value_dim", "scale", "use_kernel"))
+def _latent_write_and_attend(qa, pool, bta, sla, new, *, value_dim, scale,
+                             use_kernel):
+    """One ``latent_paged_attention`` call on arrays: write the chunk's
+    rows, then attend with the pool as keys and values."""
+    B, T, H, D = qa.shape
+    nb, bs, _ = pool.shape
+    sla_i, bta_i = sla.astype(jnp.int32), bta.astype(jnp.int32)
+    blk, off = _new_rows_slots(bta_i, sla_i, T, nb, bs)
+    pool = pool.at[blk, off].set(new.astype(pool.dtype), mode="drop")
+    if use_kernel:
+        out = _decode_attend(qa, pool, None, bta_i, sla_i, scale, value_dim)
+    elif bta.shape[1] * bs > BLOCKWISE_FROM:
+        out = _blockwise_attend(qa, pool, None, bta_i, sla_i, True, scale,
+                                value_dim)
+    else:
+        out = _gather_attend(qa, pool, None, bta_i, sla_i, None, None, True,
+                             scale, value_dim)
+    return out, pool
+
+
+def latent_paged_attention(q, pages, block_tables, seq_lens, new_rows,
+                           value_dim, scale, name=None):
+    """Write-and-attend over latent (MLA) pages in the absorbed form:
+    multi-query attention with ONE K/V head whose keys are the cached rows
+    and whose values are their first ``value_dim`` columns.
+
+    Args:
+      q: (B, T, H, D) absorbed queries ``[W_uk^T q_nope | q_rope]`` for the
+         T newest positions of each sequence.
+      pages: (num_blocks, block_size, D) rows ``[c | k_r]`` (zeros past
+         them where the row is padded to whole lane tiles; ``q`` carries
+         zeros there too).
+      block_tables / seq_lens: as ``block_multihead_attention``.
+      new_rows: (B, T, D), written at positions [len-T, len) before
+         attending; query t sees the history up to and including its own
+         row.
+      value_dim: columns of a row that are its value (``kv_lora_rank``).
+      scale: the softmax scale (of the UN-absorbed head: ``qk_head_dim **
+         -0.5``; it does not follow from D).
+
+    Returns (out (B, T, H, value_dim), pages). The pool updates functionally
+    (donate it in a jitted serving step)."""
+    q, pool = _t(q), _t(pages)
+    tensors = [q, pool, _t(block_tables), _t(seq_lens), _t(new_rows)]
+    use_kernel = attention_path(
+        q._data.shape, q._data.dtype, pool._data.shape, pool._data.dtype,
+        value_dim=int(value_dim)) == "kernel"
+    if not use_kernel:
+        _log_path("composite")  # the kernel path logs where it is lowered
+
+    def f(qa, pa, bta, sla, new):
+        with jax.named_scope("paged_attention"):
+            return _latent_write_and_attend(
+                qa, pa, bta, sla, new, value_dim=int(value_dim),
+                scale=float(scale), use_kernel=use_kernel)
+
+    return dispatch.call("latent_paged_attention", f, tensors,
+                         differentiable_mask=[True, True, False, False,
+                                              True])
 
 
 @functools.partial(jax.jit, static_argnames=("window", "scale"))

@@ -21,3 +21,4 @@ from .rnn import (RNN, BiRNN, GRU, GRUCell, LSTM, LSTMCell, RNNCellBase,
 from .tail import *        # noqa: F401,F403
 from .latent_moe import LatentMoE
 from .swiglu_moe import SwiGLUMoE
+from .mla import MultiHeadLatentAttention

@@ -25,6 +25,16 @@ scalar-prefetch operands, the work list is walked at run time.
   probabilities go in as two bfloat16 halves (``p = hi + lo`` to 16 bits of
   mantissa) stacked into one product, again with float32 accumulation;
   float32 pages use full-precision products.
+* **Pages that are K and V at once** (``value_cache=None``): a latent
+  (MLA) cache keeps ONE row a token, ``[c | k_r]``, that the absorbed form
+  reads as its key (the whole row) and as its value (the row's first
+  ``value_dim`` columns), one K/V head under every query head. The pool is
+  then ``(num_blocks, block_size, D)``, a page is copied in once and the PV
+  product reads the first ``value_dim`` lanes of the same buffer, so a
+  latent row leaves HBM once a step. ``D`` and ``value_dim`` are whole lane
+  tiles: the engine pads a 576-wide row to 640 with zeros (a bfloat16 array
+  whose minor dimension is 576 is laid out in 640 lanes anyway), and the
+  queries carry zeros there.
 * **One software pipeline over (lane, chunk) work items**: while a chunk is
   being multiplied the next one's pages (the same lane's, or the next live
   lane's first) are in flight, two buffers deep. A lane with ``seq_len <= 0``
@@ -57,12 +67,22 @@ INTERPRET = False
 CHUNK_ROWS = 2048
 
 
-def supports(q_shape, q_dtype, cache_shape, cache_dtype) -> bool:
+def supports(q_shape, q_dtype, cache_shape, cache_dtype,
+             value_dim=None) -> bool:
     """Whether the kernel was written for these shapes: one query a lane,
     float pages of the queries' dtype, lane-dense heads (D a multiple of
-    128) and pages and query blocks that fill whole sublane tiles."""
+    128) and pages and query blocks that fill whole sublane tiles. The
+    pages are a K pool beside a V pool of the same shape, ``(num_blocks,
+    block_size, KVH, D)``, or with ``value_dim`` ONE pool ``(num_blocks,
+    block_size, D)`` whose rows are a token's key and, in their first
+    ``value_dim`` columns (whole lane tiles, at most D), its value."""
     _b, t, h, d = q_shape
-    _nb, bs, kvh, dc = cache_shape
+    if value_dim is None:
+        _nb, bs, kvh, dc = cache_shape
+    else:
+        (_nb, bs, dc), kvh = cache_shape, 1
+        if value_dim % 128 or not 0 < value_dim <= dc:
+            return False
     dt = jnp.dtype(cache_dtype)
     if t != 1 or dt != jnp.dtype(q_dtype) or dc != d or h % kvh:
         return False
@@ -72,9 +92,17 @@ def supports(q_shape, q_dtype, cache_shape, cache_dtype) -> bool:
     return d % 128 == 0 and (bs * kvh) % sublanes == 0 and h % sublanes == 0
 
 
-def _kernel(tables_ref, lens_ref, q_ref, k_hbm, v_hbm, o_ref,
-            kbuf, vbuf, sems, m_scr, l_scr, acc_scr, *,
-            block_size, kv_heads, pages_per_chunk, scale):
+def _kernel(tables_ref, lens_ref, q_ref, *refs,
+            block_size, kv_heads, pages_per_chunk, scale, value_dim):
+    # ``value_dim`` None: a K pool and a V pool, a buffer each; else one
+    # pool and one buffer, read as keys whole and as values in its first
+    # ``value_dim`` lanes
+    if value_dim is None:
+        (k_hbm, v_hbm, o_ref, kbuf, vbuf, sems, m_scr, l_scr,
+         acc_scr) = refs
+    else:
+        k_hbm, o_ref, kbuf, sems, m_scr, l_scr, acc_scr = refs
+        v_hbm = vbuf = None
     lanes, heads, _d = q_ref.shape
     page_rows = block_size * kv_heads
     chunk_rows = pages_per_chunk * page_rows
@@ -100,13 +128,17 @@ def _kernel(tables_ref, lens_ref, q_ref, k_hbm, v_hbm, o_ref,
     def copies(lane, chunk, slot, page):
         blk = tables_ref[lane, chunk * pages_per_chunk + page]
         rows = pl.ds(page * page_rows, page_rows)
-        return (pltpu.make_async_copy(k_hbm.at[blk], kbuf.at[slot, rows],
-                                      sems.at[0, slot]),
+        k_copy = pltpu.make_async_copy(k_hbm.at[blk], kbuf.at[slot, rows],
+                                       sems.at[0, slot])
+        if v_hbm is None:
+            return (k_copy,)
+        return (k_copy,
                 pltpu.make_async_copy(v_hbm.at[blk], vbuf.at[slot, rows],
                                       sems.at[1, slot]))
 
     def each_copy(lane, chunk, slot, act):
-        """``act`` on the K and the V copy of every page the item holds."""
+        """``act`` on every copy (K and V, or the one of a shared page) of
+        every page the item holds."""
         def one(page, carry):
             for c in copies(lane, chunk, slot, page):
                 act(c)
@@ -134,7 +166,10 @@ def _kernel(tables_ref, lens_ref, q_ref, k_hbm, v_hbm, o_ref,
     # lanes with nothing to attend give zeros; rows of a chunk that no copy
     # fills must hold numbers (a masked probability of 0 times a NaN is one)
     o_ref[...] = jnp.zeros_like(o_ref)
-    vbuf[...] = jnp.zeros_like(vbuf)
+    if vbuf is None:
+        kbuf[...] = jnp.zeros_like(kbuf)
+    else:
+        vbuf[...] = jnp.zeros_like(vbuf)
 
     row = jax.lax.broadcasted_iota(jnp.int32, (heads, chunk_rows), 1)
     head = jax.lax.broadcasted_iota(jnp.int32, (heads, chunk_rows), 0)
@@ -169,13 +204,15 @@ def _kernel(tables_ref, lens_ref, q_ref, k_hbm, v_hbm, o_ref,
         alpha = jnp.exp(m_prev - m_new)
         p = jnp.exp(s - m_new)      # a masked score gives exactly 0
         l_scr[...] = alpha * l_scr[...] + jnp.sum(p, axis=-1, keepdims=True)
+        values = vbuf[slot] if vbuf is not None \
+            else kbuf[slot, :, :value_dim]
         if exact:
-            pv = jnp.dot(p, vbuf[slot], preferred_element_type=jnp.float32,
+            pv = jnp.dot(p, values, preferred_element_type=jnp.float32,
                          precision=precision)
         else:
             hi = p.astype(jnp.bfloat16)
             lo = (p - hi.astype(jnp.float32)).astype(jnp.bfloat16)
-            both = jnp.dot(jnp.concatenate([hi, lo], axis=0), vbuf[slot],
+            both = jnp.dot(jnp.concatenate([hi, lo], axis=0), values,
                            preferred_element_type=jnp.float32)
             pv = both[:heads] + both[heads:]
         acc_scr[...] = alpha * acc_scr[...] + pv
@@ -192,22 +229,29 @@ def _kernel(tables_ref, lens_ref, q_ref, k_hbm, v_hbm, o_ref,
 
 
 def paged_decode_attention(q, key_cache, value_cache, block_tables, seq_lens,
-                           scale=None):
+                           scale=None, value_dim=None):
     """Attention of one query a lane over the lane's paged K/V.
 
     Args:
       q: (B, 1, H, D).
       key_cache / value_cache: (num_blocks, block_size, KVH, D), the new
-        token's K/V already written; only read.
+        token's K/V already written; only read. With ``value_cache=None``
+        and ``value_dim``: ``key_cache`` (num_blocks, block_size, D) is the
+        one pool of a latent cache, a row the key of its token and, in its
+        first ``value_dim`` columns, the value, under every query head.
       block_tables: (B, max_blocks) int32; entries past a lane's length are
         never looked at.
       seq_lens: (B,) int32, the new token included; a lane with
         ``seq_len <= 0`` reads no page and gives zeros.
 
-    Returns (B, 1, H, D) in ``q``'s dtype. ``supports`` says which shapes.
+    Returns (B, 1, H, D), or (B, 1, H, value_dim) over shared pages, in
+    ``q``'s dtype. ``supports`` says which shapes.
     """
     b, _t, h, d = q.shape
-    nb, bs, kvh, _ = key_cache.shape
+    shared = value_cache is None
+    nb, bs = key_cache.shape[:2]
+    kvh = 1 if shared else key_cache.shape[2]
+    dv = value_dim if shared else d
     page_rows = bs * kvh
     pages_per_chunk = max(1, min(CHUNK_ROWS // page_rows,
                                  block_tables.shape[1]))
@@ -215,33 +259,32 @@ def paged_decode_attention(q, key_cache, value_cache, block_tables, seq_lens,
     sc = scale if scale is not None else 1.0 / (d ** 0.5)
     kernel = functools.partial(
         _kernel, block_size=bs, kv_heads=kvh,
-        pages_per_chunk=pages_per_chunk, scale=sc)
+        pages_per_chunk=pages_per_chunk, scale=sc,
+        value_dim=value_dim if shared else None)
+    pools = [key_cache.reshape(nb, page_rows, d)]
+    if not shared:
+        pools.append(value_cache.reshape(nb, page_rows, d))
     out = pl.pallas_call(
         kernel,
-        out_shape=jax.ShapeDtypeStruct((b, h, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, h, dv), q.dtype),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(1,),
-            in_specs=[
-                pl.BlockSpec((b, h, d), lambda i, *_: (0, 0, 0)),
-                pl.BlockSpec(memory_space=pl.ANY),
-                pl.BlockSpec(memory_space=pl.ANY),
-            ],
-            out_specs=pl.BlockSpec((b, h, d), lambda i, *_: (0, 0, 0)),
+            in_specs=[pl.BlockSpec((b, h, d), lambda i, *_: (0, 0, 0))]
+            + [pl.BlockSpec(memory_space=pl.ANY)] * len(pools),
+            out_specs=pl.BlockSpec((b, h, dv), lambda i, *_: (0, 0, 0)),
             scratch_shapes=[
-                pltpu.VMEM((2, chunk_rows, d), key_cache.dtype),
-                pltpu.VMEM((2, chunk_rows, d), value_cache.dtype),
+                pltpu.VMEM((2, chunk_rows, d), pool.dtype) for pool in pools
+            ] + [
                 pltpu.SemaphoreType.DMA((2, 2)),
                 pltpu.VMEM((h, 1), jnp.float32),
                 pltpu.VMEM((h, 1), jnp.float32),
-                pltpu.VMEM((h, d), jnp.float32),
+                pltpu.VMEM((h, dv), jnp.float32),
             ]),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=INTERPRET,
         name="paged_decode_attn",
     )(block_tables.astype(jnp.int32), seq_lens.astype(jnp.int32),
-      q.reshape(b, h, d),
-      key_cache.reshape(nb, page_rows, d),
-      value_cache.reshape(nb, page_rows, d))
-    return out.reshape(b, 1, h, d)
+      q.reshape(b, h, d), *pools)
+    return out.reshape(b, 1, h, dv)

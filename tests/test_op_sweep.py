@@ -699,6 +699,7 @@ SKIP = {
     # dedicated suites
     "block_multihead_attention": "covered by tests/test_paged_attention.py",
     "window_ring_attention": "covered by tests/test_paged_attention.py",
+    "latent_paged_attention": "covered by tests/test_paged_attention.py",
     "ctc_loss": "covered by tests/test_ops_round2b.py (CTC numerics)",
     "ctc_align": "covered by tests/test_ops_round2b.py",
     "rnnt_loss": "covered by tests/test_text_onnx.py / round2b",

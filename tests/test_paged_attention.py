@@ -746,3 +746,229 @@ class TestWindowRing:
                 z(1, 64, 4, 16), z(1, 190, 2, 16), z(1, 190, 2, 16),
                 paddle.to_tensor(np.asarray([64], np.int32)),
                 z(1, 64, 2, 16), z(1, 64, 2, 16), window=128)
+
+
+# ------------------------------------------------------------- latent pages
+def _latent_dense(q, rows, dv, scale, first):
+    """q (T, H, D) at positions first.., rows (S, D): every head attends
+    the one K/V head whose keys are the rows and whose values are their
+    first ``dv`` columns, causally; float64."""
+    T = q.shape[0]
+    S = rows.shape[0]
+    s = np.einsum("thd,sd->ths", q.astype(np.float64),
+                  rows.astype(np.float64)) * scale
+    seen = (np.arange(S)[None, :] <= (first + np.arange(T))[:, None])
+    s = np.where(seen[:, None, :], s, -np.inf)
+    p = np.exp(s - s.max(-1, keepdims=True))
+    p = p / p.sum(-1, keepdims=True)
+    return np.einsum("ths,sc->thc", p, rows[:, :dv].astype(np.float64))
+
+
+def _latent_case(rng, lens, T, H, D, mb, bs=16):
+    """Histories of ``len - T`` rows in shuffled pages of ONE pool plus the
+    chunk's new rows; returns the call's inputs and each lane's rows."""
+    B = len(lens)
+    nb = B * mb + 2
+    pool = rng.randn(nb, bs, D).astype(np.float32)
+    tables = (rng.permutation(nb - 1)[:B * mb] + 1).reshape(B, mb).astype(
+        np.int32)
+    new = rng.randn(B, T, D).astype(np.float32)
+    q = rng.randn(B, T, H, D).astype(np.float32)
+    full = []
+    for b, n in enumerate(lens):
+        hist = max(n - T, 0)
+        rows = pool[tables[b]].reshape(mb * bs, D)[:hist]
+        full.append(np.concatenate([rows, new[b, max(T - n, 0):]]))
+    return q, pool, tables, new, full
+
+
+def _latent(q, pool, tables, lens, new, dv, scale, dtype="float32"):
+    t = lambda a: paddle.to_tensor(np.asarray(a)).astype(dtype)  # noqa: E731
+    out, pool2 = F.latent_paged_attention(
+        t(q), t(pool), paddle.to_tensor(tables),
+        paddle.to_tensor(np.asarray(lens, np.int32)), t(new), dv, scale)
+    return tuple(np.asarray(x.astype("float32").numpy())
+                 for x in (out, pool2))
+
+
+class TestLatentPages:
+    """``latent_paged_attention``: one pool whose rows are keys and, in
+    their first ``value_dim`` columns, values, under every query head.
+    Tolerances as the K/V pages': 2e-5 in float32 (sums in another order);
+    2e-2 in bfloat16 on outputs of order 1 (the composite rounds the
+    probabilities to bfloat16 once, the kernel splits them in two)."""
+
+    @pytest.mark.parametrize("dtype,atol", [("float32", 2e-5),
+                                            ("bfloat16", 2e-2)])
+    @pytest.mark.parametrize("dims", [(256, 128), (640, 512)],
+                             ids=["row256-v128", "row640-v512"])
+    def test_kernel_matches_composite_and_dense(self, monkeypatch, dims,
+                                                dtype, atol):
+        """Ragged lengths: 1, exactly one block, one block + 1, the whole
+        table (a lane at the table's end), 0 (an empty lane: the sentinel
+        of mid-prefill and stalled slots) and a length that ends
+        mid-chunk."""
+        D, dv = dims
+        H, mb, bs, scale = 16, 12, 16, 0.07
+        lens = [1, bs, bs + 1, mb * bs, 0, 150]
+        q, pool, tables, new, full = _latent_case(
+            np.random.RandomState(30), lens, 1, H, D, mb)
+        if dtype == "bfloat16":     # the values the pool really holds
+            import jax.numpy as jnp
+            r = lambda a: np.asarray(jnp.asarray(a, jnp.bfloat16)  # noqa: E731
+                                     .astype(jnp.float32))
+            q, pool, new = map(r, (q, pool, new))
+            full = [r(rows) for rows in full]
+        want = _latent(q, pool, tables, lens, new, dv, scale, dtype)
+        monkeypatch.setattr(PK, "INTERPRET", True)
+        import jax.numpy as jnp
+        assert _runs_kernel(
+            lambda *a: F.latent_paged_attention(
+                *map(paddle.Tensor, a), dv, scale)[0]._data,
+            *[jnp.asarray(a).astype(dtype) for a in (q, pool)],
+            jnp.asarray(tables), jnp.asarray(lens, jnp.int32),
+            jnp.asarray(new).astype(dtype))
+        got = _latent(q, pool, tables, lens, new, dv, scale, dtype)
+        assert got[0].shape == (len(lens), 1, H, dv)
+        # the pool bit for bit what the composite leaves, the empty lane's
+        # blocks untouched
+        np.testing.assert_array_equal(got[1], want[1])
+        np.testing.assert_array_equal(got[1][tables[4]], pool[tables[4]])
+        np.testing.assert_allclose(got[0], want[0], atol=atol)
+        np.testing.assert_array_equal(got[0][4], 0.0)
+        for b, n in enumerate(lens):
+            if n:
+                ref = _latent_dense(q[b], full[b], dv, scale, n - 1)
+                np.testing.assert_allclose(got[0][b], ref, atol=atol)
+
+    def test_entries_past_the_length_are_never_read(self, kernel_on):
+        """Table entries past a lane's pages point at another lane's live
+        blocks and at a block of NaN; the empty lane's whole table does:
+        neither may reach an output (0 x NaN is NaN), through the buffer
+        that serves as K and as V."""
+        D, dv, H, mb, scale = 256, 128, 8, 6, 0.1
+        lens = [20, 70, 0]
+        q, pool, tables, new, full = _latent_case(
+            np.random.RandomState(31), lens, 1, H, D, mb)
+        pool[0] = np.nan                 # block 0 is in no table
+        tables[0, 2:] = [tables[1, 0], tables[1, 1], 0, 0]
+        tables[1, 5] = 0
+        tables[2, :] = 0
+        out, _pool = _latent(q, pool, tables, lens, new, dv, scale)
+        assert np.isfinite(out).all()
+        np.testing.assert_array_equal(out[2], 0.0)
+        for b in (0, 1):
+            ref = _latent_dense(q[b], full[b], dv, scale, lens[b] - 1)
+            np.testing.assert_allclose(out[b], ref, atol=2e-5)
+
+    @pytest.mark.parametrize("T", [5, 16])
+    @pytest.mark.parametrize("wide", [False, True], ids=["gather",
+                                                         "blockwise"])
+    def test_a_chunk_attends_causally_over_its_prefix(self, monkeypatch, T,
+                                                      wide):
+        """T > 1 (chunked prefill, a speculative verify): the gathered
+        composite, and over a table wider than ``BLOCKWISE_FROM`` the
+        blockwise one (groups of 2 pages, the last group padded), against
+        dense attention; rows of a chunk before the sequence's first token
+        (left padding) write nothing and give zeros."""
+        from paddle_tpu.nn.functional import paged_attention as fpa
+        D, dv, H, bs, mb, scale = 48, 32, 4, 8, 7, 0.2
+        calls = []
+        inner = fpa._blockwise_rows
+        monkeypatch.setattr(
+            fpa, "_blockwise_rows",
+            lambda *a: (calls.append(a[0].shape), inner(*a))[1])
+        monkeypatch.setattr(fpa, "BLOCKWISE_GROUP_TOKENS", 16)
+        if wide:
+            monkeypatch.setattr(fpa, "BLOCKWISE_FROM", mb * bs - 1)
+        fpa._latent_write_and_attend.clear_cache()
+        lens = [T, 9 + T, mb * bs, max(T - 2, 1), 0]
+        q, pool, tables, new, full = _latent_case(
+            np.random.RandomState(32 + T), lens, T, H, D, mb, bs)
+        try:
+            out, pool2 = _latent(q, pool, tables, lens, new, dv, scale)
+        finally:
+            fpa._latent_write_and_attend.clear_cache()
+        assert bool(calls) == wide
+        for b, n in enumerate(lens):
+            pad = max(T - n, 0)
+            np.testing.assert_array_equal(out[b, :pad], 0.0)
+            if n:
+                ref = _latent_dense(q[b, pad:], full[b], dv, scale,
+                                    n - T + pad)
+                np.testing.assert_allclose(out[b, pad:], ref, atol=2e-5)
+        # the chunk's rows are where the table says, and nowhere else
+        touched = np.zeros(pool.shape[:2], bool)
+        for b, n in enumerate(lens):
+            for t in range(max(T - n, 0), T):
+                pos = n - T + t
+                blk, off = tables[b, pos // bs], pos % bs
+                np.testing.assert_array_equal(pool2[blk, off], new[b, t])
+                touched[blk, off] = True
+        np.testing.assert_array_equal(pool2[~touched], pool[~touched])
+
+    @pytest.mark.parametrize("case,kernel", [
+        ("decode", True), ("decode-bf16", True), ("verify-t2", False),
+        ("row-576", False), ("values-96", False), ("block-4", False),
+        ("pool-of-another-dtype", False), ("no-tpu-no-interpreter", False)])
+    def test_dispatch_rule(self, monkeypatch, case, kernel):
+        """What the call can see decides: one query a lane, a float pool of
+        the queries' dtype, rows and values in whole lane tiles, pages in
+        whole sublane tiles, on a TPU (or under the interpreter)."""
+        import jax.numpy as jnp
+        if case != "no-tpu-no-interpreter":
+            monkeypatch.setattr(PK, "INTERPRET", True)
+        B, T, H, D, dv, bs, mb = 2, 1, 32, 640, 512, 16, 2
+        dt = pool_dt = jnp.float32
+        if case == "decode-bf16":
+            dt = pool_dt = jnp.bfloat16
+        elif case == "verify-t2":
+            T = 2
+        elif case == "row-576":
+            D = 576                     # the engine pads it to 640
+        elif case == "values-96":
+            dv = 96
+        elif case == "block-4":
+            bs = 4
+        elif case == "pool-of-another-dtype":
+            pool_dt = jnp.bfloat16
+        nb = B * mb + 1
+        q = jnp.ones((B, T, H, D), dt)
+        pool = jnp.zeros((nb, bs, D), pool_dt)
+        new = jnp.ones((B, T, D), pool_dt)
+        tables = jnp.arange(1, nb, dtype=jnp.int32).reshape(B, mb)
+        lens = jnp.asarray([5, 9], jnp.int32)
+
+        def call(q, pool, new):
+            return F.latent_paged_attention(
+                paddle.Tensor(q), paddle.Tensor(pool), paddle.Tensor(tables),
+                paddle.Tensor(lens), paddle.Tensor(new), dv, 0.1)[0]._data
+
+        assert _runs_kernel(call, q, pool, new) is kernel
+
+    def test_differentiated_call_gets_the_composites_gradient(
+            self, monkeypatch):
+        """Kernel or not, a caller that backpropagates through a call gets
+        the gathered composite's gradient, in the queries and the new
+        rows."""
+        rng = np.random.RandomState(34)
+        D, dv, H, mb = 256, 128, 8, 3
+        lens = [33, 7]
+        q, pool, tables, new, _full = _latent_case(rng, lens, 1, H, D, mb)
+        w = rng.randn(2, 1, H, dv).astype(np.float32)
+
+        def grads():
+            qt, nt = (paddle.to_tensor(a, stop_gradient=False)
+                      for a in (q, new))
+            out, _pool = F.latent_paged_attention(
+                qt, paddle.to_tensor(pool), paddle.to_tensor(tables),
+                paddle.to_tensor(np.asarray(lens, np.int32)), nt, dv, 0.1)
+            (out * paddle.to_tensor(w)).sum().backward()
+            return qt.grad.numpy(), nt.grad.numpy()
+
+        want = grads()
+        monkeypatch.setattr(PK, "INTERPRET", True)
+        got = grads()
+        for g, wnt in zip(got, want):
+            assert np.abs(wnt).max() > 0
+            np.testing.assert_allclose(g, wnt, atol=2e-5)

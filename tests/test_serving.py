@@ -508,3 +508,109 @@ def test_compiled_programs_do_not_keep_a_model_alive():
     del model, eng, twin
     gc.collect()
     assert [ref() for ref in held] == [None, None, None]
+
+
+class TestLatentPages:
+    """A latent-attention decoder through the engine: ``latent_kv`` is the
+    fifth state kind, one pool a layer under the one block table."""
+
+    @staticmethod
+    def _model():
+        from paddle_tpu.models import DeepseekV3ForCausalLM, deepseek_v3_tiny
+        paddle.seed(11)
+        m = DeepseekV3ForCausalLM(deepseek_v3_tiny(vocab_size=97))
+        m.eval()
+        return m
+
+    def test_state_kinds_and_the_handles_write(self):
+        """``_STATE_KINDS`` names five kinds; the cache handle pads queries
+        and rows to the pool's lane tiles, writes the chunk's rows where
+        the table says and nowhere else, and hands back values
+        ``value_dim`` wide."""
+        import jax.numpy as jnp
+        from paddle_tpu.inference import serving
+        assert serving._STATE_KINDS == ("paged_kv", "latent_kv", "window_kv",
+                                        "slot_state", "accumulator")
+        assert list(serving._layer_states(
+            [("latent_kv", 40), (("latent_kv", 40), ("accumulator", (3,),
+                                                     "int32"))])) == [
+            (0, ("latent_kv", 40)), (1, ("latent_kv", 40)),
+            (1, ("accumulator", (3,), "int32"))]
+        rng = np.random.RandomState(0)
+        pool = jnp.asarray(rng.randn(6, 8, 128).astype(np.float32))
+        T = 8
+        q = paddle.to_tensor(rng.randn(1, T, 4, 40).astype(np.float32))
+        rows = paddle.to_tensor(rng.randn(1, T, 40).astype(np.float32))
+        start = jnp.asarray([8], jnp.int32)         # the second chunk
+        tables = jnp.asarray([[3, 5, 1, 0]], jnp.int32)
+        cache = serving._PagedCache(
+            {(0, "latent_kv"): 0}, [], [], [pool], tables, start + T, start,
+            None, T)
+        out = cache.attend_latent(0, q, rows, 32, 0.2)
+        assert out.shape == [1, T, 4, 32]
+        got = np.asarray(cache.states[0])
+        # positions 8..15 live in the table's second block, 5
+        np.testing.assert_array_equal(got[5, :, :40], rows.numpy()[0])
+        np.testing.assert_array_equal(got[5, :, 40:], 0.0)
+        for blk in (0, 1, 2, 3, 4):
+            np.testing.assert_array_equal(got[blk], np.asarray(pool)[blk])
+
+    def test_preempted_and_readmitted_tokens_are_the_undisturbed_ones(self):
+        """Memory for one long request at a time: lanes stall, one is
+        preempted (its latent pages freed, as K/V pages are) and re-prefilled
+        later over prompt + generated tokens; greedy tokens are those of an
+        engine with room for all."""
+        model = self._model()
+        rng = np.random.RandomState(5)
+        prompts = [rng.randint(1, 97, n).tolist() for n in (12, 12, 9)]
+
+        def serve(**kw):
+            eng = PagedEngine(model, max_batch=4, block_size=8,
+                              max_blocks_per_seq=6, **kw)
+            evicted = []
+            evict = eng._evict
+            eng._evict = lambda slot: (evicted.append(slot), evict(slot))[-1]
+            rids = [eng.add_request(p, max_new_tokens=20) for p in prompts]
+            out = eng.run_to_completion(max_ticks=600)
+            assert eng.bm.available == eng._total_usable
+            return [out[r] for r in rids], evicted
+
+        want, none = serve(num_blocks=32)
+        got, evicted = serve(num_blocks=9)
+        assert not none and evicted
+        assert got == want
+
+    def test_decode_takes_the_kernel_and_health_counts_the_latent_call(
+            self, monkeypatch):
+        """With the kernel available (the interpreter, here) the decode
+        program of a model whose rows fill lane tiles attends through
+        ``paged_decode_attn`` in every layer, the path gauge says so, and
+        the tokens are the composite's."""
+        from paddle_tpu.models import DeepseekV3ForCausalLM, deepseek_v3_tiny
+        from paddle_tpu.ops.pallas import paged_attention as PK
+
+        def fresh():
+            paddle.seed(12)
+            m = DeepseekV3ForCausalLM(deepseek_v3_tiny(
+                vocab_size=97, num_attention_heads=8, kv_lora_rank=128,
+                qk_rope_head_dim=64))
+            m.eval()
+            return m
+
+        geometry = dict(max_batch=2, block_size=8, num_blocks=24,
+                        max_blocks_per_seq=6)
+        prompts = [[5, 9, 33, 2, 71, 8, 14], [3, 1, 4, 1, 5]]
+
+        def serve(eng):
+            rids = [eng.add_request(p, max_new_tokens=6) for p in prompts]
+            out = eng.run_to_completion()
+            return [out[r] for r in rids]
+
+        plain = PagedEngine(fresh(), **geometry)
+        want = serve(plain)
+        assert plain.health()["decode_attention"] == "composite"
+        monkeypatch.setattr(PK, "INTERPRET", True)
+        eng = PagedEngine(fresh(), **geometry)
+        assert serve(eng) == want
+        assert eng.health()["decode_attention"] == "kernel"
+        assert eng.health()["latent_bytes"] == 3 * 24 * 8 * 256 * 4

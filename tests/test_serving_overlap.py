@@ -50,6 +50,12 @@ def model_of(kind):
         from benchmark.tests.tiny_hybrid import NEMOTRON as cfg
         from paddle_tpu.models import NemotronHForCausalLM
         m = NemotronHForCausalLM(driver.model_config(cfg))
+    elif kind == "latent":          # latent pages, one pool a layer
+        from benchmark.drivers import serve_deepseek_v3 as driver
+        from benchmark.lib import weights_deepseek_v3 as weights_lib
+        from benchmark.tests.tiny_deepseek_v3 import DEEPSEEK as cfg
+        from paddle_tpu.models import DeepseekV3ForCausalLM
+        m = DeepseekV3ForCausalLM(driver.model_config(cfg))
     else:                           # window rows a slot beside paged K/V
         from benchmark.drivers import serve_exaone_moe as driver
         from benchmark.lib import weights_exaone_moe as weights_lib
@@ -132,7 +138,7 @@ def ref_greedy(model, prompt, n_new):
 # ------------------------------------------------------------ (a) the tokens
 @pytest.mark.parametrize("sampled", [False, True], ids=["greedy", "sampled"])
 @pytest.mark.parametrize("budget", [16, None], ids=["chunked", "whole"])
-@pytest.mark.parametrize("kind", ["dense", "hybrid", "window"])
+@pytest.mark.parametrize("kind", ["dense", "hybrid", "window", "latent"])
 def test_tokens_are_those_of_programs_run_one_at_a_time(kind, budget,
                                                         sampled):
     prompts = prompts_of(kind, LENGTHS)
@@ -429,7 +435,7 @@ def test_counters_reach_the_registry():
 
 
 # -------------------------------------- nothing compiles after the warm-up
-@pytest.mark.parametrize("kind", ["dense", "window"])
+@pytest.mark.parametrize("kind", ["dense", "window", "latent"])
 def test_no_program_compiles_after_warmup(kind):
     """The window's traffic meets every shape and every kind of argument in
     ``warmup()``: the two programs, and the hand-over of the fed token after

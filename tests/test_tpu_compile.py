@@ -84,6 +84,37 @@ def test_paged_decode_step_compiles_with_the_kernel_and_no_pool_copy(
                             "parameter", "parameter"], made
 
 
+def test_latent_decode_step_compiles_with_the_kernel_and_no_pool_copy(
+        one_chip, tpu_backend):
+    """The decode step of ``latent_paged_attention`` at the Kanana cell's
+    shapes (64 lanes, 32 query heads over rows of 640 lanes, values their
+    first 512, 49 152 pages of 16, a block table of 64 x 2048 int32 = 512
+    KiB of scalar prefetch): the scatter of the new rows, then
+    ``paged_decode_attn`` reading the ONE donated pool as it lies."""
+    B, H, D, dv, nb, bs, mb, dt = 64, 32, 640, 512, 49152, 16, 2048, \
+        jnp.bfloat16
+
+    def step(q, pool, tables, lens, new):
+        out, pool = F.latent_paged_attention(
+            paddle.Tensor(q), paddle.Tensor(pool), paddle.Tensor(tables),
+            paddle.Tensor(lens), paddle.Tensor(new), dv, 192 ** -0.5)
+        return out._data, pool._data
+
+    def s(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    text = jax.jit(step, donate_argnums=(1,)).lower(
+        s((B, 1, H, D), dt), s((nb, bs, D), dt), s((B, mb), jnp.int32),
+        s((B,), jnp.int32), s((B, 1, D), dt)).compile().as_text()
+    entry = text[text.index("ENTRY"):]
+    assert entry.count('custom_call_target="tpu_custom_call"') == 1
+    assert "%paged_decode_attn" in entry
+    # what makes or moves an array of the pool's size: the parameter and
+    # the in-place scatter (the pool is page rows already); no copy
+    made = re.findall(rf"= bf16\[{nb},\S+ ([\w-]+)\(", entry)
+    assert sorted(made) == ["fusion", "parameter"], made
+
+
 @pytest.mark.parametrize("partitioned_by", ["the-compiler", "shard-map"])
 def test_paged_decode_step_compiles_over_a_mesh(four_chips, tpu_backend,
                                                 partitioned_by):
