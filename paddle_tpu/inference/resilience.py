@@ -324,8 +324,11 @@ M_LAUNCHES = _metrics.counter(
     "paddle_tpu_serving_launches_total",
     "Serving programs launched, by whether an earlier program was still "
     "unread at the launch (overlapped=true: the host prepared this one "
-    "while the chip ran that one). health()[\"overlap_share\"] is the "
-    "true share.", labelnames=("overlapped",))
+    "while the chip ran that one; health()[\"overlap_share\"] is the "
+    "true share) and by kind: prefill (a chunk alone), decode (a step "
+    "alone), verify, mixed (a decode step with the tick's last chunk "
+    "aboard; health()[\"mixed_share\"] is mixed over decode + verify + "
+    "mixed).", labelnames=("overlapped", "kind"))
 M_READS = _metrics.counter(
     "paddle_tpu_serving_reads_total",
     "Reads of a launched program's tokens, by whether the program had "

@@ -19,6 +19,23 @@ free list, and admission/eviction is plain Python between ticks:
   inter-token latency; ``_PREFILL_WIDTH`` without one. A prefix is
   left-padded to a multiple of W, so its last chunk ends on the
   prompt's last token;
+* a tick's programs are chunk, step, or chunk-with-step. A prefill chunk
+  and a decode step each stream every weight for a few rows; where a tick
+  holds a chunk to launch AND a decode step with a lane to feed, the
+  tick's LAST chunk and the step go out as ONE program
+  (``paged_mixed_step``): what is row-wise (embedding, norms,
+  projections, MLPs, experts, the head) runs once over the chunk's W rows
+  and the lanes' B rows, so a weight leaves HBM once, and every mixer
+  (paged / window / latent attention, a recurrence) runs on each group's
+  own rows through the path it has (``_PagedCache``'s groups). Earlier
+  chunks of the same tick, and a tick with only one of the two, launch
+  the program they always did. What decides is what the tick holds, no
+  option; ``health()["mixed_share"]`` is the share of decode steps that
+  went out with a chunk aboard. The slot a final chunk finishes takes its
+  first token from that program, so it joins the NEXT tick's step. One
+  tick in ``share_window_ticks`` (32) that could mix launches the two
+  apart, so that each program's own device time stays readable (the
+  phase-share gauge, a device trace) where every step rides a chunk;
 * decode runs ALL active slots in one (B, 1) step; idle slots point at a
   reserved trash block so the compiled program never branches on
   occupancy. Its attention is the ``paged_decode_attn`` Pallas kernel
@@ -55,7 +72,7 @@ step's input), the host counts the tokens it has launched but not read,
 and ``step()`` returns with its last program still running: its tokens
 are delivered by the next ``step()``. ``speculate=`` engines (the next
 rows depend on the accepted count) and the dense scorer read their
-program in the tick that launched it.
+program in the tick that launched it, and keep chunk and step apart.
 
 Sampling is per-request deterministic: every sampled token draws from a
 key folded from (engine seed, request id, token position), so a request
@@ -164,7 +181,7 @@ class _LlamaArch:
         self.cfg = model.cfg
         self.num_kv_heads = model.cfg.num_kv_heads or model.cfg.num_heads
 
-    def forward_chunk(self, tokens, start, cache, logits_t: int = 1):
+    def forward_chunk(self, tokens, cache, logits_t: int = 1):
         from paddle_tpu import ops
         from ..models.llama import rotary_embedding
 
@@ -174,6 +191,7 @@ class _LlamaArch:
         nh = cfg.num_heads
         hd = cfg.hidden_size // nh
         nkv = self.num_kv_heads
+        pos = cache.positions
         with jax.named_scope("embed"):
             x = model.model.embed_tokens(Tensor(tokens))
         for li, blk in enumerate(model.model.layers):
@@ -182,15 +200,15 @@ class _LlamaArch:
                 q = ops.reshape(blk.self_attn.q_proj(ln), [B, T, nh, hd])
                 k = ops.reshape(blk.self_attn.k_proj(ln), [B, T, nkv, hd])
                 v = ops.reshape(blk.self_attn.v_proj(ln), [B, T, nkv, hd])
-                q = rotary_embedding(q, cfg.rope_theta, pos_offset=start)
-                k = rotary_embedding(k, cfg.rope_theta, pos_offset=start)
+                q = rotary_embedding(q, cfg.rope_theta, pos_offset=pos)
+                k = rotary_embedding(k, cfg.rope_theta, pos_offset=pos)
                 out = cache.attend(li, q, k, v)
                 x = x + blk.self_attn.o_proj(
                     ops.reshape(out, [B, T, nh * hd]))
             with jax.named_scope("mlp"):
                 x = x + blk.mlp(blk.post_attention_layernorm(x))
         x = model.model.norm(x)
-        last = Tensor(x._data[:, -logits_t:, :])
+        last = cache.head_rows(x, logits_t)
         with jax.named_scope("lm_head"):
             if model.lm_head is None:
                 return ops.matmul(last, model.model.embed_tokens.weight,
@@ -208,7 +226,7 @@ class _GPTArch:
         self.num_kv_heads = model.cfg.num_heads
         self.max_positions = model.cfg.max_seq_len
 
-    def forward_chunk(self, tokens, start, cache, logits_t: int = 1):
+    def forward_chunk(self, tokens, cache, logits_t: int = 1):
         from paddle_tpu import ops
 
         m = self.model.gpt
@@ -216,12 +234,11 @@ class _GPTArch:
         B, T = tokens.shape
         nh = cfg.num_heads
         hd = cfg.hidden_size // nh
-        # learned positional embeddings at per-slot positions; a prefill
+        # learned positional embeddings at per-row positions; a prefill
         # chunk's left padding sits at negative positions (up to
         # prefill_width - 1 of them), whose rows are discarded: row 0
         # stands in, so no gather reaches outside the table
-        pos_idx = jnp.maximum(
-            start[:, None] + jnp.arange(T, dtype=start.dtype)[None, :], 0)
+        pos_idx = jnp.maximum(cache.positions, 0)
         with jax.named_scope("embed"):
             pos_emb = jnp.take(m.wpe.weight._data, pos_idx, axis=0)
             x = m.wte(Tensor(tokens)) + Tensor(pos_emb)
@@ -239,7 +256,7 @@ class _GPTArch:
             with jax.named_scope("mlp"):
                 x = x + blk.mlp(blk.ln2(x))
         x = m.ln_f(x)
-        last = Tensor(x._data[:, -logits_t:, :])
+        last = cache.head_rows(x, logits_t)
         with jax.named_scope("lm_head"):
             return ops.matmul(last, m.wte.weight, transpose_y=True)
 
@@ -265,7 +282,8 @@ def _pick_arch(model):
         # the protocol: a model brings its own adapter (``cfg``,
         # ``num_kv_heads``, ``head_dim``, ``cache_layout(dtype)``: one entry
         # a layer, a state or a tuple of states, and
-        # ``forward_chunk(tokens, start, cache, logits_t)``)
+        # ``forward_chunk(tokens, cache, logits_t)``: positions, the real
+        # rows and the rows the head reads come from the cache handle)
         return model.paged_adapter()
     if isinstance(model, LlamaForCausalLM):
         return _LlamaArch(model)
@@ -276,7 +294,7 @@ def _pick_arch(model):
     raise TypeError(
         f"PagedEngine supports LlamaForCausalLM / GPTForCausalLM (or "
         f"subclasses), models that bring a paged_adapter() (cfg, "
-        f"num_kv_heads, head_dim, forward_chunk(tokens, start, cache, "
+        f"num_kv_heads, head_dim, forward_chunk(tokens, cache, "
         f"logits_t) and cache_layout(dtype): per layer None, one state "
         f"('paged_kv',) / ('latent_kv', row_width) / ('window_kv', window) "
         f"/ ('slot_state', {{name: (shape, dtype)}}) / ('accumulator', "
@@ -426,10 +444,14 @@ def _feed_tokens(unread, on_host, from_host):
 class _Launched:
     """A launched program whose outputs the host has not read yet."""
     phase: str                 # "prefill" / "decode": its span and gauge
-    kind: str                  # "prefill" / "decode" / "verify": its emit
+    #: "prefill" / "decode" / "verify" / "mixed" (a decode step with a
+    #: chunk aboard): its emit, its program
+    kind: str
     outs: list                 # the device arrays it hands the host
     t0: float                  # host clock at its build
-    positions: int             # rows x width, for ``scheduler.note_phase``
+    #: phase -> rows x width, for ``scheduler.note_phase`` (a mixed step
+    #: has rows of both phases: its seconds are split by them)
+    positions: Dict[str, int]
     tick: int
     #: ``(slot, tenancy)`` of each lane it sampled a token for; a lane
     #: released since (finished, cancelled, evicted, expired) drops it
@@ -446,10 +468,33 @@ def _bind_params(params, param_arrays):
     return originals
 
 
+def _array(x):
+    """A Tensor's array, an array as it is."""
+    return x._data if isinstance(x, Tensor) else x
+
+
+@dataclass
+class _Rows:
+    """One group of a program's rows: ``rows`` sequences of ``width`` new
+    tokens each, with the block tables, lengths and slots they belong to."""
+    tables: Tensor
+    seq_lens: Tensor
+    start: "jax.Array"          # (rows,) position of a sequence's first row
+    lanes: Optional["jax.Array"]
+    rows: int
+    width: int
+
+    @property
+    def positions(self):
+        """(rows, width) position of every row in its sequence."""
+        return self.start[:, None] + jnp.arange(
+            self.width, dtype=self.start.dtype)[None, :]
+
+
 class _PagedCache:
-    """The one cache handle a model's ``forward_chunk`` sees for a chunk
-    of (B, T) tokens. The model's adapter declares per layer which kind of
-    state it keeps (``cache_layout``); the handle serves each kind:
+    """The one cache handle a model's ``forward_chunk`` sees for a program's
+    rows. The model's adapter declares per layer which kind of state it
+    keeps (``cache_layout``); the handle serves each kind:
 
     * ``attend(li, q, k, v)`` — paged K/V (attention layers): append the
       chunk's K/V pages and attend over the slot's block table. Cache
@@ -467,24 +512,37 @@ class _PagedCache:
       (``nn.functional.window_ring_attention``). It follows ``recur``'s
       rules: rows a lane's own sequence did not write are never seen, left
       padding and the ``seq = 0`` sentinel lanes write nothing.
-    * ``recur(li, fn)`` — per-SLOT state that does not grow with the
-      sequence (a convolution window, an SSM state): ``fn(state) -> (out,
-      new state)`` runs on the lanes' states. A lane whose chunk starts a
-      sequence (``start <= 0``) starts from zeros; a lane with no real row
-      (the ``seq = 0`` sentinel of mid-prefill and memory-stalled lanes)
-      gets its state back bit for bit. ``valid`` (B, T) marks the real
-      rows (left padding of a first chunk sits at negative positions).
+    * ``recur(li, fn, *rows)`` — per-SLOT state that does not grow with the
+      sequence (a convolution window, an SSM state): ``fn(state, *rows) ->
+      (out, new state)`` runs on the lanes' states and their rows of each
+      of ``rows``. A lane whose chunk starts a sequence (``start <= 0``)
+      starts from zeros; a lane with no real row (the ``seq = 0`` sentinel
+      of mid-prefill and memory-stalled lanes) gets its state back bit for
+      bit.
     * ``accumulate(li, delta)`` — a device-side counter carried with the
       caches (expert load), read by the host only on request.
 
     A layer may keep more than one state (attention state and an expert
     counter): ``index`` maps ``(layer, kind)`` to the state's position.
-    ``states`` is a flat list over the window rows, slot states and
-    counters; ``lanes`` (B,) maps the chunk's rows to slots (None: row i is
-    slot i, the decode batch)."""
+    ``states`` is a flat list over the latent pools, window rows, slot
+    states and counters; ``lanes`` (B,) maps the chunk's rows to slots
+    (None: row i is slot i, the decode batch).
+
+    **Rows and groups.** The program's rows are one group, (B, T): B
+    sequences of T new tokens, the layout the model's tensors have. Or
+    several (``riders``, each ``(tables, seq_lens, start, lanes, width)``: a
+    prefill chunk with the decode batch riding it): the model's tensors are
+    then ONE row (1, sum of B x T) holding each group's rows in order, what
+    is row-wise (projections, norms, MLPs, experts, the head) runs once over
+    all of them, and every method above runs group by group on that group's
+    rows, tables and lanes, over the same pools. What a model needs of a
+    row beside its values comes from the handle in the tensors' layout:
+    ``positions`` (negative: left padding, a sentinel lane), ``valid``
+    (positions >= 0) and ``head_rows(x, n)``, the last ``n`` rows of every
+    sequence, which the head is applied to."""
 
     def __init__(self, index, kcs, vcs, states, tables, seq_lens, start,
-                 lanes, width):
+                 lanes, width, riders=()):
         # (layer, kind) -> position; None: paged K/V in every layer. The
         # form of one state a layer, layer -> (kind, position), is taken too
         if index is None:
@@ -494,30 +552,75 @@ class _PagedCache:
             (at if isinstance(key, tuple) else at[1])
             for key, at in index.items()}
         self.kcs, self.vcs, self.states = kcs, vcs, list(states)
-        self.tables, self.seq_lens = Tensor(tables), Tensor(seq_lens)
-        self.start, self.lanes = start, lanes
-        self.valid = (start[:, None]
-                      + jnp.arange(width, dtype=start.dtype)[None, :]) >= 0
+        self.groups = [
+            _Rows(Tensor(tb), Tensor(sl), st, ln, tb.shape[0], w)
+            for tb, sl, st, ln, w in ((tables, seq_lens, start, lanes,
+                                       width),) + tuple(riders)]
+        self.positions = self._as_rows([g.positions for g in self.groups])
+        self.valid = self.positions >= 0
 
+    # ------------------------------------------------- rows <-> groups
+    @staticmethod
+    def _as_rows(per_group):
+        """Arrays (B, T, ...), one a group, in the tensors' layout."""
+        if len(per_group) == 1:
+            return per_group[0]
+        return jnp.concatenate(
+            [a.reshape((1, -1) + a.shape[2:]) for a in per_group], axis=1)
+
+    def _per_group(self, *xs):
+        """Per group the (B, T, ...) rows it has of each of ``xs``."""
+        if len(self.groups) == 1:
+            return [xs]
+        out, at = [], 0
+        for g in self.groups:
+            n = g.rows * g.width
+            out.append(tuple(
+                Tensor(a[0, at:at + n].reshape((g.rows, g.width)
+                                               + a.shape[2:]))
+                for a in map(_array, xs)))
+            at += n
+        return out
+
+    def _each(self, fn, *xs):
+        """``fn(group, *its rows of xs)`` a group, joined in the tensors'
+        layout."""
+        outs = [fn(g, *mine)
+                for g, mine in zip(self.groups, self._per_group(*xs))]
+        if len(outs) == 1:
+            return outs[0]
+        return Tensor(self._as_rows([_array(o) for o in outs]))
+
+    def head_rows(self, x, n: int = 1) -> Tensor:
+        """The last ``n`` rows of every sequence of ``x`` (B, T, hidden)."""
+        return self._each(lambda _g, mine: Tensor(mine._data[:, -n:, :]), x)
+
+    # ------------------------------------------------------ the states
     def attend(self, li, q, k, v, window=None):
+        if window is not None:
+            return self._each(
+                lambda g, *qkv: self._attend_window(g, li, *qkv, window),
+                q, k, v)
+        return self._each(lambda g, *qkv: self._attend_paged(g, li, *qkv),
+                          q, k, v)
+
+    def _attend_paged(self, g, li, q, k, v):
         import paddle_tpu.nn.functional as F
 
-        if window is not None:
-            return self._attend_window(li, q, k, v, window)
         kcs, vcs = self.kcs, self.vcs
         li = self.index[li, "paged_kv"]
         if isinstance(kcs[li], tuple):
             (kp, ksc), (vp, vsc) = kcs[li], vcs[li]
             out, nkp, nvp, nks, nvs = F.block_multihead_attention(
-                q, Tensor(kp), Tensor(vp), self.tables, self.seq_lens,
+                q, Tensor(kp), Tensor(vp), g.tables, g.seq_lens,
                 new_k=k, new_v=v, causal=True,
                 k_scale=Tensor(ksc), v_scale=Tensor(vsc))
             kcs[li] = (nkp._data, nks._data)
             vcs[li] = (nvp._data, nvs._data)
         else:
             out, nkc, nvc = F.block_multihead_attention(
-                q, Tensor(kcs[li]), Tensor(vcs[li]), self.tables,
-                self.seq_lens, new_k=k, new_v=v, causal=True)
+                q, Tensor(kcs[li]), Tensor(vcs[li]), g.tables,
+                g.seq_lens, new_k=k, new_v=v, causal=True)
             kcs[li] = nkc._data
             vcs[li] = nvc._data
         return out
@@ -526,32 +629,35 @@ class _PagedCache:
         import paddle_tpu.nn.functional as F
 
         at = self.index[li, "latent_kv"]
-        pool = self.states[at]          # (num_blocks, block_size, D)
-        # the pool's rows are whole lane tiles: zeros past [c | k_r], in
-        # the queries too
-        pad = pool.shape[-1] - rows.shape[-1]
-        q, rows = q._data, rows._data
-        if pad:
-            q = jnp.pad(q, ((0, 0),) * 3 + ((0, pad),))
-            rows = jnp.pad(rows, ((0, 0),) * 2 + ((0, pad),))
-        out, new = F.latent_paged_attention(
-            Tensor(q), Tensor(pool), self.tables, self.seq_lens,
-            Tensor(rows), value_dim, scale)
-        self.states[at] = new._data
-        return out
 
-    def _lane_rows(self, whole):
-        """The chunk's lanes of a per-slot state ``{name: (max_batch,
+        def attend(g, q, rows):
+            pool = self.states[at]          # (num_blocks, block_size, D)
+            # the pool's rows are whole lane tiles: zeros past [c | k_r],
+            # in the queries too
+            pad = pool.shape[-1] - rows.shape[-1]
+            q, rows = q._data, rows._data
+            if pad:
+                q = jnp.pad(q, ((0, 0),) * 3 + ((0, pad),))
+                rows = jnp.pad(rows, ((0, 0),) * 2 + ((0, pad),))
+            out, new = F.latent_paged_attention(
+                Tensor(q), Tensor(pool), g.tables, g.seq_lens,
+                Tensor(rows), value_dim, scale)
+            self.states[at] = new._data
+            return out
+
+        return self._each(attend, q, rows)
+
+    @staticmethod
+    def _lane_rows(whole, lanes):
+        """The lanes' rows of a per-slot state ``{name: (max_batch,
         ...)}``."""
-        lanes = self.lanes
         if lanes is None:
             return whole
         return {k: jnp.concatenate(
             [jax.lax.dynamic_slice_in_dim(v, lanes[b], 1)
              for b in range(lanes.shape[0])]) for k, v in whole.items()}
 
-    def _put_lane_rows(self, at, whole, new):
-        lanes = self.lanes
+    def _put_lane_rows(self, at, whole, new, lanes):
         if lanes is None:
             self.states[at] = new
             return
@@ -561,34 +667,40 @@ class _PagedCache:
                     whole[k], v[b:b + 1], lanes[b], 0)
         self.states[at] = whole
 
-    def _attend_window(self, li, q, k, v, window):
+    def _attend_window(self, g, li, q, k, v, window):
         import paddle_tpu.nn.functional as F
 
         at = self.index[li, "window_kv"]
         whole = dict(self.states[at])      # {"k", "v": (max_batch, R, ..)}
-        mine = self._lane_rows(whole)
+        mine = self._lane_rows(whole, g.lanes)
         out, nk, nv = F.window_ring_attention(
-            q, Tensor(mine["k"]), Tensor(mine["v"]), self.seq_lens, k, v,
+            q, Tensor(mine["k"]), Tensor(mine["v"]), g.seq_lens, k, v,
             window=window)
-        self._put_lane_rows(at, whole, {"k": nk._data, "v": nv._data})
+        self._put_lane_rows(at, whole, {"k": nk._data, "v": nv._data},
+                            g.lanes)
         return out
 
-    def recur(self, li, fn):
+    def recur(self, li, fn, *rows):
         at = self.index[li, "slot_state"]
-        whole = dict(self.states[at])      # {name: (max_batch, ...)}
-        mine = self._lane_rows(whole)
 
         def per_lane(flag, v):
             return flag.reshape((-1,) + (1,) * (v.ndim - 1))
 
-        fresh = self.start <= 0
-        out, new = fn({k: jnp.where(per_lane(fresh, v), jnp.zeros_like(v), v)
-                       for k, v in mine.items()})
-        idle = ~jnp.any(self.valid, axis=1)
-        new = {k: jnp.where(per_lane(idle, v), mine[k], v.astype(mine[k].dtype))
-               for k, v in new.items()}
-        self._put_lane_rows(at, whole, new)
-        return out
+        def run(g, *rows):
+            whole = dict(self.states[at])      # {name: (max_batch, ...)}
+            mine = self._lane_rows(whole, g.lanes)
+            fresh = g.start <= 0
+            out, new = fn({k: jnp.where(per_lane(fresh, v),
+                                        jnp.zeros_like(v), v)
+                           for k, v in mine.items()}, *rows)
+            idle = ~jnp.any(g.positions >= 0, axis=1)
+            new = {k: jnp.where(per_lane(idle, v), mine[k],
+                                v.astype(mine[k].dtype))
+                   for k, v in new.items()}
+            self._put_lane_rows(at, whole, new, g.lanes)
+            return out
+
+        return self._each(run, *rows)
 
     def accumulate(self, li, delta):
         at = self.index[li, "accumulator"]
@@ -657,10 +769,44 @@ def _paged_forward(arch, params, param_arrays, kcs, vcs, tokens, seq_lens,
         start = seq_lens - T
         cache = _PagedCache(index, kcs, vcs, states, tables, seq_lens,
                             start, lanes, T)
-        logits = arch.forward_chunk(tokens, start, cache)
+        logits = arch.forward_chunk(tokens, cache)
         nxt = _sample_tokens(logits._data[:, -1, :], temps, top_ps,
                              base_key, rids, ngens, sampling)
         return nxt.astype(jnp.int32), kcs, vcs, cache.states
+    finally:
+        for p, o in zip(params, originals):
+            p._data = o
+
+
+def _paged_mixed(arch, params, param_arrays, kcs, vcs, tokens, seq_lens,
+                 tables, temps, top_ps, rids, ngens, base_key, states, lanes,
+                 chunk, sampling: bool = False, index=None):
+    """A prefill chunk with the decode step aboard: ``chunk`` is ONE slot's
+    ``(tokens (1, W), seq_lens, tables, temps, top_ps, rids, ngens)`` as
+    ``_paged_forward`` takes them, ``lanes`` (1,) that slot, the other rows
+    the decode batch's (B, 1). One pass over the layers: what is row-wise
+    runs once over the W + B rows, so a weight leaves HBM once, and every
+    mixer runs on each group's rows through the path it has (the cache
+    handle's groups). Returns ``_paged_forward``'s outputs: (B,) next-token
+    ids, the chunk's in its own slot's lane (which rides the decode group
+    as a sentinel lane; the token means something on a slot's final
+    chunk), and the new caches."""
+    originals = _bind_params(params, param_arrays)
+    try:
+        c_tokens, c_seq, c_tables, c_temps, c_top_ps, c_rids, c_ngens = chunk
+        B, W = tokens.shape[0], c_tokens.shape[1]
+        cache = _PagedCache(
+            index, kcs, vcs, states, c_tables, c_seq, c_seq - W, lanes, W,
+            riders=((tables, seq_lens, seq_lens - 1, None, 1),))
+        logits = arch.forward_chunk(
+            jnp.concatenate([c_tokens, tokens.reshape(1, B)], axis=1), cache)
+        # logits (1, 1 + B, V): the chunk's last row, then the lanes'
+        nxt = _sample_tokens(
+            logits._data[0], jnp.concatenate([c_temps, temps]),
+            jnp.concatenate([c_top_ps, top_ps]), base_key,
+            jnp.concatenate([c_rids, rids]),
+            jnp.concatenate([c_ngens, ngens]), sampling).astype(jnp.int32)
+        return nxt[1:].at[lanes[0]].set(nxt[0]), kcs, vcs, cache.states
     finally:
         for p, o in zip(params, originals):
             p._data = o
@@ -685,7 +831,7 @@ def _paged_verify(arch, params, param_arrays, kcs, vcs, tokens, seq_lens,
         start = seq_lens - T
         cache = _PagedCache(index, kcs, vcs, states, tables, seq_lens,
                             start, None, T)
-        logits = arch.forward_chunk(tokens, start, cache, logits_t=T)
+        logits = arch.forward_chunk(tokens, cache, logits_t=T)
         lg = logits._data                      # (B, T, V)
         greedy = jnp.argmax(lg, axis=-1).astype(jnp.int32)
         first = _sample_tokens(lg[:, 0, :], temps, top_ps,
@@ -891,6 +1037,10 @@ class PagedEngine:
         #: dense scorer keeps no state between ticks: both read at once)
         self._overlap = not self._dense and self._spec is None
         self._launches = self._launches_overlapped = 0
+        self._steps = self._steps_mixed = 0
+        #: ticks that held a chunk and a step to launch together (every
+        #: ``share_window_ticks``-th of them launches the two apart)
+        self._mixable = 0
         self._reads = self._reads_late = 0
         self.queue: List[Request] = []
         self.rejected: Dict[int, str] = {}
@@ -919,7 +1069,8 @@ class PagedEngine:
             (``jit_<kind>``): the chunk forward is wrapped twice, as
             ``paged_prefill_chunk`` and ``paged_decode_step``, so the two
             phases are told apart there (their shapes differ already, so
-            nothing compiles twice)."""
+            nothing compiles twice); a decode step with a chunk aboard is
+            ``paged_mixed_step``."""
             fn = cache.get((arch_key, kind))
             if fn is None:
                 bound = functools.partial(forward, self.arch,
@@ -942,7 +1093,8 @@ class PagedEngine:
                 "prefill": program("paged_prefill_chunk", _paged_forward,
                                    **paged),
                 "decode": program("paged_decode_step", _paged_forward,
-                                  **paged)}
+                                  **paged),
+                "mixed": program("paged_mixed_step", _paged_mixed, **paged)}
             self._vfn = program("paged_verify", _paged_verify, **paged)
         self._base_key = jax.random.key(seed)
         self._done: List[Request] = []
@@ -1260,18 +1412,27 @@ class PagedEngine:
         backend, where the device array may alias the numpy buffer, the
         program itself) can still be reading it, and the next tick's
         admission mutates ``tables`` in place."""
+        return ([p._data for p in self._params], self.kc, self.vc,
+                *self._row_args(tokens_np, seq_lens_np, tables_np, temps_np,
+                                top_ps_np, rids_np, ngens_np),
+                self._base_key, self.state)
+
+    @staticmethod
+    def _row_args(tokens_np, seq_lens_np, tables_np, temps_np, top_ps_np,
+                  rids_np, ngens_np):
+        """One group's rows on the device (snapshots, as ``_chunk_args``
+        says)."""
         def snap(a, dtype):
             return a if isinstance(a, jax.Array) else jnp.asarray(
                 np.array(a, dtype))
 
-        return ([p._data for p in self._params], self.kc, self.vc,
-                snap(tokens_np, np.int32), snap(seq_lens_np, np.int32),
+        return (snap(tokens_np, np.int32), snap(seq_lens_np, np.int32),
                 snap(tables_np, np.int32), snap(temps_np, np.float32),
                 snap(top_ps_np, np.float32), snap(rids_np, np.int32),
-                snap(ngens_np, np.int32), self._base_key, self.state)
+                snap(ngens_np, np.int32))
 
     def _call_program(self, rec: _Launched, fn, host_args, extra=(),
-                      **span_args):
+                      aboard=None, **span_args):
         """Launch one program under its boundary spans and read the one
         launched before it: ``serving.<phase>`` over ``.build`` (eval mode
         on, the host arrays to the device), ``.launch`` (the compiled call;
@@ -1279,9 +1440,12 @@ class PagedEngine:
         and ``.wait`` (the blocking read of the oldest unread program's
         outputs: the program launched before this one, or this one on an
         engine that does not overlap), then ``serving.emit`` for what was
-        read. ``rec`` stays behind as the unread program."""
+        read. ``rec`` stays behind as the unread program. ``aboard``: the
+        host rows of the chunk a mixed step carries, after ``extra``."""
         phase = rec.phase
         tokens, seq_lens_np, _tables_np, temps_np, *_ = host_args
+        sampling = any(np.any(np.asarray(rows[3]) > 0)
+                       for rows in (host_args, aboard) if rows is not None)
         with contextlib.ExitStack() as restore, _trace.boundary(
                 f"serving.{phase}",
                 args=dict(span_args, phase=phase,
@@ -1298,18 +1462,24 @@ class PagedEngine:
                 rec.t0 = time.perf_counter()
                 args = self._chunk_args(*host_args) + tuple(
                     jnp.asarray(np.array(a, np.int32)) for a in extra)
+                if aboard is not None:
+                    args += (self._row_args(*aboard),)
             with _trace.boundary(f"serving.{phase}.launch"), \
                     _attention_paths() as lowered:
                 *rec.outs, self.kc, self.vc, self.state = fn(
-                    *args,
-                    sampling=bool(np.any(np.asarray(temps_np) > 0)))
+                    *args, sampling=bool(sampling))
                 restore.close()
             if lowered:     # this call traced the program
                 self._attention_lowered[self._program_key(
-                    phase, tokens.shape)] = "+".join(sorted(set(lowered)))
+                    "mixed" if aboard is not None else phase,
+                    tokens.shape)] = "+".join(sorted(set(lowered)))
             due, self._unread = self._unread, rec
             self._launches += 1
-            _res.M_LAUNCHES.inc(overlapped=str(due is not None).lower())
+            if phase == "decode":
+                self._steps += 1
+                self._steps_mixed += aboard is not None
+            _res.M_LAUNCHES.inc(overlapped=str(due is not None).lower(),
+                                kind=rec.kind)
             if due is not None:
                 self._launches_overlapped += 1
             elif not self._overlap:
@@ -1335,8 +1505,10 @@ class PagedEngine:
         self._reads += 1
         self._reads_late += late
         _res.M_READS.inc(host_late=str(late).lower())
-        self.scheduler.note_phase(rec.phase, rec.positions,
-                                  time.perf_counter() - rec.t0)
+        seconds = time.perf_counter() - rec.t0
+        rows = sum(rec.positions.values())
+        for phase, n in rec.positions.items():
+            self.scheduler.note_phase(phase, n, seconds * n / rows)
         return outs
 
     def _settle(self):
@@ -1361,17 +1533,20 @@ class PagedEngine:
         return self.slots[slot] if self._tenancy[slot] == tenancy else None
 
     def _run_chunk(self, rec: _Launched, tokens, seq_lens_np, tables_np,
-                   temps_np, top_ps_np, rids_np, ngens_np, lanes=None):
+                   temps_np, top_ps_np, rids_np, ngens_np, lanes=None,
+                   aboard=None):
         """``lanes``: the slots the chunk's rows belong to, for the layers
         that keep state per slot (a prefill chunk carries one slot's rows;
         the decode batch's row i is slot i and passes none). An engine
-        with no such layer never sends them."""
-        extra = (lanes,) if lanes is not None and self._has_slot_state \
-            else ()
+        with no such layer never sends them. ``aboard``: the host rows of
+        the chunk a decode step carries (``lanes`` is then that chunk's
+        slot, and always sent: its token comes back in that lane)."""
+        extra = (lanes,) if lanes is not None and (
+            self._has_slot_state or aboard is not None) else ()
         self._call_program(
-            rec, self._fns[rec.phase],
+            rec, self._fns[rec.kind],
             (tokens, seq_lens_np, tables_np, temps_np, top_ps_np,
-             rids_np, ngens_np), extra=extra)
+             rids_np, ngens_np), extra=extra, aboard=aboard)
 
     def _run_verify(self, rec: _Launched, tokens_np, seq_lens_np, tables_np,
                     temps_np, top_ps_np, rids_np, ngens_np, max_accept_np):
@@ -1386,7 +1561,7 @@ class PagedEngine:
     def _record(self, phase: str, shape, kind: Optional[str] = None,
                 **kw) -> _Launched:
         return _Launched(phase=phase, kind=kind or phase, outs=[], t0=0.0,
-                         positions=int(shape[0]) * int(shape[1]),
+                         positions={phase: int(shape[0]) * int(shape[1])},
                          tick=self._ticks, **kw)
 
     def _emit(self, rec: _Launched, outs):
@@ -1398,7 +1573,7 @@ class PagedEngine:
                 self._emit_verified(rec.meta["active"], rec.meta["seq"],
                                     *outs, rec.meta["max_accept"])
                 return
-            if rec.kind == "prefill":
+            if "chunk" in rec.meta:         # a chunk, alone or aboard
                 self._rt_event(rec.meta["rid"], "prefill_chunk", t=now,
                                tick=rec.tick, **rec.meta["chunk"])
             (toks,) = outs
@@ -1407,12 +1582,12 @@ class PagedEngine:
                 if req is None:
                     continue
                 # a prefill chunk carries its slot's one row, the decode
-                # step row i for slot i
+                # step (a chunk aboard or not) row i for slot i
                 tok = int(toks[0 if rec.kind == "prefill" else slot])
                 req.generated.append(tok)
                 self.last_token[slot] = tok
                 self._inflight[slot] -= 1
-                if rec.kind == "decode":
+                if rec.phase == "decode" and slot != rec.meta.get("first"):
                     self._rt_event(req.rid, "decode_tick", t=now,
                                    tick=rec.tick, new_tokens=1)
                 self._record_token(req, now)
@@ -1494,20 +1669,28 @@ class PagedEngine:
         ``prefill_width`` tokens of ONE prefilling slot, the one admitted
         first, and only that slot's rows and block table. The final chunk
         of a slot yields its first sampled token (read with the next
-        launch; the slot joins this tick's decode step with the token
-        still on the device); chunks past the budget defer to later ticks
-        so the decode step below never waits out a long prompt."""
+        launch); chunks past the budget defer to later ticks so the decode
+        step below never waits out a long prompt.
+
+        Returns the tick's LAST chunk, booked and not launched (``(record,
+        host rows, slot)``), where the engine reads its programs a tick
+        late: ``_decode_active`` sends it out, with the decode step aboard
+        if one goes out (apart, each streams every weight). None: no chunk
+        this tick, or every chunk launched."""
         width = self.prefill_width
         quota = self.scheduler.token_quota(self.block_size)
         # whole programs the tick's tokens pay for (a budget wider than
         # the engine's widest chunk buys several)
         programs = (float("inf") if quota is None
                     else max(1, quota // width))
+        last = None
         while self._prefilling:
             with _trace.boundary("serving.plan"):
                 plan = self._plan_prefill_chunk(programs)
             if plan is None:
-                return
+                break
+            if last is not None:        # another chunk follows it
+                self._launch_chunk(last)
             slot, chunk, final, rows = plan
             st = self._prefilling[slot]
             req = self.slots[slot]
@@ -1530,7 +1713,17 @@ class PagedEngine:
                 self.seq_lens[slot] = len(req.prompt) + len(req.generated)
                 self._inflight[slot] += 1
                 rec.lanes.append((slot, int(self._tenancy[slot])))
-            self._run_chunk(rec, *rows, lanes=np.asarray([slot], np.int32))
+                rec.meta["first"] = slot
+            last = (rec, rows, slot)
+        if last is not None and not self._overlap:
+            self._launch_chunk(last)
+            last = None
+        return last
+
+    def _launch_chunk(self, chunk):
+        """A booked prefill chunk (``_prefill_step``) as its own program."""
+        rec, rows, slot = chunk
+        self._run_chunk(rec, *rows, lanes=np.asarray([slot], np.int32))
 
     def _plan_prefill_chunk(self, programs):
         """The next chunk call: ``(slot, chunk index, is the slot's last
@@ -1809,8 +2002,7 @@ class PagedEngine:
         # phase split: bounded prefill, then decode — decode runs EVERY
         # tick there is decodable work, however much prefill is pending
         launched = self._launches
-        self._prefill_step()
-        self._decode_active()
+        self._decode_active(self._prefill_step())
         if self._launches == launched:
             # nothing to launch (the last tokens are in flight, or memory
             # stalls every lane): read what is unread
@@ -1860,14 +2052,23 @@ class PagedEngine:
         return [i for i, s in enumerate(self.slots)
                 if s is not None and i not in self._prefilling]
 
-    def _decode_active(self):
+    def _decode_active(self, chunk=None):
+        """The tick's decode step over the lanes that decode. ``chunk``:
+        the tick's last prefill chunk, still to launch
+        (``_prefill_step``)."""
         active = self._decode_lanes()
+        if chunk is not None and all(
+                i == chunk[0].meta.get("first") for i in active):
+            # no lane decodes but the one this chunk finishes: the chunk
+            # goes alone, and the slot it finishes decodes after it
+            self._launch_chunk(chunk)
+            chunk = None
         if not active:
             return
         if self._spec is not None and self._spec_feasible(active):
             self._decode_speculative(active)
             return
-        self._decode_plain(active)
+        self._decode_plain(active, chunk)
 
     def _spec_feasible(self, active: List[int]) -> bool:
         """Speculate this tick only when every active slot has table
@@ -1881,16 +2082,39 @@ class PagedEngine:
         return all(self.slots[i].seq_len + self._spec_k <= cap
                    for i in active)
 
-    def _decode_plain(self, active: List[int]):
+    def _decode_plain(self, active: List[int], chunk=None):
+        """One decode step over ``active``. With ``chunk`` (the tick's last
+        prefill chunk, still to launch) and a lane to feed, chunk and step
+        go out as ONE program, ``paged_mixed_step``: the weights are
+        streamed once for both. The slot a final chunk finishes takes its
+        first token from that program, so it is not fed by it and joins the
+        next tick's step. With no lane to feed the chunk goes alone, and
+        the lanes are planned as they were: after it. So does one such
+        tick in every ``share_window_ticks``: the window behind the
+        phase-share gauge, and a device trace of a few seconds, then hold
+        a chunk and a step that ran alone, the one place their own device
+        time can be read where every step would ride a chunk."""
+        if chunk is not None:
+            self._mixable += 1
+            if not self._mixable % self.scheduler.config.share_window_ticks:
+                self._launch_chunk(chunk)
+                chunk, active = None, self._decode_lanes()
         while True:
+            # the slot whose first token the step's own program samples
+            aboard = None if chunk is None else chunk[0].meta.get("first")
             with _trace.boundary("serving.plan"):
-                plan = self._plan_decode(active)
+                plan = self._plan_decode(active, aboard)
+            fed, skipped = plan[-2:] if plan is not None else ((), ())
+            # every lane that would be fed is memory-stalled
+            stalled = bool(skipped) and len(skipped) >= len(fed)
+            if chunk is not None and (plan is None or stalled):
+                self._launch_chunk(chunk)
+                chunk, active = None, self._decode_lanes()
+                continue
             if plan is None:
                 return
-            tokens, seq, temps, top_ps, rids, ngens, fed, skipped = plan
-            if not skipped or len(skipped) < len(fed):
+            if not stalled:
                 break
-            # every lane that would be fed is memory-stalled
             if self._unread is None:
                 # nobody can finish to free blocks, so this would
                 # livelock. Preempt the slot with the most deadline slack
@@ -1902,6 +2126,7 @@ class PagedEngine:
             # read it, then plan on what the host knows now
             self._settle()
             active = self._decode_lanes()
+        tokens, seq, temps, top_ps, rids, ngens = plan[:-2]
         rec = self._record(
             "decode", tokens.shape,
             lanes=[(i, int(self._tenancy[i])) for i in fed
@@ -1910,16 +2135,28 @@ class PagedEngine:
             self._inflight[i] += 1
             self.seq_lens[i] = int(seq[i])   # cached positions, launched
         self._tick_work["decode_slots"] += len(rec.lanes)
-        self._run_chunk(rec, tokens, seq, self.tables, temps, top_ps, rids,
-                        ngens)
+        rows = (tokens, seq, self.tables, temps, top_ps, rids, ngens)
+        if chunk is None:
+            self._run_chunk(rec, *rows)
+            return
+        # the step takes the chunk's record aboard: its rows, the lane of
+        # the slot it finishes, the chunk event its read reports
+        crec, chunk_rows, slot = chunk
+        rec.kind = "mixed"
+        rec.positions.update(crec.positions)
+        rec.meta = crec.meta
+        rec.lanes += crec.lanes
+        self._run_chunk(rec, *rows, lanes=np.asarray([slot], np.int32),
+                        aboard=chunk_rows)
 
-    def _plan_decode(self, active: List[int]):
+    def _plan_decode(self, active: List[int], aboard: Optional[int] = None):
         """The decode call's rows ``(tokens, seq, temps, top_ps, rids,
         ngens, fed, skipped)``, counted on the tokens read plus the tokens
         in flight: ``fed`` are the active lanes that still have a token to
         sample (a lane whose last token by count is in flight is not fed
-        again), ``skipped`` those of them that found no KV block. None
-        when no lane is fed."""
+        again, nor is ``aboard``, the slot whose final chunk this step
+        carries: its first token is this program's to sample), ``skipped``
+        those of them that found no KV block. None when no lane is fed."""
         seq = self.seq_lens.copy()
         for i in self._prefilling:
             seq[i] = 0               # masked lane: no write, no attend
@@ -1931,8 +2168,9 @@ class PagedEngine:
         for i in active:
             req = self.slots[i]
             pending = int(self._inflight[i])
-            if len(req.generated) + pending >= req.max_new_tokens:
-                seq[i] = 0           # its last token is in flight
+            if (i == aboard
+                    or len(req.generated) + pending >= req.max_new_tokens):
+                seq[i] = 0           # its newest token is in flight
                 continue
             fed.append(i)
             temps[i] = req.temperature
@@ -2221,7 +2459,9 @@ class PagedEngine:
         """Compile the steady-state programs (the one (1, prefill_width)
         prefill chunk + the batched decode step, and the hand-over of the
         fed token on the device after a chunk and after a step: hence
-        three tokens) before real traffic: STARTING→WARMING→READY.
+        three tokens; where a decode step can take a chunk aboard, that
+        program too: a second synthetic request is admitted while the first
+        decodes) before real traffic: STARTING→WARMING→READY.
         Idempotent on a READY replica. Nothing is left unread.
 
         Traffic that arrived before READY (admission is open from
@@ -2232,32 +2472,47 @@ class PagedEngine:
             return self
         self.lifecycle.to(ReplicaState.WARMING, "warmup")
         n = prompt_len if prompt_len is not None else self.block_size
-        rid = self.add_request([1] * max(1, n),
-                               max_new_tokens=max_new_tokens)
-        # the synthetic request is operator work: no SLO deadlines
-        # (expiring it mid-compile would block READY), and it jumps to
-        # the queue head so a pre-READY client burst can neither starve
-        # nor shed it
-        for i, req in enumerate(self.queue):
-            if req.rid == rid:
-                req.ttft_deadline_s = req.deadline_s = None
-                self.queue.insert(0, self.queue.pop(i))
-                break
-        while self.outcomes.get(rid) is None and self.has_work():
+
+        def synthetic(new_tokens):
+            rid = self.add_request([1] * max(1, n),
+                                   max_new_tokens=new_tokens)
+            # the synthetic request is operator work: no SLO deadlines
+            # (expiring it mid-compile would block READY), and it jumps to
+            # the queue head so a pre-READY client burst can neither starve
+            # nor shed it
+            for i, req in enumerate(self.queue):
+                if req.rid == rid:
+                    req.ttft_deadline_s = req.deadline_s = None
+                    self.queue.insert(0, self.queue.pop(i))
+                    break
+            return rid
+
+        rids = [synthetic(max_new_tokens)]
+        # a second one once the first decodes: its chunk rides that step
+        rides = self._overlap and self.max_batch > 1 and max_new_tokens > 1
+        while (any(self.outcomes.get(r) is None for r in rids)
+               and self.has_work()):
             res = self.step()
-            res.pop(rid, None)          # warmup is not traffic
+            if rides and any(s is not None and s.rid == rids[0]
+                             and i not in self._prefilling
+                             for i, s in enumerate(self.slots)):
+                rids.append(synthetic(1))
+                rides = False
+            for rid in rids:
+                res.pop(rid, None)      # warmup is not traffic
             self._spillover.update(res)
         self._flush()       # client traffic's program, if one is unread
         self._spillover = self._drain_done()
-        self._spillover.pop(rid, None)
-        oc = self.outcomes.pop(rid, None)
-        if oc is None or oc.status != RequestStatus.FINISHED:
-            # stay in WARMING (still admits): READY would advertise a
-            # replica whose steady-state programs never compiled
-            raise RuntimeError(
-                f"warmup request ended "
-                f"{oc.status if oc else '<missing>'}: "
-                f"{oc.detail if oc else ''}")
+        for rid in rids:
+            self._spillover.pop(rid, None)
+            oc = self.outcomes.pop(rid, None)
+            if oc is None or oc.status != RequestStatus.FINISHED:
+                # stay in WARMING (still admits): READY would advertise a
+                # replica whose steady-state programs never compiled
+                raise RuntimeError(
+                    f"warmup request ended "
+                    f"{oc.status if oc else '<missing>'}: "
+                    f"{oc.detail if oc else ''}")
         self.lifecycle.to(ReplicaState.READY, "warmup complete")
         return self
 
@@ -2335,7 +2590,11 @@ class PagedEngine:
              "live": lc.live(),
              "queue_depth": len(self.queue),
              "active": self.num_active,
-             "prefilling": len(self._prefilling),
+             # slots part-way through a prompt, the one whose final
+             # chunk is launched and not read among them
+             "prefilling": len(self._prefilling) + (
+                 self._unread is not None
+                 and "first" in self._unread.meta),
              "kv_blocks_free": self.bm.available,
              "kv_blocks_total": self._total_usable,
              "kv_dtype": str(self.kv_dtype),
@@ -2353,6 +2612,11 @@ class PagedEngine:
                                if self._launches else None),
              "host_late_share": (self._reads_late / self._reads
                                  if self._reads else None),
+             # decode steps launched with the tick's last prefill chunk
+             # aboard (one program, one stream of the weights) over decode
+             # steps launched
+             "mixed_share": (self._steps_mixed / self._steps
+                             if self._steps else None),
              "phase_share": self.scheduler.phase_share(),
              "prefill_fill": self.scheduler.prefill_fill(),
              # the probe path doubles as the burn-rate decay poll: an

@@ -240,7 +240,7 @@ class _DeepseekV3Paged:
         return [(pages, counter) if cfg.sparse(li) else pages
                 for li in range(cfg.num_hidden_layers)]
 
-    def forward_chunk(self, tokens, start, cache, logits_t: int = 1):
+    def forward_chunk(self, tokens, cache, logits_t: int = 1):
         model = self.model
         valid = Tensor(cache.valid)
         with jax.named_scope("embed"):
@@ -248,7 +248,7 @@ class _DeepseekV3Paged:
         for li, blk in enumerate(model.model.layers):
             with jax.named_scope("attn.mla"):
                 x = x + blk.self_attn.attend_cached(
-                    blk.input_layernorm(x), start, cache, li)
+                    blk.input_layernorm(x), cache, li)
             with jax.named_scope(blk.mlp_scope):
                 u = blk.post_attention_layernorm(x)
                 if blk.sparse:
@@ -258,6 +258,6 @@ class _DeepseekV3Paged:
                     out = blk.mlp(u)
                 x = x + out
         x = model.model.norm(x)
-        last = Tensor(x._data[:, -logits_t:, :])
+        last = cache.head_rows(x, logits_t)
         with jax.named_scope("lm_head"):
             return model.lm_head(last)

@@ -164,8 +164,9 @@ class ExaoneMoeAttention(nn.Layer):
         self.k_norm = nn.RMSNorm(cfg.head_dim, epsilon=cfg.rms_norm_eps)
 
     def qkv(self, u, start=0):
-        """``q, k`` normed (and rotated from position ``start``: an int, or
-        (B,) per-sequence offsets) and ``v``, each (B, T, heads, D)."""
+        """``q, k`` normed (and rotated from position ``start``: an int,
+        (B,) per-sequence offsets, or the rows' (B, T) positions) and
+        ``v``, each (B, T, heads, D)."""
         cfg = self.cfg
         b, t = u.shape[0], u.shape[1]
         nh, nkv, hd = (cfg.num_attention_heads, cfg.num_key_value_heads,
@@ -316,7 +317,7 @@ class _ExaoneMoePaged:
             out.append((attn, counter) if mlp_kind == "sparse" else attn)
         return out
 
-    def forward_chunk(self, tokens, start, cache, logits_t: int = 1):
+    def forward_chunk(self, tokens, cache, logits_t: int = 1):
         model, cfg = self.model, self.cfg
         bsz, t = tokens.shape
         nh, hd = cfg.num_attention_heads, cfg.head_dim
@@ -325,7 +326,8 @@ class _ExaoneMoePaged:
             x = model.model.embed_tokens(Tensor(tokens))
         for li, blk in enumerate(model.model.layers):
             with jax.named_scope(blk.attn_scope):
-                q, k, v = blk.self_attn.qkv(blk.input_layernorm(x), start)
+                q, k, v = blk.self_attn.qkv(blk.input_layernorm(x),
+                                            cache.positions)
                 window = cfg.sliding_window if blk.kind == SLIDING else None
                 out = cache.attend(li, q, k, v, window=window)
                 x = x + blk.self_attn.o_proj(
@@ -339,6 +341,6 @@ class _ExaoneMoePaged:
                     out = blk.mlp(u)
                 x = x + out
         x = model.model.norm(x)
-        last = Tensor(x._data[:, -logits_t:, :])
+        last = cache.head_rows(x, logits_t)
         with jax.named_scope("lm_head"):
             return model.lm_head(last)

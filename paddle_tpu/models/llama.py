@@ -96,10 +96,13 @@ def rope_rotate(a, theta, pos_offset):
     freqs = 1.0 / (theta ** (jnp.arange(0, half, dtype=jnp.float32)
                              / half))
     off = jnp.asarray(pos_offset, jnp.float32)
-    if off.ndim == 0:
-        off = off[None]                        # (1,) broadcast over B
-    positions = (off[:, None]
-                 + jnp.arange(s, dtype=jnp.float32)[None, :])
+    if off.ndim == 2:                          # (B, S): every row's own
+        positions = off
+    else:
+        if off.ndim == 0:
+            off = off[None]                    # (1,) broadcast over B
+        positions = (off[:, None]
+                     + jnp.arange(s, dtype=jnp.float32)[None, :])
     pos = positions[:, :, None] * freqs[None, None, :]
     cos = jnp.cos(pos)[:, :, None, :]          # (B|1, S, 1, half)
     sin = jnp.sin(pos)[:, :, None, :]
@@ -113,8 +116,10 @@ def rotary_embedding(x, theta: float = 10000.0, pos_offset=0):
     """Apply RoPE to [B, S, H, D] (reference fused_rope op). Pairs are the
     (even, odd) channel convention. ``pos_offset`` may be a python int, a
     traced scalar (cached decoding compiles one step for every position),
-    or a per-batch ``(B,)`` vector (continuous-batching serving: every
-    sequence in the batch sits at a different length)."""
+    a per-batch ``(B,)`` vector (continuous-batching serving: every
+    sequence in the batch sits at a different length), or the ``(B, S)``
+    positions themselves (serving: the rows of one program need not be
+    consecutive)."""
     def f(a):
         return rope_rotate(a, theta, pos_offset)
     # static (python-int) offsets ride the IR record as semantic attrs

@@ -178,14 +178,32 @@ class Mamba2Mixer(nn.Layer):
         an invalid row feeds the convolution zeros and leaves the SSM
         state as it was (left padding of a first chunk). Returns the
         mixer's output, and the new ``(window, ssm)`` when ``state`` is
-        given."""
+        given. It is ``project`` (row-wise), ``scan`` (along each
+        sequence), ``finish`` (row-wise): a caller whose rows are not all
+        one batch of sequences (serving, a chunk with the decode batch
+        aboard) runs the three itself, ``scan`` once a group of rows."""
+        z, xbc, dt = self.project(u)
+        y, new = self.scan(xbc, dt, state, valid)
+        out = self.finish(y, z)
+        return out if state is None else (out, new)
+
+    def project(self, u):
+        """``z`` (the gate), ``xBC`` (the convolution's input) and ``dt``
+        (float32, after its softplus) of every row of ``u``."""
         cfg = self.cfg
-        bsz, t = u.shape[0], u.shape[1]
+        z, xbc, dt = ops.split(
+            self.in_proj(u),
+            [cfg.mamba_inner, cfg.conv_dim, cfg.mamba_num_heads], axis=-1)
+        return z, xbc, F.softplus(dt.astype("float32") + self.dt_bias)
+
+    def scan(self, xbc, dt, state=None, valid=None):
+        """Convolution and SSD recurrence along the sequences of ``xbc``
+        (B, T, conv_dim) / ``dt`` (B, T, H) from ``state``: ``y`` (B, T,
+        inner) and the new ``(window, ssm)``."""
+        cfg = self.cfg
+        bsz, t = xbc.shape[0], xbc.shape[1]
         d_in, h, p = cfg.mamba_inner, cfg.mamba_num_heads, cfg.mamba_head_dim
         g, n = cfg.n_groups, cfg.ssm_state_size
-        z, xbc, dt = ops.split(self.in_proj(u), [d_in, cfg.conv_dim, h],
-                               axis=-1)
-        dt = F.softplus(dt.astype("float32") + self.dt_bias)
         if valid is not None:
             xbc = xbc * valid.astype(xbc.dtype).unsqueeze(-1)
             dt = dt * valid.astype("float32").unsqueeze(-1)
@@ -203,11 +221,14 @@ class Mamba2Mixer(nn.Layer):
                 x.reshape([bsz, t, h, p]), dt, a,
                 b.reshape([bsz, t, g, n]), c.reshape([bsz, t, g, n]),
                 self.D, ssm, chunk_size=cfg.chunk_size)
+        return y.reshape([bsz, t, d_in]), (window, ssm)
+
+    def finish(self, y, z):
+        """Gated group norm and ``W_out`` of every row."""
         y = F.gated_group_rms_norm(
-            y.reshape([bsz, t, d_in]), z, self.norm_weight, groups=g,
-            epsilon=cfg.layer_norm_epsilon)
-        out = self.out_proj(y)
-        return out if state is None else (out, (window, ssm))
+            y, z, self.norm_weight, groups=self.cfg.n_groups,
+            epsilon=self.cfg.layer_norm_epsilon)
+        return self.out_proj(y)
 
 
 class NemotronHAttention(nn.Layer):
@@ -344,7 +365,7 @@ class _NemotronHPaged:
             "E": ("accumulator", (held + 2,), jnp.int32)}
         return [kinds[k] for k in cfg.hybrid_override_pattern]
 
-    def forward_chunk(self, tokens, start, cache, logits_t: int = 1):
+    def forward_chunk(self, tokens, cache, logits_t: int = 1):
         model = self.model
         bsz, t = tokens.shape
         nh, hd = self.cfg.num_attention_heads, self.cfg.head_dim
@@ -359,17 +380,22 @@ class _NemotronHPaged:
                     out = blk.mixer.o_proj(ops.reshape(
                         cache.attend(li, q, k, v), [bsz, t, nh * hd]))
                 elif blk.kind == "M":
-                    def run(state, mixer=blk.mixer, u=u):
-                        out, (window, ssm) = mixer(
-                            u, state=(Tensor(state["conv"]),
-                                      Tensor(state["ssm"])), valid=valid)
-                        return out, {"conv": window._data, "ssm": ssm._data}
-                    out = cache.recur(li, run)
+                    # the weights meet every row once; the recurrence runs
+                    # along each group's own sequences
+                    z, xbc, dt = blk.mixer.project(u)
+
+                    def run(state, xbc, dt, valid, mixer=blk.mixer):
+                        y, (window, ssm) = mixer.scan(
+                            xbc, dt, (Tensor(state["conv"]),
+                                      Tensor(state["ssm"])), valid)
+                        return y, {"conv": window._data, "ssm": ssm._data}
+                    out = blk.mixer.finish(
+                        cache.recur(li, run, xbc, dt, valid), z)
                 else:
                     out, load = blk.mixer(u, valid=valid, with_load=True)
                     cache.accumulate(li, load._data)
                 x = x + out
         x = model.model.norm_f(x)
-        last = Tensor(x._data[:, -logits_t:, :])
+        last = cache.head_rows(x, logits_t)
         with jax.named_scope("lm_head"):
             return model.lm_head(last)

@@ -57,7 +57,7 @@ def _linear(in_f, out_f, std):
 
 def _rotate(x, theta, start, interleaved):
     """Rotary embedding of ``x`` (B, T, heads, D) from position ``start``
-    (an int or (B,) offsets)."""
+    (an int, (B,) offsets, or the rows' (B, T) positions)."""
     from ...models.llama import rotary_embedding
     if interleaved:
         b, t, h, d = x.shape
@@ -182,14 +182,15 @@ class MultiHeadLatentAttention(Layer):
                            precision=exact)
         return self.unabsorb(Tensor(o_lat.astype(rows.dtype)))
 
-    def attend_cached(self, u, start, cache, li):
-        """The serving path: write the chunk's rows ``[c | k_r]`` into layer
-        ``li``'s latent pages of ``cache`` and attend over them absorbed.
+    def attend_cached(self, u, cache, li):
+        """The serving path: write the rows ``[c | k_r]`` of ``u`` (at
+        ``cache.positions``) into layer ``li``'s latent pages of ``cache``
+        and attend over them absorbed.
         Scopes: ``attn.mla.proj`` around the products with weights,
         ``attn.mla.core`` around scores, softmax and the weighted sum over
         cached rows."""
         with jax.named_scope("attn.mla.proj"):
-            q_nope, q_rope, c, k_r = self.latent(u, start)
+            q_nope, q_rope, c, k_r = self.latent(u, cache.positions)
             q = self.absorbed_queries(q_nope, q_rope)
             rows = ops.concat([c, k_r], axis=-1)
         with jax.named_scope("attn.mla.core"):

@@ -82,7 +82,10 @@ class SchedulerConfig:
         prefill work is pending — a budget can interleave, never
         livelock.
     ``share_window_ticks``
-        Ticks in the sliding window behind the phase-share gauge.
+        Ticks in the sliding window behind the phase-share gauge. Of
+        the engine's ticks that could launch a chunk and a decode step as
+        one program, one in this many launches them apart, so that the
+        window (and a device trace as long) holds each program alone.
     """
 
     prefill_token_budget: Optional[int] = None

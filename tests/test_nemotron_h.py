@@ -432,8 +432,8 @@ def test_a_memory_stalled_lane_keeps_its_state(monkeypatch):
     stalls = []
     plan = eng._plan_decode
 
-    def watching(active):
-        got = plan(active)
+    def watching(active, *aboard):
+        got = plan(active, *aboard)
         if got is not None:
             stalls.append(list(got[-1]))
         return got

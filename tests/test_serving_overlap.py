@@ -189,8 +189,8 @@ def test_eos_mid_stream_ends_the_request_where_it_is_read():
     bufs = [eng.open_stream(r) for r in rids]
     fed = []
     plan = eng._plan_decode
-    eng._plan_decode = lambda active: fed.append(
-        [int(eng._inflight[i]) for i in active]) or plan(active)
+    eng._plan_decode = lambda active, *aboard: fed.append(
+        [int(eng._inflight[i]) for i in active]) or plan(active, *aboard)
     eng.run_to_completion()
     # the step after the EOS step was launched before the EOS was read:
     # its token is dropped
@@ -430,7 +430,8 @@ def test_counters_reach_the_registry():
         text = metrics.REGISTRY.to_prometheus()
     finally:
         paddle.set_flags({"FLAGS_enable_metrics": False})
-    assert 'paddle_tpu_serving_launches_total{overlapped="true"}' in text
+    assert ('paddle_tpu_serving_launches_total{overlapped="true",'
+            'kind="decode"}') in text
     assert "paddle_tpu_serving_reads_total{host_late=" in text
 
 

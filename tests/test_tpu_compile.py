@@ -149,3 +149,50 @@ def test_paged_decode_step_compiles_over_a_mesh(four_chips, tpu_backend,
         assert (lowered, kernels) == (["kernel"], 1)
     else:
         assert (lowered, kernels) == (["composite"], 0)
+
+
+def test_mixed_step_compiles_with_the_kernel_for_the_lanes(one_chip,
+                                                           tpu_backend):
+    """``paged_mixed_step`` at Mistral's widths (two layers of the sixteen):
+    one program whose chunk rows attend through the composite and whose 32
+    decode rows through ``paged_decode_attn``, once a layer, the pools
+    donated and no copy of them made."""
+    from paddle_tpu.inference import PagedEngine
+    from paddle_tpu.models import LlamaConfig, LlamaForCausalLM
+    from paddle_tpu.nn.functional.paged_attention import log_paths
+    from paddle_tpu.nn.lazy_init import LazyGuard, materialize_layer
+
+    layers, B, nb, context = 2, 32, 512, 2048
+    with LazyGuard():
+        model = LlamaForCausalLM(LlamaConfig(
+            vocab_size=32768, hidden_size=4096, intermediate_size=14336,
+            num_layers=layers, num_heads=32, num_kv_heads=8,
+            max_seq_len=context, rope_theta=1e6, use_flash_attention=False))
+    for p in model.parameters():
+        shape = tuple(p.shape)
+        p._lazy_init = (lambda _s, _d, shape=shape: jnp.zeros(
+            shape, jnp.bfloat16), shape, jnp.bfloat16)
+    materialize_layer(model)
+    model.eval()
+    eng = PagedEngine(model, max_batch=B, block_size=16, num_blocks=nb,
+                      max_blocks_per_seq=context // 16)
+    W = eng.prefill_width
+
+    def rows(r, w):
+        return (np.zeros((r, w), np.int32), np.ones((r,), np.int32),
+                np.zeros((r, context // 16), np.int32),
+                np.zeros((r,), np.float32), np.ones((r,), np.float32),
+                np.zeros((r,), np.int32), np.zeros((r,), np.int32))
+
+    args = eng._chunk_args(*rows(B, 1)) + (
+        jnp.zeros((1,), jnp.int32), eng._row_args(*rows(1, W)))
+    shapes = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        args)
+    with log_paths() as lowered:
+        text = eng._fns["mixed"].lower(
+            *shapes, sampling=False).compile().as_text()
+    assert (W, sorted(set(lowered))) == (256, ["composite", "kernel"])
+    entry = text[text.index("ENTRY"):]
+    assert entry.count('custom_call_target="tpu_custom_call"') == layers
+    assert not re.findall(rf"= bf16\[{nb},\S+ copy\(", entry)
