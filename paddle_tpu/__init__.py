@@ -9,6 +9,10 @@ XLA collectives on ICI/DCN.
 """
 from __future__ import annotations
 
+import time as _time
+
+_T_FIRST_LINE = _time.perf_counter()    # startup.import's "before" mark
+
 import importlib
 
 __version__ = "0.1.0"
@@ -170,3 +174,8 @@ def __getattr__(name):
         globals()[name] = value
         return value
     raise AttributeError(f"module 'paddle_tpu' has no attribute {name!r}")
+
+
+# the start-up record's first entry: OS process start to this line
+from .observability import trace as _trace  # noqa: E402
+_trace.note_import(_T_FIRST_LINE)

@@ -113,6 +113,7 @@ def enable_jax_cache() -> str:
     (``chip_smoke.py``, ``benchmark/run.py``, the ``tools/`` chip scripts)
     call this BEFORE their first compile. With the variable set JAX reads it
     itself and nothing is set here."""
+    _trace.mark_backend()
     root = cache_root()
     if not os.environ.get(_JAX_CACHE_ENV):
         import jax

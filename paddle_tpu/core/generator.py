@@ -20,6 +20,8 @@ from typing import Optional
 import jax
 import numpy as np
 
+from ..observability import trace as _trace
+
 
 class Generator:
     def __init__(self, seed: int = 0):
@@ -68,6 +70,7 @@ def default_generator() -> Generator:
 def seed(s: int) -> Generator:
     """paddle.seed — reset the global stream."""
     global _default_generator
+    _trace.note_backend(query=True)
     _default_generator = Generator(s)
     return _default_generator
 

@@ -12,6 +12,8 @@ from typing import Optional
 
 import jax
 
+from ..observability import trace as _trace
+
 
 class Place:
     device_type = "unknown"
@@ -37,6 +39,7 @@ class Place:
         absent is an error, never another platform's device: a TPUPlace
         silently resolved to a CPU device runs the job on the host under
         the chip's name."""
+        _trace.note_backend(query=True)
         try:
             devs = jax.devices(self.device_type)
         except RuntimeError as e:
@@ -68,6 +71,7 @@ _current_place: Optional[Place] = None
 
 @functools.lru_cache(maxsize=None)
 def _default_place() -> Place:
+    _trace.note_backend(query=True)
     plat = jax.default_backend()
     if plat == "tpu":
         return TPUPlace(0)
@@ -100,8 +104,10 @@ def current_place() -> Place:
 
 
 def is_compiled_with_tpu() -> bool:
+    _trace.note_backend(query=True)
     return any(d.platform.lower() == "tpu" for d in jax.devices())
 
 
 def device_count() -> int:
+    _trace.note_backend(query=True)
     return jax.device_count()

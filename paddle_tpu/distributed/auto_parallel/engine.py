@@ -14,6 +14,7 @@ device_put/with_sharding_constraint.
 from __future__ import annotations
 
 import contextlib
+import itertools
 from typing import Callable, List, Optional
 
 import jax
@@ -24,6 +25,8 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from ...core.tensor import Tensor
 from ...observability import trace as _trace
 from .. import mesh as mesh_mod
+
+_ENGINE_COUNTER = itertools.count()
 
 
 class Engine:
@@ -71,8 +74,12 @@ class Engine:
         self._train_step = None
         self._eval_step = None
         self.history: List[float] = []
+        #: this Engine's name and ``fit`` calls in the start-up record
+        self._startup_name = f"engine{next(_ENGINE_COUNTER)}"
+        self._fit_calls = 0
 
     # ----------------------------------------------------------- compile
+    @_trace.in_startup_phase("startup.prepare")
     def prepare(self, inputs_spec=None, labels_spec=None, mode="train"):
         """Build + cache the jitted SPMD step (reference engine.prepare
         compiles the distributed program).
@@ -296,15 +303,28 @@ class Engine:
     # ------------------------------------------------------------ running
     def fit(self, train_data, epochs=1, batch_size=32, steps_per_epoch=None,
             log_freq=10, verbose=0):
-        with contextlib.ExitStack() as setup_span:
+        args = {"engine": self._startup_name, "call": self._fit_calls,
+                "epochs": epochs, "steps": 0, "state_placed_s": None,
+                "first_step_s": None}
+        self._fit_calls += 1
+        with _trace.startup_phase("startup.fit_call", args) as call, \
+                contextlib.ExitStack() as setup_span:
             setup_span.enter_context(_trace.boundary("fit.setup"))
-            return self._fit(setup_span, train_data, epochs, batch_size,
-                             steps_per_epoch, log_freq, verbose)
+            return self._fit(setup_span, call, train_data, epochs,
+                             batch_size, steps_per_epoch, log_freq, verbose)
 
-    def _fit(self, setup_span, train_data, epochs, batch_size,
+    def startup_summary(self) -> dict:
+        """Where this process's seconds before this Engine's first step
+        went (``observability.trace.startup_summary``): the operator's
+        question of a trainer, as ``PagedEngine.health()["startup"]`` is of
+        a replica."""
+        return _trace.startup_summary(self._startup_name)
+
+    def _fit(self, setup_span, call, train_data, epochs, batch_size,
              steps_per_epoch, log_freq, verbose):
         """``fit`` under its ``fit.setup`` span, which ``_fit`` closes at the
-        first wait for a batch (``ExitStack.close`` is idempotent)."""
+        first wait for a batch (``ExitStack.close`` is idempotent).
+        ``call`` is the call's open ``startup.fit_call`` phase."""
         if self._placement == "auto" and self.placement_plan is None:
             # plan on the first batch's shapes BEFORE the step compiles
             peek = next(iter(self.dataloader(train_data, batch_size)),
@@ -339,6 +359,7 @@ class Engine:
         opt_state = self._init_opt_state(pa)
         if self._mesh.size > 1 and not self._spmd_auto:
             pa, opt_state = self._replicate_over_mesh((pa, opt_state))
+        call.mark("state_placed_s")     # step built, state made and placed
         sched = getattr(self._opt, "_learning_rate", None)
         sched = sched if isinstance(sched, LRScheduler) else None
         use_prefetch = (bool(_flags.get_flag("prefetch"))
@@ -407,6 +428,8 @@ class Engine:
                             with _trace.boundary("fit.dispatch"):
                                 loss, pa, opt_state = self._train_step(
                                     pa, opt_state, lr, x, y)
+                            if call.args["first_step_s"] is None:
+                                call.mark("first_step_s")   # ready
                             with _trace.boundary("fit.post_step"):
                                 if n_sigs is not None \
                                         and cache_size() > n_sigs:
@@ -450,6 +473,7 @@ class Engine:
             # continues from where the Engine left off. Runs on abort
             # too: the Parameters' pre-fit payloads were donated by the
             # first step — the latest live arrays must land back.
+            call.args["steps"] = n_steps
             with _trace.boundary("fit.writeback"):
                 t, _masters, states = opt_state
                 self._opt._step_count = int(t)  # tpulint: disable=TPU103 — one end-of-fit writeback into the eager optimizer (documented contract), not a per-step sync

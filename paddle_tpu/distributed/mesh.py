@@ -17,6 +17,8 @@ import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from ..observability import trace as _trace
+
 _state = threading.local()
 _global_mesh: Optional[Mesh] = None
 _lock = threading.Lock()
@@ -34,6 +36,7 @@ def build_mesh(shape: Dict[str, int] | Sequence[int] = None,
     ``shape`` maps axis name -> size (dict), or a plain size list with
     ``axis_names``. Defaults to a 1-axis 'dp' mesh over every device.
     """
+    _trace.note_backend(query=True)
     devices = list(devices) if devices is not None else jax.devices()
     n = len(devices)
     if shape is None:

@@ -32,6 +32,7 @@ from dataclasses import dataclass, field
 from typing import Callable, List, Optional, Tuple
 
 from ..observability import metrics as _metrics
+from ..observability import trace as _trace
 
 __all__ = ["RequestStatus", "TERMINAL_STATUSES", "Overloaded",
            "RequestOutcome", "ResilienceConfig", "ReplicaState",
@@ -198,6 +199,9 @@ class ReplicaLifecycle:
         self.name = name if name is not None else \
             f"replica{next(_REPLICA_COUNTER)}"
         self.history: List[Tuple[float, str, str]] = []  # (t, state, why)
+        #: the open ``startup.warmup`` phase of the start-up record, from
+        #: the transition to WARMING to the one out of it
+        self._warming = None
         self._export_state()
 
     def _export_state(self, prev: Optional[str] = None):
@@ -216,6 +220,12 @@ class ReplicaLifecycle:
         if prev is not None:
             M_REPLICA_TRANSITIONS.inc(from_state=prev,
                                       to_state=self.state)
+        if self.state == ReplicaState.WARMING:
+            self._warming = _trace.startup_phase(
+                "startup.warmup", {"replica": self.name})
+        elif self._warming is not None:
+            self._warming.end({"state": self.state})
+            self._warming = None
 
     def to(self, state: str, reason: str = "") -> str:
         with self._lock:

@@ -880,6 +880,7 @@ class PagedEngine:
     per request — so the Router load-balances recsys replicas exactly
     like LM replicas."""
 
+    @_trace.in_startup_phase("startup.engine_build")
     def __init__(self, model, *, max_batch: int = 8,
                  block_size: Optional[int] = 16,
                  num_blocks: int = 256, max_blocks_per_seq: int = 32,
@@ -889,6 +890,7 @@ class PagedEngine:
                  resilience: Optional[ResilienceConfig] = None):
         from ..serving.scheduler import Scheduler, SchedulerConfig
 
+        _trace.note_backend(query=True)
         self.model = model
         self.arch = _pick_arch(model)
         # the adapter rides in the compiled programs that engines of one
@@ -1138,6 +1140,10 @@ class PagedEngine:
         # per rank (weakly held — a dropped engine unregisters itself)
         from ..observability import fleet as _fleet
         _fleet.register_replica(self)
+        _trace.startup_args(
+            replica=self.lifecycle.name,
+            pool_bytes=sum(a.nbytes for a in jax.tree_util.tree_leaves(
+                (self.kc, self.vc, self.state))))
 
     def _fresh_cache(self):
         """One layer's K (or V) page pool: a float array, or the int8
@@ -2513,6 +2519,7 @@ class PagedEngine:
                     f"warmup request ended "
                     f"{oc.status if oc else '<missing>'}: "
                     f"{oc.detail if oc else ''}")
+        _trace.startup_args(ticks=self._ticks, synthetic=len(rids))
         self.lifecycle.to(ReplicaState.READY, "warmup complete")
         return self
 
@@ -2619,6 +2626,9 @@ class PagedEngine:
                              if self._steps else None),
              "phase_share": self.scheduler.phase_share(),
              "prefill_fill": self.scheduler.prefill_fill(),
+             # seconds from the OS's start of the process to this
+             # replica's READY and where they went (the start-up record)
+             "startup": _trace.startup_summary(lc.name),
              # the probe path doubles as the burn-rate decay poll: an
              # idle replica's windows age out here, so the gauges fall
              # back to 0 after an incident instead of pinning high

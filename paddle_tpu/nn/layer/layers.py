@@ -16,6 +16,7 @@ import numpy as np
 
 from ...core import dtype as dtypes
 from ...core.tensor import Tensor
+from ...observability import trace as _trace
 from ..lazy_init import has_outstanding, materialize_layer
 from ..parameter import Parameter, ParamAttr, create_parameter
 
@@ -31,6 +32,7 @@ class _HookRemoveHelper:
 
 class Layer:
     def __init__(self, name_scope: Optional[str] = None, dtype="float32"):
+        _trace.note_backend()
         self.training = True
         self._dtype = dtypes.convert_dtype(dtype)
         self._name_scope = name_scope or self.__class__.__name__.lower()

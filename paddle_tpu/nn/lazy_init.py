@@ -19,6 +19,8 @@ __all__ = ["LazyGuard", "in_lazy_mode", "materialize_layer",
 
 import weakref
 
+from ..observability import trace as _trace
+
 #: lazy params awaiting materialization — id-keyed weak refs (a WeakSet
 #: would trip over Tensor's elementwise __eq__), so an abandoned
 #: LazyGuard model stops taxing every Layer.__call__ once it's GC'd
@@ -30,6 +32,7 @@ class LazyGuard:
     """Context manager: defer parameter initialization inside."""
 
     def __enter__(self):
+        _trace.note_backend()
         _STATE["on"] = True
         return self
 

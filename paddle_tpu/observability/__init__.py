@@ -59,6 +59,28 @@ metrics.gauge(
     "snapshot time.").set_function(device_live_bytes)
 
 
+def _startup_seconds() -> dict:
+    summary = trace.startup_summary()
+    return {(key[:-2],): v for key, v in summary.items()
+            if key.endswith("_s") and v is not None}
+
+
+metrics.gauge(
+    "paddle_tpu_startup_seconds",
+    "The start-up record (observability.trace.startup_summary), read at "
+    "snapshot time whatever FLAGS_enable_metrics was during set-up: "
+    "seconds from the OS's start of the process to its first ready mark "
+    "(phase=ready) and of each phase before it (import, backend, build, "
+    "warmup, fit_setup, trace_lower, compile).",
+    labelnames=("phase",)).set_function(_startup_seconds)
+metrics.gauge(
+    "paddle_tpu_startup_cache_misses",
+    "Programs compiled before the first ready mark, under a phase of the "
+    "program's own, that JAX's persistent compilation cache did not "
+    "hold.").set_function(
+        lambda: trace.startup_summary()["cache_misses"])
+
+
 # The pid that first imported this module owns the bare dump path; it is
 # published through the ENVIRONMENT so both fork- and spawn-started
 # children (which re-import the module and would otherwise see their own

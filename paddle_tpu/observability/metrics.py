@@ -130,30 +130,36 @@ class Gauge(_Metric):
     def dec(self, amount: float = 1.0, **labels):
         self.inc(-amount, **labels)
 
-    def set_function(self, fn: Callable[[], float]):
+    def set_function(self, fn: Callable[[], Any]):
         """Callback gauge: evaluated lazily at snapshot/export time (never
-        on the hot path). Only valid for unlabeled gauges."""
-        if self.labelnames:
-            raise ValueError("callback gauges cannot be labeled")
+        on the hot path). An unlabeled gauge's callback returns a number, a
+        labeled one's a dict: label-value tuple -> number."""
         self._fn = fn
         return self
 
+    def _call(self) -> Dict[tuple, float]:
+        try:
+            got = self._fn()
+            if not self.labelnames:
+                got = {(): got}
+            return {tuple(str(v) for v in k): float(x)
+                    for k, x in got.items()}
+        except Exception:
+            return {} if self.labelnames else {(): 0.0}
+
     def value(self, **labels) -> float:
+        key = self._label_values(labels)
         if self._fn is not None:
-            try:
-                return float(self._fn())
-            except Exception:
-                return 0.0
-        return self._vals.get(self._label_values(labels), 0.0)
+            return self._call().get(key, 0.0)
+        return self._vals.get(key, 0.0)
 
     def clear(self):
         with self._lock:
             self._vals.clear()
 
     def _series(self):
-        if self._fn is not None:
-            return [((), self.value())]
-        return [(k, v) for k, v in sorted(self._vals.items())]
+        vals = self._call() if self._fn is not None else self._vals
+        return [(k, v) for k, v in sorted(vals.items())]
 
 
 class Histogram(_Metric):

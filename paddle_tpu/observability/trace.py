@@ -13,17 +13,33 @@ have a second sink: ``boundary`` also enters a
 ``jax.profiler.TraceAnnotation`` of the same name, so whoever records a
 ``jax.profiler`` trace finds them on the xplane's host plane, on the clock
 of the device's ``XLA Ops`` line. Per-op sites stay buffer-only.
+
+What the process did BEFORE it served has a record of its own, always on
+and bounded (``STARTUP_SPANS``, ``startup_record``): the phases of set-up
+and one entry per trace / lower / backend compile of every program, which
+JAX reports to the one listener registered here. Every producer fires at a
+compile, a lifecycle transition or the entry and exit of a ``fit`` call,
+never in a steady step or tick.
 """
 from __future__ import annotations
 
+import functools
+import os
 import threading
 import time
 from typing import Any, Dict, List, Optional, Tuple
 
+from jax import config as _jax_config
+from jax import monitoring as _monitoring
 from jax import profiler as _profiler
+from jax._src import xla_bridge as _xla_bridge
 
 __all__ = ["active", "activate", "deactivate", "add_complete", "span",
-           "boundary", "BOUNDARY_SPANS", "drain", "clear", "MAX_EVENTS"]
+           "boundary", "BOUNDARY_SPANS", "drain", "clear", "MAX_EVENTS",
+           "STARTUP_SPANS", "MAX_STARTUP_EVENTS", "startup_phase",
+           "in_startup_phase", "startup_args",
+           "note_import", "mark_backend", "note_backend", "startup_record",
+           "startup_summary", "startup_clear"]
 
 #: buffer cap — a runaway loop must degrade to dropped spans, not OOM
 MAX_EVENTS = 200_000
@@ -182,15 +198,75 @@ BOUNDARY_SPANS: Dict[str, Tuple[str, Optional[str], str]] = {
 }
 
 
+#: THE list of the start-up record's entries: name -> (category, parent,
+#: what it brackets). An entry's own ``parent`` is the phase that was open
+#: on its thread when it began; the column gives the one it has on the two
+#: hot paths ("*": whichever phase is open, else none). ``fit.setup`` and
+#: ``fit.writeback`` are the boundary spans of those names, copied once a
+#: ``fit`` call; no per-step or per-tick span is. README "Observability"
+#: and PERF.md section 3 say which metric reads which.
+STARTUP_SPANS: Dict[str, Tuple[str, Optional[str], str]] = {
+    "startup.import": ("startup", None,
+                       "OS process start (/proc/self/stat's starttime; the "
+                       "package's first line where /proc has none) to the "
+                       "last line of paddle_tpu/__init__.py (args: "
+                       "before_package_s, the interpreter, `import jax` and "
+                       "the caller's imports; source)"),
+    "startup.backend": ("startup", None,
+                        "backend initialisation: the program's first device "
+                        "query where it is the process's first (args: "
+                        "bracketed true), else from compile.cache."
+                        "enable_jax_cache's stamp (the package's last line "
+                        "without one) to the first program entry that finds "
+                        "the backend up (bracketed false: an upper bound "
+                        "that holds whatever the caller did between)"),
+    "startup.engine_build": ("startup", None,
+                             "PagedEngine.__init__: pools, tables, adapters "
+                             "(args: replica, pool_bytes)"),
+    "startup.warmup": ("startup", None,
+                       "PagedEngine.warmup, stamped by ReplicaLifecycle at "
+                       "WARMING and at the transition out of it; READY is "
+                       "its end (args: replica, state, ticks, synthetic)"),
+    "startup.fit_call": ("startup", None,
+                         "one whole Engine.fit call (args: engine, call, "
+                         "epochs, steps, state_placed_s: entry to the step "
+                         "built and the state made and placed over the "
+                         "mesh, first_step_s: entry to the return of its "
+                         "first fit.dispatch)"),
+    "fit.setup": ("fit", "startup.fit_call", BOUNDARY_SPANS["fit.setup"][2]),
+    "fit.writeback": ("fit", "startup.fit_call",
+                      BOUNDARY_SPANS["fit.writeback"][2]),
+    "startup.prepare": ("startup", "fit.setup",
+                        "Engine.prepare: building and jitting the step, not "
+                        "its first call (no parent where the caller prepares "
+                        "before fit)"),
+    "compile.trace": ("compile", "*",
+                      "/jax/core/compile/jaxpr_trace_duration of an "
+                      "outermost trace (args: program; inner, the jitted "
+                      "functions traced inside it, which get no entry)"),
+    "compile.lower": ("compile", "*",
+                      "/jax/core/compile/jaxpr_to_mlir_module_duration "
+                      "(args: program; inner, the functions a lowering "
+                      "rule traced inside it, which get no entry)"),
+    "compile.backend": ("compile", "*",
+                        "/jax/core/compile/backend_compile_duration, the "
+                        "persistent cache's read inside it (args: program; "
+                        "cache hit / miss / off; retrieval_s and saved_s on "
+                        "a hit)"),
+}
+
+
 class boundary(span):
     """A span of ``BOUNDARY_SPANS`` (any other name is a KeyError): the
     buffer like ``span``, and a ``jax.profiler.TraceAnnotation`` of the same
     name (a ``StepTraceAnnotation`` when ``step_num`` is given) that carries
     ``args`` as the event's stats. The annotation exists only while a
     ``jax.profiler`` recording runs, the buffer entry only while ``active()``;
-    ``args`` is read at exit, so a site may fill it while the span is open."""
+    ``args`` is read at exit, so a site may fill it while the span is open.
+    The names ``STARTUP_SPANS`` lists too are phases of the start-up
+    record, whatever is active."""
 
-    __slots__ = ("_step", "_ann")
+    __slots__ = ("_step", "_ann", "_phase")
 
     def __init__(self, name: str, args: Optional[dict] = None,
                  step_num: Optional[int] = None):
@@ -198,9 +274,11 @@ class boundary(span):
         if self._step:
             args = dict(args or (), step_num=step_num)
         span.__init__(self, name, BOUNDARY_SPANS[name][0], args)
-        self._ann = None
+        self._ann = self._phase = None
 
     def __enter__(self):
+        if self.name in STARTUP_SPANS:
+            self._phase = startup_phase(self.name)
         if _profiler.TraceAnnotation.is_enabled():
             self._ann = (_profiler.StepTraceAnnotation if self._step
                          else _profiler.TraceAnnotation)(self.name)
@@ -209,6 +287,8 @@ class boundary(span):
 
     def __exit__(self, *exc):
         span.__exit__(self, *exc)
+        if self._phase is not None:
+            self._phase.end(self.args)
         if self._ann is not None:
             if self.args:
                 self._ann.set_metadata(**self.args)
@@ -235,3 +315,346 @@ def tail(n: int = 100) -> List[Tuple[str, str, float, float, int,
 
 def dropped() -> int:
     return _dropped["n"]
+
+
+# --------------------------------------------------------------------------
+# The start-up record: what the process did before it served. Always on
+# (the fit driver switches metrics on only just before its window, and an
+# operator asks of untraced processes), bounded, on perf_counter's clock.
+# --------------------------------------------------------------------------
+#: record cap: a trainer that calls ``fit`` for ever meets it, not a leak
+MAX_STARTUP_EVENTS = 4096
+
+_perf_counter = time.perf_counter   # the record's one clock (tests count it)
+_startup: List[Tuple[str, str, float, float, int, Optional[dict],
+                     Optional[str]]] = []
+_startup_dropped = {"n": 0}
+_open = threading.local()           # .stack: this thread's open phases
+_backend = {"seen": False, "mark": None}
+_summaries: Dict[Any, Tuple[int, dict]] = {}
+
+
+def _startup_add(name, t0, t1, args, parent):
+    with _lock:
+        if len(_startup) >= MAX_STARTUP_EVENTS:
+            _startup_dropped["n"] += 1
+            return
+        _startup.append((name, STARTUP_SPANS[name][0], t0, t1, _tid(),
+                         args, parent))
+
+
+def _open_stack() -> list:
+    stack = getattr(_open, "stack", None)
+    if stack is None:
+        stack = _open.stack = []
+    return stack
+
+
+class startup_phase:
+    """One open phase of ``STARTUP_SPANS`` (any other name is a KeyError),
+    begun where it is made: ``with startup_phase(name) as ph`` or, where
+    begin and end are two calls (a lifecycle's transitions),
+    ``ph = startup_phase(name)`` ... ``ph.end()``. Entries that begin on
+    this thread while it is open name it as their parent. ``args`` (the
+    caller's dict, not a copy) may be filled until the end; ``end`` may run
+    on another thread, once."""
+
+    __slots__ = ("name", "args", "parent", "t0", "_stack")
+
+    def __init__(self, name: str, args: Optional[dict] = None):
+        if name not in STARTUP_SPANS:
+            raise KeyError(name)
+        self.name, self.args = name, {} if args is None else args
+        self._stack = _open_stack()
+        self.parent = self._stack[-1].name if self._stack else None
+        self._stack.append(self)
+        self.t0 = _perf_counter()
+
+    def mark(self, key: str):
+        """``args[key]``: seconds from the phase's begin to now."""
+        self.args[key] = _perf_counter() - self.t0
+
+    def end(self, args: Optional[dict] = None):
+        stack, self._stack = self._stack, None
+        if stack is None:
+            return
+        t1 = _perf_counter()
+        with _lock:
+            if self in stack:
+                stack.remove(self)
+        if args:
+            self.args.update(args)
+        _startup_add(self.name, self.t0, t1, self.args or None, self.parent)
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        self.end()
+        return False
+
+
+def in_startup_phase(name: str):
+    """Decorator: the whole call is one ``startup_phase(name)``."""
+    def wrap(fn):
+        @functools.wraps(fn)
+        def call(*a, **kw):
+            with startup_phase(name):
+                return fn(*a, **kw)
+        return call
+    return wrap
+
+
+def startup_args(**args):
+    """Add ``args`` to the innermost phase open on this thread (none: a
+    no-op), from inside the work it brackets."""
+    stack = _open_stack()
+    if stack:
+        stack[-1].args.update(args)
+
+
+def _process_start() -> Optional[float]:
+    """The OS's start of this process on ``perf_counter``'s clock: field 22
+    of /proc/self/stat (clock ticks since boot) against CLOCK_BOOTTIME.
+    None where /proc or the clock is not there."""
+    try:
+        with open("/proc/self/stat") as f:
+            ticks = int(f.read().rsplit(")", 1)[1].split()[19])
+        age = (time.clock_gettime(time.CLOCK_BOOTTIME)
+               - ticks / os.sysconf("SC_CLK_TCK"))
+    except (OSError, ValueError, IndexError, AttributeError):
+        return None
+    return _perf_counter() - age if age >= 0 else None
+
+
+def note_import(t_first_line: float):
+    """``startup.import``, from the last line of ``paddle_tpu/__init__.py``
+    (``t_first_line``: the stamp its first line took)."""
+    t1 = _perf_counter()
+    t0 = _process_start()
+    source = "proc_stat"
+    if t0 is None or t0 > t_first_line:
+        t0, source = t_first_line, "package_first_line"
+    _backend["imported"] = t1   # an unbracketed backend's last-resort mark
+    _startup_add("startup.import", t0, t1,
+                 {"before_package_s": t_first_line - t0, "source": source},
+                 None)
+
+
+def mark_backend():
+    """The lower mark of an unbracketed ``startup.backend``: every chip
+    entry point passes ``enable_jax_cache`` before its first compile."""
+    if not _backend["seen"] and _backend["mark"] is None:
+        _backend["mark"] = _perf_counter()
+
+
+def note_backend(query: bool = False, at: Optional[float] = None):
+    """``startup.backend``, from a program entry (or, ``at`` its begin, the
+    first compile JAX reports): one check of a flag once it is seen.
+    ``query``: the caller is about to ask for a device anyway, so an
+    uninitialised backend is brought up HERE, bracketed; without it such
+    an entry only looks. A backend the caller brought up first is bounded
+    by two marks (``STARTUP_SPANS``)."""
+    if _backend["seen"]:
+        return
+    up = _xla_bridge.backends_are_initialized()
+    if not up and not query:
+        return
+    t1 = _perf_counter() if at is None else at
+    if up:
+        mark = _backend["mark"]
+        t0 = min(t1, _backend.get("imported", t1) if mark is None else mark)
+    else:
+        t0 = t1
+        _xla_bridge.get_backend()
+        t1 = _perf_counter()
+    if _backend["seen"]:        # another thread's entry got here first
+        return
+    _backend["seen"] = True
+    _startup_add("startup.backend", t0, t1, {"bracketed": not up}, None)
+
+
+_COMPILE_EVENTS = {
+    "/jax/core/compile/jaxpr_trace_duration": "compile.trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "compile.lower",
+    "/jax/core/compile/backend_compile_duration": "compile.backend",
+}
+#: the brackets that hold traces of their own: a trace the jitted functions
+#: it calls, a lowering the functions its rules trace (threefry's is
+#: hundreds of ``jnp`` calls). Only the outermost gets an entry.
+_NESTING = ("compile.trace", "compile.lower")
+_CACHE_EVENTS = {
+    "/jax/compilation_cache/compile_requests_use_cache": "miss",
+    "/jax/compilation_cache/cache_hits": "hit",
+}
+_CACHE_SECONDS = {
+    "/jax/compilation_cache/cache_retrieval_time_sec": "retrieval_s",
+    "/jax/compilation_cache/compile_time_saved_sec": "saved_s",
+}
+
+
+class _Compiling(threading.local):
+    """This thread's compile in flight: how deep in ``_NESTING`` brackets
+    it is (every ``jnp`` function traced inside a trace or a lowering is
+    one), how many the outermost held, the cache's answer so far."""
+    depth = 0
+    inner = 0
+    cache: Optional[dict] = None
+
+
+_compiling = _Compiling()
+
+
+def _on_compile_begin(event, _value, **_kw):
+    if _COMPILE_EVENTS.get(event) in _NESTING:
+        _compiling.depth += 1
+
+
+def _on_compile_event(event, **_kw):
+    """A compile that asks the persistent cache is a miss until the cache
+    says hit; one that never asks, or asks a cache with no directory, has
+    the cache off."""
+    outcome = _CACHE_EVENTS.get(event)
+    if outcome is not None:
+        if not _jax_config.jax_compilation_cache_dir:
+            outcome = "off"
+        if _compiling.cache is None:
+            _compiling.cache = {}
+        _compiling.cache["cache"] = outcome
+
+
+def _on_compile_seconds(event, duration, fun_name=None, **_kw):
+    """The listener of the durations JAX reports: an entry that ends now
+    and began ``duration`` ago. Only the outermost trace or lowering gets
+    an entry (the ones inside it are counted, ``inner``). The cache's events
+    of a backend compile arrive inside its bracket, on its thread, before
+    its duration."""
+    name = _COMPILE_EVENTS.get(event)
+    if name is None:
+        key = _CACHE_SECONDS.get(event)
+        if key is not None and _compiling.cache is not None:
+            _compiling.cache[key] = duration
+        return
+    args = {"program": fun_name}
+    if name in _NESTING:
+        _compiling.depth = max(_compiling.depth - 1, 0)
+        if _compiling.depth:
+            _compiling.inner += 1
+            return
+        args["inner"], _compiling.inner = _compiling.inner, 0
+    else:
+        args.update(_compiling.cache or {"cache": "off"})
+    if name != "compile.trace":
+        _compiling.cache = None     # a lowering starts a new lookup
+    t1 = _perf_counter()
+    if not _backend["seen"]:
+        note_backend(at=t1 - duration)
+    stack = _open_stack()
+    _startup_add(name, t1 - duration, t1, args,
+                 stack[-1].name if stack else None)
+
+
+_monitoring.register_scalar_listener(_on_compile_begin)
+_monitoring.register_event_listener(_on_compile_event)
+_monitoring.register_event_duration_secs_listener(_on_compile_seconds)
+
+
+def startup_clear():
+    """Empty the record (tests; the open phases stay open)."""
+    with _lock:
+        del _startup[:]
+        _startup_dropped["n"] = 0
+        _summaries.clear()
+
+
+def _seconds(intervals) -> float:
+    """Length of the union of ``(t0, t1)`` intervals."""
+    total, end = 0.0, None
+    for t0, t1 in sorted(intervals):
+        if end is not None:
+            t0 = max(t0, end)
+        if t1 > t0:
+            total += t1 - t0
+            end = t1
+    return total
+
+
+#: the phases that are the program's own work (compiles whose parent is
+#: one of them are the program's; under none, the caller's)
+PROGRAM_PHASES = ("startup.engine_build", "startup.warmup",
+                  "startup.fit_call", "startup.prepare", "fit.setup",
+                  "fit.writeback")
+
+
+def startup_record() -> dict:
+    """The record: ``entries`` ``(name, cat, t0, t1, tid, args, parent)``
+    in the order they ended, ``dropped`` (entries past the cap),
+    ``process_start`` (``startup.import``'s begin; None without one) and
+    ``ready``, the program's own marks ``(kind, who, t)``: a replica's
+    transition to READY, the return of the first ``fit.dispatch`` of a
+    ``fit`` call."""
+    with _lock:
+        entries = list(_startup)
+        dropped = _startup_dropped["n"]
+    start = next((e[2] for e in entries if e[0] == "startup.import"), None)
+    ready = []
+    for name, _cat, t0, t1, _tid_, args, _parent in entries:
+        args = args or {}
+        if name == "startup.warmup" and args.get("state") == "READY":
+            ready.append(("replica", args.get("replica"), t1))
+        elif name == "startup.fit_call" and args.get("first_step_s"):
+            ready.append(("fit", args.get("engine"),
+                          t0 + args["first_step_s"]))
+    return {"entries": entries, "dropped": dropped, "process_start": start,
+            "ready": sorted(ready, key=lambda r: r[2])}
+
+
+def startup_summary(who: Optional[str] = None) -> dict:
+    """What ``health()["startup"]`` and the ``paddle_tpu_startup_*`` gauges
+    show: seconds from the OS's start of the process to ready, and where
+    they went. ``who`` (a replica's or an Engine's name) keeps that one's
+    build, warm-up and ready mark; None the whole process's, ready at its
+    first mark. Compiles count while they began before ready under a
+    program phase. Recomputed only when the record has grown."""
+    with _lock:
+        n = len(_startup)
+        cached = _summaries.get(who)
+    if cached is not None and cached[0] == n:
+        return dict(cached[1])
+    rec = startup_record()
+    entries, start = rec["entries"], rec["process_start"]
+    ready = next((t for _kind, name, t in rec["ready"]
+                  if who is None or name == who), None)
+
+    def mine(e):
+        args = e[5] or {}
+        return who is None or who in (args.get("replica"),
+                                      args.get("engine"))
+
+    def spans(*names, own=False):
+        return [(e[2], e[3]) for e in entries if e[0] in names
+                and (not own or mine(e))]
+
+    compiles = [e for e in entries if e[0].startswith("compile.")
+                and e[6] in PROGRAM_PHASES and (ready is None or e[2] < ready)]
+    backend = [e for e in compiles if e[0] == "compile.backend"]
+    fit = spans("fit.setup", "fit.writeback", "startup.prepare")
+    compiling = [(e[2], e[3]) for e in compiles]
+    out = {
+        "ready_s": (ready - start if None not in (ready, start) else None),
+        "import_s": _seconds(spans("startup.import")),
+        "backend_s": _seconds(spans("startup.backend")),
+        "build_s": _seconds(spans("startup.engine_build", own=True)),
+        "warmup_s": _seconds(spans("startup.warmup", own=True)),
+        # less the compiles inside: |A| - |A and B| = |A or B| - |B|
+        "fit_setup_s": _seconds(fit + compiling) - _seconds(compiling),
+        "trace_lower_s": _seconds([(e[2], e[3]) for e in compiles
+                                   if e[0] != "compile.backend"]),
+        "compile_s": _seconds([(e[2], e[3]) for e in backend]),
+        "cache_misses": sum(1 for e in backend if e[5]["cache"] == "miss"),
+        "programs": len(backend),
+        "entries": n, "dropped": rec["dropped"],
+    }
+    with _lock:
+        _summaries[who] = (n, out)
+    return dict(out)

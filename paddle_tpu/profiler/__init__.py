@@ -81,7 +81,7 @@ _CAT_TID_BASE = {"user": 0, "dispatch": 100, "compile": 200,
                  # 500 is the unknown-category fallback lane; io/device
                  # get full 100-slot lanes so a process with many traced
                  # threads cannot bleed io spans into the device lane
-                 "io": 600, "device": 700}
+                 "io": 600, "device": 700, "startup": 800}
 
 
 def _trace_rank() -> Optional[int]:
@@ -120,7 +120,9 @@ def export_chrome_tracing(dir_name: str, worker_name: Optional[str] = None):
                            "pid": 0, "tid": 0,
                            "ts": int(t0 * 1e6),
                            "dur": max(int((t1 - t0) * 1e6), 0)})
-        for name, cat, t0, t1, tid, args in prof._spans:
+        # the start-up record first: one trace holds set-up and steady state
+        startup = [e[:6] for e in _trace.startup_record()["entries"]]
+        for name, cat, t0, t1, tid, args in startup + list(prof._spans):
             ev = {"name": name, "cat": cat, "ph": "X", "pid": 0,
                   "tid": _CAT_TID_BASE.get(cat, 500) + tid,
                   "ts": int(t0 * 1e6),

@@ -436,6 +436,8 @@ class Router:
             "shed_at_router": self.shed_at_router,
             # probe-path burn-rate decay poll (see PagedEngine.health)
             "slo_burn_rate": self._slo.burn_rates(self._clock()),
+            # the process's start-up record, ready at its first replica
+            "startup": _trace.startup_summary(),
             "per_replica": reps,
         }
 
