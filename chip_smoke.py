@@ -171,13 +171,16 @@ def _check_flash(checks, bh, seq, d):
     # logsumexp as residuals, so a broken forward tile cannot mask them
     (q, k, v, g), ref_out, lse, ref_grads = inputs_and_references(
         jax.random.key(seq + d))
-    for bq, bk in fa.FWD_TILE_CANDIDATES:
+    # every tile a call can be given: a causal call's constant, and the
+    # non-causal candidates under the causal kernels too
+    causal_tile = [(fa.CAUSAL_BLOCK, fa.CAUSAL_BLOCK)]
+    for bq, bk in causal_tile + fa.FWD_TILE_CANDIDATES:
         checks.check(
             f"flash_fwd[S={seq},d={d}]({bq},{bk})",
             lambda *a, bq=bq, bk=bk: fa._flash_fwd_bhsd(
                 *a, causal=True, scale=scale, block_q=bq, block_k=bk),
             (q, k, v), (ref_out, lse))
-    for bq, bk in fa.BWD_TILE_CANDIDATES:
+    for bq, bk in causal_tile + fa.BWD_TILE_CANDIDATES:
         # one jit holds both backward pallas_calls: dQ, then dK/dV
         checks.check(
             f"flash_dq_dkdv[S={seq},d={d}]({bq},{bk})",

@@ -37,7 +37,7 @@ from jax._src import xla_bridge as _xla_bridge
 __all__ = ["active", "activate", "deactivate", "add_complete", "span",
            "boundary", "BOUNDARY_SPANS", "drain", "clear", "MAX_EVENTS",
            "STARTUP_SPANS", "MAX_STARTUP_EVENTS", "startup_phase",
-           "in_startup_phase", "startup_args",
+           "in_startup_phase", "startup_args", "compile_note",
            "note_import", "mark_backend", "note_backend", "startup_record",
            "startup_summary", "startup_clear"]
 
@@ -500,6 +500,7 @@ class _Compiling(threading.local):
     depth = 0
     inner = 0
     cache: Optional[dict] = None
+    notes: Optional[dict] = None
 
 
 _compiling = _Compiling()
@@ -542,6 +543,9 @@ def _on_compile_seconds(event, duration, fun_name=None, **_kw):
             _compiling.inner += 1
             return
         args["inner"], _compiling.inner = _compiling.inner, 0
+        if _compiling.notes:
+            args.update(_compiling.notes)
+        _compiling.notes = None
     else:
         args.update(_compiling.cache or {"cache": "off"})
     if name != "compile.trace":
@@ -552,6 +556,18 @@ def _on_compile_seconds(event, duration, fun_name=None, **_kw):
     stack = _open_stack()
     _startup_add(name, t1 - duration, t1, args,
                  stack[-1].name if stack else None)
+
+
+def compile_note(key: str, value: dict):
+    """From code that runs while a program is traced (a kernel's wrapper):
+    ``args[key] = value`` on the ``compile.trace`` or ``compile.lower``
+    entry of the outermost bracket in flight on this thread, with
+    ``calls``, how often the trace said so. Outside one: a no-op."""
+    if _compiling.depth:
+        if _compiling.notes is None:
+            _compiling.notes = {}
+        calls = _compiling.notes.get(key, {}).get("calls", 0)
+        _compiling.notes[key] = dict(value, calls=calls + 1)
 
 
 _monitoring.register_scalar_listener(_on_compile_begin)

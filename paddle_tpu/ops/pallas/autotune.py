@@ -212,7 +212,8 @@ def autotune(key: str,
              run: Callable[[Any, int], Any],
              default: Any,
              warmup: int = 2,
-             iters: int = 5) -> Any:
+             iters: int = 5,
+             describe: Optional[Callable[[Any], dict]] = None) -> Any:
     """Return the cached winner for ``key``, measuring on first sight.
 
     ``run(candidate, i)`` executes the kernel once with that candidate on
@@ -227,6 +228,9 @@ def autotune(key: str,
     ``default`` itself is among the failures, or no candidate survives,
     this raises ``RuntimeError``: the caller's fallback is known broken
     on this chip, and caching it would hide that from every later run.
+    ``describe(candidate)`` says what a candidate makes the kernel do (the
+    flash kernels' plan): logged beside each time, and the winner's
+    stamped with it on the ``autotune:<key>`` span.
     """
     import jax
 
@@ -250,8 +254,8 @@ def autotune(key: str,
     # tracing, not the chip. (Not ensure_compile_time_eval: that also
     # constant-folds inside the Pallas kernel being traced, where
     # program_id has no eval rule.)
-    with _trace.span(f"autotune:{key}", "autotune",
-                     {"candidates": len(candidates)}), \
+    span_args = {"candidates": len(candidates)}
+    with _trace.span(f"autotune:{key}", "autotune", span_args), \
             jax.core.eval_context():
         for cand in candidates:
             try:
@@ -274,6 +278,9 @@ def autotune(key: str,
             timings[str(cand)] = dt
             if dt < best_t:
                 best, best_t = cand, dt
+        if best is not None:
+            span_args.update(winner=str(best), winner_ms=best_t * 1e3,
+                             **(describe(best) if describe else {}))
     if failed:
         _cache.failures[key] = failed
     if _metrics.enabled():
@@ -281,8 +288,10 @@ def autotune(key: str,
         if best is not None:
             _m_at_winner.set(best_t, key=key)
     if flags.get_flag("log_level") >= 1:
-        ranked = ", ".join(f"{c}={t * 1e3:.3f}ms" for c, t in
-                           sorted(timings.items(), key=lambda kv: kv[1]))
+        said = {str(c): describe(c) for c in candidates} if describe else {}
+        ranked = ", ".join(
+            f"{c}={t * 1e3:.3f}ms" + (f" {said[c]}" if c in said else "")
+            for c, t in sorted(timings.items(), key=lambda kv: kv[1]))
         log.info("autotune %s: %s", key, ranked or "no candidate survived")
     if best is None or str(default) in failed:
         what = ("no candidate survived" if best is None

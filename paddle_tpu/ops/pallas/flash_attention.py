@@ -7,8 +7,11 @@ paddle/phi/ops/yaml/ops.yaml:1635). Design:
 * forward — online-softmax over KV tiles: grid (batch*heads, q_tiles,
   kv_tiles) with the kv axis innermost so the fp32 accumulators in VMEM
   scratch persist across kv steps; the MXU consumes (Bq, d) x (d, Bk)
-  tiles; causal tiles above the diagonal are skipped with @pl.when so no
-  FLOPs are spent on masked blocks. Also emits the per-row logsumexp
+  tiles; causal tiles above the diagonal are skipped with @pl.when, and
+  INSIDE a tile the diagonal crosses only the part at or below it runs
+  (``_tile_blocks``: bands of ``SUB_BLOCK`` query rows, each against the
+  keys it can see), so no FLOPs are spent on masked blocks whatever the
+  tile. Also emits the per-row logsumexp
   (the FA2 "L" residual) for backward.
 * backward — the FA2 recompute strategy, O(S·d) memory: residuals are only
   (q, k, v, out, lse); each backward tile recomputes p = exp(qk·scale−lse)
@@ -25,6 +28,7 @@ through the Pallas interpreter so CPU tests cover the real kernel code.
 from __future__ import annotations
 
 import functools
+import logging
 import math
 
 import jax
@@ -36,30 +40,56 @@ pl, pltpu = import_pallas()
 
 NEG_INF = -1e30
 
-# Tuning knobs (VMEM-footprint vs pipeline depth); override per call via
-# flash_attention_fwd(..., block_q=..., block_k=...).
-DEFAULT_BLOCK_Q = 1024      # tuned on v5e @ S=8k: 23 TF/s vs 19 at 512
+# Tiles of a NON-causal call, as they were before PR 36 (which timed causal
+# calls only): such a tile runs whole, so its f32 scores have to fit VMEM
+# beside the operands; 2048 x 2048 does not (Mosaic refuses it on a v5e).
+# Override per call via flash_attention_fwd(..., block_q=..., block_k=...).
+DEFAULT_BLOCK_Q = 1024
 DEFAULT_BLOCK_K = 1024
 DEFAULT_BWD_BLOCK_Q = 512
 DEFAULT_BWD_BLOCK_K = 512
 
+# Tile of a CAUSAL call, both sides, both passes: the one measured winner, so
+# nothing is probed and no probe's noise picks a loser. On a v5e (PR 36;
+# bf16, bands of 256) 2048 x 2048 is first or within 0.4 % of it at every
+# length measured: S = 2048, d 64: 0.300 / 0.425 ms (forward / backward) a
+# call of 32 heads against 0.430 / 0.513 at 1024 x 1024; S = 8192, d 128:
+# 1.190 / 1.329 ms of 8 heads against 1.328 / 1.485; at S = 1024 every tile
+# of 1024 or more IS the sequence; 512-wide tiles lose 40-120 %. A band's
+# scores are 256 rows of the tile's keys, whatever the tile.
+CAUSAL_BLOCK = 2048
+
+
+def _causal_block_for(seq):
+    """Causal tile for ONE side, from that side's length: 1024 where 2048
+    would pad the side by 1024 rows or more (S = 3000 as 2 x 2 tiles of 2048
+    runs 1.6 times the scores of 3 x 3 of 1024)."""
+    if seq > CAUSAL_BLOCK and -seq % CAUSAL_BLOCK >= 1024:
+        return 1024
+    return CAUSAL_BLOCK
+
 
 def _bwd_block_for(seq):
-    """Backward tile size for ONE side (q or k), from that side's length:
-    1024 wins at short/medium seq (measured on v5e: 82.0ms vs 84.1ms GPT-2
-    step @ S=1024) but only when it divides the seq (otherwise padding
-    wastes up to 33% of the grid); longer seqs keep the 512 tiles that hold
-    the dKdV accumulators in VMEM (the original 8k tuning)."""
+    """Non-causal backward tile for ONE side (q or k), from that side's
+    length: 1024 where it divides a short sequence (otherwise padding
+    wastes up to a third of the grid), else the 512 tiles whose four
+    S x S f32 arrays of dK/dV fit VMEM."""
     if seq <= 2048 and seq % 1024 == 0:
         return 1024
     return DEFAULT_BWD_BLOCK_Q
 
+
 #: run kernels in the Pallas interpreter (CPU testing of kernel code)
 INTERPRET = False
 
-# Candidate tile grids for the measured autotuner (ops/pallas/autotune.py).
-# Small on purpose: each candidate costs one Pallas compile at first sight
-# of a new (shape-class, chip) key; winners persist to disk.
+#: rows of queries in a band of a causal tile (``_tile_blocks``)
+SUB_BLOCK = 256
+_LANES = 128
+
+# Candidate tile grids of a non-causal call for the measured autotuner
+# (ops/pallas/autotune.py). Small on purpose: each candidate costs one
+# Pallas compile at first sight of a new (shape-class, chip) key; winners
+# persist to disk.
 FWD_TILE_CANDIDATES = [(1024, 1024), (512, 512), (512, 1024), (1024, 512),
                        (2048, 512)]
 BWD_TILE_CANDIDATES = [(512, 512), (1024, 1024), (256, 512), (512, 1024),
@@ -67,7 +97,8 @@ BWD_TILE_CANDIDATES = [(512, 512), (1024, 1024), (256, 512), (512, 1024),
 
 
 def _tuned_blocks(kind, bh, s_q, s_k, d, dtype, causal, scale):
-    """Measured (block_q, block_k) for this shape class on this chip.
+    """(block_q, block_k) for this shape class on this chip: a causal call's
+    constant, a non-causal call's measured winner.
 
     Falls back to the hand-tuned v5e constants when autotuning is off or
     the backend is not a real TPU (reference
@@ -78,6 +109,8 @@ def _tuned_blocks(kind, bh, s_q, s_k, d, dtype, causal, scale):
     """
     from . import autotune as at
 
+    if causal:
+        return _causal_block_for(s_q), _causal_block_for(s_k)
     if INTERPRET or not at.should_autotune():
         if kind == "fwd":
             return DEFAULT_BLOCK_Q, DEFAULT_BLOCK_K
@@ -150,8 +183,9 @@ def _tuned_blocks(kind, bh, s_q, s_k, d, dtype, causal, scale):
             j = i % nvar
             return fn(qs[j], ks[j], vs[j], outs[j], lses[j], outs[j])
 
-    return tuple(at.autotune(key, candidates, run, default,
-                             warmup=2, iters=5))
+    return tuple(at.autotune(
+        key, candidates, run, default, warmup=2, iters=5,
+        describe=lambda c: flash_plan(sq_b, sk_b, causal, *c)))
 
 
 def _causal_run(q_idx, kv_idx, block_q, block_k, offset):
@@ -159,19 +193,213 @@ def _causal_run(q_idx, kv_idx, block_q, block_k, offset):
     return kv_idx * block_k <= q_idx * block_q + (block_q - 1) + offset
 
 
-def _tile_mask(q_idx, kv_idx, block_q, block_k, seq_k, causal, offset):
-    q_pos = q_idx * block_q + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 0)
-    k_pos = kv_idx * block_k + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 1)
+def _tile_mask(q_idx, kv_idx, block_q, block_k, seq_k, causal, offset,
+               corner=None):
+    """Mask of a tile's scores: the key exists and, causal, the query sees
+    it. ``corner`` (r0, c0, rows, cols): of that part of the tile only."""
+    r0, c0, rows, cols = corner or (0, 0, block_q, block_k)
+    q_pos = _at(q_idx * block_q, r0) + jax.lax.broadcasted_iota(
+        jnp.int32, (rows, cols), 0)
+    k_pos = _at(kv_idx * block_k, c0) + jax.lax.broadcasted_iota(
+        jnp.int32, (rows, cols), 1)
     mask = k_pos < seq_k
     if causal:
         mask = mask & (q_pos + offset >= k_pos)
     return mask
 
 
+def _at(base, off):
+    return base + off if off else base
+
+
+# --------------------------------------------------------------------------
+# The work inside a causal tile. Query row r of a tile sees its key c iff
+# r - c >= d0, d0 = kv_idx*block_k - (seq_k - seq_q) - q_idx*block_q, and
+# c < kv_valid (the keys of the tile that exist). A tile's work is a static
+# list of BLOCKS (r0, r1, c1, mc0, guard): the scores of the band of query
+# rows [r0, r1) against keys [0, c1), the keys one of its rows sees; only
+# keys [mc0, c1) can hold a masked score and get the mask (mc0 == c1: none
+# does); ``guard``: a row of the band may see no key of the tile (the
+# forward's fully-masked-row guard).
+# --------------------------------------------------------------------------
+def _floor_to(x, m):
+    return x // m * m
+
+
+def _ceil_to(x, m):
+    return -(-x // m) * m
+
+
+def _tile_blocks(d0, block_q, block_k, kv_valid, band):
+    """Blocks of the causal tile at ``d0``: bands of ``band`` query rows,
+    their keys cut at the lane width (a side no longer than its unit is one
+    piece; a longer one is whole units, ``_geometry``)."""
+    band, lanes = min(band, block_q), min(_LANES, block_k)
+    blocks = []
+    for r0 in range(0, block_q, band):
+        r1 = r0 + band
+        # keys SOME row sees: c <= r1 - 1 - d0; EVERY row: c <= r0 - d0
+        c1 = min(max(_ceil_to(min(r1 - d0, kv_valid), lanes), 0), block_k)
+        if c1:
+            mc0 = min(max(_floor_to(min(r0 - d0 + 1, kv_valid), lanes), 0),
+                      c1)
+            blocks.append((r0, r1, c1, mc0, r0 < d0))
+    return tuple(blocks)
+
+
+def _geometry(seq_q, seq_k, block_q, block_k, band=None):
+    """(block_q, block_k, padded seq_q, padded seq_k) as the kernels'
+    wrappers tile a call: a side shorter than the tile is one tile.
+    ``band`` (a causal call's): a tile longer than a band is whole bands,
+    wider than the lanes whole lane groups, whatever the sequence's length
+    or the caller's pin, so that no tile the diagonal crosses runs whole
+    (a ragged 2000 x 2000 tile's f32 scores alone are 16 MB of VMEM)."""
+    block_q = min(block_q, max(seq_q, 8))
+    block_k = min(block_k, max(seq_k, 8))
+    if band:
+        if block_q > band:
+            block_q = _ceil_to(block_q, band)
+        if block_k > _LANES:
+            block_k = _ceil_to(block_k, _LANES)
+    return (block_q, block_k, _ceil_to(seq_q, block_q),
+            _ceil_to(seq_k, block_k))
+
+
+@functools.lru_cache(maxsize=64)      # a model's layers ask alike
+def _grid_classes(seq_q, seq_k, causal, block_q, block_k, band):
+    """The grid's tiles by what they run:
+    ``((blocks, ((d0, tail), ...), n_tiles), ...)``. A causal tile is told
+    by its ``d0`` (held at 1 - block_k, from where on every row sees every
+    key) and by whether it holds the padded tail; tiles above the diagonal
+    (``_causal_run`` false) are in no class. Non-causal tiles are one
+    class of one block, masked everywhere (the mask is the tail's)."""
+    block_q, block_k, sp_q, sp_k = _geometry(seq_q, seq_k, block_q, block_k,
+                                             band if causal else None)
+    n_q, n_k = sp_q // block_q, sp_k // block_k
+    if not causal:
+        return ((((0, block_q, block_k, 0, True),), (), n_q * n_k),)
+    kv_tail = seq_k - (n_k - 1) * block_k
+    by_key = {}
+    for qi in range(n_q):
+        for ki in range(n_k):
+            d0 = ki * block_k - (seq_k - seq_q) - qi * block_q
+            if d0 > block_q - 1:
+                continue
+            key = (max(d0, 1 - block_k), ki == n_k - 1 and kv_tail < block_k)
+            by_key[key] = by_key.get(key, 0) + 1
+    by_blocks = {}
+    for (d0, tail), n in by_key.items():
+        blocks = _tile_blocks(d0, block_q, block_k,
+                              kv_tail if tail else block_k, band)
+        keys, count = by_blocks.get(blocks, ((), 0))
+        by_blocks[blocks] = (keys + ((d0, tail),), count + n)
+    return tuple((blocks, keys, n) for blocks, (keys, n) in by_blocks.items())
+
+
+def flash_plan(seq_q, seq_k, causal, block_q, block_k):
+    """What the three kernels execute for a call, fixed when it is traced:
+    ``tiles`` (the grid a head, queries x keys), ``sub_block`` (the rows
+    of a band of a causal tile; None where a tile runs whole) and
+    ``executed_share``, the area of scores computed over the padded
+    seq_q x seq_k square (1.0 non-causal; 0.625 for one causal tile of
+    1024 in bands of 256)."""
+    bq, bk, sp_q, sp_k = _geometry(seq_q, seq_k, block_q, block_k,
+                                   SUB_BLOCK if causal else None)
+    area = sum(n * sum((r1 - r0) * c1 for r0, r1, c1, _mc0, _g in blocks)
+               for blocks, _keys, n in _grid_classes(
+                   seq_q, seq_k, causal, block_q, block_k, SUB_BLOCK))
+    return {"tiles": [sp_q // bq, sp_k // bk],
+            "sub_block": min(SUB_BLOCK, bq) if causal else None,
+            "executed_share": area / (sp_q * sp_k)}
+
+
+def _plan_entry(kind, seq_q, seq_k, causal, block_q, block_k):
+    return (f"flash_{kind}[{seq_q}x{seq_k},{'causal' if causal else 'full'},"
+            f"{block_q}x{block_k}]",
+            flash_plan(seq_q, seq_k, causal, block_q, block_k))
+
+
+def _stamp_plan(*call):
+    """From the vjp rules: the plan on the ``compile.trace`` entry of the
+    program being traced (the start-up record; outside a trace, nothing)."""
+    from ...observability import trace as _trace
+    _trace.compile_note(*_plan_entry(*call))
+
+
+def _log_plan(*call):
+    """From the jitted wrappers, so once a signature a process: the plan on
+    the autotuner's logger at ``FLAGS_log_level`` 1."""
+    from ...core import flags
+    if flags.get_flag("log_level") >= 1:
+        logging.getLogger("paddle_tpu.autotune").info(
+            "%s: %s", *_plan_entry(*call))
+
+
+def _for_tile(classes, q_idx, kv_idx, num_kv, block_q, block_k, offset, body):
+    """``body(block)`` for every block of the class the tile is in."""
+    if len(classes) == 1:
+        for block in classes[0][0]:
+            body(block)
+        return
+    d0 = jnp.maximum(kv_idx * block_k - offset - q_idx * block_q,
+                     1 - block_k)
+    last = kv_idx == num_kv - 1
+    tails = any(tail for _b, keys, _n in classes for _d0, tail in keys)
+    for blocks, keys, _n in classes:
+        cond = False
+        for value, tail in keys:
+            here = d0 == value
+            if tails:
+                here = here & (last if tail else jnp.logical_not(last))
+            cond = here | cond
+
+        @pl.when(cond)
+        def _class(blocks=blocks):
+            for block in blocks:
+                body(block)
+
+
+def _masked_keys(x, block, fn):
+    """``fn`` on the columns of the block's (rows, keys) array ``x`` that
+    can hold masked scores, the rest as they are."""
+    _r0, _r1, c1, mc0, _guard = block
+    if mc0 == 0:
+        return fn(x)
+    if mc0 == c1:
+        return x
+    return jnp.concatenate([x[:, :mc0], fn(x[:, mc0:])], axis=1)
+
+
+def _block_mask(block, q_idx, kv_idx, block_q, block_k, seq_k, causal,
+                offset):
+    """The mask of the block's maskable keys, or None where it has none."""
+    r0, r1, c1, mc0, _guard = block
+    if mc0 == c1:
+        return None
+    return _tile_mask(q_idx, kv_idx, block_q, block_k, seq_k, causal, offset,
+                      corner=(r0, mc0, r1 - r0, c1 - mc0))
+
+
+def _scores(q, k, scale):
+    return jax.lax.dot_general(
+        q, k, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32) * scale
+
+
+def _probs(s, lse, block, mask):
+    """p = exp(s - lse), zero where masked. The mask guards (not just exp
+    underflow): for fully-masked rows lse is garbage (~NEG_INF) and
+    exp(NEG_INF - lse) would be 1, not 0."""
+    if mask is None:
+        return jnp.exp(s - lse)
+    s = _masked_keys(s, block, lambda x: jnp.where(mask, x, NEG_INF))
+    return _masked_keys(jnp.exp(s - lse), block,
+                        lambda x: jnp.where(mask, x, 0.0))
+
+
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
-                *, scale, causal, block_q, block_k, seq_q, seq_k):
+                *, scale, causal, block_q, block_k, seq_q, seq_k, classes,
+                one_pass):
     kv_idx = pl.program_id(2)
     q_idx = pl.program_id(1)
     num_kv = pl.num_programs(2)
@@ -179,52 +407,77 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
     # XLA reference path): query i attends keys <= i + (seq_k - seq_q).
     causal_offset = seq_k - seq_q
 
-    @pl.when(kv_idx == 0)
-    def _init():
-        m_scr[:] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[:] = jnp.zeros_like(l_scr)
-        acc_scr[:] = jnp.zeros_like(acc_scr)
+    if not one_pass:
+        @pl.when(kv_idx == 0)
+        def _init():
+            m_scr[:] = jnp.full_like(m_scr, NEG_INF)
+            l_scr[:] = jnp.zeros_like(l_scr)
+            acc_scr[:] = jnp.zeros_like(acc_scr)
+    elif seq_q > seq_k:
+        # the first seq_q - seq_k rows see no key: no band writes them
+        o_ref[0] = jnp.zeros_like(o_ref[0])
+        lse_ref[0] = jnp.full_like(lse_ref[0], NEG_INF)
 
     run = True
     if causal:
         run = _causal_run(q_idx, kv_idx, block_q, block_k, causal_offset)
 
-    @pl.when(run)
-    def _step():
-        q = q_ref[0]          # (block_q, d)
-        k = k_ref[0]          # (block_k, d)
-        v = v_ref[0]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale
-        mask = _tile_mask(q_idx, kv_idx, block_q, block_k, seq_k, causal,
-                          causal_offset)
-        s = jnp.where(mask, s, NEG_INF)
+    def _block(block):
+        rows, cols = slice(*block[:2]), slice(0, block[2])
+        q = q_ref[0, rows]    # (rows, d)
+        k = k_ref[0, cols]    # (keys, d)
+        v = v_ref[0, cols]
+        s = _scores(q, k, scale)
+        mask = _block_mask(block, q_idx, kv_idx, block_q, block_k, seq_k,
+                           causal, causal_offset)
+        if mask is not None:
+            s = _masked_keys(s, block, lambda x: jnp.where(mask, x, NEG_INF))
 
-        m_prev = m_scr[:]                      # (block_q, 1)
+        if one_pass:
+            # the band's only keys: nothing to merge, nothing to carry
+            m_new = jnp.max(s, axis=1, keepdims=True)
+            p = jnp.exp(s - m_new)
+            if block[4]:
+                p = jnp.where(m_new > NEG_INF / 2, p, 0.0)
+            l = jnp.maximum(jnp.sum(p, axis=1, keepdims=True), 1e-30)
+            acc = jax.lax.dot_general(
+                p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            o_ref[0, rows] = (acc / l).astype(o_ref.dtype)
+            lse_ref[0, rows] = m_new + jnp.log(l)
+            return
+        m_prev = m_scr[rows]                   # (rows, 1)
         m_cur = jnp.max(s, axis=1, keepdims=True)
         m_new = jnp.maximum(m_prev, m_cur)
-        p = jnp.exp(s - m_new)                 # (block_q, block_k)
-        # fully-masked rows (causal, seq_q > seq_k): m_new == NEG_INF and
-        # exp(s - m_new) == 1; zero them so l stays 0 and out stays 0
-        p = jnp.where(m_new > NEG_INF / 2, p, 0.0)
+        p = jnp.exp(s - m_new)                 # (rows, keys)
+        if block[4]:
+            # fully-masked rows (causal, seq_q > seq_k): m_new == NEG_INF
+            # and exp(s - m_new) == 1; zero them so l stays 0, out stays 0
+            p = jnp.where(m_new > NEG_INF / 2, p, 0.0)
         alpha = jnp.exp(m_prev - m_new)
-        l_new = alpha * l_scr[:] + jnp.sum(p, axis=1, keepdims=True)
-        acc_scr[:] = acc_scr[:] * alpha + jax.lax.dot_general(
+        l_new = alpha * l_scr[rows] + jnp.sum(p, axis=1, keepdims=True)
+        acc_scr[rows] = acc_scr[rows] * alpha + jax.lax.dot_general(
             p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
-        m_scr[:] = m_new
-        l_scr[:] = l_new
+        m_scr[rows] = m_new
+        l_scr[rows] = l_new
 
-    @pl.when(kv_idx == num_kv - 1)
-    def _finish():
-        l = jnp.maximum(l_scr[:], 1e-30)
-        o_ref[0] = (acc_scr[:] / l).astype(o_ref.dtype)
-        lse_ref[0] = m_scr[:] + jnp.log(l)
+    @pl.when(run)
+    def _step():
+        _for_tile(classes, q_idx, kv_idx, num_kv, block_q, block_k,
+                  causal_offset, _block)
+
+    if not one_pass:
+        @pl.when(kv_idx == num_kv - 1)
+        def _finish():
+            l = jnp.maximum(l_scr[:], 1e-30)
+            o_ref[0] = (acc_scr[:] / l).astype(o_ref.dtype)
+            lse_ref[0] = m_scr[:] + jnp.log(l)
 
 
 def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
-               dq_scr, *, scale, causal, block_q, block_k, seq_q, seq_k):
+               dq_scr, *, scale, causal, block_q, block_k, seq_q, seq_k,
+               classes):
     kv_idx = pl.program_id(2)
     q_idx = pl.program_id(1)
     num_kv = pl.num_programs(2)
@@ -238,30 +491,30 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
     if causal:
         run = _causal_run(q_idx, kv_idx, block_q, block_k, causal_offset)
 
-    @pl.when(run)
-    def _step():
-        q = q_ref[0]
-        k = k_ref[0]
-        v = v_ref[0]
-        do = do_ref[0]
-        lse = lse_ref[0]                       # (block_q, 1)
-        delta = delta_ref[0]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale
-        mask = _tile_mask(q_idx, kv_idx, block_q, block_k, seq_k, causal,
-                          causal_offset)
-        s = jnp.where(mask, s, NEG_INF)
-        # mask-guard (not just exp underflow): for fully-masked rows lse is
-        # garbage (~NEG_INF) and exp(NEG_INF - lse) would be 1, not 0
-        p = jnp.where(mask, jnp.exp(s - lse), 0.0)
+    def _block(block):
+        rows, cols = slice(*block[:2]), slice(0, block[2])
+        q = q_ref[0, rows]
+        k = k_ref[0, cols]
+        v = v_ref[0, cols]
+        do = do_ref[0, rows]
+        lse = lse_ref[0, rows]                 # (rows, 1)
+        delta = delta_ref[0, rows]
+        s = _scores(q, k, scale)
+        mask = _block_mask(block, q_idx, kv_idx, block_q, block_k, seq_k,
+                           causal, causal_offset)
+        p = _probs(s, lse, block, mask)
         dp = jax.lax.dot_general(
             do, v, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)
-        ds = p * (dp - delta) * scale          # (block_q, block_k) fp32
-        dq_scr[:] += jax.lax.dot_general(
+        ds = p * (dp - delta) * scale          # (rows, keys) fp32
+        dq_scr[rows] += jax.lax.dot_general(
             ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
+
+    @pl.when(run)
+    def _step():
+        _for_tile(classes, q_idx, kv_idx, num_kv, block_q, block_k,
+                  causal_offset, _block)
 
     @pl.when(kv_idx == num_kv - 1)
     def _finish():
@@ -270,10 +523,11 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
 
 def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref,
                 dv_ref, dk_scr, dv_scr, *, scale, causal, block_q, block_k,
-                seq_q, seq_k):
+                seq_q, seq_k, classes):
     q_idx = pl.program_id(2)       # q innermost in this kernel
     kv_idx = pl.program_id(1)
     num_q = pl.num_programs(2)
+    num_kv = pl.num_programs(1)
     causal_offset = seq_k - seq_q
 
     @pl.when(q_idx == 0)
@@ -285,23 +539,20 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref,
     if causal:
         run = _causal_run(q_idx, kv_idx, block_q, block_k, causal_offset)
 
-    @pl.when(run)
-    def _step():
-        q = q_ref[0]
-        k = k_ref[0]
-        v = v_ref[0]
-        do = do_ref[0]
-        lse = lse_ref[0]
-        delta = delta_ref[0]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale
-        mask = _tile_mask(q_idx, kv_idx, block_q, block_k, seq_k, causal,
-                          causal_offset)
-        s = jnp.where(mask, s, NEG_INF)
-        p = jnp.where(mask, jnp.exp(s - lse), 0.0)
+    def _block(block):
+        rows, cols = slice(*block[:2]), slice(0, block[2])
+        q = q_ref[0, rows]
+        k = k_ref[0, cols]
+        v = v_ref[0, cols]
+        do = do_ref[0, rows]
+        lse = lse_ref[0, rows]
+        delta = delta_ref[0, rows]
+        s = _scores(q, k, scale)
+        mask = _block_mask(block, q_idx, kv_idx, block_q, block_k, seq_k,
+                           causal, causal_offset)
+        p = _probs(s, lse, block, mask)
         # dv += P^T dO
-        dv_scr[:] += jax.lax.dot_general(
+        dv_scr[cols] += jax.lax.dot_general(
             p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
         dp = jax.lax.dot_general(
@@ -309,9 +560,14 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref,
             preferred_element_type=jnp.float32)
         ds = p * (dp - delta) * scale
         # dk += dS^T Q
-        dk_scr[:] += jax.lax.dot_general(
+        dk_scr[cols] += jax.lax.dot_general(
             ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
+
+    @pl.when(run)
+    def _step():
+        _for_tile(classes, q_idx, kv_idx, num_kv, block_q, block_k,
+                  causal_offset, _block)
 
     @pl.when(q_idx == num_q - 1)
     def _finish():
@@ -328,20 +584,41 @@ def _pad_bhsd(x, block_s, pad_d):
 
 def _flash_fwd_bhsd(q, k, v, *, causal, scale, block_q, block_k):
     """q/k/v: (BH, S, d) -> (out (BH, S, d), lse fp32 (BH, Sq_padded))."""
+    return _fwd_call(q, k, v, causal=causal, scale=float(scale),
+                     block_q=block_q, block_k=block_k, band=SUB_BLOCK,
+                     interpret=INTERPRET)
+
+
+# One jitted function a kernel: a model's layers call it with one signature,
+# so the program that holds them traces the kernel body and lowers it to
+# Mosaic once, not once a layer (the module's switches are arguments, so a
+# test that flips one is not served the other's trace).
+_STATIC = ("causal", "scale", "block_q", "block_k", "band", "interpret")
+
+
+@functools.partial(jax.jit, static_argnames=_STATIC)
+def _fwd_call(q, k, v, *, causal, scale, block_q, block_k, band, interpret):
     bh, s_q, d = q.shape
     s_k = k.shape[1]
-    block_q = min(block_q, max(s_q, 8))
-    block_k = min(block_k, max(s_k, 8))
+    _log_plan("fwd", s_q, s_k, causal, block_q, block_k)
+    classes = _grid_classes(s_q, s_k, causal, block_q, block_k, band)
+    block_q, block_k, sp_q, sp_k = _geometry(s_q, s_k, block_q, block_k,
+                                             band if causal else None)
     pad_d = (-d) % 128
     q = _pad_bhsd(q, block_q, pad_d)
     k = _pad_bhsd(k, block_k, pad_d)
     v = _pad_bhsd(v, block_k, pad_d)
-    sp_q, sp_k, dp = q.shape[1], k.shape[1], d + pad_d
+    dp = d + pad_d
 
     grid = (bh, sp_q // block_q, sp_k // block_k)
     kernel = functools.partial(
         _fwd_kernel, scale=scale, causal=causal, block_q=block_q,
-        block_k=block_k, seq_q=s_q, seq_k=s_k)
+        block_k=block_k, seq_q=s_q, seq_k=s_k, classes=classes,
+        # a causal row of tiles one tile long: every band meets all its
+        # keys at once (a non-causal call keeps the kernel it had). On a
+        # v5e the running-state form runs the same bands 1.44 times as long
+        # at S 1024, d 64 (1.20 at S 2048): 4.6 % of a GPT-2 345M step
+        one_pass=causal and sp_k == block_k)
     out, lse = pl.pallas_call(
         kernel,
         out_shape=[jax.ShapeDtypeStruct((bh, sp_q, dp), q.dtype),
@@ -361,7 +638,7 @@ def _flash_fwd_bhsd(q, k, v, *, causal, scale, block_q, block_k):
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, dp), jnp.float32),
         ],
-        interpret=INTERPRET,
+        interpret=interpret,
         name="flash_fwd",
     )(q, k, v)
     return out[:, :s_q, :d], lse
@@ -371,10 +648,20 @@ def _flash_bwd_bhsd(q, k, v, out, lse, do, *, causal, scale, block_q,
                     block_k):
     """FA2 backward. All of q/k/v/out/do: (BH, S, d); lse: (BH, Sq_pad_fwd).
     Returns (dq, dk, dv) unpadded."""
+    return _bwd_call(q, k, v, out, lse, do, causal=causal,
+                     scale=float(scale), block_q=block_q, block_k=block_k,
+                     band=SUB_BLOCK, interpret=INTERPRET)
+
+
+@functools.partial(jax.jit, static_argnames=_STATIC)
+def _bwd_call(q, k, v, out, lse, do, *, causal, scale, block_q, block_k,
+              band, interpret):
     bh, s_q, d = q.shape
     s_k = k.shape[1]
-    block_q = min(block_q, max(s_q, 8))
-    block_k = min(block_k, max(s_k, 8))
+    _log_plan("bwd", s_q, s_k, causal, block_q, block_k)
+    classes = _grid_classes(s_q, s_k, causal, block_q, block_k, band)
+    block_q, block_k, sp_q, sp_k = _geometry(s_q, s_k, block_q, block_k,
+                                             band if causal else None)
     pad_d = (-d) % 128
 
     # Δ = rowsum(dO ∘ O): one fused XLA reduction, fp32.
@@ -385,7 +672,7 @@ def _flash_bwd_bhsd(q, k, v, out, lse, do, *, causal, scale, block_q,
     do = _pad_bhsd(do, block_q, pad_d)
     k = _pad_bhsd(k, block_k, pad_d)
     v = _pad_bhsd(v, block_k, pad_d)
-    sp_q, sp_k, dp = q.shape[1], k.shape[1], d + pad_d
+    dp = d + pad_d
     if lse.shape[1] < sp_q:     # fwd may have tiled with a different block
         lse = jnp.pad(lse, ((0, 0), (0, sp_q - lse.shape[1]), (0, 0)))
     elif lse.shape[1] > sp_q:
@@ -393,7 +680,7 @@ def _flash_bwd_bhsd(q, k, v, out, lse, do, *, causal, scale, block_q,
     delta = jnp.pad(delta, ((0, 0), (0, sp_q - s_q), (0, 0)))
 
     kw = dict(scale=scale, causal=causal, block_q=block_q, block_k=block_k,
-              seq_q=s_q, seq_k=s_k)
+              seq_q=s_q, seq_k=s_k, classes=classes)
     q_spec = pl.BlockSpec((1, block_q, dp), lambda b, i, j: (b, i, 0))
     row_spec = pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0))
 
@@ -409,7 +696,7 @@ def _flash_bwd_bhsd(q, k, v, out, lse, do, *, causal, scale, block_q,
         ],
         out_specs=q_spec,
         scratch_shapes=[pltpu.VMEM((block_q, dp), jnp.float32)],
-        interpret=INTERPRET,
+        interpret=interpret,
         name="flash_dq",
     )(q, k, v, do, lse, delta)
 
@@ -426,7 +713,7 @@ def _flash_bwd_bhsd(q, k, v, out, lse, do, *, causal, scale, block_q,
         out_specs=[kv_spec, kv_spec],
         scratch_shapes=[pltpu.VMEM((block_k, dp), jnp.float32),
                         pltpu.VMEM((block_k, dp), jnp.float32)],
-        interpret=INTERPRET,
+        interpret=interpret,
         name="flash_dkv",
     )(q, k, v, do, lse, delta)
     return (dq[:, :s_q, :d], dk[:, :s_k, :d], dv[:, :s_k, :d])
@@ -468,6 +755,7 @@ def _flash_fwd_rule(q, k, v, causal, scale, block_q, block_k):
     b, s, h, d = q.shape
     block_q, block_k = _resolve_blocks("fwd", block_q, block_k, q, k,
                                        causal, scale)
+    _stamp_plan("fwd", s, k.shape[1], causal, block_q, block_k)
     out, lse = _flash_fwd_bhsd(
         _bshd_to_bhsd(q), _bshd_to_bhsd(k), _bshd_to_bhsd(v),
         causal=causal, scale=scale, block_q=block_q, block_k=block_k)
@@ -480,6 +768,7 @@ def _flash_bwd_rule(causal, scale, block_q, block_k, res, g):
     b, s, h, d = q.shape
     block_q, block_k = _resolve_blocks("bwd", block_q, block_k, q, k,
                                        causal, scale)
+    _stamp_plan("bwd", s, k.shape[1], causal, block_q, block_k)
     dq, dk, dv = _flash_bwd_bhsd(
         _bshd_to_bhsd(q), _bshd_to_bhsd(k), _bshd_to_bhsd(v),
         _bshd_to_bhsd(out), lse, _bshd_to_bhsd(g),
@@ -494,8 +783,8 @@ _flash_attention.defvjp(_flash_fwd_rule, _flash_bwd_rule)
 def flash_attention_fwd(q, k, v, causal=False, scale=None, block_q=None,
                         block_k=None):
     """Public entry: q/k/v (batch, seq, heads, head_dim). ``block_q`` /
-    ``block_k`` tune the tile sizes (defaults: DEFAULT_BLOCK_Q/K forward,
-    DEFAULT_BWD_BLOCK_Q/K backward)."""
+    ``block_k`` tune the tile sizes (unset: the autotuner's pick on a TPU,
+    else DEFAULT_BLOCK_Q/K, forward and backward)."""
     if scale is None:
         scale = 1.0 / math.sqrt(q.shape[-1])
     return _flash_attention(q, k, v, causal, scale, block_q, block_k)

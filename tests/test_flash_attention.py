@@ -257,3 +257,206 @@ def test_pallas_flash_is_partitioned_by_hand_under_a_mesh(monkeypatch):
     for a, b in zip(got_grads, ref_grads):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=2e-5)
     assert got_grads[0].sharding.spec == P("dp")
+
+
+# (seq_q, seq_k, block_q, block_k or None for the causal tile, dtype,
+#  SUB_BLOCK or None for the module's)
+_CAUSAL_CASES = [
+    pytest.param(1024, 1024, 1024, 1024, "bfloat16", None,
+                 id="cells-shape-one-tile-bf16"),
+    pytest.param(1024, 1024, 1024, 1024, "float32", None,
+                 id="cells-shape-one-tile-f32"),
+    pytest.param(384, 384, 1024, 1024, "float32", None,
+                 id="s384-padded-to-two-bands"),
+    pytest.param(384, 384, 1024, 1024, "float32", 128,
+                 id="s384-three-bands-of-128"),
+    pytest.param(1000, 1000, 512, 512, "float32", None,
+                 id="s1000-ragged-tail-2x2-tiles"),
+    pytest.param(1000, 1000, 1024, 1024, "bfloat16", None,
+                 id="s1000-one-tile-padded-to-four-bands-bf16"),
+    pytest.param(1100, 1100, None, None, "float32", None,
+                 id="s1100-the-causal-tile-padded-to-five-bands"),
+    pytest.param(200, 200, None, None, "float32", None,
+                 id="s200-shorter-than-a-band-runs-whole"),
+    pytest.param(512, 1024, 512, 1024, "float32", None,
+                 id="sq-lt-sk-offset-512"),
+    pytest.param(640, 1000, 256, 512, "float32", 128,
+                 id="sq-lt-sk-offset-360-ragged"),
+    pytest.param(1024, 512, 1024, 512, "float32", None,
+                 id="sq-gt-sk-512-rows-see-nothing"),
+    pytest.param(600, 300, 512, 512, "float32", 128,
+                 id="sq-gt-sk-ragged-2-q-tiles"),
+    pytest.param(2048, 2048, 512, 512, "bfloat16", None,
+                 id="s2048-4x4-tiles-bf16"),
+    pytest.param(1024, 1024, 256, 512, "float32", 128,
+                 id="s1024-4x2-tiles-of-two-bands"),
+]
+
+
+@pytest.mark.parametrize("sq,sk,bq,bk,dtype,sub", _CAUSAL_CASES)
+def test_causal_forward_and_backward_match_the_reference(
+        monkeypatch, sq, sk, bq, bk, dtype, sub):
+    """The causal kernels run only the part of a tile at or below the
+    diagonal (bands of SUB_BLOCK rows, each against the keys it sees):
+    values and gradients against ``_sdpa_xla`` for one tile and several,
+    ragged tails, both offsets, both dtypes. Rows that see no key
+    (seq_q > seq_k) give zeros and zero gradients; the reference gives
+    them a uniform softmax, so the cotangent is zero there."""
+    from paddle_tpu.nn.functional.flash_attention import _sdpa_xla
+
+    if sub is not None:
+        monkeypatch.setattr(fa, "SUB_BLOCK", sub)
+    d = 64 if sq == sk == 1024 else 32
+    q, k, v = (x.astype(dtype) for x in _rand_qkv(s=sq, t=sk, h=1, d=d))
+    blind = max(sq - sk, 0)
+    g = _rand_qkv(s=sq, h=1, d=d, seed=1)[0].at[:, :blind].set(0.0)
+
+    def vg(attn):
+        return jax.jit(jax.value_and_grad(
+            lambda q, k, v: jnp.sum(attn(q, k, v).astype(jnp.float32) * g),
+            argnums=(0, 1, 2)))
+
+    out = fa.flash_attention_fwd(q, k, v, causal=True, block_q=bq,
+                                 block_k=bk)
+    ref = _sdpa_xla(q, k, v, causal=True)
+    _, grads = vg(lambda q, k, v: fa.flash_attention_fwd(
+        q, k, v, causal=True, block_q=bq, block_k=bk))(q, k, v)
+    _, ref_grads = vg(lambda q, k, v: _sdpa_xla(q, k, v, causal=True))(
+        q, k, v)
+
+    f32 = dtype == "float32"
+    tol = dict(atol=2e-5, rtol=2e-5) if f32 else dict(atol=0.15, rtol=0.1)
+    gtol = dict(atol=5e-5, rtol=5e-4) if f32 else dict(atol=0.15, rtol=0.1)
+    as32 = lambda x: np.asarray(x, np.float32)      # noqa: E731
+    np.testing.assert_array_equal(as32(out)[:, :blind], 0.0)
+    np.testing.assert_array_equal(as32(grads[0])[:, :blind], 0.0)
+    np.testing.assert_allclose(as32(out)[:, blind:], as32(ref)[:, blind:],
+                               **tol)
+    for a, b, name in zip(grads, ref_grads, "qkv"):
+        np.testing.assert_allclose(as32(a), as32(b), **gtol,
+                                   err_msg=f"d{name}")
+
+
+def test_the_three_kernels_keep_the_signatures_the_benchmark_reads():
+    """benchmark/layer_metrics/flash_attn_roofline_pct.py::kernel_kind tells
+    the three Mosaic calls of a trace apart by operands and results: forward
+    3 -> (out, lse f32), dQ 6 -> 1, dK/dV 6 -> 2. A traced run that does not
+    show all three kinds reads the metric None."""
+    import re
+
+    old, fa.INTERPRET = fa.INTERPRET, False
+    try:
+        x = jax.ShapeDtypeStruct((2, 1024, 64), jnp.bfloat16)
+        lowered = jax.jit(jax.value_and_grad(
+            lambda q, k, v: jnp.sum(fa.flash_attention_fwd(
+                q, k, v, causal=True, block_q=1024,
+                block_k=1024).astype(jnp.float32)),
+            argnums=(0, 1, 2))).trace(x.update(shape=(1, 1024, 2, 64)),
+                                      x.update(shape=(1, 1024, 2, 64)),
+                                      x.update(shape=(1, 1024, 2, 64))
+                                      ).lower(lowering_platforms=("tpu",))
+    finally:
+        fa.INTERPRET = old
+    calls = {}
+    for line in lowered.as_text().splitlines():
+        if "@tpu_custom_call" not in line:
+            continue
+        name = re.search(r'kernel_name = "(\w+)"', line).group(1)
+        operands, results = line.rsplit(" : ", 1)[1].split(" -> ")
+        calls[name] = (operands.count("tensor<"),
+                       re.findall(r"x([a-z]+\d+)>", results))
+    assert calls == {"flash_fwd": (3, ["bf16", "f32"]),
+                     "flash_dq": (6, ["bf16"]),
+                     "flash_dkv": (6, ["bf16", "bf16"])}
+
+
+def _brute_force_share(sq, sk, bq, bk, band):
+    """Visited (band x lane-group) cells of every tile over the padded
+    square: a cell runs iff one of its scores is unmasked."""
+    bq, bk = min(bq, max(sq, 8)), min(bk, max(sk, 8))
+    # a tile longer than a band is whole bands, wider than the lanes whole
+    # lane groups; a shorter one is one piece
+    bq = -(-bq // band) * band if bq > band else bq
+    bk = -(-bk // 128) * 128 if bk > 128 else bk
+    sp_q, sp_k = -(-sq // bq) * bq, -(-sk // bk) * bk
+    band, lanes = min(band, bq), min(128, bk)
+    r = np.arange(sp_q)[:, None]
+    c = np.arange(sp_k)[None, :]
+    live = (c <= r + (sk - sq)) & (c < sk)
+    area = 0
+    for q0 in range(0, sp_q, bq):
+        for k0 in range(0, sp_k, bk):
+            for r0 in range(q0, q0 + bq, band):
+                # a band runs keys [k0, last live lane group] in one piece
+                groups = [c0 for c0 in range(k0, k0 + bk, lanes)
+                          if live[r0:r0 + band, c0:c0 + lanes].any()]
+                if groups:
+                    area += band * (groups[-1] + lanes - k0)
+    return area / (sp_q * sp_k)
+
+
+def test_flash_plan_counts_what_the_kernels_run(monkeypatch):
+    for bq, bk in set([(fa.CAUSAL_BLOCK,) * 2] + fa.FWD_TILE_CANDIDATES
+                      + fa.BWD_TILE_CANDIDATES):
+        plan = fa.flash_plan(1024, 1024, True, bq, bk)
+        assert plan["executed_share"] <= 0.63, (bq, bk, plan)
+        assert plan["tiles"] == [1024 // min(bq, 1024), 1024 // min(bk, 1024)]
+        assert plan["sub_block"] == 256
+        assert fa.flash_plan(1024, 1024, False, bq, bk) == {
+            "tiles": plan["tiles"], "sub_block": None, "executed_share": 1.0}
+    for band in (128, 256):
+        monkeypatch.setattr(fa, "SUB_BLOCK", band)
+        for sq, sk, bq, bk in [(1024, 1024, 1024, 1024), (1000, 1000, 512, 512),
+                               (640, 1000, 256, 512), (600, 300, 512, 512),
+                               (1024, 512, 1024, 512), (384, 384, 1024, 1024),
+                               (2048, 2048, 512, 1024), (100, 100, 64, 32),
+                               (2000, 2000, 2048, 2048), (1100, 900, 2048, 2048),
+                               (700, 700, 300, 200), (8192, 8192, 1024, 1024)]:
+            plan = fa.flash_plan(sq, sk, True, bq, bk)
+            assert plan["executed_share"] == pytest.approx(
+                _brute_force_share(sq, sk, bq, bk, band)), (band, sq, sk, bq, bk)
+    monkeypatch.setattr(fa, "SUB_BLOCK", 128)
+    assert fa.flash_plan(1024, 1024, True, 1024, 1024) == {
+        "tiles": [1, 1], "sub_block": 128, "executed_share": 0.5625}
+
+
+def test_no_causal_tile_longer_than_a_band_runs_whole():
+    """A ragged length or a caller's pin never leaves a tile the diagonal
+    crosses in one piece: a 2000 x 2000 tile's f32 scores alone are 16 MB
+    of VMEM. The tile is padded to whole bands and lane groups, and the
+    mask takes the tail."""
+    assert fa._geometry(2000, 2000, 2048, 2048, 256) == (2048, 2048, 2048,
+                                                         2048)
+    assert fa._geometry(1100, 900, 2048, 2048, 256) == (1280, 1024, 1280,
+                                                        1024)
+    assert fa._geometry(700, 700, 300, 200, 256) == (512, 256, 1024, 768)
+    assert fa._geometry(200, 100, 2048, 2048, 256) == (200, 100, 200, 100)
+    for sq, sk, bq, bk in [(2000, 2000, 2048, 2048), (1100, 900, 2048, 2048),
+                           (700, 700, 300, 200), (2047, 4000, 1000, 1000)]:
+        for blocks, _keys, _n in fa._grid_classes(sq, sk, True, bq, bk, 256):
+            assert all(r1 - r0 == 256 and c1 % 128 == 0 and mc0 % 128 == 0
+                       for r0, r1, c1, mc0, _guard in blocks), (sq, sk)
+    # a non-causal call's tiles are clamped to the sequence and no more
+    assert fa._geometry(2000, 2000, 1024, 1024) == (1024, 1024, 2048, 2048)
+    assert fa._geometry(700, 700, 1024, 1024) == (700, 700, 700, 700)
+
+
+def test_a_non_causal_call_runs_one_block_a_tile_whatever_the_band(monkeypatch):
+    """The bands are the causal mask's: a non-causal kernel is traced to
+    the same program at any SUB_BLOCK, two matmuls a forward tile."""
+    x = jax.ShapeDtypeStruct((2, 1024, 64), jnp.bfloat16)
+    lse = jax.ShapeDtypeStruct((2, 1024, 1), jnp.float32)
+
+    def programs():
+        kw = dict(causal=False, scale=0.125, block_q=512, block_k=1024)
+        return (str(jax.make_jaxpr(lambda q, k, v: fa._flash_fwd_bhsd(
+                    q, k, v, **kw))(x, x, x)),
+                str(jax.make_jaxpr(lambda *a: fa._flash_bwd_bhsd(
+                    *a, **kw))(x, x, x, x, lse, x)))
+
+    monkeypatch.setattr(fa, "SUB_BLOCK", 128)
+    fwd, bwd = programs()
+    monkeypatch.setattr(fa, "SUB_BLOCK", 256)
+    assert (fwd, bwd) == programs()
+    assert fwd.count("dot_general") == 2 and bwd.count("dot_general") == 7
+    assert "concatenate" not in fwd + bwd

@@ -288,10 +288,14 @@ class TestAutotune:
         from paddle_tpu.ops.pallas import flash_attention as fa
         assert not at.should_autotune()
         assert fa._tuned_blocks("fwd", 8, 8192, 8192, 128, "float32",
-                                True, 0.1) == (fa.DEFAULT_BLOCK_Q,
-                                               fa.DEFAULT_BLOCK_K)
+                                False, 0.1) == (fa.DEFAULT_BLOCK_Q,
+                                                fa.DEFAULT_BLOCK_K)
         assert fa._tuned_blocks("bwd", 8, 1024, 1024, 128, "float32",
-                                True, 0.1) == (1024, 1024)
+                                False, 0.1) == (1024, 1024)
+        # a causal call's tile is one measured constant, on a TPU too
+        for kind in ("fwd", "bwd"):
+            assert fa._tuned_blocks(kind, 8, 1024, 1024, 64, "bfloat16",
+                                    True, 0.1) == (fa.CAUSAL_BLOCK,) * 2
 
     def test_serving_block_size_default_off_tpu(self):
         from paddle_tpu.inference.serving import _tuned_decode_block_size

@@ -160,6 +160,41 @@ class TestAutotuneFixes:
         msgs = [r.getMessage() for r in caplog.records]
         assert any("ktimings" in m and "ms" in m for m in msgs)
 
+    def test_described_candidates_reach_the_log_and_the_span(
+            self, tmp_path, monkeypatch, caplog):
+        """``describe`` (the flash kernels' plan): beside every time of the
+        log line, and the winner's on the ``autotune:<key>`` span."""
+        import jax.numpy as jnp
+        from paddle_tpu.core import flags
+        from paddle_tpu.observability import trace
+        from paddle_tpu.ops.pallas import autotune as at
+        monkeypatch.setattr(at, "_cache",
+                            at.AutotuneCache(str(tmp_path / "c.json")))
+        old = flags.get_flag("log_level")
+        flags.set_flags({"log_level": 1})
+        lg = logging.getLogger("paddle_tpu.autotune")
+        lg.addHandler(caplog.handler)
+        trace.clear()
+        trace.activate()
+        try:
+            with caplog.at_level(logging.INFO, "paddle_tpu.autotune"):
+                best = at.autotune(
+                    "kplan", [(1, 1), (2, 2)], lambda c, i: jnp.zeros(()),
+                    default=(1, 1), warmup=1, iters=1,
+                    describe=lambda c: {"executed_share": c[0] / 2})
+        finally:
+            trace.deactivate()
+            lg.removeHandler(caplog.handler)
+            flags.set_flags({"log_level": old})
+        (line,) = [r.getMessage() for r in caplog.records
+                   if "kplan" in r.getMessage()]
+        assert "{'executed_share': 0.5}" in line
+        assert "{'executed_share': 1.0}" in line
+        (span,) = [e for e in trace.drain() if e[0] == "autotune:kplan"]
+        assert span[5]["winner"] == str(best)
+        assert span[5]["executed_share"] == best[0] / 2
+        assert span[5]["candidates"] == 2
+
 
 # ------------------------------------------------- Engine regularizer fold
 class TestEngineRegularizerParity:

@@ -148,6 +148,38 @@ def test_traces_inside_a_lowering_are_counted_not_listed(record):
                 and lower[2] <= e[2] and e[3] <= lower[3]]
 
 
+def test_a_kernels_wrapper_stamps_its_plan_on_the_trace_entry(record):
+    """``compile_note`` from code that runs while a program is traced: the
+    flash kernels' wrappers say which tiles and what share of the square
+    the program was built with (``flash_plan``), once a key with a count;
+    outside a trace it is a no-op."""
+    import paddle_tpu.ops.pallas.flash_attention as fa
+
+    trace.compile_note("nobody", {"x": 1})      # no trace in flight
+    q = jnp.ones((1, 512, 2, 32))
+
+    def step(q):
+        attend = lambda q: fa.flash_attention_fwd(  # noqa: E731
+            q, q, q, causal=True, block_q=512, block_k=512)
+        return jax.grad(lambda q: jnp.sum(attend(attend(q))))(q)
+
+    old, fa.INTERPRET = fa.INTERPRET, True
+    try:
+        jax.jit(step).lower(q)
+    finally:
+        fa.INTERPRET = old
+    entries = record()
+    (traced,) = [e for e in named(entries, "compile.trace")
+                 if e[5]["program"] == "step"]
+    plan = dict(fa.flash_plan(512, 512, True, 512, 512))
+    assert plan["executed_share"] == 0.75 and plan["sub_block"] == 256
+    assert traced[5]["flash_fwd[512x512,causal,512x512]"] == dict(
+        plan, calls=2)
+    assert traced[5]["flash_bwd[512x512,causal,512x512]"] == dict(
+        plan, calls=2)
+    assert not [e for e in entries if "nobody" in (e[5] or {})]
+
+
 def test_persistent_cache_outcomes_are_recorded(record, tmp_path):
     from jax.experimental.compilation_cache import compilation_cache as cc
 

@@ -196,3 +196,32 @@ def test_mixed_step_compiles_with_the_kernel_for_the_lanes(one_chip,
     entry = text[text.index("ENTRY"):]
     assert entry.count('custom_call_target="tpu_custom_call"') == layers
     assert not re.findall(rf"= bf16\[{nb},\S+ copy\(", entry)
+
+
+# (causal, S, d, heads): a tile that runs whole has to fit VMEM beside its
+# operands (a non-causal tile; Mosaic refuses 2048 x 2048, 16 MB of f32
+# scores), and a causal tile of any length runs in bands
+@pytest.mark.parametrize("call", [
+    pytest.param((True, 1024, 64, 128), id="causal-the-fit-cells-shape"),
+    pytest.param((True, 1100, 64, 4), id="causal-ragged-1100"),
+    pytest.param((True, 2000, 64, 4), id="causal-ragged-2000"),
+    pytest.param((False, 2048, 64, 4), id="full-2048"),
+    pytest.param((False, 4096, 128, 4), id="full-4096"),
+    pytest.param((False, 1536, 128, 4), id="full-ragged-1536")])
+def test_flash_forward_and_backward_compile_at_their_own_tiles(
+        one_chip, monkeypatch, call):
+    """``flash_attention_fwd`` with no tile pinned, forward and backward,
+    as a model's step calls it: the tiles are the module's (no probe runs
+    without a chip) and Mosaic takes all three kernels."""
+    from paddle_tpu.ops.pallas import flash_attention as fa
+
+    monkeypatch.setattr(fa, "INTERPRET", False)
+    causal, S, d, heads = call
+    x = jax.ShapeDtypeStruct((1, S, heads, d), jnp.bfloat16,
+                             sharding=one_chip)
+    text = jax.jit(jax.value_and_grad(
+        lambda q, k, v: jnp.sum(fa.flash_attention_fwd(
+            q, k, v, causal=causal).astype(jnp.float32)),
+        argnums=(0, 1, 2))).lower(x, x, x).compile().as_text()
+    for kernel in ("flash_fwd", "flash_dq", "flash_dkv"):
+        assert f"%{kernel}" in text

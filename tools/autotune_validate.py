@@ -1,10 +1,13 @@
 """Validate the kernel autotuner on the real chip.
 
-For S in {1k, 2k, 8k, 32k}: time flash fwd and bwd with (a) the hand-tuned
-v5e constants and (b) the autotuner's measured winner, plus the serving
-decode tick block-size probe. Prints a table; the autotuned choice must
-match or beat the constants (VERDICT r4 item 3 'Done' criterion), and the
-cache file must round-trip.
+For S in {1k, 2k, 8k, 32k}, causal and not: time flash fwd and bwd with
+every tile (the constants and the autotuner's candidates), printing beside
+each time what that tile makes the kernels run (``flash_plan``: the grid
+and the executed share of the square), then (a) the hand-tuned v5e
+constants against (b) ``_tuned_blocks``' pick (a causal call's is its
+constant; a non-causal call's the measured winner), plus the serving
+decode tick block-size probe. The pick must match or beat the constants
+(VERDICT r4 item 3 'Done' criterion), and the cache file must round-trip.
 
 Timing discipline: jitted closures only (steady state, no retracing),
 a few distinct inputs cycled across timed calls, and every timed call
@@ -66,44 +69,55 @@ def main():
                 jax.random.fold_in(kp, 2), (bh, S, D)).astype(dt))
         scale = 1.0 / (D ** 0.5)
 
-        kernel_flops = 4.0 * bh * S * S * D * 0.5
-        reps = at.probe_reps(kernel_flops)
+        for causal in (True, False):
+            kernel_flops = 4.0 * bh * S * S * D * (0.5 if causal else 1.0)
+            reps = at.probe_reps(kernel_flops)
 
-        def jfwd(bq, bk):
-            kern = functools.partial(
-                fa._flash_fwd_bhsd, causal=True, scale=scale,
-                block_q=bq, block_k=bk)
-            f = jax.jit(lambda q0, k0, v0: jax.lax.fori_loop(
-                0, reps, lambda _, q: kern(q, k0, v0)[0], q0))
-            return lambda i: f(qs[i % NVAR], ks[i % NVAR], vs[i % NVAR])
+            def jfwd(bq, bk):
+                kern = functools.partial(
+                    fa._flash_fwd_bhsd, causal=causal, scale=scale,
+                    block_q=bq, block_k=bk)
+                f = jax.jit(lambda q0, k0, v0: jax.lax.fori_loop(
+                    0, reps, lambda _, q: kern(q, k0, v0)[0], q0))
+                return lambda i: f(qs[i % NVAR], ks[i % NVAR], vs[i % NVAR])
 
-        # ---------------- forward
-        t_def = timeit(jfwd(fa.DEFAULT_BLOCK_Q, fa.DEFAULT_BLOCK_K))
-        tuned = fa._tuned_blocks("fwd", bh, S, S, D, dt, True, scale)
-        t_tun = timeit(jfwd(*tuned))
-        rows.append(("fwd", S, (fa.DEFAULT_BLOCK_Q, fa.DEFAULT_BLOCK_K),
-                     t_def, tuned, t_tun))
+            fdef = ((fa.CAUSAL_BLOCK,) * 2 if causal else
+                    (fa.DEFAULT_BLOCK_Q, fa.DEFAULT_BLOCK_K))
+            f0 = jax.jit(functools.partial(
+                fa._flash_fwd_bhsd, causal=causal, scale=scale,
+                block_q=fdef[0], block_k=fdef[1]))
+            outs, lses = zip(*(f0(qs[v], ks[v], vs[v]) for v in range(NVAR)))
 
-        # ---------------- backward
-        f0 = jax.jit(functools.partial(
-            fa._flash_fwd_bhsd, causal=True, scale=scale,
-            block_q=fa.DEFAULT_BLOCK_Q, block_k=fa.DEFAULT_BLOCK_K))
-        outs, lses = zip(*(f0(qs[v], ks[v], vs[v]) for v in range(NVAR)))
+            def jbwd(bq, bk):
+                kern = functools.partial(
+                    fa._flash_bwd_bhsd, causal=causal, scale=scale,
+                    block_q=bq, block_k=bk)
+                f = jax.jit(lambda q0, k0, v0, o0, l0: jax.lax.fori_loop(
+                    0, reps, lambda _, q: kern(q, k0, v0, o0, l0, o0)[0], q0))
+                return lambda i: f(qs[i % NVAR], ks[i % NVAR], vs[i % NVAR],
+                                   outs[i % NVAR], lses[i % NVAR])
 
-        def jbwd(bq, bk):
-            kern = functools.partial(
-                fa._flash_bwd_bhsd, causal=True, scale=scale,
-                block_q=bq, block_k=bk)
-            f = jax.jit(lambda q0, k0, v0, o0, l0: jax.lax.fori_loop(
-                0, reps, lambda _, q: kern(q, k0, v0, o0, l0, o0)[0], q0))
-            return lambda i: f(qs[i % NVAR], ks[i % NVAR], vs[i % NVAR],
-                               outs[i % NVAR], lses[i % NVAR])
-
-        bdef = (fa._bwd_block_for(S), fa._bwd_block_for(S))
-        t_def = timeit(jbwd(*bdef))
-        btun = fa._tuned_blocks("bwd", bh, S, S, D, dt, True, scale)
-        t_tun = timeit(jbwd(*btun))
-        rows.append(("bwd", S, bdef, t_def, btun, t_tun))
+            bdef = fdef if causal else (fa._bwd_block_for(S),) * 2
+            for kind, make, cdef, cands in (
+                    ("fwd", jfwd, fdef, fa.FWD_TILE_CANDIDATES),
+                    ("bwd", jbwd, bdef, fa.BWD_TILE_CANDIDATES)):
+                times = {}
+                for c in dict.fromkeys([cdef] + cands):
+                    try:
+                        times[c] = timeit(make(*c))
+                    except Exception as e:  # noqa: BLE001 — the refusal is the datum
+                        print(f"{kind} S={S} causal={causal} tile={c}: "
+                              f"FAILED {type(e).__name__}: {str(e)[:200]}")
+                        continue
+                    plan = fa.flash_plan(S, S, causal, *c)
+                    print(f"{kind} S={S:>6} causal={causal!s:5} "
+                          f"tile={str(c):>12} {times[c] * 1e3:8.2f}m  "
+                          f"grid={plan['tiles']} "
+                          f"executed_share={plan['executed_share']:.4f}")
+                tuned = tuple(fa._tuned_blocks(kind, bh, S, S, D, dt, causal,
+                                               scale))
+                rows.append((kind, S, cdef, times[cdef], tuned,
+                             times[tuned]))
 
     print(f"\n{'pass':4} {'S':>6} {'constants':>12} {'t_const':>9} "
           f"{'tuned':>12} {'t_tuned':>9} {'speedup':>8}")
