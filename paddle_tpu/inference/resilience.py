@@ -307,8 +307,8 @@ M_KV_BYTES_PER_TOKEN = _metrics.gauge(
 M_STATE_BYTES = _metrics.gauge(
     "paddle_tpu_serving_state_bytes",
     "Resident bytes of the per-slot recurrent state (convolution windows, "
-    "SSM states) reserved for max_batch slots; 0 for a pure-attention "
-    "model.")
+    "SSM states, delta-rule matrix states) reserved for max_batch slots; "
+    "0 for a pure-attention model.")
 M_WINDOW_BYTES = _metrics.gauge(
     "paddle_tpu_serving_window_bytes",
     "Resident bytes of the sliding-window layers' K/V rows (window plus one "

@@ -56,8 +56,12 @@ free list, and admission/eviction is plain Python between ticks:
   table; these two kinds alone have page pools, so only they make a block
   cost bytes), a fixed ring of K/V rows a slot (``window_kv``:
   sliding-window layers, ``window + prefill_width`` rows whatever the
-  context), a recurrent state a slot (``slot_state``), a device-side
-  counter (``accumulator``); Llama and GPT keep K/V pages in every layer;
+  context), a recurrent state a slot (``slot_state``: arrays of any
+  shape and dtype behind the slot axis, a convolution window beside an SSM
+  state, or beside a delta-rule layer's float32 matrix a head, megabytes
+  a lane, which its layer zeroes and keeps itself in the one visit it
+  makes: ``recur(..., masks=True)``), a device-side counter
+  (``accumulator``); Llama and GPT keep K/V pages in every layer;
 * K/V pages are stored in the model's compute dtype, or as an int8 page
   pool with sidecar per-(position, head) scales (``kv_dtype="int8"`` —
   the ``nn/quant`` weight-only pattern applied to KV), halving resident
@@ -513,12 +517,16 @@ class _PagedCache:
       rules: rows a lane's own sequence did not write are never seen, left
       padding and the ``seq = 0`` sentinel lanes write nothing.
     * ``recur(li, fn, *rows)`` — per-SLOT state that does not grow with the
-      sequence (a convolution window, an SSM state): ``fn(state, *rows) ->
-      (out, new state)`` runs on the lanes' states and their rows of each
-      of ``rows``. A lane whose chunk starts a sequence (``start <= 0``)
-      starts from zeros; a lane with no real row (the ``seq = 0`` sentinel
-      of mid-prefill and memory-stalled lanes) gets its state back bit for
-      bit.
+      sequence (a convolution window, an SSM state, a delta-rule matrix):
+      ``fn(state, *rows) -> (out, new state)`` runs on the lanes' states
+      and their rows of each of ``rows``. A lane whose chunk starts a
+      sequence (``start <= 0``) starts from zeros; a lane with no real row
+      (the ``seq = 0`` sentinel of mid-prefill and memory-stalled lanes)
+      gets its state back bit for bit. With ``masks=True`` the handle
+      leaves those two rules to ``fn(state, *rows, fresh, idle)`` ((lanes,)
+      bool each): a state of megabytes a lane is then zeroed and kept
+      inside the one visit ``fn`` makes, where the handle's own ``where``
+      before and after would each be another pass over it.
     * ``accumulate(li, delta)`` — a device-side counter carried with the
       caches (expert load), read by the host only on request.
 
@@ -680,7 +688,7 @@ class _PagedCache:
                             g.lanes)
         return out
 
-    def recur(self, li, fn, *rows):
+    def recur(self, li, fn, *rows, masks=False):
         at = self.index[li, "slot_state"]
 
         def per_lane(flag, v):
@@ -690,6 +698,11 @@ class _PagedCache:
             whole = dict(self.states[at])      # {name: (max_batch, ...)}
             mine = self._lane_rows(whole, g.lanes)
             fresh = g.start <= 0
+            if masks:       # fn zeroes and keeps as it visits the state
+                idle = ~jnp.any(g.positions >= 0, axis=1)
+                out, new = fn(mine, *rows, fresh, idle)
+                self._put_lane_rows(at, whole, new, g.lanes)
+                return out
             out, new = fn({k: jnp.where(per_lane(fresh, v),
                                         jnp.zeros_like(v), v)
                            for k, v in mine.items()}, *rows)

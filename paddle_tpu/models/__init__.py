@@ -18,6 +18,8 @@ from .exaone_moe import (ExaoneMoeConfig, ExaoneMoeForCausalLM,
                          ExaoneMoeModel, exaone_moe_tiny)
 from .deepseek_v3 import (DeepseekV3Config, DeepseekV3ForCausalLM,
                           DeepseekV3Model, deepseek_v3_tiny)
+from .olmo_hybrid import (OlmoHybridConfig, OlmoHybridForCausalLM,
+                          OlmoHybridModel, olmo_hybrid_tiny)
 
 __all__ = [
     "LeNet", "GPTConfig", "GPTModel", "GPTForCausalLM",
@@ -33,4 +35,6 @@ __all__ = [
     "exaone_moe_tiny",
     "DeepseekV3Config", "DeepseekV3Model", "DeepseekV3ForCausalLM",
     "deepseek_v3_tiny",
+    "OlmoHybridConfig", "OlmoHybridModel", "OlmoHybridForCausalLM",
+    "olmo_hybrid_tiny",
 ]

@@ -14,8 +14,10 @@ from .paged_attention import *  # noqa: F401,F403
 from .fused import *       # noqa: F401,F403
 from .tail import *        # noqa: F401,F403
 from .ssm import *         # noqa: F401,F403
+from .delta_rule import *  # noqa: F401,F403
 from .experts import *     # noqa: F401,F403
 from ...ops.search import class_center_sample, gather_tree  # noqa: F401
 
-from . import (activation, common, conv, experts, flash_attention, fused,
-               loss, norm, paged_attention, pooling, ssm, tail, vision)
+from . import (activation, common, conv, delta_rule, experts,
+               flash_attention, fused, loss, norm, paged_attention, pooling,
+               ssm, tail, vision)

@@ -309,9 +309,13 @@ def _register_all():
     register_module(_vis, "vision")
     from ..nn.functional import paged_attention as _paged
     register_module(_paged, "attention")
-    from ..nn.functional import experts as _experts, ssm as _ssm
+    from ..nn.functional import (delta_rule as _delta, experts as _experts,
+                                 ssm as _ssm)
     register_module(_ssm, "nn_common")
     register_module(_experts, "nn_common")
+    # the three ops; the packed state's helpers are plain functions
+    register_module(_delta, "nn_common",
+                    skip=("heads_packed", "pack_state", "unpack_state"))
     from ..vision import ops as _vops
     register_module(_vops, "vision")
     from .. import geometric as _geo

@@ -67,15 +67,24 @@ INTERPRET = False
 CHUNK_ROWS = 2048
 
 
+def _sublanes(dtype) -> int:
+    """Rows of a vector register's tile of ``dtype``."""
+    return 8 * 4 // jnp.dtype(dtype).itemsize
+
+
 def supports(q_shape, q_dtype, cache_shape, cache_dtype,
              value_dim=None) -> bool:
     """Whether the kernel was written for these shapes: one query a lane,
     float pages of the queries' dtype, lane-dense heads (D a multiple of
-    128) and pages and query blocks that fill whole sublane tiles. The
-    pages are a K pool beside a V pool of the same shape, ``(num_blocks,
-    block_size, KVH, D)``, or with ``value_dim`` ONE pool ``(num_blocks,
-    block_size, D)`` whose rows are a token's key and, in their first
-    ``value_dim`` columns (whole lane tiles, at most D), its value."""
+    128) and pages that fill whole sublane tiles. The query heads may be
+    any multiple of the K/V heads, a group of one included (``H == KVH``):
+    a count that is no whole sublane tile (30) is padded to one in the
+    query block (32), and the pad rows see no K/V row and are never
+    written out. The pages are a K pool beside a V pool of the same shape,
+    ``(num_blocks, block_size, KVH, D)``, or with ``value_dim`` ONE pool
+    ``(num_blocks, block_size, D)`` whose rows are a token's key and, in
+    their first ``value_dim`` columns (whole lane tiles, at most D), its
+    value. K/V heads fill whole sublane tiles or divide one."""
     _b, t, h, d = q_shape
     if value_dim is None:
         _nb, bs, kvh, dc = cache_shape
@@ -88,12 +97,17 @@ def supports(q_shape, q_dtype, cache_shape, cache_dtype,
         return False
     if dt not in (jnp.dtype(jnp.bfloat16), jnp.dtype(jnp.float32)):
         return False
-    sublanes = 8 * 4 // dt.itemsize
-    return d % 128 == 0 and (bs * kvh) % sublanes == 0 and h % sublanes == 0
+    # a pool is read as (num_blocks, block_size * KVH, D): that view is the
+    # same bytes only where KVH fills whole sublane tiles or divides one (30
+    # heads of bfloat16 are laid out in 32, and the view would be a copy of
+    # the pool every step: the caller pads such a pool's heads itself)
+    tile = _sublanes(dt)
+    return (d % 128 == 0 and (bs * kvh) % tile == 0
+            and (kvh % tile == 0 or tile % kvh == 0))
 
 
 def _kernel(tables_ref, lens_ref, q_ref, *refs,
-            block_size, kv_heads, pages_per_chunk, scale, value_dim):
+            block_size, kv_heads, group, pages_per_chunk, scale, value_dim):
     # ``value_dim`` None: a K pool and a V pool, a buffer each; else one
     # pool and one buffer, read as keys whole and as values in its first
     # ``value_dim`` lanes
@@ -103,11 +117,12 @@ def _kernel(tables_ref, lens_ref, q_ref, *refs,
     else:
         k_hbm, o_ref, kbuf, sems, m_scr, l_scr, acc_scr = refs
         v_hbm = vbuf = None
+    # ``heads`` counts the query block's rows, pad rows included: a pad
+    # row's ``head // group`` is no K/V head, so it sees nothing
     lanes, heads, _d = q_ref.shape
     page_rows = block_size * kv_heads
     chunk_rows = pages_per_chunk * page_rows
     chunk_tokens = pages_per_chunk * block_size
-    group = heads // kv_heads
     exact = q_ref.dtype == jnp.float32
     precision = jax.lax.Precision.HIGHEST if exact else None
 
@@ -248,6 +263,7 @@ def paged_decode_attention(q, key_cache, value_cache, block_tables, seq_lens,
     ``q``'s dtype. ``supports`` says which shapes.
     """
     b, _t, h, d = q.shape
+    hp = -(-h // _sublanes(q.dtype)) * _sublanes(q.dtype)
     shared = value_cache is None
     nb, bs = key_cache.shape[:2]
     kvh = 1 if shared else key_cache.shape[2]
@@ -258,33 +274,36 @@ def paged_decode_attention(q, key_cache, value_cache, block_tables, seq_lens,
     chunk_rows = pages_per_chunk * page_rows
     sc = scale if scale is not None else 1.0 / (d ** 0.5)
     kernel = functools.partial(
-        _kernel, block_size=bs, kv_heads=kvh,
+        _kernel, block_size=bs, kv_heads=kvh, group=h // kvh,
         pages_per_chunk=pages_per_chunk, scale=sc,
         value_dim=value_dim if shared else None)
     pools = [key_cache.reshape(nb, page_rows, d)]
     if not shared:
         pools.append(value_cache.reshape(nb, page_rows, d))
+    rows = q.reshape(b, h, d)
+    if hp != h:
+        rows = jnp.pad(rows, ((0, 0), (0, hp - h), (0, 0)))
     out = pl.pallas_call(
         kernel,
-        out_shape=jax.ShapeDtypeStruct((b, h, dv), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, hp, dv), q.dtype),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(1,),
-            in_specs=[pl.BlockSpec((b, h, d), lambda i, *_: (0, 0, 0))]
+            in_specs=[pl.BlockSpec((b, hp, d), lambda i, *_: (0, 0, 0))]
             + [pl.BlockSpec(memory_space=pl.ANY)] * len(pools),
-            out_specs=pl.BlockSpec((b, h, dv), lambda i, *_: (0, 0, 0)),
+            out_specs=pl.BlockSpec((b, hp, dv), lambda i, *_: (0, 0, 0)),
             scratch_shapes=[
                 pltpu.VMEM((2, chunk_rows, d), pool.dtype) for pool in pools
             ] + [
                 pltpu.SemaphoreType.DMA((2, 2)),
-                pltpu.VMEM((h, 1), jnp.float32),
-                pltpu.VMEM((h, 1), jnp.float32),
-                pltpu.VMEM((h, dv), jnp.float32),
+                pltpu.VMEM((hp, 1), jnp.float32),
+                pltpu.VMEM((hp, 1), jnp.float32),
+                pltpu.VMEM((hp, dv), jnp.float32),
             ]),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=INTERPRET,
         name="paged_decode_attn",
     )(block_tables.astype(jnp.int32), seq_lens.astype(jnp.int32),
-      q.reshape(b, h, d), *pools)
-    return out.reshape(b, 1, h, dv)
+      rows, *pools)
+    return (out[:, :h] if hp != h else out).reshape(b, 1, h, dv)

@@ -1,0 +1,267 @@
+"""The gated delta rule (``nn.functional.delta_rule``) against the token-by-
+token loop in float64: the chunked form, the one-token step applied T times
+and the loop agree to float32 rounding, from a zero and from a carried
+state, with ragged ``valid`` rows, at ``beta`` up to 2; the packed state and
+the decode kernel (``ops.pallas.delta_rule``, in interpret mode) are the
+same function.
+
+Tolerances: ``TIGHT`` 2e-5 absolute on values of order 1. Everything is
+float32 at ``highest`` precision; what differs is the order of a few hundred
+float32 additions (a triangular solve and four products a chunk against one
+rank-one update a token), not a precision.
+"""
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+import paddle_tpu as paddle
+import paddle_tpu.nn.functional as F
+from paddle_tpu.nn.functional import delta_rule as dr
+from paddle_tpu.nn.functional import ssm
+from paddle_tpu.ops.pallas import delta_rule as kernel
+
+TIGHT = 2e-5
+B, H, DK, DV = 2, 4, 12, 32
+
+
+def inputs(tokens, seed=0, beta_max=2.0):
+    rng = np.random.default_rng(seed)
+    q = rng.normal(size=(B, tokens, H, DK))
+    k = rng.normal(size=(B, tokens, H, DK))
+    v = rng.normal(size=(B, tokens, H, DV))
+    q /= np.linalg.norm(q, axis=-1, keepdims=True) * np.sqrt(DK)
+    k /= np.linalg.norm(k, axis=-1, keepdims=True)
+    alpha_log = -np.exp(rng.normal(size=(B, tokens, H))) * 0.3
+    # up to the bound: a tenth of the rows sit AT beta_max
+    beta = beta_max / (1 + np.exp(-rng.normal(size=(B, tokens, H)) * 3))
+    beta[rng.uniform(size=beta.shape) < 0.1] = beta_max
+    return q, k, v, alpha_log, beta
+
+
+def loop(q, k, v, alpha_log, beta, state, valid):
+    """The recurrence as written, a token and a head at a time, float64."""
+    s = np.array(state, np.float64)
+    out = np.zeros(v.shape)
+    for t in range(q.shape[1]):
+        for b in range(q.shape[0]):
+            if not valid[b, t]:
+                continue
+            for h in range(q.shape[2]):
+                decayed = np.exp(alpha_log[b, t, h]) * s[b, h]
+                u = beta[b, t, h] * (v[b, t, h] - decayed.T @ k[b, t, h])
+                s[b, h] = decayed + np.outer(k[b, t, h], u)
+                out[b, t, h] = s[b, h].T @ q[b, t, h]
+    return out, s
+
+
+def close(got, want, atol=TIGHT):
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=atol,
+                               rtol=0)
+
+
+def ragged(tokens, kind):
+    valid = np.ones((B, tokens), bool)
+    if kind == "left":          # left padding of a first chunk
+        valid[0, :min(5, tokens - 1)] = False
+    elif kind == "right":       # a last sub-chunk that is not full
+        valid[1, max(1, tokens - 7):] = False
+    elif kind == "none":        # a lane with no real row at all
+        valid[0] = False
+    return valid
+
+
+@pytest.mark.parametrize("carried", [False, True], ids=["zero", "carried"])
+@pytest.mark.parametrize("kind", ["all", "left", "right", "none"])
+@pytest.mark.parametrize("tokens,chunk", [(1, 64), (7, 4), (64, 64),
+                                          (150, 64), (33, 8)])
+def test_chunked_form_is_the_token_loop(tokens, chunk, kind, carried):
+    q, k, v, alpha_log, beta = inputs(tokens, seed=tokens)
+    state = (np.random.default_rng(1).normal(size=(B, H, DK, DV))
+             if carried else np.zeros((B, H, DK, DV)))
+    valid = ragged(tokens, kind)
+    want_o, want_s = loop(q, k, v, alpha_log, beta, state, valid)
+    got_o, got_s = dr.chunk_arrays(
+        *map(jnp.asarray, (q, k, v, alpha_log, beta, state)),
+        jnp.asarray(valid), chunk)
+    live = valid[:, :, None, None]
+    close(np.where(live, got_o, 0.0), np.where(live, want_o, 0.0))
+    close(got_s, want_s)
+    if kind == "none":      # no valid row: the state back bit for bit
+        assert np.array_equal(np.asarray(got_s[0]),
+                              np.asarray(state[0], np.float32))
+
+
+@pytest.mark.parametrize("packed", [None, 4], ids=["plain", "packed"])
+@pytest.mark.parametrize("carried", [False, True], ids=["zero", "carried"])
+def test_step_applied_t_times_is_the_token_loop(carried, packed):
+    tokens = 40
+    q, k, v, alpha_log, beta = inputs(tokens, seed=3)
+    state = (np.random.default_rng(2).normal(size=(B, H, DK, DV))
+             if carried else np.zeros((B, H, DK, DV)))
+    valid = ragged(tokens, "right")
+    want_o, want_s = loop(q, k, v, alpha_log, beta, state, valid)
+    s = jnp.asarray(state, jnp.float32)
+    if packed:
+        assert dr.heads_packed(H, DV) == packed
+        s = dr.pack_state(s, packed)
+    step = jax.jit(lambda *a: dr.step_arrays(*a, packed=packed))
+    outs = []
+    for t in range(tokens):
+        o, s = step(*(jnp.asarray(a[:, t]) for a in (q, k, v)),
+                    jnp.exp(jnp.asarray(alpha_log[:, t])),
+                    jnp.asarray(beta[:, t]), s, None,
+                    jnp.asarray(~valid[:, t]))
+        outs.append(o)
+    if packed:
+        s = dr.unpack_state(s, packed)
+    live = valid[:, :, None, None]
+    close(np.where(live, np.stack(outs, 1), 0.0), np.where(live, want_o, 0.0))
+    close(s, want_s)
+
+
+@pytest.mark.parametrize("cuts", [(11,), (64, 128), (1, 2, 3), (70, 71)])
+def test_a_chunk_continues_from_the_carried_state(cuts):
+    tokens = 150
+    q, k, v, alpha_log, beta = map(jnp.asarray, inputs(tokens, seed=9))
+    valid = jnp.ones((B, tokens), bool)
+    zero = jnp.zeros((B, H, DK, DV))
+    whole_o, whole_s = dr.chunk_arrays(q, k, v, alpha_log, beta, zero, valid,
+                                       64)
+    s, parts = zero, []
+    for lo, hi in zip((0,) + cuts, cuts + (tokens,)):
+        o, s = dr.chunk_arrays(*(a[:, lo:hi] for a in
+                                 (q, k, v, alpha_log, beta)), s,
+                               valid[:, lo:hi], 64)
+        parts.append(o)
+    close(jnp.concatenate(parts, axis=1), whole_o)
+    close(s, whole_s)
+
+
+def test_pack_and_unpack_are_inverse_and_whole_lane_tiles():
+    assert dr.heads_packed(30, 192) == 2        # the published sizes
+    assert dr.heads_packed(6, 64) == 2 and dr.heads_packed(4, 32) == 4
+    assert dr.heads_packed(8, 128) == 1 and dr.heads_packed(3, 100) == 1
+    s = jnp.asarray(np.random.default_rng(0).normal(size=(3, 6, 8, 64)),
+                    jnp.float32)
+    packed = dr.pack_state(s, 2)
+    assert packed.shape == (3, 3, 8, 128)
+    # heads 2g and 2g + 1 side by side in row g
+    assert np.array_equal(np.asarray(packed[1, 2, :, 64:]),
+                          np.asarray(s[1, 5]))
+    assert np.array_equal(np.asarray(dr.unpack_state(packed, 2)),
+                          np.asarray(s))
+
+
+# -------------------------------------------------------------- the kernel
+@pytest.fixture
+def interpreted(monkeypatch):
+    monkeypatch.setattr(kernel, "INTERPRET", True)
+
+
+@pytest.mark.parametrize("shape", [(3, 4, 16, 64), (2, 6, 8, 64),
+                                   (5, 30, 96, 192), (2, 8, 16, 128)],
+                         ids=["p2", "tiny-model", "published", "p1"])
+def test_decode_kernel_is_the_composite(interpreted, shape):
+    """``delta_rule_step`` in interpret mode equals ``step_arrays`` on the
+    packed state: a fresh lane starts from zeros whatever its slot held, an
+    idle lane gets its state back bit for bit."""
+    bsz, h, dk, dv = shape
+    p = dr.heads_packed(h, dv)
+    rng = np.random.default_rng(7)
+    q, k = (jnp.asarray(a / np.linalg.norm(a, axis=-1, keepdims=True),
+                        jnp.float32)
+            for a in rng.normal(size=(2, bsz, h, dk)))
+    v = jnp.asarray(rng.normal(size=(bsz, h, dv)), jnp.float32)
+    alpha = jnp.asarray(rng.uniform(0.5, 1, size=(bsz, h)), jnp.float32)
+    beta = jnp.asarray(rng.uniform(0, 2, size=(bsz, h)), jnp.float32)
+    state = dr.pack_state(jnp.asarray(
+        rng.normal(size=(bsz, h, dk, dv)) / np.sqrt(dk), jnp.float32), p)
+    fresh = jnp.zeros((bsz,), bool).at[0].set(True)
+    idle = jnp.zeros((bsz,), bool).at[bsz - 1].set(True)
+    assert kernel.supports(state.shape, dk, p)
+    assert dr.use_step_kernel(state.shape, dk, p)
+    want_o, want_s = dr.step_arrays(q, k, v, alpha, beta, state, fresh, idle,
+                                    p)
+    got_o, got_s = kernel.delta_rule_step(q, k, v, alpha, beta, state, fresh,
+                                          idle, p)
+    close(got_o[:-1], want_o[:-1])          # an idle lane's row means nothing
+    close(got_s, want_s)
+    assert np.array_equal(np.asarray(got_s[-1]), np.asarray(state[-1]))
+    # the fresh lane: as from a zero state
+    zero_o, zero_s = dr.step_arrays(q[:1], k[:1], v[:1], alpha[:1], beta[:1],
+                                    jnp.zeros_like(state[:1]), packed=p)
+    close(got_o[0], zero_o[0])
+    close(got_s[0], zero_s[0])
+
+
+def test_kernel_refuses_what_it_was_not_written_for(interpreted):
+    assert not kernel.supports((4, 30, 96, 192), 96, 1)    # 192: no lane tile
+    assert not kernel.supports((4, 15, 96, 384), 64, 2)    # another d_k
+    assert not kernel.supports((4, 15, 12, 384), 12, 2)    # no sublane tile
+    assert not kernel.supports((4, 15, 96, 384), 96, 0)    # not packed
+    # and the functional falls back to the composite there
+    assert not dr.use_step_kernel((4, 30, 96, 192), 96, None)
+    assert kernel.rows_per_block(15, 96, 384, 2) in (1, 3, 5, 15)
+
+
+def test_without_a_tpu_or_the_switch_the_step_is_the_composite():
+    assert not dr.use_step_kernel((4, 15, 96, 384), 96, 2)
+
+
+# ------------------------------------------------------- the small pieces
+def test_gated_norm_norms_first_then_gates():
+    rng = np.random.default_rng(0)
+    o = rng.normal(size=(2, 5, 4, 32)) * 3
+    gate = rng.normal(size=(2, 5, 4, 32))
+    w = rng.uniform(0.5, 1.5, size=32)
+    got = F.gated_rms_norm(paddle.to_tensor(o.astype(np.float32)),
+                           paddle.to_tensor(gate.astype(np.float32)),
+                           paddle.to_tensor(w.astype(np.float32)),
+                           epsilon=1e-6)
+    normed = o / np.sqrt((o * o).mean(-1, keepdims=True) + 1e-6) * w
+    want = normed * gate / (1 + np.exp(-gate))
+    close(got._data, want)
+    # ssm's gates first: another function
+    other = ssm.gated_norm_arrays(jnp.asarray(o, jnp.float32),
+                                  jnp.asarray(gate, jnp.float32),
+                                  jnp.asarray(w, jnp.float32), 4, 1e-6)
+    assert np.abs(np.asarray(other).reshape(want.shape) - want).max() > 0.1
+
+
+@pytest.mark.parametrize("bsz", [1, 3])
+def test_convolution_carries_its_window(bsz):
+    """One sequence runs as (T, C), several as (B, T, C): both are
+    ``ssm.conv_arrays`` with a zero bias, and a cut sequence continues from
+    the window."""
+    rng = np.random.default_rng(1)
+    x = jnp.asarray(rng.normal(size=(bsz, 20, 24)), jnp.float32)
+    w = jnp.asarray(rng.uniform(-0.5, 0.5, size=(24, 4)), jnp.float32)
+    window = jnp.asarray(rng.normal(size=(bsz, 3, 24)), jnp.float32)
+    got, last = dr.conv_arrays(x, w, window)
+    want, want_last = ssm.conv_arrays(x, w, jnp.zeros((24,)), window)
+    close(got, want)
+    assert np.array_equal(np.asarray(last), np.asarray(want_last))
+    first, mid = dr.conv_arrays(x[:, :9], w, window)
+    second, end = dr.conv_arrays(x[:, 9:], w, mid)
+    close(jnp.concatenate([first, second], axis=1), want)
+    assert np.array_equal(np.asarray(end), np.asarray(want_last))
+
+
+def test_op_wrappers_take_tensors_and_default_the_state():
+    q, k, v, alpha_log, beta = inputs(9, seed=4)
+    t = [paddle.to_tensor(a.astype(np.float32))
+         for a in (q, k, v, alpha_log, beta)]
+    o, s = F.gated_delta_chunk(*t, chunk_size=4)
+    want_o, want_s = loop(q, k, v, alpha_log, beta,
+                          np.zeros((B, H, DK, DV)), np.ones((B, 9), bool))
+    close(o._data, want_o)
+    close(s._data, want_s)
+    o1, s1 = F.gated_delta_step(
+        *(paddle.to_tensor(a[:, 0].astype(np.float32)) for a in (q, k, v)),
+        paddle.to_tensor(np.exp(alpha_log[:, 0]).astype(np.float32)),
+        paddle.to_tensor(beta[:, 0].astype(np.float32)),
+        paddle.to_tensor(np.zeros((B, H, DK, DV), np.float32)))
+    close(o1._data, want_o[:, 0])

@@ -671,6 +671,10 @@ SKIP = {
        for n in ("causal_conv1d", "ssd_chunk_scan", "ssd_state_update",
                  "gated_group_rms_norm", "sigmoid_topk_route",
                  "held_experts_relu2")},
+    **{n: "compared with the token-by-token loop in "
+          "tests/test_delta_rule.py (carried matrix state: no elementwise "
+          "sweep contract)"
+       for n in ("gated_delta_chunk", "gated_delta_step", "gated_rms_norm")},
     "held_experts_swiglu":
         "routing table in, no elementwise sweep contract; compared with "
         "the plain K-EXAONE reference in tests/test_exaone_moe.py",

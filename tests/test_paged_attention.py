@@ -236,9 +236,15 @@ class TestPagedAttention:
 # --------------------------------------------------------------------------
 from paddle_tpu.ops.pallas import paged_attention as PK  # noqa: E402
 
-# (H, KVH): Mistral's grouping (group 4) and the hybrid's (group 16)
+# (H, KVH): Mistral's grouping (group 4), the hybrid's (group 16), a group
+# of ONE (multi-head attention: the Olmo-Hybrid adapter's 30 heads a side,
+# padded to a whole tile of 32 in the pages) and a head count that is no
+# whole sublane tile (6: the query block is padded to 8 or 16, the pad rows
+# see no K/V row and are never written out)
 GROUPINGS = [pytest.param((32, 8), id="g4-kvh8"),
-             pytest.param((32, 2), id="g16-kvh2")]
+             pytest.param((32, 2), id="g16-kvh2"),
+             pytest.param((32, 32), id="g1-kvh32"),
+             pytest.param((6, 2), id="g3-h6")]
 
 
 @pytest.fixture
@@ -390,7 +396,8 @@ class TestDecodeKernel:
         ("head-dim-64", False), ("block-4", False),
         ("pools-of-another-dtype", False), ("no-tpu-no-interpreter", False),
         ("pallas-kernels-off", False), ("pools-over-two-devices", False),
-        ("manual-over-two-devices", True)])
+        ("manual-over-two-devices", True), ("group-of-one", True),
+        ("heads-no-whole-tile", True), ("kv-heads-no-whole-tile", False)])
     def test_dispatch_rule(self, monkeypatch, case, kernel):
         """What the call can see decides: its shapes, dtypes and backend,
         and, where it is lowered, whether the compiler would have to
@@ -416,6 +423,14 @@ class TestDecodeKernel:
             pool_dt = jnp.bfloat16
         elif case == "pallas-kernels-off":
             flags.set_flags({"use_pallas_kernels": False})
+        elif case == "group-of-one":
+            H = KVH = 16
+        elif case == "heads-no-whole-tile":
+            H, KVH = 30, 2              # the query block is padded to 32
+        elif case == "kv-heads-no-whole-tile":
+            # 30 K/V heads are laid out in 32: reading the pool as page rows
+            # would copy it every step, so the caller pads such a pool
+            H = KVH = 30
         nb = B * mb + 1
         q = jnp.ones((B, T, H, D), dt)
         kc = jnp.zeros((nb, bs, KVH, D), pool_dt)
@@ -453,6 +468,34 @@ class TestDecodeKernel:
         finally:
             if case == "pallas-kernels-off":
                 flags.set_flags({"use_pallas_kernels": True})
+
+    @pytest.mark.parametrize("dtype,atol", [("float32", 2e-5),
+                                            ("bfloat16", 2e-2)])
+    def test_thirty_heads_a_side_through_pages_of_thirty_two(
+            self, monkeypatch, dtype, atol):
+        """Multi-head attention at 30 query and 30 K/V heads: 30 K/V heads
+        are no whole sublane tile, so the caller keeps pages of 32 (two of
+        zeros, q's pad heads zeros too) and drops the pad heads' output. The
+        kernel over the padded pools equals the composite over the 30-head
+        pools it stands for."""
+        H, pad, mb = 30, 2, 6
+        lens = [1, 16, 17, 0, 70]
+        q, kc, vc, tables, nk, nv, _ = _decode_case(
+            np.random.RandomState(15), lens, H, H, mb)
+        want = _decode(q, kc, vc, tables, lens, nk, nv, dtype)[0]
+        wider = lambda a: np.pad(  # noqa: E731
+            a, [(0, 0)] * (a.ndim - 2) + [(0, pad), (0, 0)])
+        monkeypatch.setattr(PK, "INTERPRET", True)
+        args = [wider(a) for a in (q, kc, vc)] + [tables, lens] \
+            + [wider(a) for a in (nk, nv)]
+        import jax.numpy as jnp
+        assert PK.supports((5, 1, 32, 128), jnp.dtype(dtype),
+                           (kc.shape[0], 16, 32, 128), jnp.dtype(dtype))
+        assert not PK.supports((5, 1, 30, 128), jnp.dtype(dtype),
+                               (kc.shape[0], 16, 30, 128), jnp.dtype(dtype))
+        got = _decode(*args, dtype)[0]
+        np.testing.assert_allclose(got[:, :, :H], want, atol=atol)
+        np.testing.assert_array_equal(got[3], 0.0)
 
     def test_differentiated_call_gets_the_composites_gradient(
             self, monkeypatch):

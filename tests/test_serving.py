@@ -614,3 +614,82 @@ class TestLatentPages:
         assert serve(eng) == want
         assert eng.health()["decode_attention"] == "kernel"
         assert eng.health()["latent_bytes"] == 3 * 24 * 8 * 256 * 4
+
+
+class TestMatrixSlotState:
+    """A state of megabytes a lane (a delta-rule matrix a head) under
+    ``slot_state``: the handle can leave a fresh lane's zeroing and an idle
+    lane's keeping to the function that visits the state."""
+
+    @pytest.mark.parametrize("lanes", [None, [5, 127, 0]],
+                             ids=["every-lane", "three-of-128"])
+    @pytest.mark.parametrize("masks", [False, True])
+    def test_recur_hands_fresh_and_idle_to_the_function_at_128_lanes(
+            self, lanes, masks):
+        """``recur(..., masks=True)``: ``fn`` gets the lanes' states as they
+        lie (no ``where`` over them before or after) and (lanes,) ``fresh``
+        / ``idle`` flags, and what it returns is written as it is; without
+        ``masks`` the handle applies both rules itself. 128 slots: the
+        per-lane slices and writes at a batch no cell ran before."""
+        import jax.numpy as jnp
+        from paddle_tpu.inference import serving
+        slots = 128
+        state = {"s": jnp.arange(slots * 6, dtype=jnp.float32).reshape(
+            slots, 2, 3) + 1.0}
+        mine = list(range(slots)) if lanes is None else lanes
+        rows = len(mine)
+        # lane 0 of the group idle (seq = 0), lane 1 first token, rest later
+        start = jnp.asarray(([-1, 0] + [5] * rows)[:rows], jnp.int32)
+        cache = serving._PagedCache(
+            {0: ("slot_state", 0)}, [], [], [dict(state)],
+            jnp.zeros((rows, 1), jnp.int32), start + 1, start,
+            None if lanes is None else jnp.asarray(lanes, jnp.int32), 1)
+        seen = {}
+
+        def fn(st, *flags):
+            seen["in"], seen["flags"] = st["s"], flags
+            return jnp.zeros(()), {"s": st["s"] + 100.0}
+
+        cache.recur(0, fn, masks=masks)
+        got = np.asarray(cache.states[0]["s"])
+        before = np.asarray(state["s"])
+        if masks:
+            fresh, idle = (np.asarray(f) for f in seen["flags"])
+            # the sentinel lane is "fresh" too (start -1): fn sorts it out
+            assert fresh.tolist() == ([True, True] + [False] * rows)[:rows]
+            assert idle.tolist() == ([True] + [False] * rows)[:rows]
+            assert (np.asarray(seen["in"]) == before[mine]).all()
+            assert (got[mine] == before[mine] + 100.0).all()
+        else:
+            assert seen["flags"] == ()
+            assert (got[mine[0]] == before[mine[0]]).all()          # kept
+            assert (np.asarray(seen["in"])[1] == 0).all()           # reset
+            assert (got[mine[1]] == 100.0).all()
+            assert (got[mine[2:]] == before[mine[2:]] + 100.0).all()
+        others = sorted(set(range(slots)) - set(mine))
+        assert (got[others] == before[others]).all()                # no lane
+
+    def test_speculation_is_refused_naming_the_state_kind(self):
+        from paddle_tpu.models import OlmoHybridForCausalLM, olmo_hybrid_tiny
+        paddle.seed(3)
+        m = OlmoHybridForCausalLM(olmo_hybrid_tiny(num_hidden_layers=4))
+        with pytest.raises(TypeError, match="slot_state"):
+            PagedEngine(m, max_batch=2, block_size=8, num_blocks=16,
+                        max_blocks_per_seq=4, speculate="ngram")
+
+    def test_state_bytes_count_the_packed_matrix_and_the_window(self):
+        from paddle_tpu.models import OlmoHybridForCausalLM, olmo_hybrid_tiny
+        paddle.seed(3)
+        m = OlmoHybridForCausalLM(olmo_hybrid_tiny(num_hidden_layers=4))
+        m.eval()
+        eng = PagedEngine(m, max_batch=128, block_size=8, num_blocks=16,
+                          max_blocks_per_seq=4)
+        # three linear layers: a 3 x 480 window and six 8 x 64 float32
+        # matrices packed (3, 8, 128); one full layer: K and V of 16 heads
+        # (6 padded to a tile) of 16
+        assert eng.state_bytes_per_slot == 3 * (3 * 480 + 6 * 8 * 64) * 4
+        h = eng.health()
+        assert h["state_bytes_per_slot"] == eng.state_bytes_per_slot
+        assert h["kv_bytes_per_token"] == 2 * 16 * 16 * 4
+        assert [tuple(st["s"].shape) for st in eng.state] == \
+            [(128, 3, 8, 128)] * 3

@@ -58,7 +58,9 @@ def _decode_step(q, kc, vc, tables, lens, nk, nv):
 @pytest.mark.parametrize("geometry", [
     pytest.param((32, 32, 8, 4096, 128), id="mistral-7b"),
     pytest.param((64, 32, 2, 16384, 256), id="nemotron-3-super"),
-    pytest.param((64, 64, 8, 49152, 1152), id="k-exaone")])
+    pytest.param((64, 64, 8, 49152, 1152), id="k-exaone"),
+    # 30 heads a side in pages of 32 (two of zeros): a group of one
+    pytest.param((128, 32, 32, 10240, 256), id="olmo-hybrid")])
 def test_paged_decode_step_compiles_with_the_kernel_and_no_pool_copy(
         one_chip, tpu_backend, geometry):
     """The decode step of ``block_multihead_attention`` at a cell's shapes:
@@ -113,6 +115,123 @@ def test_latent_decode_step_compiles_with_the_kernel_and_no_pool_copy(
     # the in-place scatter (the pool is page rows already); no copy
     made = re.findall(rf"= bf16\[{nb},\S+ ([\w-]+)\(", entry)
     assert sorted(made) == ["fusion", "parameter"], made
+
+
+def test_thirty_kv_heads_would_copy_the_pool_and_are_refused(one_chip):
+    """Why ``supports()`` wants K/V heads that fill whole sublane tiles: a
+    pool of 30 bfloat16 heads is laid out in 32, so reading it as page rows
+    is no bitcast and the compiler copies it, 1.26 GB a pool a step at the
+    Olmo-Hybrid cell's size (a 512-block pool here)."""
+    from paddle_tpu.ops.pallas import paged_attention as pk
+    nb, bs, D, dt = 512, 16, 128, jnp.bfloat16
+
+    def s(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    assert not pk.supports((128, 1, 30, D), dt, (nb, bs, 30, D), dt)
+    temp = {}
+    for heads in (30, 32):
+        compiled = jax.jit(pk.paged_decode_attention).lower(
+            s((128, 1, heads, D), dt), s((nb, bs, heads, D), dt),
+            s((nb, bs, heads, D), dt), s((128, 256), jnp.int32),
+            s((128,), jnp.int32)).compile()
+        temp[heads] = compiled.memory_analysis().temp_size_in_bytes
+    pool = nb * bs * 30 * D * 2
+    assert temp[30] >= 2 * pool and temp[32] < pool // 4, temp
+
+
+def test_delta_rule_decode_step_compiles_in_place(one_chip):
+    """``delta_rule_step`` at the Olmo-Hybrid cell's sizes (128 lanes, 30
+    heads of 96 x 192 packed two to a row): one Mosaic kernel, the donated
+    283 MB state aliased to the new one, and no buffer of the state's size
+    beside it."""
+    from paddle_tpu.ops.pallas import delta_rule as dk
+    B, H, d_k, d_v, p = 128, 30, 96, 192, 2
+    f32 = jnp.float32
+
+    def s(shape, dtype=f32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    state = (B, H // p, d_k, p * d_v)
+    assert dk.supports(state, d_k, p)
+    compiled = jax.jit(
+        lambda q, k, v, a, b, st, fr, idl: dk.delta_rule_step(
+            q, k, v, a, b, st, fr, idl, p), donate_argnums=(5,)).lower(
+        s((B, H, d_k)), s((B, H, d_k)), s((B, H, d_v)), s((B, H)),
+        s((B, H)), s(state), s((B,), bool), s((B,), bool)).compile()
+    entry = compiled.as_text()
+    entry = entry[entry.index("ENTRY"):]
+    assert entry.count('custom_call_target="tpu_custom_call"') == 1
+    assert "%delta_rule_step" in entry
+    mem = compiled.memory_analysis()
+    nbytes = B * H * d_k * d_v * 4
+    assert mem.alias_size_in_bytes >= nbytes
+    assert mem.temp_size_in_bytes < nbytes // 8
+
+
+def test_olmo_hybrid_programs_compile_with_both_kernels(one_chip,
+                                                        tpu_backend,
+                                                        monkeypatch):
+    """The decode step and the mixed step of an Olmo-Hybrid decoder at the
+    published widths (one linear and one full layer, 128 lanes, vocabulary
+    cut to keep the test's arrays small): ``delta_rule_step`` once a linear layer
+    and ``paged_decode_attn`` once a full layer, states and pools donated
+    and no copy of them, and no temporary of the size the chunk's
+    convolution once took (1.5 GB: ``nn.functional.delta_rule.conv_arrays``
+    has why)."""
+    from paddle_tpu.inference import PagedEngine
+    from paddle_tpu.models import OlmoHybridConfig, OlmoHybridForCausalLM
+    from paddle_tpu.nn.functional import delta_rule as fdr
+    from paddle_tpu.nn.functional.paged_attention import log_paths
+    from paddle_tpu.nn.lazy_init import LazyGuard, materialize_layer
+    from paddle_tpu.serving import SchedulerConfig
+
+    monkeypatch.setattr(fdr.jax, "default_backend", lambda: "tpu")
+    layers, B, nb, context = 2, 128, 320, 4096
+    with LazyGuard():
+        model = OlmoHybridForCausalLM(OlmoHybridConfig(
+            num_hidden_layers=layers, vocab_size=1024, max_seq_len=context,
+            layer_types=("linear_attention", "full_attention")))
+    for p in model.parameters():
+        shape = tuple(p.shape)
+        dt = jnp.float32 if len(shape) == 1 else jnp.bfloat16
+        p._lazy_init = (lambda _s, _d, shape=shape, dt=dt: jnp.zeros(
+            shape, dt), shape, dt)
+    materialize_layer(model)
+    model.eval()
+    eng = PagedEngine(model, max_batch=B, block_size=16, num_blocks=nb,
+                      max_blocks_per_seq=context // 16,
+                      scheduler=SchedulerConfig(prefill_token_budget=256))
+    W = eng.prefill_width
+
+    def rows(r, w):
+        return (np.zeros((r, w), np.int32), np.ones((r,), np.int32),
+                np.zeros((r, context // 16), np.int32),
+                np.zeros((r,), np.float32), np.ones((r,), np.float32),
+                np.zeros((r,), np.int32), np.zeros((r,), np.int32))
+
+    def shapes(args):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=one_chip), args)
+
+    decode = eng._chunk_args(*rows(B, 1))
+    mixed = decode + (jnp.zeros((1,), jnp.int32),
+                      eng._row_args(*rows(1, W)))
+    state = B * 30 * 96 * 192 * 4
+    for name, args, paths in (("decode", decode, ["kernel"]),
+                              ("mixed", mixed, ["composite", "kernel"])):
+        with log_paths() as lowered:
+            compiled = eng._fns[name].lower(
+                *shapes(args), sampling=False).compile()
+        assert (W, sorted(set(lowered))) == (256, paths)
+        entry = compiled.as_text()
+        entry = entry[entry.index("ENTRY"):]
+        assert entry.count('custom_call_target="tpu_custom_call"') == layers
+        assert "%delta_rule_step" in entry and "%paged_decode_attn" in entry
+        assert not re.findall(
+            rf"= (?:bf16\[{nb},16|f32\[{B},15),\S+ copy\(", entry)
+        assert compiled.memory_analysis().temp_size_in_bytes < state, name
 
 
 @pytest.mark.parametrize("partitioned_by", ["the-compiler", "shard-map"])
