@@ -11,9 +11,10 @@ negative eigenvalues, Grazzi et al., arXiv:2411.12537)::
     o_t = S_t^T q_t
 
 where Mamba-2's transition is a scalar a head (``ssm.py``: a masked product
-inside a chunk), this one is a matrix, so the chunked form needs a triangular
-solve inside every chunk. Three pieces, each taking and returning what a
-serving cache carries so a sequence can be fed in pieces:
+inside a chunk), this one is a matrix, so the chunked form needs the inverse
+of a unit lower triangular matrix inside every chunk. Three pieces, each
+taking and returning what a serving cache carries so a sequence can be fed
+in pieces:
 
 * ``gated_delta_chunk`` — the chunked form for a run of tokens that
   CONTINUES from a carried state. Over ``C`` tokens with ``g_i`` the
@@ -22,9 +23,23 @@ serving cache carries so a sequence can be fed in pieces:
   K^T))`` and ``T = (I + A)^-1 diag(beta)``: ``W = T (gamma * K)``, ``U = T
   V``, against the carried state ``U' = U - W S``, ``O = (gamma * Q) S +
   lower(Gamma * Q K^T) U'``, ``S <- gamma_C S + ((gamma_C / gamma) * K)^T
-  U'``. ``T`` does not depend on ``S``, so every chunk's solve runs at once
-  and only the short state carry is a scan. ``(I + A)^-1`` is
-  ``jax.scipy.linalg.solve_triangular`` (unit lower, ``C x C``, float32).
+  U'``. ``T`` does not depend on ``S``, so every chunk's inverse is taken at
+  once and only the short state carry is a scan. ``(I + A)^-1`` (unit lower,
+  ``C x C``, float32) is built by blocks, ``unit_lower_inverse``: the
+  diagonal blocks of width ``BLOCK`` by substitution, every block of every
+  system in one batched sweep, then pairs of inverted blocks merged upward by
+  ``[[P, 0], [L, Q]]^-1 = [[P^-1, 0], [-Q^-1 L P^-1, Q^-1]]``, then ONE
+  product with ``diag(beta) [gamma * K | V]``. Until PR 38 it was
+  ``jax.scipy.linalg``'s triangular solve: XLA's ``triangular_solve``
+  custom call took 0.60 ms a layer for a (1, 256) chunk's 120 systems of
+  64 x 64 against 288 columns, 3.6 of a chunk's 13.8 ms on the chip, for
+  ~0.3 GFLOP: latency, not work. Alone on a v5e that call reads 0.650 ms
+  and this form 0.114 (blocks of 8: 0.122; PR 37 read 8 / 16 / 32 at 0.377
+  / 0.360 / 0.398 against 0.660 under the host's dispatch floor).
+  ``chunk_plan`` says what a call was built with. The finite product ``(I -
+  N)(I + N^2)(I + N^4)(I + N^8)`` is NOT used for the diagonal blocks: where
+  a block is 2 on its whole lower triangle its powers reach 1e6 and cancel
+  (0.12 off at 0.999 of that case, float32).
 * ``gated_delta_step`` — the recurrence itself for one token (decode),
   written so that the state is READ ONCE: ``S^T k`` and ``S^T q`` are two
   reductions of one pass, and ``o = alpha S^T q + (k . q) u`` needs no second
@@ -47,6 +62,8 @@ packed form when ``packed=p`` is given.
 """
 from __future__ import annotations
 
+import logging
+
 import jax
 import jax.numpy as jnp
 
@@ -58,6 +75,9 @@ __all__ = ["gated_delta_chunk", "gated_delta_step", "gated_rms_norm",
 
 _HI = jax.lax.Precision.HIGHEST
 _LANES = 128
+#: width of the diagonal blocks ``unit_lower_inverse`` inverts by
+#: substitution (the measured best at a chunk of 64: module docstring)
+BLOCK = 16
 
 
 def _t(x):
@@ -88,15 +108,93 @@ def unpack_state(packed, p: int):
         0, 1, 3, 2, 4).reshape(b, g * p, dk, l // p)
 
 
+# ------------------------------------------------- the inverse of I + A
+def chunk_plan(tokens: int, chunk_size: int) -> dict:
+    """What the chunked form executes for ``tokens`` rows at ``chunk_size``,
+    fixed when it is traced: ``chunk`` (rows a chunk, ``C``), ``block`` (the
+    width of the diagonal blocks of ``I + A`` inverted by substitution),
+    ``merge_levels`` (how often pairs of inverted blocks are merged) and
+    ``padded`` (``block << merge_levels``: the size the inverse is built
+    at, ``C`` completed with identity rows and columns)."""
+    c = min(chunk_size, tokens)
+    block = min(BLOCK, c)
+    levels = (-(-c // block) - 1).bit_length()
+    return {"chunk": c, "block": block, "merge_levels": levels,
+            "padded": block << levels}
+
+
+def _stamp_plan(tokens, chunk_size):
+    """The plan on the ``compile.trace`` entry of the program being traced
+    (the start-up record; outside a trace, nothing)."""
+    from ...observability import trace as _trace
+    _trace.compile_note(f"delta_rule_chunk[{tokens},{chunk_size}]",
+                        chunk_plan(tokens, chunk_size))
+
+
+# One jitted function: a model's linear layers call it with one signature, so
+# the program that holds them traces the sweep and the merges once, not once
+# a layer (``ops.pallas.flash_attention`` has what forgetting that cost).
+@jax.jit
+def unit_lower_inverse(a_mat):
+    """``(I + A)^-1`` for strictly lower ``a_mat`` (..., C, C) float32: the
+    diagonal blocks by substitution, merged by products at ``highest``."""
+    c = a_mat.shape[-1]
+    plan = chunk_plan(c, c)
+    if flags.get_flag("log_level") >= 1:    # here: once a signature
+        logging.getLogger("paddle_tpu.delta_rule").info(
+            "unit_lower_inverse%s: %s", a_mat.shape, plan)
+    b, size = plan["block"], plan["padded"]
+    if size > c:    # zero rows and columns of A: identity ones of I + A
+        a_mat = jnp.pad(a_mat, [(0, 0)] * (a_mat.ndim - 2)
+                        + [(0, size - c)] * 2)
+
+    def block(width, row, col):
+        return a_mat[..., row * width:(row + 1) * width,
+                     col * width:(col + 1) * width]
+
+    # (I + N) X = I a row at a time, as rank-one sweeps over every block of
+    # every system at once: after sweep j row j + 1 is final. N is strictly
+    # lower, so a sweep leaves rows <= j alone without a mask, and N = 0
+    # gives the identity exactly. A loop, not b - 1 unrolled sweeps: those
+    # were 2.2 MB more of executable a layer (a serving program of six
+    # layers loaded 0.4 s slower from the compile cache) and ran 0.05 ms a
+    # layer slower on the chip.
+    n = jnp.stack([block(b, i, i) for i in range(size // b)], axis=-3)
+
+    def sweep(j, inv):
+        col = jax.lax.dynamic_slice_in_dim(n, j, 1, axis=n.ndim - 1)
+        row = jax.lax.dynamic_slice_in_dim(inv, j, 1, axis=n.ndim - 2)
+        return inv - col * row
+
+    inv = jax.lax.fori_loop(0, b - 1, sweep, jnp.broadcast_to(
+        jnp.eye(b, dtype=a_mat.dtype), n.shape))
+    width = b
+    while width < size:     # [[P, 0], [L, Q]]^-1, pairs of neighbours
+        p_inv, q_inv = inv[..., 0::2, :, :], inv[..., 1::2, :, :]
+        low = jnp.stack([block(width, i + 1, i)
+                         for i in range(0, size // width, 2)], axis=-3)
+        low = -jnp.matmul(q_inv, jnp.matmul(low, p_inv, precision=_HI),
+                          precision=_HI)
+        inv = jnp.concatenate(
+            [jnp.concatenate([p_inv, jnp.zeros_like(p_inv)], axis=-1),
+             jnp.concatenate([low, q_inv], axis=-1)], axis=-2)
+        width *= 2
+    return inv[..., 0, :c, :c]
+
+
 # ------------------------------------------------------------ array level
 def chunk_arrays(q, k, v, alpha_log, beta, state, valid, chunk_size):
     """``q`` / ``k`` (B, T, H, d_k) (``k`` of unit length, ``q`` scaled: the
     caller's), ``v`` (B, T, H, d_v), ``alpha_log`` (B, T, H) <= 0, ``beta``
     (B, T, H), ``state`` (B, H, d_k, d_v) float32, ``valid`` (B, T) bool or
     None. Returns ``(o (B, T, H, d_v) float32, the state after the last
-    token)``."""
+    token)``. ``(I + A)^-1`` is ``unit_lower_inverse``, a block inverse in
+    ``jnp`` whatever the backend (XLA's ``triangular_solve`` custom call was
+    the largest piece of a prefill chunk's rule: module docstring), applied
+    to ``[gamma * K | V]`` in one product."""
     f32 = jnp.float32
     bsz, t, h, dk = q.shape
+    _stamp_plan(t, chunk_size)
     dv = v.shape[-1]
     q, k, v = q.astype(f32), k.astype(f32), v.astype(f32)
     alpha_log, beta = alpha_log.astype(f32), beta.astype(f32)
@@ -129,8 +227,7 @@ def chunk_arrays(q, k, v, alpha_log, beta, state, valid, chunk_size):
                       0.0)
     gamma = jnp.exp(g)[..., None]                          # (B, H, n, c, 1)
     rhs = beta[..., None] * jnp.concatenate([gamma * k, v], axis=-1)
-    solved = jax.scipy.linalg.solve_triangular(
-        a_mat + jnp.eye(c, dtype=f32), rhs, lower=True, unit_diagonal=True)
+    solved = jnp.matmul(unit_lower_inverse(a_mat), rhs, precision=_HI)
     w, u = solved[..., :dk], solved[..., dk:]
     qk = gam * jnp.einsum("bhnik,bhnjk->bhnij", q, k, precision=_HI)
     to_end = jnp.exp(g[..., -1:] - g)[..., None]           # gamma_C / gamma

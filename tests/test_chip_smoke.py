@@ -99,8 +99,11 @@ def test_kernels_phase_names_every_broken_kernel(interpreted, monkeypatch):
     assert "fused_residual_norm" not in msg     # the healthy ones pass
 
 
-def test_train_phase_tiny():
+def test_train_phase_tiny(monkeypatch):
+    from paddle_tpu.distributed import mesh as mesh_mod
     from paddle_tpu.models import GPTConfig
+    # a file that ran before this one in the worker may have left its mesh
+    monkeypatch.setattr(mesh_mod, "_global_mesh", None)
     cfg = GPTConfig(vocab_size=128, hidden_size=32, num_layers=1,
                     num_heads=2, max_seq_len=16, recompute=True)
     # the suite's 8 virtual devices: the Engine's default dp mesh spans

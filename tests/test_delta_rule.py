@@ -7,8 +7,10 @@ same function.
 
 Tolerances: ``TIGHT`` 2e-5 absolute on values of order 1. Everything is
 float32 at ``highest`` precision; what differs is the order of a few hundred
-float32 additions (a triangular solve and four products a chunk against one
-rank-one update a token), not a precision.
+float32 additions (a block inverse and five products a chunk against one
+rank-one update a token), not a precision. The inverse alone
+(``unit_lower_inverse``) is held to ``jax.scipy.linalg.solve_triangular``'s
+own error against ``numpy.linalg.solve`` in float64.
 """
 from __future__ import annotations
 
@@ -76,7 +78,8 @@ def ragged(tokens, kind):
 @pytest.mark.parametrize("carried", [False, True], ids=["zero", "carried"])
 @pytest.mark.parametrize("kind", ["all", "left", "right", "none"])
 @pytest.mark.parametrize("tokens,chunk", [(1, 64), (7, 4), (64, 64),
-                                          (150, 64), (33, 8)])
+                                          (150, 64), (33, 8), (256, 64),
+                                          (200, 128)])
 def test_chunked_form_is_the_token_loop(tokens, chunk, kind, carried):
     q, k, v, alpha_log, beta = inputs(tokens, seed=tokens)
     state = (np.random.default_rng(1).normal(size=(B, H, DK, DV))
@@ -92,6 +95,108 @@ def test_chunked_form_is_the_token_loop(tokens, chunk, kind, carried):
     if kind == "none":      # no valid row: the state back bit for bit
         assert np.array_equal(np.asarray(got_s[0]),
                               np.asarray(state[0], np.float32))
+
+
+# ------------------------------------------------------------ the inverse
+def strict_lower(kind, c, rng, systems=(2, 3)):
+    """``A`` of ``systems`` chunks of ``c`` rows, float32 as the rule builds
+    it: ``random`` keys with ``beta`` to 2; ``worst``, the largest
+    admissible entries (every key the same unit vector, ``beta`` 2, ``alpha``
+    1: 2 on the whole strict lower triangle); ``zero``, no valid row."""
+    if kind == "zero":
+        return np.zeros(systems + (c, c), np.float32)
+    if kind == "worst":
+        return np.broadcast_to(np.tril(np.full((c, c), 2.0, np.float32), -1),
+                               systems + (c, c))
+    k = rng.normal(size=systems + (c, DK))
+    k /= np.linalg.norm(k, axis=-1, keepdims=True)
+    beta = 2 / (1 + np.exp(-rng.normal(size=systems + (c,)) * 3))
+    beta[rng.uniform(size=beta.shape) < 0.1] = 2.0
+    g = np.cumsum(-np.exp(rng.normal(size=systems + (c,))) * 0.3, axis=-1)
+    gam = np.exp(np.tril(g[..., :, None] - g[..., None, :]))
+    return np.tril(beta[..., None] * gam * (k @ np.swapaxes(k, -1, -2)),
+                   -1).astype(np.float32)
+
+
+def inverse_in_float64(a):
+    eye = np.eye(a.shape[-1])
+    return np.linalg.solve(a.astype(np.float64) + eye,
+                           np.broadcast_to(eye, a.shape))
+
+
+# the block form may be this many times as far from float64 as the
+# triangular solve it replaced (or one float32 rounding of an entry of 2,
+# where that solve is exact)
+INVERSE_FACTOR = 4.0
+
+
+@pytest.mark.parametrize("kind", ["random", "worst", "zero"])
+@pytest.mark.parametrize("c", [1, 4, 7, 8, 16, 24, 64, 128])
+def test_block_inverse_is_the_triangular_solve(c, kind):
+    a = strict_lower(kind, c, np.random.default_rng(c))
+    want = inverse_in_float64(a)
+    got = np.asarray(dr.unit_lower_inverse(jnp.asarray(a)))
+    assert got.dtype == np.float32 and got.shape == a.shape
+    solve = np.asarray(jax.scipy.linalg.solve_triangular(
+        jnp.asarray(a) + jnp.eye(c, dtype=jnp.float32),
+        jnp.broadcast_to(jnp.eye(c, dtype=jnp.float32), a.shape),
+        lower=True, unit_diagonal=True))
+    err = np.abs(got - want).max()
+    assert err <= INVERSE_FACTOR * max(np.abs(solve - want).max(),
+                                       2 * np.finfo(np.float32).eps)
+    assert not np.triu(got, 1).any()
+    if kind == "zero":      # no valid row: the identity, exactly
+        assert np.array_equal(got, np.broadcast_to(
+            np.eye(c, dtype=np.float32), a.shape))
+    if kind == "worst":     # whole numbers throughout: exact, entries to 2
+        assert err == 0 and np.abs(got).max() == min(c, 2)
+
+
+def test_block_inverse_where_the_matrix_is_ill_conditioned():
+    """Next to the worst admissible case but off the whole numbers, the
+    merges' products cancel (``L P^-1`` sums 32 terms of 4 to a 2) where a
+    substitution does not: the block form is held to 2e-4 on entries of 2 at
+    ``C`` = 64, not to the solve's 3e-7. 64 keys a chunk that are one
+    vector, each written at ``beta`` 2 without decay, is no served input."""
+    a = strict_lower("worst", 64, None) * np.float32(0.999)
+    got = np.asarray(dr.unit_lower_inverse(jnp.asarray(a)))
+    assert np.abs(got - inverse_in_float64(a)).max() < 2e-4
+
+
+@pytest.mark.parametrize("tokens,chunk,plan", [
+    (256, 64, {"chunk": 64, "block": 16, "merge_levels": 2, "padded": 64}),
+    (200, 128, {"chunk": 128, "block": 16, "merge_levels": 3,
+                "padded": 128}),
+    (24, 24, {"chunk": 24, "block": 16, "merge_levels": 1, "padded": 32}),
+    (7, 4, {"chunk": 4, "block": 4, "merge_levels": 0, "padded": 4}),
+    (1, 64, {"chunk": 1, "block": 1, "merge_levels": 0, "padded": 1})])
+def test_chunk_plan_says_what_a_call_is_built_with(tokens, chunk, plan):
+    assert dr.chunk_plan(tokens, chunk) == plan
+
+
+def test_chunk_arrays_stamps_its_plan_on_the_trace_entry():
+    """As the flash kernels do: the plan on the ``compile.trace`` entry of
+    the program being traced, once a signature with a count of the calls
+    (one a linear layer), and the inverse traced ONCE for them all."""
+    from paddle_tpu.observability import trace
+
+    args = tuple(map(jnp.asarray, inputs(40, seed=5)))
+    zero, valid = jnp.zeros((B, H, DK, DV)), jnp.ones((B, 40), bool)
+
+    def two_layers(*a):
+        o, s = dr.chunk_arrays(*a, zero, valid, 16)
+        return dr.chunk_arrays(*a, s, valid, 16)[0] + o
+
+    trace.startup_clear()
+    text = jax.jit(two_layers).lower(*args).as_text()
+    assert text.count("func.func private @unit_lower_inverse") == 1
+    assert text.count("call @unit_lower_inverse") == 2
+    (traced,) = [e for e in trace.startup_record()["entries"]
+                 if e[0] == "compile.trace"
+                 and e[5]["program"] == "two_layers"]
+    assert traced[5]["delta_rule_chunk[40,16]"] == dict(
+        dr.chunk_plan(40, 16), calls=2)
+    trace.startup_clear()
 
 
 @pytest.mark.parametrize("packed", [None, 4], ids=["plain", "packed"])

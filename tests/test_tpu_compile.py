@@ -172,13 +172,17 @@ def test_delta_rule_decode_step_compiles_in_place(one_chip):
 def test_olmo_hybrid_programs_compile_with_both_kernels(one_chip,
                                                         tpu_backend,
                                                         monkeypatch):
-    """The decode step and the mixed step of an Olmo-Hybrid decoder at the
-    published widths (one linear and one full layer, 128 lanes, vocabulary
-    cut to keep the test's arrays small): ``delta_rule_step`` once a linear layer
-    and ``paged_decode_attn`` once a full layer, states and pools donated
-    and no copy of them, and no temporary of the size the chunk's
-    convolution once took (1.5 GB: ``nn.functional.delta_rule.conv_arrays``
-    has why)."""
+    """The decode step, the mixed step and the prefill chunk of an
+    Olmo-Hybrid decoder at the published widths (one linear and one full
+    layer, 128 lanes, vocabulary cut to keep the test's arrays small):
+    ``delta_rule_step`` once a linear layer and ``paged_decode_attn`` once a
+    full layer in the programs that hold a decode step, none in the chunk,
+    states and pools donated and no copy of them, no temporary of the size
+    the chunk's convolution once took (1.5 GB:
+    ``nn.functional.delta_rule.conv_arrays`` has why), and no
+    ``triangular_solve`` custom call in a program that runs the chunked rule
+    (``unit_lower_inverse`` is products and sweeps; the call was 3.6 of a
+    chunk's 13.8 ms)."""
     from paddle_tpu.inference import PagedEngine
     from paddle_tpu.models import OlmoHybridConfig, OlmoHybridForCausalLM
     from paddle_tpu.nn.functional import delta_rule as fdr
@@ -218,17 +222,23 @@ def test_olmo_hybrid_programs_compile_with_both_kernels(one_chip,
     decode = eng._chunk_args(*rows(B, 1))
     mixed = decode + (jnp.zeros((1,), jnp.int32),
                       eng._row_args(*rows(1, W)))
+    chunk = eng._chunk_args(*rows(1, W)) + (jnp.zeros((1,), jnp.int32),)
     state = B * 30 * 96 * 192 * 4
-    for name, args, paths in (("decode", decode, ["kernel"]),
-                              ("mixed", mixed, ["composite", "kernel"])):
+    for name, args, paths, kernels in (
+            ("decode", decode, ["kernel"], layers),
+            ("mixed", mixed, ["composite", "kernel"], layers),
+            ("prefill", chunk, ["composite"], 0)):
         with log_paths() as lowered:
             compiled = eng._fns[name].lower(
                 *shapes(args), sampling=False).compile()
         assert (W, sorted(set(lowered))) == (256, paths)
-        entry = compiled.as_text()
-        entry = entry[entry.index("ENTRY"):]
-        assert entry.count('custom_call_target="tpu_custom_call"') == layers
-        assert "%delta_rule_step" in entry and "%paged_decode_attn" in entry
+        text = compiled.as_text()
+        assert not re.search("triangular_solve|TriangularSolve|"
+                             "InvertDiagBlocks", text), name
+        entry = text[text.index("ENTRY"):]
+        assert entry.count('custom_call_target="tpu_custom_call"') == kernels
+        assert ("%delta_rule_step" in entry) == bool(kernels)
+        assert ("%paged_decode_attn" in entry) == bool(kernels)
         assert not re.findall(
             rf"= (?:bf16\[{nb},16|f32\[{B},15),\S+ copy\(", entry)
         assert compiled.memory_analysis().temp_size_in_bytes < state, name
