@@ -74,6 +74,10 @@ class Engine:
         self._train_step = None
         self._eval_step = None
         self.history: List[float] = []
+        #: host totals (int64) of the device counters its steps carried
+        #: (``observability.trace.STEP_COUNTERS``), over every ``fit`` call
+        self.step_counters: dict = {}
+        self._step_counter_decl: dict = {}   # what the running fit carries
         #: this Engine's name and ``fit`` calls in the start-up record
         self._startup_name = f"engine{next(_ENGINE_COUNTER)}"
         self._fit_calls = 0
@@ -125,24 +129,39 @@ class Engine:
         def init_opt_state(param_arrays):
             states = [opt._init_state(p) for p in params]
             masters = [None] * len(params)  # fp32 params: no master copies
-            return (jnp.asarray(0, jnp.int32), masters, states)
+            state = (jnp.asarray(0, jnp.int32), masters, states)
+            # device counters the model's layers declare ride the donated
+            # state as a fourth entry, only while metrics are on: off, the
+            # step is the program it was and carries none
+            self._step_counter_decl = self._declared_step_counters()
+            if self._step_counter_decl:
+                state += ({name: jnp.zeros(c.shape, c.dtype) for name, c
+                           in self._step_counter_decl.items()},)
+            return state
 
         self._init_opt_state = init_opt_state
 
         def engine_train_step(param_arrays, opt_state, lr, x, y):
+            t, masters, states, *counters = opt_state
+
             def f(pa):
                 originals = [p._data for p in params]
                 for p, a in zip(params, pa):
                     p._data = a
                 try:
-                    return self._traced_loss(model, loss_fn, params,
-                                             x, y)
+                    if not counters:
+                        return self._traced_loss(model, loss_fn, params,
+                                                 x, y), {}
+                    with _trace.step_counters() as counted:
+                        loss = self._traced_loss(model, loss_fn, params,
+                                                 x, y)
+                    return loss, counted.values
                 finally:
                     for p, o in zip(params, originals):
                         p._data = o
 
-            loss, grads = jax.value_and_grad(f)(param_arrays)
-            t, masters, states = opt_state
+            (loss, counted), grads = jax.value_and_grad(
+                f, has_aux=True)(param_arrays)
             t = t + 1
             if opt._grad_clip is not None:
                 pairs = opt._grad_clip(
@@ -159,7 +178,11 @@ class Engine:
                 new_p, new_m, new_st = opt._tree_step(
                     lr, t, param_arrays, grads, masters, states, lr_mults,
                     wd_flags)
-            return loss, new_p, (t, new_m, new_st)
+            new_state = (t, new_m, new_st)
+            if counters:
+                new_state += ({name: total + counted[name]
+                               for name, total in counters[0].items()},)
+            return loss, new_p, new_state
 
         # The step owns the state it is given: params + optimizer state
         # are donated, so XLA writes the updated values into their HBM.
@@ -189,6 +212,37 @@ class Engine:
 
         self._eval_step = jax.jit(engine_eval_step)
         return self
+
+    def _declared_step_counters(self) -> dict:
+        """``{name: StepCounter}`` over the model's layers (a Layer with a
+        ``step_counters()``), empty while metrics are off."""
+        from ...observability import metrics as _metrics
+        out = {}
+        if _metrics.enabled() and hasattr(self._model, "sublayers"):
+            for layer in self._model.sublayers(include_self=True):
+                declare = getattr(layer, "step_counters", None)
+                if callable(declare):
+                    out.update(declare())
+        return out
+
+    def _read_step_counters(self, opt_state):
+        """The epoch's counts to the host (with the loss's read), their
+        exporters called, the device counters zeroed where they lie (``a -
+        a`` keeps the placement the step gave them, so the step is not
+        traced again; like the running loss sum's add it is a tiny program
+        of its own). Returns the state to go on with."""
+        counters = opt_state[3]
+        fresh = {name: np.asarray(a).astype(np.int64)  # tpulint: disable=TPU104 — telemetry-by-design: one host read an epoch, beside the loss's, under FLAGS_enable_metrics only
+                 for name, a in counters.items()}
+        for name, value in fresh.items():
+            held = self.step_counters.get(name)
+            self.step_counters[name] = value if held is None \
+                else held + value
+            export = self._step_counter_decl[name].export
+            if export is not None:
+                export(value)
+        return opt_state[:3] + ({name: a - a
+                                 for name, a in counters.items()},)
 
     def _traced_loss(self, model, loss_fn, params, x, y):
         """One forward+loss inside the traced step — under SPMD auto
@@ -467,6 +521,8 @@ class Engine:
                     with _trace.boundary("fit.epoch_sync"):
                         self.history.append(
                             float(loss_sum) / loss_n)  # tpulint: disable=TPU103 — end-of-epoch history materialization (documented contract), not a per-step sync
+                        if len(opt_state) == 4:
+                            opt_state = self._read_step_counters(opt_state)
         finally:
             # write the trained arrays AND accumulator states back into
             # the eager optimizer, so a later opt.step()/state_dict()
@@ -475,7 +531,7 @@ class Engine:
             # first step — the latest live arrays must land back.
             call.args["steps"] = n_steps
             with _trace.boundary("fit.writeback"):
-                t, _masters, states = opt_state
+                t, _masters, states = opt_state[:3]
                 self._opt._step_count = int(t)  # tpulint: disable=TPU103 — one end-of-fit writeback into the eager optimizer (documented contract), not a per-step sync
                 for p, a, st in zip(self._params, pa, states):
                     p._data = a

@@ -2,8 +2,10 @@
 reference uses for auto-parallel tests, test/auto_parallel/get_gpt_model.py).
 These are the BASELINE.md ladder configs: LeNet, ResNet, BERT, GPT, LLaMA,
 the Nemotron-H hybrid (Mamba-2 + attention + latent experts), the
-K-EXAONE decoder (window + full attention, SwiGLU experts) and the
-DeepSeek-V3 decoder (latent attention, SwiGLU experts).
+K-EXAONE decoder (window + full attention, SwiGLU experts), the
+DeepSeek-V3 decoder (latent attention, SwiGLU experts) and the LFM2-MoE
+decoder (gated short convolutions + grouped-query attention, SwiGLU experts;
+trained, not served).
 """
 from .lenet import LeNet
 from .gpt import GPTConfig, GPTModel, GPTForCausalLM, gpt2_small, gpt2_medium
@@ -20,6 +22,8 @@ from .deepseek_v3 import (DeepseekV3Config, DeepseekV3ForCausalLM,
                           DeepseekV3Model, deepseek_v3_tiny)
 from .olmo_hybrid import (OlmoHybridConfig, OlmoHybridForCausalLM,
                           OlmoHybridModel, olmo_hybrid_tiny)
+from .lfm2_moe import (Lfm2MoeConfig, Lfm2MoeForCausalLM, Lfm2MoeModel,
+                       lfm2_moe_tiny)
 
 __all__ = [
     "LeNet", "GPTConfig", "GPTModel", "GPTForCausalLM",
@@ -37,4 +41,5 @@ __all__ = [
     "deepseek_v3_tiny",
     "OlmoHybridConfig", "OlmoHybridModel", "OlmoHybridForCausalLM",
     "olmo_hybrid_tiny",
+    "Lfm2MoeConfig", "Lfm2MoeModel", "Lfm2MoeForCausalLM", "lfm2_moe_tiny",
 ]

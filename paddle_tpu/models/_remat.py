@@ -22,13 +22,20 @@ def remat_block(blk, *args):
     """Run ``blk(*args)`` (Tensor -> Tensor) with activation checkpointing.
 
     ``blk`` is typically a Layer; extra Tensor args (e.g. an attention
-    mask) ride along and are saved as residuals, not rematerialized.
+    mask) ride along and are saved as residuals, not rematerialized. Under
+    a trace the block may return a tuple of Tensors (its output and what it
+    counted).
     """
     datas = [a._data for a in args]
     if any(isinstance(d, jax.core.Tracer) for d in datas):
         def f(*arrs):
-            return blk(*[Tensor(a) for a in arrs])._data
-        return Tensor(jax.checkpoint(f)(*datas), stop_gradient=False)
+            out = blk(*[Tensor(a) for a in arrs])
+            return tuple(o._data for o in out) if isinstance(out, tuple) \
+                else out._data
+        out = jax.checkpoint(f)(*datas)
+        if isinstance(out, tuple):
+            return tuple(Tensor(o, stop_gradient=False) for o in out)
+        return Tensor(out, stop_gradient=False)
     if not dispatch.grad_enabled():
         return blk(*args)
     from ..distributed.fleet.recompute import recompute

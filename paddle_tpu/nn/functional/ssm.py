@@ -11,7 +11,9 @@ Three pieces, each taking and returning what a serving cache carries so a
 sequence can be fed in pieces:
 
 * ``causal_conv1d`` — the depthwise convolution in front of the scan, with
-  the last ``K - 1`` inputs as its carried window;
+  the last ``K - 1`` inputs as its carried window (``gated_short_conv`` is
+  LFM2's operator of the same family: the convolution gated on both sides,
+  no activation, over whole sequences);
 * ``ssd_chunk_scan`` — the chunked (SSD) form for a run of tokens that
   CONTINUES from a carried state: inside a chunk the recurrence is a masked
   matrix product, between chunks a short scan over per-chunk states;
@@ -29,8 +31,8 @@ import jax.numpy as jnp
 from ...core import dispatch
 from ...core.tensor import Tensor, as_tensor
 
-__all__ = ["causal_conv1d", "ssd_chunk_scan", "ssd_state_update",
-           "gated_group_rms_norm"]
+__all__ = ["causal_conv1d", "gated_short_conv", "ssd_chunk_scan",
+           "ssd_state_update", "gated_group_rms_norm"]
 
 
 def _t(x):
@@ -50,6 +52,20 @@ def conv_arrays(x, weight, bias, window):
         acc = acc + (full[:, j:j + t, :].astype(jnp.float32)
                      * weight[:, j].astype(jnp.float32)[None, None, :])
     return jax.nn.silu(acc).astype(x.dtype), full[:, -(k - 1):, :]
+
+
+def gated_conv_arrays(bcz, taps):
+    """``bcz`` (B, T, 3C): the in-projection's ``[B | C | z]``; ``taps``
+    (C, K). ``C * conv(B * z)`` with ``conv[t] = sum_j taps[:, j] *
+    (B * z)[t - (K - 1) + j]``, zeros left of the first token, accumulated
+    in float32."""
+    f32 = jnp.float32
+    b, c, z = jnp.split(bcz, 3, axis=-1)
+    k, t = taps.shape[1], bcz.shape[1]
+    g = jnp.pad(b.astype(f32) * z.astype(f32), ((0, 0), (k - 1, 0), (0, 0)))
+    acc = sum(g[:, j:j + t, :] * taps[:, j].astype(f32)[None, None, :]
+              for j in range(k))
+    return (c.astype(f32) * acc).astype(bcz.dtype)
 
 
 def scan_arrays(x, dt, a, b, c, d, state, chunk_size):
@@ -153,6 +169,15 @@ def causal_conv1d(x, weight, bias, window=None, name=None):
             (x.shape[0], weight.shape[1] - 1, x.shape[2]), x._data.dtype))
     return dispatch.call("causal_conv1d", conv_arrays,
                          [x, weight, bias, _t(window)])
+
+
+def gated_short_conv(bcz, taps, name=None):
+    """LFM2's gated short convolution over whole sequences (training, no
+    carried window): ``bcz`` (B, T, 3C) is the in-projection's output
+    ``[B | C | z]``, ``taps`` (C, K) the depthwise causal filter (no bias).
+    Returns ``C * conv(B * z)`` (B, T, C); differentiable in both."""
+    return dispatch.call("gated_short_conv", gated_conv_arrays,
+                         [_t(bcz), _t(taps)])
 
 
 def ssd_chunk_scan(x, dt, a, b, c, d, state=None, chunk_size=128,

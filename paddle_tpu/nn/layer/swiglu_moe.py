@@ -1,5 +1,5 @@
-"""Mixture of SwiGLU experts with a shared expert, told which experts it
-holds.
+"""Mixture of SwiGLU experts, with or without a shared expert, told which
+experts it holds.
 
 The DeepSeek-V3 form that K-EXAONE's sparse layers take: a float32 sigmoid
 router over ALL ``num_experts`` (``functional.sigmoid_topk_route``), routed
@@ -8,7 +8,10 @@ expert of the same form beside them that every token passes through. Like
 ``LatentMoE`` the layer holds experts ``[lo, hi)`` as stacked parameters and
 computes only their part of the routed sum, which is what expert parallelism
 asks of a chip; the exchange that would bring the other chips' parts is not
-here.
+here. LFM2-MoE's sparse layers are the same layer with no shared expert
+(``shared_width = 0``) and their own renormalisation epsilon. The layer is
+served by the paged engine and trained by ``Engine.fit``; the functional
+picks the form of the expert product from the call's rows.
 """
 from __future__ import annotations
 
@@ -34,12 +37,15 @@ class SwiGLUMoE(Layer):
     picks ``top_k``. ``forward(u, valid=None)`` returns the layer's output;
     ``forward(..., with_load=True)`` also the int32 load vector of
     ``functional.experts.load_arrays`` (tokens per held expert, pairs
-    landed here, pairs selected)."""
+    landed here, pairs selected). ``shared_width = 0`` builds no shared
+    expert; ``norm_eps`` is what the renormalisation of the chosen scores
+    adds to their sum."""
 
     def __init__(self, hidden_size: int, expert_width: int,
                  shared_width: int, num_experts: int, top_k: int,
                  experts_held=None, routed_scale: float = 1.0,
-                 norm_topk: bool = True, init_std: float = 0.02):
+                 norm_topk: bool = True, init_std: float = 0.02,
+                 norm_eps: float = 1e-20):
         super().__init__()
         lo, hi = experts_held or (0, num_experts)
         if not 0 <= lo < hi <= num_experts:
@@ -48,6 +54,7 @@ class SwiGLUMoE(Layer):
         self.num_experts, self.top_k = num_experts, top_k
         self.experts_held = (lo, hi)
         self.routed_scale, self.norm_topk = routed_scale, norm_topk
+        self.norm_eps, self.shared_width = norm_eps, shared_width
         normal = ParamAttr(initializer=Normal(0.0, init_std))
         self.gate_weight = self.create_parameter(
             [hidden_size, num_experts], attr=normal)
@@ -60,9 +67,10 @@ class SwiGLUMoE(Layer):
             [hi - lo, hidden_size, expert_width], attr=normal)
         self.w_down = self.create_parameter(
             [hi - lo, expert_width, hidden_size], attr=normal)
-        self.shared_gate = _linear(hidden_size, shared_width, init_std)
-        self.shared_up = _linear(hidden_size, shared_width, init_std)
-        self.shared_down = _linear(shared_width, hidden_size, init_std)
+        if shared_width:
+            self.shared_gate = _linear(hidden_size, shared_width, init_std)
+            self.shared_up = _linear(hidden_size, shared_width, init_std)
+            self.shared_down = _linear(shared_width, hidden_size, init_std)
 
     def shared(self, flat):
         """The shared expert: what every chip computes alike."""
@@ -78,14 +86,15 @@ class SwiGLUMoE(Layer):
             idx, w = F.sigmoid_topk_route(
                 flat, self.gate_weight, self.e_score_correction_bias,
                 self.top_k, scale=self.routed_scale,
-                normalize=self.norm_topk)
+                normalize=self.norm_topk, norm_eps=self.norm_eps)
         with jax.named_scope("moe.experts"):
             routed = F.held_experts_swiglu(
                 flat, idx, w, self.w_gate, self.w_up, self.w_down, lo=lo,
                 valid=rows)
-        with jax.named_scope("moe.shared"):
-            shared = self.shared(flat)
-        out = (routed + shared).reshape(shape)
+        if self.shared_width:
+            with jax.named_scope("moe.shared"):
+                routed = routed + self.shared(flat)
+        out = routed.reshape(shape)
         if not with_load:
             return out
         load = _experts.load_arrays(

@@ -27,7 +27,8 @@ import functools
 import os
 import threading
 import time
-from typing import Any, Dict, List, Optional, Tuple
+from typing import (Any, Callable, Dict, List, NamedTuple, Optional,
+                    Tuple)
 
 from jax import config as _jax_config
 from jax import monitoring as _monitoring
@@ -39,7 +40,9 @@ __all__ = ["active", "activate", "deactivate", "add_complete", "span",
            "STARTUP_SPANS", "MAX_STARTUP_EVENTS", "startup_phase",
            "in_startup_phase", "startup_args", "compile_note",
            "note_import", "mark_backend", "note_backend", "startup_record",
-           "startup_summary", "startup_clear"]
+           "startup_summary", "startup_clear", "DEVICE_SCOPES",
+           "STEP_COUNTERS", "StepCounter", "counting_step", "count_in_step",
+           "step_counters"]
 
 #: buffer cap — a runaway loop must degrade to dropped spans, not OOM
 MAX_EVENTS = 200_000
@@ -254,6 +257,96 @@ STARTUP_SPANS: Dict[str, Tuple[str, Optional[str], str]] = {
                         "cache hit / miss / off; retrieval_s and saved_s on "
                         "a hit)"),
 }
+
+
+#: THE list of the ``jax.named_scope``s a trained model's operations carry
+#: in their ``op_name`` on the device plane: name -> what runs under it. A
+#: dotted name sits inside its stem's scope. Forward and backward alike (the
+#: backward's path wraps the name in ``transpose(jvp(...))``); a fusion is
+#: billed to the one name the compiler left on it. PERF.md section 3 says
+#: which metric reads which.
+DEVICE_SCOPES: Dict[str, str] = {
+    "embed": "the token embedding's gather (and a learned position table)",
+    "attn": "a softmax-attention operator: projections, q/k norms, rotary "
+            "angles, the flash kernels, the output projection",
+    "short_conv": "LFM2's gated short convolution: in-projection, the two "
+                  "gates, the depthwise causal taps, out-projection",
+    "mlp": "a dense feed-forward",
+    "moe": "a routed expert layer, all of it",
+    "moe.router": "float32 scores, top-k, renormalised weights",
+    "moe.experts": "the held experts' product, either form",
+    "moe.group": "grouped form only: sort of the landed pairs by expert, "
+                 "gather of their token rows, un-sort of the results",
+    "moe.grouped_matmul": "grouped form only: one grouped matmul a matrix "
+                          "(XLA names the kernel itself ``ragged-dot-*``, "
+                          "whatever scope it was traced under)",
+    "moe.shared": "the shared expert, where the layer has one",
+    "lm_head": "the head's product where logits are returned",
+    "loss": "cross entropy; with a fused head, the head's product too",
+    "optimizer": "the update rule over every parameter",
+}
+
+
+class StepCounter(NamedTuple):
+    """What a layer declares of a device counter its forward adds to while
+    a compiled train step counts; ``export`` (or None) is called with the
+    int64 host array of what an epoch added."""
+    shape: Tuple[int, ...]
+    dtype: Any
+    export: Optional[Callable[[Any], None]] = None
+
+
+#: THE list of device counters a compiled train step may carry in its
+#: donated state: name -> what it counts. A Layer declares the ones it feeds
+#: (``step_counters() -> {name: StepCounter}``); ``Engine.fit`` carries them
+#: only under ``FLAGS_enable_metrics``, sums them inside the step, reads them
+#: where it reads the epoch's loss and hands the epoch's count to ``export``.
+STEP_COUNTERS: Dict[str, str] = {
+    "moe.expert_load": "int32 (expert layers, E_held + 2): per routed layer "
+                       "the tokens each held expert received, then the "
+                       "(token, expert) pairs that landed on held experts "
+                       "and the pairs selected in all "
+                       "(nn.functional.experts.load_arrays); exported "
+                       "through distributed.fleet.moe.stamp_expert_load",
+}
+
+_counting = threading.local()       # .sink: the step being traced, if any
+
+
+class step_counters:
+    """While open on this thread, ``count_in_step`` adds into ``values``
+    (``Engine``'s step opens it around the traced forward, only where the
+    step's state carries counters)."""
+
+    def __init__(self):
+        self.values: Dict[str, Any] = {}
+
+    def __enter__(self):
+        self._outer = getattr(_counting, "sink", None)
+        _counting.sink = self
+        return self
+
+    def __exit__(self, *exc):
+        _counting.sink = self._outer
+        return False
+
+
+def counting_step() -> bool:
+    """Whether the forward being traced feeds a step's counters: a layer
+    computes what it would count only then."""
+    return getattr(_counting, "sink", None) is not None
+
+
+def count_in_step(name: str, value) -> None:
+    """Add ``value`` to the counter ``name`` of ``STEP_COUNTERS`` (any other
+    name is a KeyError) of the step being traced; nothing where none is.
+    Call it where the value belongs to the step's own trace, not from inside
+    a ``jax.checkpoint``ed block."""
+    STEP_COUNTERS[name]
+    sink = getattr(_counting, "sink", None)
+    if sink is not None:
+        held = sink.values.get(name)
+        sink.values[name] = value if held is None else held + value
 
 
 class boundary(span):

@@ -82,6 +82,15 @@ def _bwd_block_for(seq):
 #: run kernels in the Pallas interpreter (CPU testing of kernel code)
 INTERPRET = False
 
+#: scoped VMEM a call of several 2048-wide causal tiles asks for: beside the
+#: band's scores such a tile keeps running state (max, sum, accumulator,
+#: each row a whole (8, 128) tile of f32) and double-buffered operands,
+#: 19.2 MB at d 64 against the compiler's default limit of 16 MB, which
+#: refused every causal call longer than one tile (S 4096, S 8192). A v5e
+#: core has 128 MiB. One-tile calls (S <= 2048) ask for nothing and compile
+#: the kernels they compiled before.
+MULTI_TILE_VMEM_BYTES = 32 * 1024 * 1024
+
 #: rows of queries in a band of a causal tile (``_tile_blocks``)
 SUB_BLOCK = 256
 _LANES = 128
@@ -582,6 +591,16 @@ def _pad_bhsd(x, block_s, pad_d):
     return x
 
 
+def _compiler_params(causal, sp_q, sp_k, block_q, block_k):
+    """Mosaic's parameters for a call: the default (None) unless it is a
+    causal call of SEVERAL tiles wider than 1024, which needs more scoped
+    VMEM than the default limit (``MULTI_TILE_VMEM_BYTES``)."""
+    if (causal and max(block_q, block_k) > 1024
+            and (sp_q > block_q or sp_k > block_k)):
+        return pltpu.CompilerParams(vmem_limit_bytes=MULTI_TILE_VMEM_BYTES)
+    return None
+
+
 def _flash_fwd_bhsd(q, k, v, *, causal, scale, block_q, block_k):
     """q/k/v: (BH, S, d) -> (out (BH, S, d), lse fp32 (BH, Sq_padded))."""
     return _fwd_call(q, k, v, causal=causal, scale=float(scale),
@@ -638,6 +657,8 @@ def _fwd_call(q, k, v, *, causal, scale, block_q, block_k, band, interpret):
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, dp), jnp.float32),
         ],
+        compiler_params=_compiler_params(causal, sp_q, sp_k, block_q,
+                                         block_k),
         interpret=interpret,
         name="flash_fwd",
     )(q, k, v)
@@ -696,6 +717,8 @@ def _bwd_call(q, k, v, out, lse, do, *, causal, scale, block_q, block_k,
         ],
         out_specs=q_spec,
         scratch_shapes=[pltpu.VMEM((block_q, dp), jnp.float32)],
+        compiler_params=_compiler_params(causal, sp_q, sp_k, block_q,
+                                         block_k),
         interpret=interpret,
         name="flash_dq",
     )(q, k, v, do, lse, delta)
@@ -713,6 +736,8 @@ def _bwd_call(q, k, v, out, lse, do, *, causal, scale, block_q, block_k,
         out_specs=[kv_spec, kv_spec],
         scratch_shapes=[pltpu.VMEM((block_k, dp), jnp.float32),
                         pltpu.VMEM((block_k, dp), jnp.float32)],
+        compiler_params=_compiler_params(causal, sp_q, sp_k, block_q,
+                                         block_k),
         interpret=interpret,
         name="flash_dkv",
     )(q, k, v, do, lse, delta)

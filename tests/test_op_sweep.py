@@ -678,6 +678,10 @@ SKIP = {
     "held_experts_swiglu":
         "routing table in, no elementwise sweep contract; compared with "
         "the plain K-EXAONE reference in tests/test_exaone_moe.py",
+    "gated_short_conv":
+        "a causal filter along the sequence axis, no elementwise sweep "
+        "contract; compared with a token loop and with the plain LFM2-MoE "
+        "reference in tests/test_lfm2_moe.py",
     # op-surface tail without a sweepable contract
     "histogramdd": "multi-output (hist, edges-list) contract; "
                    "numpy-parity tested in test_api_tail",

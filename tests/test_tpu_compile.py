@@ -334,6 +334,11 @@ def test_mixed_step_compiles_with_the_kernel_for_the_lanes(one_chip,
     pytest.param((True, 1024, 64, 128), id="causal-the-fit-cells-shape"),
     pytest.param((True, 1100, 64, 4), id="causal-ragged-1100"),
     pytest.param((True, 2000, 64, 4), id="causal-ragged-2000"),
+    # several 2048-wide causal tiles keep running state beside the band's
+    # scores: 19.2 MB of scoped VMEM at d 64, over the default 16 MB limit
+    # (every causal call longer than one tile was refused before PR 39)
+    pytest.param((True, 4096, 64, 4), id="causal-4096-two-tiles"),
+    pytest.param((True, 8192, 64, 8), id="causal-8192-the-lfm2-cells-shape"),
     pytest.param((False, 2048, 64, 4), id="full-2048"),
     pytest.param((False, 4096, 128, 4), id="full-4096"),
     pytest.param((False, 1536, 128, 4), id="full-ragged-1536")])
@@ -352,5 +357,67 @@ def test_flash_forward_and_backward_compile_at_their_own_tiles(
         lambda q, k, v: jnp.sum(fa.flash_attention_fwd(
             q, k, v, causal=causal).astype(jnp.float32)),
         argnums=(0, 1, 2))).lower(x, x, x).compile().as_text()
+    for kernel in ("flash_fwd", "flash_dq", "flash_dkv"):
+        assert f"%{kernel}" in text
+
+
+def test_lfm2_train_step_compiles_with_the_grouped_matmuls(one_chip,
+                                                           monkeypatch):
+    """``Engine``'s train step over a small LFM2-MoE (every kind of layer,
+    blocks rematerialised, bf16 O1 autocast) at 2 x 1024 tokens, the rows
+    from which the expert product takes its grouped form: XLA lowers each
+    ``ragged_dot`` and each of its transposes to a ``ragged-dot`` Mosaic
+    kernel, and the attention layer runs the three flash kernels."""
+    from paddle_tpu import amp, nn
+    from paddle_tpu.distributed.auto_parallel import Engine
+    from paddle_tpu.models import Lfm2MoeForCausalLM, lfm2_moe_tiny
+    from paddle_tpu.nn.functional import experts
+    from paddle_tpu.ops.pallas import flash_attention as fa
+
+    from paddle_tpu.distributed import mesh as mesh_mod
+
+    monkeypatch.setattr(fa, "INTERPRET", False)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    # the program's mesh is one device, as in the cell (the suite's is the
+    # CPU's eight, over which the flash call would be shard_mapped)
+    monkeypatch.setattr(mesh_mod, "_global_mesh", mesh_mod.build_mesh(
+        devices=jax.devices()[:1]))
+    batch, seq = 2, 1024
+    assert experts.takes_grouped_form(batch * seq)
+    cfg = lfm2_moe_tiny(hidden_size=128, num_attention_heads=2,
+                        num_key_value_heads=1, intermediate_size=256,
+                        moe_intermediate_size=128, num_experts=8,
+                        experts_held=(0, 4), vocab_size=512, recompute=True)
+
+    class Loss(nn.Layer):
+        def __init__(self, lm):
+            super().__init__()
+            self.lm = lm
+
+        def forward(self, ids):
+            with amp.auto_cast(level="O1", dtype="bfloat16"):
+                return self.lm(ids, labels=ids)[1]
+
+    net = Loss(Lfm2MoeForCausalLM(cfg))
+    opt = paddle.optimizer.AdamW(learning_rate=1e-4,
+                                 parameters=net.parameters())
+    eng = Engine(net, loss=lambda loss, _labels: loss, optimizer=opt)
+    eng.prepare()
+
+    def s(a):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+
+    params = [s(p._data) for p in eng._params]
+    state = jax.tree_util.tree_map(s, eng._init_opt_state(None))
+    ids = jax.ShapeDtypeStruct((batch, seq), jnp.int32, sharding=one_chip)
+    text = eng._train_step.lower(
+        params, state, jax.ShapeDtypeStruct((), jnp.float32,
+                                            sharding=one_chip),
+        ids, ids).compile().as_text()
+    entry = text[text.index("ENTRY"):]
+    grouped = re.findall(r"%ragged-dot-(?!metadata)[\w-]+(?:\.\d+)? = ", entry)
+    # four routed layers: three products forward, again rematerialised, and
+    # six transposes backward, less what the compiler shares between them
+    assert 4 * 9 <= len(grouped) <= 4 * 12
     for kernel in ("flash_fwd", "flash_dq", "flash_dkv"):
         assert f"%{kernel}" in text
