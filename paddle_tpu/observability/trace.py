@@ -663,6 +663,12 @@ def compile_note(key: str, value: dict):
         _compiling.notes[key] = dict(value, calls=calls + 1)
 
 
+def compile_noted(key: str) -> Optional[dict]:
+    """The note ``key`` of the bracket in flight as it stands (for a note
+    that sums over a trace's calls); None where there is none."""
+    return (_compiling.notes or {}).get(key) if _compiling.depth else None
+
+
 _monitoring.register_scalar_listener(_on_compile_begin)
 _monitoring.register_event_listener(_on_compile_event)
 _monitoring.register_event_duration_secs_listener(_on_compile_seconds)

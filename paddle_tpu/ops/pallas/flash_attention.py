@@ -27,12 +27,15 @@ through the Pallas interpreter so CPU tests cover the real kernel code.
 """
 from __future__ import annotations
 
+import contextlib
 import functools
 import logging
 import math
+import threading
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from . import import_pallas
 
@@ -81,6 +84,15 @@ def _bwd_block_for(seq):
 
 #: run kernels in the Pallas interpreter (CPU testing of kernel code)
 INTERPRET = False
+
+#: the two residuals of a call that the backward kernels read and the forward
+#: kernel alone can make, as the forward rule names them
+#: (``jax.ad_checkpoint.checkpoint_name``): ``out`` as (B, S, H, d), the array
+#: the block goes on with, and the logsumexp as lane-dense (BH, S_padded)
+#: float32. A rematerialised block keeps exactly these beside its input
+#: (``models/_remat.py``), so its backward recomputes everything but the
+#: kernel; outside a ``jax.checkpoint`` a name is the identity.
+KEPT_RESIDUALS = ("flash_out", "flash_lse")
 
 #: scoped VMEM a call of several 2048-wide causal tiles asks for: beside the
 #: band's scores such a tile keeps running state (max, sum, accumulator,
@@ -756,8 +768,7 @@ def _bhsd_to_bshd(x, b, h):
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
 def _flash_attention(q, k, v, causal, scale, block_q, block_k):
-    out, _ = _flash_fwd_rule(q, k, v, causal, scale, block_q, block_k)
-    return out
+    return _flash_fwd(q, k, v, causal, scale, block_q, block_k)[0]
 
 
 def _resolve_blocks(kind, block_q, block_k, q, k, causal, scale):
@@ -776,7 +787,34 @@ def _resolve_blocks(kind, block_q, block_k, q, k, causal, scale):
             tk if block_k is None else block_k)
 
 
-def _flash_fwd_rule(q, k, v, causal, scale, block_q, block_k):
+class _Kept(threading.local):
+    """This thread's list of what the forward rules named, while a
+    ``kept_residuals()`` block is open."""
+    log = None
+
+
+_kept = _Kept()
+
+
+@contextlib.contextmanager
+def kept_residuals():
+    """``[(name, shape, dtype), ...]`` of the residuals the forward rules
+    named while the body ran (``remat_block`` reports them)."""
+    outer, _kept.log = _kept.log, []
+    try:
+        yield _kept.log
+    finally:
+        _kept.log = outer
+
+
+def _keep(x, name):
+    if _kept.log is not None:
+        _kept.log.append((name, x.shape, x.dtype))
+    return checkpoint_name(x, name)
+
+
+def _flash_fwd(q, k, v, causal, scale, block_q, block_k):
+    """(out (B, S, H, d), lse (BH, S_padded, 1)) as the kernel gives them."""
     b, s, h, d = q.shape
     block_q, block_k = _resolve_blocks("fwd", block_q, block_k, q, k,
                                        causal, scale)
@@ -784,7 +822,15 @@ def _flash_fwd_rule(q, k, v, causal, scale, block_q, block_k):
     out, lse = _flash_fwd_bhsd(
         _bshd_to_bhsd(q), _bshd_to_bhsd(k), _bshd_to_bhsd(v),
         causal=causal, scale=scale, block_q=block_q, block_k=block_k)
-    out_bshd = _bhsd_to_bshd(out, b, h)
+    return _bhsd_to_bshd(out, b, h), lse
+
+
+def _flash_fwd_rule(q, k, v, causal, scale, block_q, block_k):
+    out_bshd, lse = _flash_fwd(q, k, v, causal, scale, block_q, block_k)
+    out_bshd = _keep(out_bshd, KEPT_RESIDUALS[0])
+    # the kernel's (BH, S, 1) column is laid out a whole (8, 128) tile of
+    # float32 to every 8 rows, 128 times its bytes: what is kept is the row
+    lse = _keep(lse[..., 0], KEPT_RESIDUALS[1])[..., None]
     return out_bshd, (q, k, v, out_bshd, lse)
 
 

@@ -361,33 +361,25 @@ def test_flash_forward_and_backward_compile_at_their_own_tiles(
         assert f"%{kernel}" in text
 
 
-def test_lfm2_train_step_compiles_with_the_grouped_matmuls(one_chip,
-                                                           monkeypatch):
-    """``Engine``'s train step over a small LFM2-MoE (every kind of layer,
-    blocks rematerialised, bf16 O1 autocast) at 2 x 1024 tokens, the rows
-    from which the expert product takes its grouped form: XLA lowers each
-    ``ragged_dot`` and each of its transposes to a ``ragged-dot`` Mosaic
-    kernel, and the attention layer runs the three flash kernels."""
-    from paddle_tpu import amp, nn
-    from paddle_tpu.distributed.auto_parallel import Engine
-    from paddle_tpu.models import Lfm2MoeForCausalLM, lfm2_moe_tiny
-    from paddle_tpu.nn.functional import experts
-    from paddle_tpu.ops.pallas import flash_attention as fa
+def _flash_calls(entry):
+    return {kernel: len(re.findall(rf"%{kernel}(?:\.\d+)? = ", entry))
+            for kernel in ("flash_fwd", "flash_dq", "flash_dkv")}
 
+
+def _compiled_train_step(lm, batch, seq, one_chip, monkeypatch):
+    """The ENTRY computation of ``Engine``'s train step over ``lm`` under
+    bf16 O1 autocast with AdamW, compiled for the described chip."""
+    from paddle_tpu import amp, nn
     from paddle_tpu.distributed import mesh as mesh_mod
+    from paddle_tpu.distributed.auto_parallel import Engine
+    from paddle_tpu.ops.pallas import flash_attention as fa
 
     monkeypatch.setattr(fa, "INTERPRET", False)
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    # the program's mesh is one device, as in the cell (the suite's is the
+    # the program's mesh is one device, as in the cells (the suite's is the
     # CPU's eight, over which the flash call would be shard_mapped)
     monkeypatch.setattr(mesh_mod, "_global_mesh", mesh_mod.build_mesh(
         devices=jax.devices()[:1]))
-    batch, seq = 2, 1024
-    assert experts.takes_grouped_form(batch * seq)
-    cfg = lfm2_moe_tiny(hidden_size=128, num_attention_heads=2,
-                        num_key_value_heads=1, intermediate_size=256,
-                        moe_intermediate_size=128, num_experts=8,
-                        experts_held=(0, 4), vocab_size=512, recompute=True)
 
     class Loss(nn.Layer):
         def __init__(self, lm):
@@ -398,7 +390,7 @@ def test_lfm2_train_step_compiles_with_the_grouped_matmuls(one_chip,
             with amp.auto_cast(level="O1", dtype="bfloat16"):
                 return self.lm(ids, labels=ids)[1]
 
-    net = Loss(Lfm2MoeForCausalLM(cfg))
+    net = Loss(lm)
     opt = paddle.optimizer.AdamW(learning_rate=1e-4,
                                  parameters=net.parameters())
     eng = Engine(net, loss=lambda loss, _labels: loss, optimizer=opt)
@@ -414,10 +406,51 @@ def test_lfm2_train_step_compiles_with_the_grouped_matmuls(one_chip,
         params, state, jax.ShapeDtypeStruct((), jnp.float32,
                                             sharding=one_chip),
         ids, ids).compile().as_text()
-    entry = text[text.index("ENTRY"):]
+    return text[text.index("ENTRY"):]
+
+
+def test_lfm2_train_step_compiles_with_the_grouped_matmuls(one_chip,
+                                                           monkeypatch):
+    """``Engine``'s train step over a small LFM2-MoE (every kind of layer,
+    blocks rematerialised, bf16 O1 autocast) at 2 x 1024 tokens, the rows
+    from which the expert product takes its grouped form: XLA lowers each
+    ``ragged_dot`` and each of its transposes to a ``ragged-dot`` Mosaic
+    kernel, and the attention layer runs the three flash kernels, the
+    forward one once (the block keeps the kernel's two residuals,
+    ``models/_remat.py``)."""
+    from paddle_tpu.models import Lfm2MoeForCausalLM, lfm2_moe_tiny
+    from paddle_tpu.nn.functional import experts
+
+    batch, seq = 2, 1024
+    assert experts.takes_grouped_form(batch * seq)
+    cfg = lfm2_moe_tiny(hidden_size=128, num_attention_heads=2,
+                        num_key_value_heads=1, intermediate_size=256,
+                        moe_intermediate_size=128, num_experts=8,
+                        experts_held=(0, 4), vocab_size=512, recompute=True)
+    entry = _compiled_train_step(Lfm2MoeForCausalLM(cfg), batch, seq,
+                                 one_chip, monkeypatch)
     grouped = re.findall(r"%ragged-dot-(?!metadata)[\w-]+(?:\.\d+)? = ", entry)
     # four routed layers: three products forward, again rematerialised, and
     # six transposes backward, less what the compiler shares between them
     assert 4 * 9 <= len(grouped) <= 4 * 12
-    for kernel in ("flash_fwd", "flash_dq", "flash_dkv"):
-        assert f"%{kernel}" in text
+    assert _flash_calls(entry) == {"flash_fwd": 1, "flash_dq": 1,
+                                   "flash_dkv": 1}
+
+
+def test_gpt2_train_step_runs_each_forward_kernel_once(one_chip,
+                                                       monkeypatch):
+    """Two GPT-2 medium blocks at the fit cell's shape (8 x 1024 tokens, 16
+    heads of 64, blocks rematerialised): the policy's names reach through
+    the jitted kernel wrapper in the TPU's lowering, so the step holds one
+    forward kernel a layer, and the logsumexp crosses to the backward as
+    (BH, S) rows."""
+    from paddle_tpu.models import GPTConfig, GPTForCausalLM
+
+    layers, heads, batch, seq = 2, 16, 8, 1024
+    entry = _compiled_train_step(GPTForCausalLM(GPTConfig(
+        vocab_size=512, hidden_size=1024, num_layers=layers, num_heads=heads,
+        max_seq_len=seq, recompute=True)), batch, seq, one_chip, monkeypatch)
+    assert _flash_calls(entry) == dict.fromkeys(
+        ("flash_fwd", "flash_dq", "flash_dkv"), layers)
+    rows = re.findall(rf"= f32\[{batch * heads},{seq}\]\S* ", entry)
+    assert len(rows) >= layers, "the logsumexp is not kept as rows"
