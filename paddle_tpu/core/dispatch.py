@@ -156,8 +156,11 @@ class set_grad_enabled_ctx:
 AMP_WHITE_OPS = {
     "matmul", "mm", "bmm", "conv2d", "conv1d", "conv3d", "conv2d_transpose",
     "einsum", "linear", "addmm", "flash_attention", "scaled_dot_product_attention",
-    # chunked head+loss fusion: the matmul dominates, internal lse math
-    # accumulates in f32 regardless of the input dtype
+    # head + loss, chunk by chunk: three products with the vocabulary a
+    # differentiated call (logits, the rows' gradient, the table's) take the
+    # amp dtype's operands; max / logsumexp / picked logit / loss are f32
+    # and the table's gradient sums in f32 over the chunks whatever the
+    # input dtype; nothing of (rows, vocabulary) is kept
     "fused_linear_cross_entropy",
     # GEMM-bearing fused ops (compile/fusion): the norm prologue /
     # rope epilogue compute in f32 internally regardless of input dtype

@@ -75,6 +75,15 @@ def has_mesh() -> bool:
     return _global_mesh is not None
 
 
+def axes_dividing(mesh: Mesh, n: int, names: Sequence[str]):
+    """The axes among ``names`` that ``mesh`` spreads over more than one
+    device, if together they divide ``n`` evenly (a dim of ``n`` can be cut
+    over them); else None."""
+    axes = tuple(a for a in names if mesh.shape.get(a, 1) > 1)
+    size = int(np.prod([mesh.shape[a] for a in axes]))
+    return axes if axes and n % size == 0 else None
+
+
 def axis_size(axis: str) -> int:
     mesh = get_mesh()
     return int(mesh.shape[axis]) if axis in mesh.shape else 1

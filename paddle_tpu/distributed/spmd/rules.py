@@ -709,7 +709,13 @@ def softmax_rule(in_specs, in_shapes, attrs, out_shapes) -> SpmdResult:
 def cross_entropy_rule(in_specs, in_shapes, attrs,
                        out_shapes) -> SpmdResult:
     """logits(N, C) + labels(N) -> loss: batch dims carry, the class
-    dim and any reduced output replicate."""
+    dim and any reduced output replicate. ``fused_linear_cross_entropy``
+    has hidden rows (..., H) first and labels (...): their leading dims are
+    the batch dims, H is contracted inside the op like a class dim, a
+    per-row loss carries the rows' axes and "mean" / "sum" replicate; the
+    op cuts its own rows over the data axes (a ``shard_map`` over ``dp`` /
+    ``sharding`` on the first dim), which is the placement this rule
+    propagates."""
     if not in_specs:
         return SpmdResult(out_specs=[(None,) * len(s) for s in out_shapes])
     lg_spec, lg_shape = in_specs[0], in_shapes[0]

@@ -11,6 +11,8 @@ from __future__ import annotations
 
 from dataclasses import dataclass
 
+import jax
+
 from .. import nn, ops
 from ..nn import functional as F
 from ..nn.initializer import Normal
@@ -30,9 +32,6 @@ class BertConfig:
     type_vocab_size: int = 2
     initializer_range: float = 0.02
     recompute: bool = False          # activation-checkpoint every layer
-    #: fused MLM decoder + chunked streaming CE over the tied embedding
-    #: matrix (forward returns (None, nsp_logits, loss) with labels)
-    fused_loss: bool = False
 
 
 def bert_base(**kw) -> "BertConfig":
@@ -144,27 +143,14 @@ class BertForPretraining(nn.Layer):
         h = self.mlm_norm(F.gelu(self.mlm_dense(seq), approximate=True))
         # tied decoder: project onto word embedding matrix
         w = self.bert.embeddings.word_embeddings.weight
-        if masked_lm_labels is not None and self.bert.cfg.fused_loss:
-            hidden = self.bert.cfg.hidden_size
-            loss = F.fused_linear_cross_entropy(
-                ops.reshape(h, [-1, hidden]), w,
-                ops.reshape(masked_lm_labels, [-1]), transpose_y=True,
-                ignore_index=-100)
-            nsp_logits = self.nsp(pooled)
-            if next_sentence_labels is not None:
-                loss = loss + F.cross_entropy(nsp_logits,
-                                              next_sentence_labels)
-            return None, nsp_logits, loss
-        mlm_logits = ops.matmul(h, w, transpose_y=True)
         nsp_logits = self.nsp(pooled)
         if masked_lm_labels is None:
-            return mlm_logits, nsp_logits
-        v = mlm_logits.shape[-1]
-        mlm_loss = F.cross_entropy(
-            ops.reshape(mlm_logits, [-1, v]),
-            ops.reshape(masked_lm_labels, [-1]), ignore_index=-100)
-        loss = mlm_loss
+            return ops.matmul(h, w, transpose_y=True), nsp_logits
+        # the decoder's product is inside the loss, chunk by chunk: no
+        # (tokens, vocabulary) array exists, and none is returned
+        with jax.named_scope("loss"):
+            loss = F.fused_linear_cross_entropy(
+                h, w, masked_lm_labels, transpose_y=True, ignore_index=-100)
         if next_sentence_labels is not None:
-            loss = loss + F.cross_entropy(nsp_logits,
-                                          next_sentence_labels)
-        return mlm_logits, nsp_logits, loss
+            loss = loss + F.cross_entropy(nsp_logits, next_sentence_labels)
+        return None, nsp_logits, loss

@@ -20,6 +20,7 @@ from ..nn import functional as F
 from ..nn.initializer import Normal
 from ..nn.parameter import ParamAttr
 from .. import ops
+from ._head import next_token_loss
 
 
 def _init_attr(std=0.02):
@@ -42,10 +43,6 @@ class GPTConfig:
     #: activation-checkpoint every block (reference recompute pass) —
     #: required to train the 345M+ rungs on a 16 GB chip
     recompute: bool = False
-    #: fuse the lm-head matmul into the loss (chunked streaming CE; the
-    #: full (B*S, V) logits tensor is never materialized). forward()
-    #: then returns (None, loss) when labels are given.
-    fused_loss: bool = False
     #: long-context attention backend over the 'sep' axis:
     #: "" (dense/flash local), "ring" (ring attention), "ulysses"
     #: (all-to-all head-scatter) — see fleet.meta_parallel.sep_utils
@@ -215,24 +212,14 @@ class GPTForCausalLM(nn.Layer):
         self.gpt = GPTModel(cfg)
 
     def forward(self, input_ids, labels=None):
+        """Logits; with ``labels``, ``(None, loss)``: the head's product is
+        inside the loss (``_head.next_token_loss``)."""
         h = self.gpt(input_ids)
-        if labels is not None and self.cfg.fused_loss:
-            with jax.named_scope("loss"):       # head and loss in one op
-                loss = F.fused_linear_cross_entropy(
-                    ops.reshape(h[:, :-1, :], [-1, self.cfg.hidden_size]),
-                    self.gpt.wte.weight,
-                    ops.reshape(labels[:, 1:], [-1]), transpose_y=True)
-            return None, loss
+        if labels is not None:
+            return None, next_token_loss(h, self.gpt.wte.weight, labels,
+                                         transpose_y=True)
         with jax.named_scope("lm_head"):
-            logits = ops.matmul(h, self.gpt.wte.weight, transpose_y=True)
-        if labels is None:
-            return logits
-        with jax.named_scope("loss"):
-            v = logits.shape[-1]
-            loss = F.cross_entropy(
-                ops.reshape(logits[:, :-1, :], [-1, v]),
-                ops.reshape(labels[:, 1:], [-1]))
-        return logits, loss
+            return ops.matmul(h, self.gpt.wte.weight, transpose_y=True)
 
     def num_params(self) -> int:
         return sum(p.size for p in self.parameters())

@@ -45,6 +45,7 @@ from ..nn import functional as F
 from ..nn.initializer import Normal
 from ..nn.parameter import ParamAttr
 from ..observability import trace as _trace
+from ._head import next_token_loss
 from ._remat import remat_block
 from .llama import rotary_embedding
 
@@ -330,11 +331,7 @@ class Lfm2MoeForCausalLM(nn.Layer):
         if labels is None:
             with jax.named_scope("lm_head"):
                 return ops.matmul(h, table, transpose_y=True)
-        with jax.named_scope("loss"):
-            loss = F.fused_linear_cross_entropy(
-                ops.reshape(h[:, :-1, :], [-1, self.cfg.hidden_size]), table,
-                ops.reshape(labels[:, 1:], [-1]), transpose_y=True)
-        return None, loss
+        return None, next_token_loss(h, table, labels, transpose_y=True)
 
     def num_params(self) -> int:
         return sum(int(np.prod(p.shape)) for p in self.parameters())

@@ -23,6 +23,7 @@ from ..core.tensor import Tensor
 from ..nn import functional as F
 from ..nn.initializer import Normal
 from ..nn.parameter import ParamAttr
+from ._head import next_token_loss
 
 
 @dataclass
@@ -42,8 +43,6 @@ class LlamaConfig:
     sequence_parallel: bool = False
     context_parallel: str = ""       # "", "ring", "ulysses"
     recompute: bool = False          # activation-checkpoint every block
-    #: fused lm-head + chunked streaming CE (forward returns (None, loss))
-    fused_loss: bool = False
 
     def __post_init__(self):
         if self.num_kv_heads == 0:
@@ -323,33 +322,19 @@ class LlamaForCausalLM(nn.Layer):
                                         cfg.vocab_size)
 
     def forward(self, input_ids, labels=None):
+        """Logits; with ``labels``, ``(None, loss)``: the head's product is
+        inside the loss (``_head.next_token_loss``)."""
         h = self.model(input_ids)
-        if labels is not None and self.cfg.fused_loss:
-            with jax.named_scope("loss"):       # head and loss in one op
-                hh = ops.reshape(h[:, :-1, :], [-1, self.cfg.hidden_size])
-                lab = ops.reshape(labels[:, 1:], [-1])
-                if self.lm_head is None:
-                    loss = F.fused_linear_cross_entropy(
-                        hh, self.model.embed_tokens.weight, lab,
-                        transpose_y=True)
-                else:
-                    loss = F.fused_linear_cross_entropy(
-                        hh, self.lm_head.weight, lab)
-            return None, loss
+        tied = self.lm_head is None
+        if labels is not None:
+            table = self.model.embed_tokens if tied else self.lm_head
+            return None, next_token_loss(h, table.weight, labels,
+                                         transpose_y=tied)
         with jax.named_scope("lm_head"):
-            if self.lm_head is None:
-                logits = ops.matmul(h, self.model.embed_tokens.weight,
-                                    transpose_y=True)
-            else:
-                logits = self.lm_head(h)
-        if labels is None:
-            return logits
-        with jax.named_scope("loss"):
-            v = logits.shape[-1]
-            loss = F.cross_entropy(
-                ops.reshape(logits[:, :-1, :], [-1, v]),
-                ops.reshape(labels[:, 1:], [-1]))
-        return logits, loss
+            if tied:
+                return ops.matmul(h, self.model.embed_tokens.weight,
+                                  transpose_y=True)
+            return self.lm_head(h)
 
     def num_params(self) -> int:
         return sum(p.size for p in self.parameters())

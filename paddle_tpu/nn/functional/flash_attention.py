@@ -78,13 +78,8 @@ def _flash_pallas(q, k, v, causal):
     if mesh.size == 1 or jax.sharding.get_abstract_mesh().manual_axes:
         return kernel(q, k, v)
 
-    def axes_dividing(n, names):
-        axes = tuple(a for a in names if mesh.shape.get(a, 1) > 1)
-        size = math.prod(mesh.shape[a] for a in axes)
-        return axes if axes and n % size == 0 else None
-
-    spec = P(axes_dividing(q.shape[0], ("dp", "sharding")), None,
-             axes_dividing(q.shape[2], ("mp",)), None)
+    spec = P(mesh_mod.axes_dividing(mesh, q.shape[0], ("dp", "sharding")),
+             None, mesh_mod.axes_dividing(mesh, q.shape[2], ("mp",)), None)
     return shard_map(kernel, mesh, in_specs=(spec, spec, spec),
                      out_specs=spec)(q, k, v)
 

@@ -6,6 +6,10 @@ dispatcher routes float0 cotangents around them automatically.
 """
 from __future__ import annotations
 
+import functools
+import logging
+import math
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -661,27 +665,238 @@ def rnnt_loss(logits, labels, logit_lengths, label_lengths, blank=0,
 __all__ += ['hsigmoid_loss', 'edit_distance', 'ctc_align', 'rnnt_loss']
 
 
+#: rows a chunk of ``fused_linear_cross_entropy``: the most of the
+#: ``(rows, vocabulary)`` logits that is alive at once. Head + loss alone,
+#: forward + backward on a v5e (``tools/head_loss_bench.py``, PR 42), at
+#: 2048 / 4096 / 8192 rows a chunk: 17.45 / 18.61 / 16.91 ms for 8192 rows x
+#: 1024 x 50 257 (8192 is one chunk, 0.82 GB of temporaries against 0.55 and
+#: 0.24) and 22.87 / 23.18 / 24.64 ms for 16 384 rows x 2048 x 16 384: no
+#: chunk is the faster one at both, so the default is no function of the
+#: vocabulary
+HEAD_LOSS_CHUNK_ROWS = 4096
+
+_head_loss_logged = set()
+
+
+def head_loss_plan(rows: int, chunk_rows: int, vocab: int, hidden: int,
+                   dtype, by_rule: bool) -> dict:
+    """What ``fused_linear_cross_entropy`` executes for ``rows`` rows (a
+    device's, where the call is partitioned), fixed when it is traced:
+    ``chunk`` rows a pass of the scan, ``chunks`` passes, and ``products``,
+    how often a differentiated call multiplies by the vocabulary: 3 under
+    the hand-written rule (logits, the rows' gradient, the table's), 4 where
+    the chunk is rematerialised (``reduction="none"``)."""
+    chunk = min(chunk_rows, rows)
+    return {"rows": rows, "chunk": chunk, "chunks": -(-rows // chunk),
+            "vocab": vocab, "hidden": hidden,
+            "products": 3 if by_rule else 4, "dtype": str(jnp.dtype(dtype))}
+
+
+def _stamp_head_loss_plan(plan):
+    """The plan on the ``compile.trace`` entry of the program being traced
+    (the start-up record; outside a trace, nothing), and once a signature on
+    the logger ``paddle_tpu.head_loss`` at ``FLAGS_log_level`` 1."""
+    from ...core import flags
+    from ...observability import trace as _trace
+    _trace.compile_note("head_loss_plan", plan)
+    signature = tuple(plan.items())
+    if flags.get_flag("log_level") >= 1 and signature not in _head_loss_logged:
+        _head_loss_logged.add(signature)
+        logging.getLogger("paddle_tpu.head_loss").info(
+            "head_loss_plan: %s", plan)
+
+
+def _chunked(xa, lab, chunk, ignore_index):
+    """``(rows, H)``, ``(rows,)`` -> ``(chunks, chunk, H)``, ``(chunks,
+    chunk)``; rows that complete the last chunk carry ``ignore_index``."""
+    n, h = xa.shape
+    pad = (-n) % chunk
+    if pad:
+        xa = jnp.concatenate([xa, jnp.zeros((pad, h), xa.dtype)], axis=0)
+        lab = jnp.concatenate(
+            [lab, jnp.full((pad,), ignore_index, lab.dtype)], axis=0)
+    return xa.reshape(-1, chunk, h), lab.reshape(-1, chunk)
+
+
+def _chunk_nll(x_c, l_c, wa, ba, transpose_y, ignore_index):
+    """One chunk: its logits as the unfused matmul gives them, each row's
+    loss (0 where the label is ``ignore_index``) with the statistics in
+    float32, and what the gradient needs of them."""
+    logits = (x_c @ wa.T) if transpose_y else (x_c @ wa)
+    if ba is not None:
+        logits = logits + ba
+    wide = logits.astype(jnp.float32)
+    m = jax.lax.stop_gradient(jnp.max(wide, axis=-1))
+    lse = jnp.log(jnp.sum(jnp.exp(wide - m[:, None]), axis=-1)) + m
+    l_i = l_c.astype(jnp.int32)
+    valid = l_i != ignore_index
+    safe = jnp.where(valid, l_i, 0)
+    picked = jnp.squeeze(jnp.take_along_axis(
+        logits, safe[:, None], axis=-1), -1).astype(jnp.float32)
+    return jnp.where(valid, lse - picked, 0.0), valid, logits, lse, safe
+
+
+def _head_loss_rows(xa, wa, ba, lab, transpose_y, ignore_index, chunk):
+    """``reduction="none"``: the rows' losses, each chunk's logits
+    rematerialised in the backward (``jax.checkpoint``)."""
+    @jax.checkpoint
+    def rows_nll(x_c, l_c):
+        return _chunk_nll(x_c, l_c, wa, ba, transpose_y, ignore_index)[0]
+
+    _, per_row = jax.lax.scan(
+        lambda carry, xl: (carry, rows_nll(*xl)), None,
+        _chunked(xa, lab, chunk, ignore_index))
+    return per_row.reshape(-1)[:xa.shape[0]]
+
+
+def _head_loss_sums(xa, wa, ba, lab, transpose_y, ignore_index, chunk):
+    """``(sum of the rows' losses, rows counted)`` of ``x (rows, H)`` chunk
+    by chunk, with a hand-written VJP: differentiated, the forward's scan
+    takes each chunk's gradient while its logits are at hand, so a step
+    multiplies by the vocabulary three times and keeps arrays of ``x``'s and
+    the table's shape, never of (rows, vocabulary)."""
+    n, h = xa.shape
+
+    def zeros():        # inside each rule: a rule closes over no traced value
+        return jnp.zeros((), jnp.float32), jnp.zeros((), jnp.int32)
+
+    def loss_only(xa, wa, ba, lab):
+        def body(sums, xl):
+            nll, valid, *_ = _chunk_nll(*xl, wa, ba, transpose_y,
+                                        ignore_index)
+            return (sums[0] + jnp.sum(nll),
+                    sums[1] + jnp.sum(valid, dtype=jnp.int32)), None
+
+        return jax.lax.scan(body, zeros(),
+                            _chunked(xa, lab, chunk, ignore_index))[0]
+
+    def with_gradients(xa, wa, ba, lab):
+        classes = jnp.arange(wa.shape[0 if transpose_y else 1],
+                             dtype=jnp.int32)
+        over_rows = (((0,), (0,)), ((), ()))    # contract the chunk's rows
+
+        def body(carry, xl):
+            total, count, dw, db = carry
+            nll, valid, logits, lse, safe = _chunk_nll(
+                *xl, wa, ba, transpose_y, ignore_index)
+            # d(sum of losses) / d(logits): softmax - onehot on the counted
+            # rows, rounded to the logits' dtype as the unfused path's cast
+            # rounds its cotangent
+            d = jnp.exp(logits.astype(jnp.float32) - lse[:, None])
+            d = jnp.where(valid[:, None],
+                          d - (classes == safe[:, None]), 0.0)
+            d = d.astype(logits.dtype)
+            dx_c = (d @ wa) if transpose_y else (d @ wa.T)
+            dw = dw + jax.lax.dot_general(
+                *((d, xl[0]) if transpose_y else (xl[0], d)), over_rows,
+                preferred_element_type=jnp.float32)
+            if ba is not None:
+                db = db + jnp.sum(d, axis=0, dtype=jnp.float32)
+            return (total + jnp.sum(nll),
+                    count + jnp.sum(valid, dtype=jnp.int32), dw,
+                    db), dx_c.astype(xa.dtype)
+
+        (total, count, dw, db), dx = jax.lax.scan(
+            body, zeros() + (jnp.zeros(wa.shape, jnp.float32),
+                             None if ba is None
+                             else jnp.zeros(ba.shape, jnp.float32)),
+            _chunked(xa, lab, chunk, ignore_index))
+        return (total, count), (dx.reshape(-1, h)[:n], dw, db)
+
+    def scaled(kept, cts):
+        dx, dw, db = kept
+        g = cts[0]                  # the count's cotangent is a float0
+        return ((g * dx).astype(xa.dtype), (g * dw).astype(wa.dtype),
+                None if db is None else (g * db).astype(ba.dtype), None)
+
+    sums = jax.custom_vjp(loss_only)
+    sums.defvjp(with_gradients, scaled)
+    return sums(xa, wa, ba, lab)
+
+
+def _data_axes_dividing(batch: int):
+    """``(mesh, its data axes)`` where a mesh is set, the caller is in no
+    manual region yet and the axes' devices divide ``batch``; else
+    ``(None, None)``."""
+    from ...distributed import mesh as mesh_mod
+    if mesh_mod.has_mesh() and not \
+            jax.sharding.get_abstract_mesh().manual_axes:
+        mesh = mesh_mod.get_mesh()
+        axes = mesh_mod.axes_dividing(mesh, batch, ("dp", "sharding"))
+        if axes:
+            return mesh, axes
+    return None, None
+
+
+@functools.lru_cache(maxsize=64)
+def _head_loss_program(transpose_y, ignore_index, reduction, chunk_rows,
+                       mesh, axes):
+    """``(x, table, labels[, bias]) -> rows' losses`` ("none") or ``(sums,
+    counts)``, one entry a data shard: ``local`` over a device's rows,
+    inside a ``shard_map`` over ``axes`` of ``mesh`` where there are any
+    (jitted there, so that an eager call runs one program too)."""
+
+    def local(xa, wa, lab, *b):
+        lead = xa.shape[:-1]
+        x2, l1 = xa.reshape(-1, xa.shape[-1]), lab.reshape(-1)
+        args = (x2, wa, b[0] if b else None, l1, transpose_y, ignore_index,
+                min(chunk_rows, x2.shape[0]))
+        if reduction == "none":
+            return _head_loss_rows(*args).reshape(lead)
+        total, count = _head_loss_sums(*args)
+        return total[None], count[None]
+
+    if not axes:
+        return local
+    from jax.sharding import PartitionSpec as P
+    from ...distributed.shard_map_compat import shard_map
+
+    def head_loss_shards(xa, wa, lab, *b):
+        return shard_map(local, mesh,
+                         in_specs=(P(axes), P(), P(axes)) + (P(),) * len(b),
+                         out_specs=P(axes), axis_names=axes)(xa, wa, lab, *b)
+
+    return jax.jit(head_loss_shards)
+
+
 def fused_linear_cross_entropy(x, weight, label, bias=None,
                                transpose_y=False, ignore_index=-100,
-                               reduction="mean", chunk_rows=4096):
+                               reduction="mean",
+                               chunk_rows=HEAD_LOSS_CHUNK_ROWS):
     """Cross entropy of ``x @ weight (+ bias)`` against hard ``label``
-    WITHOUT materializing the full ``(N, V)`` logits tensor.
+    WITHOUT materializing the ``(rows, vocabulary)`` logits, in any dtype.
 
     TPU-native fusion of the LM-head matmul with the loss (the reference
-    computes them as two ops — ``matmul`` then ``cross_entropy_with_softmax``
-    — which forces the ``(batch*seq, vocab)`` logits through HBM twice in
-    forward and again in backward). Here the rows are processed in
-    ``chunk_rows`` slices under ``jax.lax.scan``; each slice's logits are
-    a transient and are REcomputed inside backward (``jax.checkpoint``), so
-    peak memory is ``O(chunk_rows * V)`` and the logits never round-trip
-    HBM between ops. The streaming max/lse accumulate in f32 while the
-    matmul stays in the input dtype (bf16 under AMP).
+    computes them as two ops, ``matmul`` then ``cross_entropy_with_softmax``,
+    which sends the logits through HBM twice forward and again backward,
+    in float32 for the loss). The rows run ``chunk_rows`` at a time under
+    ``jax.lax.scan``; only a chunk's logits and their gradient are ever
+    alive. ``reduction`` "mean" and "sum" have a hand-written VJP: a
+    differentiated call takes each chunk's gradient in the forward's scan
+    while its logits are at hand (``softmax - onehot`` on the counted rows,
+    its product with the table: the rows' gradient; its product with the
+    chunk's rows, summed in float32 over the chunks: the table's; its column
+    sums: the bias's), keeps those, and the backward scales them by the
+    incoming cotangent. That is THREE products with the vocabulary a step,
+    the unfused path's count. A call that is not differentiated runs the
+    loss alone. ``reduction="none"`` (a cotangent a row) rematerialises each
+    chunk's logits in the backward instead, four products. The logits are
+    what the unfused ``matmul`` gives (bf16 under O1 autocast, out of
+    float32 accumulation); max, logsumexp, the picked logit and the loss
+    are float32.
 
-    Args follow ``cross_entropy``; ``x`` is ``(N, H)`` (callers flatten
-    batch/seq), ``weight`` is ``(H, V)`` (or ``(V, H)`` with
-    ``transpose_y=True`` for embedding-tied heads), ``label`` is ``(N,)``.
-    ``reduction`` in {"mean", "sum", "none"}; mean averages over
-    non-ignored rows.
+    ``x`` is ``(..., H)``, ``weight`` ``(H, V)`` (or ``(V, H)`` with
+    ``transpose_y=True`` for embedding-tied heads), ``label`` ``x``'s
+    leading shape; rows whose label is ``ignore_index`` count for nothing;
+    "mean" averages over the others; "none" returns ``label``'s shape.
+    Under a mesh whose data axes (``dp``, ``sharding``) divide ``x``'s
+    first dimension the scan runs inside a ``shard_map`` over them: a device
+    chunks its OWN rows (the chunk axis is never the partitioned one), and
+    the table's gradient is reduced over the data axes once, after the scan.
+
+    ``chunk_rows``: 4096 rows of a 50 257-wide vocabulary are 412 MB of bf16
+    logits; ``head_loss_plan`` (stamped on the traced program's
+    ``compile.trace`` entry) says what a call was built with.
     """
     x, weight, label = _t(x), _t(weight), _t(label)
     inputs = [x, weight, label]
@@ -690,52 +905,23 @@ def fused_linear_cross_entropy(x, weight, label, bias=None,
         inputs.append(_t(bias))
 
     def f(xa, wa, lab, *b):
-        n, h = xa.shape
+        lead, h = xa.shape[:-1], xa.shape[-1]
+        n = math.prod(lead)
         if n == 0:      # e.g. seq_len==1 -> empty shifted labels
             if reduction == "none":
-                return jnp.zeros((0,), jnp.float32)
+                return jnp.zeros(lead, jnp.float32)
             return jnp.asarray(0.0, jnp.float32)
-        chunk = min(chunk_rows, n)
-        pad = (-n) % chunk
-        if pad:
-            xa = jnp.concatenate(
-                [xa, jnp.zeros((pad, h), xa.dtype)], axis=0)
-            lab = jnp.concatenate(
-                [lab, jnp.full((pad,), ignore_index, lab.dtype)], axis=0)
-        n_chunks = xa.shape[0] // chunk
-        xc = xa.reshape(n_chunks, chunk, h)
-        lc = lab.reshape(n_chunks, chunk)
-
-        def chunk_nll(x_c, l_c):
-            logits = (x_c @ wa.T) if transpose_y else (x_c @ wa)
-            if has_b:
-                logits = logits + b[0]
-            m = jax.lax.stop_gradient(
-                jnp.max(logits, axis=-1, keepdims=True))
-            shifted = (logits - m).astype(jnp.float32)
-            lse = jnp.log(jnp.sum(jnp.exp(shifted), axis=-1)) \
-                + jnp.squeeze(m.astype(jnp.float32), -1)
-            l_i = l_c.astype(jnp.int32)
-            valid = l_i != ignore_index
-            safe = jnp.where(valid, l_i, 0)
-            picked = jnp.squeeze(jnp.take_along_axis(
-                logits, safe[:, None], axis=-1), -1)
-            nll = jnp.where(valid, lse - picked.astype(jnp.float32), 0.0)
-            return nll, valid
-
-        chunk_nll = jax.checkpoint(chunk_nll)
-
-        def body(carry, xl):
-            s, c = carry
-            nll, valid = chunk_nll(*xl)
-            return (s + jnp.sum(nll), c + jnp.sum(valid)), \
-                (nll if reduction == "none" else None)
-
-        (total, count), per_row = jax.lax.scan(
-            body, (jnp.asarray(0.0, jnp.float32),
-                   jnp.asarray(0, jnp.int32)), (xc, lc))
+        mesh, axes = _data_axes_dividing(lead[0])
+        shards = math.prod(mesh.shape[a] for a in axes) if axes else 1
+        _stamp_head_loss_plan(head_loss_plan(
+            n // shards, chunk_rows, wa.shape[0 if transpose_y else 1], h,
+            xa.dtype, reduction != "none"))
+        out = _head_loss_program(
+            transpose_y, ignore_index, reduction, chunk_rows, mesh, axes)(
+                xa, wa, lab, *b)
         if reduction == "none":
-            return per_row.reshape(-1)[:n]
+            return out
+        total, count = jnp.sum(out[0]), jnp.sum(out[1])
         if reduction == "sum":
             return total
         return total / jnp.maximum(count, 1)

@@ -251,6 +251,22 @@ def cross_entropy_cost(input_shapes, input_dtypes, attrs,
     return OpCost(6.0 * n, read, written, "softmax+nll")
 
 
+def fused_linear_cross_entropy_cost(input_shapes, input_dtypes, attrs,
+                                    output_shapes) -> OpCost:
+    """Head and loss in one op, as a training step pays for it: THREE
+    products of rows x hidden x vocabulary (logits, the rows' gradient, the
+    table's: the op's forward takes the gradients while a chunk's logits
+    are at hand) and ~6 flops a logit of softmax + nll. The logits never
+    leave the op: its traffic is the rows, the table and the labels."""
+    x, table = tuple(input_shapes[0]), tuple(input_shapes[1])
+    hidden = int(x[-1])
+    rows = _numel(x) // max(hidden, 1)
+    vocab = _numel(table) // max(hidden, 1)
+    read, written = _io_bytes(input_shapes, input_dtypes, output_shapes)
+    return OpCost(3 * 2.0 * rows * hidden * vocab + 6.0 * rows * vocab,
+                  read, written, "3 x matmul + softmax+nll")
+
+
 def fused_residual_norm_cost(input_shapes, input_dtypes, attrs,
                              output_shapes) -> OpCost:
     """residual add (1) + norm (~8) flops/element; traffic = x +
@@ -362,8 +378,10 @@ def _fill_models():
     COST_MODELS["softmax"] = softmax_cost
     COST_MODELS["log_softmax"] = softmax_cost
     for name in ("cross_entropy", "softmax_with_cross_entropy",
-                 "fused_linear_cross_entropy", "bce_with_logits"):
+                 "bce_with_logits"):
         COST_MODELS[name] = cross_entropy_cost
+    COST_MODELS["fused_linear_cross_entropy"] = \
+        fused_linear_cross_entropy_cost
     for name in ("embedding", "gather", "gather_nd", "index_select",
                  "take_along_axis"):
         COST_MODELS[name] = gather_cost

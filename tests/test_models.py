@@ -19,8 +19,10 @@ def test_gpt_forward_loss_and_grad():
     m = GPTForCausalLM(tiny_gpt())
     ids = paddle.to_tensor(
         np.random.RandomState(0).randint(0, 128, (2, 16)).astype(np.int64))
-    logits, loss = m(ids, labels=ids)
-    assert logits.shape == [2, 16, 128]
+    assert m(ids).shape == [2, 16, 128]
+    # with labels the head's product is inside the loss: no logits exist
+    none, loss = m(ids, labels=ids)
+    assert none is None
     # initial loss ~ ln(vocab)
     assert 3.0 < float(loss) < 7.0
     loss.backward()

@@ -454,3 +454,44 @@ def test_gpt2_train_step_runs_each_forward_kernel_once(one_chip,
         ("flash_fwd", "flash_dq", "flash_dkv"), layers)
     rows = re.findall(rf"= f32\[{batch * heads},{seq}\]\S* ", entry)
     assert len(rows) >= layers, "the logsumexp is not kept as rows"
+
+
+@pytest.mark.parametrize("chips", [1, 4], ids=["one-chip", "dp4"])
+def test_head_and_loss_compile_chunk_by_chunk_at_the_fit_cells_shape(
+        four_chips, monkeypatch, chips):
+    """GPT-2 medium's head + loss, forward and backward, at 8 x 1024 rows a
+    chip x 1024 x 50 257 under bf16 O1 autocast (``models/_head.py``): a
+    scan of two chunks whose temporaries are a chunk's bf16 logits and the
+    float32 table gradient, where the (8192, 50 257) float32 logits alone
+    are 1.65 GB; over ``dp`` 4 a chip chunks its own rows, nothing is
+    gathered, and the loss, the count and the table's gradient are each
+    reduced once, outside the loop."""
+    from paddle_tpu import amp
+    from paddle_tpu.core.tensor import Tensor
+    from paddle_tpu.distributed import mesh as mesh_mod
+    from paddle_tpu.models._head import next_token_loss
+
+    mesh = Mesh(np.asarray(four_chips[:chips]), ("dp",))
+    monkeypatch.setattr(mesh_mod, "_global_mesh", mesh)
+    rows, whole = NamedSharding(mesh, P("dp")), NamedSharding(mesh, P())
+
+    def loss(h, table, ids):
+        with amp.auto_cast(level="O1", dtype="bfloat16"):
+            return next_token_loss(Tensor(h), Tensor(table), Tensor(ids),
+                                   transpose_y=True)._data
+
+    compiled = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(
+        jax.ShapeDtypeStruct((8 * chips, 1024, 1024), jnp.float32,
+                             sharding=rows),
+        jax.ShapeDtypeStruct((50257, 1024), jnp.float32, sharding=whole),
+        jax.ShapeDtypeStruct((8 * chips, 1024), jnp.int32,
+                             sharding=rows)).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.8e9
+    text = compiled.as_text()
+    assert "bf16[2,4096,1024]" in text      # the scan's rows: two chunks
+    assert not re.search(r" all-(gather|to-all)(-start)?\(", text)
+    reduces = re.findall(r" all-reduce(?:-start)?\(", text)
+    assert len(reduces) == (3 if chips > 1 else 0)
+    body = re.search(r" while\(.*?body=%([\w.-]+)", text).group(1)
+    assert "all-reduce" not in re.search(
+        rf"(?ms)^%{re.escape(body)} \(.*?^}}", text).group(0)
