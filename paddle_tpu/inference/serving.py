@@ -307,80 +307,6 @@ def _pick_arch(model):
         f"serve_dense(); got {type(model).__name__}")
 
 
-def _tuned_decode_block_size(cfg, nkv, max_batch, max_blocks_per_seq,
-                             candidates=(8, 16, 32)) -> int:
-    """Measured KV page size for the decode tick on this chip.
-
-    Probes one paged-attention decode step (T=1, full batch) per
-    candidate on zero caches sized to the engine's real geometry — through
-    ``block_multihead_attention`` itself, so each candidate is timed on
-    the path an engine built with it would run (the decode kernel where a
-    page fills whole sublane tiles, else the composite); the winner
-    persists in the autotune cache (ops/pallas/autotune.py), so one
-    process per chip ever pays the probe. Off-TPU: 16.
-    """
-    from ..ops.pallas import autotune as at
-
-    default = 16
-    if not at.should_autotune():
-        return default
-    head_dim = cfg.hidden_size // cfg.num_heads
-    key = at.make_key("serving_decode_block", nkv=nkv, d=head_dim,
-                      b=max_batch)
-    cached = at.get_cache().get(key)
-    if cached is not None:
-        return int(cached)
-
-    import paddle_tpu.nn.functional as F
-    from ..core.tensor import Tensor
-
-    prepared = {}
-    nvar = 3
-
-    def run(bs, i):
-        entry = prepared.get(bs)
-        if entry is None:
-            import jax
-            nb = max_batch * max_blocks_per_seq + 1
-            kc = jnp.zeros((nb, bs, nkv, head_dim), jnp.bfloat16)
-            vc = jnp.zeros_like(kc)
-            tables = jnp.asarray(
-                np.arange(1, max_batch * max_blocks_per_seq + 1)
-                .reshape(max_batch, max_blocks_per_seq).astype(np.int32))
-            # mid-stream decode: every sequence half way into its pages
-            seq_lens = jnp.full((max_batch,),
-                                (max_blocks_per_seq // 2) * bs, jnp.int32)
-            # distinct probe queries per timed iteration (replay-caching
-            # backends fake repeat-identical executions)
-            q_vars = [jnp.asarray(np.random.RandomState(v).randn(
-                max_batch, 1, cfg.num_heads, head_dim), jnp.bfloat16)
-                for v in range(nvar)]
-            nk = jnp.asarray(np.random.RandomState(9).randn(
-                max_batch, 1, nkv, head_dim), jnp.bfloat16)
-
-            def tick(qa, kca, vca, ta, sla, nka):
-                out, _, _ = F.block_multihead_attention(
-                    Tensor(qa), Tensor(kca), Tensor(vca), Tensor(ta),
-                    Tensor(sla), new_k=Tensor(nka), new_v=Tensor(nka),
-                    causal=True)
-                return out._data
-
-            def chained(qa, kca, vca, ta, sla, nka):
-                # chain ticks data-dependently (out is q-shaped) so
-                # device time dominates per-call dispatch/transport
-                return jax.lax.fori_loop(
-                    0, 128,
-                    lambda _, qq: tick(qq, kca, vca, ta, sla, nka), qa)
-
-            entry = prepared[bs] = (jax.jit(chained), q_vars,
-                                    (kc, vc, tables, seq_lens, nk))
-        fn, q_vars, rest = entry
-        return fn(q_vars[i % nvar], *rest)
-
-    return int(at.autotune(key, list(candidates), run, default,
-                           warmup=2, iters=5))
-
-
 #: model -> {(arch name, program kind): jitted tick fn} — shared across
 #: engines of one model (entries die with the model; see
 #: PagedEngine.__init__)
@@ -895,7 +821,7 @@ class PagedEngine:
 
     @_trace.in_startup_phase("startup.engine_build")
     def __init__(self, model, *, max_batch: int = 8,
-                 block_size: Optional[int] = 16,
+                 block_size: int = 16,
                  num_blocks: int = 256, max_blocks_per_seq: int = 32,
                  eos_id: Optional[int] = None, seed: int = 0,
                  kv_dtype=None, scheduler=None, speculate=None,
@@ -922,12 +848,9 @@ class PagedEngine:
             # the exact steady-state program
             block_size = self.arch.width
             speculate = None
-        if block_size is None:
-            # measured choice for this chip/model-geometry (falls back to
-            # 16 off-TPU); ops/pallas/autotune.py caches winners on disk
-            block_size = _tuned_decode_block_size(
-                self.cfg, self.arch.num_kv_heads, max_batch,
-                max_blocks_per_seq)
+        if type(block_size) is not int or block_size < 1:
+            raise ValueError(
+                f"block_size must be a positive int, got {block_size!r}")
         self.block_size = block_size
         self.max_blocks_per_seq = max_blocks_per_seq
         self.eos_id = eos_id

@@ -99,8 +99,9 @@ class TestLlama:
             np.random.randint(0, 512, (2, 8)).astype(np.int64))
         for kv in (4, 2):     # MHA and GQA
             m = LlamaForCausalLM(self._tiny(num_kv_heads=kv))
-            a = m.generate(ids, max_new_tokens=6, use_cache=False).numpy()
-            b = m.generate(ids, max_new_tokens=6, use_cache=True).numpy()
+            # three tokens: one from the prefill, two from the cache
+            a = m.generate(ids, max_new_tokens=3, use_cache=False).numpy()
+            b = m.generate(ids, max_new_tokens=3, use_cache=True).numpy()
             np.testing.assert_array_equal(a, b)
 
     def test_sampled_decode_rng_parity(self):

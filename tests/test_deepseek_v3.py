@@ -22,6 +22,7 @@ against ``q . (W_uk c)``), not of a lower precision:
 from __future__ import annotations
 
 import copy
+import dataclasses
 import os
 import sys
 
@@ -33,55 +34,38 @@ import pytest
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
 
+sys.path.insert(0, os.path.join(ROOT, "tests"))
+
 import paddle_tpu as paddle  # noqa: E402
-from benchmark.drivers import serve_deepseek_v3 as driver  # noqa: E402
 from benchmark.lib import weights_deepseek_v3 as weights_lib  # noqa: E402
 from benchmark.reference import deepseek_v3 as ref  # noqa: E402
 from benchmark.tests.tiny_deepseek_v3 import DEEPSEEK  # noqa: E402
 from paddle_tpu import nn  # noqa: E402
 from paddle_tpu.core.tensor import Tensor  # noqa: E402
-from paddle_tpu.inference import PagedEngine, serving  # noqa: E402
-from paddle_tpu.models import DeepseekV3ForCausalLM  # noqa: E402
+from paddle_tpu.inference import serving  # noqa: E402
 from paddle_tpu.nn.functional import paged_attention as fpa  # noqa: E402
-from paddle_tpu.serving import Router, SchedulerConfig  # noqa: E402
+
+import served  # noqa: E402
+from served import (close, models, rand, rec, recording,  # noqa: E402,F401
+                    traced)
 
 SEED = 5
 TIGHT = 2e-5
 LOGITS = 5e-4
 CFG = DEEPSEEK
 ROW = CFG["kv_lora_rank"] + CFG["qk_rope_head_dim"]
+CASE = served.Case(
+    "latent", CFG, SEED, budget=16, atol=LOGITS,
+    reference=lambda ids: ref.logits(CFG, SEED, ids, block=16))
 
 
 def f32_weights(cfg, seed, layers=None):
-    """The table's bf16 draws upcast to float32: what the reference reads."""
-    made = weights_lib.make(cfg, seed, jnp.bfloat16, layers=layers)
-    return {k: v.astype(jnp.float32) for k, v in made.items()}
-
-
-def fresh_model(cfg=CFG, dtype=jnp.float32):
-    """A model of its own: the compiled programs of a shared one are
-    shared too, and each test records through its own."""
-    m = DeepseekV3ForCausalLM(driver.model_config(cfg))
-    # matrices in ``dtype``; norm scales and the router's bias stay float32
-    driver.put_weights(m, {k: v.astype(dtype) if v.ndim > 1 else v
-                           for k, v in f32_weights(cfg, SEED).items()})
-    m.eval()
-    return m
+    return served.f32_weights(weights_lib, cfg, seed, layers)
 
 
 @pytest.fixture(scope="module")
 def model():
-    return fresh_model()
-
-
-def rand(shape, seed, scale=1.0):
-    return jnp.asarray(np.random.RandomState(seed).randn(*shape) * scale,
-                       jnp.float32)
-
-
-def close(got, want, atol):
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=atol,
-                               rtol=0)
+    return served.model_of(CASE)
 
 
 # ======================================================== latent attention
@@ -92,7 +76,8 @@ def test_absorbed_form_is_the_materialised_one(model):
     onto the output."""
     attn = model.model.layers[1].self_attn
     u = Tensor(rand((2, 19, 64), 3))
-    close(attn.forward_absorbed(u)._data, attn(u)._data, TIGHT)
+    close(traced(attn.forward_absorbed, u)._data, traced(attn, u)._data,
+          TIGHT)
 
 
 def test_attention_layer_is_the_references(model):
@@ -111,7 +96,7 @@ def test_attention_layer_is_the_references(model):
     # the reference norms its input; hand the layer the normed rows
     u = x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True)
                           + CFG["rms_norm_eps"])
-    got = model.model.layers[1].self_attn(Tensor(u[None]))._data[0]
+    got = traced(model.model.layers[1].self_attn, Tensor(u[None]))._data[0]
     close(got, want, TIGHT)
 
 
@@ -120,9 +105,10 @@ def test_the_rotary_layout_shows_in_the_logits(model):
     half-split (``rope_interleave`` off) moves a logit by far more than
     ``LOGITS``: the comparisons below would see the wrong layout."""
     ids = np.random.RandomState(3).randint(1, CFG["vocab_size"], (1, 30))
-    want = model(paddle.to_tensor(ids.astype(np.int32)))._data
-    other = fresh_model(dict(CFG, rope_interleave=False))
-    moved = other(paddle.to_tensor(ids.astype(np.int32)))._data
+    want = traced(model, paddle.to_tensor(ids.astype(np.int32)))._data
+    other = served.build(dataclasses.replace(
+        CASE, cfg=dict(CFG, rope_interleave=False)))
+    moved = traced(other, paddle.to_tensor(ids.astype(np.int32)))._data
     assert float(jnp.abs(moved - want).max()) > 20 * LOGITS
 
 
@@ -176,8 +162,8 @@ def test_shares_add_up_to_the_uncut_layer():
     total = shared = None
     for lo in range(0, 128, 16):
         layer = moe_layer(cfg, lw, (lo, lo + 16))
-        shared = layer.shared(Tensor(u.reshape(-1, 64)))._data
-        out = layer(Tensor(u))._data.reshape(-1, 64)
+        shared = traced(layer.shared, Tensor(u.reshape(-1, 64)))._data
+        out = traced(layer, Tensor(u))._data.reshape(-1, 64)
         part = dict(lw, **{k: lw[k][lo:lo + 16]
                            for k in ("w_gate", "w_up", "w_down")})
         close(out, ref_moe(part, (lo, lo + 16)), TIGHT)
@@ -193,7 +179,7 @@ def test_whole_sequence_forward_is_the_reference(model, tokens):
     published (materialised) form."""
     ids = np.random.RandomState(tokens).randint(
         1, CFG["vocab_size"], (2, tokens)).astype(np.int32)
-    got = model(paddle.to_tensor(ids))._data
+    got = traced(model, paddle.to_tensor(ids))._data
     for row in range(2):
         close(got[row], ref.logits(CFG, SEED, ids[row], block=16), LOGITS)
 
@@ -204,8 +190,8 @@ def test_bfloat16_would_fail_the_tolerance():
     precisions apart."""
     ids = np.random.RandomState(9).randint(
         1, CFG["vocab_size"], (1, 24)).astype(np.int32)
-    low = fresh_model(dtype=jnp.bfloat16)
-    got = low(paddle.to_tensor(ids))._data.astype(jnp.float32)
+    low = served.build(CASE, dtype=jnp.bfloat16)
+    got = traced(low, paddle.to_tensor(ids))._data.astype(jnp.float32)
     want = ref.logits(CFG, SEED, ids[0], block=16)
     assert float(jnp.abs(got[0] - want).max()) > 20 * LOGITS
 
@@ -214,101 +200,28 @@ def test_whole_sequence_forward_is_differentiable():
     """Eager autograd reaches every parameter through latent attention and
     the expert product (the router's correction bias only steers a choice:
     its gradient is zero)."""
-    m = fresh_model()
-    m.train()
-    ids = paddle.to_tensor(np.random.RandomState(1).randint(
-        1, CFG["vocab_size"], (2, 12)).astype(np.int32))
-    out = m(ids)
-    (out * out).mean().backward()
-    for name, p in m.named_parameters():
-        assert p.grad is not None, name
-        g = p.grad.numpy()
-        assert np.isfinite(g).all(), name
-        if not name.endswith("e_score_correction_bias"):
-            assert np.abs(g).max() > 0, name
+    served.whole_sequence_forward_is_differentiable(
+        CASE, np.random.RandomState(1).randint(
+            1, CFG["vocab_size"], (2, 12)).astype(np.int32))
 
 
 # ====================================================== through the engine
-class Recorder:
-    """The logits every program call samples from, keyed by (request,
-    tokens generated so far): ``serving._sample_tokens`` wrapped with a
-    host callback. A lane that ran under the ``seq = 0`` sentinel writes
-    garbage under its key and the real step overwrites it later."""
-
-    def __init__(self, monkeypatch):
-        self.rows = {}
-        inner = serving._sample_tokens
-
-        def sample(logits, temps, top_ps, base_key, rids, ngens, sampling):
-            jax.debug.callback(self.note, logits, rids, ngens, ordered=True)
-            return inner(logits, temps, top_ps, base_key, rids, ngens,
-                         sampling)
-
-        monkeypatch.setattr(serving, "_sample_tokens", sample)
-
-    def note(self, logits, rids, ngens):
-        for row, rid, n in zip(np.asarray(logits), np.asarray(rids),
-                               np.asarray(ngens)):
-            if rid:
-                self.rows[(int(rid), int(n))] = row
-
-
-def check_against_reference(rec, rid, prompt, served, atol=LOGITS):
-    ids = np.asarray(list(prompt) + list(served[:-1]), np.int32)
-    want = np.asarray(ref.logits(CFG, SEED, ids, block=16))
-    for n in range(len(served)):
-        close(rec.rows[(rid, n)], want[len(prompt) - 1 + n], atol)
-
-
-def prompts_of(lengths, seed=0):
-    rng = np.random.RandomState(seed)
-    return [rng.randint(1, CFG["vocab_size"], n).tolist() for n in lengths]
-
-
-def engine(model, **kw):
-    kw.setdefault("max_batch", 4)
-    kw.setdefault("block_size", 8)
-    kw.setdefault("num_blocks", 64)
-    kw.setdefault("max_blocks_per_seq", 16)
-    kw.setdefault("scheduler", SchedulerConfig(prefill_token_budget=16))
-    return PagedEngine(model, **kw)
-
-
 @pytest.mark.parametrize("front", ["engine", "router"])
-def test_served_logits_are_the_references(monkeypatch, front):
+def test_served_logits_are_the_references(rec, front):
     """Prefill in one to five chunks of 16 (left-padded first chunk where
     the prompt is no multiple of 16), then decode through the latent pages,
     four requests of unequal length sharing the batch: every logits row the
     programs sampled from against the reference's full forward over prompt
     + served tokens."""
-    rec = Recorder(monkeypatch)
-    eng = engine(fresh_model())
-    assert eng.prefill_width == 16
-    prompts = prompts_of((5, 40, 70, 32))
-    if front == "router":
-        door = Router([eng]).warmup()     # placement needs a READY replica
-        jax.effects_barrier()
-        rec.rows.clear()                  # the warm-up request's rows
-        rids = [door.add_request(p, max_new_tokens=10) for p in prompts]
-        while door.has_work():
-            door.step()
-        served = {r: door.outcomes[r].tokens for r in rids}
-        assert all(door.outcomes[r].status == "FINISHED" for r in rids)
-    else:
-        rids = [eng.add_request(p, max_new_tokens=10) for p in prompts]
-        served = eng.run_to_completion()
-    jax.effects_barrier()
-    engine_rids = sorted({rid for rid, _n in rec.rows})
-    assert len(engine_rids) == 4
-    for erid, rid, p in zip(engine_rids, rids, prompts):
-        check_against_reference(rec, erid, p, served[rid])
+    eng, _prompts, _ = served.served_logits_are_the_references(
+        CASE, rec, front, width=16, new=10)
     load = eng.expert_load()
     assert load["layers"] == [1, 2]
     assert [sum(t) for t in load["tokens"]] == load["pairs_held"]
 
 
 @pytest.mark.parametrize("width", [1, 7, 256])
-def test_chunk_widths_and_the_blockwise_prefix(monkeypatch, width):
+def test_chunk_widths_and_the_blockwise_prefix(monkeypatch, rec, width):
     """Prefill in chunks of 8 (a budget of 1 or 7 tokens rounds up to one
     block) or of 256 (the engine's widest chunk, the benchmark's: a
     150-token prompt in one left-padded chunk, a 300-token prompt in two),
@@ -322,84 +235,62 @@ def test_chunk_widths_and_the_blockwise_prefix(monkeypatch, width):
     inner = fpa._blockwise_rows
     monkeypatch.setattr(fpa, "_blockwise_rows",
                         lambda *a: (seen.append(a[0].shape), inner(*a))[1])
-    rec = Recorder(monkeypatch)
     # functions jitted at module level keep traces made under other values
     fpa._latent_write_and_attend.clear_cache()
     try:
-        eng = engine(fresh_model(), max_batch=32 if width == 256 else 2,
-                     num_blocks=128, max_blocks_per_seq=40,
-                     scheduler=SchedulerConfig(prefill_token_budget=width))
+        # a model of its own: its programs are traced under the patches
+        eng = served.engine(CASE, model=served.build(CASE), budget=width,
+                            max_batch=32 if width == 256 else 2,
+                            num_blocks=128, max_blocks_per_seq=40)
         assert eng.prefill_width == (256 if width == 256 else 8)
-        prompts = prompts_of((150, 300 if width == 256 else 21), seed=width)
+        prompts = served.prompts_of(
+            CASE, (150, 300 if width == 256 else 21), seed=width)
         rids = [eng.add_request(p, max_new_tokens=6) for p in prompts]
-        served = eng.run_to_completion(max_ticks=400)
+        out = eng.run_to_completion(max_ticks=400)
         jax.effects_barrier()
     finally:
         fpa._latent_write_and_attend.clear_cache()
     widths = {shape[1] for shape in seen}
     assert widths == {eng.prefill_width, 1}
     assert all(s[-1] == eng._latent_row(ROW) for s in seen)
-    for rid, p in zip(rids, prompts):
-        check_against_reference(rec, rid, p, served[rid])
+    served.check_served(CASE, rec, out, prompts, rids)
 
 
-def test_a_reused_slot_sees_nothing_of_its_last_request(monkeypatch):
-    """One slot, two requests one after the other, the second shorter than
-    the pages the first left behind (freed and handed out again): its
-    logits are the reference's, which starts from nothing."""
-    rec = Recorder(monkeypatch)
-    eng = engine(fresh_model(), max_batch=1)
-    first, second = prompts_of((45, 13), seed=1)
-    a = eng.add_request(first, max_new_tokens=5)
-    out_a = eng.run_to_completion()[a]
-    b = eng.add_request(second, max_new_tokens=5)
-    out_b = eng.run_to_completion()[b]
-    jax.effects_barrier()
-    check_against_reference(rec, a, first, out_a)
-    check_against_reference(rec, b, second, out_b)
+def test_a_reused_slot_sees_nothing_of_its_last_request(rec):
+    """The second request is shorter than the pages the first left behind
+    (freed and handed out again)."""
+    served.a_reused_slot_starts_clean(CASE, rec, (45, 13))
 
 
-def test_evict_then_readmit_reproduces_the_logits(monkeypatch):
-    """Every lane stalled: one is preempted, its latent pages freed, and it
-    is re-prefilled over prompt + generated tokens later, as ``paged_kv``
+def test_evict_then_readmit_reproduces_the_logits(rec):
+    """Its latent pages are freed and it is re-prefilled, as ``paged_kv``
     pages are."""
-    rec = Recorder(monkeypatch)
-    eng = engine(fresh_model(), num_blocks=7, max_blocks_per_seq=6)
-    evicted = []
-    evict = eng._evict
-    eng._evict = lambda slot: (evicted.append(slot), evict(slot))[-1]
-    p, q = prompts_of((12, 12), seed=4)
-    a = eng.add_request(p, max_new_tokens=20)
-    b = eng.add_request(q, max_new_tokens=20)
-    served = eng.run_to_completion(max_ticks=400)
-    assert evicted
-    jax.effects_barrier()
-    check_against_reference(rec, a, p, served[a])
-    check_against_reference(rec, b, q, served[b])
+    served.evict_then_readmit_reproduces_the_logits(
+        CASE, rec, usable=6, length=12, new=20, max_ticks=400)
 
 
-def test_speculate_serves_the_same_tokens(model):
+def test_speculate_serves_the_same_tokens():
     """``speculate=`` over latent pages needs no rollback: a rejected
     draft's row sits past the sequence's length, where nothing reads it,
     and the next step writes over it (as with ``paged_kv``). The verify
     step (T = k + 1) attends through the composite; greedy tokens are the
     plain engine's."""
-    prompts = prompts_of((9, 30), seed=6)
+    prompts = served.prompts_of(CASE, (9, 30), seed=6)
     # a repeating prompt gives the n-gram proposer something to accept
     prompts.append((prompts[0][:4] * 6)[:22])
-    plain = engine(fresh_model())
+    plain = served.engine(CASE)
     rids = [plain.add_request(p, max_new_tokens=12) for p in prompts]
     want = plain.run_to_completion()
-    spec = engine(fresh_model(), speculate="ngram")
+    spec = served.engine(CASE, speculate="ngram")
     sids = [spec.add_request(p, max_new_tokens=12) for p in prompts]
     got = spec.run_to_completion()
     assert [got[s] for s in sids] == [want[r] for r in rids]
     assert spec.spec_proposed > 0
 
 
-def test_int8_pages_are_refused_by_name(model):
+def test_int8_pages_are_refused_by_name():
     with pytest.raises(TypeError, match="latent_kv.*no int8 form"):
-        engine(model, kv_dtype="int8")
+        served.engine(CASE, kv_dtype="int8")
 
 
 def test_latent_pages_are_counted(model):
@@ -411,7 +302,7 @@ def test_latent_pages_are_counted(model):
     prev = flags.get_flag("enable_metrics")
     paddle.set_flags({"FLAGS_enable_metrics": True})
     try:
-        eng = engine(model, num_blocks=32)
+        eng = served.engine(CASE, num_blocks=32)
     finally:
         paddle.set_flags({"FLAGS_enable_metrics": prev})
     layout = model.paged_adapter().cache_layout(jnp.float32)
@@ -436,12 +327,12 @@ def test_latent_pages_are_counted(model):
         serving._cache_index([("ring",)])
 
 
-def test_scopes_are_in_the_lowered_programs(model):
+def test_scopes_are_in_the_lowered_programs():
     """``attn.mla`` with ``attn.mla.proj`` and ``attn.mla.core`` inside it,
     ``moe`` with its three parts, ``mlp``, ``embed``, ``lm_head`` and,
     inside the core, ``paged_attention``, in the ``op_name`` of both
     serving programs."""
-    eng = engine(model)
+    eng = served.engine(CASE)
     args = eng._chunk_args(
         np.zeros((4, 1), np.int32), np.ones((4,), np.int32), eng.tables,
         np.zeros((4,), np.float32), np.ones((4,), np.float32),

@@ -59,6 +59,13 @@ def loop(q, k, v, alpha_log, beta, state, valid):
     return out, s
 
 
+#: the rule's functions as ONE traced program a shape (what the models run),
+#: not a dispatch and a compile an operation
+chunk_arrays = jax.jit(dr.chunk_arrays, static_argnums=(7,))
+step_arrays = jax.jit(dr.step_arrays, static_argnames=("packed",))
+conv_arrays = jax.jit(dr.conv_arrays)
+
+
 def close(got, want, atol=TIGHT):
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=atol,
                                rtol=0)
@@ -86,7 +93,7 @@ def test_chunked_form_is_the_token_loop(tokens, chunk, kind, carried):
              if carried else np.zeros((B, H, DK, DV)))
     valid = ragged(tokens, kind)
     want_o, want_s = loop(q, k, v, alpha_log, beta, state, valid)
-    got_o, got_s = dr.chunk_arrays(
+    got_o, got_s = chunk_arrays(
         *map(jnp.asarray, (q, k, v, alpha_log, beta, state)),
         jnp.asarray(valid), chunk)
     live = valid[:, :, None, None]
@@ -233,13 +240,12 @@ def test_a_chunk_continues_from_the_carried_state(cuts):
     q, k, v, alpha_log, beta = map(jnp.asarray, inputs(tokens, seed=9))
     valid = jnp.ones((B, tokens), bool)
     zero = jnp.zeros((B, H, DK, DV))
-    whole_o, whole_s = dr.chunk_arrays(q, k, v, alpha_log, beta, zero, valid,
-                                       64)
+    whole_o, whole_s = chunk_arrays(q, k, v, alpha_log, beta, zero, valid, 64)
     s, parts = zero, []
     for lo, hi in zip((0,) + cuts, cuts + (tokens,)):
-        o, s = dr.chunk_arrays(*(a[:, lo:hi] for a in
-                                 (q, k, v, alpha_log, beta)), s,
-                               valid[:, lo:hi], 64)
+        o, s = chunk_arrays(*(a[:, lo:hi] for a in
+                              (q, k, v, alpha_log, beta)), s,
+                            valid[:, lo:hi], 64)
         parts.append(o)
     close(jnp.concatenate(parts, axis=1), whole_o)
     close(s, whole_s)
@@ -288,16 +294,16 @@ def test_decode_kernel_is_the_composite(interpreted, shape):
     idle = jnp.zeros((bsz,), bool).at[bsz - 1].set(True)
     assert kernel.supports(state.shape, dk, p)
     assert dr.use_step_kernel(state.shape, dk, p)
-    want_o, want_s = dr.step_arrays(q, k, v, alpha, beta, state, fresh, idle,
-                                    p)
+    want_o, want_s = step_arrays(q, k, v, alpha, beta, state, fresh, idle,
+                                 packed=p)
     got_o, got_s = kernel.delta_rule_step(q, k, v, alpha, beta, state, fresh,
                                           idle, p)
     close(got_o[:-1], want_o[:-1])          # an idle lane's row means nothing
     close(got_s, want_s)
     assert np.array_equal(np.asarray(got_s[-1]), np.asarray(state[-1]))
     # the fresh lane: as from a zero state
-    zero_o, zero_s = dr.step_arrays(q[:1], k[:1], v[:1], alpha[:1], beta[:1],
-                                    jnp.zeros_like(state[:1]), packed=p)
+    zero_o, zero_s = step_arrays(q[:1], k[:1], v[:1], alpha[:1], beta[:1],
+                                 jnp.zeros_like(state[:1]), packed=p)
     close(got_o[0], zero_o[0])
     close(got_s[0], zero_s[0])
 
@@ -345,12 +351,12 @@ def test_convolution_carries_its_window(bsz):
     x = jnp.asarray(rng.normal(size=(bsz, 20, 24)), jnp.float32)
     w = jnp.asarray(rng.uniform(-0.5, 0.5, size=(24, 4)), jnp.float32)
     window = jnp.asarray(rng.normal(size=(bsz, 3, 24)), jnp.float32)
-    got, last = dr.conv_arrays(x, w, window)
+    got, last = conv_arrays(x, w, window)
     want, want_last = ssm.conv_arrays(x, w, jnp.zeros((24,)), window)
     close(got, want)
     assert np.array_equal(np.asarray(last), np.asarray(want_last))
-    first, mid = dr.conv_arrays(x[:, :9], w, window)
-    second, end = dr.conv_arrays(x[:, 9:], w, mid)
+    first, mid = conv_arrays(x[:, :9], w, window)
+    second, end = conv_arrays(x[:, 9:], w, mid)
     close(jnp.concatenate([first, second], axis=1), want)
     assert np.array_equal(np.asarray(end), np.asarray(want_last))
 

@@ -30,52 +30,39 @@ import pytest
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
 
+sys.path.insert(0, os.path.join(ROOT, "tests"))
+
 import paddle_tpu as paddle  # noqa: E402
-from benchmark.drivers import serve_exaone_moe as driver  # noqa: E402
 from benchmark.lib import weights_exaone_moe as weights_lib  # noqa: E402
 from benchmark.reference import exaone_moe as ref  # noqa: E402
 from benchmark.tests.tiny_exaone_moe import EXAONE  # noqa: E402
 from paddle_tpu import nn  # noqa: E402
 from paddle_tpu.core.tensor import Tensor  # noqa: E402
-from paddle_tpu.inference import PagedEngine, serving  # noqa: E402
-from paddle_tpu.models import ExaoneMoeForCausalLM  # noqa: E402
-from paddle_tpu.serving import Router, SchedulerConfig  # noqa: E402
+from paddle_tpu.inference import serving  # noqa: E402
+
+import served  # noqa: E402
+from served import (close, models, rand, rec, recording,  # noqa: E402,F401
+                    traced)
 
 SEED = 5
 TIGHT = 2e-5
 LOGITS = 5e-4
 CFG = EXAONE
 WINDOW = CFG["sliding_window"]
+#: chunks of 16 tokens: window rows 8 + 16 = 24 a lane, so a prompt of 40
+#: wraps them, and three chunks prefill it
+CASE = served.Case(
+    "window", CFG, SEED, budget=16, atol=LOGITS,
+    reference=lambda ids: ref.logits(CFG, SEED, ids, block=16))
 
 
 def f32_weights(cfg, seed, layers=None):
-    """The table's bf16 draws upcast to float32: what the reference reads."""
-    made = weights_lib.make(cfg, seed, jnp.bfloat16, layers=layers)
-    return {k: v.astype(jnp.float32) for k, v in made.items()}
-
-
-def fresh_model(cfg=CFG):
-    """A model of its own: the compiled programs of a shared one are
-    shared too, and each test records through its own."""
-    m = ExaoneMoeForCausalLM(driver.model_config(cfg))
-    driver.put_weights(m, f32_weights(cfg, SEED))
-    m.eval()
-    return m
+    return served.f32_weights(weights_lib, cfg, seed, layers)
 
 
 @pytest.fixture(scope="module")
 def model():
-    return fresh_model()
-
-
-def rand(shape, seed, scale=1.0):
-    return jnp.asarray(np.random.RandomState(seed).randn(*shape) * scale,
-                       jnp.float32)
-
-
-def close(got, want, atol):
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=atol,
-                               rtol=0)
+    return served.model_of(CASE)
 
 
 # ============================================================ expert layer
@@ -129,7 +116,7 @@ def test_expert_layer_is_the_per_token_loop(uncut, held):
     cfg, lw = uncut
     layer = moe_layer(cfg, lw, held)
     u = rand((11, 64), 17)
-    out, load = layer(Tensor(u), with_load=True)
+    out, load = traced(layer, Tensor(u), with_load=True)
     w = {k: np.asarray(v, np.float64) for k, v in lw.items()}
     un = np.asarray(u, np.float64)
     lo, hi = held
@@ -168,8 +155,8 @@ def test_shares_add_up_to_the_uncut_layer(uncut):
     total = shared = None
     for lo in range(0, 16, 2):
         layer = moe_layer(cfg, lw, (lo, lo + 2))
-        shared = layer.shared(Tensor(u.reshape(-1, 64)))._data
-        out = layer(Tensor(u))._data
+        shared = traced(layer.shared, Tensor(u.reshape(-1, 64)))._data
+        out = traced(layer, Tensor(u))._data
         part = dict(lw, **{k: lw[k][lo:lo + 2]
                            for k in ("w_gate", "w_up", "w_down")})
         close(out, ref_moe(cfg, u, part, (lo, lo + 2)), TIGHT)
@@ -201,7 +188,7 @@ def test_whole_sequence_forward_is_the_reference(model, tokens):
     tokens are five windows long."""
     ids = np.random.RandomState(tokens).randint(
         1, CFG["vocab_size"], (2, tokens)).astype(np.int32)
-    got = model(paddle.to_tensor(ids))._data
+    got = traced(model, paddle.to_tensor(ids))._data
     for row in range(2):
         close(got[row], ref.logits(CFG, SEED, ids[row], block=16), LOGITS)
 
@@ -209,19 +196,11 @@ def test_whole_sequence_forward_is_the_reference(model, tokens):
 def test_whole_sequence_forward_is_differentiable():
     """Eager autograd reaches every parameter through both attention
     kinds and the expert product (the router's correction bias only steers
-    a choice: its gradient is zero)."""
-    m = fresh_model()
-    m.train()
-    ids = paddle.to_tensor(np.random.RandomState(1).randint(
-        1, CFG["vocab_size"], (2, 12)).astype(np.int32))
-    out = m(ids)
-    (out * out).mean().backward()
-    for name, p in m.named_parameters():
-        assert p.grad is not None, name
-        g = p.grad.numpy()
-        assert np.isfinite(g).all(), name
-        if not name.endswith("e_score_correction_bias"):
-            assert np.abs(g).max() > 0, name
+    a choice: its gradient is zero). Twelve tokens: past the window's
+    edge."""
+    served.whole_sequence_forward_is_differentiable(
+        CASE, np.random.RandomState(1).randint(
+            1, CFG["vocab_size"], (2, 12)).astype(np.int32))
 
 
 def test_window_edge_shows_in_the_logits(model):
@@ -237,84 +216,17 @@ def test_window_edge_shows_in_the_logits(model):
 
 
 # ====================================================== through the engine
-class Recorder:
-    """The logits every program call samples from, keyed by (request,
-    tokens generated so far): ``serving._sample_tokens`` wrapped with a
-    host callback. A lane that ran under the ``seq = 0`` sentinel writes
-    garbage under its key and the real step overwrites it later."""
-
-    def __init__(self, monkeypatch):
-        self.rows = {}
-        inner = serving._sample_tokens
-
-        def sample(logits, temps, top_ps, base_key, rids, ngens, sampling):
-            jax.debug.callback(self.note, logits, rids, ngens, ordered=True)
-            return inner(logits, temps, top_ps, base_key, rids, ngens,
-                         sampling)
-
-        monkeypatch.setattr(serving, "_sample_tokens", sample)
-
-    def note(self, logits, rids, ngens):
-        for row, rid, n in zip(np.asarray(logits), np.asarray(rids),
-                               np.asarray(ngens)):
-            if rid:
-                self.rows[(int(rid), int(n))] = row
-
-
-def check_against_reference(rec, rid, prompt, served, atol=LOGITS):
-    ids = np.asarray(list(prompt) + list(served[:-1]), np.int32)
-    want = np.asarray(ref.logits(CFG, SEED, ids, block=16))
-    for n in range(len(served)):
-        close(rec.rows[(rid, n)], want[len(prompt) - 1 + n], atol)
-
-
-def prompts_of(lengths, seed=0):
-    rng = np.random.RandomState(seed)
-    return [rng.randint(1, CFG["vocab_size"], n).tolist() for n in lengths]
-
-
-def engine(model, **kw):
-    """Chunks of 16 tokens: window rows 8 + 16 = 24 a lane, so a prompt of
-    40 wraps them, and three chunks prefill it."""
-    kw.setdefault("max_batch", 4)
-    kw.setdefault("block_size", 8)
-    kw.setdefault("num_blocks", 64)
-    kw.setdefault("max_blocks_per_seq", 16)
-    kw.setdefault("scheduler", SchedulerConfig(prefill_token_budget=16))
-    return PagedEngine(model, **kw)
-
-
 @pytest.mark.parametrize("front", ["engine", "router"])
-def test_served_logits_are_the_references(monkeypatch, front):
+def test_served_logits_are_the_references(rec, front):
     """Prefill in one to five chunks of 16 (left-padded first chunk where
     the prompt is no multiple of 16), then decode through the cache, four
     requests of unequal length sharing the batch, the longest nine windows
     long so that its rows wrap three times: every logits row the programs
     sampled from against the reference's full forward over prompt + served
     tokens."""
-    rec = Recorder(monkeypatch)
-    eng = engine(fresh_model())
-    assert eng.prefill_width == 16
-    prompts = prompts_of((5, 40, 70, 32))
-    if front == "router":
-        door = Router([eng]).warmup()     # placement needs a READY replica
-        jax.effects_barrier()
-        rec.rows.clear()                  # the warm-up request's rows
-        before = eng.expert_load()["pairs_selected"]
-        rids = [door.add_request(p, max_new_tokens=10) for p in prompts]
-        while door.has_work():
-            door.step()
-        served = {r: door.outcomes[r].tokens for r in rids}
-        assert all(door.outcomes[r].status == "FINISHED" for r in rids)
-    else:
-        before = [0] * 4
-        rids = [eng.add_request(p, max_new_tokens=10) for p in prompts]
-        served = eng.run_to_completion()
-    jax.effects_barrier()
-    engine_rids = sorted({rid for rid, _n in rec.rows})
-    assert len(engine_rids) == 4
-    for erid, rid, p in zip(engine_rids, rids, prompts):
-        check_against_reference(rec, erid, p, served[rid])
+    eng, prompts, before = served.served_logits_are_the_references(
+        CASE, rec, front, width=16, new=10,
+        probe=lambda eng: eng.expert_load()["pairs_selected"])
     rows = sum(len(p) + 10 - 1 for p in prompts)
     load = eng.expert_load()
     # padding rows and sentinel lanes are not counted: every real row
@@ -329,77 +241,38 @@ def test_served_logits_are_the_references(monkeypatch, front):
     assert [sum(t) for t in load["tokens"]] == load["pairs_held"]
 
 
-def test_a_reused_slot_sees_nothing_of_its_last_request(monkeypatch):
-    """One slot, two requests one after the other, the second shorter than
-    the rows the first left behind: its logits are the reference's, which
-    starts from nothing. The rows need no clearing: what a row holds
-    follows from the sequence's own length."""
-    rec = Recorder(monkeypatch)
-    eng = engine(fresh_model(), max_batch=1)
-    first, second = prompts_of((45, 13), seed=1)
-    a = eng.add_request(first, max_new_tokens=5)
-    out_a = eng.run_to_completion()[a]
-    b = eng.add_request(second, max_new_tokens=5)
-    out_b = eng.run_to_completion()[b]
-    jax.effects_barrier()
-    check_against_reference(rec, a, first, out_a)
-    check_against_reference(rec, b, second, out_b)
+def test_a_reused_slot_sees_nothing_of_its_last_request(rec):
+    """The second request is shorter than the rows the first left behind.
+    The rows need no clearing: what a row holds follows from the sequence's
+    own length."""
+    served.a_reused_slot_starts_clean(CASE, rec, (45, 13))
 
 
-def test_a_lane_mid_prefill_keeps_its_rows_while_others_decode(monkeypatch):
-    """A budget of 8 prompt tokens a tick: the 45-token prompt is mid-way
-    for six ticks while the short request decodes in every one of them
-    (its lane rides those decode steps under the seq = 0 sentinel and must
-    write no row)."""
-    rec = Recorder(monkeypatch)
-    eng = engine(fresh_model(),
-                 scheduler=SchedulerConfig(prefill_token_budget=8))
-    short, long_ = prompts_of((6, 45), seed=2)
-    a = eng.add_request(short, max_new_tokens=12)
-    b = eng.add_request(long_, max_new_tokens=4)
-    overlapped, served = 0, {}
-    while eng.has_work():
-        mid = len(eng._prefilling)
-        decoding = len(eng._decode_lanes())
-        served.update(eng.step())
-        overlapped += bool(mid and decoding)
-    assert overlapped >= 3
-    jax.effects_barrier()
-    check_against_reference(rec, a, short, served[a])
-    check_against_reference(rec, b, long_, served[b])
+def test_a_lane_mid_prefill_keeps_its_rows_while_others_decode(rec):
+    """(Its lane rides those decode steps under the seq = 0 sentinel and
+    must write no row.)"""
+    served.a_lane_mid_prefill_keeps_what_it_holds(CASE, rec)
 
 
-def test_evict_then_readmit_reproduces_the_logits(monkeypatch):
-    """Every lane stalled: one is preempted, its blocks freed, and it is
-    re-prefilled over prompt + generated tokens later. The window rows
-    need no free and no snapshot: the re-prefill rewrites them."""
-    rec = Recorder(monkeypatch)
-    eng = engine(fresh_model(), num_blocks=7, max_blocks_per_seq=6)
-    evicted = []
-    evict = eng._evict
-    eng._evict = lambda slot: (evicted.append(slot), evict(slot))[-1]
-    p, q = prompts_of((12, 12), seed=4)
-    a = eng.add_request(p, max_new_tokens=20)
-    b = eng.add_request(q, max_new_tokens=20)
-    served = eng.run_to_completion(max_ticks=400)
-    assert evicted
-    jax.effects_barrier()
-    check_against_reference(rec, a, p, served[a])
-    check_against_reference(rec, b, q, served[b])
+def test_evict_then_readmit_reproduces_the_logits(rec):
+    """The window rows need no free and no snapshot: the re-prefill
+    rewrites them."""
+    served.evict_then_readmit_reproduces_the_logits(
+        CASE, rec, usable=6, length=12, new=20, max_ticks=400)
 
 
-def test_speculate_needs_its_rows_back(model):
+def test_speculate_needs_its_rows_back():
     with pytest.raises(TypeError, match="state rollback.*rows back"):
-        engine(model, speculate="ngram")
+        served.engine(CASE, speculate="ngram")
 
 
 @pytest.mark.parametrize("context_blocks", [16, 512])
-def test_window_bytes_do_not_depend_on_the_context(model, context_blocks):
+def test_window_bytes_do_not_depend_on_the_context(context_blocks):
     """Window layers hold ``sliding_window`` + one chunk of rows a lane
     whatever ``context`` is; the pools of the one full layer alone grow
     with ``num_blocks``, and a token costs one layer's K and V."""
-    eng = engine(model, max_blocks_per_seq=context_blocks,
-                 num_blocks=2 * context_blocks)
+    eng = served.engine(CASE, max_blocks_per_seq=context_blocks,
+                        num_blocks=2 * context_blocks)
     h = eng.health()
     rows = WINDOW + eng.prefill_width
     # four window layers: K and V rows of 2 KV heads of 16, float32
@@ -438,11 +311,11 @@ def test_a_layer_keeps_two_states(model):
         serving._cache_index([("ring",)])
 
 
-def test_scopes_are_in_the_lowered_programs(model):
+def test_scopes_are_in_the_lowered_programs():
     """``attn.window``, ``attn.full``, ``moe`` with its three parts,
     ``mlp``, ``embed``, ``lm_head`` and, around both attention kinds,
     ``paged_attention``, in the ``op_name`` of both serving programs."""
-    eng = engine(model)
+    eng = served.engine(CASE)
     args = eng._chunk_args(
         np.zeros((4, 1), np.int32), np.ones((4,), np.int32), eng.tables,
         np.zeros((4,), np.float32), np.ones((4,), np.float32),

@@ -38,6 +38,7 @@ import pytest
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
+sys.path.insert(0, os.path.join(ROOT, "tests"))
 
 import paddle_tpu as paddle  # noqa: E402
 import paddle_tpu.nn.functional as F  # noqa: E402
@@ -55,6 +56,8 @@ from paddle_tpu.models._remat import remat_block  # noqa: E402
 from paddle_tpu.nn.functional import experts as E  # noqa: E402
 from paddle_tpu.observability import metrics  # noqa: E402
 from paddle_tpu.observability import trace as obs_trace  # noqa: E402
+
+from served import close, rand, traced  # noqa: E402
 
 TIGHT, GRAD = 2e-5, 2e-4
 LOSS, GRAD_NORM, DELTA_NORM = 2e-4, 0.03, 0.03
@@ -81,16 +84,6 @@ def metrics_on():
     paddle.set_flags({"FLAGS_enable_metrics": True})
     yield
     paddle.set_flags({"FLAGS_enable_metrics": False})
-
-
-def rand(shape, seed, scale=1.0):
-    return jnp.asarray(np.random.RandomState(seed).randn(*shape) * scale,
-                       jnp.float32)
-
-
-def close(got, want, atol):
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=atol,
-                               rtol=0)
 
 
 def grads_close(got, want):
@@ -137,12 +130,13 @@ def test_grouped_product_is_the_masked_product(form, padded):
     def grouped(x, w, *m):
         return E.grouped_experts_arrays(x, idx, w, m, lo, valid)
 
-    close(grouped(x, w, *mats), masked(x, w, *mats), TIGHT * 10)
+    close(jax.jit(grouped)(x, w, *mats), jax.jit(masked)(x, w, *mats),
+          TIGHT * 10)
     args = tuple(range(2 + len(mats)))
-    want = jax.grad(lambda *a: jnp.sum(masked(*a) ** 2), argnums=args)(
-        x, w, *mats)
-    got = jax.grad(lambda *a: jnp.sum(grouped(*a) ** 2), argnums=args)(
-        x, w, *mats)
+    want = jax.jit(jax.grad(lambda *a: jnp.sum(masked(*a) ** 2),
+                            argnums=args))(x, w, *mats)
+    got = jax.jit(jax.grad(lambda *a: jnp.sum(grouped(*a) ** 2),
+                           argnums=args))(x, w, *mats)
     grads_close(got, want)
 
 
@@ -190,13 +184,14 @@ def test_rows_past_the_last_group_never_reach_a_result(monkeypatch):
     def grouped(x, w, *m):
         return E.grouped_experts_arrays(x, idx, w, m, lo, valid)
 
-    close(grouped(x, w, *mats), masked(x, w, *mats), TIGHT * 10)
+    close(jax.jit(grouped)(x, w, *mats), jax.jit(masked)(x, w, *mats),
+          TIGHT * 10)
     args = (0, 1, 2, 3, 4)
-    got = jax.grad(lambda *a: jnp.sum(grouped(*a) ** 2), argnums=args)(
-        x, w, *mats)
+    got = jax.jit(jax.grad(lambda *a: jnp.sum(grouped(*a) ** 2),
+                           argnums=args))(x, w, *mats)
     assert all(bool(jnp.all(jnp.isfinite(g))) for g in got)
-    grads_close(got, jax.grad(lambda *a: jnp.sum(masked(*a) ** 2),
-                              argnums=args)(x, w, *mats))
+    grads_close(got, jax.jit(jax.grad(lambda *a: jnp.sum(masked(*a) ** 2),
+                                      argnums=args))(x, w, *mats))
 
 
 @pytest.mark.parametrize("rows,grouped", [
@@ -245,7 +240,7 @@ def test_no_shared_width_builds_no_shared_expert():
     assert names == {"gate_weight", "e_score_correction_bias", "w_gate",
                      "w_up", "w_down"}
     layer.shared = None                      # calling it would raise
-    out, load = layer(Tensor(rand((3, 5, 16), 4)), with_load=True)
+    out, load = traced(layer, Tensor(rand((3, 5, 16), 4)), with_load=True)
     assert out.shape == [3, 5, 16] and load.shape == [6]
     with_shared = nn.SwiGLUMoE(16, 24, 24, 8, 2)
     assert "shared_gate.weight" in {
@@ -281,8 +276,8 @@ def test_gated_short_conv_is_the_token_loop(taps):
             x, eye, w, jnp.eye(ch), jnp.matmul)
         return jnp.sum(jax.vmap(one)(bcz) ** 2)
 
-    grads_close(jax.grad(program, argnums=(0, 1))(bcz, w),
-                jax.grad(reference, argnums=(0, 1))(bcz, w))
+    grads_close(jax.jit(jax.grad(program, argnums=(0, 1)))(bcz, w),
+                jax.jit(jax.grad(reference, argnums=(0, 1)))(bcz, w))
 
 
 # ============================================================ the share test
@@ -325,7 +320,7 @@ def test_shares_add_up_to_the_uncut_layer():
     whole = ref.moe(u, lw, lw["moe.bias"], cfg, jnp.matmul)
     total = None
     for lo in range(0, 16, 4):
-        out = moe_layer(cfg, lw, (lo, lo + 4))(Tensor(u))._data
+        out = traced(moe_layer(cfg, lw, (lo, lo + 4)), Tensor(u))._data
         part = dict(cfg, experts_held=[lo, lo + 4])
         share = {k: (v[lo:lo + 4] if k in ("moe.w1", "moe.w3", "moe.w2")
                      else v) for k, v in lw.items()}
@@ -370,7 +365,8 @@ def test_float32_loss_and_gradients_are_the_references(recompute):
             for (_n, p), o in zip(named, olds):
                 p._data = o
 
-    got, grads = jax.value_and_grad(loss_of)([p._data for _n, p in named])
+    got, grads = jax.jit(jax.value_and_grad(loss_of))(
+        [p._data for _n, p in named])
     assert abs(float(got) - float(want)) < TIGHT * 5
     for (name, _p), g in zip(named, grads):
         w = want_grads[driver.table_key(name)]
@@ -381,7 +377,7 @@ def test_float32_loss_and_gradients_are_the_references(recompute):
 def test_logits_come_from_the_tied_embedding():
     model = float32_model(4)
     ids = jnp.asarray(np.random.RandomState(1).randint(0, 97, (1, 16)))
-    logits = model(Tensor(ids))
+    logits = traced(model, Tensor(ids))
     assert logits.shape == [1, 16, CFG["vocab_size"]]
     assert "lm_head" not in {n for n, _p in model.named_parameters()}
 

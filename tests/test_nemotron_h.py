@@ -29,27 +29,32 @@ import pytest
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
 
+sys.path.insert(0, os.path.join(ROOT, "tests"))
+
 import paddle_tpu as paddle  # noqa: E402
-from benchmark.drivers import serve_hybrid  # noqa: E402
 from benchmark.lib import weights_nemotron_h as weights_lib  # noqa: E402
 from benchmark.reference import nemotron_h as ref  # noqa: E402
 from benchmark.tests.tiny_hybrid import NEMOTRON  # noqa: E402
 from paddle_tpu import nn  # noqa: E402
 from paddle_tpu.core.tensor import Tensor  # noqa: E402
-from paddle_tpu.inference import PagedEngine, serving  # noqa: E402
-from paddle_tpu.models import NemotronHForCausalLM  # noqa: E402
-from paddle_tpu.serving import Router, SchedulerConfig  # noqa: E402
+from paddle_tpu.inference import serving  # noqa: E402
+
+import served  # noqa: E402
+from served import (close, models, rand, rec, recording,  # noqa: E402,F401
+                    traced)
 
 SEED = 5
 TIGHT = 2e-5
 LOGITS = 5e-4
 CFG = NEMOTRON
+#: chunks of 32 (the engine's widest for four lanes of 8-token blocks)
+CASE = served.Case(
+    "hybrid", CFG, SEED, budget=None, atol=LOGITS,
+    reference=lambda ids: ref.logits(CFG, SEED, ids[None])[0])
 
 
 def f32_weights(cfg, seed, layers=None):
-    """The table's bf16 draws upcast to float32: what the reference reads."""
-    made = weights_lib.make(cfg, seed, jnp.bfloat16, layers=layers)
-    return {k: v.astype(jnp.float32) for k, v in made.items()}
+    return served.f32_weights(weights_lib, cfg, seed, layers)
 
 
 def layer_weights(layer):
@@ -59,20 +64,7 @@ def layer_weights(layer):
 
 @pytest.fixture(scope="module")
 def model():
-    m = NemotronHForCausalLM(serve_hybrid.model_config(CFG))
-    serve_hybrid.put_weights(m, f32_weights(CFG, SEED))
-    m.eval()
-    return m
-
-
-def rand(shape, seed, scale=1.0):
-    return jnp.asarray(np.random.RandomState(seed).randn(*shape) * scale,
-                       jnp.float32)
-
-
-def close(got, want, atol):
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=atol,
-                               rtol=0)
+    return served.model_of(CASE)
 
 
 # ================================================================ Mamba-2
@@ -94,7 +86,7 @@ def test_chunked_scan_is_the_time_step_recurrence(model, tokens):
     inside the scan) against the reference's ``lax.scan`` over time."""
     mixer = model.model.layers[0].mixer
     u = rand((2, tokens, 64), tokens)
-    close(mixer(Tensor(u))._data, mamba_ref(u), TIGHT)
+    close(traced(mixer, Tensor(u))._data, mamba_ref(u), TIGHT)
 
 
 @pytest.mark.parametrize("cuts", [(11,), (8, 16), (1, 2, 3), (5, 6, 18)])
@@ -106,7 +98,7 @@ def test_a_chunk_continues_from_the_carried_state(model, cuts):
     u = rand((2, 19, 64), 7)
     state, outs, lo = zero_state(mixer, 2), [], 0
     for hi in cuts + (19,):
-        out, state = mixer(Tensor(u[:, lo:hi]), state=state)
+        out, state = traced(mixer, Tensor(u[:, lo:hi]), state=state)
         outs.append(out._data)
         lo = hi
     close(jnp.concatenate(outs, axis=1), mamba_ref(u, layer=2), TIGHT)
@@ -117,7 +109,7 @@ def test_dropping_the_carried_state_shows(model):
     with the second one started from zeros miss the reference by far."""
     mixer = model.model.layers[2].mixer
     u = rand((2, 19, 64), 7)
-    out, _s = mixer(Tensor(u[:, 11:]), state=zero_state(mixer, 2))
+    out, _s = traced(mixer, Tensor(u[:, 11:]), state=zero_state(mixer, 2))
     miss = jnp.abs(out._data - mamba_ref(u, layer=2)[:, 11:]).max()
     assert float(miss) > 1e-2 > 100 * TIGHT
 
@@ -130,9 +122,11 @@ def test_left_padding_of_a_first_chunk_is_not_seen(model, pad):
     mixer = model.model.layers[0].mixer
     u = rand((1, 16, 64), pad, scale=2.0)      # the padding rows are NOT 0
     valid = Tensor(jnp.arange(16)[None, :] >= pad)
-    out, state = mixer(Tensor(u), state=zero_state(mixer, 1), valid=valid)
+    out, state = traced(mixer, Tensor(u), state=zero_state(mixer, 1),
+                        valid=valid)
     close(out._data[:, pad:], mamba_ref(u[:, pad:]), TIGHT)
-    _o, alone = mixer(Tensor(u[:, pad:]), state=zero_state(mixer, 1))
+    _o, alone = traced(mixer, Tensor(u[:, pad:]),
+                       state=zero_state(mixer, 1))
     close(state[0]._data, alone[0]._data, 0)        # window: copies
     close(state[1]._data, alone[1]._data, TIGHT)
 
@@ -140,9 +134,11 @@ def test_left_padding_of_a_first_chunk_is_not_seen(model, pad):
 def test_one_token_update_is_the_scans_next_step(model):
     mixer = model.model.layers[0].mixer
     u = rand((3, 13, 64), 3)
-    _o, before = mixer(Tensor(u[:, :12]), state=zero_state(mixer, 3))
-    step_out, step_state = mixer(Tensor(u[:, 12:]), state=before)
-    scan_out, scan_state = mixer(Tensor(u), state=zero_state(mixer, 3))
+    _o, before = traced(mixer, Tensor(u[:, :12]),
+                        state=zero_state(mixer, 3))
+    step_out, step_state = traced(mixer, Tensor(u[:, 12:]), state=before)
+    scan_out, scan_state = traced(mixer, Tensor(u),
+                                  state=zero_state(mixer, 3))
     close(step_out._data[:, 0], scan_out._data[:, 12], TIGHT)
     close(step_state[1]._data, scan_state[1]._data, TIGHT)
     close(step_state[0]._data, scan_state[0]._data, 0)
@@ -206,7 +202,7 @@ def test_expert_layer_is_the_per_token_loop(uncut, held):
     layer = moe_layer(cfg, lw, held)
     u = rand((11, 64), 17)
     u = u.at[3].set(outside_token(lw, held)) if held != (0, 16) else u
-    out, load = layer(Tensor(u), with_load=True)
+    out, load = traced(layer, Tensor(u), with_load=True)
     w = {k: np.asarray(v, np.float64) for k, v in lw.items()}
     un = np.asarray(u, np.float64)
     lo, hi = held
@@ -246,9 +242,9 @@ def test_shares_add_up_to_the_uncut_layer(uncut):
     for lo in (0, 4, 8, 12):
         layer = moe_layer(cfg, lw, (lo, lo + 4))
         flat = Tensor(u.reshape(-1, 64))
-        shared = layer.shared_down(
-            nn.layer.latent_moe.relu2(layer.shared_up(flat)))._data
-        out = layer(Tensor(u))._data
+        shared = traced(lambda x: layer.shared_down(
+            nn.layer.latent_moe.relu2(layer.shared_up(x))), flat)._data
+        out = traced(layer, Tensor(u))._data
         part = dict(lw, w1=lw["w1"][lo:lo + 4], w2=lw["w2"][lo:lo + 4])
         with jax.default_matmul_precision("highest"):
             close(out, ref.mixer(dict(cfg, experts_held=[lo, lo + 4]), "E",
@@ -265,115 +261,30 @@ def test_whole_sequence_forward_is_the_reference(model, tokens):
     """``forward(ids)``, what a trainer or an offline scorer calls."""
     ids = np.random.RandomState(tokens).randint(1, CFG["vocab_size"],
                                                 (2, tokens)).astype(np.int32)
-    close(model(paddle.to_tensor(ids))._data, ref.logits(CFG, SEED, ids),
-          LOGITS)
+    close(traced(model, paddle.to_tensor(ids))._data,
+          ref.logits(CFG, SEED, ids), LOGITS)
 
 
 def test_whole_sequence_forward_is_differentiable():
     """Eager autograd reaches every parameter through the scan, the
     convolution, the gated norm and the expert product (the router's
-    correction bias only steers a choice: its gradient is zero)."""
-    m = fresh_model()
-    m.train()
-    ids = paddle.to_tensor(np.random.RandomState(1).randint(
-        1, CFG["vocab_size"], (2, 12)).astype(np.int32))
-    out = m(ids)
-    (out * out).mean().backward()
-    for name, p in m.named_parameters():
-        assert p.grad is not None, name
-        g = p.grad.numpy()
-        assert np.isfinite(g).all(), name
-        if not name.endswith("e_score_correction_bias"):
-            assert np.abs(g).max() > 0, name
+    correction bias only steers a choice: its gradient is zero). Twelve
+    tokens: a whole chunk of 8 and a padded one."""
+    served.whole_sequence_forward_is_differentiable(
+        CASE, np.random.RandomState(1).randint(
+            1, CFG["vocab_size"], (2, 12)).astype(np.int32))
 
 
 # ====================================================== through the engine
-class Recorder:
-    """The logits every program call samples from, keyed by (request,
-    tokens generated so far): ``serving._sample_tokens`` wrapped with a
-    host callback. A lane that ran under the ``seq = 0`` sentinel writes
-    garbage under its key and the real step overwrites it later."""
-
-    def __init__(self, monkeypatch):
-        self.rows = {}
-        inner = serving._sample_tokens
-
-        def sample(logits, temps, top_ps, base_key, rids, ngens, sampling):
-            jax.debug.callback(self.note, logits, rids, ngens, ordered=True)
-            return inner(logits, temps, top_ps, base_key, rids, ngens,
-                         sampling)
-
-        monkeypatch.setattr(serving, "_sample_tokens", sample)
-
-    def note(self, logits, rids, ngens):
-        for row, rid, n in zip(np.asarray(logits), np.asarray(rids),
-                               np.asarray(ngens)):
-            if rid:
-                self.rows[(int(rid), int(n))] = row
-
-
-def fresh_model():
-    """A model of its own: the compiled programs of a shared one are
-    shared too, and each test records through its own."""
-    m = NemotronHForCausalLM(serve_hybrid.model_config(CFG))
-    serve_hybrid.put_weights(m, f32_weights(CFG, SEED))
-    m.eval()
-    return m
-
-
-def reference_logits(prompt, served):
-    ids = np.asarray([list(prompt) + list(served[:-1])], np.int32)
-    return np.asarray(ref.logits(CFG, SEED, ids))[0]
-
-
-def check_against_reference(rec, rid, prompt, served, atol=LOGITS):
-    want = reference_logits(prompt, served)
-    for n in range(len(served)):
-        close(rec.rows[(rid, n)], want[len(prompt) - 1 + n], atol)
-
-
-def prompts_of(lengths, seed=0):
-    rng = np.random.RandomState(seed)
-    return [rng.randint(1, CFG["vocab_size"], n).tolist() for n in lengths]
-
-
-def engine(model, **kw):
-    kw.setdefault("max_batch", 4)
-    kw.setdefault("block_size", 8)
-    kw.setdefault("num_blocks", 64)
-    kw.setdefault("max_blocks_per_seq", 16)
-    return PagedEngine(model, **kw)
-
-
 @pytest.mark.parametrize("front", ["engine", "router"])
-def test_served_logits_are_the_references(monkeypatch, front):
+def test_served_logits_are_the_references(rec, front):
     """Prefill in one to three chunks of 32 (left-padded first chunk),
     then decode through the cache, four requests sharing the batch: every
     logits row the programs sampled from against the reference's full
     forward over prompt + served tokens."""
-    rec = Recorder(monkeypatch)
-    eng = engine(fresh_model())
-    assert eng.prefill_width == 32
-    prompts = prompts_of((5, 40, 70, 32))
-    if front == "router":
-        door = Router([eng]).warmup()     # placement needs a READY replica
-        jax.effects_barrier()
-        rec.rows.clear()                  # the warm-up request's rows
-        before = eng.expert_load()["pairs_selected"]
-        rids = [door.add_request(p, max_new_tokens=6) for p in prompts]
-        while door.has_work():
-            door.step()
-        served = {r: door.outcomes[r].tokens for r in rids}
-        assert all(door.outcomes[r].status == "FINISHED" for r in rids)
-    else:
-        before = [0, 0]
-        rids = [eng.add_request(p, max_new_tokens=6) for p in prompts]
-        served = eng.run_to_completion()
-    jax.effects_barrier()
-    engine_rids = sorted({rid for rid, _n in rec.rows})
-    assert len(engine_rids) == 4
-    for erid, rid, p in zip(engine_rids, rids, prompts):
-        check_against_reference(rec, erid, p, served[rid])
+    eng, prompts, before = served.served_logits_are_the_references(
+        CASE, rec, front, width=32, new=6,
+        probe=lambda eng: eng.expert_load()["pairs_selected"])
     rows = sum(len(p) + 6 - 1 for p in prompts)
     load = eng.expert_load()
     # padding rows and sentinel lanes are not counted: every real row
@@ -385,111 +296,45 @@ def test_served_logits_are_the_references(monkeypatch, front):
     assert [sum(t) for t in load["tokens"]] == load["pairs_held"]
 
 
-def test_a_reused_slot_starts_from_zero_state(monkeypatch):
-    """One slot, two requests one after the other: the second's logits
-    are the reference's, which starts from zero state."""
-    rec = Recorder(monkeypatch)
-    eng = engine(fresh_model(), max_batch=1)
-    first, second = prompts_of((20, 13), seed=1)
-    a = eng.add_request(first, max_new_tokens=5)
-    out_a = eng.run_to_completion()[a]
-    b = eng.add_request(second, max_new_tokens=5)
-    out_b = eng.run_to_completion()[b]
-    jax.effects_barrier()
-    check_against_reference(rec, a, first, out_a)
-    check_against_reference(rec, b, second, out_b)
+def test_a_reused_slot_starts_from_zero_state(rec):
+    served.a_reused_slot_starts_clean(CASE, rec, (20, 13))
 
 
-def test_a_lane_mid_prefill_keeps_its_state_while_others_decode(monkeypatch):
-    """A budget of 8 prompt tokens a tick: the 45-token prompt is mid-way
-    for six ticks while the short request decodes in every one of them
-    (its lane rides those decode steps under the seq = 0 sentinel)."""
-    rec = Recorder(monkeypatch)
-    eng = engine(fresh_model(),
-                 scheduler=SchedulerConfig(prefill_token_budget=8))
-    short, long_ = prompts_of((6, 45), seed=2)
-    a = eng.add_request(short, max_new_tokens=12)
-    b = eng.add_request(long_, max_new_tokens=4)
-    overlapped = 0
-    served = {}
-    while eng.has_work():
-        mid = len(eng._prefilling)
-        decoding = len(eng._decode_lanes())
-        served.update(eng.step())
-        overlapped += bool(mid and decoding)
-    assert overlapped >= 3
-    jax.effects_barrier()
-    check_against_reference(rec, a, short, served[a])
-    check_against_reference(rec, b, long_, served[b])
+def test_a_lane_mid_prefill_keeps_its_state_while_others_decode(rec):
+    served.a_lane_mid_prefill_keeps_what_it_holds(CASE, rec)
 
 
-def test_a_memory_stalled_lane_keeps_its_state(monkeypatch):
-    """Five usable blocks, two requests that need three each: the second
-    waits out the first's last steps under the seq = 0 sentinel, then goes
-    on from the state it had."""
-    rec = Recorder(monkeypatch)
-    eng = engine(fresh_model(), num_blocks=6, max_blocks_per_seq=4)
-    stalls = []
-    plan = eng._plan_decode
-
-    def watching(active, *aboard):
-        got = plan(active, *aboard)
-        if got is not None:
-            stalls.append(list(got[-1]))
-        return got
-
-    eng._plan_decode = watching
-    p, q = prompts_of((7, 7), seed=3)
-    a = eng.add_request(p, max_new_tokens=12)
-    b = eng.add_request(q, max_new_tokens=16)
-    served = eng.run_to_completion(max_ticks=200)
-    assert any(s for s in stalls)
-    jax.effects_barrier()
-    check_against_reference(rec, a, p, served[a])
-    check_against_reference(rec, b, q, served[b])
+def test_a_memory_stalled_lane_keeps_its_state(rec):
+    served.a_memory_stalled_lane_keeps_what_it_holds(CASE, rec)
 
 
-def test_evict_then_readmit_reproduces_the_logits(monkeypatch):
-    """Every lane stalled: one is preempted, its blocks freed, and it is
-    re-prefilled over prompt + generated tokens later. The state needs no
-    free and no snapshot: the re-prefill recomputes it."""
-    rec = Recorder(monkeypatch)
-    eng = engine(fresh_model(), num_blocks=5, max_blocks_per_seq=4)
-    evicted = []
-    evict = eng._evict
-    eng._evict = lambda slot: (evicted.append(slot), evict(slot))[-1]
-    p, q = prompts_of((4, 4), seed=4)
-    a = eng.add_request(p, max_new_tokens=14)
-    b = eng.add_request(q, max_new_tokens=14)
-    served = eng.run_to_completion(max_ticks=300)
-    assert evicted
-    jax.effects_barrier()
-    check_against_reference(rec, a, p, served[a])
-    check_against_reference(rec, b, q, served[b])
+def test_evict_then_readmit_reproduces_the_logits(rec):
+    """The state needs no free and no snapshot: the re-prefill recomputes
+    it."""
+    served.evict_then_readmit_reproduces_the_logits(
+        CASE, rec, usable=4, length=4, new=14, max_ticks=300)
 
 
-def test_int8_kv_pages_serve_the_attention_layer(monkeypatch):
+def test_int8_kv_pages_serve_the_attention_layer(rec):
     """``kv_dtype="int8"`` quantises the one attention layer's K/V pages
     (one part in 127 of each head's largest value); the recurrent state is
     untouched. Tolerance 0.05 on logits of order 1: the int8 rounding of
     K and V through one attention layer of five blocks."""
-    rec = Recorder(monkeypatch)
-    eng = engine(fresh_model(), kv_dtype="int8")
-    (p,) = prompts_of((37,), seed=5)
+    eng = served.engine(CASE, kv_dtype="int8")
+    (p,) = served.prompts_of(CASE, (37,), seed=5)
     a = eng.add_request(p, max_new_tokens=5)
-    served = eng.run_to_completion()
-    jax.effects_barrier()
-    check_against_reference(rec, a, p, served[a], atol=0.05)
+    out = eng.run_to_completion()
+    served.check_served(CASE, rec, out, (p,), (a,), atol=0.05)
     assert eng.health()["kv_dtype"] == "int8"
 
 
-def test_speculate_needs_a_state_rollback(model):
+def test_speculate_needs_a_state_rollback():
     with pytest.raises(TypeError, match="state rollback"):
-        engine(model, speculate="ngram")
+        served.engine(CASE, speculate="ngram")
 
 
-def test_health_counts_each_kind_of_state(model):
-    eng = engine(model)
+def test_health_counts_each_kind_of_state():
+    eng = served.engine(CASE)
     h = eng.health()
     # one attention layer of five: K and V, 2 KV heads of 16, float32
     assert h["kv_bytes_per_token"] == 2 * 2 * 16 * 4 == eng.kv_bytes_per_token

@@ -20,6 +20,7 @@ softmax against a masked one), not of a lower precision:
 """
 from __future__ import annotations
 
+import dataclasses
 import os
 import sys
 
@@ -31,17 +32,20 @@ import pytest
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
 
+sys.path.insert(0, os.path.join(ROOT, "tests"))
+
 import paddle_tpu as paddle  # noqa: E402
 from benchmark.drivers import serve_olmo_hybrid as driver  # noqa: E402
 from benchmark.lib import weights_olmo_hybrid as weights_lib  # noqa: E402
 from benchmark.reference import olmo_hybrid as ref  # noqa: E402
 from benchmark.tests.tiny_olmo_hybrid import CFG as TINY  # noqa: E402
 from paddle_tpu.core.tensor import Tensor  # noqa: E402
-from paddle_tpu.inference import PagedEngine, serving  # noqa: E402
 from paddle_tpu.models import (OlmoHybridConfig,  # noqa: E402
                                OlmoHybridForCausalLM, olmo_hybrid_tiny)
 from paddle_tpu.nn.functional import delta_rule as dr  # noqa: E402
-from paddle_tpu.serving import Router, SchedulerConfig  # noqa: E402
+
+import served  # noqa: E402
+from served import close, models, rec, recording, traced  # noqa: E402,F401
 
 SEED = 5
 TIGHT = 2e-5
@@ -49,31 +53,19 @@ LOGITS = 1e-3
 #: N(0, 0.1) matrices: logits of order 1-4, so a difference shows
 CFG = dict(TINY, initializer_range=0.1)
 LINEAR, FULL = "linear_attention", "full_attention"
+#: chunks of 32 (the engine's widest for four lanes of 8-token blocks)
+CASE = served.Case(
+    "linear", CFG, SEED, budget=None, atol=LOGITS,
+    reference=lambda ids: ref.logits(CFG, SEED, ids[None])[0])
 
 
 def f32_weights(cfg, seed, layers=None):
-    """The table's bf16 draws upcast to float32: what the reference reads."""
-    made = weights_lib.make(cfg, seed, jnp.bfloat16, layers=layers)
-    return {k: v.astype(jnp.float32) for k, v in made.items()}
-
-
-def fresh_model(cfg=CFG):
-    """A model of its own: the compiled programs of a shared one are shared
-    too, and each test records through its own."""
-    m = OlmoHybridForCausalLM(driver.model_config(cfg))
-    driver.put_weights(m, f32_weights(cfg, SEED))
-    m.eval()
-    return m
+    return served.f32_weights(weights_lib, cfg, seed, layers)
 
 
 @pytest.fixture(scope="module")
 def model():
-    return fresh_model()
-
-
-def close(got, want, atol):
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=atol,
-                               rtol=0)
+    return served.model_of(CASE)
 
 
 def ids_of(rows, tokens, seed=0):
@@ -131,7 +123,7 @@ def mixer_ref(x, layer=0):
 def test_a_mixer_is_the_references(model, layer, tokens):
     x = jnp.asarray(np.random.RandomState(tokens).randn(2, tokens, 96),
                     jnp.float32)
-    got = model.model.layers[layer].mixer(Tensor(x))
+    got = traced(model.model.layers[layer].mixer, Tensor(x))
     close(got._data, mixer_ref(x, layer), TIGHT)
 
 
@@ -144,7 +136,7 @@ def test_a_chunk_continues_from_the_carried_state(model, cuts):
     state = mixer.zero_state(2, jnp.float32)
     parts = []
     for lo, hi in zip((0,) + cuts, cuts + (40,)):
-        out, state = mixer(Tensor(x[:, lo:hi]), state=state)
+        out, state = traced(mixer, Tensor(x[:, lo:hi]), state=state)
         parts.append(out._data)
     close(jnp.concatenate(parts, axis=1), mixer_ref(x), TIGHT)
 
@@ -153,8 +145,8 @@ def test_dropping_the_carried_state_shows(model):
     mixer = model.model.layers[0].mixer
     x = jnp.asarray(np.random.RandomState(2).randn(1, 24, 96), jnp.float32)
     zero = mixer.zero_state(1, jnp.float32)
-    _first, _state = mixer(Tensor(x[:, :12]), state=zero)
-    second, _ = mixer(Tensor(x[:, 12:]), state=zero)     # state dropped
+    _first, _state = traced(mixer, Tensor(x[:, :12]), state=zero)
+    second, _ = traced(mixer, Tensor(x[:, 12:]), state=zero)  # state dropped
     assert np.abs(np.asarray(second._data)
                   - np.asarray(mixer_ref(x))[:, 12:]).max() > 1e-2
 
@@ -165,9 +157,9 @@ def test_left_padding_of_a_first_chunk_is_not_seen(model, pad):
     x = jnp.asarray(np.random.RandomState(3).randn(1, 11, 96), jnp.float32)
     padded = jnp.concatenate([jnp.ones((1, pad, 96)) * 7.0, x], axis=1)
     valid = jnp.arange(pad + 11)[None, :] >= pad
-    out, state = mixer(Tensor(padded), state=mixer.zero_state(
+    out, state = traced(mixer, Tensor(padded), state=mixer.zero_state(
         1, jnp.float32), valid=Tensor(valid))
-    want, want_state = mixer(Tensor(x), state=mixer.zero_state(
+    want, want_state = traced(mixer, Tensor(x), state=mixer.zero_state(
         1, jnp.float32))
     close(out._data[:, pad:], want._data, TIGHT)
     close(state["s"], want_state["s"], TIGHT)
@@ -178,7 +170,7 @@ def test_left_padding_of_a_first_chunk_is_not_seen(model, pad):
 @pytest.mark.parametrize("tokens", [9, 50])
 def test_whole_sequence_forward_is_the_reference(model, tokens):
     ids = ids_of(2, tokens, seed=tokens)
-    got = model(paddle.to_tensor(ids))._data
+    got = traced(model, paddle.to_tensor(ids))._data
     want = ref.logits(CFG, SEED, ids)
     assert float(jnp.abs(want).max()) > 1.0
     close(got, want, LOGITS)
@@ -192,177 +184,59 @@ def test_int8_control_is_another_function():
 
 
 # ====================================================== through the engine
-class Recorder:
-    """The logits every program call samples from, keyed by (request,
-    tokens generated so far): ``serving._sample_tokens`` wrapped with a
-    host callback (as ``tests/test_nemotron_h.py``'s)."""
-
-    def __init__(self, monkeypatch):
-        self.rows = {}
-        inner = serving._sample_tokens
-
-        def sample(logits, temps, top_ps, base_key, rids, ngens, sampling):
-            jax.debug.callback(self.note, logits, rids, ngens, ordered=True)
-            return inner(logits, temps, top_ps, base_key, rids, ngens,
-                         sampling)
-
-        monkeypatch.setattr(serving, "_sample_tokens", sample)
-
-    def note(self, logits, rids, ngens):
-        for row, rid, n in zip(np.asarray(logits), np.asarray(rids),
-                               np.asarray(ngens)):
-            if rid:
-                self.rows[(int(rid), int(n))] = row
-
-
-def reference_logits(prompt, served):
-    ids = np.asarray([list(prompt) + list(served[:-1])], np.int32)
-    return np.asarray(ref.logits(CFG, SEED, ids))[0]
-
-
-def check_against_reference(rec, rid, prompt, served, atol=LOGITS):
-    want = reference_logits(prompt, served)
-    for n in range(len(served)):
-        close(rec.rows[(rid, n)], want[len(prompt) - 1 + n], atol)
-
-
-def prompts_of(lengths, seed=0):
-    rng = np.random.RandomState(seed)
-    return [rng.randint(1, CFG["vocab_size"], n).tolist() for n in lengths]
-
-
-def engine(model, **kw):
-    kw.setdefault("max_batch", 4)
-    kw.setdefault("block_size", 8)
-    kw.setdefault("num_blocks", 64)
-    kw.setdefault("max_blocks_per_seq", 16)
-    return PagedEngine(model, **kw)
-
-
 @pytest.mark.parametrize("front", ["engine", "router", "serial"])
-def test_served_logits_are_the_references(monkeypatch, front):
+def test_served_logits_are_the_references(rec, front):
     """Prefill in one to three chunks of 32 (left-padded first chunk), then
     decode through the cache, four requests sharing the batch, chunks riding
     decode steps as one program where a tick holds both: every logits row
     the programs sampled from against the reference's full forward over
     prompt + served tokens. ``serial``: the schedule without the overlap
     and without the mixed step, the same rows."""
-    rec = Recorder(monkeypatch)
-    eng = engine(fresh_model())
-    assert eng.prefill_width == 32
-    prompts = prompts_of((5, 40, 70, 32))
-    if front == "router":
-        door = Router([eng]).warmup()
-        jax.effects_barrier()
-        rec.rows.clear()                  # the warm-up request's rows
-        rids = [door.add_request(p, max_new_tokens=6) for p in prompts]
-        while door.has_work():
-            door.step()
-        served = {r: door.outcomes[r].tokens for r in rids}
-        assert all(door.outcomes[r].status == "FINISHED" for r in rids)
-    else:
-        if front == "serial":
-            eng._overlap = False
-        rids = [eng.add_request(p, max_new_tokens=6) for p in prompts]
-        served = eng.run_to_completion()
-    jax.effects_barrier()
-    engine_rids = sorted({rid for rid, _n in rec.rows})
-    assert len(engine_rids) == 4
-    for erid, rid, p in zip(engine_rids, rids, prompts):
-        check_against_reference(rec, erid, p, served[rid])
+    eng, _prompts, _ = served.served_logits_are_the_references(
+        CASE, rec, front, width=32, new=6)
     mixed = eng.health()["mixed_share"]
     assert (mixed == 0) if front == "serial" else (mixed > 0)
 
 
 def test_mixed_and_serial_schedules_serve_the_same_tokens():
-    prompts = prompts_of((9, 50, 33, 70, 20, 41), seed=6)
-    served = []
+    prompts = served.prompts_of(CASE, (9, 50, 33, 70, 20, 41), seed=6)
+    tokens = []
     for overlap in (True, False):
-        eng = engine(fresh_model(), scheduler=SchedulerConfig(
-            prefill_token_budget=16))
-        eng._overlap = overlap
+        eng = served.engine(CASE, budget=16, serial=not overlap)
         rids = [eng.add_request(p, max_new_tokens=12) for p in prompts]
         out = eng.run_to_completion()
-        served.append([out[r] for r in rids])
+        tokens.append([out[r] for r in rids])
         assert (eng.health()["mixed_share"] > 0) == overlap
-    assert served[0] == served[1]
+    assert tokens[0] == tokens[1]
 
 
-def test_a_reused_slot_starts_from_zero_state(monkeypatch):
-    rec = Recorder(monkeypatch)
-    eng = engine(fresh_model(), max_batch=1)
-    first, second = prompts_of((20, 13), seed=1)
-    a = eng.add_request(first, max_new_tokens=5)
-    out_a = eng.run_to_completion()[a]
-    b = eng.add_request(second, max_new_tokens=5)
-    out_b = eng.run_to_completion()[b]
-    jax.effects_barrier()
-    check_against_reference(rec, a, first, out_a)
-    check_against_reference(rec, b, second, out_b)
+def test_a_reused_slot_starts_from_zero_state(rec):
+    served.a_reused_slot_starts_clean(CASE, rec, (20, 13))
 
 
-def test_a_lane_mid_prefill_keeps_its_state_while_others_decode(monkeypatch):
-    """A budget of 8 prompt tokens a tick: the 45-token prompt is mid-way
-    for six ticks while the short request decodes in every one of them (its
-    lane rides those decode steps under the seq = 0 sentinel)."""
-    rec = Recorder(monkeypatch)
-    eng = engine(fresh_model(),
-                 scheduler=SchedulerConfig(prefill_token_budget=8))
-    short, long_ = prompts_of((6, 45), seed=2)
-    a = eng.add_request(short, max_new_tokens=12)
-    b = eng.add_request(long_, max_new_tokens=4)
-    overlapped = 0
-    served = {}
-    while eng.has_work():
-        mid = len(eng._prefilling)
-        decoding = len(eng._decode_lanes())
-        served.update(eng.step())
-        overlapped += bool(mid and decoding)
-    assert overlapped >= 3
-    jax.effects_barrier()
-    check_against_reference(rec, a, short, served[a])
-    check_against_reference(rec, b, long_, served[b])
+def test_a_lane_mid_prefill_keeps_its_state_while_others_decode(rec):
+    served.a_lane_mid_prefill_keeps_what_it_holds(CASE, rec)
 
 
-def test_a_memory_stalled_lane_keeps_its_state(monkeypatch):
-    rec = Recorder(monkeypatch)
-    eng = engine(fresh_model(), num_blocks=6, max_blocks_per_seq=4)
-    p, q = prompts_of((7, 7), seed=3)
-    a = eng.add_request(p, max_new_tokens=12)
-    b = eng.add_request(q, max_new_tokens=16)
-    served = eng.run_to_completion(max_ticks=200)
-    jax.effects_barrier()
-    check_against_reference(rec, a, p, served[a])
-    check_against_reference(rec, b, q, served[b])
+def test_a_memory_stalled_lane_keeps_its_state(rec):
+    served.a_memory_stalled_lane_keeps_what_it_holds(CASE, rec)
 
 
-def test_a_preempted_request_re_prefills_to_the_same_tokens(monkeypatch):
-    """Every lane stalled: one is preempted, its blocks freed, and it is
-    re-prefilled over prompt + generated tokens later. The matrix state
-    needs no free and no snapshot: the re-prefill recomputes it, through
-    the chunked form where the first pass went through the step."""
-    rec = Recorder(monkeypatch)
-    eng = engine(fresh_model(), num_blocks=5, max_blocks_per_seq=4)
-    evicted = []
-    evict = eng._evict
-    eng._evict = lambda slot: (evicted.append(slot), evict(slot))[-1]
-    p, q = prompts_of((4, 4), seed=4)
-    a = eng.add_request(p, max_new_tokens=14)
-    b = eng.add_request(q, max_new_tokens=14)
-    served = eng.run_to_completion(max_ticks=300)
-    assert evicted
-    jax.effects_barrier()
-    check_against_reference(rec, a, p, served[a])
-    check_against_reference(rec, b, q, served[b])
-    roomy = engine(fresh_model())
-    again = [roomy.add_request(x, max_new_tokens=14) for x in (p, q)]
+def test_a_preempted_request_re_prefills_to_the_same_tokens(rec):
+    """The matrix state needs no free and no snapshot: the re-prefill
+    recomputes it, through the chunked form where the first pass went
+    through the step. The tokens are those of an engine with room."""
+    prompts, tokens = served.evict_then_readmit_reproduces_the_logits(
+        CASE, rec, usable=4, length=4, new=14, max_ticks=300)
+    roomy = served.engine(CASE)
+    again = [roomy.add_request(x, max_new_tokens=14) for x in prompts]
     out = roomy.run_to_completion()
-    assert [served[a], served[b]] == [out[r] for r in again]
+    assert tokens == [out[r] for r in again]
 
 
-def test_speculate_raises_naming_slot_state(model):
+def test_speculate_raises_naming_slot_state():
     with pytest.raises(TypeError, match="slot_state"):
-        engine(model, speculate="ngram")
+        served.engine(CASE, speculate="ngram")
 
 
 def test_layout_and_health_count_the_matrix_state(model):
@@ -375,7 +249,7 @@ def test_layout_and_health_count_the_matrix_state(model):
                             "s": ((3, 8, 128), jnp.float32)}
     # 6 K/V heads are padded to a whole tile of 16 in the pages
     assert adapter.num_kv_heads == 16 and adapter.pad_heads == 10
-    eng = engine(model)
+    eng = served.engine(CASE)
     h = eng.health()
     per_layer = (3 * 480 + 6 * 8 * 64) * 4
     assert h["state_bytes_per_slot"] == 6 * per_layer \
@@ -384,7 +258,7 @@ def test_layout_and_health_count_the_matrix_state(model):
     assert len(eng.kc) == len(eng.vc) == 2 and len(eng.state) == 6
     paddle.set_flags({"FLAGS_enable_metrics": True})
     try:
-        engine(model)
+        served.engine(CASE)
         from paddle_tpu.inference import resilience
         assert resilience.M_STATE_BYTES.value() == 4 * 6 * per_layer
     finally:
@@ -397,8 +271,9 @@ def test_a_group_query_model_keeps_its_kv_heads():
     m = OlmoHybridForCausalLM(olmo_hybrid_tiny(num_key_value_heads=2))
     adapter = m.paged_adapter()
     assert adapter.num_kv_heads == 2 and adapter.pad_heads == 0
-    eng = engine(m)
-    rid = eng.add_request(prompts_of((11,))[0], max_new_tokens=4)
+    eng = served.engine(CASE, model=m)
+    rid = eng.add_request(served.prompts_of(CASE, (11,))[0],
+                          max_new_tokens=4)
     assert len(eng.run_to_completion()[rid]) == 4
 
 
@@ -421,15 +296,18 @@ def test_an_idle_lane_is_untouched_and_a_fresh_slot_zero_at_128_lanes(
         monkeypatch.setattr(rule_kernel, "INTERPRET", True)
         monkeypatch.setattr(attn_kernel, "INTERPRET", True)
     # two attention heads of 128: the decode-attention kernel takes heads
-    # that are whole lane tiles (the tiny preset's 16 go to the composite)
+    # that are whole lane tiles (the tiny preset's 16 go to the composite);
+    # one layer of each kind
     cfg = dict(CFG, hidden_size=256, num_attention_heads=2,
-               num_key_value_heads=2)
-    prompts = prompts_of((6, 45, 20), seed=8)
-    served = []
+               num_key_value_heads=2, num_hidden_layers=2,
+               layer_types=[LINEAR, FULL])
+    # a model of its own a case (the kernels' switch is read by the trace),
+    # under both of the case's engines
+    m = served.build(dataclasses.replace(CASE, cfg=cfg))
+    prompts = served.prompts_of(CASE, (6, 45, 20), seed=8)
+    tokens = []
     for lanes in (128, 4):
-        eng = PagedEngine(fresh_model(cfg), max_batch=lanes, block_size=8,
-                          num_blocks=160, max_blocks_per_seq=16,
-                          scheduler=SchedulerConfig(prefill_token_budget=8))
+        eng = served.engine(CASE, model=m, max_batch=lanes, budget=8)
         if lanes == 128:
             eng.state = [{k: jnp.full_like(v, 3.0) for k, v in st.items()}
                          for st in eng.state]
@@ -439,10 +317,10 @@ def test_an_idle_lane_is_untouched_and_a_fresh_slot_zero_at_128_lanes(
             overlapped += bool(eng._prefilling and eng._decode_lanes())
             out.update(eng.step())
         assert overlapped >= 3
-        served.append([out[r] for r in rids])
+        tokens.append([out[r] for r in rids])
         if kernels:
             assert eng.health()["decode_attention"] == "kernel"
-    assert served[0] == served[1]
+    assert tokens[0] == tokens[1]
 
 
 def test_decode_step_takes_the_kernels_where_they_run(monkeypatch):
@@ -466,12 +344,13 @@ def test_a_lane_that_is_fresh_and_idle_is_kept_not_zeroed(model, tokens):
     rng = np.random.RandomState(4)
     state = {k: jnp.asarray(rng.randn(*v.shape), v.dtype)
              for k, v in mixer.zero_state(2, jnp.float32).items()}
-    qkv, alpha_log, beta, _gate = mixer.project(
+    qkv, alpha_log, beta, _gate = traced(
+        mixer.project,
         Tensor(jnp.asarray(rng.randn(2, tokens, 96), jnp.float32)))
     valid = jnp.asarray([[False] * tokens, [True] * tokens])
     flags = jnp.asarray([True, False])
-    _o, new = mixer.scan(state, qkv._data, alpha_log._data, beta._data,
-                         valid, fresh=flags, idle=flags)
+    _o, new = traced(mixer.scan, state, qkv._data, alpha_log._data,
+                     beta._data, valid, fresh=flags, idle=flags)
     for name in ("conv", "s"):
         assert np.array_equal(np.asarray(new[name][0]),
                               np.asarray(state[name][0])), name

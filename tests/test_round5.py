@@ -297,13 +297,15 @@ class TestAutotune:
             assert fa._tuned_blocks(kind, 8, 1024, 1024, 64, "bfloat16",
                                     True, 0.1) == (fa.CAUSAL_BLOCK,) * 2
 
-    def test_serving_block_size_default_off_tpu(self):
-        from paddle_tpu.inference.serving import _tuned_decode_block_size
-        from paddle_tpu.models import GPTConfig
-        cfg = GPTConfig(vocab_size=64, hidden_size=32, num_layers=1,
-                        num_heads=2, max_seq_len=32,
-                        use_flash_attention=False)
-        assert _tuned_decode_block_size(cfg, 2, 4, 8) == 16
+    def test_serving_block_size_none_is_refused_by_name(self):
+        """The KV page size is the caller's: nothing probes one."""
+        from paddle_tpu.inference import PagedEngine
+        from paddle_tpu.models import GPTConfig, GPTForCausalLM
+        model = GPTForCausalLM(GPTConfig(
+            vocab_size=64, hidden_size=32, num_layers=1, num_heads=2,
+            max_seq_len=32, use_flash_attention=False))
+        with pytest.raises(ValueError, match="block_size"):
+            PagedEngine(model, block_size=None)
 
     def test_use_autotune_flag_gates(self, monkeypatch):
         from paddle_tpu.core import flags

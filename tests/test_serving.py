@@ -5,60 +5,34 @@ fused_multi_transformer cached decoding — paged-cache generation must
 reproduce the model's own greedy decode exactly, across mixed prompt
 lengths, admission waves, and block-boundary growth.
 """
+import os
+import sys
+
 import numpy as np
 import pytest
 
-import paddle_tpu as paddle
-from paddle_tpu.inference import BlockManager, LlamaPagedEngine, PagedEngine
-from paddle_tpu.models import (GPTConfig, GPTForCausalLM, LlamaConfig,
-                               LlamaForCausalLM)
-from paddle_tpu.serving import SchedulerConfig
+sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 
+import paddle_tpu as paddle  # noqa: E402
+from paddle_tpu.inference import (BlockManager, LlamaPagedEngine,  # noqa: E402
+                                  PagedEngine)
+from paddle_tpu.models import (GPTConfig, GPTForCausalLM,  # noqa: E402
+                               LlamaConfig, LlamaForCausalLM)
+from paddle_tpu.serving import SchedulerConfig  # noqa: E402
 
-_MODEL_CACHE = {}
+from served import (assert_greedy, model_of, models,  # noqa: E402,F401
+                    watch_evictions)
 
 
 def _tiny_model():
-    # one shared instance: weights are seeded identically every call and
-    # no test mutates them, while engines over one model share compiled
-    # tick programs (serving._PAGED_JIT_CACHE) — this suite is decode
-    # parity, not compile timing
-    if "m" not in _MODEL_CACHE:
-        paddle.seed(7)
-        cfg = LlamaConfig(vocab_size=97, hidden_size=64,
-                          intermediate_size=128, num_layers=2, num_heads=4,
-                          max_seq_len=128, use_flash_attention=False)
-        _MODEL_CACHE["m"] = LlamaForCausalLM(cfg)
-    return _MODEL_CACHE["m"]
+    # the file's one dense model: no test mutates a weight, and engines
+    # over one model share compiled tick programs (serving.
+    # _PAGED_JIT_CACHE): this suite is decode parity, not compile timing
+    return model_of("dense")
 
 
 def _tiny_gpt():
-    if "gpt" not in _MODEL_CACHE:
-        paddle.seed(11)
-        cfg = GPTConfig(vocab_size=83, hidden_size=64, num_layers=2,
-                        num_heads=4, max_seq_len=64, dropout=0.0,
-                        use_flash_attention=False)
-        _MODEL_CACHE["gpt"] = GPTForCausalLM(cfg)
-        _MODEL_CACHE["gpt"].eval()
-    return _MODEL_CACHE["gpt"]
-
-
-def _ref_greedy(model, prompt, n_new):
-    ids = paddle.to_tensor(np.asarray([prompt], np.int64))
-    out = model.generate(ids, max_new_tokens=n_new, temperature=0.0,
-                         use_cache=False)
-    return [int(t) for t in np.asarray(out.numpy())[0][len(prompt):]]
-
-
-def _ref_greedy_any(model, prompt, n_new):
-    """Full-recompute greedy through the model's own forward (either
-    architecture)."""
-    ids, out = list(prompt), []
-    for _ in range(n_new):
-        logits = model(paddle.to_tensor(np.asarray([ids], np.int64)))
-        out.append(int(np.argmax(np.asarray(logits.numpy())[0, -1])))
-        ids.append(out[-1])
-    return out
+    return model_of("gpt")
 
 
 class TestBlockManager:
@@ -82,7 +56,7 @@ class TestPagedEngineParity:
                                num_blocks=32, max_blocks_per_seq=16)
         rid = eng.add_request(prompt, max_new_tokens=8)
         out = eng.run_to_completion()
-        assert out[rid] == _ref_greedy(model, prompt, 8)
+        assert_greedy(model, prompt, out[rid], 8)
 
     @pytest.mark.slow
     # slow-marked (~15s, 870s tier-1 budget): paged-vs-dense parity
@@ -100,7 +74,7 @@ class TestPagedEngineParity:
         out = eng.run_to_completion()
         # only 2 slots: requests 3/4 admitted after earlier ones finish
         for rid, p in zip(rids, prompts):
-            assert out[rid] == _ref_greedy(model, p, 6), p
+            assert_greedy(model, p, out[rid], 6)
 
     def test_block_growth_across_boundaries(self):
         model = _tiny_model()
@@ -111,18 +85,21 @@ class TestPagedEngineParity:
                                num_blocks=16, max_blocks_per_seq=8)
         rid = eng.add_request(prompt, max_new_tokens=12)
         out = eng.run_to_completion()
-        assert out[rid] == _ref_greedy(model, prompt, 12)
+        assert_greedy(model, prompt, out[rid], 12)
         # all blocks released after completion
         assert eng.bm.available == 15
 
     def test_eos_stops_early(self):
         model = _tiny_model()
         prompt = [5, 9, 2]
-        ref = _ref_greedy(model, prompt, 10)
+        geometry = dict(max_batch=1, block_size=4, num_blocks=16,
+                        max_blocks_per_seq=8)
+        plain = LlamaPagedEngine(model, **geometry)
+        rid = plain.add_request(prompt, max_new_tokens=10)
+        ref = plain.run_to_completion()[rid]
+        assert_greedy(model, prompt, ref, 10)
         eos = ref[2]                  # force a stop at the 3rd token
-        eng = LlamaPagedEngine(model, max_batch=1, block_size=4,
-                               num_blocks=16, max_blocks_per_seq=8,
-                               eos_id=eos)
+        eng = LlamaPagedEngine(model, eos_id=eos, **geometry)
         rid = eng.add_request(prompt, max_new_tokens=10)
         out = eng.run_to_completion()
         assert out[rid] == ref[:3]
@@ -141,8 +118,8 @@ class TestPagedEngineParity:
         r1 = eng.add_request(p1, max_new_tokens=6)
         r2 = eng.add_request(p2, max_new_tokens=6)
         out = eng.run_to_completion(max_ticks=200)
-        assert out[r1] == _ref_greedy(model, p1, 6)
-        assert out[r2] == _ref_greedy(model, p2, 6)
+        assert_greedy(model, p1, out[r1], 6)
+        assert_greedy(model, p2, out[r2], 6)
         assert eng.bm.available == 4          # everything released
 
     def test_never_fitting_request_fails_at_submit(self):
@@ -189,7 +166,9 @@ class TestSampling:
             return eng.run_to_completion()[rid]
 
         # greedy path ignores the seed entirely
-        assert run(0, 0.0) == run(123, 0.0) == _ref_greedy(model, prompt, 8)
+        greedy = run(0, 0.0)
+        assert greedy == run(123, 0.0)
+        assert_greedy(model, prompt, greedy, 8)
         # sampling is reproducible per seed, and seeds differ
         s1, s2, s3 = run(7, 1.0), run(7, 1.0), run(9, 1.0)
         assert s1 == s2
@@ -217,7 +196,7 @@ class TestGPTPagedEngine:
         assert eng.prefill_width == 8
         rid = eng.add_request(prompt, max_new_tokens=6)
         out = eng.run_to_completion()
-        assert out[rid] == _ref_greedy_any(model, prompt, 6)
+        assert_greedy(model, prompt, out[rid], 6)
 
 
 # --------------------------------------------- the prefill program's shape
@@ -269,7 +248,7 @@ class TestPrefillShape:
         assert got["budget"] == got["block"]
         if kv_dtype is None:
             # the float engine is also the model's own greedy decode
-            assert got["block"] == _ref_greedy_any(model, prompt, 6)
+            assert_greedy(model, prompt, got["block"], 6)
 
     @pytest.mark.parametrize("kind", list(_WIDTHS))
     def test_preemption_reprefills_same_tokens(self, kind):
@@ -280,14 +259,12 @@ class TestPrefillShape:
         prompts = [[int(t) for t in rng.randint(1, 97, size=4)]
                    for _ in range(2)]
         eng = _width_engine(model, kind, num_blocks=5, max_blocks_per_seq=4)
-        evicted = []
-        evict = eng._evict
-        eng._evict = lambda slot: (evicted.append(slot), evict(slot))[-1]
+        evicted = watch_evictions(eng)
         rids = [eng.add_request(p, max_new_tokens=6) for p in prompts]
         out = eng.run_to_completion(max_ticks=200)
         assert evicted
         for rid, p in zip(rids, prompts):
-            assert out[rid] == _ref_greedy(model, p, 6)
+            assert_greedy(model, p, out[rid], 6)
         assert eng.bm.available == 4
 
     @pytest.mark.parametrize("kind", list(_WIDTHS))
@@ -377,9 +354,8 @@ class TestDecodeKernelParity:
             self._model(), max_batch=3, block_size=16, num_blocks=8,
             max_blocks_per_seq=6,
             scheduler=SchedulerConfig(prefill_token_budget=16))
-        evicted, sentinel_ticks = [], []
-        evict, run = eng._evict, eng._run_chunk
-        eng._evict = lambda slot: (evicted.append(slot), evict(slot))[-1]
+        evicted, sentinel_ticks = watch_evictions(eng), []
+        run = eng._run_chunk
 
         def spy(rec, tokens, seq_lens, *a, **kw):
             if rec.phase == "decode" and eng._prefilling:
@@ -567,9 +543,7 @@ class TestLatentPages:
         def serve(**kw):
             eng = PagedEngine(model, max_batch=4, block_size=8,
                               max_blocks_per_seq=6, **kw)
-            evicted = []
-            evict = eng._evict
-            eng._evict = lambda slot: (evicted.append(slot), evict(slot))[-1]
+            evicted = watch_evictions(eng)
             rids = [eng.add_request(p, max_new_tokens=20) for p in prompts]
             out = eng.run_to_completion(max_ticks=600)
             assert eng.bm.available == eng._total_usable

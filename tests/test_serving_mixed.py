@@ -25,27 +25,12 @@ sys.path.insert(0, os.path.join(ROOT, "tests"))
 import jax  # noqa: E402
 
 import paddle_tpu as paddle  # noqa: E402
-from paddle_tpu.models import GPTConfig, GPTForCausalLM  # noqa: E402
 
-import test_serving_overlap as overlap  # noqa: E402
-from test_serving_overlap import (LENGTHS, NEW, engine, prompts_of,  # noqa: E402
-                                  quiesced, serve)
+from served import (LENGTHS, NEW, assert_greedy, engine, model_of,  # noqa: E402,F401
+                    models, prompts_of, quiesced, serve)
 
 #: an adapter each: Llama, GPT, nemotron_h, exaone_moe, deepseek_v3
 KINDS = ["dense", "gpt", "hybrid", "window", "latent"]
-
-
-@pytest.fixture(autouse=True)
-def gpt_model():
-    """The GPT adapter's model (learned positions, fused qkv, tied head),
-    under the overlap file's table of models."""
-    if "gpt" not in overlap._MODELS:
-        paddle.seed(11)
-        m = GPTForCausalLM(GPTConfig(
-            vocab_size=89, hidden_size=64, num_layers=2, num_heads=4,
-            max_seq_len=128, use_flash_attention=False))
-        m.eval()
-        overlap._MODELS["gpt"], overlap.VOCAB["gpt"] = m, 89
 
 
 def watch(eng):
@@ -130,14 +115,13 @@ def test_whole_prompt_prefill_mixes_its_last_chunk_only(kind):
 
 @pytest.mark.parametrize("kind", KINDS)
 def test_a_memory_stalled_lane_rides_a_mixed_step_as_a_sentinel(kind):
-    """Ten usable blocks of 8 for three lanes that come to need six, five
+    """Ten usable blocks of 8 for three requests that come to need six, five
     and eight: a lane finds no block for its next token while another
     slot's chunk is aboard the step (it rides as a sentinel and is fed
     again once blocks free), one lane is preempted and prefilled again."""
-    kw = dict(block_size=8, num_blocks=11, max_blocks_per_seq=12,
-              max_batch=3)
     want, got, seen, _health = both(
-        kind, prompts_of(kind, (20, 9, 33), seed=4), new=(24, 24, 24), **kw)
+        kind, prompts_of(kind, (20, 9, 33), seed=4), new=(24, 24, 24),
+        usable=10)
     assert got == want
     assert seen["mixed"] and seen["stalled"]
 
@@ -283,8 +267,7 @@ def test_warmup_leaves_nothing_behind_and_early_traffic_is_served():
     eng.warmup()
     assert eng._unread is None and eng.lifecycle.ready()
     assert not eng.outcomes or set(eng.outcomes) == {early}
-    assert eng.run_to_completion()[early] == \
-        overlap.ref_greedy(overlap.model_of("dense"), p, 3)
+    assert_greedy(model_of("dense"), p, eng.run_to_completion()[early], 3)
     quiesced(eng)
 
 

@@ -13,126 +13,23 @@ from __future__ import annotations
 import os
 import sys
 
-import numpy as np
 import pytest
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
 
+sys.path.insert(0, os.path.join(ROOT, "tests"))
+
 import jax  # noqa: E402
-import jax.numpy as jnp  # noqa: E402
 
 import paddle_tpu as paddle  # noqa: E402
 from paddle_tpu.fault import inject  # noqa: E402
 from paddle_tpu.inference import PagedEngine  # noqa: E402
 from paddle_tpu.inference.resilience import ReplicaState, RequestStatus  # noqa: E402
-from paddle_tpu.models import LlamaConfig, LlamaForCausalLM  # noqa: E402
-from paddle_tpu.serving import Router, SchedulerConfig  # noqa: E402
+from paddle_tpu.serving import Router  # noqa: E402
 
-_MODELS = {}
-VOCAB = {"dense": 97}
-
-
-def model_of(kind):
-    """One model a kind for the file: engines over one model share their
-    compiled programs, and no test changes a weight."""
-    if kind in _MODELS:
-        return _MODELS[kind]
-    if kind == "dense":
-        paddle.seed(7)
-        m = LlamaForCausalLM(LlamaConfig(
-            vocab_size=97, hidden_size=64, intermediate_size=128,
-            num_layers=2, num_heads=4, max_seq_len=128,
-            use_flash_attention=False))
-    elif kind == "hybrid":          # Mamba-2 state a slot beside paged K/V
-        from benchmark.drivers import serve_hybrid as driver
-        from benchmark.lib import weights_nemotron_h as weights_lib
-        from benchmark.tests.tiny_hybrid import NEMOTRON as cfg
-        from paddle_tpu.models import NemotronHForCausalLM
-        m = NemotronHForCausalLM(driver.model_config(cfg))
-    elif kind == "latent":          # latent pages, one pool a layer
-        from benchmark.drivers import serve_deepseek_v3 as driver
-        from benchmark.lib import weights_deepseek_v3 as weights_lib
-        from benchmark.tests.tiny_deepseek_v3 import DEEPSEEK as cfg
-        from paddle_tpu.models import DeepseekV3ForCausalLM
-        m = DeepseekV3ForCausalLM(driver.model_config(cfg))
-    else:                           # window rows a slot beside paged K/V
-        from benchmark.drivers import serve_exaone_moe as driver
-        from benchmark.lib import weights_exaone_moe as weights_lib
-        from benchmark.tests.tiny_exaone_moe import EXAONE as cfg
-        from paddle_tpu.models import ExaoneMoeForCausalLM
-        m = ExaoneMoeForCausalLM(driver.model_config(cfg))
-    if kind != "dense":
-        VOCAB[kind] = cfg["vocab_size"]
-        made = weights_lib.make(cfg, 5, jnp.bfloat16)
-        driver.put_weights(m, {k: v.astype(jnp.float32)
-                               for k, v in made.items()})
-    m.eval()
-    _MODELS[kind] = m
-    return m
-
-
-def engine(kind="dense", *, serial=False, budget=16, **kw):
-    kw.setdefault("max_batch", 4)
-    kw.setdefault("block_size", 8)
-    kw.setdefault("num_blocks", 64)
-    kw.setdefault("max_blocks_per_seq", 16)
-    if budget:
-        kw.setdefault("scheduler",
-                      SchedulerConfig(prefill_token_budget=budget))
-    eng = PagedEngine(model_of(kind), **kw)
-    if serial:
-        eng._overlap = False        # every program read before the next
-    return eng
-
-
-def prompts_of(kind, lengths, seed=0):
-    model_of(kind)
-    rng = np.random.RandomState(seed)
-    return [rng.randint(1, VOCAB[kind], n).tolist() for n in lengths]
-
-
-LENGTHS = (5, 17, 33, 8, 40, 3, 21)
-#: answers of one token (never fed to a decode step) to a dozen
-NEW = (6, 1, 9, 2, 12, 7, 4)
-
-
-def serve(eng, prompts, new=NEW, sampled=False, streams=True):
-    """``{index: tokens}`` of every request, its stream checked against
-    its outcome on the way."""
-    rids, bufs = [], []
-    for i, (p, n) in enumerate(zip(prompts, new)):
-        warm = sampled and i % 3 != 2        # a greedy lane among sampled
-        rids.append(eng.add_request(
-            p, max_new_tokens=n, temperature=0.8 if warm else 0.0,
-            top_p=0.9 if warm else 1.0))
-        bufs.append(eng.open_stream(rids[-1]) if streams else None)
-    out = eng.run_to_completion(max_ticks=2000)
-    assert eng.tick_failures == 0
-    served = {}
-    for i, rid in enumerate(rids):
-        oc = eng.outcomes[rid]
-        assert oc.status == RequestStatus.FINISHED, (i, oc.status, oc.detail)
-        assert out[rid] == oc.tokens and len(oc.tokens) == new[i]
-        if streams:
-            assert bufs[i] == oc.tokens
-        served[i] = oc.tokens
-    quiesced(eng)
-    return served
-
-
-def quiesced(eng):
-    assert eng._unread is None and not eng.has_work()
-    assert not eng.queue and all(s is None for s in eng.slots)
-    assert eng.bm.available == eng._total_usable, "leaked KV blocks"
-    assert not eng._inflight.any()
-
-
-def ref_greedy(model, prompt, n_new):
-    ids = paddle.to_tensor(np.asarray([prompt], np.int64))
-    out = model.generate(ids, max_new_tokens=n_new, temperature=0.0,
-                         use_cache=False)
-    return [int(t) for t in np.asarray(out.numpy())[0][len(prompt):]]
+from served import (LENGTHS, NEW, assert_greedy, engine, model_of,  # noqa: E402,F401
+                    models, prompts_of, quiesced, serve)
 
 
 # ------------------------------------------------------------ (a) the tokens
@@ -150,7 +47,7 @@ def test_tokens_are_those_of_programs_run_one_at_a_time(kind, budget,
     assert eng.health()["overlap_share"] > 0.5
     if kind == "dense" and not sampled:
         for i, p in enumerate(prompts):
-            assert got[i] == ref_greedy(model_of(kind), p, NEW[i])
+            assert_greedy(model_of(kind), p, got[i], NEW[i])
 
 
 def test_a_decode_step_feeds_on_the_unread_steps_tokens():
@@ -310,7 +207,7 @@ def test_a_program_failure_surfaces_at_the_read_and_the_engine_serves_on():
     quiesced(eng)           # the newer launch went with the failed one
     later = eng.add_request(prompts[2], max_new_tokens=5)
     out = eng.run_to_completion()
-    assert out[later] == ref_greedy(model_of("dense"), prompts[2], 5)
+    assert_greedy(model_of("dense"), prompts[2], out[later], 5)
     eng.recover()
     assert eng.lifecycle.ready()
     quiesced(eng)
@@ -326,8 +223,7 @@ def test_nothing_is_left_unread(how):
         early = eng.add_request(p, max_new_tokens=3)
         eng.warmup()
         assert eng._unread is None and eng.lifecycle.ready()
-        assert eng.run_to_completion()[early] == \
-            ref_greedy(model_of("dense"), p, 3)
+        assert_greedy(model_of("dense"), p, eng.run_to_completion()[early], 3)
         return quiesced(eng)
     front = Router([eng]).warmup() if how == "router" else eng
     rid = front.add_request(p, max_new_tokens=3)
@@ -341,15 +237,15 @@ def test_nothing_is_left_unread(how):
         # chunk + step (the chunk's token is read behind the step's
         # launch), a step, and the read of the last one
         assert seen == [1, 2, 3]
-        assert done[rid] == ref_greedy(model_of("dense"), p, 3)
+        got = done[rid]
     elif how == "run_to_completion":
-        assert front.run_to_completion()[rid] == \
-            ref_greedy(model_of("dense"), p, 3)
+        got = front.run_to_completion()[rid]
     else:
         eng.step()
         assert eng._unread is not None and eng.has_work()
-        assert eng.drain()[rid] == ref_greedy(model_of("dense"), p, 3)
+        got = eng.drain()[rid]
         assert eng.lifecycle.state == ReplicaState.STOPPED
+    assert_greedy(model_of("dense"), p, got, 3)
     quiesced(eng)
 
 
@@ -373,8 +269,7 @@ def test_speculative_engine_reads_in_the_launching_tick():
     while eng.has_work():
         eng.step()
         assert eng._unread is None
-    assert eng.outcomes[rid].tokens == \
-        ref_greedy(model_of("dense"), p + p, 8)
+    assert_greedy(model_of("dense"), p + p, eng.outcomes[rid].tokens, 8)
     h = eng.health()
     assert h["overlap_share"] == 0 and h["host_late_share"] is not None
     quiesced(eng)

@@ -8,11 +8,17 @@ import pickle
 import struct
 import tarfile
 
+import sys
+
 import numpy as np
 import pytest
 
-import paddle_tpu as paddle
-from paddle_tpu.vision import datasets, models, transforms as T
+sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
+
+import paddle_tpu as paddle  # noqa: E402
+from paddle_tpu.vision import datasets, models, transforms as T  # noqa: E402
+
+from served import traced  # noqa: E402
 
 
 class TestZooForward:
@@ -48,14 +54,15 @@ class TestZooForward:
         net.eval()
         x = paddle.to_tensor(np.random.RandomState(0).rand(
             2, 3, size, size).astype(np.float32))
-        assert net(x).shape == [2, 7]
+        # the forward as one traced program: a compile, not one a layer
+        assert traced(net, x).shape == [2, 7]
 
     def test_googlenet_aux_heads(self):
         net = models.googlenet(num_classes=5)
         net.eval()
         x = paddle.to_tensor(np.random.RandomState(0).rand(
             1, 3, 96, 96).astype(np.float32))
-        out, a1, a2 = net(x)
+        out, a1, a2 = traced(net, x)
         assert out.shape == [1, 5] and a1.shape == [1, 5] and a2.shape == [1, 5]
 
     @_slow

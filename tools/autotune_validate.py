@@ -5,8 +5,8 @@ every tile (the constants and the autotuner's candidates), printing beside
 each time what that tile makes the kernels run (``flash_plan``: the grid
 and the executed share of the square), then (a) the hand-tuned v5e
 constants against (b) ``_tuned_blocks``' pick (a causal call's is its
-constant; a non-causal call's the measured winner), plus the serving
-decode tick block-size probe. The pick must match or beat the constants
+constant; a non-causal call's the measured winner). The pick must match
+or beat the constants
 (VERDICT r4 item 3 'Done' criterion), and the cache file must round-trip.
 
 Timing discipline: jitted closures only (steady state, no retracing),
@@ -127,15 +127,6 @@ def main():
         worst = min(worst, sp)
         print(f"{kind:4} {S:>6} {str(cdef):>12} {td*1e3:8.2f}m "
               f"{str(tuple(ctun)):>12} {tt*1e3:8.2f}m {sp:7.3f}x")
-
-    # serving decode probe
-    from paddle_tpu.inference.serving import _tuned_decode_block_size
-    from paddle_tpu.models import GPTConfig
-    cfg = GPTConfig(vocab_size=50304, hidden_size=1024, num_layers=1,
-                    num_heads=16, max_seq_len=1024,
-                    use_flash_attention=False)
-    bs = _tuned_decode_block_size(cfg, 16, 8, 32)
-    print(f"serving decode block_size -> {bs}")
 
     # cache round-trip
     with open(cache_file) as f:
