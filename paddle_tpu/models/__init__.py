@@ -24,6 +24,8 @@ from .olmo_hybrid import (OlmoHybridConfig, OlmoHybridForCausalLM,
                           OlmoHybridModel, olmo_hybrid_tiny)
 from .lfm2_moe import (Lfm2MoeConfig, Lfm2MoeForCausalLM, Lfm2MoeModel,
                        lfm2_moe_tiny)
+from .solar_open2 import (SolarOpen2Config, SolarOpen2ForCausalLM,
+                          SolarOpen2Model, solar_open2_tiny)
 
 __all__ = [
     "LeNet", "GPTConfig", "GPTModel", "GPTForCausalLM",
@@ -42,4 +44,6 @@ __all__ = [
     "OlmoHybridConfig", "OlmoHybridModel", "OlmoHybridForCausalLM",
     "olmo_hybrid_tiny",
     "Lfm2MoeConfig", "Lfm2MoeModel", "Lfm2MoeForCausalLM", "lfm2_moe_tiny",
+    "SolarOpen2Config", "SolarOpen2Model", "SolarOpen2ForCausalLM",
+    "solar_open2_tiny",
 ]

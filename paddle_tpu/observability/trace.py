@@ -259,16 +259,31 @@ STARTUP_SPANS: Dict[str, Tuple[str, Optional[str], str]] = {
 }
 
 
-#: THE list of the ``jax.named_scope``s a trained model's operations carry
-#: in their ``op_name`` on the device plane: name -> what runs under it. A
-#: dotted name sits inside its stem's scope. Forward and backward alike (the
-#: backward's path wraps the name in ``transpose(jvp(...))``); a fusion is
-#: billed to the one name the compiler left on it. PERF.md section 3 says
-#: which metric reads which.
+#: THE list of the ``jax.named_scope``s a model's operations carry in their
+#: ``op_name`` on the device plane, trained (``Engine``'s step) or served
+#: (the paged engine's chunk, step and chunk-with-step programs): name ->
+#: what runs under it. A dotted name sits inside its stem's scope. Forward
+#: and backward alike (the backward's path wraps the name in
+#: ``transpose(jvp(...))``); a fusion is billed to the one name the compiler
+#: left on it. PERF.md section 3 says which metric reads which.
 DEVICE_SCOPES: Dict[str, str] = {
     "embed": "the token embedding's gather (and a learned position table)",
     "attn": "a softmax-attention operator: projections, q/k norms, rotary "
             "angles, the flash kernels, the output projection",
+    "attn.full": "served: a full-attention layer that pages its K/V "
+                 "(projections, cache write, scores, the output projection)",
+    "attn.full.gate": "Solar Open 2's output gate: sigmoid of its own "
+                      "projection times the attention's output",
+    "attn.linear": "served: a delta-rule linear-attention layer, all of it "
+                   "(Olmo-Hybrid's scalar decay, Solar Open 2's decay a "
+                   "channel)",
+    "attn.linear.proj": "q / k / v, decay, beta and gate projections, and "
+                        "the output projection",
+    "attn.linear.conv": "the causal depthwise convolutions and their "
+                        "carried windows",
+    "attn.linear.rule": "q / k normalisation and the rule: the step kernel "
+                        "``delta_rule_step`` (decode) or the chunked form",
+    "attn.linear.norm": "the per-head RMSNorm of the read-out and its gate",
     "short_conv": "LFM2's gated short convolution: in-projection, the two "
                   "gates, the depthwise causal taps, out-projection",
     "mlp": "a dense feed-forward",

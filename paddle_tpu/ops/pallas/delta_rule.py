@@ -34,6 +34,13 @@ is bound by those bytes.
 * The state is aliased in place (``input_output_aliases``): a donated cache
   is updated where it lies.
 
+**A decay a key channel** (Kimi delta attention; ``alpha`` (B, H, d_k)):
+``u = beta (v - S^T (alpha * k))``, ``S' = Diag(alpha) S + k u^T``, ``o =
+S^T (alpha * q) + (k . q) u``. The decay is a third column beside ``k`` and
+``q``: broadcast along the lanes it scales each ROW of the tile by its own
+factor, once, and both reductions read the scaled tile; ``p`` is 1 (the
+family's ``d_v`` is 128). Same visit, same bytes, one product a cell more.
+
 ``INTERPRET = True`` runs the same kernel through the Pallas interpreter so
 CPU tests cover the kernel's own code. The kernel's name on a device trace
 is ``delta_rule_step``.
@@ -59,28 +66,33 @@ BLOCK_BYTES = 3 << 19
 _LANES = 128
 
 
-def rows_per_block(groups: int, key_dim: int, width: int, packed: int) -> int:
+def rows_per_block(groups: int, key_dim: int, width: int, packed: int,
+                   columns: int = 2) -> int:
     """Packed rows (``packed`` heads each) a grid step takes: the most that
-    divide ``groups``, fit ``BLOCK_BYTES`` and whose heads' ``k`` and ``q``
-    columns fit one 128-lane tile."""
+    divide ``groups``, fit ``BLOCK_BYTES`` and whose heads' ``columns``
+    columns each (``k`` and ``q``; with a decay a channel that too) fit one
+    128-lane tile."""
     best = 0
     for n in range(1, groups + 1):
         if (groups % n == 0 and n * key_dim * width * 4 <= BLOCK_BYTES
-                and 2 * n * packed <= _LANES):
+                and columns * n * packed <= _LANES):
             best = n
     return best
 
 
-def supports(state_shape, key_dim: int, packed: int) -> bool:
+def supports(state_shape, key_dim: int, packed: int,
+             channel: bool = False) -> bool:
     """Whether the kernel was written for this packed state ``(B, H / p,
     d_k, p * d_v)``: whole lane tiles in the minor dimension, whole float32
-    sublane tiles of ``d_k``, and a block of rows that fits."""
-    if len(state_shape) != 4 or not packed:
+    sublane tiles of ``d_k``, and a block of rows that fits. ``channel``:
+    with the decay a key channel, which takes ``p`` = 1."""
+    if len(state_shape) != 4 or not packed or (channel and packed != 1):
         return False
     _b, groups, dk, width = state_shape
     return (dk == key_dim and width % _LANES == 0 and width % packed == 0
             and dk % 8 == 0
-            and rows_per_block(groups, dk, width, packed) > 0)
+            and rows_per_block(groups, dk, width, packed,
+                               3 if channel else 2) > 0)
 
 
 def _kernel(fresh_ref, alpha_ref, beta_ref, qk_ref, s_ref, cols_ref, v_ref,
@@ -124,23 +136,50 @@ def _kernel(fresh_ref, alpha_ref, beta_ref, qk_ref, s_ref, cols_ref, v_ref,
         o_ref[0, 0, r:r + 1] = alpha * s_q + per_head(qk_ref, head) * u
 
 
+def _channel_kernel(fresh_ref, beta_ref, qk_ref, s_ref, cols_ref, v_ref,
+                    s_out, o_ref, *, rows):
+    lane, block = pl.program_id(0), pl.program_id(1)
+    fresh = fresh_ref[lane] > 0
+    dk, width = s_ref.shape[2], s_ref.shape[3]
+    cols = cols_ref[0, 0]                                  # (d_k, 128)
+
+    def column(at):
+        return jnp.broadcast_to(cols[:, at:at + 1], (dk, width))
+
+    for r in range(rows):
+        head = block * rows + r
+        s = s_ref[0, r]                                    # (d_k, d_v)
+        s = jnp.where(fresh, jnp.zeros_like(s), s)
+        s = column(2 * rows + r) * s                       # Diag(alpha) S
+        kb = column(r)
+        s_k = jnp.sum(s * kb, axis=0, keepdims=True)       # S^T (alpha * k)
+        s_q = jnp.sum(s * column(rows + r), axis=0, keepdims=True)
+        u = beta_ref[lane, head] * (v_ref[0, 0, r:r + 1] - s_k)
+        s_out[0, r] = s + kb * u
+        o_ref[0, 0, r:r + 1] = s_q + qk_ref[lane, head] * u
+
+
 def delta_rule_step(q, k, v, alpha, beta, state, fresh=None, idle=None,
                     packed=1):
     """``nn.functional.delta_rule.step_arrays`` on a packed state, one visit
     a tile. ``q`` / ``k`` (B, H, d_k), ``v`` (B, H, d_v), ``alpha`` /
     ``beta`` (B, H), ``state`` (B, H / packed, d_k, packed * d_v) float32,
-    ``fresh`` / ``idle`` (B,) bool or None. Returns ``(o (B, H, d_v)
-    float32, new state)``; ``supports`` says which shapes."""
+    ``fresh`` / ``idle`` (B,) bool or None. With ``alpha`` (B, H, d_k), a
+    decay a key channel, it is ``channel_step_arrays`` (``packed`` 1).
+    Returns ``(o (B, H, d_v) float32, new state)``; ``supports`` says which
+    shapes."""
     f32 = jnp.float32
     bsz, h, dk = q.shape
     dv = v.shape[-1]
+    channel = alpha.ndim == 3
     grp, width = h // packed, packed * dv
-    rows = rows_per_block(grp, dk, width, packed)
+    rows = rows_per_block(grp, dk, width, packed, 3 if channel else 2)
     blocks = grp // rows
     q, k, v = q.astype(f32), k.astype(f32), v.astype(f32)
     alpha, beta = alpha.astype(f32), beta.astype(f32)
     if idle is not None:
-        alpha = jnp.where(idle[:, None], 1.0, alpha)
+        alpha = jnp.where(idle[(slice(None),) + (None,) * (alpha.ndim - 1)],
+                          1.0, alpha)
         beta = jnp.where(idle[:, None], 0.0, beta)
     fresh = (jnp.zeros((bsz,), jnp.int32) if fresh is None
              else fresh.astype(jnp.int32))
@@ -150,17 +189,25 @@ def delta_rule_step(q, k, v, alpha, beta, state, fresh=None, idle=None,
     def columns(a):     # (B, H, d_k) -> (B, blocks, d_k, rows * packed)
         return a.reshape(bsz, blocks, rows * packed, dk).transpose(0, 1, 3, 2)
 
-    cols = jnp.concatenate([columns(k), columns(q)], axis=-1)
+    cols = jnp.concatenate([columns(k), columns(q)]
+                           + ([columns(alpha)] if channel else []), axis=-1)
     cols = jnp.pad(cols, ((0, 0),) * 3 + ((0, _LANES - cols.shape[-1]),))
 
-    kernel = functools.partial(_kernel, rows=rows, packed=packed,
-                               value_dim=dv)
+    # what is a number a head a lane, in SMEM before the tiles
+    per_head = (beta, jnp.sum(q * k, axis=-1))
+    if channel:
+        kernel = functools.partial(_channel_kernel, rows=rows)
+    else:
+        per_head = (alpha,) + per_head
+        kernel = functools.partial(_kernel, rows=rows, packed=packed,
+                                   value_dim=dv)
+    scalars = (fresh,) + per_head
     new, o = pl.pallas_call(
         kernel,
         out_shape=(jax.ShapeDtypeStruct(state.shape, f32),
                    jax.ShapeDtypeStruct((bsz, blocks, rows, width), f32)),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=4,
+            num_scalar_prefetch=len(scalars),
             grid=(bsz, blocks),
             in_specs=[
                 pl.BlockSpec((1, rows, dk, width),
@@ -174,12 +221,11 @@ def delta_rule_step(q, k, v, alpha, beta, state, fresh=None, idle=None,
                              lambda b, n, *_: (b, n, 0, 0)),
                 pl.BlockSpec((1, 1, rows, width),
                              lambda b, n, *_: (b, n, 0, 0))]),
-        # the state (operand 4, after the prefetched scalars) in place
-        input_output_aliases={4: 0},
+        # the state (the first operand after the prefetched scalars) in place
+        input_output_aliases={len(scalars): 0},
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
         interpret=INTERPRET,
         name="delta_rule_step",
-    )(fresh, alpha, beta, jnp.sum(q * k, axis=-1), state.astype(f32), cols,
-      v.reshape(bsz, blocks, rows, width))
+    )(*scalars, state.astype(f32), cols, v.reshape(bsz, blocks, rows, width))
     return o.reshape(bsz, h, dv), new

@@ -103,6 +103,9 @@ _PRESETS = {
     "linear": ("serve_olmo_hybrid", "weights_olmo_hybrid",   # a delta-rule
                ("tiny_olmo_hybrid", "CFG"),                  # matrix a head
                "OlmoHybridForCausalLM"),
+    "kda": ("serve_solar_open2", "weights_solar_open2",     # a delta-rule
+            ("tiny_solar_open2", "CFG"),                    # matrix a head
+            "SolarOpen2ForCausalLM"),                       # + experts
 }
 
 

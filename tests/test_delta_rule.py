@@ -178,7 +178,9 @@ def test_block_inverse_where_the_matrix_is_ill_conditioned():
     (7, 4, {"chunk": 4, "block": 4, "merge_levels": 0, "padded": 4}),
     (1, 64, {"chunk": 1, "block": 1, "merge_levels": 0, "padded": 1})])
 def test_chunk_plan_says_what_a_call_is_built_with(tokens, chunk, plan):
-    assert dr.chunk_plan(tokens, chunk) == plan
+    assert dr.chunk_plan(tokens, chunk) == dict(plan, decay="head")
+    assert dr.chunk_plan(tokens, chunk, "channel") == dict(plan,
+                                                           decay="channel")
 
 
 def test_chunk_arrays_stamps_its_plan_on_the_trace_entry():
@@ -376,3 +378,209 @@ def test_op_wrappers_take_tensors_and_default_the_state():
         paddle.to_tensor(beta[:, 0].astype(np.float32)),
         paddle.to_tensor(np.zeros((B, H, DK, DV), np.float32)))
     close(o1._data, want_o[:, 0])
+
+
+# ====================================================== a decay a channel
+# Kimi delta attention: ``alpha`` is a vector a head, ``S_t = (I - beta_t
+# k_t k_t^T) Diag(alpha_t) S_{t-1} + beta_t k_t v_t^T``.
+channel_chunk_arrays = jax.jit(dr.channel_chunk_arrays, static_argnums=(7,))
+channel_step_arrays = jax.jit(dr.channel_step_arrays)
+
+
+def channel_inputs(tokens, seed=0, steep=None):
+    """``inputs`` with a log decay a key channel; ``steep``: every third
+    channel decays by that much a token (``exp(-30)`` is 1e-13: over a full
+    chunk its cumulative log decay passes -1900, where ``exp`` of the
+    negated difference is float32's infinity)."""
+    q, k, v, _alpha_log, beta = inputs(tokens, seed)
+    rng = np.random.default_rng(seed + 100)
+    alpha_log = -np.exp(rng.normal(size=(B, tokens, H, DK))) * 0.3
+    if steep is not None:
+        alpha_log[..., ::3] = -steep
+    return q, k, v, alpha_log, beta
+
+
+def channel_loop(q, k, v, alpha_log, beta, state, valid):
+    """The recurrence as written, a token and a head at a time, float64."""
+    s = np.array(state, np.float64)
+    out = np.zeros(v.shape)
+    for t in range(q.shape[1]):
+        for b in range(q.shape[0]):
+            if not valid[b, t]:
+                continue
+            for h in range(q.shape[2]):
+                decayed = np.exp(alpha_log[b, t, h])[:, None] * s[b, h]
+                u = beta[b, t, h] * (v[b, t, h] - decayed.T @ k[b, t, h])
+                s[b, h] = decayed + np.outer(k[b, t, h], u)
+                out[b, t, h] = s[b, h].T @ q[b, t, h]
+    return out, s
+
+
+@pytest.mark.parametrize("carried", [False, True], ids=["zero", "carried"])
+@pytest.mark.parametrize("kind", ["all", "left", "right", "none"])
+@pytest.mark.parametrize("tokens,chunk", [(1, 64), (7, 4), (64, 64),
+                                          (150, 64), (40, 64), (70, 16),
+                                          (200, 128)])
+def test_channel_chunked_form_is_the_token_loop(tokens, chunk, kind, carried):
+    """From a zero and from a carried state, ragged ``valid``, chunk lengths
+    that are no multiple of 64 (nor of the sub-block's 16)."""
+    q, k, v, alpha_log, beta = channel_inputs(tokens, seed=tokens)
+    state = (np.random.default_rng(1).normal(size=(B, H, DK, DV))
+             if carried else np.zeros((B, H, DK, DV)))
+    valid = ragged(tokens, kind)
+    want_o, want_s = channel_loop(q, k, v, alpha_log, beta, state, valid)
+    got_o, got_s = channel_chunk_arrays(
+        *map(jnp.asarray, (q, k, v, alpha_log, beta, state)),
+        jnp.asarray(valid), chunk)
+    live = valid[:, :, None, None]
+    close(np.where(live, got_o, 0.0), np.where(live, want_o, 0.0))
+    close(got_s, want_s)
+    if kind == "none":      # no valid row: the state back bit for bit
+        assert np.array_equal(np.asarray(got_s[0]),
+                              np.asarray(state[0], np.float32))
+
+
+@pytest.mark.parametrize("steep", [30.0, 88.0])
+def test_a_channel_that_decays_by_e30_a_token_is_finite_and_the_loop(steep):
+    """No ``exp`` of a positive number anywhere in the chunk: channels whose
+    log decay reaches -30 (and -88, the edge of float32) a token over a full
+    chunk of 64 beside channels that hardly decay. ``(K * e^g)(K * e^-g)^T``
+    would hold ``e^1900``."""
+    tokens = 128
+    q, k, v, alpha_log, beta = channel_inputs(tokens, seed=4, steep=steep)
+    state = np.random.default_rng(3).normal(size=(B, H, DK, DV))
+    valid = np.ones((B, tokens), bool)
+    want_o, want_s = channel_loop(q, k, v, alpha_log, beta, state, valid)
+    got_o, got_s = channel_chunk_arrays(
+        *map(jnp.asarray, (q, k, v, alpha_log, beta, state)),
+        jnp.asarray(valid), 64)
+    assert np.isfinite(np.asarray(got_o)).all()
+    assert np.isfinite(np.asarray(got_s)).all()
+    close(got_o, want_o)
+    close(got_s, want_s)
+
+
+@pytest.mark.parametrize("tokens,chunk", [(40, 16), (150, 64)])
+def test_a_decay_constant_over_channels_is_the_scalar_call(tokens, chunk):
+    """The per-channel forms handed one number a head repeated over the
+    channels are the scalar forms (other sums, the same function), through
+    the public ops as through the arrays."""
+    q, k, v, alpha_log, beta = map(jnp.asarray, inputs(tokens, seed=7))
+    wide = jnp.broadcast_to(alpha_log[..., None], alpha_log.shape + (DK,))
+    state = jnp.asarray(np.random.default_rng(5).normal(
+        size=(B, H, DK, DV)), jnp.float32)
+    valid = jnp.asarray(ragged(tokens, "right"))
+    want_o, want_s = F.gated_delta_chunk(q, k, v, alpha_log, beta, state,
+                                         valid, chunk_size=chunk)
+    got_o, got_s = F.gated_delta_chunk(q, k, v, wide, beta, state, valid,
+                                       chunk_size=chunk)
+    live = np.asarray(valid)[:, :, None, None]
+    close(np.where(live, got_o.numpy(), 0.0),
+          np.where(live, want_o.numpy(), 0.0))
+    close(got_s.numpy(), want_s.numpy())
+    want_o, want_s = F.gated_delta_step(
+        q[:, 0], k[:, 0], v[:, 0], jnp.exp(alpha_log[:, 0]), beta[:, 0],
+        state)
+    got_o, got_s = F.gated_delta_step(
+        q[:, 0], k[:, 0], v[:, 0], jnp.exp(wide[:, 0]), beta[:, 0], state)
+    close(got_o.numpy(), want_o.numpy())
+    close(got_s.numpy(), want_s.numpy())
+
+
+def test_the_scalar_call_traces_the_program_it_traced():
+    """The scalar chunked form is untouched by the per-channel one: its
+    jaxpr holds no operand with a trailing channel axis on the decay, and
+    its stamp still reads ``delta_rule_chunk[T,C]`` where the per-channel
+    call's reads ``delta_rule_chunk_channel[T,C]``."""
+    from paddle_tpu.observability import trace
+
+    q, k, v, alpha_log, beta = map(jnp.asarray, inputs(40, seed=5))
+    wide = jnp.broadcast_to(alpha_log[..., None], alpha_log.shape + (DK,))
+    zero, valid = jnp.zeros((B, H, DK, DV)), jnp.ones((B, 40), bool)
+
+    def scalar(*a):
+        return dr.chunk_arrays(*a, zero, valid, 16)
+
+    def channel(*a):
+        return dr.channel_chunk_arrays(*a, zero, valid, 16)
+
+    trace.startup_clear()
+    jax.jit(scalar).lower(q, k, v, alpha_log, beta)
+    jax.jit(channel).lower(q, k, v, wide, beta)
+    notes = {e[5]["program"]: e[5] for e in trace.startup_record()["entries"]
+             if e[0] == "compile.trace"}
+    assert notes["scalar"]["delta_rule_chunk[40,16]"]["decay"] == "head"
+    assert "delta_rule_chunk_channel[40,16]" not in notes["scalar"]
+    assert notes["channel"]["delta_rule_chunk_channel[40,16]"] == dict(
+        dr.chunk_plan(40, 16, "channel"), calls=1)
+    trace.startup_clear()
+
+
+@pytest.mark.parametrize("carried", [False, True], ids=["zero", "carried"])
+def test_channel_step_applied_t_times_is_the_token_loop(carried):
+    tokens = 40
+    q, k, v, alpha_log, beta = channel_inputs(tokens, seed=3)
+    state = (np.random.default_rng(2).normal(size=(B, H, DK, DV))
+             if carried else np.zeros((B, H, DK, DV)))
+    valid = ragged(tokens, "right")
+    want_o, want_s = channel_loop(q, k, v, alpha_log, beta, state, valid)
+    s = jnp.asarray(state, jnp.float32)
+    outs = []
+    for t in range(tokens):
+        o, s = channel_step_arrays(
+            *(jnp.asarray(a[:, t]) for a in (q, k, v)),
+            jnp.exp(jnp.asarray(alpha_log[:, t])), jnp.asarray(beta[:, t]),
+            s, None, jnp.asarray(~valid[:, t]))
+        outs.append(o)
+    live = valid[:, :, None, None]
+    close(np.where(live, np.stack(outs, 1), 0.0), np.where(live, want_o, 0.0))
+    close(s, want_s)
+
+
+@pytest.mark.parametrize("heads,lanes", [(8, 3), (32, 2)],
+                         ids=["one-block", "two-blocks"])
+def test_channel_step_kernel_is_step_arrays(monkeypatch, heads, lanes):
+    """``delta_rule_step`` with the decay as a column, interpreted, against
+    ``channel_step_arrays``: a fresh lane starts from zeros whatever its
+    tile held, an idle lane gets its state back bit for bit, the others
+    agree to float32 rounding. 32 heads of 128 x 128 are two grid steps of
+    16 (three columns a head fill a 128-lane tile at 42)."""
+    monkeypatch.setattr(kernel, "INTERPRET", True)
+    d = 128
+    rng = np.random.default_rng(heads)
+    q, k, v = (jnp.asarray(rng.normal(size=(lanes, heads, d)), jnp.float32)
+               for _ in range(3))
+    alpha = jnp.asarray(rng.uniform(0.05, 1.0, (lanes, heads, d)),
+                        jnp.float32)
+    beta = jnp.asarray(rng.uniform(0.0, 2.0, (lanes, heads)), jnp.float32)
+    state = jnp.asarray(rng.normal(size=(lanes, heads, d, d)), jnp.float32)
+    fresh = jnp.zeros((lanes,), bool).at[0].set(True)
+    idle = jnp.zeros((lanes,), bool).at[lanes - 1].set(True)
+    assert kernel.supports(state.shape, d, 1, channel=True)
+    assert kernel.rows_per_block(heads, d, d, 1, columns=3) == min(heads, 16)
+    assert dr.use_step_kernel(state.shape, d, 1, channel=True)
+    want_o, want_s = dr.channel_step_arrays(q, k, v, alpha, beta, state,
+                                            fresh, idle)
+    got_o, got_s = jax.jit(dr.step_any)(q, k, v, alpha, beta, state, fresh,
+                                        idle)
+    close(got_o[:-1], want_o[:-1], 2e-4)        # values of order 10
+    close(got_s, want_s, 2e-4)
+    assert np.array_equal(np.asarray(got_s[-1]), np.asarray(state[-1]))
+    # the fresh lane: what one token writes into zeros
+    close(got_s[0], k[0][..., None] * (beta[0][..., None] * v[0])[:, None, :],
+          2e-4)
+    with pytest.raises(ValueError, match="unpacked"):
+        dr.step_any(q, k, v, alpha, beta, state, packed=2)
+
+
+def test_gated_rms_norm_gates_by_sigmoid_where_asked():
+    rng = np.random.default_rng(0)
+    o = jnp.asarray(rng.normal(size=(2, 5, 3, 16)), jnp.float32)
+    g = jnp.asarray(rng.normal(size=(2, 5, 3, 16)), jnp.float32)
+    w = jnp.asarray(rng.uniform(0.5, 1.5, 16), jnp.float32)
+    normed = o * jax.lax.rsqrt(jnp.mean(o * o, -1, keepdims=True) + 1e-5) * w
+    close(F.gated_rms_norm(o, g, w, epsilon=1e-5,
+                           activation="sigmoid").numpy(),
+          normed * jax.nn.sigmoid(g))
+    close(F.gated_rms_norm(o, g, w, epsilon=1e-5).numpy(),
+          normed * jax.nn.silu(g))

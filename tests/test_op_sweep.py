@@ -672,8 +672,8 @@ SKIP = {
                  "gated_group_rms_norm", "sigmoid_topk_route",
                  "held_experts_relu2")},
     **{n: "compared with the token-by-token loop in "
-          "tests/test_delta_rule.py (carried matrix state: no elementwise "
-          "sweep contract)"
+          "tests/test_delta_rule.py, with a decay a head and a decay a key "
+          "channel (carried matrix state: no elementwise sweep contract)"
        for n in ("gated_delta_chunk", "gated_delta_step", "gated_rms_norm")},
     "held_experts_swiglu":
         "routing table in, no elementwise sweep contract; compared with "

@@ -244,6 +244,112 @@ def test_olmo_hybrid_programs_compile_with_both_kernels(one_chip,
         assert compiled.memory_analysis().temp_size_in_bytes < state, name
 
 
+def test_kimi_delta_step_compiles_in_place(one_chip):
+    """``delta_rule_step`` with the decay as a column, at the Solar-Open2
+    cell's sizes (256 lanes, 64 heads of 128 x 128, no packing): one Mosaic
+    kernel, 16 heads a grid step (three columns a head in one 128-lane
+    tile), the donated 1.07 GB state aliased to the new one, and no buffer
+    of the state's size beside it."""
+    from paddle_tpu.ops.pallas import delta_rule as dk
+    B, H, d = 256, 64, 128
+    f32 = jnp.float32
+
+    def s(shape, dtype=f32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    state = (B, H, d, d)
+    assert dk.supports(state, d, 1, channel=True)
+    assert not dk.supports(state, d, 2, channel=True)
+    assert dk.rows_per_block(H, d, d, 1, columns=3) == 16
+    compiled = jax.jit(
+        lambda q, k, v, a, b, st, fr, idl: dk.delta_rule_step(
+            q, k, v, a, b, st, fr, idl, 1), donate_argnums=(5,)).lower(
+        s((B, H, d)), s((B, H, d)), s((B, H, d)), s((B, H, d)),
+        s((B, H)), s(state), s((B,), bool), s((B,), bool)).compile()
+    entry = compiled.as_text()
+    entry = entry[entry.index("ENTRY"):]
+    assert entry.count('custom_call_target="tpu_custom_call"') == 1
+    assert "%delta_rule_step" in entry
+    mem = compiled.memory_analysis()
+    nbytes = B * H * d * d * 4
+    assert mem.alias_size_in_bytes >= nbytes
+    assert mem.temp_size_in_bytes < nbytes // 8
+
+
+def test_solar_open2_programs_compile_with_both_kernels(one_chip,
+                                                        tpu_backend,
+                                                        monkeypatch):
+    """The decode step, the mixed step and the prefill chunk of a Solar Open
+    2 decoder at the published widths and the cell's engine sizes (256
+    lanes, a 256 x 512 block table of scalar prefetch, context 8192; one GQA
+    and one KDA layer, 8 held experts of the router's 320 and a vocabulary
+    cut to keep the test's arrays small): ``delta_rule_step`` once a KDA
+    layer and ``paged_decode_attn`` once a GQA layer in the programs that
+    hold a decode step, none in the chunk, states and pools donated and no
+    copy of them, no temporary of the size of the state, and no
+    ``triangular_solve`` custom call in a program that runs the chunked
+    rule."""
+    from paddle_tpu.inference import PagedEngine
+    from paddle_tpu.models import SolarOpen2Config, SolarOpen2ForCausalLM
+    from paddle_tpu.nn.functional import delta_rule as fdr
+    from paddle_tpu.nn.functional.paged_attention import log_paths
+    from paddle_tpu.nn.lazy_init import LazyGuard, materialize_layer
+    from paddle_tpu.serving import SchedulerConfig
+
+    monkeypatch.setattr(fdr.jax, "default_backend", lambda: "tpu")
+    layers, B, nb, context = 2, 256, 640, 8192
+    with LazyGuard():
+        model = SolarOpen2ForCausalLM(SolarOpen2Config(
+            num_hidden_layers=layers, gqa_layers=(0,), vocab_size=1024,
+            experts_held=(0, 8), max_seq_len=context))
+    for p in model.parameters():
+        shape = tuple(p.shape)
+        dt = jnp.float32 if len(shape) == 1 else jnp.bfloat16
+        p._lazy_init = (lambda _s, _d, shape=shape, dt=dt: jnp.zeros(
+            shape, dt), shape, dt)
+    materialize_layer(model)
+    model.eval()
+    eng = PagedEngine(model, max_batch=B, block_size=16, num_blocks=nb,
+                      max_blocks_per_seq=context // 16,
+                      scheduler=SchedulerConfig(prefill_token_budget=512))
+    W = eng.prefill_width
+
+    def rows(r, w):
+        return (np.zeros((r, w), np.int32), np.ones((r,), np.int32),
+                np.zeros((r, context // 16), np.int32),
+                np.zeros((r,), np.float32), np.ones((r,), np.float32),
+                np.zeros((r,), np.int32), np.zeros((r,), np.int32))
+
+    def shapes(args):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=one_chip), args)
+
+    decode = eng._chunk_args(*rows(B, 1))
+    mixed = decode + (jnp.zeros((1,), jnp.int32),
+                      eng._row_args(*rows(1, W)))
+    chunk = eng._chunk_args(*rows(1, W)) + (jnp.zeros((1,), jnp.int32),)
+    state = B * 64 * 128 * 128 * 4
+    for name, args, paths, kernels in (
+            ("decode", decode, ["kernel"], layers),
+            ("mixed", mixed, ["composite", "kernel"], layers),
+            ("prefill", chunk, ["composite"], 0)):
+        with log_paths() as lowered:
+            compiled = eng._fns[name].lower(
+                *shapes(args), sampling=False).compile()
+        assert (W, sorted(set(lowered))) == (256, paths)
+        text = compiled.as_text()
+        assert not re.search("triangular_solve|TriangularSolve|"
+                             "InvertDiagBlocks", text), name
+        entry = text[text.index("ENTRY"):]
+        assert entry.count('custom_call_target="tpu_custom_call"') == kernels
+        assert ("%delta_rule_step" in entry) == bool(kernels)
+        assert ("%paged_decode_attn" in entry) == bool(kernels)
+        assert not re.findall(
+            rf"= (?:bf16\[{nb},16|f32\[{B},64),\S+ copy\(", entry)
+        assert compiled.memory_analysis().temp_size_in_bytes < state, name
+
+
 @pytest.mark.parametrize("partitioned_by", ["the-compiler", "shard-map"])
 def test_paged_decode_step_compiles_over_a_mesh(four_chips, tpu_backend,
                                                 partitioned_by):
