@@ -139,7 +139,8 @@ class _KernelChecks:
 
 
 def _attn_ref(q, k, v, scale):
-    """Plain causal attention over (BH, S, d), f32 softmax: (out, lse)."""
+    """Plain causal attention over (BH, S, d), f32 softmax: (out, lse), the
+    logsumexp as the kernels lay it out, (BH, 1, S) rows."""
     import jax
     import jax.numpy as jnp
     s = jnp.einsum("bqd,bkd->bqk", q, k).astype(jnp.float32) * scale
@@ -147,7 +148,7 @@ def _attn_ref(q, k, v, scale):
     s = jnp.where(jnp.tril(jnp.ones((n, n), bool)), s, -1e30)
     p = jax.nn.softmax(s, axis=-1).astype(q.dtype)
     return (jnp.einsum("bqk,bkd->bqd", p, v),
-            jax.nn.logsumexp(s, axis=-1, keepdims=True))
+            jax.nn.logsumexp(s, axis=-1)[:, None])
 
 
 def _check_flash(checks, bh, seq, d):

@@ -18,8 +18,19 @@ paddle/phi/ops/yaml/ops.yaml:1635). Design:
   on the fly. Two kernels: dQ iterates kv innermost accumulating
   dq += ds·K; dK/dV iterates q innermost accumulating dv += pᵀ·dO and
   dk += dsᵀ·Q, where ds = p·(dp − Δ)·scale, dp = dO·Vᵀ and
-  Δ = rowsum(dO∘O) is precomputed by one fused XLA reduction. The full
-  (S, S) probability matrix is never materialized in either pass.
+  Δ = rowsum(dO∘O) is precomputed by one fused XLA reduction. dK/dV
+  computes everything TRANSPOSED, sᵀ = K·Qᵀ (keys x rows), so that pᵀ and
+  dsᵀ are what it holds and both accumulations are plain products (no
+  contraction over the leading dimension of a scores-sized array). The
+  full (S, S) probability matrix is never materialized in either pass.
+* the per-row statistics (logsumexp, Δ) are lane-dense ROWS in HBM,
+  (BH, 1, S_padded) float32 in blocks of (1, 1, block_q), from the forward
+  kernel's store to the backward kernels' loads: 4 bytes a value (a
+  (.., S, 1) column is a whole (8, 128) tile to every 8 values, 128 times
+  that). The forward turns a band's (rows, 1) column into the row in VMEM
+  before the store; dK/dV's transposed scores take a (1, rows) block as
+  it lies, broadcast down the keys; dQ, which keeps (rows x keys), turns
+  its block back into a column in VMEM.
 
 ``block_q`` / ``block_k`` are exposed for tuning (reference
 flash_attn's num_splits analog); ``INTERPRET=True`` runs the same kernels
@@ -88,10 +99,11 @@ INTERPRET = False
 #: the two residuals of a call that the backward kernels read and the forward
 #: kernel alone can make, as the forward rule names them
 #: (``jax.ad_checkpoint.checkpoint_name``): ``out`` as (B, S, H, d), the array
-#: the block goes on with, and the logsumexp as lane-dense (BH, S_padded)
-#: float32. A rematerialised block keeps exactly these beside its input
-#: (``models/_remat.py``), so its backward recomputes everything but the
-#: kernel; outside a ``jax.checkpoint`` a name is the identity.
+#: the block goes on with, and the logsumexp as the forward kernel writes it,
+#: lane-dense (BH, 1, S_padded) float32 rows. A rematerialised block keeps
+#: exactly these beside its input (``models/_remat.py``), so its backward
+#: recomputes everything but the kernel; outside a ``jax.checkpoint`` a name
+#: is the identity.
 KEPT_RESIDUALS = ("flash_out", "flash_lse")
 
 #: scoped VMEM a call of several 2048-wide causal tiles asks for: beside the
@@ -206,7 +218,7 @@ def _tuned_blocks(kind, bh, s_q, s_k, d, dtype, causal, scale):
 
     return tuple(at.autotune(
         key, candidates, run, default, warmup=2, iters=5,
-        describe=lambda c: flash_plan(sq_b, sk_b, causal, *c)))
+        describe=lambda c: flash_plan(sq_b, sk_b, causal, *c, kind)))
 
 
 def _causal_run(q_idx, kv_idx, block_q, block_k, offset):
@@ -215,14 +227,16 @@ def _causal_run(q_idx, kv_idx, block_q, block_k, offset):
 
 
 def _tile_mask(q_idx, kv_idx, block_q, block_k, seq_k, causal, offset,
-               corner=None):
+               corner=None, keys_axis=1):
     """Mask of a tile's scores: the key exists and, causal, the query sees
-    it. ``corner`` (r0, c0, rows, cols): of that part of the tile only."""
+    it. ``corner`` (r0, c0, rows, cols): of that part of the tile only.
+    ``keys_axis`` 0: of the transposed scores (keys x rows)."""
     r0, c0, rows, cols = corner or (0, 0, block_q, block_k)
+    shape = (rows, cols) if keys_axis else (cols, rows)
     q_pos = _at(q_idx * block_q, r0) + jax.lax.broadcasted_iota(
-        jnp.int32, (rows, cols), 0)
+        jnp.int32, shape, 1 - keys_axis)
     k_pos = _at(kv_idx * block_k, c0) + jax.lax.broadcasted_iota(
-        jnp.int32, (rows, cols), 1)
+        jnp.int32, shape, keys_axis)
     mask = k_pos < seq_k
     if causal:
         mask = mask & (q_pos + offset >= k_pos)
@@ -317,13 +331,32 @@ def _grid_classes(seq_q, seq_k, causal, block_q, block_k, band):
     return tuple((blocks, keys, n) for blocks, (keys, n) in by_blocks.items())
 
 
-def flash_plan(seq_q, seq_k, causal, block_q, block_k):
+def _stats_shape(bh, sp_q):
+    """The per-row float32 statistics of ``bh`` heads (logsumexp, delta) as
+    they lie in HBM between the kernels: one lane-dense row a head."""
+    return (bh, 1, sp_q)
+
+
+def _hbm_bytes(shape):
+    """Bytes of a float32 array in HBM: its last dimension is whole 128-lane
+    tiles, and the one before it whole sublanes (8) unless it is 1 (a
+    (.., S, 1) column is a tile of 8 x 128 to every 8 values, a (.., 1, S)
+    row 128 values a tile)."""
+    *lead, sub, lane = shape
+    return (math.prod(lead) * (sub if sub == 1 else _ceil_to(sub, 8))
+            * _ceil_to(lane, _LANES) * 4)
+
+
+def flash_plan(seq_q, seq_k, causal, block_q, block_k, kind="fwd"):
     """What the three kernels execute for a call, fixed when it is traced:
     ``tiles`` (the grid a head, queries x keys), ``sub_block`` (the rows
-    of a band of a causal tile; None where a tile runs whole) and
+    of a band of a causal tile; None where a tile runs whole),
     ``executed_share``, the area of scores computed over the padded
     seq_q x seq_k square (1.0 non-causal; 0.625 for one causal tile of
-    1024 in bands of 256)."""
+    1024 in bands of 256), and ``stats_bytes``, what a head's per-row
+    statistics occupy in HBM as the kernels lay them out: the logsumexp
+    the forward (``kind`` "fwd") writes, the logsumexp and delta the
+    backward ("bwd") reads."""
     bq, bk, sp_q, sp_k = _geometry(seq_q, seq_k, block_q, block_k,
                                    SUB_BLOCK if causal else None)
     area = sum(n * sum((r1 - r0) * c1 for r0, r1, c1, _mc0, _g in blocks)
@@ -331,13 +364,15 @@ def flash_plan(seq_q, seq_k, causal, block_q, block_k):
                    seq_q, seq_k, causal, block_q, block_k, SUB_BLOCK))
     return {"tiles": [sp_q // bq, sp_k // bk],
             "sub_block": min(SUB_BLOCK, bq) if causal else None,
-            "executed_share": area / (sp_q * sp_k)}
+            "executed_share": area / (sp_q * sp_k),
+            "stats_bytes": ((2 if kind == "bwd" else 1)
+                            * _hbm_bytes(_stats_shape(1, sp_q)))}
 
 
 def _plan_entry(kind, seq_q, seq_k, causal, block_q, block_k):
     return (f"flash_{kind}[{seq_q}x{seq_k},{'causal' if causal else 'full'},"
             f"{block_q}x{block_k}]",
-            flash_plan(seq_q, seq_k, causal, block_q, block_k))
+            flash_plan(seq_q, seq_k, causal, block_q, block_k, kind))
 
 
 def _stamp_plan(*call):
@@ -380,25 +415,29 @@ def _for_tile(classes, q_idx, kv_idx, num_kv, block_q, block_k, offset, body):
                 body(block)
 
 
-def _masked_keys(x, block, fn):
-    """``fn`` on the columns of the block's (rows, keys) array ``x`` that
-    can hold masked scores, the rest as they are."""
+def _masked_keys(x, block, fn, keys_axis=1):
+    """``fn`` on the keys of the block's scores ``x`` ((rows, keys), or
+    (keys, rows) at ``keys_axis`` 0) that can hold masked scores, the rest
+    as they are."""
     _r0, _r1, c1, mc0, _guard = block
     if mc0 == 0:
         return fn(x)
     if mc0 == c1:
         return x
-    return jnp.concatenate([x[:, :mc0], fn(x[:, mc0:])], axis=1)
+    seen, maskable = (x[:, :mc0], x[:, mc0:]) if keys_axis else (x[:mc0],
+                                                                 x[mc0:])
+    return jnp.concatenate([seen, fn(maskable)], axis=keys_axis)
 
 
 def _block_mask(block, q_idx, kv_idx, block_q, block_k, seq_k, causal,
-                offset):
+                offset, keys_axis=1):
     """The mask of the block's maskable keys, or None where it has none."""
     r0, r1, c1, mc0, _guard = block
     if mc0 == c1:
         return None
     return _tile_mask(q_idx, kv_idx, block_q, block_k, seq_k, causal, offset,
-                      corner=(r0, mc0, r1 - r0, c1 - mc0))
+                      corner=(r0, mc0, r1 - r0, c1 - mc0),
+                      keys_axis=keys_axis)
 
 
 def _scores(q, k, scale):
@@ -407,15 +446,16 @@ def _scores(q, k, scale):
         preferred_element_type=jnp.float32) * scale
 
 
-def _probs(s, lse, block, mask):
+def _probs(s, lse, block, mask, keys_axis=1):
     """p = exp(s - lse), zero where masked. The mask guards (not just exp
     underflow): for fully-masked rows lse is garbage (~NEG_INF) and
     exp(NEG_INF - lse) would be 1, not 0."""
     if mask is None:
         return jnp.exp(s - lse)
-    s = _masked_keys(s, block, lambda x: jnp.where(mask, x, NEG_INF))
+    s = _masked_keys(s, block, lambda x: jnp.where(mask, x, NEG_INF),
+                     keys_axis)
     return _masked_keys(jnp.exp(s - lse), block,
-                        lambda x: jnp.where(mask, x, 0.0))
+                        lambda x: jnp.where(mask, x, 0.0), keys_axis)
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
@@ -465,7 +505,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
                 p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)
             o_ref[0, rows] = (acc / l).astype(o_ref.dtype)
-            lse_ref[0, rows] = m_new + jnp.log(l)
+            lse_ref[0, :, rows] = (m_new + jnp.log(l)).T
             return
         m_prev = m_scr[rows]                   # (rows, 1)
         m_cur = jnp.max(s, axis=1, keepdims=True)
@@ -493,7 +533,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
         def _finish():
             l = jnp.maximum(l_scr[:], 1e-30)
             o_ref[0] = (acc_scr[:] / l).astype(o_ref.dtype)
-            lse_ref[0] = m_scr[:] + jnp.log(l)
+            lse_ref[0] = (m_scr[:] + jnp.log(l)).T
 
 
 def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
@@ -518,8 +558,8 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
         k = k_ref[0, cols]
         v = v_ref[0, cols]
         do = do_ref[0, rows]
-        lse = lse_ref[0, rows]                 # (rows, 1)
-        delta = delta_ref[0, rows]
+        lse = lse_ref[0, :, rows].reshape(-1, 1)    # (1, rows) -> (rows, 1)
+        delta = delta_ref[0, :, rows].reshape(-1, 1)
         s = _scores(q, k, scale)
         mask = _block_mask(block, q_idx, kv_idx, block_q, block_k, seq_k,
                            causal, causal_offset)
@@ -566,23 +606,25 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref,
         k = k_ref[0, cols]
         v = v_ref[0, cols]
         do = do_ref[0, rows]
-        lse = lse_ref[0, rows]
-        delta = delta_ref[0, rows]
-        s = _scores(q, k, scale)
+        lse = lse_ref[0, :, rows]              # (1, rows): down the keys
+        delta = delta_ref[0, :, rows]
+        # everything (keys, rows): the statistics broadcast as they lie and
+        # no product contracts over the leading dimension of the scores
+        s_t = _scores(k, q, scale)
         mask = _block_mask(block, q_idx, kv_idx, block_q, block_k, seq_k,
-                           causal, causal_offset)
-        p = _probs(s, lse, block, mask)
+                           causal, causal_offset, keys_axis=0)
+        p_t = _probs(s_t, lse, block, mask, keys_axis=0)
         # dv += P^T dO
         dv_scr[cols] += jax.lax.dot_general(
-            p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
+            p_t.astype(do.dtype), do, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
-        dp = jax.lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())),
+        dp_t = jax.lax.dot_general(
+            v, do, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)
-        ds = p * (dp - delta) * scale
+        ds_t = p_t * (dp_t - delta) * scale
         # dk += dS^T Q
         dk_scr[cols] += jax.lax.dot_general(
-            ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
+            ds_t.astype(q.dtype), q, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
     @pl.when(run)
@@ -614,7 +656,7 @@ def _compiler_params(causal, sp_q, sp_k, block_q, block_k):
 
 
 def _flash_fwd_bhsd(q, k, v, *, causal, scale, block_q, block_k):
-    """q/k/v: (BH, S, d) -> (out (BH, S, d), lse fp32 (BH, Sq_padded))."""
+    """q/k/v: (BH, S, d) -> (out (BH, S, d), lse fp32 (BH, 1, Sq_padded))."""
     return _fwd_call(q, k, v, causal=causal, scale=float(scale),
                      block_q=block_q, block_k=block_k, band=SUB_BLOCK,
                      interpret=INTERPRET)
@@ -652,8 +694,9 @@ def _fwd_call(q, k, v, *, causal, scale, block_q, block_k, band, interpret):
         one_pass=causal and sp_k == block_k)
     out, lse = pl.pallas_call(
         kernel,
-        out_shape=[jax.ShapeDtypeStruct((bh, sp_q, dp), q.dtype),
-                   jax.ShapeDtypeStruct((bh, sp_q, 1), jnp.float32)],
+        out_shape=[
+            jax.ShapeDtypeStruct((bh, sp_q, dp), q.dtype),
+            jax.ShapeDtypeStruct(_stats_shape(bh, sp_q), jnp.float32)],
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, block_q, dp), lambda b, i, j: (b, i, 0)),
@@ -662,7 +705,7 @@ def _fwd_call(q, k, v, *, causal, scale, block_q, block_k, band, interpret):
         ],
         out_specs=[
             pl.BlockSpec((1, block_q, dp), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0)),
+            pl.BlockSpec((1, 1, block_q), lambda b, i, j: (b, 0, i)),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_q, 1), jnp.float32),
@@ -679,8 +722,8 @@ def _fwd_call(q, k, v, *, causal, scale, block_q, block_k, band, interpret):
 
 def _flash_bwd_bhsd(q, k, v, out, lse, do, *, causal, scale, block_q,
                     block_k):
-    """FA2 backward. All of q/k/v/out/do: (BH, S, d); lse: (BH, Sq_pad_fwd).
-    Returns (dq, dk, dv) unpadded."""
+    """FA2 backward. All of q/k/v/out/do: (BH, S, d); lse: (BH, 1,
+    Sq_pad_fwd), as the forward gives it. Returns (dq, dk, dv) unpadded."""
     return _bwd_call(q, k, v, out, lse, do, causal=causal,
                      scale=float(scale), block_q=block_q, block_k=block_k,
                      band=SUB_BLOCK, interpret=INTERPRET)
@@ -697,25 +740,25 @@ def _bwd_call(q, k, v, out, lse, do, *, causal, scale, block_q, block_k,
                                              band if causal else None)
     pad_d = (-d) % 128
 
-    # Δ = rowsum(dO ∘ O): one fused XLA reduction, fp32.
+    # Δ = rowsum(dO ∘ O): one fused XLA reduction, fp32, a row like lse
     delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32),
-                    axis=-1, keepdims=True)                # (BH, s_q, 1)
+                    axis=-1).reshape(_stats_shape(bh, s_q))
 
     q = _pad_bhsd(q, block_q, pad_d)
     do = _pad_bhsd(do, block_q, pad_d)
     k = _pad_bhsd(k, block_k, pad_d)
     v = _pad_bhsd(v, block_k, pad_d)
     dp = d + pad_d
-    if lse.shape[1] < sp_q:     # fwd may have tiled with a different block
-        lse = jnp.pad(lse, ((0, 0), (0, sp_q - lse.shape[1]), (0, 0)))
-    elif lse.shape[1] > sp_q:
-        lse = lse[:, :sp_q]
-    delta = jnp.pad(delta, ((0, 0), (0, sp_q - s_q), (0, 0)))
+    if lse.shape[2] < sp_q:     # fwd may have tiled with a different block
+        lse = jnp.pad(lse, ((0, 0), (0, 0), (0, sp_q - lse.shape[2])))
+    elif lse.shape[2] > sp_q:
+        lse = lse[..., :sp_q]
+    delta = jnp.pad(delta, ((0, 0), (0, 0), (0, sp_q - s_q)))
 
     kw = dict(scale=scale, causal=causal, block_q=block_q, block_k=block_k,
               seq_q=s_q, seq_k=s_k, classes=classes)
     q_spec = pl.BlockSpec((1, block_q, dp), lambda b, i, j: (b, i, 0))
-    row_spec = pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0))
+    row_spec = pl.BlockSpec((1, 1, block_q), lambda b, i, j: (b, 0, i))
 
     dq = pl.pallas_call(
         functools.partial(_dq_kernel, **kw),
@@ -737,7 +780,7 @@ def _bwd_call(q, k, v, out, lse, do, *, causal, scale, block_q, block_k,
 
     # dk/dv: kv outer, q inner
     qi_spec = pl.BlockSpec((1, block_q, dp), lambda b, i, j: (b, j, 0))
-    rowi_spec = pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, j, 0))
+    rowi_spec = pl.BlockSpec((1, 1, block_q), lambda b, i, j: (b, 0, j))
     kv_spec = pl.BlockSpec((1, block_k, dp), lambda b, i, j: (b, i, 0))
     dk, dv = pl.pallas_call(
         functools.partial(_dkv_kernel, **kw),
@@ -814,7 +857,7 @@ def _keep(x, name):
 
 
 def _flash_fwd(q, k, v, causal, scale, block_q, block_k):
-    """(out (B, S, H, d), lse (BH, S_padded, 1)) as the kernel gives them."""
+    """(out (B, S, H, d), lse (BH, 1, S_padded)) as the kernel gives them."""
     b, s, h, d = q.shape
     block_q, block_k = _resolve_blocks("fwd", block_q, block_k, q, k,
                                        causal, scale)
@@ -828,9 +871,9 @@ def _flash_fwd(q, k, v, causal, scale, block_q, block_k):
 def _flash_fwd_rule(q, k, v, causal, scale, block_q, block_k):
     out_bshd, lse = _flash_fwd(q, k, v, causal, scale, block_q, block_k)
     out_bshd = _keep(out_bshd, KEPT_RESIDUALS[0])
-    # the kernel's (BH, S, 1) column is laid out a whole (8, 128) tile of
-    # float32 to every 8 rows, 128 times its bytes: what is kept is the row
-    lse = _keep(lse[..., 0], KEPT_RESIDUALS[1])[..., None]
+    # the kernel's rows as they come: kept, and read by the backward
+    # kernels, with no pass of XLA's over them
+    lse = _keep(lse, KEPT_RESIDUALS[1])
     return out_bshd, (q, k, v, out_bshd, lse)
 
 

@@ -403,7 +403,8 @@ def test_flash_plan_counts_what_the_kernels_run(monkeypatch):
         assert plan["tiles"] == [1024 // min(bq, 1024), 1024 // min(bk, 1024)]
         assert plan["sub_block"] == 256
         assert fa.flash_plan(1024, 1024, False, bq, bk) == {
-            "tiles": plan["tiles"], "sub_block": None, "executed_share": 1.0}
+            "tiles": plan["tiles"], "sub_block": None, "executed_share": 1.0,
+            "stats_bytes": 4096}
     for band in (128, 256):
         monkeypatch.setattr(fa, "SUB_BLOCK", band)
         for sq, sk, bq, bk in [(1024, 1024, 1024, 1024), (1000, 1000, 512, 512),
@@ -417,7 +418,31 @@ def test_flash_plan_counts_what_the_kernels_run(monkeypatch):
                 _brute_force_share(sq, sk, bq, bk, band)), (band, sq, sk, bq, bk)
     monkeypatch.setattr(fa, "SUB_BLOCK", 128)
     assert fa.flash_plan(1024, 1024, True, 1024, 1024) == {
-        "tiles": [1, 1], "sub_block": 128, "executed_share": 0.5625}
+        "tiles": [1, 1], "sub_block": 128, "executed_share": 0.5625,
+        "stats_bytes": 4096}
+
+
+@pytest.mark.parametrize("call", [
+    (1024, 1024, True, 2048, 2048), (8192, 8192, True, 2048, 2048),
+    (700, 700, False, 1024, 1024), (1536, 1536, False, 1024, 1024),
+    (640, 1000, True, 256, 512)], ids=lambda c: "x".join(map(str, c[:2])))
+def test_flash_plan_says_what_the_row_statistics_occupy(call):
+    """``stats_bytes``: a head's logsumexp (forward) or logsumexp + delta
+    (backward) in HBM as the kernels lay them out, a float32 a padded row in
+    whole 128-lane tiles; the kernel's own result has that shape. A
+    (.., S, 1) column would read 128 times as much."""
+    sq, sk, causal, bq, bk = call
+    sp_q = fa._geometry(sq, sk, bq, bk, fa.SUB_BLOCK if causal else None)[2]
+    lanes = -(-sp_q // 128) * 128
+    assert fa.flash_plan(*call)["stats_bytes"] == 4 * lanes
+    assert fa.flash_plan(*call, kind="bwd")["stats_bytes"] == 8 * lanes
+    assert fa._hbm_bytes((1, sp_q, 1)) == 128 * 4 * -(-sp_q // 8) * 8
+    x = jax.ShapeDtypeStruct((3, sq, 32), jnp.bfloat16)
+    k = jax.ShapeDtypeStruct((3, sk, 32), jnp.bfloat16)
+    _out, lse = jax.eval_shape(lambda q, k: fa._flash_fwd_bhsd(
+        q, k, k, causal=causal, scale=1.0, block_q=bq, block_k=bk), x, k)
+    assert (lse.shape, lse.dtype) == ((3, 1, sp_q), jnp.float32)
+    assert fa._hbm_bytes(lse.shape) == 3 * 4 * lanes
 
 
 def test_no_causal_tile_longer_than_a_band_runs_whole():
@@ -445,7 +470,7 @@ def test_a_non_causal_call_runs_one_block_a_tile_whatever_the_band(monkeypatch):
     """The bands are the causal mask's: a non-causal kernel is traced to
     the same program at any SUB_BLOCK, two matmuls a forward tile."""
     x = jax.ShapeDtypeStruct((2, 1024, 64), jnp.bfloat16)
-    lse = jax.ShapeDtypeStruct((2, 1024, 1), jnp.float32)
+    lse = jax.ShapeDtypeStruct((2, 1, 1024), jnp.float32)
 
     def programs():
         kw = dict(causal=False, scale=0.125, block_q=512, block_k=1024)

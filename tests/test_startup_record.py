@@ -176,7 +176,7 @@ def test_a_kernels_wrapper_stamps_its_plan_on_the_trace_entry(record):
     assert traced[5]["flash_fwd[512x512,causal,512x512]"] == dict(
         plan, calls=2)
     assert traced[5]["flash_bwd[512x512,causal,512x512]"] == dict(
-        plan, calls=2)
+        fa.flash_plan(512, 512, True, 512, 512, kind="bwd"), calls=2)
     assert not [e for e in entries if "nobody" in (e[5] or {})]
 
 

@@ -543,23 +543,83 @@ def test_lfm2_train_step_compiles_with_the_grouped_matmuls(one_chip,
                                    "flash_dkv": 1}
 
 
-def test_gpt2_train_step_runs_each_forward_kernel_once(one_chip,
-                                                       monkeypatch):
+GPT2_STEP = dict(layers=2, heads=16, batch=8, seq=1024)
+
+
+@pytest.fixture(scope="module")
+def gpt2_step(four_chips):
     """Two GPT-2 medium blocks at the fit cell's shape (8 x 1024 tokens, 16
-    heads of 64, blocks rematerialised): the policy's names reach through
-    the jitted kernel wrapper in the TPU's lowering, so the step holds one
-    forward kernel a layer, and the logsumexp crosses to the backward as
-    (BH, S) rows."""
+    heads of 64, blocks rematerialised), the train step's ENTRY: compiled
+    once for the tests that read it."""
     from paddle_tpu.models import GPTConfig, GPTForCausalLM
 
-    layers, heads, batch, seq = 2, 16, 8, 1024
-    entry = _compiled_train_step(GPTForCausalLM(GPTConfig(
-        vocab_size=512, hidden_size=1024, num_layers=layers, num_heads=heads,
-        max_seq_len=seq, recompute=True)), batch, seq, one_chip, monkeypatch)
-    assert _flash_calls(entry) == dict.fromkeys(
-        ("flash_fwd", "flash_dq", "flash_dkv"), layers)
-    rows = re.findall(rf"= f32\[{batch * heads},{seq}\]\S* ", entry)
-    assert len(rows) >= layers, "the logsumexp is not kept as rows"
+    g = GPT2_STEP
+    with pytest.MonkeyPatch.context() as patch:
+        return _compiled_train_step(GPTForCausalLM(GPTConfig(
+            vocab_size=512, hidden_size=1024, num_layers=g["layers"],
+            num_heads=g["heads"], max_seq_len=g["seq"], recompute=True)),
+            g["batch"], g["seq"], SingleDeviceSharding(four_chips[0]), patch)
+
+
+def test_gpt2_train_step_runs_each_forward_kernel_once(gpt2_step):
+    """The policy's names reach through the jitted kernel wrapper in the
+    TPU's lowering, so the step holds one forward kernel a layer, and the
+    logsumexp crosses to the backward as (BH, 1, S) rows, a lane tile of
+    128 values (``T(1,128)``)."""
+    g = GPT2_STEP
+    assert _flash_calls(gpt2_step) == dict.fromkeys(
+        ("flash_fwd", "flash_dq", "flash_dkv"), g["layers"])
+    rows = re.findall(
+        rf"= f32\[{g['batch'] * g['heads']},1,{g['seq']}\]{{2,1,0:T\(1,128\)}} ",
+        gpt2_step)
+    assert len(rows) >= g["layers"], "the logsumexp is not kept as rows"
+
+
+def _instructions(entry):
+    """``{name: (result shape, opcode, [operand names])}`` of a
+    computation's text."""
+    found = {}
+    for line in entry.splitlines():
+        named = re.match(r"\s*(?:ROOT )?%([\w.-]+) =( .*)", line)
+        opcode = named and re.search(r" ([a-z][\w-]*)\(", named.group(2))
+        if opcode:
+            rest = named.group(2)
+            found[named.group(1)] = (
+                rest[:opcode.start()], opcode.group(1),
+                re.findall(r"%([\w.-]+)", rest[opcode.end():]))
+    return found
+
+
+def test_gpt2_train_step_holds_the_row_statistics_as_rows(gpt2_step):
+    """No float32 column (.., S, 1) exists anywhere in the step (a tile of
+    8 x 128 to every 8 values: 64 MB a layer where the values are 512 KB),
+    and the kept logsumexp goes from ``flash_fwd`` to ``flash_dq`` and
+    ``flash_dkv`` as it is: nothing computes on it on the way (the parent's
+    ``reduce`` to the row and ``copy`` back into the column, 5.4 ms of a
+    GPT-2 medium step). The compiler's own prefetch of the row into the
+    other memory space (``copy-start`` / ``copy-done``, asynchronous, the
+    same shape and tiling) is not a layout copy and may stand."""
+    g = GPT2_STEP
+    assert not re.findall(rf"f32\[(?:\d+,)*{g['seq']},1\]", gpt2_step)
+    found = _instructions(gpt2_step)
+    users = {}
+    for name, (_shape, _opcode, operands) in found.items():
+        for operand in operands:
+            users.setdefault(operand, []).append(name)
+    passes_on = ("get-tuple-element", "bitcast", "copy-start", "copy-done")
+    for fwd in (n for n in found if re.fullmatch(r"flash_fwd(\.\d+)?", n)):
+        reached, todo = set(), [
+            u for u in users[fwd] if found[u][1] == "get-tuple-element"
+            and f"f32[{g['batch'] * g['heads']},1,{g['seq']}]" in found[u][0]]
+        assert len(todo) == 1, (fwd, todo)
+        while todo:
+            for user in users.get(todo.pop(), []):
+                if found[user][1] in passes_on:
+                    todo.append(user)
+                else:
+                    reached.add(user)
+        assert {re.sub(r"\.\d+$", "", r) for r in reached} == {
+            "flash_dq", "flash_dkv"}, (fwd, reached)
 
 
 @pytest.mark.parametrize("chips", [1, 4], ids=["one-chip", "dp4"])
