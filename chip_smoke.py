@@ -40,7 +40,7 @@ import numpy as np
 
 # ---------------------------------------------------------------- sizes
 #: flash-attention shape classes the training cells use:
-#: (heads*batch, seq, head_dim) — d 64 pads to 128 lanes (GPT-2 345M,
+#: (heads, seq, head_dim) — d 64, two heads a 128-lane block (GPT-2 345M,
 #: S 1024) and d 128 at S 2048 (the llama cells)
 FLASH_SHAPES = ((4, 1024, 64), (4, 2048, 128))
 #: fused_ops kernels: (rows, hidden, ffn, head_dim, seq)
@@ -139,19 +139,24 @@ class _KernelChecks:
 
 
 def _attn_ref(q, k, v, scale):
-    """Plain causal attention over (BH, S, d), f32 softmax: (out, lse), the
-    logsumexp as the kernels lay it out, (BH, 1, S) rows."""
+    """Plain causal attention over (1, S, H, d), f32 softmax: (out, lse),
+    the logsumexp as the kernels lay it out, a row a head, the heads of a
+    lane block together: (1, H // hpb, hpb, S)."""
     import jax
     import jax.numpy as jnp
-    s = jnp.einsum("bqd,bkd->bqk", q, k).astype(jnp.float32) * scale
+
+    from paddle_tpu.ops.pallas import flash_attention as fa
+    s = jnp.einsum("bqhd,bkhd->bhqk", q, k).astype(jnp.float32) * scale
     n = s.shape[-1]
     s = jnp.where(jnp.tril(jnp.ones((n, n), bool)), s, -1e30)
     p = jax.nn.softmax(s, axis=-1).astype(q.dtype)
-    return (jnp.einsum("bqk,bkd->bqd", p, v),
-            jax.nn.logsumexp(s, axis=-1)[:, None])
+    heads = q.shape[2]
+    hpb = fa._head_layout(heads, q.shape[3])[0]
+    return (jnp.einsum("bhqk,bkhd->bqhd", p, v),
+            jax.nn.logsumexp(s, axis=-1).reshape(1, heads // hpb, hpb, n))
 
 
-def _check_flash(checks, bh, seq, d):
+def _check_flash(checks, heads, seq, d):
     import math
 
     import jax
@@ -163,8 +168,8 @@ def _check_flash(checks, bh, seq, d):
 
     @jax.jit
     def inputs_and_references(key):
-        q, k, v, g = (jax.random.normal(kx, (bh, seq, d)).astype(jnp.bfloat16)
-                      for kx in jax.random.split(key, 4))
+        q, k, v, g = (jax.random.normal(kx, (1, seq, heads, d)).astype(
+            jnp.bfloat16) for kx in jax.random.split(key, 4))
         (out, lse), vjp = jax.vjp(lambda *a: _attn_ref(*a, scale), q, k, v)
         return (q, k, v, g), out, lse, vjp((g, jnp.zeros_like(lse)))
 
@@ -178,14 +183,14 @@ def _check_flash(checks, bh, seq, d):
     for bq, bk in causal_tile + fa.FWD_TILE_CANDIDATES:
         checks.check(
             f"flash_fwd[S={seq},d={d}]({bq},{bk})",
-            lambda *a, bq=bq, bk=bk: fa._flash_fwd_bhsd(
+            lambda *a, bq=bq, bk=bk: fa._flash_fwd_bshd(
                 *a, causal=True, scale=scale, block_q=bq, block_k=bk),
             (q, k, v), (ref_out, lse))
     for bq, bk in causal_tile + fa.BWD_TILE_CANDIDATES:
         # one jit holds both backward pallas_calls: dQ, then dK/dV
         checks.check(
             f"flash_dq_dkdv[S={seq},d={d}]({bq},{bk})",
-            lambda *a, bq=bq, bk=bk: fa._flash_bwd_bhsd(
+            lambda *a, bq=bq, bk=bk: fa._flash_bwd_bshd(
                 *a, causal=True, scale=scale, block_q=bq, block_k=bk),
             (q, k, v, ref_out, lse, g), ref_grads)
 
@@ -269,8 +274,8 @@ def phase_kernels(flash_shapes=FLASH_SHAPES, fused_shape=FUSED_SHAPE,
     import jax
 
     checks = _KernelChecks(jax.default_backend() == "tpu", tol)
-    for bh, seq, d in flash_shapes:
-        _check_flash(checks, bh, seq, d)
+    for heads, seq, d in flash_shapes:
+        _check_flash(checks, heads, seq, d)
     _check_fused(checks, **fused_shape)
     if checks.failed:
         raise RuntimeError(

@@ -4,33 +4,44 @@ Replaces the reference's FlashAttention-2 CUDA library integration
 (reference: third_party/flashattn; op `flash_attn` at
 paddle/phi/ops/yaml/ops.yaml:1635). Design:
 
-* forward — online-softmax over KV tiles: grid (batch*heads, q_tiles,
-  kv_tiles) with the kv axis innermost so the fp32 accumulators in VMEM
-  scratch persist across kv steps; the MXU consumes (Bq, d) x (d, Bk)
-  tiles; causal tiles above the diagonal are skipped with @pl.when, and
-  INSIDE a tile the diagonal crosses only the part at or below it runs
-  (``_tile_blocks``: bands of ``SUB_BLOCK`` query rows, each against the
-  keys it can see), so no FLOPs are spent on masked blocks whatever the
-  tile. Also emits the per-row logsumexp
+* layout — the kernels take their blocks from the (B, S, H*d) array as the
+  model holds it (the fused projection's result, split and reshaped for
+  free): grid (batch, head groups, q_tiles, kv_tiles), a block is
+  (1, tile, 128) at lane-block index = the head group, and carries
+  ``heads_per_block`` = 128 // d heads side by side (two at d 64); a head
+  of d = n * 128 lanes is a block of its own; any other head (an odd head
+  count, d 16 with 3 heads) is padded to a block of whole lane tiles. No
+  transpose into (B*H, S, d), and for heads that pack no pad of d and no
+  slice of the results ("Heads of a block" below).
+* forward — online-softmax over KV tiles, the kv axis innermost so the fp32
+  accumulators in VMEM scratch persist across kv steps; the MXU consumes
+  (Bq, 128) x (128, Bk) tiles; causal tiles above the diagonal are skipped
+  with @pl.when, and INSIDE a tile the diagonal crosses only the part at or
+  below it runs (``_tile_blocks``: bands of ``SUB_BLOCK`` query rows, each
+  against the keys it can see), so no FLOPs are spent on masked blocks
+  whatever the tile. The band body runs once a head of the block, each head
+  with its own max, sum and accumulator. Also emits the per-row logsumexp
   (the FA2 "L" residual) for backward.
 * backward — the FA2 recompute strategy, O(S·d) memory: residuals are only
   (q, k, v, out, lse); each backward tile recomputes p = exp(qk·scale−lse)
   on the fly. Two kernels: dQ iterates kv innermost accumulating
   dq += ds·K; dK/dV iterates q innermost accumulating dv += pᵀ·dO and
   dk += dsᵀ·Q, where ds = p·(dp − Δ)·scale, dp = dO·Vᵀ and
-  Δ = rowsum(dO∘O) is precomputed by one fused XLA reduction. dK/dV
-  computes everything TRANSPOSED, sᵀ = K·Qᵀ (keys x rows), so that pᵀ and
-  dsᵀ are what it holds and both accumulations are plain products (no
-  contraction over the leading dimension of a scores-sized array). The
-  full (S, S) probability matrix is never materialized in either pass.
-* the per-row statistics (logsumexp, Δ) are lane-dense ROWS in HBM,
-  (BH, 1, S_padded) float32 in blocks of (1, 1, block_q), from the forward
-  kernel's store to the backward kernels' loads: 4 bytes a value (a
-  (.., S, 1) column is a whole (8, 128) tile to every 8 values, 128 times
-  that). The forward turns a band's (rows, 1) column into the row in VMEM
-  before the store; dK/dV's transposed scores take a (1, rows) block as
-  it lies, broadcast down the keys; dQ, which keeps (rows x keys), turns
-  its block back into a column in VMEM.
+  Δ = rowsum(dO∘O), which each kernel makes for its band from the blocks of
+  dO and O as they lie (no pass of XLA's over the two arrays, no Δ in
+  HBM). dK/dV computes everything TRANSPOSED, sᵀ = K·Qᵀ (keys x rows), so
+  that pᵀ and dsᵀ are what it holds and both accumulations are plain
+  products (no contraction over the leading dimension of a scores-sized
+  array). The full (S, S) probability matrix is never materialized in
+  either pass.
+* the logsumexp is lane-dense ROWS in HBM, (B, H // hpb, hpb, S_padded)
+  float32 in blocks of (1, 1, hpb, block_q), the rows of a block's heads
+  together, from the forward kernel's store to the backward kernels' loads:
+  4 bytes a value (a (.., S, 1) column is a whole (8, 128) tile to every 8
+  values, 128 times that). The forward turns a band's (rows, 1) column
+  into the row in VMEM before the store; dK/dV's transposed scores take a
+  (1, rows) block as it lies, broadcast down the keys; dQ, which keeps
+  (rows x keys), turns its block back into a column in VMEM.
 
 ``block_q`` / ``block_k`` are exposed for tuning (reference
 flash_attn's num_splits analog); ``INTERPRET=True`` runs the same kernels
@@ -99,24 +110,31 @@ INTERPRET = False
 #: the two residuals of a call that the backward kernels read and the forward
 #: kernel alone can make, as the forward rule names them
 #: (``jax.ad_checkpoint.checkpoint_name``): ``out`` as (B, S, H, d), the array
-#: the block goes on with, and the logsumexp as the forward kernel writes it,
-#: lane-dense (BH, 1, S_padded) float32 rows. A rematerialised block keeps
+#: the block goes on with and the backward kernels read in place, and the
+#: logsumexp as the forward kernel writes it, lane-dense (B, H // hpb, hpb,
+#: S_padded) float32 rows. A rematerialised block keeps
 #: exactly these beside its input (``models/_remat.py``), so its backward
 #: recomputes everything but the kernel; outside a ``jax.checkpoint`` a name
 #: is the identity.
 KEPT_RESIDUALS = ("flash_out", "flash_lse")
 
-#: scoped VMEM a call of several 2048-wide causal tiles asks for: beside the
-#: band's scores such a tile keeps running state (max, sum, accumulator,
-#: each row a whole (8, 128) tile of f32) and double-buffered operands,
-#: 19.2 MB at d 64 against the compiler's default limit of 16 MB, which
-#: refused every causal call longer than one tile (S 4096, S 8192). A v5e
-#: core has 128 MiB. One-tile calls (S <= 2048) ask for nothing and compile
-#: the kernels they compiled before.
+#: scoped VMEM a causal call of tiles wider than 1024 asks for: beside a
+#: band's scores (256 x 2048 float32, 2 MB an array, and a block of two
+#: heads keeps two heads' at once: one padded tile of 2048 at d 64 is
+#: 19.7 MB) a row of several tiles keeps running state (max, sum,
+#: accumulator, each row a whole (8, 128) tile of f32) and double-buffered
+#: operands, against the compiler's default limit of 16 MB, which refused
+#: every causal call longer than one tile (S 4096, S 8192). A v5e core has
+#: 128 MiB. Calls of tiles up to 1024 (S <= 1024, the GPT-2 cells') ask for
+#: nothing.
 MULTI_TILE_VMEM_BYTES = 32 * 1024 * 1024
 
 #: rows of queries in a band of a causal tile (``_tile_blocks``)
 SUB_BLOCK = 256
+#: bands of a grid step up to which the loop over a block's heads is
+#: unrolled (``_unroll_heads``): a tile is at most 8 bands, and a grid of
+#: several causal tiles has a class on the diagonal and one below it, 16
+UNROLL_HEADS_BANDS = 8
 _LANES = 128
 
 # Candidate tile grids of a non-causal call for the measured autotuner
@@ -129,7 +147,7 @@ BWD_TILE_CANDIDATES = [(512, 512), (1024, 1024), (256, 512), (512, 1024),
                        (1024, 512)]
 
 
-def _tuned_blocks(kind, bh, s_q, s_k, d, dtype, causal, scale):
+def _tuned_blocks(kind, heads, s_q, s_k, d, dtype, causal, scale):
     """(block_q, block_k) for this shape class on this chip: a causal call's
     constant, a non-causal call's measured winner.
 
@@ -137,8 +155,9 @@ def _tuned_blocks(kind, bh, s_q, s_k, d, dtype, causal, scale):
     the backend is not a real TPU (reference
     phi/kernels/autotune/switch_autotune.cc gate). Benchmarks run on
     noise at the BUCKETED sequence lengths (tile ranking is data- and
-    batch-mostly-independent; batch*heads is capped at 8 to keep the
-    probe cheap).
+    batch-mostly-independent; one sequence of at most 8 heads, or the most
+    below that lie as the caller's do (``_head_layout``), keeps the probe
+    cheap).
     """
     from . import autotune as at
 
@@ -149,28 +168,29 @@ def _tuned_blocks(kind, bh, s_q, s_k, d, dtype, causal, scale):
             return DEFAULT_BLOCK_Q, DEFAULT_BLOCK_K
         return _bwd_block_for(s_q), _bwd_block_for(s_k)
 
+    hpb = _head_layout(heads, d)[0]
     sq_b, sk_b = at.seq_bucket(s_q), at.seq_bucket(s_k)
-    key = at.make_key(f"flash_{kind}", sq=sq_b, sk=sk_b, d=d,
+    key = at.make_key(f"flash_{kind}", sq=sq_b, sk=sk_b, d=d, hpb=hpb,
                       dt=str(jnp.dtype(dtype)), causal=bool(causal))
     cached = at.get_cache().get(key)
     if cached is not None:
         return tuple(cached)
 
-    bh_b = min(bh, 8)
+    heads = _probe_heads(heads, d)
     # probe on noise, not zeros (constant-folding could skip real work)
     nvar = 3
     qs, ks, vs = [], [], []
     for i in range(nvar):
         kp = jax.random.key(i)
-        qs.append(jax.random.normal(kp, (bh_b, sq_b, d)).astype(dtype))
+        qs.append(jax.random.normal(kp, (1, sq_b, heads, d)).astype(dtype))
         ks.append(jax.random.normal(
-            jax.random.fold_in(kp, 1), (bh_b, sk_b, d)).astype(dtype))
+            jax.random.fold_in(kp, 1), (1, sk_b, heads, d)).astype(dtype))
         vs.append(jax.random.normal(
-            jax.random.fold_in(kp, 2), (bh_b, sk_b, d)).astype(dtype))
+            jax.random.fold_in(kp, 2), (1, sk_b, heads, d)).astype(dtype))
     # amortize per-call dispatch under the kernel: chain K applications
     # data-dependently inside ONE program (the kernel's q-shaped output
     # feeds the next iteration), sized so device time dominates
-    kernel_flops = 4.0 * bh_b * sq_b * sk_b * d * (0.5 if causal else 1.0)
+    kernel_flops = 4.0 * heads * sq_b * sk_b * d * (0.5 if causal else 1.0)
     reps = at.probe_reps(kernel_flops)
     jitted = {}
     if kind == "fwd":
@@ -181,7 +201,7 @@ def _tuned_blocks(kind, bh, s_q, s_k, d, dtype, causal, scale):
             fn = jitted.get(c)
             if fn is None:
                 kern = functools.partial(
-                    _flash_fwd_bhsd, causal=causal, scale=scale,
+                    _flash_fwd_bshd, causal=causal, scale=scale,
                     block_q=c[0], block_k=c[1])
 
                 def chained(q0, k0, v0):
@@ -195,7 +215,7 @@ def _tuned_blocks(kind, bh, s_q, s_k, d, dtype, causal, scale):
         candidates = BWD_TILE_CANDIDATES
         default = (_bwd_block_for(s_q), _bwd_block_for(s_k))
         fwd = jax.jit(functools.partial(
-            _flash_fwd_bhsd, causal=causal, scale=scale,
+            _flash_fwd_bshd, causal=causal, scale=scale,
             block_q=DEFAULT_BLOCK_Q, block_k=DEFAULT_BLOCK_K))
         outs, lses = zip(*(fwd(qs[j], ks[j], vs[j])
                            for j in range(nvar)))
@@ -204,7 +224,7 @@ def _tuned_blocks(kind, bh, s_q, s_k, d, dtype, causal, scale):
             fn = jitted.get(c)
             if fn is None:
                 kern = functools.partial(
-                    _flash_bwd_bhsd, causal=causal, scale=scale,
+                    _flash_bwd_bshd, causal=causal, scale=scale,
                     block_q=c[0], block_k=c[1])
 
                 def chained(q0, k0, v0, o0, l0, g0):
@@ -218,7 +238,8 @@ def _tuned_blocks(kind, bh, s_q, s_k, d, dtype, causal, scale):
 
     return tuple(at.autotune(
         key, candidates, run, default, warmup=2, iters=5,
-        describe=lambda c: flash_plan(sq_b, sk_b, causal, *c, kind)))
+        describe=lambda c: flash_plan(sq_b, sk_b, causal, *c, heads, d,
+                                      jnp.dtype(dtype).itemsize)))
 
 
 def _causal_run(q_idx, kv_idx, block_q, block_k, offset):
@@ -331,48 +352,58 @@ def _grid_classes(seq_q, seq_k, causal, block_q, block_k, band):
     return tuple((blocks, keys, n) for blocks, (keys, n) in by_blocks.items())
 
 
-def _stats_shape(bh, sp_q):
-    """The per-row float32 statistics of ``bh`` heads (logsumexp, delta) as
-    they lie in HBM between the kernels: one lane-dense row a head."""
-    return (bh, 1, sp_q)
+def _stats_shape(batch, heads, hpb, sp_q):
+    """The per-row float32 logsumexp as it lies in HBM between the kernels:
+    a lane-dense row a head, the heads of a block together (the block of a
+    grid step is the whole of that axis)."""
+    return (batch, heads // hpb, hpb, sp_q)
 
 
 def _hbm_bytes(shape):
     """Bytes of a float32 array in HBM: its last dimension is whole 128-lane
-    tiles, and the one before it whole sublanes (8) unless it is 1 (a
-    (.., S, 1) column is a tile of 8 x 128 to every 8 values, a (.., 1, S)
-    row 128 values a tile)."""
+    tiles, and the one before it whole sublanes (8) unless it is 1, 2 or 4,
+    which XLA tiles as they are (a (.., S, 1) column is a tile of 8 x 128 to
+    every 8 values, a (.., 1, S) row 128 values a tile)."""
     *lead, sub, lane = shape
-    return (math.prod(lead) * (sub if sub == 1 else _ceil_to(sub, 8))
+    return (math.prod(lead) * (sub if sub in (1, 2, 4) else _ceil_to(sub, 8))
             * _ceil_to(lane, _LANES) * 4)
 
 
-def flash_plan(seq_q, seq_k, causal, block_q, block_k, kind="fwd"):
+def flash_plan(seq_q, seq_k, causal, block_q, block_k, heads=1,
+               head_dim=_LANES, itemsize=2):
     """What the three kernels execute for a call, fixed when it is traced:
-    ``tiles`` (the grid a head, queries x keys), ``sub_block`` (the rows
-    of a band of a causal tile; None where a tile runs whole),
+    ``tiles`` (the grid a block of heads, queries x keys), ``sub_block``
+    (the rows of a band of a causal tile; None where a tile runs whole),
     ``executed_share``, the area of scores computed over the padded
     seq_q x seq_k square (1.0 non-causal; 0.625 for one causal tile of
-    1024 in bands of 256), and ``stats_bytes``, what a head's per-row
-    statistics occupy in HBM as the kernels lay them out: the logsumexp
-    the forward (``kind`` "fwd") writes, the logsumexp and delta the
-    backward ("bwd") reads."""
+    1024 in bands of 256), ``heads_per_block``, the heads that share a
+    128-lane block of the (B, S, H*d) array (1: a head is a block of its
+    own, padded to whole lane tiles), ``io_bytes``, what one head's q
+    occupies in HBM as the kernels read it (its share of the block: S_padded
+    x d where heads pack, S_padded x 128 for a lone 64-wide head), and
+    ``stats_bytes``, what a head's logsumexp occupies there, the one per-row
+    statistic that crosses HBM (the forward writes it, both backward kernels
+    read it; delta is theirs, made in VMEM)."""
     bq, bk, sp_q, sp_k = _geometry(seq_q, seq_k, block_q, block_k,
                                    SUB_BLOCK if causal else None)
     area = sum(n * sum((r1 - r0) * c1 for r0, r1, c1, _mc0, _g in blocks)
                for blocks, _keys, n in _grid_classes(
                    seq_q, seq_k, causal, block_q, block_k, SUB_BLOCK))
+    hpb, head_lanes = _head_layout(heads, head_dim)
     return {"tiles": [sp_q // bq, sp_k // bk],
             "sub_block": min(SUB_BLOCK, bq) if causal else None,
             "executed_share": area / (sp_q * sp_k),
-            "stats_bytes": ((2 if kind == "bwd" else 1)
-                            * _hbm_bytes(_stats_shape(1, sp_q)))}
+            "heads_per_block": hpb,
+            "io_bytes": sp_q * head_lanes * itemsize,
+            "stats_bytes": _hbm_bytes(_stats_shape(1, hpb, hpb, sp_q)) // hpb}
 
 
-def _plan_entry(kind, seq_q, seq_k, causal, block_q, block_k):
+def _plan_entry(kind, seq_q, seq_k, causal, block_q, block_k, heads,
+                head_dim, dtype):
     return (f"flash_{kind}[{seq_q}x{seq_k},{'causal' if causal else 'full'},"
             f"{block_q}x{block_k}]",
-            flash_plan(seq_q, seq_k, causal, block_q, block_k, kind))
+            flash_plan(seq_q, seq_k, causal, block_q, block_k, heads,
+                       head_dim, jnp.dtype(dtype).itemsize))
 
 
 def _stamp_plan(*call):
@@ -458,12 +489,99 @@ def _probs(s, lse, block, mask, keys_axis=1):
                         lambda x: jnp.where(mask, x, 0.0), keys_axis)
 
 
+# --------------------------------------------------------------------------
+# Heads of a block. The kernels take their blocks from the (B, S, H*d) array
+# the model holds: a block is ``_LANES`` lanes wide and carries
+# ``heads_per_block`` heads side by side (two at d 64), or one head of d
+# lanes (d a multiple of 128; any other width is padded to one). The band
+# body runs once a head (``_for_heads``), on whole blocks: the band's queries
+# and dO are taken with the other heads' lanes zeroed (``_head_lanes``), so a
+# product that CONTRACTS over the lanes (scores, dP) adds exact zeros and one
+# that GIVES lanes is the head's in the head's lanes (P^T dO, dS^T Q: zero in
+# the others, accumulated as it is; P V, dS K: the head's lanes chosen). The
+# MXU passes are those of a head padded to 128 lanes, and nothing moves
+# across lanes.
+# --------------------------------------------------------------------------
+def _head_layout(heads, d):
+    """``(heads_per_block, lanes a head)`` of a call of ``heads`` heads of
+    ``d``: heads that tile 128 lanes exactly share a block as they lie in
+    (B, S, H*d); any other head is one block of d lanes, padded to whole
+    lane tiles."""
+    if _LANES % d == 0 and heads * d % _LANES == 0:
+        return _LANES // d, d
+    return 1, _ceil_to(d, _LANES)
+
+
+def _probe_heads(heads, d):
+    """Heads of the autotuner's probe: the most, up to 8 or one block's,
+    that lie as the caller's ``heads`` do (9 heads of 64 are each padded to
+    a block of their own, and so are the probe's 7: 8 would pack)."""
+    hpb = _head_layout(heads, d)[0]
+    return next(n for n in range(min(heads, max(8, hpb)), 0, -1)
+                if _head_layout(n, d)[0] == hpb)
+
+
+def _head_keep(h, hpb, shape):
+    """Which lanes of a (n, lanes) block are head ``h``'s (None: all, the
+    block is one head's)."""
+    if hpb == 1:
+        return None
+    lane = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+    d = shape[1] // hpb
+    return (lane >= h * d) & (lane < (h + 1) * d)
+
+
+def _head_lanes(x, keep):
+    """The block ``x`` with the other heads' lanes zeroed."""
+    return x if keep is None else jnp.where(keep, x, jnp.zeros_like(x))
+
+
+def _set_head(ref, idx, keep, value):
+    """``ref[idx] = value`` in the head's lanes, the others' as they are."""
+    ref[idx] = value if keep is None else jnp.where(keep, value, ref[idx])
+
+
+def _for_heads(hpb, unroll, body):
+    """``body(h)`` for every head of the block, one after the other.
+    ``unroll`` False: a real loop (h a traced index), so that a block of
+    several heads is the one-head body in code size and compile time."""
+    if hpb == 1:
+        body(0)
+    else:
+        def step(h, carry):
+            body(h)
+            return carry
+
+        jax.lax.fori_loop(0, hpb, step, 0, unroll=unroll)
+
+
+def _unroll_heads(classes):
+    """Whether a grid step's loop over its block's heads is unrolled: where
+    its body is few bands (up to ``UNROLL_HEADS_BANDS``), a real loop (the
+    head a traced index) where it is more. Timed either way on a v5e (PR
+    46, bf16, d 64, the kernels alone, the backward's ms a call unrolled /
+    as a loop): a non-causal 4096 in tiles of 512, 1 band, 11.62 / 12.45;
+    causal 1024, one tile of 4 bands (the GPT-2 cells'), 0.908 / 1.157;
+    causal 2048, one tile of 8, 1.503 / 1.765; causal 4096 and 8192 in
+    tiles of 2048, 16 bands in two classes, 9.87 / 6.52 and 36.0 / 24.8
+    (LFM2's; 64 MB of scoped VMEM for 32 changes neither). A square causal
+    grid of whole tiles has no count between 8 and 16. Compiled for a
+    described v5e the two forms' schedules a head are alike at 16 bands
+    (the forward's: 68 800 bundles unrolled, 2 x 36 478 as a loop), so what
+    the chip pays is not in them: the unrolled kernels are 69-102 thousand
+    bundles of code where every kernel that runs well is under 54 thousand
+    (8 bands unrolled 22-35, 16 as a loop 36-54), and instruction fetch is
+    the suspect (ROADMAP S5 (e)). Unrolling also doubles what Mosaic
+    compiles (7.4 s -> 18 s for the three kernels at S 8192)."""
+    return sum(len(blocks) for blocks, _k, _n in classes) <= UNROLL_HEADS_BANDS
+
+
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
                 *, scale, causal, block_q, block_k, seq_q, seq_k, classes,
-                one_pass):
-    kv_idx = pl.program_id(2)
-    q_idx = pl.program_id(1)
-    num_kv = pl.num_programs(2)
+                one_pass, hpb, unroll):
+    kv_idx = pl.program_id(3)
+    q_idx = pl.program_id(2)
+    num_kv = pl.num_programs(3)
     # Bottom-right-aligned causal diagonal (matches tril(..., k=t-s) in the
     # XLA reference path): query i attends keys <= i + (seq_k - seq_q).
     causal_offset = seq_k - seq_q
@@ -477,7 +595,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
     elif seq_q > seq_k:
         # the first seq_q - seq_k rows see no key: no band writes them
         o_ref[0] = jnp.zeros_like(o_ref[0])
-        lse_ref[0] = jnp.full_like(lse_ref[0], NEG_INF)
+        lse_ref[0, 0] = jnp.full_like(lse_ref[0, 0], NEG_INF)
 
     run = True
     if causal:
@@ -485,43 +603,56 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
 
     def _block(block):
         rows, cols = slice(*block[:2]), slice(0, block[2])
-        q = q_ref[0, rows]    # (rows, d)
-        k = k_ref[0, cols]    # (keys, d)
-        v = v_ref[0, cols]
-        s = _scores(q, k, scale)
-        mask = _block_mask(block, q_idx, kv_idx, block_q, block_k, seq_k,
-                           causal, causal_offset)
-        if mask is not None:
-            s = _masked_keys(s, block, lambda x: jnp.where(mask, x, NEG_INF))
 
-        if one_pass:
-            # the band's only keys: nothing to merge, nothing to carry
-            m_new = jnp.max(s, axis=1, keepdims=True)
-            p = jnp.exp(s - m_new)
+        def _head(h):
+            q = q_ref[0, rows]    # (rows, lanes)
+            k = k_ref[0, cols]    # (keys, lanes)
+            v = v_ref[0, cols]
+            mask = _block_mask(block, q_idx, kv_idx, block_q, block_k, seq_k,
+                               causal, causal_offset)
+            keep = _head_keep(h, hpb, q.shape)
+            s = _scores(_head_lanes(q, keep), k, scale)
+            if mask is not None:
+                s = _masked_keys(s, block,
+                                 lambda x: jnp.where(mask, x, NEG_INF))
+
+            if one_pass:
+                # the band's only keys: nothing to merge, nothing to carry
+                m_new = jnp.max(s, axis=1, keepdims=True)
+                p = jnp.exp(s - m_new)
+                if block[4]:
+                    p = jnp.where(m_new > NEG_INF / 2, p, 0.0)
+                l = jnp.maximum(jnp.sum(p, axis=1, keepdims=True), 1e-30)
+                acc = jax.lax.dot_general(
+                    p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32)
+                if keep is None:
+                    o_ref[0, rows] = (acc / l).astype(o_ref.dtype)
+                else:       # the heads' lanes meet in the idle accumulator
+                    _set_head(acc_scr, rows, keep, acc / l)
+                lse_ref[0, 0, pl.ds(h, 1), rows] = (m_new + jnp.log(l)).T
+                return
+            m_prev = m_scr[h, rows]                # (rows, 1)
+            m_cur = jnp.max(s, axis=1, keepdims=True)
+            m_new = jnp.maximum(m_prev, m_cur)
+            p = jnp.exp(s - m_new)                 # (rows, keys)
             if block[4]:
+                # fully-masked rows (causal, seq_q > seq_k): m_new ==
+                # NEG_INF and exp(s - m_new) == 1; zero them so l stays 0,
+                # out stays 0
                 p = jnp.where(m_new > NEG_INF / 2, p, 0.0)
-            l = jnp.maximum(jnp.sum(p, axis=1, keepdims=True), 1e-30)
-            acc = jax.lax.dot_general(
-                p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
-            o_ref[0, rows] = (acc / l).astype(o_ref.dtype)
-            lse_ref[0, :, rows] = (m_new + jnp.log(l)).T
-            return
-        m_prev = m_scr[rows]                   # (rows, 1)
-        m_cur = jnp.max(s, axis=1, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_cur)
-        p = jnp.exp(s - m_new)                 # (rows, keys)
-        if block[4]:
-            # fully-masked rows (causal, seq_q > seq_k): m_new == NEG_INF
-            # and exp(s - m_new) == 1; zero them so l stays 0, out stays 0
-            p = jnp.where(m_new > NEG_INF / 2, p, 0.0)
-        alpha = jnp.exp(m_prev - m_new)
-        l_new = alpha * l_scr[rows] + jnp.sum(p, axis=1, keepdims=True)
-        acc_scr[rows] = acc_scr[rows] * alpha + jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_scr[rows] = m_new
-        l_scr[rows] = l_new
+            alpha = jnp.exp(m_prev - m_new)
+            l_scr[h, rows] = (alpha * l_scr[h, rows]
+                              + jnp.sum(p, axis=1, keepdims=True))
+            m_scr[h, rows] = m_new
+            _set_head(acc_scr, rows, keep,
+                      acc_scr[rows] * alpha + jax.lax.dot_general(
+                          p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                          preferred_element_type=jnp.float32))
+
+        _for_heads(hpb, unroll, _head)
+        if one_pass and hpb > 1:
+            o_ref[0, rows] = acc_scr[rows].astype(o_ref.dtype)
 
     @pl.when(run)
     def _step():
@@ -531,17 +662,22 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
     if not one_pass:
         @pl.when(kv_idx == num_kv - 1)
         def _finish():
-            l = jnp.maximum(l_scr[:], 1e-30)
-            o_ref[0] = (acc_scr[:] / l).astype(o_ref.dtype)
-            lse_ref[0] = (m_scr[:] + jnp.log(l)).T
+            def _head(h):
+                keep = _head_keep(h, hpb, acc_scr.shape)
+                l = jnp.maximum(l_scr[h], 1e-30)
+                _set_head(acc_scr, slice(None), keep, acc_scr[:] / l)
+                lse_ref[0, 0, pl.ds(h, 1), :] = (m_scr[h] + jnp.log(l)).T
+
+            _for_heads(hpb, unroll, _head)
+            o_ref[0] = acc_scr[:].astype(o_ref.dtype)
 
 
-def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
+def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, out_ref, dq_ref,
                dq_scr, *, scale, causal, block_q, block_k, seq_q, seq_k,
-               classes):
-    kv_idx = pl.program_id(2)
-    q_idx = pl.program_id(1)
-    num_kv = pl.num_programs(2)
+               classes, hpb, unroll):
+    kv_idx = pl.program_id(3)
+    q_idx = pl.program_id(2)
+    num_kv = pl.num_programs(3)
     causal_offset = seq_k - seq_q
 
     @pl.when(kv_idx == 0)
@@ -554,23 +690,33 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
 
     def _block(block):
         rows, cols = slice(*block[:2]), slice(0, block[2])
-        q = q_ref[0, rows]
-        k = k_ref[0, cols]
-        v = v_ref[0, cols]
-        do = do_ref[0, rows]
-        lse = lse_ref[0, :, rows].reshape(-1, 1)    # (1, rows) -> (rows, 1)
-        delta = delta_ref[0, :, rows].reshape(-1, 1)
-        s = _scores(q, k, scale)
-        mask = _block_mask(block, q_idx, kv_idx, block_q, block_k, seq_k,
-                           causal, causal_offset)
-        p = _probs(s, lse, block, mask)
-        dp = jax.lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        ds = p * (dp - delta) * scale          # (rows, keys) fp32
-        dq_scr[rows] += jax.lax.dot_general(
-            ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+
+        def _head(h):
+            q = q_ref[0, rows]
+            k = k_ref[0, cols]
+            v = v_ref[0, cols]
+            do = do_ref[0, rows]
+            out = out_ref[0, rows].astype(jnp.float32)
+            mask = _block_mask(block, q_idx, kv_idx, block_q, block_k, seq_k,
+                               causal, causal_offset)
+            keep = _head_keep(h, hpb, q.shape)
+            do_h = _head_lanes(do, keep)
+            # row -> column
+            lse = lse_ref[0, 0, pl.ds(h, 1), rows].reshape(-1, 1)
+            # delta = rowsum(dO * out) of the head, the blocks as they lie
+            delta = jnp.sum(do_h.astype(jnp.float32) * out, axis=1,
+                            keepdims=True)
+            s = _scores(_head_lanes(q, keep), k, scale)
+            p = _probs(s, lse, block, mask)
+            dp = jax.lax.dot_general(
+                do_h, v, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            ds = p * (dp - delta) * scale          # (rows, keys) fp32
+            dq_scr[rows] += _head_lanes(jax.lax.dot_general(
+                ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32), keep)
+
+        _for_heads(hpb, unroll, _head)
 
     @pl.when(run)
     def _step():
@@ -582,13 +728,13 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
         dq_ref[0] = dq_scr[:].astype(dq_ref.dtype)
 
 
-def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref,
+def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, out_ref, dk_ref,
                 dv_ref, dk_scr, dv_scr, *, scale, causal, block_q, block_k,
-                seq_q, seq_k, classes):
-    q_idx = pl.program_id(2)       # q innermost in this kernel
-    kv_idx = pl.program_id(1)
-    num_q = pl.num_programs(2)
-    num_kv = pl.num_programs(1)
+                seq_q, seq_k, classes, hpb, unroll):
+    q_idx = pl.program_id(3)       # q innermost in this kernel
+    kv_idx = pl.program_id(2)
+    num_q = pl.num_programs(3)
+    num_kv = pl.num_programs(2)
     causal_offset = seq_k - seq_q
 
     @pl.when(q_idx == 0)
@@ -602,30 +748,41 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref,
 
     def _block(block):
         rows, cols = slice(*block[:2]), slice(0, block[2])
-        q = q_ref[0, rows]
-        k = k_ref[0, cols]
-        v = v_ref[0, cols]
-        do = do_ref[0, rows]
-        lse = lse_ref[0, :, rows]              # (1, rows): down the keys
-        delta = delta_ref[0, :, rows]
-        # everything (keys, rows): the statistics broadcast as they lie and
-        # no product contracts over the leading dimension of the scores
-        s_t = _scores(k, q, scale)
-        mask = _block_mask(block, q_idx, kv_idx, block_q, block_k, seq_k,
-                           causal, causal_offset, keys_axis=0)
-        p_t = _probs(s_t, lse, block, mask, keys_axis=0)
-        # dv += P^T dO
-        dv_scr[cols] += jax.lax.dot_general(
-            p_t.astype(do.dtype), do, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        dp_t = jax.lax.dot_general(
-            v, do, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        ds_t = p_t * (dp_t - delta) * scale
-        # dk += dS^T Q
-        dk_scr[cols] += jax.lax.dot_general(
-            ds_t.astype(q.dtype), q, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+
+        def _head(h):
+            q = q_ref[0, rows]
+            k = k_ref[0, cols]
+            v = v_ref[0, cols]
+            do = do_ref[0, rows]
+            out = out_ref[0, rows].astype(jnp.float32)
+            mask = _block_mask(block, q_idx, kv_idx, block_q, block_k, seq_k,
+                               causal, causal_offset, keys_axis=0)
+            keep = _head_keep(h, hpb, q.shape)
+            # queries and dO with the head's lanes alone: the products that
+            # give lanes (P^T dO, dS^T Q) are then zero in the others'
+            q_h, do_h = _head_lanes(q, keep), _head_lanes(do, keep)
+            lse = lse_ref[0, 0, pl.ds(h, 1), rows]  # (1, rows): down the keys
+            delta = jnp.sum(do_h.astype(jnp.float32) * out, axis=1,
+                            keepdims=True).T       # column -> row
+            # everything (keys, rows): the statistics broadcast as they lie
+            # and no product contracts over the leading dimension of the
+            # scores
+            s_t = _scores(k, q_h, scale)
+            p_t = _probs(s_t, lse, block, mask, keys_axis=0)
+            # dv += P^T dO
+            dv_scr[cols] += jax.lax.dot_general(
+                p_t.astype(do.dtype), do_h, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            dp_t = jax.lax.dot_general(
+                v, do_h, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            ds_t = p_t * (dp_t - delta) * scale
+            # dk += dS^T Q
+            dk_scr[cols] += jax.lax.dot_general(
+                ds_t.astype(q.dtype), q_h, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+
+        _for_heads(hpb, unroll, _head)
 
     @pl.when(run)
     def _step():
@@ -638,25 +795,37 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref,
         dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
 
 
-def _pad_bhsd(x, block_s, pad_d):
-    pad_s = (-x.shape[1]) % block_s
-    if pad_s or pad_d:
-        x = jnp.pad(x, ((0, 0), (0, pad_s), (0, pad_d)))
-    return x
+def _to_blocks(x, block_s, lanes):
+    """(B, S, H, d) -> (B, S_padded, H * lanes): the array the kernels take
+    their blocks from. Heads that pack (``lanes`` == d) and a sequence of
+    whole tiles: the reshape alone, which is the model's own (B, S, H*d)
+    array; else zeros to the tile and to ``lanes`` a head."""
+    b, s, h, d = x.shape
+    pad_s = -s % block_s
+    if pad_s or lanes != d:
+        x = jnp.pad(x, ((0, 0), (0, pad_s), (0, 0), (0, lanes - d)))
+    return x.reshape(b, s + pad_s, h * lanes)
 
 
-def _compiler_params(causal, sp_q, sp_k, block_q, block_k):
+def _from_blocks(x, s, h, d):
+    """``_to_blocks`` undone: (B, S_padded, H * lanes) -> (B, S, H, d)."""
+    b, sp, width = x.shape
+    x = x.reshape(b, sp, h, width // h)
+    return x if (sp, width) == (s, h * d) else x[:, :s, :, :d]
+
+
+def _compiler_params(causal, block_q, block_k):
     """Mosaic's parameters for a call: the default (None) unless it is a
-    causal call of SEVERAL tiles wider than 1024, which needs more scoped
-    VMEM than the default limit (``MULTI_TILE_VMEM_BYTES``)."""
-    if (causal and max(block_q, block_k) > 1024
-            and (sp_q > block_q or sp_k > block_k)):
+    causal call of tiles wider than 1024, which needs more scoped VMEM than
+    the default limit (``MULTI_TILE_VMEM_BYTES``)."""
+    if causal and max(block_q, block_k) > 1024:
         return pltpu.CompilerParams(vmem_limit_bytes=MULTI_TILE_VMEM_BYTES)
     return None
 
 
-def _flash_fwd_bhsd(q, k, v, *, causal, scale, block_q, block_k):
-    """q/k/v: (BH, S, d) -> (out (BH, S, d), lse fp32 (BH, 1, Sq_padded))."""
+def _flash_fwd_bshd(q, k, v, *, causal, scale, block_q, block_k):
+    """q/k/v: (B, S, H, d) -> (out (B, S, H, d), lse fp32 (B, H //
+    heads_per_block, heads_per_block, Sq_padded))."""
     return _fwd_call(q, k, v, causal=causal, scale=float(scale),
                      block_q=block_q, block_k=block_k, band=SUB_BLOCK,
                      interpret=INTERPRET)
@@ -671,19 +840,19 @@ _STATIC = ("causal", "scale", "block_q", "block_k", "band", "interpret")
 
 @functools.partial(jax.jit, static_argnames=_STATIC)
 def _fwd_call(q, k, v, *, causal, scale, block_q, block_k, band, interpret):
-    bh, s_q, d = q.shape
+    b, s_q, h, d = q.shape
     s_k = k.shape[1]
-    _log_plan("fwd", s_q, s_k, causal, block_q, block_k)
+    _log_plan("fwd", s_q, s_k, causal, block_q, block_k, h, d, q.dtype)
     classes = _grid_classes(s_q, s_k, causal, block_q, block_k, band)
     block_q, block_k, sp_q, sp_k = _geometry(s_q, s_k, block_q, block_k,
                                              band if causal else None)
-    pad_d = (-d) % 128
-    q = _pad_bhsd(q, block_q, pad_d)
-    k = _pad_bhsd(k, block_k, pad_d)
-    v = _pad_bhsd(v, block_k, pad_d)
-    dp = d + pad_d
+    hpb, head_lanes = _head_layout(h, d)
+    lanes = hpb * head_lanes
+    q = _to_blocks(q, block_q, head_lanes)
+    k = _to_blocks(k, block_k, head_lanes)
+    v = _to_blocks(v, block_k, head_lanes)
 
-    grid = (bh, sp_q // block_q, sp_k // block_k)
+    grid = (b, h // hpb, sp_q // block_q, sp_k // block_k)
     kernel = functools.partial(
         _fwd_kernel, scale=scale, causal=causal, block_q=block_q,
         block_k=block_k, seq_q=s_q, seq_k=s_k, classes=classes,
@@ -691,39 +860,39 @@ def _fwd_call(q, k, v, *, causal, scale, block_q, block_k, band, interpret):
         # keys at once (a non-causal call keeps the kernel it had). On a
         # v5e the running-state form runs the same bands 1.44 times as long
         # at S 1024, d 64 (1.20 at S 2048): 4.6 % of a GPT-2 345M step
-        one_pass=causal and sp_k == block_k)
+        one_pass=causal and sp_k == block_k, hpb=hpb,
+        unroll=_unroll_heads(classes))
+    q_spec = pl.BlockSpec((1, block_q, lanes), lambda b, g, i, j: (b, i, g))
+    kv_spec = pl.BlockSpec((1, block_k, lanes), lambda b, g, i, j: (b, j, g))
     out, lse = pl.pallas_call(
         kernel,
         out_shape=[
-            jax.ShapeDtypeStruct((bh, sp_q, dp), q.dtype),
-            jax.ShapeDtypeStruct(_stats_shape(bh, sp_q), jnp.float32)],
+            jax.ShapeDtypeStruct(q.shape, q.dtype),
+            jax.ShapeDtypeStruct(_stats_shape(b, h, hpb, sp_q),
+                                 jnp.float32)],
         grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, block_q, dp), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_k, dp), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, block_k, dp), lambda b, i, j: (b, j, 0)),
-        ],
+        in_specs=[q_spec, kv_spec, kv_spec],
         out_specs=[
-            pl.BlockSpec((1, block_q, dp), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, 1, block_q), lambda b, i, j: (b, 0, i)),
+            q_spec,
+            pl.BlockSpec((1, 1, hpb, block_q),
+                         lambda b, g, i, j: (b, g, 0, i)),
         ],
         scratch_shapes=[
-            pltpu.VMEM((block_q, 1), jnp.float32),
-            pltpu.VMEM((block_q, 1), jnp.float32),
-            pltpu.VMEM((block_q, dp), jnp.float32),
+            pltpu.VMEM((hpb, block_q, 1), jnp.float32),
+            pltpu.VMEM((hpb, block_q, 1), jnp.float32),
+            pltpu.VMEM((block_q, lanes), jnp.float32),
         ],
-        compiler_params=_compiler_params(causal, sp_q, sp_k, block_q,
-                                         block_k),
+        compiler_params=_compiler_params(causal, block_q, block_k),
         interpret=interpret,
         name="flash_fwd",
     )(q, k, v)
-    return out[:, :s_q, :d], lse
+    return _from_blocks(out, s_q, h, d), lse
 
 
-def _flash_bwd_bhsd(q, k, v, out, lse, do, *, causal, scale, block_q,
+def _flash_bwd_bshd(q, k, v, out, lse, do, *, causal, scale, block_q,
                     block_k):
-    """FA2 backward. All of q/k/v/out/do: (BH, S, d); lse: (BH, 1,
-    Sq_pad_fwd), as the forward gives it. Returns (dq, dk, dv) unpadded."""
+    """FA2 backward. All of q/k/v/out/do: (B, S, H, d); lse as the forward
+    gives it. Returns (dq, dk, dv), (B, S, H, d)."""
     return _bwd_call(q, k, v, out, lse, do, causal=causal,
                      scale=float(scale), block_q=block_q, block_k=block_k,
                      band=SUB_BLOCK, interpret=INTERPRET)
@@ -732,81 +901,68 @@ def _flash_bwd_bhsd(q, k, v, out, lse, do, *, causal, scale, block_q,
 @functools.partial(jax.jit, static_argnames=_STATIC)
 def _bwd_call(q, k, v, out, lse, do, *, causal, scale, block_q, block_k,
               band, interpret):
-    bh, s_q, d = q.shape
+    b, s_q, h, d = q.shape
     s_k = k.shape[1]
-    _log_plan("bwd", s_q, s_k, causal, block_q, block_k)
+    _log_plan("bwd", s_q, s_k, causal, block_q, block_k, h, d, q.dtype)
     classes = _grid_classes(s_q, s_k, causal, block_q, block_k, band)
     block_q, block_k, sp_q, sp_k = _geometry(s_q, s_k, block_q, block_k,
                                              band if causal else None)
-    pad_d = (-d) % 128
+    hpb, head_lanes = _head_layout(h, d)
+    lanes = hpb * head_lanes
 
-    # Δ = rowsum(dO ∘ O): one fused XLA reduction, fp32, a row like lse
-    delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32),
-                    axis=-1).reshape(_stats_shape(bh, s_q))
-
-    q = _pad_bhsd(q, block_q, pad_d)
-    do = _pad_bhsd(do, block_q, pad_d)
-    k = _pad_bhsd(k, block_k, pad_d)
-    v = _pad_bhsd(v, block_k, pad_d)
-    dp = d + pad_d
-    if lse.shape[2] < sp_q:     # fwd may have tiled with a different block
-        lse = jnp.pad(lse, ((0, 0), (0, 0), (0, sp_q - lse.shape[2])))
-    elif lse.shape[2] > sp_q:
+    # delta = rowsum(dO * out) is the kernels' own, a band and a head at a
+    # time from the blocks of dO and out as they lie: no pass of XLA's over
+    # the two arrays, and no (B, S, H) array to turn into rows
+    q = _to_blocks(q, block_q, head_lanes)
+    do = _to_blocks(do, block_q, head_lanes)
+    out = _to_blocks(out, block_q, head_lanes)
+    k = _to_blocks(k, block_k, head_lanes)
+    v = _to_blocks(v, block_k, head_lanes)
+    if lse.shape[3] < sp_q:     # fwd may have tiled with a different block
+        lse = jnp.pad(lse, ((0, 0),) * 3 + ((0, sp_q - lse.shape[3]),))
+    elif lse.shape[3] > sp_q:
         lse = lse[..., :sp_q]
-    delta = jnp.pad(delta, ((0, 0), (0, 0), (0, sp_q - s_q)))
 
     kw = dict(scale=scale, causal=causal, block_q=block_q, block_k=block_k,
-              seq_q=s_q, seq_k=s_k, classes=classes)
-    q_spec = pl.BlockSpec((1, block_q, dp), lambda b, i, j: (b, i, 0))
-    row_spec = pl.BlockSpec((1, 1, block_q), lambda b, i, j: (b, 0, i))
+              seq_q=s_q, seq_k=s_k, classes=classes, hpb=hpb,
+              unroll=_unroll_heads(classes))
+    q_spec = pl.BlockSpec((1, block_q, lanes), lambda b, g, i, j: (b, i, g))
+    k_spec = pl.BlockSpec((1, block_k, lanes), lambda b, g, i, j: (b, j, g))
+    row_spec = pl.BlockSpec((1, 1, hpb, block_q),
+                            lambda b, g, i, j: (b, g, 0, i))
 
     dq = pl.pallas_call(
         functools.partial(_dq_kernel, **kw),
-        out_shape=jax.ShapeDtypeStruct((bh, sp_q, dp), q.dtype),
-        grid=(bh, sp_q // block_q, sp_k // block_k),
-        in_specs=[
-            q_spec,
-            pl.BlockSpec((1, block_k, dp), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, block_k, dp), lambda b, i, j: (b, j, 0)),
-            q_spec, row_spec, row_spec,
-        ],
+        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        grid=(b, h // hpb, sp_q // block_q, sp_k // block_k),
+        in_specs=[q_spec, k_spec, k_spec, q_spec, row_spec, q_spec],
         out_specs=q_spec,
-        scratch_shapes=[pltpu.VMEM((block_q, dp), jnp.float32)],
-        compiler_params=_compiler_params(causal, sp_q, sp_k, block_q,
-                                         block_k),
+        scratch_shapes=[pltpu.VMEM((block_q, lanes), jnp.float32)],
+        compiler_params=_compiler_params(causal, block_q, block_k),
         interpret=interpret,
         name="flash_dq",
-    )(q, k, v, do, lse, delta)
+    )(q, k, v, do, lse, out)
 
     # dk/dv: kv outer, q inner
-    qi_spec = pl.BlockSpec((1, block_q, dp), lambda b, i, j: (b, j, 0))
-    rowi_spec = pl.BlockSpec((1, 1, block_q), lambda b, i, j: (b, 0, j))
-    kv_spec = pl.BlockSpec((1, block_k, dp), lambda b, i, j: (b, i, 0))
+    qi_spec = pl.BlockSpec((1, block_q, lanes), lambda b, g, i, j: (b, j, g))
+    rowi_spec = pl.BlockSpec((1, 1, hpb, block_q),
+                             lambda b, g, i, j: (b, g, 0, j))
+    kv_spec = pl.BlockSpec((1, block_k, lanes), lambda b, g, i, j: (b, i, g))
     dk, dv = pl.pallas_call(
         functools.partial(_dkv_kernel, **kw),
-        out_shape=[jax.ShapeDtypeStruct((bh, sp_k, dp), k.dtype),
-                   jax.ShapeDtypeStruct((bh, sp_k, dp), v.dtype)],
-        grid=(bh, sp_k // block_k, sp_q // block_q),
-        in_specs=[qi_spec, kv_spec, kv_spec, qi_spec, rowi_spec, rowi_spec],
+        out_shape=[jax.ShapeDtypeStruct(k.shape, k.dtype),
+                   jax.ShapeDtypeStruct(v.shape, v.dtype)],
+        grid=(b, h // hpb, sp_k // block_k, sp_q // block_q),
+        in_specs=[qi_spec, kv_spec, kv_spec, qi_spec, rowi_spec, qi_spec],
         out_specs=[kv_spec, kv_spec],
-        scratch_shapes=[pltpu.VMEM((block_k, dp), jnp.float32),
-                        pltpu.VMEM((block_k, dp), jnp.float32)],
-        compiler_params=_compiler_params(causal, sp_q, sp_k, block_q,
-                                         block_k),
+        scratch_shapes=[pltpu.VMEM((block_k, lanes), jnp.float32),
+                        pltpu.VMEM((block_k, lanes), jnp.float32)],
+        compiler_params=_compiler_params(causal, block_q, block_k),
         interpret=interpret,
         name="flash_dkv",
-    )(q, k, v, do, lse, delta)
-    return (dq[:, :s_q, :d], dk[:, :s_k, :d], dv[:, :s_k, :d])
-
-
-def _bshd_to_bhsd(x):
-    b, s, h, d = x.shape
-    return x.transpose(0, 2, 1, 3).reshape(b * h, s, d)
-
-
-def _bhsd_to_bshd(x, b, h):
-    bh, s, d = x.shape
-    return x.reshape(b, h, s, d).transpose(0, 2, 1, 3)
+    )(q, k, v, do, lse, out)
+    return (_from_blocks(dq, s_q, h, d), _from_blocks(dk, s_k, h, d),
+            _from_blocks(dv, s_k, h, d))
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
@@ -822,10 +978,10 @@ def _resolve_blocks(kind, block_q, block_k, q, k, causal, scale):
     trace time)."""
     if block_q is not None and block_k is not None:
         return block_q, block_k
-    b, s, h, d = q.shape
+    _b, s, h, d = q.shape
     with jax.core.eval_context():
-        tq, tk = _tuned_blocks(kind, b * h, s, k.shape[1], d, q.dtype,
-                               causal, scale)
+        tq, tk = _tuned_blocks(kind, h, s, k.shape[1], d, q.dtype, causal,
+                               scale)
     return (tq if block_q is None else block_q,
             tk if block_k is None else block_k)
 
@@ -857,38 +1013,33 @@ def _keep(x, name):
 
 
 def _flash_fwd(q, k, v, causal, scale, block_q, block_k):
-    """(out (B, S, H, d), lse (BH, 1, S_padded)) as the kernel gives them."""
-    b, s, h, d = q.shape
+    """(out (B, S, H, d), lse (B, H // hpb, hpb, S_padded)) as the kernel
+    gives them."""
     block_q, block_k = _resolve_blocks("fwd", block_q, block_k, q, k,
                                        causal, scale)
-    _stamp_plan("fwd", s, k.shape[1], causal, block_q, block_k)
-    out, lse = _flash_fwd_bhsd(
-        _bshd_to_bhsd(q), _bshd_to_bhsd(k), _bshd_to_bhsd(v),
-        causal=causal, scale=scale, block_q=block_q, block_k=block_k)
-    return _bhsd_to_bshd(out, b, h), lse
+    _stamp_plan("fwd", q.shape[1], k.shape[1], causal, block_q, block_k,
+                q.shape[2], q.shape[3], q.dtype)
+    return _flash_fwd_bshd(q, k, v, causal=causal, scale=scale,
+                           block_q=block_q, block_k=block_k)
 
 
 def _flash_fwd_rule(q, k, v, causal, scale, block_q, block_k):
-    out_bshd, lse = _flash_fwd(q, k, v, causal, scale, block_q, block_k)
-    out_bshd = _keep(out_bshd, KEPT_RESIDUALS[0])
+    out, lse = _flash_fwd(q, k, v, causal, scale, block_q, block_k)
+    out = _keep(out, KEPT_RESIDUALS[0])
     # the kernel's rows as they come: kept, and read by the backward
     # kernels, with no pass of XLA's over them
     lse = _keep(lse, KEPT_RESIDUALS[1])
-    return out_bshd, (q, k, v, out_bshd, lse)
+    return out, (q, k, v, out, lse)
 
 
 def _flash_bwd_rule(causal, scale, block_q, block_k, res, g):
     q, k, v, out, lse = res
-    b, s, h, d = q.shape
     block_q, block_k = _resolve_blocks("bwd", block_q, block_k, q, k,
                                        causal, scale)
-    _stamp_plan("bwd", s, k.shape[1], causal, block_q, block_k)
-    dq, dk, dv = _flash_bwd_bhsd(
-        _bshd_to_bhsd(q), _bshd_to_bhsd(k), _bshd_to_bhsd(v),
-        _bshd_to_bhsd(out), lse, _bshd_to_bhsd(g),
-        causal=causal, scale=scale, block_q=block_q, block_k=block_k)
-    return (_bhsd_to_bshd(dq, b, h), _bhsd_to_bshd(dk, b, h),
-            _bhsd_to_bshd(dv, b, h))
+    _stamp_plan("bwd", q.shape[1], k.shape[1], causal, block_q, block_k,
+                q.shape[2], q.shape[3], q.dtype)
+    return _flash_bwd_bshd(q, k, v, out, lse, g, causal=causal, scale=scale,
+                           block_q=block_q, block_k=block_k)
 
 
 _flash_attention.defvjp(_flash_fwd_rule, _flash_bwd_rule)

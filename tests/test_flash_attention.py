@@ -337,37 +337,176 @@ def test_causal_forward_and_backward_match_the_reference(
                                    err_msg=f"d{name}")
 
 
+# (heads, head width): how the heads lie in a 128-lane block
+_HEAD_LAYOUTS = [
+    pytest.param(2, 128, id="d128-one-head-a-block"),
+    pytest.param(4, 64, id="d64-two-heads-a-block"),
+    pytest.param(4, 32, id="d32-four-heads-a-block"),
+    pytest.param(3, 16, id="d16-three-heads-each-padded"),
+    pytest.param(3, 64, id="d64-odd-head-count-each-padded"),
+]
+# (seq_q, seq_k, causal, block_q, block_k, dtype)
+_HEAD_CALLS = [
+    pytest.param(128, 128, True, 128, 128, "float32", id="causal-one-pass"),
+    pytest.param(128, 128, True, 64, 64, "float32", id="causal-2x2-tiles"),
+    pytest.param(128, 128, False, 64, 64, "float32", id="full-2x2-tiles"),
+    pytest.param(100, 100, True, 64, 32, "float32", id="causal-ragged-100"),
+    pytest.param(64, 160, False, 64, 64, "float32", id="full-sq-lt-sk-ragged"),
+    pytest.param(128, 64, True, 64, 64, "float32", id="causal-sq-gt-sk"),
+    pytest.param(256, 256, True, None, None, "bfloat16",
+                 id="causal-bf16-the-modules-tile"),
+]
+
+
+@pytest.mark.parametrize("heads,d", _HEAD_LAYOUTS)
+@pytest.mark.parametrize("sq,sk,causal,bq,bk,dtype", _HEAD_CALLS)
+def test_every_head_layout_matches_the_reference(heads, d, sq, sk, causal, bq,
+                                                 bk, dtype):
+    """Forward and the three gradients against ``_sdpa_xla`` for every way
+    the heads of a call lie in the kernels' lane blocks (one head of d
+    lanes, 128 // d heads side by side, a head padded to a block of its
+    own), batch 2: each head of a block with its own max, sum and
+    accumulator, its own logsumexp row and delta."""
+    from paddle_tpu.nn.functional.flash_attention import _sdpa_xla
+
+    assert fa._head_layout(heads, d)[0] == {
+        (2, 128): 1, (4, 64): 2, (4, 32): 4, (3, 16): 1, (3, 64): 1}[heads, d]
+    q, k, v = (x.astype(dtype) for x in _rand_qkv(b=2, s=sq, t=sk, h=heads,
+                                                  d=d))
+    blind = max(sq - sk, 0) if causal else 0
+    g = _rand_qkv(b=2, s=sq, h=heads, d=d, seed=1)[0].at[:, :blind].set(0.0)
+
+    def vg(attn):
+        return jax.jit(jax.value_and_grad(
+            lambda q, k, v: jnp.sum(attn(q, k, v).astype(jnp.float32) * g),
+            argnums=(0, 1, 2), has_aux=False))
+
+    flash = lambda q, k, v: fa.flash_attention_fwd(   # noqa: E731
+        q, k, v, causal=causal, block_q=bq, block_k=bk)
+    out = jax.jit(flash)(q, k, v)
+    ref = _sdpa_xla(q, k, v, causal=causal)
+    _, grads = vg(flash)(q, k, v)
+    _, ref_grads = vg(lambda q, k, v: _sdpa_xla(q, k, v, causal=causal))(
+        q, k, v)
+    f32 = dtype == "float32"
+    tol = dict(atol=2e-5, rtol=2e-5) if f32 else dict(atol=0.15, rtol=0.1)
+    gtol = dict(atol=5e-5, rtol=5e-4) if f32 else dict(atol=0.15, rtol=0.1)
+    as32 = lambda x: np.asarray(x, np.float32)      # noqa: E731
+    assert out.shape == q.shape and out.dtype == q.dtype
+    np.testing.assert_array_equal(as32(out)[:, :blind], 0.0)
+    np.testing.assert_allclose(as32(out)[:, blind:], as32(ref)[:, blind:],
+                               **tol)
+    for a, b, name in zip(grads, ref_grads, "qkv"):
+        assert a.shape == b.shape
+        np.testing.assert_allclose(as32(a), as32(b), **gtol,
+                                   err_msg=f"d{name}")
+
+
+@pytest.mark.parametrize("sq,sk,causal,bq,bk", [
+    (256, 256, True, 128, 128), (128, 128, True, 128, 128),
+    (192, 256, False, 64, 128)], ids=["causal-2x2", "causal-one-pass", "full"])
+def test_the_heads_loop_gives_what_its_unrolling_gives(monkeypatch, sq, sk,
+                                                        causal, bq, bk):
+    """A grid step of few bands unrolls the loop over its block's heads, one
+    of many keeps it a loop with a traced head index (``_unroll_heads``: the
+    S 4096 and S 8192 calls, whose unrolled kernels ran 45-51 % longer on
+    the chip and compile in 18 s for 7.4): the same body, so the same values
+    and gradients bit for bit; and the rule is the count of bands, with the
+    shapes that were timed on either side of it."""
+    def rule(s, causal, block):
+        return fa._unroll_heads(fa._grid_classes(s, s, causal, block, block,
+                                                 fa.SUB_BLOCK))
+
+    assert rule(1024, True, 2048) and rule(2048, True, 2048)
+    assert not rule(4096, True, 2048) and not rule(8192, True, 2048)
+    assert rule(4096, False, 512) and rule(4096, False, 1024)
+    q, k, v = _rand_qkv(b=2, s=sq, t=sk, h=4, d=64)
+
+    def run(bands):
+        monkeypatch.setattr(fa, "UNROLL_HEADS_BANDS", bands)
+        fa._fwd_call.clear_cache()
+        fa._bwd_call.clear_cache()
+        text = str(jax.make_jaxpr(lambda q: fa.flash_attention_fwd(
+            q, k, v, causal=causal, block_q=bq, block_k=bk))(q))
+        return text, jax.jit(jax.value_and_grad(
+            lambda q, k, v: jnp.sum(fa.flash_attention_fwd(
+                q, k, v, causal=causal, block_q=bq, block_k=bk) ** 2),
+            argnums=(0, 1, 2)))(q, k, v)
+
+    try:
+        loop_text, (loop, loop_grads) = run(0)
+        flat_text, (flat, flat_grads) = run(64)
+    finally:
+        fa._fwd_call.clear_cache()
+        fa._bwd_call.clear_cache()
+    assert "unroll=1 " in loop_text.replace("\n", " ")
+    assert "unroll=2 " in flat_text.replace("\n", " ")
+    np.testing.assert_array_equal(np.asarray(loop), np.asarray(flat))
+    for a, b in zip(loop_grads, flat_grads):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+def _tpu_lowered_value_and_grad(shape):
+    """StableHLO text of ``value_and_grad`` of the public entry, causal,
+    bf16, lowered for a TPU (the Mosaic kernels as ``tpu_custom_call``)."""
+    old, fa.INTERPRET = fa.INTERPRET, False
+    try:
+        x = jax.ShapeDtypeStruct(shape, jnp.bfloat16)
+        return jax.jit(jax.value_and_grad(
+            lambda q, k, v: jnp.sum(fa.flash_attention_fwd(
+                q, k, v, causal=True, block_q=1024,
+                block_k=1024).astype(jnp.float32)),
+            argnums=(0, 1, 2))).trace(x, x, x).lower(
+                lowering_platforms=("tpu",)).as_text()
+    finally:
+        fa.INTERPRET = old
+
+
+def _kernel_signatures(text):
+    import re
+
+    calls = {}
+    for line in text.splitlines():
+        if "@tpu_custom_call" not in line:
+            continue
+        name = re.search(r'kernel_name = "(\w+)"', line).group(1)
+        operands, results = line.rsplit(" : ", 1)[1].split(" -> ")
+        assert name not in calls, name
+        calls[name] = (operands.count("tensor<"),
+                       re.findall(r"x([a-z]+\d+)>", results))
+    return calls
+
+
+_SIGNATURES = {"flash_fwd": (3, ["bf16", "f32"]), "flash_dq": (6, ["bf16"]),
+               "flash_dkv": (6, ["bf16", "bf16"])}
+
+
 def test_the_three_kernels_keep_the_signatures_the_benchmark_reads():
     """benchmark/layer_metrics/flash_attn_roofline_pct.py::kernel_kind tells
     the three Mosaic calls of a trace apart by operands and results: forward
     3 -> (out, lse f32), dQ 6 -> 1, dK/dV 6 -> 2. A traced run that does not
     show all three kinds reads the metric None."""
-    import re
+    assert _kernel_signatures(
+        _tpu_lowered_value_and_grad((1, 1024, 2, 64))) == _SIGNATURES
 
-    old, fa.INTERPRET = fa.INTERPRET, False
-    try:
-        x = jax.ShapeDtypeStruct((2, 1024, 64), jnp.bfloat16)
-        lowered = jax.jit(jax.value_and_grad(
-            lambda q, k, v: jnp.sum(fa.flash_attention_fwd(
-                q, k, v, causal=True, block_q=1024,
-                block_k=1024).astype(jnp.float32)),
-            argnums=(0, 1, 2))).trace(x.update(shape=(1, 1024, 2, 64)),
-                                      x.update(shape=(1, 1024, 2, 64)),
-                                      x.update(shape=(1, 1024, 2, 64))
-                                      ).lower(lowering_platforms=("tpu",))
-    finally:
-        fa.INTERPRET = old
-    calls = {}
-    for line in lowered.as_text().splitlines():
-        if "@tpu_custom_call" not in line:
-            continue
-        name = re.search(r'kernel_name = "(\w+)"', line).group(1)
-        operands, results = line.rsplit(" : ", 1)[1].split(" -> ")
-        calls[name] = (operands.count("tensor<"),
-                       re.findall(r"x([a-z]+\d+)>", results))
-    assert calls == {"flash_fwd": (3, ["bf16", "f32"]),
-                     "flash_dq": (6, ["bf16"]),
-                     "flash_dkv": (6, ["bf16", "bf16"])}
+
+def test_heads_that_pack_reach_the_kernels_with_no_transpose_and_no_pad():
+    """Two 64-wide heads are one 128-lane block of the (B, S, H*d) array the
+    model holds: around the three calls the lowered program reshapes and
+    nothing else (no ``transpose`` into (B*H, S, d), no ``pad`` of d to 128
+    lanes; delta is the kernels' own, so no reduction either). Three heads
+    of 16 are a block each, padded to 128 lanes: pads, and still no
+    transpose."""
+    packed = _tpu_lowered_value_and_grad((1, 1024, 2, 64))
+    assert "stablehlo.transpose" not in packed
+    assert "stablehlo.pad" not in packed
+    assert "stablehlo.reduce" not in packed.replace(
+        "stablehlo.reduce_precision", "")[packed.index("@tpu_custom_call"):
+                                          packed.rindex("@tpu_custom_call")]
+    padded = _tpu_lowered_value_and_grad((1, 1024, 3, 16))
+    assert "stablehlo.transpose" not in padded
+    assert "stablehlo.pad" in padded
+    assert _kernel_signatures(padded) == _SIGNATURES
 
 
 def _brute_force_share(sq, sk, bq, bk, band):
@@ -404,7 +543,7 @@ def test_flash_plan_counts_what_the_kernels_run(monkeypatch):
         assert plan["sub_block"] == 256
         assert fa.flash_plan(1024, 1024, False, bq, bk) == {
             "tiles": plan["tiles"], "sub_block": None, "executed_share": 1.0,
-            "stats_bytes": 4096}
+            "heads_per_block": 1, "io_bytes": 262144, "stats_bytes": 4096}
     for band in (128, 256):
         monkeypatch.setattr(fa, "SUB_BLOCK", band)
         for sq, sk, bq, bk in [(1024, 1024, 1024, 1024), (1000, 1000, 512, 512),
@@ -417,9 +556,9 @@ def test_flash_plan_counts_what_the_kernels_run(monkeypatch):
             assert plan["executed_share"] == pytest.approx(
                 _brute_force_share(sq, sk, bq, bk, band)), (band, sq, sk, bq, bk)
     monkeypatch.setattr(fa, "SUB_BLOCK", 128)
-    assert fa.flash_plan(1024, 1024, True, 1024, 1024) == {
+    assert fa.flash_plan(1024, 1024, True, 1024, 1024, 16, 64) == {
         "tiles": [1, 1], "sub_block": 128, "executed_share": 0.5625,
-        "stats_bytes": 4096}
+        "heads_per_block": 2, "io_bytes": 131072, "stats_bytes": 4096}
 
 
 @pytest.mark.parametrize("call", [
@@ -427,22 +566,55 @@ def test_flash_plan_counts_what_the_kernels_run(monkeypatch):
     (700, 700, False, 1024, 1024), (1536, 1536, False, 1024, 1024),
     (640, 1000, True, 256, 512)], ids=lambda c: "x".join(map(str, c[:2])))
 def test_flash_plan_says_what_the_row_statistics_occupy(call):
-    """``stats_bytes``: a head's logsumexp (forward) or logsumexp + delta
-    (backward) in HBM as the kernels lay them out, a float32 a padded row in
-    whole 128-lane tiles; the kernel's own result has that shape. A
-    (.., S, 1) column would read 128 times as much."""
+    """``stats_bytes``: a head's logsumexp in HBM as the kernels lay it out,
+    a float32 a padded row in whole 128-lane tiles, the rows of a block's
+    heads together ((.., 2, S) tiles as T(2,128): no sublane is padded);
+    the kernel's own result has that shape. It is the one statistic that
+    crosses HBM (delta is the backward kernels' own). A (.., S, 1) column
+    would read 128 times as much."""
     sq, sk, causal, bq, bk = call
     sp_q = fa._geometry(sq, sk, bq, bk, fa.SUB_BLOCK if causal else None)[2]
     lanes = -(-sp_q // 128) * 128
-    assert fa.flash_plan(*call)["stats_bytes"] == 4 * lanes
-    assert fa.flash_plan(*call, kind="bwd")["stats_bytes"] == 8 * lanes
+    for heads, d, hpb in [(4, 32, 4), (6, 64, 2), (3, 128, 1), (3, 32, 1)]:
+        assert fa.flash_plan(*call, heads, d)["stats_bytes"] == 4 * lanes
+        x = jax.ShapeDtypeStruct((3, sq, heads, d), jnp.bfloat16)
+        k = jax.ShapeDtypeStruct((3, sk, heads, d), jnp.bfloat16)
+        out, lse = jax.eval_shape(lambda q, k: fa._flash_fwd_bshd(
+            q, k, k, causal=causal, scale=1.0, block_q=bq, block_k=bk), x, k)
+        assert out.shape == x.shape
+        assert (lse.shape, lse.dtype) == ((3, heads // hpb, hpb, sp_q),
+                                          jnp.float32)
+        assert fa._hbm_bytes(lse.shape) == 3 * heads * 4 * lanes
     assert fa._hbm_bytes((1, sp_q, 1)) == 128 * 4 * -(-sp_q // 8) * 8
-    x = jax.ShapeDtypeStruct((3, sq, 32), jnp.bfloat16)
-    k = jax.ShapeDtypeStruct((3, sk, 32), jnp.bfloat16)
-    _out, lse = jax.eval_shape(lambda q, k: fa._flash_fwd_bhsd(
-        q, k, k, causal=causal, scale=1.0, block_q=bq, block_k=bk), x, k)
-    assert (lse.shape, lse.dtype) == ((3, 1, sp_q), jnp.float32)
-    assert fa._hbm_bytes(lse.shape) == 3 * 4 * lanes
+    assert fa._hbm_bytes((1, 3, sp_q)) == 8 * 4 * lanes
+
+
+@pytest.mark.parametrize("heads,d,itemsize,hpb,io_bytes", [
+    (16, 64, 2, 2, 131072),     # the GPT-2 cells': half of the padded 262144
+    (32, 64, 2, 2, 131072), (8, 128, 2, 1, 262144), (4, 256, 2, 1, 524288),
+    (8, 32, 2, 4, 65536), (8, 32, 4, 4, 131072),
+    # heads that do not tile 128 lanes: each padded to a block of its own
+    (3, 16, 2, 1, 262144), (3, 64, 2, 1, 262144), (2, 32, 4, 1, 524288),
+    (5, 96, 2, 1, 262144), (2, 192, 2, 1, 524288)])
+def test_flash_plan_says_how_the_heads_share_a_lane_block(heads, d, itemsize,
+                                                          hpb, io_bytes):
+    """``heads_per_block`` and ``io_bytes`` (a head's q in HBM as the kernels
+    read it, S 1024) follow from the head count and width alone."""
+    plan = fa.flash_plan(1024, 1024, True, 2048, 2048, heads, d, itemsize)
+    assert (plan["heads_per_block"], plan["io_bytes"]) == (hpb, io_bytes)
+    assert fa._head_layout(heads, d) == (hpb, -(-d // 128) * 128
+                                         if hpb == 1 else d)
+
+
+@pytest.mark.parametrize("heads,d,probe", [
+    (16, 64, 8), (9, 64, 7), (3, 16, 3), (12, 16, 7), (10, 32, 7),
+    (12, 32, 8), (32, 8, 16), (16, 128, 8), (5, 96, 5)])
+def test_the_autotuners_probe_lies_as_the_callers_heads_do(heads, d, probe):
+    """``_tuned_blocks`` caches a winner under the caller's
+    ``heads_per_block``, so the few heads it times lie the same way: an odd
+    count of 64-wide heads is one padded head a block in the probe too."""
+    assert fa._probe_heads(heads, d) == probe
+    assert fa._head_layout(probe, d) == fa._head_layout(heads, d)
 
 
 def test_no_causal_tile_longer_than_a_band_runs_whole():
@@ -466,17 +638,21 @@ def test_no_causal_tile_longer_than_a_band_runs_whole():
     assert fa._geometry(700, 700, 1024, 1024) == (700, 700, 700, 700)
 
 
-def test_a_non_causal_call_runs_one_block_a_tile_whatever_the_band(monkeypatch):
+@pytest.mark.parametrize("heads,d,hpb", [(2, 64, 2), (2, 128, 1), (4, 32, 4),
+                                         (3, 64, 1)])
+def test_a_non_causal_call_runs_one_block_a_tile_whatever_the_band(
+        monkeypatch, heads, d, hpb):
     """The bands are the causal mask's: a non-causal kernel is traced to
-    the same program at any SUB_BLOCK, two matmuls a forward tile."""
-    x = jax.ShapeDtypeStruct((2, 1024, 64), jnp.bfloat16)
-    lse = jax.ShapeDtypeStruct((2, 1, 1024), jnp.float32)
+    the same program at any SUB_BLOCK, two matmuls a forward tile: the body
+    of a head, traced once, in a loop over the block's heads."""
+    x = jax.ShapeDtypeStruct((1, 1024, heads, d), jnp.bfloat16)
+    lse = jax.ShapeDtypeStruct((1, heads // hpb, hpb, 1024), jnp.float32)
 
     def programs():
         kw = dict(causal=False, scale=0.125, block_q=512, block_k=1024)
-        return (str(jax.make_jaxpr(lambda q, k, v: fa._flash_fwd_bhsd(
+        return (str(jax.make_jaxpr(lambda q, k, v: fa._flash_fwd_bshd(
                     q, k, v, **kw))(x, x, x)),
-                str(jax.make_jaxpr(lambda *a: fa._flash_bwd_bhsd(
+                str(jax.make_jaxpr(lambda *a: fa._flash_bwd_bshd(
                     *a, **kw))(x, x, x, x, lse, x)))
 
     monkeypatch.setattr(fa, "SUB_BLOCK", 128)
@@ -484,4 +660,10 @@ def test_a_non_causal_call_runs_one_block_a_tile_whatever_the_band(monkeypatch):
     monkeypatch.setattr(fa, "SUB_BLOCK", 256)
     assert (fwd, bwd) == programs()
     assert fwd.count("dot_general") == 2 and bwd.count("dot_general") == 7
+    # the loops over the heads: the forward's tile and its last step's
+    # normalisation, dQ's tile, dK/dV's tile
+    assert (fwd.count("scan["), bwd.count("scan[")) == (
+        (2, 2) if hpb > 1 else (0, 0))
     assert "concatenate" not in fwd + bwd
+    # only a head that is no whole share of a lane block is padded
+    assert (" pad[" in fwd) == (d % 128 != 0 and hpb == 1)

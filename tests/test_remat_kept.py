@@ -185,7 +185,8 @@ def test_a_block_keeps_its_arguments_and_the_two_named_arrays(
         one_device_mesh):
     """``saved_residuals`` is not public: only shapes, dtypes and names are
     pinned. The logsumexp is kept as the kernel writes it, a lane-dense row
-    a head, (BH, 1, S): no (BH, S, 1) column exists to keep."""
+    a head, the heads of a 128-lane block together, (B, H // hpb, hpb, S):
+    no (.., S, 1) column exists to keep."""
     from jax._src.ad_checkpoint import saved_residuals
     model = gpt()
     blk = model.gpt.blocks[0]
@@ -202,7 +203,9 @@ def test_a_block_keeps_its_arguments_and_the_two_named_arrays(
     (out, out_what), (lse, lse_what) = sorted(made, key=lambda m: -m[0].ndim)
     assert out.shape == (BATCH, SEQ, HEADS, WIDTH // HEADS)
     assert "flash_out" in out_what or "reduce_precision" in out_what
-    assert (lse.shape, lse.dtype) == ((BATCH * HEADS, 1, SEQ), jnp.float32)
+    hpb = fa._head_layout(HEADS, WIDTH // HEADS)[0]
+    assert (lse.shape, lse.dtype) == ((BATCH, HEADS // hpb, hpb, SEQ),
+                                      jnp.float32)
     assert "flash_lse" in lse_what
     assert (BATCH, SEQ, WIDTH) in [aval.shape for aval, _what in kept]
 

@@ -171,12 +171,14 @@ def test_a_kernels_wrapper_stamps_its_plan_on_the_trace_entry(record):
     entries = record()
     (traced,) = [e for e in named(entries, "compile.trace")
                  if e[5]["program"] == "step"]
-    plan = dict(fa.flash_plan(512, 512, True, 512, 512))
+    plan = dict(fa.flash_plan(512, 512, True, 512, 512, 2, 32, 4))
     assert plan["executed_share"] == 0.75 and plan["sub_block"] == 256
+    # two float32 heads of 32 fill no 128-lane block: each its own, padded
+    assert (plan["heads_per_block"], plan["io_bytes"]) == (1, 512 * 128 * 4)
     assert traced[5]["flash_fwd[512x512,causal,512x512]"] == dict(
         plan, calls=2)
     assert traced[5]["flash_bwd[512x512,causal,512x512]"] == dict(
-        fa.flash_plan(512, 512, True, 512, 512, kind="bwd"), calls=2)
+        plan, calls=2)
     assert not [e for e in entries if "nobody" in (e[5] or {})]
 
 

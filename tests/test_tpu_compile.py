@@ -544,6 +544,8 @@ def test_lfm2_train_step_compiles_with_the_grouped_matmuls(one_chip,
 
 
 GPT2_STEP = dict(layers=2, heads=16, batch=8, seq=1024)
+#: the logsumexp of a layer's call there: (B, H / 2, 2, S)
+_LSE = "8,8,2,1024"
 
 
 @pytest.fixture(scope="module")
@@ -564,15 +566,44 @@ def gpt2_step(four_chips):
 def test_gpt2_train_step_runs_each_forward_kernel_once(gpt2_step):
     """The policy's names reach through the jitted kernel wrapper in the
     TPU's lowering, so the step holds one forward kernel a layer, and the
-    logsumexp crosses to the backward as (BH, 1, S) rows, a lane tile of
-    128 values (``T(1,128)``)."""
+    logsumexp crosses to the backward as (B, H / 2, 2, S) rows, the two
+    heads of a lane block together, tiled as they are (``T(2,128)``: 4 bytes
+    a value, which is what ``flash_plan`` says)."""
+    from paddle_tpu.ops.pallas import flash_attention as fa
+
     g = GPT2_STEP
     assert _flash_calls(gpt2_step) == dict.fromkeys(
         ("flash_fwd", "flash_dq", "flash_dkv"), g["layers"])
     rows = re.findall(
-        rf"= f32\[{g['batch'] * g['heads']},1,{g['seq']}\]{{2,1,0:T\(1,128\)}} ",
-        gpt2_step)
+        rf"= f32\[{_LSE}\]{{3,2,1,0:T\(2,128\)}} ", gpt2_step)
     assert len(rows) >= g["layers"], "the logsumexp is not kept as rows"
+    assert fa.flash_plan(g["seq"], g["seq"], True, 2048, 2048, g["heads"],
+                         64) == {
+        "tiles": [1, 1], "sub_block": 256, "executed_share": 0.625,
+        "heads_per_block": 2, "io_bytes": g["seq"] * 64 * 2,
+        "stats_bytes": g["seq"] * 4}
+
+
+def test_gpt2_train_step_lays_nothing_out_around_the_flash_calls(gpt2_step):
+    """The kernels take their blocks from the (B, S, H*d) array the
+    projections make and take: the compiled step holds no ``pad``, no 4-D
+    (B, H, S, d) or (B, S, H, d) array and no transpose of one, and no
+    reduction for delta (the backward kernels' own). What is left of XLA's
+    layout work on a layer's attention arrays is its own choice for the kept
+    ``out``: written S-minor for the out-projection's products, copied back
+    for the two backward kernels (two copies a layer where there were 21,
+    and 7 pads)."""
+    g = GPT2_STEP
+    b, s, h = g["batch"], g["seq"], g["heads"]
+    found = _instructions(gpt2_step)
+    assert not [n for n, (shape, op, _o) in found.items() if op == "pad"
+                and "bf16" in shape]
+    four_d = rf"bf16\[{b},(?:{s},{h}|{h},{s}),64\]"
+    assert not re.findall(four_d, gpt2_step)
+    big = rf"(?:bf16|f32)\[{b},{s},{h * 64}\]"
+    copies = [n for n, (shape, op, _o) in found.items()
+              if op == "copy" and re.search(big, shape)]
+    assert len(copies) <= 2 * g["layers"], copies
 
 
 def _instructions(entry):
@@ -610,7 +641,7 @@ def test_gpt2_train_step_holds_the_row_statistics_as_rows(gpt2_step):
     for fwd in (n for n in found if re.fullmatch(r"flash_fwd(\.\d+)?", n)):
         reached, todo = set(), [
             u for u in users[fwd] if found[u][1] == "get-tuple-element"
-            and f"f32[{g['batch'] * g['heads']},1,{g['seq']}]" in found[u][0]]
+            and f"f32[{_LSE}]" in found[u][0]]
         assert len(todo) == 1, (fwd, todo)
         while todo:
             for user in users.get(todo.pop(), []):

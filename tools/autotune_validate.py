@@ -58,24 +58,24 @@ def main():
     dt = jnp.bfloat16
     rows = []
     for S in (1024, 2048, 8192, 32768):
-        bh = B * H if S <= 8192 else 4   # fit 32k on one chip
+        b, h = (B, H) if S <= 8192 else (1, 4)   # fit 32k on one chip
         qs, ks, vs = [], [], []
         for v in range(NVAR):
             kp = jax.random.key(100 + v)
-            qs.append(jax.random.normal(kp, (bh, S, D)).astype(dt))
+            qs.append(jax.random.normal(kp, (b, S, h, D)).astype(dt))
             ks.append(jax.random.normal(
-                jax.random.fold_in(kp, 1), (bh, S, D)).astype(dt))
+                jax.random.fold_in(kp, 1), (b, S, h, D)).astype(dt))
             vs.append(jax.random.normal(
-                jax.random.fold_in(kp, 2), (bh, S, D)).astype(dt))
+                jax.random.fold_in(kp, 2), (b, S, h, D)).astype(dt))
         scale = 1.0 / (D ** 0.5)
 
         for causal in (True, False):
-            kernel_flops = 4.0 * bh * S * S * D * (0.5 if causal else 1.0)
+            kernel_flops = 4.0 * b * h * S * S * D * (0.5 if causal else 1.0)
             reps = at.probe_reps(kernel_flops)
 
             def jfwd(bq, bk):
                 kern = functools.partial(
-                    fa._flash_fwd_bhsd, causal=causal, scale=scale,
+                    fa._flash_fwd_bshd, causal=causal, scale=scale,
                     block_q=bq, block_k=bk)
                 f = jax.jit(lambda q0, k0, v0: jax.lax.fori_loop(
                     0, reps, lambda _, q: kern(q, k0, v0)[0], q0))
@@ -84,13 +84,13 @@ def main():
             fdef = ((fa.CAUSAL_BLOCK,) * 2 if causal else
                     (fa.DEFAULT_BLOCK_Q, fa.DEFAULT_BLOCK_K))
             f0 = jax.jit(functools.partial(
-                fa._flash_fwd_bhsd, causal=causal, scale=scale,
+                fa._flash_fwd_bshd, causal=causal, scale=scale,
                 block_q=fdef[0], block_k=fdef[1]))
             outs, lses = zip(*(f0(qs[v], ks[v], vs[v]) for v in range(NVAR)))
 
             def jbwd(bq, bk):
                 kern = functools.partial(
-                    fa._flash_bwd_bhsd, causal=causal, scale=scale,
+                    fa._flash_bwd_bshd, causal=causal, scale=scale,
                     block_q=bq, block_k=bk)
                 f = jax.jit(lambda q0, k0, v0, o0, l0: jax.lax.fori_loop(
                     0, reps, lambda _, q: kern(q, k0, v0, o0, l0, o0)[0], q0))
@@ -109,12 +109,12 @@ def main():
                         print(f"{kind} S={S} causal={causal} tile={c}: "
                               f"FAILED {type(e).__name__}: {str(e)[:200]}")
                         continue
-                    plan = fa.flash_plan(S, S, causal, *c)
+                    plan = fa.flash_plan(S, S, causal, *c, h, D)
                     print(f"{kind} S={S:>6} causal={causal!s:5} "
                           f"tile={str(c):>12} {times[c] * 1e3:8.2f}m  "
                           f"grid={plan['tiles']} "
                           f"executed_share={plan['executed_share']:.4f}")
-                tuned = tuple(fa._tuned_blocks(kind, bh, S, S, D, dt, causal,
+                tuned = tuple(fa._tuned_blocks(kind, h, S, S, D, dt, causal,
                                                scale))
                 rows.append((kind, S, cdef, times[cdef], tuned,
                              times[tuned]))
