@@ -138,17 +138,21 @@ class _KernelChecks:
         self.passed[name] = round(max(errs), 5)
 
 
-def _attn_ref(q, k, v, scale):
+def _attn_ref(q, k, v, scale, window=None):
     """Plain causal attention over (1, S, H, d), f32 softmax: (out, lse),
     the logsumexp as the kernels lay it out, a row a head, the heads of a
-    lane block together: (1, H // hpb, hpb, S)."""
+    lane block together: (1, H // hpb, hpb, S). ``window``: a query sees its
+    own key and the ``window - 1`` before it."""
     import jax
     import jax.numpy as jnp
 
     from paddle_tpu.ops.pallas import flash_attention as fa
     s = jnp.einsum("bqhd,bkhd->bhqk", q, k).astype(jnp.float32) * scale
     n = s.shape[-1]
-    s = jnp.where(jnp.tril(jnp.ones((n, n), bool)), s, -1e30)
+    seen = jnp.tril(jnp.ones((n, n), bool))
+    if window is not None:
+        seen = seen & ~jnp.tril(jnp.ones((n, n), bool), k=-window)
+    s = jnp.where(seen, s, -1e30)
     p = jax.nn.softmax(s, axis=-1).astype(q.dtype)
     heads = q.shape[2]
     hpb = fa._head_layout(heads, q.shape[3])[0]
@@ -192,6 +196,29 @@ def _check_flash(checks, heads, seq, d):
             f"flash_dq_dkdv[S={seq},d={d}]({bq},{bk})",
             lambda *a, bq=bq, bk=bk: fa._flash_bwd_bshd(
                 *a, causal=True, scale=scale, block_q=bq, block_k=bk),
+            (q, k, v, ref_out, lse, g), ref_grads)
+    # the same kernels under a window of half the sequence, at the causal
+    # call's tile and at a quarter of the sequence (tiles under the band
+    # that are skipped, tiles its lower edge crosses)
+    window = seq // 2
+
+    @jax.jit
+    def windowed_references(q, k, v, g):
+        (out, lse), vjp = jax.vjp(
+            lambda *a: _attn_ref(*a, scale, window), q, k, v)
+        return out, lse, vjp((g, jnp.zeros_like(lse)))
+
+    ref_out, lse, ref_grads = windowed_references(q, k, v, g)
+    for tile in sorted({fa.CAUSAL_BLOCK, max(seq // 4, 8)}):
+        kw = dict(causal=True, scale=scale, block_q=tile, block_k=tile,
+                  window=window)
+        checks.check(
+            f"flash_fwd[S={seq},d={d},window={window}]({tile},{tile})",
+            lambda *a, kw=kw: fa._flash_fwd_bshd(*a, **kw),
+            (q, k, v), (ref_out, lse))
+        checks.check(
+            f"flash_dq_dkdv[S={seq},d={d},window={window}]({tile},{tile})",
+            lambda *a, kw=kw: fa._flash_bwd_bshd(*a, **kw),
             (q, k, v, ref_out, lse, g), ref_grads)
 
 

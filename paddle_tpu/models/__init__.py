@@ -3,9 +3,11 @@ reference uses for auto-parallel tests, test/auto_parallel/get_gpt_model.py).
 These are the BASELINE.md ladder configs: LeNet, ResNet, BERT, GPT, LLaMA,
 the Nemotron-H hybrid (Mamba-2 + attention + latent experts), the
 K-EXAONE decoder (window + full attention, SwiGLU experts), the
-DeepSeek-V3 decoder (latent attention, SwiGLU experts) and the LFM2-MoE
+DeepSeek-V3 decoder (latent attention, SwiGLU experts), the LFM2-MoE
 decoder (gated short convolutions + grouped-query attention, SwiGLU experts;
-trained, not served).
+trained, not served) and the SmallThinker decoder (window + rotary layers
+beside full NoPE layers, a router that reads the attention's input, ReGLU
+experts; trained, not served).
 """
 from .lenet import LeNet
 from .gpt import GPTConfig, GPTModel, GPTForCausalLM, gpt2_small, gpt2_medium
@@ -26,6 +28,8 @@ from .lfm2_moe import (Lfm2MoeConfig, Lfm2MoeForCausalLM, Lfm2MoeModel,
                        lfm2_moe_tiny)
 from .solar_open2 import (SolarOpen2Config, SolarOpen2ForCausalLM,
                           SolarOpen2Model, solar_open2_tiny)
+from .smallthinker import (SmallThinkerConfig, SmallThinkerForCausalLM,
+                           SmallThinkerModel, smallthinker_tiny)
 
 __all__ = [
     "LeNet", "GPTConfig", "GPTModel", "GPTForCausalLM",
@@ -46,4 +50,6 @@ __all__ = [
     "Lfm2MoeConfig", "Lfm2MoeModel", "Lfm2MoeForCausalLM", "lfm2_moe_tiny",
     "SolarOpen2Config", "SolarOpen2Model", "SolarOpen2ForCausalLM",
     "solar_open2_tiny",
+    "SmallThinkerConfig", "SmallThinkerModel", "SmallThinkerForCausalLM",
+    "smallthinker_tiny",
 ]

@@ -263,6 +263,18 @@ class Lfm2MoeBlock(nn.Layer):
             return h + out, load
 
 
+def export_expert_load(fresh, first_held: int):
+    """An epoch's load of a trained model's routed layers, (layers, E_held +
+    2) int64 on the host (``functional.experts.load_arrays`` a layer), to
+    the expert-load metrics."""
+    from ..distributed.fleet import moe as _moe
+    tokens = fresh[:, :-2]
+    worst = [float(t.max() / t.mean()) for t in tokens if t.sum() > 0]
+    _moe.stamp_expert_load(
+        tokens.sum(axis=0), first_held, fresh[:, -2].sum(),
+        fresh[:, -1].sum(), max(worst, default=None))
+
+
 class Lfm2MoeModel(nn.Layer):
     def __init__(self, cfg: Lfm2MoeConfig):
         super().__init__()
@@ -287,14 +299,7 @@ class Lfm2MoeModel(nn.Layer):
             (cfg.sparse_layers, hi - lo + 2), jnp.int32, self._export_load)}
 
     def _export_load(self, fresh):
-        """An epoch's load, (sparse layers, E_held + 2) int64 on the host,
-        to the expert-load metrics."""
-        from ..distributed.fleet import moe as _moe
-        tokens = fresh[:, :-2]
-        worst = [float(t.max() / t.mean()) for t in tokens if t.sum() > 0]
-        _moe.stamp_expert_load(
-            tokens.sum(axis=0), self.cfg.experts_held[0],
-            fresh[:, -2].sum(), fresh[:, -1].sum(), max(worst, default=None))
+        export_expert_load(fresh, self.cfg.experts_held[0])
 
     def forward(self, input_ids):
         with jax.named_scope("embed"):

@@ -1,10 +1,14 @@
 """Dropless routed experts for a chip that holds a SHARE of them.
 
-The router scores every expert of the model (sigmoid scores, a correction
-bias that only steers the choice, top-k, weights renormalised over the
-chosen and scaled: the DeepSeek-V3 / Nemotron-H router); the chip computes
-the part of the result that the experts it holds, ``[lo, lo + E_held)``,
-give for the tokens routed to them. No capacity, no dropped token; what the
+The router scores every expert of the model (``sigmoid_topk_route``: sigmoid
+scores, a correction bias that only steers the choice, top-k, weights
+renormalised over the chosen and scaled, the DeepSeek-V3 / Nemotron-H
+router; ``softmax_topk_route``: the ``k`` largest logits and a softmax over
+those ``k``, SmallThinker's); the chip computes the part of the result that
+the experts it holds, ``[lo, lo + E_held)``, give for the tokens routed to
+them. The routing is the caller's to make: from the expert layer's own
+input, or from another tensor of the block (SmallThinker routes from the
+attention's input). No capacity, no dropped token; what the
 absent experts would add is another chip's part (on one chip: left out).
 
 ``distributed.fleet.MoELayer`` is the GShard layer of the reference API
@@ -40,8 +44,11 @@ import jax.numpy as jnp
 from ...core import dispatch
 from ...core.tensor import Tensor, as_tensor
 
-__all__ = ["sigmoid_topk_route", "held_experts_relu2",
+__all__ = ["sigmoid_topk_route", "softmax_topk_route", "held_experts_relu2",
            "held_experts_swiglu"]
+
+#: what a gated expert applies to its gate: ``D (act(G x) * U x)``
+GATE_ACTIVATIONS = {"silu": jax.nn.silu, "relu": jax.nn.relu}
 
 #: rows from which a call takes the grouped form: the crossover measured on
 #: a v5e (PR 39; 8 held experts of 2048 x 1792 in bfloat16, top-4 of 32,
@@ -80,6 +87,17 @@ def route_arrays(u, gate, bias, k, scale, normalize, norm_eps=1e-20):
     return idx.astype(jnp.int32), w * scale
 
 
+def softmax_route_arrays(u, gate, k):
+    """``u`` (n, hidden), ``gate`` (hidden, E): float32 logits at full
+    matmul precision, the ``k`` largest chosen, a softmax over those ``k``.
+    Returns ``(idx (n, k) int32, weights (n, k) float32)``."""
+    f32 = jnp.float32
+    logits = jnp.dot(u.astype(f32), gate.astype(f32),
+                     precision=jax.lax.Precision.HIGHEST)
+    top, idx = jax.lax.top_k(logits, k)
+    return idx.astype(jnp.int32), jax.nn.softmax(top, axis=1)
+
+
 def combine_arrays(idx, w, lo, held, valid=None):
     """``(n, E_held)`` float32: the weight each held expert's output gets
     in each token's sum (0 where the token did not choose it)."""
@@ -108,22 +126,22 @@ def load_arrays(idx, lo, held, valid=None):
                             jnp.sum(live, dtype=jnp.int32)[None]])
 
 
-def experts_arrays(x, combine, mats):
+def experts_arrays(x, combine, mats, activation="silu"):
     """``sum_e combine[:, e] * E_e(x)`` over the held experts: the product
     over ALL of them with each token's unchosen experts weighted 0. The
     expert's form follows from the matrices it is made of: two, ``(w1,
     w2)``, give ``relu(x W1_e)^2 W2_e``; three, ``(gate, up, down)``, the
-    SwiGLU ``(silu(x G_e) * x U_e) D_e``. ``x`` (n, in), ``combine``
-    (n, E_held), first matrices (E_held, in, width), last (E_held, width,
-    out)."""
+    gated ``(act(x G_e) * x U_e) D_e`` with ``activation`` ``silu`` (SwiGLU)
+    or ``relu`` (ReGLU). ``x`` (n, in), ``combine`` (n, E_held), first
+    matrices (E_held, in, width), last (E_held, width, out)."""
     f32 = jnp.float32
     *first, last = mats
     h = jnp.einsum("nl,elf->enf", x, first[0], preferred_element_type=f32)
     if len(first) == 1:
         h = jnp.square(jax.nn.relu(h))
     else:
-        h = jax.nn.silu(h) * jnp.einsum("nl,elf->enf", x, first[1],
-                                        preferred_element_type=f32)
+        h = GATE_ACTIVATIONS[activation](h) * jnp.einsum(
+            "nl,elf->enf", x, first[1], preferred_element_type=f32)
     h = h * combine.T[:, :, None]
     return jnp.einsum("enf,efl->nl", h.astype(x.dtype), last,
                       preferred_element_type=f32).astype(x.dtype)
@@ -170,7 +188,8 @@ _permute.defvjp(lambda a, perm, inv: (a[perm], (perm, inv)),
                 lambda res, g: (g[res[1]], None, None))
 
 
-def grouped_experts_arrays(x, idx, w, mats, lo, valid=None):
+def grouped_experts_arrays(x, idx, w, mats, lo, valid=None,
+                           activation="silu"):
     """``experts_arrays``'s sum computed over the pairs that landed here.
 
     The ``n * k`` (token, choice) pairs are sorted by held expert (a stable
@@ -211,7 +230,7 @@ def grouped_experts_arrays(x, idx, w, mats, lo, valid=None):
     if len(first) == 1:
         h = jnp.square(jax.nn.relu(h))
     else:
-        h = jax.nn.silu(h) * grouped(xs, first[1])
+        h = GATE_ACTIVATIONS[activation](h) * grouped(xs, first[1])
     y = grouped((h * ws[:, None]).astype(x.dtype), last)
     with jax.named_scope("moe.group"):
         # rows past the last group belong to no expert: whatever the kernel
@@ -238,7 +257,21 @@ def sigmoid_topk_route(u, gate, bias, k, scale=1.0, normalize=True,
                "normalize": bool(normalize), "norm_eps": float(norm_eps)})
 
 
-def _held_experts(op, x, idx, weights, mats, lo, valid):
+def softmax_topk_route(u, gate, k, name=None):
+    """Top-k router with a softmax over the CHOSEN logits, in float32:
+    choose the ``k`` experts with the largest ``u @ gate``; their weights
+    are ``softmax`` of those ``k`` logits (they sum to 1, so a
+    renormalisation over the chosen changes nothing). ``u`` (n, hidden).
+    Returns ``(idx (n, k) int32, weights (n, k) float32)``; the weights
+    carry gradients to ``u`` and ``gate``."""
+    def f(ua, ga, **_attrs):
+        return softmax_route_arrays(ua, ga, k)
+
+    return dispatch.call("softmax_topk_route", f, [_t(u), _t(gate)],
+                         attrs={"k": int(k)})
+
+
+def _held_experts(op, x, idx, weights, mats, lo, valid, activation="silu"):
     """The held experts' part of a routed sum, dropless, for an expert
     made of ``mats`` (``experts_arrays`` has the forms), in the form the
     call's rows ask for (``takes_grouped_form``). Under O1 autocast the
@@ -258,11 +291,15 @@ def _held_experts(op, x, idx, weights, mats, lo, valid):
         if level == "O1":
             xa, ms = xa.astype(amp_dtype), [m.astype(amp_dtype) for m in ms]
         if grouped:
-            return grouped_experts_arrays(xa, ia, wa, ms, lo, va)
-        return experts_arrays(xa, combine_arrays(ia, wa, lo, held, va), ms)
+            return grouped_experts_arrays(xa, ia, wa, ms, lo, va, activation)
+        return experts_arrays(xa, combine_arrays(ia, wa, lo, held, va), ms,
+                              activation)
 
+    attrs = {"lo": int(lo)}
+    if activation != "silu":
+        attrs["activation"] = activation
     return dispatch.call(
-        op, f, inputs, attrs={"lo": int(lo)},
+        op, f, inputs, attrs=attrs,
         differentiable_mask=[True, False, True] + [True] * len(mats)
         + [False] * (valid is not None))
 
@@ -280,9 +317,13 @@ def held_experts_relu2(x, idx, weights, w1, w2, lo=0, valid=None,
 
 
 def held_experts_swiglu(x, idx, weights, w_gate, w_up, w_down, lo=0,
-                        valid=None, name=None):
-    """As ``held_experts_relu2`` for SwiGLU experts: ``sum_j weights[j] *
-    D_e (silu(G_e x) * U_e x)``. ``w_gate`` / ``w_up`` (E_held, hidden,
+                        valid=None, name=None, activation="silu"):
+    """As ``held_experts_relu2`` for gated experts: ``sum_j weights[j] *
+    D_e (act(G_e x) * U_e x)``, ``activation`` ``silu`` (SwiGLU, the
+    default) or ``relu`` (ReGLU). ``w_gate`` / ``w_up`` (E_held, hidden,
     width), ``w_down`` (E_held, width, hidden). Returns (n, hidden)."""
+    if activation not in GATE_ACTIVATIONS:
+        raise ValueError(f"activation {activation!r} is none of "
+                         f"{sorted(GATE_ACTIVATIONS)}")
     return _held_experts("held_experts_swiglu", x, idx, weights,
-                         (w_gate, w_up, w_down), lo, valid)
+                         (w_gate, w_up, w_down), lo, valid, activation)

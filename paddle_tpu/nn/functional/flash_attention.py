@@ -59,7 +59,7 @@ def _use_pallas(seq_len=None, head_dim=None, dtype=None, causal=True):
     return True
 
 
-def _flash_pallas(q, k, v, causal):
+def _flash_pallas(q, k, v, causal, window=None):
     """The Pallas kernel over (B, S, H, D) arrays, partitioned by hand
     where it has to be. GSPMD cannot partition a Mosaic kernel ("wrap
     the call in a shard_map"), so under a multi-device mesh the call
@@ -73,7 +73,8 @@ def _flash_pallas(q, k, v, causal):
     from ...distributed.shard_map_compat import shard_map
     from ...ops.pallas.flash_attention import flash_attention_fwd
 
-    kernel = functools.partial(flash_attention_fwd, causal=causal)
+    kernel = functools.partial(flash_attention_fwd, causal=causal,
+                               window=window)
     mesh = mesh_mod.get_mesh()
     if mesh.size == 1 or jax.sharding.get_abstract_mesh().manual_axes:
         return kernel(q, k, v)
@@ -144,8 +145,10 @@ def _tuned_attn_impl(seq_len, head_dim, dtype, causal):
 
 
 def _sdpa_xla(q, k, v, bias=None, causal=False, dropout_p=0.0, key=None,
-              scale=None):
-    """Reference-path attention in BSHD layout; fp32 softmax accumulator."""
+              scale=None, window=None):
+    """Reference-path attention in BSHD layout; fp32 softmax accumulator.
+    ``window`` (with ``causal``): a query sees its own key and the ``window
+    - 1`` before it."""
     sc = scale if scale is not None else 1.0 / math.sqrt(q.shape[-1])
     qt = jnp.einsum("bshd,bthd->bhst", q, k) * sc
     logits = qt.astype(jnp.float32)
@@ -154,6 +157,8 @@ def _sdpa_xla(q, k, v, bias=None, causal=False, dropout_p=0.0, key=None,
     if causal:
         s, t = logits.shape[-2], logits.shape[-1]
         mask = jnp.tril(jnp.ones((s, t), bool), k=t - s)
+        if window is not None:
+            mask = mask & ~jnp.tril(jnp.ones((s, t), bool), k=t - s - window)
         logits = jnp.where(mask, logits, -1e30)
     probs = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
     if dropout_p > 0.0 and key is not None:
@@ -164,8 +169,17 @@ def _sdpa_xla(q, k, v, bias=None, causal=False, dropout_p=0.0, key=None,
 
 def flash_attention(query, key, value, dropout=0.0, causal=False,
                     return_softmax=False, fixed_seed_offset=None, rng_name="",
-                    training=True, name=None):
+                    training=True, name=None, window=None):
+    """``softmax(q k^T / sqrt(d)) v`` over (batch, seq, heads, head_dim)
+    arrays (reference ``flash_attention``; returns ``(out, None)``).
+    ``window`` (with ``causal``): sliding-window attention, query ``i`` sees
+    key ``j`` iff ``0 <= i - j < window``: its own key and the ``window - 1``
+    before it. The flash kernels neither run nor fetch the tiles wholly
+    outside that band; a window no shorter than the sequence is the causal
+    call."""
     q, k, v = _t(query), _t(key), _t(value)
+    if window is not None and (not causal or window < 1):
+        raise ValueError("a window is a causal call's, and at least 1")
     drop_key = None
     if dropout > 0.0 and training:
         from ...core.generator import next_key
@@ -175,12 +189,13 @@ def flash_attention(query, key, value, dropout=0.0, causal=False,
                    causal) and dropout == 0.0:
         out = dispatch.call(
             "flash_attention",
-            functools.partial(_flash_pallas, causal=causal), [q, k, v])
+            functools.partial(_flash_pallas, causal=causal, window=window),
+            [q, k, v])
     else:
         def f(qa, ka, va):
             return _sdpa_xla(qa, ka, va, causal=causal,
                              dropout_p=dropout if training else 0.0,
-                             key=drop_key)
+                             key=drop_key, window=window)
         out = dispatch.call("flash_attention", f, [q, k, v])
     return out, None
 

@@ -270,8 +270,14 @@ DEVICE_SCOPES: Dict[str, str] = {
     "embed": "the token embedding's gather (and a learned position table)",
     "attn": "a softmax-attention operator: projections, q/k norms, rotary "
             "angles, the flash kernels, the output projection",
-    "attn.full": "served: a full-attention layer that pages its K/V "
-                 "(projections, cache write, scores, the output projection)",
+    "attn.full": "a full-attention layer of a model that has windowed ones "
+                 "too: served, it pages its K/V (projections, cache write, "
+                 "scores, the output projection); trained (SmallThinker), "
+                 "the causal flash kernels over every key",
+    "attn.window": "a sliding-window attention layer: served, a ring of "
+                   "window rows a lane; trained (SmallThinker), the flash "
+                   "kernels with a window (projections, rotary embedding, "
+                   "the K/V repeat, the kernels, the output projection)",
     "attn.full.gate": "Solar Open 2's output gate: sigmoid of its own "
                       "projection times the attention's output",
     "attn.linear": "served: a delta-rule linear-attention layer, all of it "

@@ -22,6 +22,13 @@ paddle/phi/ops/yaml/ops.yaml:1635). Design:
   whatever the tile. The band body runs once a head of the block, each head
   with its own max, sum and accumulator. Also emits the per-row logsumexp
   (the FA2 "L" residual) for backward.
+* window — a causal call may be sliding-window attention: query i sees key
+  j iff 0 <= i - j < window. A tile wholly under the band is skipped like
+  one above the diagonal and, since its index map names the block of the
+  nearest tile inside the band, not fetched either; a tile the band's lower
+  edge crosses gets a second masked run of keys, at its low end; the tiles
+  between the two edges run unmasked. All three kernels; a call without a
+  window (or with one no shorter than the keys) is built as it was.
 * backward — the FA2 recompute strategy, O(S·d) memory: residuals are only
   (q, k, v, out, lse); each backward tile recomputes p = exp(qk·scale−lse)
   on the fly. Two kernels: dQ iterates kv innermost accumulating
@@ -242,15 +249,22 @@ def _tuned_blocks(kind, heads, s_q, s_k, d, dtype, causal, scale):
                                       jnp.dtype(dtype).itemsize)))
 
 
-def _causal_run(q_idx, kv_idx, block_q, block_k, offset):
-    """Tile intersects the bottom-right-aligned causal region."""
-    return kv_idx * block_k <= q_idx * block_q + (block_q - 1) + offset
+def _causal_run(q_idx, kv_idx, block_q, block_k, offset, window=None):
+    """Tile intersects the bottom-right-aligned causal region and, with a
+    ``window``, the band under it: its last key is one the tile's first
+    query still sees."""
+    run = kv_idx * block_k <= q_idx * block_q + (block_q - 1) + offset
+    if window is not None:
+        run = run & (kv_idx * block_k + (block_k - 1)
+                     > q_idx * block_q + offset - window)
+    return run
 
 
 def _tile_mask(q_idx, kv_idx, block_q, block_k, seq_k, causal, offset,
-               corner=None, keys_axis=1):
+               corner=None, keys_axis=1, window=None):
     """Mask of a tile's scores: the key exists and, causal, the query sees
-    it. ``corner`` (r0, c0, rows, cols): of that part of the tile only.
+    it (with a ``window``: is one of the ``window`` keys up to its own).
+    ``corner`` (r0, c0, rows, cols): of that part of the tile only.
     ``keys_axis`` 0: of the transposed scores (keys x rows)."""
     r0, c0, rows, cols = corner or (0, 0, block_q, block_k)
     shape = (rows, cols) if keys_axis else (cols, rows)
@@ -261,6 +275,8 @@ def _tile_mask(q_idx, kv_idx, block_q, block_k, seq_k, causal, offset,
     mask = k_pos < seq_k
     if causal:
         mask = mask & (q_pos + offset >= k_pos)
+    if window is not None:
+        mask = mask & (q_pos + offset < k_pos + window)
     return mask
 
 
@@ -271,12 +287,15 @@ def _at(base, off):
 # --------------------------------------------------------------------------
 # The work inside a causal tile. Query row r of a tile sees its key c iff
 # r - c >= d0, d0 = kv_idx*block_k - (seq_k - seq_q) - q_idx*block_q, and
-# c < kv_valid (the keys of the tile that exist). A tile's work is a static
-# list of BLOCKS (r0, r1, c1, mc0, guard): the scores of the band of query
-# rows [r0, r1) against keys [0, c1), the keys one of its rows sees; only
-# keys [mc0, c1) can hold a masked score and get the mask (mc0 == c1: none
-# does); ``guard``: a row of the band may see no key of the tile (the
-# forward's fully-masked-row guard).
+# c < kv_valid (the keys of the tile that exist); under a ``window`` also
+# iff r - c <= w0 = d0 + window - 1 (the query's own key and the window - 1
+# before it). A tile's work is a static list of BLOCKS (r0, r1, c1, mc0,
+# guard, c0, mc1): the scores of the band of query rows [r0, r1) against
+# keys [c0, c1), the keys one of its rows sees; only keys [c0, mc1) (the
+# window's lower edge) and [mc0, c1) (the diagonal, the tail) can hold a
+# masked score and get the mask, c0 <= mc1 <= mc0 <= c1 (an empty range:
+# none does; without a window c0 = mc1 = 0); ``guard``: a row of the band
+# may see no key of the tile (the forward's fully-masked-row guard).
 # --------------------------------------------------------------------------
 def _floor_to(x, m):
     return x // m * m
@@ -286,7 +305,7 @@ def _ceil_to(x, m):
     return -(-x // m) * m
 
 
-def _tile_blocks(d0, block_q, block_k, kv_valid, band):
+def _tile_blocks(d0, block_q, block_k, kv_valid, band, window=None):
     """Blocks of the causal tile at ``d0``: bands of ``band`` query rows,
     their keys cut at the lane width (a side no longer than its unit is one
     piece; a longer one is whole units, ``_geometry``)."""
@@ -296,11 +315,30 @@ def _tile_blocks(d0, block_q, block_k, kv_valid, band):
         r1 = r0 + band
         # keys SOME row sees: c <= r1 - 1 - d0; EVERY row: c <= r0 - d0
         c1 = min(max(_ceil_to(min(r1 - d0, kv_valid), lanes), 0), block_k)
-        if c1:
-            mc0 = min(max(_floor_to(min(r0 - d0 + 1, kv_valid), lanes), 0),
-                      c1)
-            blocks.append((r0, r1, c1, mc0, r0 < d0))
+        mc0 = min(max(_floor_to(min(r0 - d0 + 1, kv_valid), lanes), 0), c1)
+        c0 = mc1 = 0
+        guard = r0 < d0
+        if window is not None:
+            # keys SOME row sees: c >= r0 - w0; EVERY row: c >= r1 - 1 - w0
+            w0 = d0 + window - 1
+            c0 = min(max(_floor_to(r0 - w0, lanes), 0), c1)
+            mc1 = min(max(_ceil_to(r1 - 1 - w0, lanes), c0), c1)
+            if mc1 > mc0:       # the two edges meet: the mask everywhere
+                mc0 = mc1 = c0
+            guard = guard or r1 - 1 - w0 >= kv_valid
+        if c1 > c0:
+            blocks.append((r0, r1, c1, mc0, guard, c0, mc1))
     return tuple(blocks)
+
+
+def _canonical_d0(d0, block_q, block_k, window):
+    """The ``d0`` that stands for a tile's class: every tile whose rows all
+    see all its keys is the one at ``1 - block_k`` (without a window every
+    tile from there down; under one those whose window's edge lies below
+    the tile too, which exist where the window spans a tile and a half)."""
+    if window is None:
+        return max(d0, 1 - block_k)
+    return 1 - block_k if block_q - window <= d0 <= 1 - block_k else d0
 
 
 def _geometry(seq_q, seq_k, block_q, block_k, band=None):
@@ -322,31 +360,34 @@ def _geometry(seq_q, seq_k, block_q, block_k, band=None):
 
 
 @functools.lru_cache(maxsize=64)      # a model's layers ask alike
-def _grid_classes(seq_q, seq_k, causal, block_q, block_k, band):
+def _grid_classes(seq_q, seq_k, causal, block_q, block_k, band, window=None):
     """The grid's tiles by what they run:
     ``((blocks, ((d0, tail), ...), n_tiles), ...)``. A causal tile is told
-    by its ``d0`` (held at 1 - block_k, from where on every row sees every
-    key) and by whether it holds the padded tail; tiles above the diagonal
-    (``_causal_run`` false) are in no class. Non-causal tiles are one
-    class of one block, masked everywhere (the mask is the tail's)."""
+    by its ``d0`` (``_canonical_d0``: held at 1 - block_k where every row
+    sees every key) and by whether it holds the padded tail; tiles above the
+    diagonal or, under a ``window``, wholly below the band (``_causal_run``
+    false) are in no class. Non-causal tiles are one class of one block,
+    masked everywhere (the mask is the tail's)."""
     block_q, block_k, sp_q, sp_k = _geometry(seq_q, seq_k, block_q, block_k,
                                              band if causal else None)
     n_q, n_k = sp_q // block_q, sp_k // block_k
     if not causal:
-        return ((((0, block_q, block_k, 0, True),), (), n_q * n_k),)
+        return ((((0, block_q, block_k, 0, True, 0, 0),), (), n_q * n_k),)
     kv_tail = seq_k - (n_k - 1) * block_k
     by_key = {}
     for qi in range(n_q):
         for ki in range(n_k):
             d0 = ki * block_k - (seq_k - seq_q) - qi * block_q
-            if d0 > block_q - 1:
+            if d0 > block_q - 1 or (window is not None
+                                    and d0 + window - 1 < 1 - block_k):
                 continue
-            key = (max(d0, 1 - block_k), ki == n_k - 1 and kv_tail < block_k)
+            key = (_canonical_d0(d0, block_q, block_k, window),
+                   ki == n_k - 1 and kv_tail < block_k)
             by_key[key] = by_key.get(key, 0) + 1
     by_blocks = {}
     for (d0, tail), n in by_key.items():
         blocks = _tile_blocks(d0, block_q, block_k,
-                              kv_tail if tail else block_k, band)
+                              kv_tail if tail else block_k, band, window)
         keys, count = by_blocks.get(blocks, ((), 0))
         by_blocks[blocks] = (keys + ((d0, tail),), count + n)
     return tuple((blocks, keys, n) for blocks, (keys, n) in by_blocks.items())
@@ -370,7 +411,7 @@ def _hbm_bytes(shape):
 
 
 def flash_plan(seq_q, seq_k, causal, block_q, block_k, heads=1,
-               head_dim=_LANES, itemsize=2):
+               head_dim=_LANES, itemsize=2, window=None):
     """What the three kernels execute for a call, fixed when it is traced:
     ``tiles`` (the grid a block of heads, queries x keys), ``sub_block``
     (the rows of a band of a causal tile; None where a tile runs whole),
@@ -383,27 +424,36 @@ def flash_plan(seq_q, seq_k, causal, block_q, block_k, heads=1,
     x d where heads pack, S_padded x 128 for a lone 64-wide head), and
     ``stats_bytes``, what a head's logsumexp occupies there, the one per-row
     statistic that crosses HBM (the forward writes it, both backward kernels
-    read it; delta is theirs, made in VMEM)."""
+    read it; delta is theirs, made in VMEM). A call with a ``window`` also
+    says it and ``tiles_run``, the tiles of the grid that do any work (the
+    others are neither run nor fetched)."""
     bq, bk, sp_q, sp_k = _geometry(seq_q, seq_k, block_q, block_k,
                                    SUB_BLOCK if causal else None)
-    area = sum(n * sum((r1 - r0) * c1 for r0, r1, c1, _mc0, _g in blocks)
-               for blocks, _keys, n in _grid_classes(
-                   seq_q, seq_k, causal, block_q, block_k, SUB_BLOCK))
+    classes = _grid_classes(seq_q, seq_k, causal, block_q, block_k,
+                            SUB_BLOCK, window)
+    area = sum(n * sum((r1 - r0) * (c1 - c0)
+                       for r0, r1, c1, _mc0, _g, c0, _mc1 in blocks)
+               for blocks, _keys, n in classes)
     hpb, head_lanes = _head_layout(heads, head_dim)
-    return {"tiles": [sp_q // bq, sp_k // bk],
+    plan = {"tiles": [sp_q // bq, sp_k // bk],
             "sub_block": min(SUB_BLOCK, bq) if causal else None,
             "executed_share": area / (sp_q * sp_k),
             "heads_per_block": hpb,
             "io_bytes": sp_q * head_lanes * itemsize,
             "stats_bytes": _hbm_bytes(_stats_shape(1, hpb, hpb, sp_q)) // hpb}
+    if window is not None:
+        plan.update(window=window,
+                    tiles_run=sum(n for _b, _k, n in classes))
+    return plan
 
 
 def _plan_entry(kind, seq_q, seq_k, causal, block_q, block_k, heads,
-                head_dim, dtype):
-    return (f"flash_{kind}[{seq_q}x{seq_k},{'causal' if causal else 'full'},"
-            f"{block_q}x{block_k}]",
+                head_dim, dtype, window=None):
+    mask = ("full" if not causal else "causal" if window is None
+            else f"window{window}")
+    return (f"flash_{kind}[{seq_q}x{seq_k},{mask},{block_q}x{block_k}]",
             flash_plan(seq_q, seq_k, causal, block_q, block_k, heads,
-                       head_dim, jnp.dtype(dtype).itemsize))
+                       head_dim, jnp.dtype(dtype).itemsize, window))
 
 
 def _stamp_plan(*call):
@@ -422,14 +472,19 @@ def _log_plan(*call):
             "%s: %s", *_plan_entry(*call))
 
 
-def _for_tile(classes, q_idx, kv_idx, num_kv, block_q, block_k, offset, body):
+def _for_tile(classes, q_idx, kv_idx, num_kv, block_q, block_k, offset, body,
+              window=None):
     """``body(block)`` for every block of the class the tile is in."""
     if len(classes) == 1:
         for block in classes[0][0]:
             body(block)
         return
-    d0 = jnp.maximum(kv_idx * block_k - offset - q_idx * block_q,
-                     1 - block_k)
+    d0 = kv_idx * block_k - offset - q_idx * block_q
+    if window is None:
+        d0 = jnp.maximum(d0, 1 - block_k)
+    else:                           # ``_canonical_d0`` of a traced d0
+        d0 = jnp.where((d0 >= block_q - window) & (d0 <= 1 - block_k),
+                       1 - block_k, d0)
     last = kv_idx == num_kv - 1
     tails = any(tail for _b, keys, _n in classes for _d0, tail in keys)
     for blocks, keys, _n in classes:
@@ -446,29 +501,38 @@ def _for_tile(classes, q_idx, kv_idx, num_kv, block_q, block_k, offset, body):
                 body(block)
 
 
-def _masked_keys(x, block, fn, keys_axis=1):
-    """``fn`` on the keys of the block's scores ``x`` ((rows, keys), or
-    (keys, rows) at ``keys_axis`` 0) that can hold masked scores, the rest
-    as they are."""
-    _r0, _r1, c1, mc0, _guard = block
-    if mc0 == 0:
-        return fn(x)
-    if mc0 == c1:
-        return x
-    seen, maskable = (x[:, :mc0], x[:, mc0:]) if keys_axis else (x[:mc0],
-                                                                 x[mc0:])
-    return jnp.concatenate([seen, fn(maskable)], axis=keys_axis)
+def _key_runs(block):
+    """The block's keys, relative to its first, as runs ``(start, stop,
+    maskable)``, the empty ones left out."""
+    _r0, _r1, c1, mc0, _guard, c0, mc1 = block
+    runs = ((c0, mc1, True), (mc1, mc0, False), (mc0, c1, True))
+    return [(a - c0, b - c0, m) for a, b, m in runs if b > a]
 
 
-def _block_mask(block, q_idx, kv_idx, block_q, block_k, seq_k, causal,
-                offset, keys_axis=1):
-    """The mask of the block's maskable keys, or None where it has none."""
-    r0, r1, c1, mc0, _guard = block
-    if mc0 == c1:
-        return None
-    return _tile_mask(q_idx, kv_idx, block_q, block_k, seq_k, causal, offset,
-                      corner=(r0, mc0, r1 - r0, c1 - mc0),
-                      keys_axis=keys_axis)
+def _masked_keys(x, block, masks, fn, keys_axis=1):
+    """``fn(part, mask)`` on the runs of keys of the block's scores ``x``
+    ((rows, keys), or (keys, rows) at ``keys_axis`` 0) that can hold masked
+    scores (``masks``: ``_block_masks``), the rest as they are."""
+    runs, masks = _key_runs(block), iter(masks)
+    if len(runs) == 1:
+        return fn(x, next(masks)) if runs[0][2] else x
+    parts = []
+    for a, b, maskable in runs:
+        part = x[:, a:b] if keys_axis else x[a:b]
+        parts.append(fn(part, next(masks)) if maskable else part)
+    return jnp.concatenate(parts, axis=keys_axis)
+
+
+def _block_masks(block, q_idx, kv_idx, block_q, block_k, seq_k, causal,
+                 offset, keys_axis=1, window=None):
+    """The masks of the block's maskable runs of keys, in their order
+    (``_key_runs``), or None where it has none."""
+    r0, r1, _c1, _mc0, _guard, c0, _mc1 = block
+    masks = [_tile_mask(q_idx, kv_idx, block_q, block_k, seq_k, causal,
+                        offset, corner=(r0, c0 + a, r1 - r0, b - a),
+                        keys_axis=keys_axis, window=window)
+             for a, b, maskable in _key_runs(block) if maskable]
+    return masks or None
 
 
 def _scores(q, k, scale):
@@ -477,16 +541,16 @@ def _scores(q, k, scale):
         preferred_element_type=jnp.float32) * scale
 
 
-def _probs(s, lse, block, mask, keys_axis=1):
+def _probs(s, lse, block, masks, keys_axis=1):
     """p = exp(s - lse), zero where masked. The mask guards (not just exp
     underflow): for fully-masked rows lse is garbage (~NEG_INF) and
     exp(NEG_INF - lse) would be 1, not 0."""
-    if mask is None:
+    if masks is None:
         return jnp.exp(s - lse)
-    s = _masked_keys(s, block, lambda x: jnp.where(mask, x, NEG_INF),
-                     keys_axis)
-    return _masked_keys(jnp.exp(s - lse), block,
-                        lambda x: jnp.where(mask, x, 0.0), keys_axis)
+    s = _masked_keys(s, block, masks,
+                     lambda x, m: jnp.where(m, x, NEG_INF), keys_axis)
+    return _masked_keys(jnp.exp(s - lse), block, masks,
+                        lambda x, m: jnp.where(m, x, 0.0), keys_axis)
 
 
 # --------------------------------------------------------------------------
@@ -578,7 +642,7 @@ def _unroll_heads(classes):
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
                 *, scale, causal, block_q, block_k, seq_q, seq_k, classes,
-                one_pass, hpb, unroll):
+                one_pass, hpb, unroll, window):
     kv_idx = pl.program_id(3)
     q_idx = pl.program_id(2)
     num_kv = pl.num_programs(3)
@@ -599,22 +663,23 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
 
     run = True
     if causal:
-        run = _causal_run(q_idx, kv_idx, block_q, block_k, causal_offset)
+        run = _causal_run(q_idx, kv_idx, block_q, block_k, causal_offset,
+                          window)
 
     def _block(block):
-        rows, cols = slice(*block[:2]), slice(0, block[2])
+        rows, cols = slice(*block[:2]), slice(block[5], block[2])
 
         def _head(h):
             q = q_ref[0, rows]    # (rows, lanes)
             k = k_ref[0, cols]    # (keys, lanes)
             v = v_ref[0, cols]
-            mask = _block_mask(block, q_idx, kv_idx, block_q, block_k, seq_k,
-                               causal, causal_offset)
+            masks = _block_masks(block, q_idx, kv_idx, block_q, block_k,
+                                 seq_k, causal, causal_offset, window=window)
             keep = _head_keep(h, hpb, q.shape)
             s = _scores(_head_lanes(q, keep), k, scale)
-            if mask is not None:
-                s = _masked_keys(s, block,
-                                 lambda x: jnp.where(mask, x, NEG_INF))
+            if masks is not None:
+                s = _masked_keys(s, block, masks,
+                                 lambda x, m: jnp.where(m, x, NEG_INF))
 
             if one_pass:
                 # the band's only keys: nothing to merge, nothing to carry
@@ -657,7 +722,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
     @pl.when(run)
     def _step():
         _for_tile(classes, q_idx, kv_idx, num_kv, block_q, block_k,
-                  causal_offset, _block)
+                  causal_offset, _block, window)
 
     if not one_pass:
         @pl.when(kv_idx == num_kv - 1)
@@ -674,7 +739,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
 
 def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, out_ref, dq_ref,
                dq_scr, *, scale, causal, block_q, block_k, seq_q, seq_k,
-               classes, hpb, unroll):
+               classes, hpb, unroll, window):
     kv_idx = pl.program_id(3)
     q_idx = pl.program_id(2)
     num_kv = pl.num_programs(3)
@@ -686,10 +751,11 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, out_ref, dq_ref,
 
     run = True
     if causal:
-        run = _causal_run(q_idx, kv_idx, block_q, block_k, causal_offset)
+        run = _causal_run(q_idx, kv_idx, block_q, block_k, causal_offset,
+                          window)
 
     def _block(block):
-        rows, cols = slice(*block[:2]), slice(0, block[2])
+        rows, cols = slice(*block[:2]), slice(block[5], block[2])
 
         def _head(h):
             q = q_ref[0, rows]
@@ -697,8 +763,8 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, out_ref, dq_ref,
             v = v_ref[0, cols]
             do = do_ref[0, rows]
             out = out_ref[0, rows].astype(jnp.float32)
-            mask = _block_mask(block, q_idx, kv_idx, block_q, block_k, seq_k,
-                               causal, causal_offset)
+            masks = _block_masks(block, q_idx, kv_idx, block_q, block_k,
+                                 seq_k, causal, causal_offset, window=window)
             keep = _head_keep(h, hpb, q.shape)
             do_h = _head_lanes(do, keep)
             # row -> column
@@ -707,7 +773,7 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, out_ref, dq_ref,
             delta = jnp.sum(do_h.astype(jnp.float32) * out, axis=1,
                             keepdims=True)
             s = _scores(_head_lanes(q, keep), k, scale)
-            p = _probs(s, lse, block, mask)
+            p = _probs(s, lse, block, masks)
             dp = jax.lax.dot_general(
                 do_h, v, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32)
@@ -721,7 +787,7 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, out_ref, dq_ref,
     @pl.when(run)
     def _step():
         _for_tile(classes, q_idx, kv_idx, num_kv, block_q, block_k,
-                  causal_offset, _block)
+                  causal_offset, _block, window)
 
     @pl.when(kv_idx == num_kv - 1)
     def _finish():
@@ -730,7 +796,7 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, out_ref, dq_ref,
 
 def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, out_ref, dk_ref,
                 dv_ref, dk_scr, dv_scr, *, scale, causal, block_q, block_k,
-                seq_q, seq_k, classes, hpb, unroll):
+                seq_q, seq_k, classes, hpb, unroll, window):
     q_idx = pl.program_id(3)       # q innermost in this kernel
     kv_idx = pl.program_id(2)
     num_q = pl.num_programs(3)
@@ -744,10 +810,11 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, out_ref, dk_ref,
 
     run = True
     if causal:
-        run = _causal_run(q_idx, kv_idx, block_q, block_k, causal_offset)
+        run = _causal_run(q_idx, kv_idx, block_q, block_k, causal_offset,
+                          window)
 
     def _block(block):
-        rows, cols = slice(*block[:2]), slice(0, block[2])
+        rows, cols = slice(*block[:2]), slice(block[5], block[2])
 
         def _head(h):
             q = q_ref[0, rows]
@@ -755,8 +822,9 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, out_ref, dk_ref,
             v = v_ref[0, cols]
             do = do_ref[0, rows]
             out = out_ref[0, rows].astype(jnp.float32)
-            mask = _block_mask(block, q_idx, kv_idx, block_q, block_k, seq_k,
-                               causal, causal_offset, keys_axis=0)
+            masks = _block_masks(block, q_idx, kv_idx, block_q, block_k,
+                                 seq_k, causal, causal_offset, keys_axis=0,
+                                 window=window)
             keep = _head_keep(h, hpb, q.shape)
             # queries and dO with the head's lanes alone: the products that
             # give lanes (P^T dO, dS^T Q) are then zero in the others'
@@ -768,7 +836,7 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, out_ref, dk_ref,
             # and no product contracts over the leading dimension of the
             # scores
             s_t = _scores(k, q_h, scale)
-            p_t = _probs(s_t, lse, block, mask, keys_axis=0)
+            p_t = _probs(s_t, lse, block, masks, keys_axis=0)
             # dv += P^T dO
             dv_scr[cols] += jax.lax.dot_general(
                 p_t.astype(do.dtype), do_h, (((1,), (0,)), ((), ())),
@@ -787,7 +855,7 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, out_ref, dk_ref,
     @pl.when(run)
     def _step():
         _for_tile(classes, q_idx, kv_idx, num_kv, block_q, block_k,
-                  causal_offset, _block)
+                  causal_offset, _block, window)
 
     @pl.when(q_idx == num_q - 1)
     def _finish():
@@ -823,27 +891,41 @@ def _compiler_params(causal, block_q, block_k):
     return None
 
 
-def _flash_fwd_bshd(q, k, v, *, causal, scale, block_q, block_k):
+def _band_tile(j, i, tile_i, tile_j, n_j, lo, hi):
+    """Tile ``j`` of the other side held inside the tiles that tile ``i``
+    of this side meets under a window, those of positions ``i * tile_i +
+    lo`` to ``i * tile_i + hi``: a grid step outside the band names the
+    block of the nearest step inside it, which is then not fetched again."""
+    first = jnp.maximum(i * tile_i + lo, 0) // tile_j
+    last = jnp.maximum(i * tile_i + hi, 0) // tile_j
+    return jnp.clip(j, jnp.minimum(first, n_j - 1), jnp.minimum(last, n_j - 1))
+
+
+def _flash_fwd_bshd(q, k, v, *, causal, scale, block_q, block_k,
+                    window=None):
     """q/k/v: (B, S, H, d) -> (out (B, S, H, d), lse fp32 (B, H //
     heads_per_block, heads_per_block, Sq_padded))."""
     return _fwd_call(q, k, v, causal=causal, scale=float(scale),
                      block_q=block_q, block_k=block_k, band=SUB_BLOCK,
-                     interpret=INTERPRET)
+                     interpret=INTERPRET, window=window)
 
 
 # One jitted function a kernel: a model's layers call it with one signature,
 # so the program that holds them traces the kernel body and lowers it to
 # Mosaic once, not once a layer (the module's switches are arguments, so a
 # test that flips one is not served the other's trace).
-_STATIC = ("causal", "scale", "block_q", "block_k", "band", "interpret")
+_STATIC = ("causal", "scale", "block_q", "block_k", "band", "interpret",
+           "window")
 
 
 @functools.partial(jax.jit, static_argnames=_STATIC)
-def _fwd_call(q, k, v, *, causal, scale, block_q, block_k, band, interpret):
+def _fwd_call(q, k, v, *, causal, scale, block_q, block_k, band, interpret,
+              window=None):
     b, s_q, h, d = q.shape
     s_k = k.shape[1]
-    _log_plan("fwd", s_q, s_k, causal, block_q, block_k, h, d, q.dtype)
-    classes = _grid_classes(s_q, s_k, causal, block_q, block_k, band)
+    _log_plan("fwd", s_q, s_k, causal, block_q, block_k, h, d, q.dtype,
+              window)
+    classes = _grid_classes(s_q, s_k, causal, block_q, block_k, band, window)
     block_q, block_k, sp_q, sp_k = _geometry(s_q, s_k, block_q, block_k,
                                              band if causal else None)
     hpb, head_lanes = _head_layout(h, d)
@@ -861,9 +943,10 @@ def _fwd_call(q, k, v, *, causal, scale, block_q, block_k, band, interpret):
         # v5e the running-state form runs the same bands 1.44 times as long
         # at S 1024, d 64 (1.20 at S 2048): 4.6 % of a GPT-2 345M step
         one_pass=causal and sp_k == block_k, hpb=hpb,
-        unroll=_unroll_heads(classes))
+        unroll=_unroll_heads(classes), window=window)
     q_spec = pl.BlockSpec((1, block_q, lanes), lambda b, g, i, j: (b, i, g))
-    kv_spec = pl.BlockSpec((1, block_k, lanes), lambda b, g, i, j: (b, j, g))
+    kv_spec = pl.BlockSpec((1, block_k, lanes), _kv_index(
+        window, block_q, block_k, sp_k // block_k, s_k - s_q))
     out, lse = pl.pallas_call(
         kernel,
         out_shape=[
@@ -889,22 +972,34 @@ def _fwd_call(q, k, v, *, causal, scale, block_q, block_k, band, interpret):
     return _from_blocks(out, s_q, h, d), lse
 
 
+def _kv_index(window, block_q, block_k, n_k, offset):
+    """Index map of the K / V blocks of a grid (batch, heads, q tiles, kv
+    tiles): tile j as it is, or under a ``window`` held inside the band of
+    q tile i (``_band_tile``)."""
+    if window is None:
+        return lambda b, g, i, j: (b, j, g)
+    return lambda b, g, i, j: (
+        b, _band_tile(j, i, block_q, block_k, n_k, offset - (window - 1),
+                      offset + block_q - 1), g)
+
+
 def _flash_bwd_bshd(q, k, v, out, lse, do, *, causal, scale, block_q,
-                    block_k):
+                    block_k, window=None):
     """FA2 backward. All of q/k/v/out/do: (B, S, H, d); lse as the forward
     gives it. Returns (dq, dk, dv), (B, S, H, d)."""
     return _bwd_call(q, k, v, out, lse, do, causal=causal,
                      scale=float(scale), block_q=block_q, block_k=block_k,
-                     band=SUB_BLOCK, interpret=INTERPRET)
+                     band=SUB_BLOCK, interpret=INTERPRET, window=window)
 
 
 @functools.partial(jax.jit, static_argnames=_STATIC)
 def _bwd_call(q, k, v, out, lse, do, *, causal, scale, block_q, block_k,
-              band, interpret):
+              band, interpret, window=None):
     b, s_q, h, d = q.shape
     s_k = k.shape[1]
-    _log_plan("bwd", s_q, s_k, causal, block_q, block_k, h, d, q.dtype)
-    classes = _grid_classes(s_q, s_k, causal, block_q, block_k, band)
+    _log_plan("bwd", s_q, s_k, causal, block_q, block_k, h, d, q.dtype,
+              window)
+    classes = _grid_classes(s_q, s_k, causal, block_q, block_k, band, window)
     block_q, block_k, sp_q, sp_k = _geometry(s_q, s_k, block_q, block_k,
                                              band if causal else None)
     hpb, head_lanes = _head_layout(h, d)
@@ -925,9 +1020,10 @@ def _bwd_call(q, k, v, out, lse, do, *, causal, scale, block_q, block_k,
 
     kw = dict(scale=scale, causal=causal, block_q=block_q, block_k=block_k,
               seq_q=s_q, seq_k=s_k, classes=classes, hpb=hpb,
-              unroll=_unroll_heads(classes))
+              unroll=_unroll_heads(classes), window=window)
     q_spec = pl.BlockSpec((1, block_q, lanes), lambda b, g, i, j: (b, i, g))
-    k_spec = pl.BlockSpec((1, block_k, lanes), lambda b, g, i, j: (b, j, g))
+    k_spec = pl.BlockSpec((1, block_k, lanes), _kv_index(
+        window, block_q, block_k, sp_k // block_k, s_k - s_q))
     row_spec = pl.BlockSpec((1, 1, hpb, block_q),
                             lambda b, g, i, j: (b, g, 0, i))
 
@@ -943,10 +1039,18 @@ def _bwd_call(q, k, v, out, lse, do, *, causal, scale, block_q, block_k,
         name="flash_dq",
     )(q, k, v, do, lse, out)
 
-    # dk/dv: kv outer, q inner
-    qi_spec = pl.BlockSpec((1, block_q, lanes), lambda b, g, i, j: (b, j, g))
+    # dk/dv: kv outer, q inner; under a window q tile j held inside the
+    # band of kv tile i
+    def q_tile(i, j):
+        if window is None:
+            return j
+        return _band_tile(j, i, block_k, block_q, sp_q // block_q,
+                          s_q - s_k, s_q - s_k + block_k - 1 + window - 1)
+
+    qi_spec = pl.BlockSpec((1, block_q, lanes),
+                           lambda b, g, i, j: (b, q_tile(i, j), g))
     rowi_spec = pl.BlockSpec((1, 1, hpb, block_q),
-                             lambda b, g, i, j: (b, g, 0, j))
+                             lambda b, g, i, j: (b, g, 0, q_tile(i, j)))
     kv_spec = pl.BlockSpec((1, block_k, lanes), lambda b, g, i, j: (b, i, g))
     dk, dv = pl.pallas_call(
         functools.partial(_dkv_kernel, **kw),
@@ -965,9 +1069,9 @@ def _bwd_call(q, k, v, out, lse, do, *, causal, scale, block_q, block_k,
             _from_blocks(dv, s_k, h, d))
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
-def _flash_attention(q, k, v, causal, scale, block_q, block_k):
-    return _flash_fwd(q, k, v, causal, scale, block_q, block_k)[0]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+def _flash_attention(q, k, v, causal, scale, block_q, block_k, window=None):
+    return _flash_fwd(q, k, v, causal, scale, block_q, block_k, window)[0]
 
 
 def _resolve_blocks(kind, block_q, block_k, q, k, causal, scale):
@@ -1012,19 +1116,19 @@ def _keep(x, name):
     return checkpoint_name(x, name)
 
 
-def _flash_fwd(q, k, v, causal, scale, block_q, block_k):
+def _flash_fwd(q, k, v, causal, scale, block_q, block_k, window):
     """(out (B, S, H, d), lse (B, H // hpb, hpb, S_padded)) as the kernel
     gives them."""
     block_q, block_k = _resolve_blocks("fwd", block_q, block_k, q, k,
                                        causal, scale)
     _stamp_plan("fwd", q.shape[1], k.shape[1], causal, block_q, block_k,
-                q.shape[2], q.shape[3], q.dtype)
+                q.shape[2], q.shape[3], q.dtype, window)
     return _flash_fwd_bshd(q, k, v, causal=causal, scale=scale,
-                           block_q=block_q, block_k=block_k)
+                           block_q=block_q, block_k=block_k, window=window)
 
 
-def _flash_fwd_rule(q, k, v, causal, scale, block_q, block_k):
-    out, lse = _flash_fwd(q, k, v, causal, scale, block_q, block_k)
+def _flash_fwd_rule(q, k, v, causal, scale, block_q, block_k, window):
+    out, lse = _flash_fwd(q, k, v, causal, scale, block_q, block_k, window)
     out = _keep(out, KEPT_RESIDUALS[0])
     # the kernel's rows as they come: kept, and read by the backward
     # kernels, with no pass of XLA's over them
@@ -1032,24 +1136,34 @@ def _flash_fwd_rule(q, k, v, causal, scale, block_q, block_k):
     return out, (q, k, v, out, lse)
 
 
-def _flash_bwd_rule(causal, scale, block_q, block_k, res, g):
+def _flash_bwd_rule(causal, scale, block_q, block_k, window, res, g):
     q, k, v, out, lse = res
     block_q, block_k = _resolve_blocks("bwd", block_q, block_k, q, k,
                                        causal, scale)
     _stamp_plan("bwd", q.shape[1], k.shape[1], causal, block_q, block_k,
-                q.shape[2], q.shape[3], q.dtype)
+                q.shape[2], q.shape[3], q.dtype, window)
     return _flash_bwd_bshd(q, k, v, out, lse, g, causal=causal, scale=scale,
-                           block_q=block_q, block_k=block_k)
+                           block_q=block_q, block_k=block_k, window=window)
 
 
 _flash_attention.defvjp(_flash_fwd_rule, _flash_bwd_rule)
 
 
 def flash_attention_fwd(q, k, v, causal=False, scale=None, block_q=None,
-                        block_k=None):
+                        block_k=None, window=None):
     """Public entry: q/k/v (batch, seq, heads, head_dim). ``block_q`` /
     ``block_k`` tune the tile sizes (unset: the autotuner's pick on a TPU,
-    else DEFAULT_BLOCK_Q/K, forward and backward)."""
+    else DEFAULT_BLOCK_Q/K, forward and backward). ``window`` (a causal
+    call's): query ``i`` sees key ``j`` iff ``0 <= i - j < window``, its own
+    key and the ``window - 1`` before it (``i`` counted from the keys' end
+    where the sides differ in length, as the causal diagonal is); a window
+    no shorter than the keys is no window, and the call is the causal one,
+    kernel for kernel."""
     if scale is None:
         scale = 1.0 / math.sqrt(q.shape[-1])
-    return _flash_attention(q, k, v, causal, scale, block_q, block_k)
+    if window is not None:
+        if not causal or window < 1:
+            raise ValueError("a window is a causal call's, and at least 1")
+        if window >= k.shape[1]:
+            window = None
+    return _flash_attention(q, k, v, causal, scale, block_q, block_k, window)

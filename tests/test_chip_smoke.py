@@ -82,9 +82,12 @@ def test_kernels_phase_tiny(interpreted):
         fused_shape=dict(rows=32, hidden=128, ffn=256, head_dim=32, seq=16))
     names = " ".join(out["max_rel_err"])
     for site in ("flash_fwd", "flash_dq_dkdv", "fused_residual_norm",
-                 "fused_bias_act", "fused_matmul[", "fused_matmul_rope"):
+                 "fused_bias_act", "fused_matmul[", "fused_matmul_rope",
+                 "flash_fwd[S=128,d=32,window=64](32,32)",
+                 "flash_dq_dkdv[S=128,d=32,window=64](2048,2048)"):
         assert site in names, names
-    assert out["kernels_checked"] == len(out["max_rel_err"]) == 9
+    # the windowed kernels: forward and backward at two tiles
+    assert out["kernels_checked"] == len(out["max_rel_err"]) == 13
 
 
 def test_kernels_phase_names_every_broken_kernel(interpreted, monkeypatch):

@@ -632,7 +632,7 @@ def test_no_causal_tile_longer_than_a_band_runs_whole():
                            (700, 700, 300, 200), (2047, 4000, 1000, 1000)]:
         for blocks, _keys, _n in fa._grid_classes(sq, sk, True, bq, bk, 256):
             assert all(r1 - r0 == 256 and c1 % 128 == 0 and mc0 % 128 == 0
-                       for r0, r1, c1, mc0, _guard in blocks), (sq, sk)
+                       for r0, r1, c1, mc0, _guard, *_window in blocks), (sq, sk)
     # a non-causal call's tiles are clamped to the sequence and no more
     assert fa._geometry(2000, 2000, 1024, 1024) == (1024, 1024, 2048, 2048)
     assert fa._geometry(700, 700, 1024, 1024) == (700, 700, 700, 700)
@@ -667,3 +667,132 @@ def test_a_non_causal_call_runs_one_block_a_tile_whatever_the_band(
     assert "concatenate" not in fwd + bwd
     # only a head that is no whole share of a lane block is padded
     assert (" pad[" in fwd) == (d % 128 != 0 and hpb == 1)
+
+
+# --------------------------------------------------------------------------
+# A window: query i sees key j iff 0 <= i - j < window (ISSUE 47).
+# --------------------------------------------------------------------------
+def _window_share(sq, sk, bq, bk, band, window):
+    """``_brute_force_share`` under a window: a band runs the keys from its
+    first live lane group to its last, in one piece."""
+    bq, bk = min(bq, max(sq, 8)), min(bk, max(sk, 8))
+    bq = -(-bq // band) * band if bq > band else bq
+    bk = -(-bk // 128) * 128 if bk > 128 else bk
+    sp_q, sp_k = -(-sq // bq) * bq, -(-sk // bk) * bk
+    band, lanes = min(band, bq), min(128, bk)
+    back = np.arange(sp_q)[:, None] + (sk - sq) - np.arange(sp_k)[None, :]
+    live = (back >= 0) & (back < window) & (np.arange(sp_k)[None, :] < sk)
+    area = tiles = 0
+    for q0 in range(0, sp_q, bq):
+        for k0 in range(0, sp_k, bk):
+            tiles += bool(live[q0:q0 + bq, k0:k0 + bk].any())
+            for r0 in range(q0, q0 + bq, band):
+                groups = [c0 for c0 in range(k0, k0 + bk, lanes)
+                          if live[r0:r0 + band, c0:c0 + lanes].any()]
+                if groups:
+                    area += band * (groups[-1] + lanes - groups[0])
+    return area / (sp_q * sp_k), tiles
+
+
+_WINDOW_CASES = [
+    # (sq, sk, window, block_q, block_k, band, dtype)
+    pytest.param(512, 512, 24, 256, 256, 64, jnp.float32, id="under-a-band"),
+    pytest.param(512, 512, 128, 128, 128, 64, jnp.float32, id="one-tile"),
+    pytest.param(500, 500, 300, 128, 128, 64, jnp.float32,
+                 id="tiles-ragged-tail"),
+    pytest.param(384, 512, 200, 128, 256, 128, jnp.float32,
+                 id="fewer-queries"),
+    pytest.param(256, 256, 100, 512, 512, 64, jnp.float32, id="one-pass"),
+    pytest.param(512, 512, 257, 128, 256, 128, jnp.bfloat16, id="bf16"),
+]
+
+
+@pytest.mark.parametrize("sq,sk,window,bq,bk,band,dtype", _WINDOW_CASES)
+def test_a_window_matches_the_definition(monkeypatch, sq, sk, window, bq, bk,
+                                         band, dtype):
+    """The three kernels under a window against ``_sdpa_xla``'s mask, outputs
+    and dQ / dK / dV: a window shorter than a band (both edges in one band's
+    mask), one tile long, spanning tiles over a ragged tail, with fewer
+    queries than keys, a row of tiles one tile long (the one-pass forward);
+    and the plan says the window, the tiles that run (the rest are in no
+    class of ``_grid_classes``) and the share of the square computed."""
+    from paddle_tpu.nn.functional.flash_attention import _sdpa_xla
+    monkeypatch.setattr(fa, "SUB_BLOCK", band)
+    q, k, v = (a.astype(dtype) for a in _rand_qkv(s=sq, t=sk, h=2, d=32))
+    g = _rand_qkv(s=sq, h=2, d=32, seed=1)[0].astype(dtype)
+
+    def grads(attend):
+        return jax.value_and_grad(
+            lambda q, k, v: jnp.sum((attend(q, k, v) * g).astype(jnp.float32)),
+            argnums=(0, 1, 2))(q, k, v)
+
+    out, got = grads(lambda q, k, v: fa.flash_attention_fwd(
+        q, k, v, causal=True, window=window, block_q=bq, block_k=bk))
+    ref_out, ref = grads(lambda q, k, v: _sdpa_xla(
+        q, k, v, causal=True, window=window))
+    tol = (dict(atol=2e-5, rtol=2e-5) if dtype == jnp.float32
+           else dict(atol=0.06, rtol=0.06))
+    np.testing.assert_allclose(float(out), float(ref_out), rtol=5e-3
+                               if dtype == jnp.bfloat16 else 1e-5)
+    for a, b, name in zip(got, ref, "qkv"):
+        np.testing.assert_allclose(np.asarray(a, np.float32),
+                                   np.asarray(b, np.float32), **tol,
+                                   err_msg=f"d{name}")
+    plan = fa.flash_plan(sq, sk, True, bq, bk, 2, 32, 4, window)
+    share, tiles = _window_share(sq, sk, bq, bk, band, window)
+    assert plan["window"] == window and plan["tiles_run"] == tiles
+    assert plan["executed_share"] == pytest.approx(share)
+    assert tiles <= plan["tiles"][0] * plan["tiles"][1]
+
+
+def test_a_window_no_shorter_than_the_keys_is_the_causal_call():
+    """``window >= seq`` (or None) builds the causal call, kernel for
+    kernel: the same program text, the same values and gradients bit for
+    bit; a window on a non-causal call is refused."""
+    q, k, v = _rand_qkv(s=256, h=2, d=32)
+
+    def program(window):
+        f = lambda q, k, v: jnp.sum(fa.flash_attention_fwd(  # noqa: E731
+            q, k, v, causal=True, window=window, block_q=128,
+            block_k=128) ** 2)
+        return (str(jax.make_jaxpr(jax.grad(f, argnums=(0, 1, 2)))(q, k, v)),
+                jax.value_and_grad(f, argnums=(0, 1, 2))(q, k, v))
+
+    causal_text, (causal, causal_grads) = program(None)
+    for window in (256, 4096):
+        text, (out, grads) = program(window)
+        assert text == causal_text
+        assert float(out) == float(causal)
+        for a, b in zip(grads, causal_grads):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    assert program(255)[0] != causal_text
+    with pytest.raises(ValueError):
+        fa.flash_attention_fwd(q, k, v, causal=False, window=64)
+
+
+def test_a_call_without_a_window_has_the_plan_it_had():
+    """``flash_plan`` of the three cells that ran the kernels before the
+    window (GPT-2 at S 1024, LFM2 at S 8192: the tiles, the bands, the
+    share of the square, every byte count) and the name its stamp goes
+    under; a windowed call says more, under a name of its own."""
+    assert fa.flash_plan(1024, 1024, True, 2048, 2048, 16, 64, 2) == {
+        "tiles": [1, 1], "sub_block": 256, "executed_share": 0.625,
+        "heads_per_block": 2, "io_bytes": 131072, "stats_bytes": 4096}
+    assert fa.flash_plan(8192, 8192, True, 2048, 2048, 32, 64, 2) == {
+        "tiles": [4, 4], "sub_block": 256, "executed_share": 0.515625,
+        "heads_per_block": 2, "io_bytes": 1048576, "stats_bytes": 32768}
+    assert fa._plan_entry("fwd", 1024, 1024, True, 2048, 2048, 16, 64,
+                          jnp.bfloat16)[0] == (
+        "flash_fwd[1024x1024,causal,2048x2048]")
+    # the new cell's two calls: one full layer, three of window 4096
+    full = fa.flash_plan(16384, 16384, True, 2048, 2048, 28, 128, 2)
+    name, windowed = fa._plan_entry("bwd", 16384, 16384, True, 2048, 2048,
+                                    28, 128, jnp.bfloat16, 4096)
+    assert name == "flash_bwd[16384x16384,window4096,2048x2048]"
+    assert full["tiles"] == windowed["tiles"] == [8, 8]
+    assert "window" not in full and "tiles_run" not in full
+    assert (windowed["window"], windowed["tiles_run"]) == (4096, 21)
+    # the pairs inside the band are 0.4375 of the causal triangle's; as the
+    # kernels run them (bands of 256 rows, keys cut at 128 lanes) 0.458
+    assert windowed["executed_share"] / full["executed_share"] == \
+        pytest.approx(0.458, abs=0.002)

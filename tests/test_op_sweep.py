@@ -677,7 +677,12 @@ SKIP = {
        for n in ("gated_delta_chunk", "gated_delta_step", "gated_rms_norm")},
     "held_experts_swiglu":
         "routing table in, no elementwise sweep contract; compared with "
-        "the plain K-EXAONE reference in tests/test_exaone_moe.py",
+        "the plain K-EXAONE reference in tests/test_exaone_moe.py (and, "
+        "with a ReLU gate, SmallThinker's in tests/test_smallthinker.py)",
+    "softmax_topk_route":
+        "routing table out (top-k indices and a softmax over the chosen "
+        "logits), no elementwise sweep contract; compared with numpy and "
+        "the plain SmallThinker reference in tests/test_smallthinker.py",
     "gated_short_conv":
         "a causal filter along the sequence axis, no elementwise sweep "
         "contract; compared with a token loop and with the plain LFM2-MoE "

@@ -692,3 +692,51 @@ def test_head_and_loss_compile_chunk_by_chunk_at_the_fit_cells_shape(
     body = re.search(r" while\(.*?body=%([\w.-]+)", text).group(1)
     assert "all-reduce" not in re.search(
         rf"(?ms)^%{re.escape(body)} \(.*?^}}", text).group(0)
+
+
+SMALLTHINKER_STEP = dict(seq=16384, window=4096, heads=2)
+
+
+def test_smallthinker_16k_train_step_compiles_with_the_windowed_kernels(
+        one_chip, monkeypatch):
+    """``Engine``'s train step over a narrow SmallThinker (one full + NoPE
+    layer, one 4096-window rotary layer, heads of 128, blocks
+    rematerialised, bf16 O1 autocast) at the cell's 1 x 16 384 tokens,
+    compiled for the described v5e: the full layer and the window layer
+    each run the three flash kernels at the module's own 2048 tiles (what
+    Mosaic refuses of a windowed tile it refuses here), the forward one once
+    a layer (the block keeps the kernel's two residuals), the expert product
+    takes its grouped form, and no array as large as S x S exists in the
+    whole program."""
+    from paddle_tpu.models import SmallThinkerForCausalLM, smallthinker_tiny
+    from paddle_tpu.ops.pallas import flash_attention as fa
+
+    g = SMALLTHINKER_STEP
+    cfg = smallthinker_tiny(
+        hidden_size=256, num_hidden_layers=2, num_attention_heads=g["heads"],
+        num_key_value_heads=1, head_dim=128, sliding_window_size=g["window"],
+        max_position_embeddings=g["seq"], moe_num_primary_experts=8,
+        moe_num_active_primary_experts=2, moe_ffn_hidden_size=128,
+        experts_held=(0, 4), vocab_size=512, recompute=True)
+    assert [blk.self_attn.window for blk in SmallThinkerForCausalLM(
+        cfg).model.layers] == [None, g["window"]]
+    with fa.kept_residuals():       # a fresh log: none is open here
+        text = _compiled_train_step(SmallThinkerForCausalLM(cfg), 1,
+                                    g["seq"], one_chip, monkeypatch)
+    assert _flash_calls(text) == dict.fromkeys(
+        ("flash_fwd", "flash_dq", "flash_dkv"), 2)
+    assert re.findall(r"%ragged-dot-(?!metadata)[\w-]+(?:\.\d+)? = ", text)
+    square = rf"\[(?:\d+,)*{g['seq']},{g['seq']}(?:,\d+)*\]"
+    assert not re.findall(square, text)
+    # what the two calls were built with: the same 8 x 8 tiles of 2048, the
+    # window's 21 of the 36 that the causal call runs
+    full = fa.flash_plan(g["seq"], g["seq"], True, 2048, 2048, g["heads"],
+                         128)
+    band = fa.flash_plan(g["seq"], g["seq"], True, 2048, 2048, g["heads"],
+                         128, window=g["window"])
+    assert full["tiles"] == band["tiles"] == [8, 8]
+    assert band["tiles_run"] == 21
+    # the band's pairs are 0.4375 of the triangle's; in bands of 256 rows
+    # and lanes of 128 keys the kernels run 0.458 of what the causal ones do
+    assert band["executed_share"] / full["executed_share"] == pytest.approx(
+        0.458, abs=0.002)
