@@ -52,6 +52,14 @@ _m_routed_pairs = _metrics.counter(
     "(token, expert) pairs a serving engine's routers selected "
     "(kind=selected) and those that landed on experts held here "
     "(kind=held).", labelnames=("kind",))
+_m_pair_strides = _metrics.counter(
+    "paddle_tpu_moe_pair_strides_total",
+    "Strides of the sorted pair buffer a trained model's routed layers "
+    "walked (kind=walked: ceil(landed pairs / stride) a layer a step, at "
+    "the epoch's mean load a step) and the strides of the whole buffer "
+    "(kind=buffer: what every step walked before the layer stopped at its "
+    "landed pairs). walked / buffer is the share of the buffer's row work "
+    "that is still done.", labelnames=("kind",))
 
 
 def stamp_expert_load(tokens_per_expert, first_expert: int, pairs_held,
@@ -67,6 +75,22 @@ def stamp_expert_load(tokens_per_expert, first_expert: int, pairs_held,
     _m_routed_pairs.inc(float(pairs_selected), kind="selected")
     if imbalance is not None:
         _m_load_imbalance.set(imbalance)
+
+
+def stamp_pair_strides(landed, selected, stride: int, pairs: int):
+    """Export how far the grouped expert product walked its pair buffer
+    over an epoch: ``landed`` / ``selected`` the epoch's pairs a routed
+    layer (host arrays, ``moe.expert_load``'s last two columns), ``stride``
+    / ``pairs`` the static stride and buffer of one call
+    (``nn.functional.experts.pair_walk``). Returns ``(walked, buffer)``."""
+    steps = np.asarray(selected) // pairs
+    a_step = -(-np.asarray(landed) // np.maximum(steps, 1))
+    walked = int(np.sum(steps * -(-a_step // stride)))
+    whole = int(np.sum(steps) * -(-pairs // stride))
+    if _metrics.enabled():
+        _m_pair_strides.inc(float(walked), kind="walked")
+        _m_pair_strides.inc(float(whole), kind="buffer")
+    return walked, whole
 
 
 def _stamp_expert_load(dispatch_mask: Tensor):

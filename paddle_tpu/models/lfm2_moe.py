@@ -42,6 +42,7 @@ import numpy as np
 
 from .. import nn, ops
 from ..nn import functional as F
+from ..nn.functional import experts as _experts
 from ..nn.initializer import Normal
 from ..nn.parameter import ParamAttr
 from ..observability import trace as _trace
@@ -263,16 +264,20 @@ class Lfm2MoeBlock(nn.Layer):
             return h + out, load
 
 
-def export_expert_load(fresh, first_held: int):
+def export_expert_load(fresh, first_held: int, walk=None):
     """An epoch's load of a trained model's routed layers, (layers, E_held +
     2) int64 on the host (``functional.experts.load_arrays`` a layer), to
-    the expert-load metrics."""
+    the expert-load metrics; with ``walk``, a step's ``(stride, pairs)``
+    (``functional.experts.pair_walk``), also how many strides of the pair
+    buffer the grouped product walked for those pairs."""
     from ..distributed.fleet import moe as _moe
     tokens = fresh[:, :-2]
     worst = [float(t.max() / t.mean()) for t in tokens if t.sum() > 0]
     _moe.stamp_expert_load(
         tokens.sum(axis=0), first_held, fresh[:, -2].sum(),
         fresh[:, -1].sum(), max(worst, default=None))
+    if walk is not None:
+        _moe.stamp_pair_strides(fresh[:, -2], fresh[:, -1], *walk)
 
 
 class Lfm2MoeModel(nn.Layer):
@@ -287,6 +292,9 @@ class Lfm2MoeModel(nn.Layer):
             [Lfm2MoeBlock(cfg, i) for i in range(cfg.num_hidden_layers)])
         self.embedding_norm = nn.RMSNorm(cfg.hidden_size,
                                          epsilon=cfg.norm_eps)
+        #: ``(stride, pairs)`` of a routed layer's grouped product in the
+        #: counting step as it was traced (None: the masked form)
+        self._walk = None
 
     def step_counters(self) -> dict:
         """``observability.trace.STEP_COUNTERS`` this model feeds while a
@@ -299,12 +307,17 @@ class Lfm2MoeModel(nn.Layer):
             (cfg.sparse_layers, hi - lo + 2), jnp.int32, self._export_load)}
 
     def _export_load(self, fresh):
-        export_expert_load(fresh, self.cfg.experts_held[0])
+        export_expert_load(fresh, self.cfg.experts_held[0], self._walk)
 
     def forward(self, input_ids):
         with jax.named_scope("embed"):
             x = self.embed_tokens(input_ids)
         counting = _trace.counting_step() and self.cfg.sparse_layers > 0
+        if counting:
+            lo, hi = self.cfg.experts_held
+            self._walk = _experts.pair_walk(
+                math.prod(input_ids.shape), self.cfg.num_experts_per_tok,
+                hi - lo, self.cfg.num_experts)
         loads = []
         for blk in self.layers:
             if counting and blk.sparse:

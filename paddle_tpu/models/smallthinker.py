@@ -44,6 +44,7 @@ import numpy as np
 
 from .. import nn, ops
 from ..nn import functional as F
+from ..nn.functional import experts as _experts
 from ..nn.initializer import Normal
 from ..nn.parameter import ParamAttr
 from ..observability import trace as _trace
@@ -221,6 +222,9 @@ class SmallThinkerModel(nn.Layer):
         self.layers = nn.LayerList(
             [SmallThinkerBlock(cfg, i) for i in range(cfg.num_hidden_layers)])
         self.norm = nn.RMSNorm(cfg.hidden_size, epsilon=cfg.rms_norm_eps)
+        #: ``(stride, pairs)`` of a routed layer's grouped product in the
+        #: counting step as it was traced (None: the masked form)
+        self._walk = None
 
     def step_counters(self) -> dict:
         """``observability.trace.STEP_COUNTERS`` this model feeds while a
@@ -232,12 +236,18 @@ class SmallThinkerModel(nn.Layer):
             self._export_load)}
 
     def _export_load(self, fresh):
-        export_expert_load(fresh, self.cfg.experts_held[0])
+        export_expert_load(fresh, self.cfg.experts_held[0], self._walk)
 
     def forward(self, input_ids):
         with jax.named_scope("embed"):
             x = self.embed_tokens(input_ids)
         counting = _trace.counting_step()
+        if counting:
+            lo, hi = self.cfg.experts_held
+            self._walk = _experts.pair_walk(
+                math.prod(input_ids.shape),
+                self.cfg.moe_num_active_primary_experts, hi - lo,
+                self.cfg.moe_num_primary_experts)
         loads = []
         for blk in self.layers:
             if counting:

@@ -86,7 +86,7 @@ class LatentMoE(Layer):
         with jax.named_scope("moe.experts"):
             routed = self.latent_up(F.held_experts_relu2(
                 self.latent_down(flat), idx, w, self.w1, self.w2, lo=lo,
-                valid=rows))
+                valid=rows, num_experts=self.gate_weight.shape[1]))
         with jax.named_scope("moe.shared"):
             shared = self.shared_down(relu2(self.shared_up(flat)))
         out = (routed + shared).reshape(shape)

@@ -123,7 +123,8 @@ class SwiGLUMoE(Layer):
         with jax.named_scope("moe.experts"):
             routed = F.held_experts_swiglu(
                 flat, idx, w, self.w_gate, self.w_up, self.w_down, lo=lo,
-                valid=rows, activation=self.activation)
+                valid=rows, activation=self.activation,
+                num_experts=self.gate_weight.shape[1])
         if self.shared_width:
             with jax.named_scope("moe.shared"):
                 routed = routed + self.shared(flat)

@@ -296,11 +296,16 @@ DEVICE_SCOPES: Dict[str, str] = {
     "moe": "a routed expert layer, all of it",
     "moe.router": "float32 scores, top-k, renormalised weights",
     "moe.experts": "the held experts' product, either form",
-    "moe.group": "grouped form only: sort of the landed pairs by expert, "
-                 "gather of their token rows, un-sort of the results",
+    "moe.group": "grouped form only: sort of the (token, choice) pairs by "
+                 "expert and, inside the layer's loop bodies (forward and "
+                 "the hand-written backward, ``while/body/moe.group``), a "
+                 "stride's gather of token rows, gate and write into the "
+                 "result buffer; after the loop the un-sort and the sum "
+                 "over a token's choices",
     "moe.grouped_matmul": "grouped form only: one grouped matmul a matrix "
-                          "(XLA names the kernel itself ``ragged-dot-*``, "
-                          "whatever scope it was traced under)",
+                          "a stride, inside the loop bodies (XLA names the "
+                          "kernel itself ``ragged-dot-*``, whatever scope "
+                          "it was traced under)",
     "moe.shared": "the shared expert, where the layer has one",
     "lm_head": "the head's product where logits are returned",
     "loss": "cross entropy; with a fused head, the head's product too",
@@ -328,7 +333,15 @@ STEP_COUNTERS: Dict[str, str] = {
                        "(token, expert) pairs that landed on held experts "
                        "and the pairs selected in all "
                        "(nn.functional.experts.load_arrays); exported "
-                       "through distributed.fleet.moe.stamp_expert_load",
+                       "through distributed.fleet.moe.stamp_expert_load, "
+                       "and, from the landed and selected pairs and the "
+                       "call's static stride, as the strides of the sorted "
+                       "pair buffer the grouped product walked against the "
+                       "strides of the whole buffer "
+                       "(paddle_tpu_moe_pair_strides_total{kind=walked|"
+                       "buffer}, distributed.fleet.moe.stamp_pair_strides: "
+                       "no device counter of its own, the step's program "
+                       "is unchanged)",
 }
 
 _counting = threading.local()       # .sink: the step being traced, if any

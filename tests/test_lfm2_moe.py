@@ -29,6 +29,7 @@ from __future__ import annotations
 
 import copy
 import os
+import re
 import sys
 
 import jax
@@ -140,58 +141,174 @@ def test_grouped_product_is_the_masked_product(form, padded):
     grads_close(got, want)
 
 
-def test_rows_past_the_last_group_never_reach_a_result(monkeypatch):
+#: the strided walk's test shapes: 400 x 4 = 1600 pairs, 4 of 32 experts
+#: held, so ``pair_stride`` is one 512-row tile and the buffer four strides
+#: (the last one 448 rows of padding that are no pair's)
+WALK = dict(n=400, k=4, hidden=16, width=24, held=4, lo=8, router=32)
+
+
+def landing(landed, favourite=None, padded=False, seed=7):
+    """Routing of the ``WALK`` shapes under which exactly ``landed`` pairs
+    land on the held experts (``favourite``: the share of them that one
+    held expert takes), the rest on absent ones; ``padded`` marks a tenth of
+    the rows as padding, whose pairs land nowhere whatever they chose."""
+    g = WALK
+    rng = np.random.RandomState(seed)
+    valid = np.ones(g["n"], bool)
+    if padded:
+        valid[rng.permutation(g["n"])[:g["n"] // 10]] = False
+    idx = rng.randint(g["lo"] + g["held"], g["router"], (g["n"], g["k"]))
+    idx[~valid] = g["lo"]              # padding rows choose a held expert
+    free = np.flatnonzero(np.repeat(valid, g["k"]))
+    assert landed <= len(free)
+    here = rng.permutation(free)[:landed]
+    chosen = g["lo"] + rng.randint(0, g["held"], landed)
+    if favourite is not None:
+        chosen[:int(favourite * landed)] = g["lo"] + 1
+    idx.reshape(-1)[here] = chosen
+    idx, valid = jnp.asarray(idx, jnp.int32), jnp.asarray(valid)
+    load = np.asarray(E.load_arrays(idx, g["lo"], g["held"],
+                                    valid if padded else None))
+    assert load[-2] == landed
+    return (idx, jnp.asarray(rng.rand(g["n"], g["k"]), jnp.float32),
+            valid if padded else None)
+
+
+def walk_operands(form):
+    g = WALK
+    shapes = [(g["held"], g["hidden"], g["width"])] \
+        * (2 if form == "swiglu" else 1) \
+        + [(g["held"], g["width"], g["hidden"])]
+    return rand((g["n"], g["hidden"]), 1), \
+        [rand(s, 30 + i, 0.3) for i, s in enumerate(shapes)]
+
+
+def both_forms(idx, valid):
+    """``(masked, grouped)``: each ``(x, w, *mats) -> (values, gradients in
+    every operand of the sum of squares)`` as one program."""
+    g = WALK
+
+    def masked(x, w, *m):
+        return E.experts_arrays(
+            x, E.combine_arrays(idx, w, g["lo"], g["held"], valid), m)
+
+    def grouped(x, w, *m):
+        return E.grouped_experts_arrays(x, idx, w, m, g["lo"], valid,
+                                        num_experts=g["router"])
+
+    def with_gradients(fn):
+        def run(*a):
+            grads, out = jax.grad(
+                lambda *a: (lambda y: (jnp.sum(y ** 2), y))(fn(*a)),
+                argnums=tuple(range(len(a))), has_aux=True)(*a)
+            return out, grads
+        return jax.jit(run)
+
+    return with_gradients(masked), with_gradients(grouped)
+
+
+STRIDE = 512
+WALKS = {"nothing-lands": (0, None, 0),
+         "one-stride-exactly": (STRIDE, None, 1),
+         "one-pair-more": (STRIDE + 1, None, 2),
+         "one-expert-takes-nearly-all": (1200, 0.95, 3)}
+
+
+@pytest.mark.parametrize("form", ["swiglu", "relu2"])
+@pytest.mark.parametrize("padded", [False, True], ids=["whole", "padded"])
+@pytest.mark.parametrize("case", [*WALKS, "every-pair-lands"])
+def test_strided_walk_is_the_masked_product(case, padded, form):
+    """The grouped form walks ``ceil(landed / stride)`` strides of its
+    sorted buffer and multiplies every pair that landed, whatever the
+    count: values and the gradients in ``x``, the routing weights and every
+    matrix against ``experts_arrays``, the definition."""
+    g = WALK
+    assert E.pair_stride(g["n"], g["k"], g["held"], g["router"]) == STRIDE
+    if case == "every-pair-lands":      # the whole buffer, the worst case
+        rows = g["n"] - (g["n"] // 10 if padded else 0)
+        landed, favourite, strides = rows * g["k"], None, -(
+            -rows * g["k"] // STRIDE)
+    else:
+        landed, favourite, strides = WALKS[case]
+    assert E.strides_walked(landed, STRIDE) == strides
+    idx, w, valid = landing(landed, favourite, padded)
+    x, mats = walk_operands(form)
+    masked, grouped = both_forms(idx, valid)
+    want, want_grads = masked(x, w, *mats)
+    got, got_grads = grouped(x, w, *mats)
+    close(got, want, TIGHT * 10)
+    assert all(bool(jnp.all(jnp.isfinite(d))) for d in got_grads)
+    grads_close(got_grads, want_grads)
+    if not landed:
+        assert not np.asarray(got).any()
+        assert not any(np.asarray(d).any() for d in got_grads)
+
+
+@pytest.mark.parametrize("landed", [
+    pytest.param(700, id="last-stride-half-live"),
+    pytest.param(0, id="no-stride-walked")])
+def test_rows_past_the_last_group_never_reach_a_result(monkeypatch, landed):
     """On a TPU the grouped-matmul kernel does not write the rows that
     belong to no group, in the product or in its transpose (the CPU's
     ``ragged_dot`` zeroes them, which hid a gradient of 3e5 times its size
-    on the chip). A stand-in that fills those rows with NaN, forward and
-    backward: values and gradients still equal the masked product's."""
+    on the chip), and the buffers the strides write into start as whatever
+    the memory held. Stand-ins that fill both with NaN: the rows of the last
+    stride past the landed pairs, and the buffer rows of strides never
+    walked (two of four here, or all four), forward and backward. Values
+    and gradients still equal the masked product's."""
     real = jax.lax.ragged_dot
 
-    def poison(a, sizes):
-        rows = jnp.arange(a.shape[0])[:, None]
-        return jnp.where(rows < jnp.sum(sizes), a, jnp.nan)
+    def kernel(lhs, rhs, sizes, **kw):
+        rows = jnp.arange(lhs.shape[0])[:, None]
+        return jnp.where(rows < jnp.sum(sizes), real(lhs, rhs, sizes, **kw),
+                         jnp.nan)
 
-    @jax.custom_vjp
-    def kernel(lhs, rhs, sizes):
-        return poison(real(lhs, rhs, sizes), sizes)
+    monkeypatch.setattr(E.jax.lax, "ragged_dot", kernel)
+    monkeypatch.setattr(E, "_unwritten",
+                        lambda shape, dtype: jnp.full(shape, jnp.nan, dtype))
+    idx, w, valid = landing(landed, padded=True)
+    x, mats = walk_operands("swiglu")
+    masked, grouped = both_forms(idx, valid)
+    want, want_grads = masked(x, w, *mats)
+    got, got_grads = grouped(x, w, *mats)
+    assert bool(jnp.all(jnp.isfinite(got)))
+    close(got, want, TIGHT * 10)
+    assert all(bool(jnp.all(jnp.isfinite(d))) for d in got_grads)
+    grads_close(got_grads, want_grads)
 
-    def fwd(lhs, rhs, sizes):
-        return kernel(lhs, rhs, sizes), (lhs, rhs, sizes)
 
-    def bwd(res, g):
-        lhs, rhs, sizes = res
-        rows = jnp.arange(g.shape[0])[:, None]
-        g = jnp.where(rows < jnp.sum(sizes), g, 0.0)     # read by no group
-        _out, vjp = jax.vjp(lambda a, b: real(a, b, sizes), lhs, rhs)
-        d_lhs, d_rhs = vjp(g)
-        return poison(d_lhs, sizes), d_rhs, None
+def test_the_walk_is_one_loop_a_direction_and_no_branch():
+    """The lowered grouped call, forward + backward: one ``while`` a
+    direction (a data-dependent trip count, ONE body), no ``case`` / ``if``
+    over sizes, every grouped matmul inside a loop body, and the layer's two
+    scopes on the operations of both bodies (the backward is a
+    ``custom_vjp``'s, traced apart from the forward: it opens them
+    itself)."""
+    g = WALK
+    idx, w, _valid = landing(700)
+    x, mats = walk_operands("swiglu")
 
-    kernel.defvjp(fwd, bwd)
-    monkeypatch.setattr(
-        E.jax.lax, "ragged_dot",
-        lambda lhs, rhs, sizes, **_kw: kernel(lhs, rhs, sizes))
-    n, k, hidden, width, held, lo = 64, 4, 16, 24, 8, 8
-    idx, w, valid = skewed_routing(n, k, 32, lo, held, 5)
-    x = rand((n, hidden), 1)
-    mats = [rand(s, 20 + i, 0.3) for i, s in enumerate(
-        [(held, hidden, width)] * 2 + [(held, width, hidden)])]
+    def loss(x, w, *m):
+        with jax.named_scope("moe"):
+            return jnp.sum(E.grouped_experts_arrays(
+                x, idx, w, m, g["lo"], num_experts=g["router"]) ** 2)
 
-    def masked(x, w, *m):
-        return E.experts_arrays(x, E.combine_arrays(idx, w, lo, held, valid),
-                                m)
-
-    def grouped(x, w, *m):
-        return E.grouped_experts_arrays(x, idx, w, m, lo, valid)
-
-    close(jax.jit(grouped)(x, w, *mats), jax.jit(masked)(x, w, *mats),
-          TIGHT * 10)
-    args = (0, 1, 2, 3, 4)
-    got = jax.jit(jax.grad(lambda *a: jnp.sum(grouped(*a) ** 2),
-                           argnums=args))(x, w, *mats)
-    assert all(bool(jnp.all(jnp.isfinite(g))) for g in got)
-    grads_close(got, jax.jit(jax.grad(lambda *a: jnp.sum(masked(*a) ** 2),
-                                      argnums=args))(x, w, *mats))
+    step = jax.grad(loss, argnums=(0, 1, 2, 3, 4))
+    text = jax.jit(step).lower(x, w, *mats).as_text(debug_info=True)
+    assert text.count("stablehlo.while") == 2
+    assert "stablehlo.case" not in text and "stablehlo.if" not in text
+    names = re.findall(r'loc\("([^"]*ragged_dot_general[^"]*)"', text)
+    assert names and all("/while/body/moe.grouped_matmul/" in n
+                         for n in names)
+    for direction in ("jvp(moe)", "transpose(jvp(moe))"):
+        for scope in ("moe.group", "moe.grouped_matmul"):
+            assert f"{direction}/while/body/{scope}/" in text, (
+                direction, scope)
+    # three products forward; backward the first two again, three
+    # transposes in the rows and three in the matrices: none outside a loop
+    jaxpr = str(jax.make_jaxpr(step)(x, w, *mats))
+    assert jaxpr.count("ragged_dot_general[") == 3 + 2 + 3 + 3
+    assert jaxpr.count("while[") == 2 and "cond[" not in jaxpr
 
 
 @pytest.mark.parametrize("rows,grouped", [
@@ -430,6 +547,65 @@ def test_step_carries_the_load_counter_only_under_metrics(metrics_on):
     assert int(engine._train_step._cache_size()) == 1
     pairs = metrics.REGISTRY.get("paddle_tpu_moe_routed_pairs_total").total()
     assert pairs - pairs0 == load[:, -2:].sum()
+
+
+@pytest.mark.parametrize("rows,k,held,router,want", [
+    pytest.param(16384, 4, 8, 32, (32768, 65536), id="lfm2-cell"),
+    pytest.param(16384, 6, 8, 64, (24576, 98304), id="smallthinker-cell"),
+    pytest.param(2048, 4, 8, 32, (4096, 8192), id="first-grouped-rows"),
+    pytest.param(2047, 4, 8, 32, None, id="masked-form"),
+    pytest.param(2100, 4, 3, 32, (2048, 8400), id="whole-tiles"),
+    pytest.param(4096, 2, 2, None, (8192, 8192), id="router-unknown"),
+    pytest.param(4096, 2, 8, 8, (8192, 8192), id="every-expert-held")])
+def test_the_stride_follows_from_the_shapes(rows, k, held, router, want):
+    """Twice the pairs even routing lands on the held experts, in whole
+    512-row tiles, never longer than the buffer; the whole buffer where the
+    router's width is not known; nothing under ``GROUPED_MIN_ROWS``."""
+    assert E.pair_walk(rows, k, held, router) == want
+
+
+def test_strides_walked_are_exported_beside_the_pairs(metrics_on):
+    """From an epoch's landed and selected pairs a layer and the call's
+    static stride: ``ceil(landed a step / stride)`` strides a layer a step
+    against the strides of the whole buffer."""
+    from paddle_tpu.distributed.fleet import moe as fleet_moe
+    counter = metrics.REGISTRY.get("paddle_tpu_moe_pair_strides_total")
+    before = counter.total()
+    steps, pairs, stride = 10, 65536, 32768
+    landed = np.array([20000, 40000, 0, 3]) * steps
+    walked, whole = fleet_moe.stamp_pair_strides(
+        landed, np.full(4, steps * pairs), stride, pairs)
+    assert (walked, whole) == (steps * (1 + 2 + 0 + 1), steps * 4 * 2)
+    assert counter.total() - before == walked + whole
+
+
+def test_a_counting_step_notes_what_its_routed_layers_walk(metrics_on):
+    """The model notes the ``(stride, pairs)`` of its routed layers where
+    the counting step is traced (here: shapes only), and hands it to the
+    exporter with each epoch's load; a batch that takes the masked form
+    walks nothing and exports no stride."""
+    from paddle_tpu.distributed.fleet import moe as fleet_moe
+    lm = Lfm2MoeForCausalLM(driver.model_config(CFG))
+    model = lm.model
+    lo, hi = model.cfg.experts_held
+    counter = metrics.REGISTRY.get("paddle_tpu_moe_pair_strides_total")
+    sparse = CFG["num_hidden_layers"] - CFG["num_dense_layers"]
+    for rows, walks in ((E.GROUPED_MIN_ROWS, True), (64, False)):
+        def forward(ids):
+            with obs_trace.step_counters():
+                return model(Tensor(ids))._data
+        jax.eval_shape(forward, jax.ShapeDtypeStruct((1, rows), jnp.int32))
+        want = E.pair_walk(rows, CFG["num_experts_per_tok"], hi - lo,
+                           model.cfg.num_experts)       # the router's width
+        assert model._walk == want and (want is not None) is walks
+        fresh = np.zeros((sparse, hi - lo + 2), np.int64)
+        fresh[:, -1] = 3 * rows * CFG["num_experts_per_tok"]   # three steps
+        fresh[:, -2] = 3 * 5
+        before = counter.total()
+        model._export_load(fresh)
+        whole = 0 if not walks else sparse * 3 * -(-want[1] // want[0])
+        assert counter.total() - before == (sparse * 3 + whole
+                                            if walks else 0)
 
 
 def test_step_carries_no_counter_with_metrics_off():

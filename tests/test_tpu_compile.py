@@ -472,9 +472,10 @@ def _flash_calls(entry):
             for kernel in ("flash_fwd", "flash_dq", "flash_dkv")}
 
 
-def _compiled_train_step(lm, batch, seq, one_chip, monkeypatch):
+def _compiled_train_step(lm, batch, seq, one_chip, monkeypatch, whole=False):
     """The ENTRY computation of ``Engine``'s train step over ``lm`` under
-    bf16 O1 autocast with AdamW, compiled for the described chip."""
+    bf16 O1 autocast with AdamW, compiled for the described chip (``whole``:
+    every computation of the module, the loops' bodies among them)."""
     from paddle_tpu import amp, nn
     from paddle_tpu.distributed import mesh as mesh_mod
     from paddle_tpu.distributed.auto_parallel import Engine
@@ -512,7 +513,26 @@ def _compiled_train_step(lm, batch, seq, one_chip, monkeypatch):
         params, state, jax.ShapeDtypeStruct((), jnp.float32,
                                             sharding=one_chip),
         ids, ids).compile().as_text()
-    return text[text.index("ENTRY"):]
+    return text if whole else text[text.index("ENTRY"):]
+
+
+_GROUPED_KERNEL = r"%ragged-dot-(?!metadata)[\w-]+(?:\.\d+)? = "
+
+
+def _expert_loop_bodies(entry):
+    """Names of the body computations of the ``while``s that ENTRY runs for
+    the expert layers (``nn/functional/experts.py`` ``_walk``)."""
+    return [m.group(1) for m in re.finditer(
+        r"[^\n]* while\([^\n]*body=%([\w.-]+)[^\n]*", entry)
+        if "moe.experts/while" in m.group(0)]
+
+
+def _computations(text):
+    """``{name: text}`` of a compiled module's computations."""
+    found = re.split(r"\n(?=(?:ENTRY )?%[\w.-]+ \([^\n]*\) -> [^\n]* \{\n)",
+                     text)
+    return {re.match(r"(?:ENTRY )?%([\w.-]+) ", c).group(1): c
+            for c in found if re.match(r"(?:ENTRY )?%[\w.-]+ \(", c)}
 
 
 def test_lfm2_train_step_compiles_with_the_grouped_matmuls(one_chip,
@@ -521,7 +541,8 @@ def test_lfm2_train_step_compiles_with_the_grouped_matmuls(one_chip,
     blocks rematerialised, bf16 O1 autocast) at 2 x 1024 tokens, the rows
     from which the expert product takes its grouped form: XLA lowers each
     ``ragged_dot`` and each of its transposes to a ``ragged-dot`` Mosaic
-    kernel, and the attention layer runs the three flash kernels, the
+    kernel inside the layer's two loops, no branch over sizes comes from
+    the layer, and the attention layer runs the three flash kernels, the
     forward one once (the block keeps the kernel's two residuals,
     ``models/_remat.py``)."""
     from paddle_tpu.models import Lfm2MoeForCausalLM, lfm2_moe_tiny
@@ -533,12 +554,31 @@ def test_lfm2_train_step_compiles_with_the_grouped_matmuls(one_chip,
                         num_key_value_heads=1, intermediate_size=256,
                         moe_intermediate_size=128, num_experts=8,
                         experts_held=(0, 4), vocab_size=512, recompute=True)
-    entry = _compiled_train_step(Lfm2MoeForCausalLM(cfg), batch, seq,
-                                 one_chip, monkeypatch)
-    grouped = re.findall(r"%ragged-dot-(?!metadata)[\w-]+(?:\.\d+)? = ", entry)
-    # four routed layers: three products forward, again rematerialised, and
-    # six transposes backward, less what the compiler shares between them
-    assert 4 * 9 <= len(grouped) <= 4 * 12
+    text = _compiled_train_step(Lfm2MoeForCausalLM(cfg), batch, seq,
+                                one_chip, monkeypatch, whole=True)
+    comps = _computations(text)
+    entry = text[text.index("ENTRY"):]
+    # the expert layer walks its sorted pairs in ONE loop a direction
+    # (``nn/functional/experts.py`` ``_walk``): every grouped matmul is in
+    # the body of a ``while`` traced under ``moe.experts``, none in ENTRY
+    loops = _expert_loop_bodies(entry)
+    # four routed layers, a loop forward and a loop backward each (the
+    # rematerialised forward's results are read by nothing, its loop is
+    # dropped) or a third where the compiler keeps that copy
+    assert 4 * 2 <= len(loops) <= 4 * 3
+    assert not re.findall(_GROUPED_KERNEL, entry)
+    grouped = sum(len(re.findall(_GROUPED_KERNEL, comps[b])) for b in loops)
+    # three products forward; backward two again and six transposes, less
+    # what the compiler shares between them
+    assert 4 * 9 <= grouped <= 4 * 12
+    assert not [line for line in text.splitlines()
+                if " conditional(" in line and "moe" in line]
+    # the bodies' own operations carry the layer's scope, forward and
+    # backward; the kernels are ``ragged-dot-*`` whatever they were traced
+    # under (``moe.grouped_matmul`` is read on the lowered call,
+    # ``tests/test_lfm2_moe.py``)
+    for body in loops:
+        assert "moe.experts/while/body/moe.group/" in comps[body], body
     assert _flash_calls(entry) == {"flash_fwd": 1, "flash_dq": 1,
                                    "flash_dkv": 1}
 
@@ -721,13 +761,23 @@ def test_smallthinker_16k_train_step_compiles_with_the_windowed_kernels(
     assert [blk.self_attn.window for blk in SmallThinkerForCausalLM(
         cfg).model.layers] == [None, g["window"]]
     with fa.kept_residuals():       # a fresh log: none is open here
-        text = _compiled_train_step(SmallThinkerForCausalLM(cfg), 1,
-                                    g["seq"], one_chip, monkeypatch)
+        whole = _compiled_train_step(SmallThinkerForCausalLM(cfg), 1,
+                                     g["seq"], one_chip, monkeypatch,
+                                     whole=True)
+    text = whole[whole.index("ENTRY"):]
     assert _flash_calls(text) == dict.fromkeys(
         ("flash_fwd", "flash_dq", "flash_dkv"), 2)
-    assert re.findall(r"%ragged-dot-(?!metadata)[\w-]+(?:\.\d+)? = ", text)
+    # the grouped matmuls run in the expert layers' loop bodies: two layers,
+    # a loop forward and one backward each, none of them a branch
+    comps = _computations(whole)
+    bodies = _expert_loop_bodies(text)
+    assert 2 * 2 <= len(bodies) <= 2 * 3
+    assert all(re.findall(_GROUPED_KERNEL, comps[b]) for b in bodies)
+    assert not re.findall(_GROUPED_KERNEL, text)
+    assert not [line for line in whole.splitlines()
+                if " conditional(" in line and "moe" in line]
     square = rf"\[(?:\d+,)*{g['seq']},{g['seq']}(?:,\d+)*\]"
-    assert not re.findall(square, text)
+    assert not re.findall(square, whole)
     # what the two calls were built with: the same 8 x 8 tiles of 2048, the
     # window's 21 of the 36 that the causal call runs
     full = fa.flash_plan(g["seq"], g["seq"], True, 2048, 2048, g["heads"],
