@@ -83,9 +83,18 @@ class Counter(_Metric):
         self._vals: Dict[tuple, float] = {}
 
     def inc(self, amount: float = 1.0, **labels):
+        if _enabled["on"]:
+            self.inc_at(self._label_values(labels), amount)
+
+    def key(self, **labels) -> tuple:
+        """The series key of ``labels``, made once for ``inc_at``."""
+        return self._label_values(labels)
+
+    def inc_at(self, key: tuple, amount: float = 1.0):
+        """``inc`` for a site that may allocate nothing an event (the
+        collector's hook): ``key`` is ``key(**labels)``."""
         if not _enabled["on"]:
             return
-        key = self._label_values(labels)
         with self._lock:
             self._vals[key] = self._vals.get(key, 0.0) + amount
 

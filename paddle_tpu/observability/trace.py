@@ -20,10 +20,20 @@ and one entry per trace / lower / backend compile of every program, which
 JAX reports to the one listener registered here. Every producer fires at a
 compile, a lifecycle transition or the entry and exit of a ``fit`` call,
 never in a steady step or tick.
+
+What stops every Python thread at once has a table of its own
+(``HOST_SPANS``): the cyclic collector's passes, measured by one
+``gc.callbacks`` hook that exists only while ``FLAGS_enable_metrics`` is on.
+A pass is a ``host.gc`` annotation in a recording, two counters, and (a
+full pass, or one of a millisecond or more) an entry of the bounded pause
+record ``host_pauses()``. While a recording runs a boundary span also
+carries ``cpu_s``, its thread's CPU seconds: wall time far above it was
+spent off the CPU.
 """
 from __future__ import annotations
 
 import functools
+import gc
 import os
 import threading
 import time
@@ -35,6 +45,9 @@ from jax import monitoring as _monitoring
 from jax import profiler as _profiler
 from jax._src import xla_bridge as _xla_bridge
 
+from ..core import flags as _flags
+from . import metrics as _metrics
+
 __all__ = ["active", "activate", "deactivate", "add_complete", "span",
            "boundary", "BOUNDARY_SPANS", "drain", "clear", "MAX_EVENTS",
            "STARTUP_SPANS", "MAX_STARTUP_EVENTS", "startup_phase",
@@ -42,7 +55,8 @@ __all__ = ["active", "activate", "deactivate", "add_complete", "span",
            "note_import", "mark_backend", "note_backend", "startup_record",
            "startup_summary", "startup_clear", "DEVICE_SCOPES",
            "STEP_COUNTERS", "StepCounter", "counting_step", "count_in_step",
-           "step_counters"]
+           "step_counters", "HOST_SPANS", "MAX_HOST_PAUSES", "host_pauses",
+           "host_pauses_clear"]
 
 #: buffer cap — a runaway loop must degrade to dropped spans, not OOM
 MAX_EVENTS = 200_000
@@ -344,6 +358,18 @@ STEP_COUNTERS: Dict[str, str] = {
                        "is unchanged)",
 }
 
+#: THE list of spans of what stops the whole host at once: name ->
+#: (category, parent, what it brackets). "*": whichever span is open on the
+#: thread the event ran on. Entered by ``_on_collector``, only while
+#: ``FLAGS_enable_metrics`` is on. README "Observability" and PERF.md
+#: section 3 say which metric reads which.
+HOST_SPANS: Dict[str, Tuple[str, Optional[str], str]] = {
+    "host.gc": ("host", "*",
+                "one pass of the cyclic collector; every Python thread is "
+                "stopped for its length, because the pass holds the GIL "
+                "(stats: generation, collected, uncollectable)"),
+}
+
 _counting = threading.local()       # .sink: the step being traced, if any
 
 
@@ -383,6 +409,9 @@ def count_in_step(name: str, value) -> None:
         sink.values[name] = value if held is None else held + value
 
 
+_thread_time = time.thread_time     # cpu_s's clock (tests count its reads)
+
+
 class boundary(span):
     """A span of ``BOUNDARY_SPANS`` (any other name is a KeyError): the
     buffer like ``span``, and a ``jax.profiler.TraceAnnotation`` of the same
@@ -390,10 +419,12 @@ class boundary(span):
     ``args`` as the event's stats. The annotation exists only while a
     ``jax.profiler`` recording runs, the buffer entry only while ``active()``;
     ``args`` is read at exit, so a site may fill it while the span is open.
+    The annotation also carries ``cpu_s``, the thread's CPU seconds inside
+    the span (``time.thread_time``, read only where an annotation was made).
     The names ``STARTUP_SPANS`` lists too are phases of the start-up
     record, whatever is active."""
 
-    __slots__ = ("_step", "_ann", "_phase")
+    __slots__ = ("_step", "_ann", "_phase", "_cpu0")
 
     def __init__(self, name: str, args: Optional[dict] = None,
                  step_num: Optional[int] = None):
@@ -410,6 +441,7 @@ class boundary(span):
             self._ann = (_profiler.StepTraceAnnotation if self._step
                          else _profiler.TraceAnnotation)(self.name)
             self._ann.__enter__()
+            self._cpu0 = _thread_time()
         return span.__enter__(self)
 
     def __exit__(self, *exc):
@@ -417,8 +449,8 @@ class boundary(span):
         if self._phase is not None:
             self._phase.end(self.args)
         if self._ann is not None:
-            if self.args:
-                self._ann.set_metadata(**self.args)
+            self._ann.set_metadata(cpu_s=_thread_time() - self._cpu0,
+                                   **(self.args or {}))
             self._ann.__exit__(*exc)
         return False
 
@@ -807,3 +839,84 @@ def startup_summary(who: Optional[str] = None) -> dict:
     with _lock:
         _summaries[who] = (n, out)
     return dict(out)
+
+
+# --------------------------------------------------------------------------
+# The collector's pauses (HOST_SPANS). The hook is in ``gc.callbacks`` only
+# while FLAGS_enable_metrics is on: off, a pass reads no clock of ours.
+# --------------------------------------------------------------------------
+#: pause record cap; the passes past it are counted as ``dropped``
+MAX_HOST_PAUSES = 4096
+#: a pass of a younger generation enters the pause record from here on
+HOST_PAUSE_MIN_S = 1e-3
+
+_M_GC_SECONDS = _metrics.counter(
+    "paddle_tpu_host_gc_pause_seconds_total",
+    "Seconds every Python thread stood still for the cyclic collector",
+    ("generation",))
+_M_GC_COLLECTIONS = _metrics.counter(
+    "paddle_tpu_host_gc_collections_total",
+    "Passes of the cyclic collector", ("generation",))
+_GC_KEYS = tuple(_M_GC_SECONDS.key(generation=g) for g in range(3))
+
+_pauses: List[Tuple[float, float, int, int, int]] = []
+_pauses_dropped = {"n": 0}
+# the pass in flight: passes do not nest, and the GIL is held from the
+# "start" callback to the "stop" one
+_collecting = {"t0": 0.0, "ann": None}
+
+
+def _on_collector(phase: str, info: dict):
+    """The ``gc.callbacks`` hook. A young pass under ``HOST_PAUSE_MIN_S``
+    allocates no container: two floats and two counter slots."""
+    if phase == "start":
+        if _profiler.TraceAnnotation.is_enabled():
+            ann = _collecting["ann"] = _profiler.TraceAnnotation("host.gc")
+            ann.__enter__()
+        _collecting["t0"] = _perf_counter()
+        return
+    t1 = _perf_counter()
+    t0 = _collecting["t0"]
+    generation = info["generation"]
+    ann = _collecting["ann"]
+    if ann is not None:
+        _collecting["ann"] = None
+        ann.set_metadata(generation=generation, collected=info["collected"],
+                         uncollectable=info["uncollectable"])
+        ann.__exit__(None, None, None)
+    key = _GC_KEYS[generation]
+    _M_GC_SECONDS.inc_at(key, t1 - t0)
+    _M_GC_COLLECTIONS.inc_at(key)
+    if generation == 2 or t1 - t0 >= HOST_PAUSE_MIN_S:
+        if len(_pauses) >= MAX_HOST_PAUSES:
+            _pauses_dropped["n"] += 1
+        else:
+            _pauses.append((t0, t1, generation, info["collected"], _tid()))
+
+
+def _watch_collector(on):
+    """``FLAGS_enable_metrics``'s observer: the hook is in ``gc.callbacks``
+    exactly while the flag is on."""
+    if on and _on_collector not in gc.callbacks:
+        gc.callbacks.append(_on_collector)
+    elif not on and _on_collector in gc.callbacks:
+        gc.callbacks.remove(_on_collector)
+
+
+_flags.on_change("enable_metrics", _watch_collector)
+_watch_collector(_metrics.enabled())
+
+
+def host_pauses() -> dict:
+    """The pause record: ``entries`` ``(t0, t1, generation, collected,
+    tid)`` on ``perf_counter``'s clock (the start-up record's) in the order
+    they ended, every full (generation 2) pass and any pass of
+    ``HOST_PAUSE_MIN_S`` or more since ``FLAGS_enable_metrics`` went on,
+    and ``dropped``, the passes past ``MAX_HOST_PAUSES``."""
+    return {"entries": list(_pauses), "dropped": _pauses_dropped["n"]}
+
+
+def host_pauses_clear():
+    """Empty the pause record (tests; a window of one's own)."""
+    del _pauses[:]
+    _pauses_dropped["n"] = 0
