@@ -84,6 +84,18 @@ def axes_dividing(mesh: Mesh, n: int, names: Sequence[str]):
     return axes if axes and n % size == 0 else None
 
 
+def data_axes_dividing(batch: int):
+    """``(mesh, its data axes)`` where a mesh is set, the caller is in no
+    manual region yet and the axes' devices divide ``batch`` (an array of
+    ``batch`` rows traced here is cut over them); else ``(None, None)``."""
+    if has_mesh() and not jax.sharding.get_abstract_mesh().manual_axes:
+        mesh = get_mesh()
+        axes = axes_dividing(mesh, batch, ("dp", "sharding"))
+        if axes:
+            return mesh, axes
+    return None, None
+
+
 def axis_size(axis: str) -> int:
     mesh = get_mesh()
     return int(mesh.shape[axis]) if axis in mesh.shape else 1

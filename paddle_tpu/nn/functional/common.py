@@ -10,7 +10,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ...core import dispatch
+from ...core import dispatch, residuals
 from ...core.generator import next_key
 from ...core.tensor import Tensor, as_tensor
 
@@ -26,11 +26,15 @@ def linear(x, weight, bias=None, name=None):
     if bias is not None:
         inputs.append(_t(bias))
 
-        def f(a, w, b):
-            return jnp.matmul(a, w.astype(a.dtype)) + b.astype(a.dtype)
-    else:
-        def f(a, w):
-            return jnp.matmul(a, w.astype(a.dtype))
+    def f(a, w, b=None):
+        y = jnp.matmul(a, w.astype(a.dtype))
+        if b is not None:
+            y = y + b.astype(a.dtype)
+        # inside a rematerialised block the result gets the name under which
+        # the block's policy may keep it (``models/_remat.py``); anywhere
+        # else it gets none, and the program is the one it was
+        return residuals.keep(y, residuals.LINEAR_OUT) \
+            if residuals.is_open() else y
     return dispatch.call("linear", f, inputs)
 
 

@@ -814,20 +814,6 @@ def _head_loss_sums(xa, wa, ba, lab, transpose_y, ignore_index, chunk):
     return sums(xa, wa, ba, lab)
 
 
-def _data_axes_dividing(batch: int):
-    """``(mesh, its data axes)`` where a mesh is set, the caller is in no
-    manual region yet and the axes' devices divide ``batch``; else
-    ``(None, None)``."""
-    from ...distributed import mesh as mesh_mod
-    if mesh_mod.has_mesh() and not \
-            jax.sharding.get_abstract_mesh().manual_axes:
-        mesh = mesh_mod.get_mesh()
-        axes = mesh_mod.axes_dividing(mesh, batch, ("dp", "sharding"))
-        if axes:
-            return mesh, axes
-    return None, None
-
-
 @functools.lru_cache(maxsize=64)
 def _head_loss_program(transpose_y, ignore_index, reduction, chunk_rows,
                        mesh, axes):
@@ -911,7 +897,8 @@ def fused_linear_cross_entropy(x, weight, label, bias=None,
             if reduction == "none":
                 return jnp.zeros(lead, jnp.float32)
             return jnp.asarray(0.0, jnp.float32)
-        mesh, axes = _data_axes_dividing(lead[0])
+        from ...distributed import mesh as mesh_mod
+        mesh, axes = mesh_mod.data_axes_dividing(lead[0])
         shards = math.prod(mesh.shape[a] for a in axes) if axes else 1
         _stamp_head_loss_plan(head_loss_plan(
             n // shards, chunk_rows, wa.shape[0 if transpose_y else 1], h,

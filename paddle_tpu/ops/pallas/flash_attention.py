@@ -56,16 +56,14 @@ through the Pallas interpreter so CPU tests cover the real kernel code.
 """
 from __future__ import annotations
 
-import contextlib
 import functools
 import logging
 import math
-import threading
 
 import jax
 import jax.numpy as jnp
-from jax.ad_checkpoint import checkpoint_name
 
+from ...core.residuals import KEPT_RESIDUALS, keep as _keep
 from . import import_pallas
 
 pl, pltpu = import_pallas()
@@ -113,17 +111,6 @@ def _bwd_block_for(seq):
 
 #: run kernels in the Pallas interpreter (CPU testing of kernel code)
 INTERPRET = False
-
-#: the two residuals of a call that the backward kernels read and the forward
-#: kernel alone can make, as the forward rule names them
-#: (``jax.ad_checkpoint.checkpoint_name``): ``out`` as (B, S, H, d), the array
-#: the block goes on with and the backward kernels read in place, and the
-#: logsumexp as the forward kernel writes it, lane-dense (B, H // hpb, hpb,
-#: S_padded) float32 rows. A rematerialised block keeps
-#: exactly these beside its input (``models/_remat.py``), so its backward
-#: recomputes everything but the kernel; outside a ``jax.checkpoint`` a name
-#: is the identity.
-KEPT_RESIDUALS = ("flash_out", "flash_lse")
 
 #: scoped VMEM a causal call of tiles wider than 1024 asks for: beside a
 #: band's scores (256 x 2048 float32, 2 MB an array, and a block of two
@@ -1088,32 +1075,6 @@ def _resolve_blocks(kind, block_q, block_k, q, k, causal, scale):
                                scale)
     return (tq if block_q is None else block_q,
             tk if block_k is None else block_k)
-
-
-class _Kept(threading.local):
-    """This thread's list of what the forward rules named, while a
-    ``kept_residuals()`` block is open."""
-    log = None
-
-
-_kept = _Kept()
-
-
-@contextlib.contextmanager
-def kept_residuals():
-    """``[(name, shape, dtype), ...]`` of the residuals the forward rules
-    named while the body ran (``remat_block`` reports them)."""
-    outer, _kept.log = _kept.log, []
-    try:
-        yield _kept.log
-    finally:
-        _kept.log = outer
-
-
-def _keep(x, name):
-    if _kept.log is not None:
-        _kept.log.append((name, x.shape, x.dtype))
-    return checkpoint_name(x, name)
 
 
 def _flash_fwd(q, k, v, causal, scale, block_q, block_k, window):

@@ -748,6 +748,7 @@ def test_smallthinker_16k_train_step_compiles_with_the_windowed_kernels(
     a layer (the block keeps the kernel's two residuals), the expert product
     takes its grouped form, and no array as large as S x S exists in the
     whole program."""
+    from paddle_tpu.core import residuals
     from paddle_tpu.models import SmallThinkerForCausalLM, smallthinker_tiny
     from paddle_tpu.ops.pallas import flash_attention as fa
 
@@ -760,7 +761,7 @@ def test_smallthinker_16k_train_step_compiles_with_the_windowed_kernels(
         experts_held=(0, 4), vocab_size=512, recompute=True)
     assert [blk.self_attn.window for blk in SmallThinkerForCausalLM(
         cfg).model.layers] == [None, g["window"]]
-    with fa.kept_residuals():       # a fresh log: none is open here
+    with residuals.kept_residuals():    # a fresh log: none is open here
         whole = _compiled_train_step(SmallThinkerForCausalLM(cfg), 1,
                                      g["seq"], one_chip, monkeypatch,
                                      whole=True)
